@@ -38,6 +38,10 @@ Two halves:
   p50/p99, decode tokens/sec, and cached-block occupancy (see
   BENCH_SERVE_r02.json).
 
+Every mode measures the device, so every mode needs one: without a TPU the
+script exits non-zero before it builds anything, and a failed point is a
+failed run.
+
 Usage:
     python scripts/bench_serving.py [--steps 128] [--batches 1,4,8]
     python scripts/bench_serving.py --poisson [--rate 8] [--requests 48] \
@@ -50,12 +54,18 @@ from __future__ import annotations
 
 import argparse
 import json
+import os
 import random
+import sys
 import threading
 import time
 
-import jax
-import jax.numpy as jnp
+# run from a bare checkout: the repo root is not on sys.path when this file
+# is executed as a script
+sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+
+import jax  # noqa: E402
+import jax.numpy as jnp  # noqa: E402
 
 
 def bench_decode(params, cfg, batch: int, steps: int, prompt_len: int = 32):
@@ -536,7 +546,7 @@ def run_shared_prefix_comparison(args) -> dict:
     from torchx_tpu.serve.kv_pool import plan_pool
 
     platform = jax.devices()[0].platform
-    cfg_name = args.config if platform == "tpu" else "tiny"
+    cfg_name = args.config
     cfg = llama.CONFIGS[cfg_name]()
     max_new = min(args.steps, cfg.max_seq // 8)
     shared_len = min(args.shared_len, cfg.max_seq // 2)
@@ -587,10 +597,9 @@ def run_shared_prefix_comparison(args) -> dict:
         "prefix_hit_rate": dis["prefix_cache"]["hit_rate"],
         "goodput_delta": round(dis["goodput"] - uni["goodput"], 3),
     }
-    # paged-vs-dense at the target config (the HBM half of the story),
-    # same as the r01 report, plus what the cache held at steady state
-    plan_cfg = llama.CONFIGS[args.config]()
-    doc["kv_pool_occupancy"] = plan_pool(plan_cfg).occupancy_report()
+    # paged-vs-dense (the HBM half of the story), same as the r01 report,
+    # plus what the cache held at steady state
+    doc["kv_pool_occupancy"] = plan_pool(cfg).occupancy_report()
     print(json.dumps(doc["comparison"]))
     return doc
 
@@ -705,7 +714,7 @@ def run_poisson_comparison(args) -> dict:
     from torchx_tpu.serve.kv_pool import plan_pool
 
     platform = jax.devices()[0].platform
-    cfg_name = args.config if platform == "tpu" else "tiny"
+    cfg_name = args.config
     cfg = llama.CONFIGS[cfg_name]()
     max_new = min(args.steps, cfg.max_seq // 4)
     prompt_lens = tuple(
@@ -753,9 +762,7 @@ def run_poisson_comparison(args) -> dict:
         "goodput_delta": round(cont["goodput"] - coal["goodput"], 3),
     }
     # the paged-KV half of the story: concurrency at the same HBM budget
-    # (tiny on CPU has no meaningful HBM; report the target-config plan)
-    plan_cfg = llama.CONFIGS[args.config]()
-    doc["kv_pool_occupancy"] = plan_pool(plan_cfg).occupancy_report()
+    doc["kv_pool_occupancy"] = plan_pool(cfg).occupancy_report()
     print(json.dumps(doc["comparison"]))
     return doc
 
@@ -799,6 +806,16 @@ def main() -> None:
     ap.add_argument("--out", default=None, help="write the comparison JSON here")
     args = ap.parse_args()
 
+    platform = jax.devices()[0].platform
+    if platform != "tpu":
+        sys.exit(
+            f"bench_serving: no TPU (jax found {platform!r}); a number from"
+            " another backend is not a serving speed"
+        )
+    from torchx_tpu.parallel.xla_cache import setup_compilation_cache
+
+    setup_compilation_cache()
+
     if args.poisson or args.shared_prefix:
         doc = (
             run_shared_prefix_comparison(args)
@@ -815,13 +832,8 @@ def main() -> None:
     from torchx_tpu.models import llama
     from torchx_tpu.ops import quant
 
-    platform = jax.devices()[0].platform
-    if platform == "tpu":
-        cfg_name = args.config
-        cfg = llama.CONFIGS[cfg_name](max_seq=512, remat=False)
-    else:
-        cfg_name = "tiny"  # label what is actually measured
-        cfg = llama.llama_tiny()
+    cfg_name = args.config
+    cfg = llama.CONFIGS[cfg_name](max_seq=512, remat=False)
     # keep the decode window inside the config's declared context
     # (generate_stream enforces the same invariant)
     prompt_len = 32
@@ -837,15 +849,7 @@ def main() -> None:
 
     for batch in [int(b) for b in args.batches.split(",")]:
         for name, p in (("bf16", params), ("int8", qparams)):
-            try:
-                tps = bench_decode(p, cfg, batch, args.steps)
-            except Exception as e:  # noqa: BLE001 - report per point
-                print(
-                    json.dumps(
-                        {"point": f"{name}@b{batch}", "error": str(e)[:200]}
-                    )
-                )
-                continue
+            tps = bench_decode(p, cfg, batch, args.steps)
             print(
                 json.dumps(
                     {

@@ -690,13 +690,18 @@ class TestRetryRules:
         assert "TPX402" in codes(analyze(app_with(max_retries=-1)))
 
     def test_replica_retry_on_tpu_role(self):
-        report = analyze(
-            app_with(
-                retry_policy=RetryPolicy.REPLICA,
-                resource=Resource(tpu=TpuSlice("v5e", 4)),
+        def replica_role_on(chips):
+            return analyze(
+                app_with(
+                    retry_policy=RetryPolicy.REPLICA,
+                    resource=Resource(tpu=TpuSlice("v5e", chips)),
+                )
             )
-        )
-        assert "TPX401" in codes(report)
+
+        # v5e-16 is four hosts: one cannot rejoin the others' collective
+        assert "TPX401" in codes(replica_role_on(16))
+        # a one-host slice restarts whole (N one-chip servers are N worlds)
+        assert "TPX401" not in codes(replica_role_on(4))
         # REPLICA on a CPU role is fine
         assert "TPX401" not in codes(analyze(app_with(retry_policy=RetryPolicy.REPLICA)))
 

@@ -1,4 +1,4 @@
-"""CI gate for the north-star memory fit (VERDICT r4 #1).
+"""CI gate for the north-star memory fit.
 
 Compiles the real llama3_8b training step — the exact config the 45%-MFU
 v5p-32 claim uses, modulo the attention kernel — on the virtual-device CPU

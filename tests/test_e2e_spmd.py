@@ -107,8 +107,8 @@ def test_spmd_failure_surfaces_structured_error(tmp_path):
 @pytest.mark.e2e
 def test_spmd_retry_restarts_failed_gang(tmp_path):
     """Fault-injected replica death + max_retries: the gang restarts and
-    the SECOND attempt forms the full mesh (VERDICT/BASELINE: retry
-    policies actually restart a failed gang, proven end-to-end)."""
+    the SECOND attempt forms the full mesh (BASELINE: retry policies
+    actually restart a failed gang, proven end-to-end)."""
     marker = tmp_path / "fault-fired"
     with get_runner("spmd-e2e-retry") as runner:
         handle = runner.run_component(

@@ -150,14 +150,13 @@ class TestFlashSharded:
         for a, b in zip(g_flash, g_ref):
             np.testing.assert_allclose(a, b, rtol=5e-4, atol=5e-4)
 
-    def test_undividable_mesh_returns_none(self):
+    def test_undividable_mesh_raises(self):
         mesh = make_mesh(MeshConfig(dp=2, fsdp=2, tp=2, sp=1))
-        # 3 heads do not divide tp=2
+        # 3 heads do not divide tp=2: the kernel was asked for and cannot
+        # run, so the call fails instead of quietly taking another path
         q, k, v = _qkv(jax.random.PRNGKey(14), 4, 128, 3, 3, 64, jnp.float32)
-        assert (
+        with pytest.raises(ValueError, match="do not divide the mesh"):
             fused.flash_attention(q, k, v, kernels="interpret", mesh=mesh)
-            is None
-        )
 
 
 class TestRmsNormResidual:

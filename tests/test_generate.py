@@ -153,25 +153,22 @@ class TestGenerateStream:
 
 
 @pytest.mark.integ
-def test_bench_serving_script_smoke():
-    """scripts/bench_serving.py runs on CPU (tiny config) and emits valid
-    JSON lines — keeps the serving bench from rotting."""
-    import json
+@pytest.mark.parametrize("mode", [[], ["--poisson"], ["--shared-prefix"]])
+def test_bench_serving_refuses_to_measure_without_a_tpu(mode):
+    """scripts/bench_serving.py measures the device: with no TPU it exits
+    non-zero and prints no result, in every mode — it used to switch to
+    the tiny config on the CPU under the caller's label."""
     import subprocess
     import sys
     from pathlib import Path
 
     script = Path(__file__).resolve().parent.parent / "scripts" / "bench_serving.py"
     proc = subprocess.run(
-        [sys.executable, str(script), "--steps", "4", "--batches", "1"],
+        [sys.executable, str(script), "--steps", "4", "--batches", "1", *mode],
         capture_output=True,
         text=True,
         timeout=300,
     )
-    assert proc.returncode == 0, proc.stderr
-    lines = [ln for ln in proc.stdout.splitlines() if ln.startswith("{")]
-    assert len(lines) == 2  # bf16 + int8
-    for ln in lines:
-        d = json.loads(ln)
-        assert "error" not in d, d
-        assert d["value"] > 0
+    assert proc.returncode != 0
+    assert "no TPU" in proc.stderr and "'cpu'" in proc.stderr, proc.stderr
+    assert not [ln for ln in proc.stdout.splitlines() if ln.startswith("{")]

@@ -616,16 +616,37 @@ class TestCrossProcessCancelList:
 
 
 class TestTpuDeviceEnv:
+    ON_CHIP = {"JAX_PLATFORMS": "tpu,cpu"}  # never an inherited "cpu"
+
     def test_partitioning(self):
         env = tpu_device_env(4, replica_id=1, replicas_on_host=2, host_chips=8, simulate=True)
         assert env["TPU_VISIBLE_CHIPS"] == "4,5,6,7"
+        # each process is its own world over its chips
+        assert env["TPU_CHIPS_PER_PROCESS_BOUNDS"] == "2,2,1"
+        assert env["TPU_PROCESS_BOUNDS"] == "1,1,1"
+        assert env["JAX_PLATFORMS"] == "tpu,cpu"
+
+    def test_one_chip_replicas_get_distinct_chips_and_ports(self):
+        envs = [
+            tpu_device_env(1, i, replicas_on_host=4, host_chips=4, simulate=False)
+            for i in range(4)
+        ]
+        assert [e["TPU_VISIBLE_CHIPS"] for e in envs] == ["0", "1", "2", "3"]
+        assert {e["TPU_CHIPS_PER_PROCESS_BOUNDS"] for e in envs} == {"1,1,1"}
+        assert len({e["TPU_PROCESS_PORT"] for e in envs}) == 4
 
     def test_single_replica_uses_all_chips(self):
-        assert tpu_device_env(4, 0, replicas_on_host=1, host_chips=4, simulate=True) == {}
+        env = tpu_device_env(4, 0, replicas_on_host=1, host_chips=4, simulate=True)
+        assert env == self.ON_CHIP
+
+    def test_role_asking_one_chip_of_four_is_held_to_one(self):
+        env = tpu_device_env(1, 0, replicas_on_host=1, host_chips=4, simulate=True)
+        assert env["TPU_VISIBLE_CHIPS"] == "0"
+        assert env["TPU_CHIPS_PER_PROCESS_BOUNDS"] == "1,1,1"
 
     def test_partition_disabled_on_real_host(self):
         env = tpu_device_env(4, 0, replicas_on_host=2, host_chips=4, simulate=True, partition=False)
-        assert env == {}  # no CPU simulation forced on a host with chips
+        assert env == self.ON_CHIP  # no CPU simulation forced on a host with chips
 
     def test_oversubscription_raises(self):
         import pytest as _pytest
@@ -638,8 +659,48 @@ class TestTpuDeviceEnv:
         assert env["JAX_PLATFORMS"] == "cpu"
         assert "device_count=4" in env["XLA_FLAGS"]
 
-    def test_no_sim_no_chips(self):
-        assert tpu_device_env(4, 0, 1, host_chips=0, simulate=False) == {}
+    def test_no_sim_no_chips_is_refused(self):
+        import pytest as _pytest
+
+        with _pytest.raises(ValueError, match="no TPU chip"):
+            tpu_device_env(4, 0, 1, host_chips=0, simulate=False)
+
+    def test_no_sim_no_chips_fails_the_dryrun(self, tmp_path):
+        from torchx_tpu.schedulers.local_scheduler import create_scheduler
+
+        role = sh_role("t", "true")
+        role.resource = Resource(cpu=1, memMB=512, tpu=TpuSlice("v5e", 1))
+        sched = create_scheduler("no-chip")
+        try:
+            with pytest.raises(ValueError, match="no TPU chip"):
+                sched.submit_dryrun(
+                    AppDef(name="t", roles=[role]),
+                    {"log_dir": str(tmp_path), "tpu_simulate": False},
+                )
+        finally:
+            sched.close()
+
+    def test_inherited_cpu_platform_does_not_reach_a_tpu_role(
+        self, tmp_path, monkeypatch
+    ):
+        """The launching shell exports JAX_PLATFORMS=cpu (this sandbox
+        does); on a host with chips a TPU role must not inherit it."""
+        from torchx_tpu.schedulers import local_scheduler as ls
+
+        monkeypatch.setenv("JAX_PLATFORMS", "cpu")
+        monkeypatch.setattr(ls, "local_tpu_chip_count", lambda: 1)
+        role = sh_role("t", "true")
+        role.resource = Resource(cpu=1, memMB=512, tpu=TpuSlice("v5e", 1))
+        sched = ls.create_scheduler("chip")
+        try:
+            info = sched.submit_dryrun(
+                AppDef(name="t", roles=[role]),
+                {"log_dir": str(tmp_path), "tpu_simulate": False},
+            )
+        finally:
+            sched.close()
+        (rp,) = info.request.role_params["t"]
+        assert rp.env["JAX_PLATFORMS"] == "tpu,cpu"
 
 
 class TestManualResize:

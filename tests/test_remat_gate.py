@@ -40,21 +40,11 @@ class TestCheckPartitionerOutput:
                 f"blah\n{mod.REMAT_WARNING} for op %dot.1\nblah\n"
             )
 
-    def test_gspmd_deprecation_with_shardy_raises(self):
+    def test_gspmd_deprecation_raises(self):
         mod = _graft()
-        out = (
-            "shardy=on\n"
-            "W0000 GSPMD sharding propagation is going to be deprecated\n"
-        )
+        out = "W0000 GSPMD sharding propagation is going to be deprecated\n"
         with pytest.raises(RuntimeError, match="GSPMD"):
             mod.check_partitioner_output(out)
-
-    def test_gspmd_deprecation_without_shardy_passes(self):
-        # Old jax without Shardy legitimately compiles through GSPMD.
-        mod = _graft()
-        mod.check_partitioner_output(
-            "W0000 GSPMD sharding propagation is going to be deprecated\n"
-        )
 
 
 # A resharding the partitioner can only honor by replicating the whole

@@ -352,7 +352,7 @@ class TestClockSeams:
             _draining=False,
             _waiting=[object()],  # never drains
             _handoffs=[],
-            _prefilling=0,
+            _admitting=[],
             _slots=[None],
             _clock=lambda: now[0],
             _sleep=vsleep,

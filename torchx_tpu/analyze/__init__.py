@@ -66,7 +66,7 @@ Diagnostic codes
 | TPX305 | error | backend only provisions TPU slices but the role has no ``resource.tpu`` | set resource.tpu or pick another backend |
 | TPX306 | warning | ``max_retries`` set but the backend has no native restarts | run under ``tpx supervise`` |
 | TPX307 | warning | backend builds concrete resource requests but cpu/memMB are unset | set Resource.cpu / Resource.memMB |
-| TPX401 | warning | ``RetryPolicy.REPLICA`` on a TPU role (one host cannot rejoin the ICI collective) | use RetryPolicy.APPLICATION |
+| TPX401 | warning | ``RetryPolicy.REPLICA`` on a multi-host TPU role (one host cannot rejoin the ICI collective) | use RetryPolicy.APPLICATION |
 | TPX402 | error | ``max_retries < 0`` | use 0 to disable retries |
 | TPX403 | warning | supervisor preemption budget on a backend that cannot classify preemptions | raise max_app_retries or switch backend |
 | TPX404 | warning | role sets the supervisor's resume env var (it is injected on every resubmission) | let the supervisor drive resume |

@@ -1015,6 +1015,7 @@ def check_retries(ctx: RuleContext) -> Iterator[Diagnostic]:
         if (
             role.resource is not None
             and role.resource.tpu is not None
+            and role.resource.tpu.hosts > 1
             and role.retry_policy == RetryPolicy.REPLICA
         ):
             yield Diagnostic(
@@ -1023,9 +1024,9 @@ def check_retries(ctx: RuleContext) -> Iterator[Diagnostic]:
                 role=role.name,
                 field="retry_policy",
                 message=(
-                    "RetryPolicy.REPLICA on a TPU role: restarting one host"
-                    " cannot rejoin the ICI collective — the whole gang must"
-                    " restart"
+                    "RetryPolicy.REPLICA on a multi-host TPU role: restarting"
+                    " one host cannot rejoin the ICI collective — the whole"
+                    " gang must restart"
                 ),
                 hint="use RetryPolicy.APPLICATION (the TPU default)",
             )

@@ -11,9 +11,12 @@ same binary runs under every scheduler backend.
         --config llama_tiny [--ckpt-dir DIR] [--int8] [--port 8000]
 
 API (JSON):
-    GET  /healthz            -> {"status": "ok", "model": ..., "requests": N}
+    GET  /healthz            -> {"status": "ok", "model": ..., "requests": N,
+                                "platform": ..., "device_kind": ...,
+                                "device_count": N, "attention": ...}
                                 (503 {"status": "draining"} during SIGTERM
-                                grace)
+                                grace; 503 {"status": "failed"} once the
+                                engine loop has died)
     GET  /metricz            -> tpx_* metrics, Prometheus text format
     POST /v1/generate        {"tokens": [[...]], "max_new_tokens": 16,
                               "temperature": 0.0}
@@ -49,7 +52,8 @@ Two serving engines, selected by ``--engine``:
 On SIGTERM the server drains instead of dying mid-request: admission
 stops, ``/healthz`` flips to 503 (so routers and the serve pool stop
 sending traffic), in-flight slots decode to completion, then the process
-exits 0.
+exits 0. If the engine loop died (a device step raised), ``/healthz`` is
+503 with the reason, requests are refused, and the process exits 1.
 """
 
 from __future__ import annotations
@@ -61,6 +65,7 @@ import json
 import logging
 import os
 import queue
+import sys
 import threading
 import time
 from http.server import BaseHTTPRequestHandler, ThreadingHTTPServer
@@ -70,16 +75,6 @@ import jax
 import jax.numpy as jnp
 
 logger = logging.getLogger(__name__)
-
-
-def _assert_platform() -> None:
-    """Make the launcher's JAX_PLATFORMS choice stick even when a site
-    hook programmatically forced another platform (the same defense as
-    spmd_main — this app is launched directly, not through the spmd
-    bootstrap)."""
-    platforms = os.environ.get("JAX_PLATFORMS")
-    if platforms:
-        jax.config.update("jax_platforms", platforms)
 
 
 class ServiceDraining(RuntimeError):
@@ -194,6 +189,16 @@ class GenerateService:
 
             self.params = quantize_params(self.params)
         self.int8 = int8
+        from torchx_tpu.parallel.mesh import device_info
+        from torchx_tpu.settings import ENV_TPU_VISIBLE_CHIPS
+
+        # where this replica runs, as jax reports it (/healthz publishes
+        # it); visible_chips tells co-located one-chip replicas apart —
+        # each sees its own chip as device 0
+        self.device = {
+            **device_info(),
+            "visible_chips": os.environ.get(ENV_TPU_VISIBLE_CHIPS),
+        }
         self._cache_lock = threading.Lock()  # handlers run concurrently
         self._jit_cache: dict[tuple, Any] = {}
         self.requests = 0
@@ -208,7 +213,7 @@ class GenerateService:
         self._count_lock = threading.Lock()
         self._engine = None
         # prefill role: KV handoffs in flight to decode replicas — the
-        # disaggregated twin of the engine's _prefilling counter; drain()
+        # disaggregated twin of the engine's _admitting list; drain()
         # must wait these out or a mid-transfer SIGTERM drops the request
         self._transferring = 0
         self._transfer_done = threading.Condition()
@@ -262,6 +267,11 @@ class GenerateService:
             target=self._batch_loop, name="tpx-batcher", daemon=True
         )
         self._batcher.start()
+
+    @property
+    def failed(self) -> Optional[str]:
+        """Why the engine loop died, else None."""
+        return self._engine.failed if self._engine is not None else None
 
     def close(self) -> None:
         """Stop the serving engine (idempotent). Work enqueued before close
@@ -633,7 +643,7 @@ class GenerateService:
         t0 = time.monotonic()
         # the handoff window counts as in-flight for drain(): a SIGTERM
         # between prefill completion and the decode reply must not drop
-        # the request (the disaggregated twin of _prefilling)
+        # the request (the disaggregated twin of _admitting)
         with self._transfer_done:
             self._transferring += len(reqs)
         try:
@@ -735,9 +745,21 @@ def _make_handler(service: GenerateService):
 
         def do_GET(self) -> None:  # noqa: N802
             if self.path == "/healthz":
+                from torchx_tpu.ops.attention import traced
+
+                failed = service.failed
                 body = {
-                    "status": "draining" if service.draining else "ok",
+                    "status": (
+                        "failed"
+                        if failed
+                        else "draining" if service.draining else "ok"
+                    ),
                     "model": service.name,
+                    **service.device,
+                    # what the compiled steps lowered to so far ("" until
+                    # the first request traces one)
+                    "attention": traced("attention"),
+                    "kernels": service.cfg.kernels,
                     "engine": service.engine_mode,
                     "serve_role": service.serve_role,
                     "int8": service.int8,
@@ -751,9 +773,10 @@ def _make_handler(service: GenerateService):
                     # cache-aware routing inputs: what this replica holds
                     body["block_size"] = service._engine.block_size
                     body["prefix_summary"] = service._engine.prefix_summary()
-                # a draining replica must fail its health check so routers
-                # and the serve pool stop sending it traffic
-                self._reply(503 if service.draining else 200, body)
+                # a draining replica — and one whose engine died — must
+                # fail its health check so routers and the serve pool stop
+                # sending it traffic
+                self._reply(503 if failed or service.draining else 200, body)
             elif self.path == "/metricz":
                 from torchx_tpu.obs.metrics import REGISTRY
 
@@ -1068,7 +1091,10 @@ def main(argv: Optional[list[str]] = None) -> None:
 
         replica_id = int(os.environ.get(ENV_TPX_REPLICA_ID, "0") or "0")
         args.port += args.port_stride * replica_id
-    _assert_platform()
+    from torchx_tpu.parallel.xla_cache import setup_compilation_cache
+
+    # the decode step and every prefill bucket relaunch from the cache
+    setup_compilation_cache()
     t0 = time.monotonic()
     server = serve(
         args.config,
@@ -1089,13 +1115,19 @@ def main(argv: Optional[list[str]] = None) -> None:
     # report the BOUND port: with --port 0 the OS picks one, and whatever
     # launched us (serve pool, smoke test) reads it from this line
     port = server.server_address[1]
+    device = server.service.device
     print(
         f"generate_server: {args.config} [{args.engine}] on :{port}"
-        f" (loaded in {time.monotonic() - t0:.1f}s)",
+        f" (loaded in {time.monotonic() - t0:.1f}s)"
+        f" platform={device['platform']} device_kind={device['device_kind']!r}"
+        f" device_count={device['device_count']}",
         flush=True,
     )
     server.serve_forever()
     server.server_close()
+    if server.service.failed:
+        print(f"generate_server: {server.service.failed}", file=sys.stderr)
+        sys.exit(1)
 
 
 if __name__ == "__main__":

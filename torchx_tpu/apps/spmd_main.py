@@ -67,19 +67,7 @@ def _wait_for_coordinator(host: str, port: int, timeout: float = 300.0) -> None:
     raise TimeoutError(f"coordinator {host}:{port} unreachable after {timeout}s")
 
 
-def _assert_platform() -> None:
-    """Make the launcher's JAX_PLATFORMS choice stick even when a site hook
-    (sitecustomize registering a vendor PJRT plugin) programmatically forced
-    another platform before user code ran."""
-    platforms = os.environ.get(settings.ENV_JAX_PLATFORMS)
-    if platforms:
-        import jax
-
-        jax.config.update("jax_platforms", platforms)
-
-
 def initialize_distributed(port: int) -> None:
-    _assert_platform()
     process_id, num_processes, coordinator = _gang()
     # surface the resolved GLOBAL id to user code even when the backend
     # injected only the (slice, host) decomposition (e.g. GKE multi-slice)

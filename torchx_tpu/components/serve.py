@@ -137,6 +137,9 @@ def generate_server(
                 entrypoint="python",
                 args=args,
                 num_replicas=num_replicas,
+                # N independent servers, not one gang: a replica restarts
+                # alone, and no multi-slice identity is injected
+                retry_policy=specs.RetryPolicy.REPLICA,
                 port_map={"http": port},
                 resource=resource,
             )
@@ -247,6 +250,7 @@ def generate_server_disagg(
                 entrypoint="python",
                 args=_role_args("prefill", prefill_port),
                 num_replicas=prefill_replicas,
+                retry_policy=specs.RetryPolicy.REPLICA,
                 port_map={"http": prefill_port},
                 resource=resource,
                 metadata={ROLE_METADATA_KEY: spec},
@@ -257,6 +261,7 @@ def generate_server_disagg(
                 entrypoint="python",
                 args=_role_args("decode", decode_port),
                 num_replicas=decode_replicas,
+                retry_policy=specs.RetryPolicy.REPLICA,
                 port_map={"http": decode_port},
                 resource=resource,
                 metadata={ROLE_METADATA_KEY: spec},

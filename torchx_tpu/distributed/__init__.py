@@ -66,17 +66,6 @@ def coordinator_address(port: Optional[int] = None) -> str:
     return f"{host}:{port or settings.TPX_COORDINATOR_PORT}"
 
 
-def _jax_distributed_initialized() -> bool:
-    import jax
-
-    try:
-        return jax.distributed.is_initialized()
-    except AttributeError:  # older jax
-        from jax._src import distributed as _dist
-
-        return getattr(_dist.global_state, "client", None) is not None
-
-
 def init_from_env(port: Optional[int] = None) -> None:
     """Initialize jax.distributed from the launcher-injected env. Safe to
     call multiple times, outside a tpx job (no-op for single process), and
@@ -92,7 +81,7 @@ def init_from_env(port: Optional[int] = None) -> None:
     if n > 1:
         import jax
 
-        if not _jax_distributed_initialized():
+        if not jax.distributed.is_initialized():
             jax.distributed.initialize(
                 coordinator_address=f"{host}:{port or settings.TPX_COORDINATOR_PORT}",
                 num_processes=n,

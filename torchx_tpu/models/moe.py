@@ -171,7 +171,7 @@ def moe_ffn(
     * overflow: fraction of (token, choice) routings dropped because
       their expert's capacity buffer was full.
 
-    The trainer surfaces all three at log points (docs/ROADMAP.md #12)."""
+    The trainer surfaces all three at log points."""
     b, s, d = x.shape
     E, k = cfg.n_experts, cfg.top_k
     capacity = max(1, int(cfg.capacity_factor * s * k / E))

@@ -21,6 +21,11 @@ Kernel selection (``impl``):
 
 All paths compute softmax in float32 and accept grouped KV heads
 (n_kv_heads <= n_heads, Llama-3 GQA).
+
+Every call site records what it lowered to in :data:`TRACED`, so a run can
+report the kernel it used rather than the one it asked for. A kernel that
+was selected and then cannot run (a mesh that does not divide the batch or
+the heads) raises; only the static shape gates choose the XLA path.
 """
 
 from __future__ import annotations
@@ -32,11 +37,24 @@ import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
 
+#: op name -> implementations its call sites lowered to in this process
+#: (written at trace time, e.g. ``{"attention": {"splash"}}``).
+TRACED: dict[str, set[str]] = {}
+
+
+def note_traced(op: str, impl: str) -> None:
+    """Record that a call site of ``op`` traced ``impl``."""
+    TRACED.setdefault(op, set()).add(impl)
+
+
+def traced(op: str) -> str:
+    """What ``op`` lowered to so far: ``"splash"``, ``"splash+xla"`` when
+    call sites differed, ``""`` when nothing traced it yet."""
+    return "+".join(sorted(TRACED.get(op, ())))
+
+
 def _on_tpu() -> bool:
-    try:
-        return jax.devices()[0].platform == "tpu"
-    except RuntimeError:
-        return False
+    return jax.default_backend() == "tpu"
 
 
 def _repeat_kv(x: jnp.ndarray, n_rep: int) -> jnp.ndarray:
@@ -228,8 +246,8 @@ def _shard_wrap(kernel, q, k, v, segment_ids, mesh, batch_axes, head_axis):
     stream was sp-sharded), and nothing else moves — no collectives inside;
     fsdp/tp weight collectives stay outside, handled by the partitioner.
 
-    Returns None when the shapes don't divide the mesh (caller falls back
-    to xla attention, which partitions automatically).
+    Raises ValueError when the shapes don't divide the mesh: the kernel
+    was selected, so running anything else would hide what the step uses.
     """
     sizes = dict(mesh.shape)
     if all(s == 1 for s in sizes.values()):
@@ -253,7 +271,11 @@ def _shard_wrap(kernel, q, k, v, segment_ids, mesh, batch_axes, head_axis):
         or q.shape[2] % head_div
         or k.shape[2] % head_div
     ):
-        return None  # shapes don't divide the mesh: xla fallback
+        raise ValueError(
+            f"batch {q.shape[0]} / heads {q.shape[2]} (kv {k.shape[2]}) do not"
+            f" divide the mesh's {batch_axes} / {head_axis!r} axes; Pallas"
+            " kernels need divisible shapes"
+        )
 
     qkv_spec = P(batch_axes or None, None, head_axis, None)
     seg_spec = P(batch_axes or None, None)
@@ -326,18 +348,11 @@ def attention(
                     q, k, v, causal=causal, block_q=block_q, block_kv=block_kv
                 )
 
+        note_traced("attention", "splash" if use_splash else "pallas_flash")
         if mesh is None:
             return kernel(q, k, v, segment_ids)
-        out = _shard_wrap(
+        return _shard_wrap(
             kernel, q, k, v, segment_ids, mesh, ("dp", "fsdp"), "tp"
         )
-        if out is not None:
-            return out
-        if impl != "auto":
-            raise ValueError(
-                f"impl={impl!r}: batch {q.shape[0]} / heads "
-                f"{q.shape[2]} do not divide the mesh's dp*fsdp / tp axes; "
-                "Pallas kernels need divisible shapes (use impl='auto' to "
-                "fall back to xla attention)"
-            )
+    note_traced("attention", "xla")
     return xla_attention(q, k, v, causal=causal, segment_ids=segment_ids)

@@ -75,14 +75,24 @@ def _bwd_kernel(x_ref, dy_ref, w_ref, dx_ref, dw_ref, *, eps: float):
         dw_ref[...] += dw_tile
 
 
-def _pick_rows(n: int, d: int = 2048) -> int:
-    """Largest row-tile that divides ``n`` and fits scoped VMEM (~16M):
-    budget ~32 bytes/element — 3 bf16 io blocks double-buffered plus ~5 f32
-    temporaries (xf/dyf/xhat/dxhat/products) the compiler keeps live."""
+def _pick_rows(n: int, d: int = 2048, bytes_per_elt: int = 22) -> int:
+    """Largest row-tile that divides ``n`` and keeps ``bytes_per_elt`` per
+    block element under 12 MiB of the 16 MiB scoped VMEM. The default is
+    the norm backward's: 3 bf16 io blocks double-buffered (12 B) plus the
+    f32 temporaries live at once (xf/dyf and one product, ~10 B)."""
     for r in (1024, 512, 256, 128, 64, 32, 16, 8):
-        if n % r == 0 and r * d * 22 <= 12 * 1024 * 1024:
+        if n % r == 0 and r * d * bytes_per_elt <= 12 * 1024 * 1024:
             return r
     return 0
+
+
+def _refuse_on_tpu(interpret: bool, why: str) -> None:
+    """A Pallas norm kernel that was asked for and passed the static shape
+    gate is the Mosaic kernel or an error: on the chip a later give-way
+    raises. The interpreter keeps the plain-math fallback the CPU parity
+    tests rely on."""
+    if not interpret:
+        raise ValueError(f"fused norm kernel unavailable: {why}")
 
 
 def _bwd_pallas(x2d, dy2d, weight, eps: float, interpret: bool = False):
@@ -94,6 +104,7 @@ def _bwd_pallas(x2d, dy2d, weight, eps: float, interpret: bool = False):
     if rows == 0 or d % 128:
         # untileable shard (interpret mode bypasses _fused_ok, and the
         # sharded path re-tiles on PER-SHARD rows): plain math, same grads
+        _refuse_on_tpu(interpret, f"{x2d.shape} rows do not tile")
         return _bwd_math(x2d, weight, dy2d, eps)
     dx, dw = pl.pallas_call(
         functools.partial(_bwd_kernel, eps=eps),
@@ -112,27 +123,19 @@ def _bwd_pallas(x2d, dy2d, weight, eps: float, interpret: bool = False):
             jax.ShapeDtypeStruct((1, d), jnp.float32),
         ],
         interpret=interpret,
+        name="tpx_norm_bwd",
     )(x2d, dy2d, weight.reshape(1, d))
     return dx, dw[0]
 
 
 def _fused_ok(x: jnp.ndarray) -> bool:
-    """TPU only, lane-aligned feature dim, tileable row count, and not
-    inside a shard_map manual region (there the plain backward keeps the
-    well-tested semantics — the partitioner handles the skinny dots)."""
-    try:
-        on_tpu = jax.devices()[0].platform == "tpu"
-    except RuntimeError:
-        return False
-    from torchx_tpu.parallel.mesh import manual_axes
-
-    in_manual = bool(manual_axes())
+    """The static gate: TPU only, lane-aligned feature dim, tileable row
+    count."""
     n = 1
     for s in x.shape[:-1]:
         n *= s
     return (
-        on_tpu
-        and not in_manual
+        jax.default_backend() == "tpu"
         and x.ndim >= 2
         and x.shape[-1] % 128 == 0
         and _pick_rows(n, x.shape[-1]) > 0
@@ -203,14 +206,15 @@ def rms_norm(
 
         fused = os.environ.get(ENV_TPX_FUSED_NORM, "never")
     interpret = fused == "interpret"
+    if not (interpret or (fused == "pallas" and _fused_ok(x))):
+        return _rms_norm_fwd_math(x, weight, eps)
     from torchx_tpu.parallel.mesh import manual_axes
 
     if manual_axes():
         # inside a shard_map manual region (a pipeline stage): opening a
         # nested shard_map over the concrete mesh would rebind the
-        # parent's axes (rejected by Shardy) — plain backward, every mode
-        return _rms_norm_fwd_math(x, weight, eps)
-    if not (interpret or (fused == "pallas" and _fused_ok(x))):
+        # parent's axes (rejected by Shardy), so the kernel cannot run
+        _refuse_on_tpu(interpret, "no fused norm inside a pipeline stage")
         return _rms_norm_fwd_math(x, weight, eps)
     if mesh is None or all(s == 1 for s in mesh.shape.values()):
         return _rms_norm_fused(x, weight, eps, interpret)
@@ -228,6 +232,9 @@ def rms_norm(
         else None
     )
     if x.ndim != 3 or (batch_div > 1 and x.shape[0] % batch_div):
+        _refuse_on_tpu(
+            interpret, f"{x.shape} does not divide the mesh's {batch_axes}"
+        )
         return _rms_norm_fwd_math(x, weight, eps)  # unshardable: plain path
     x_spec = P(batch_axes or None, seq_axis, None)
     from torchx_tpu.parallel.mesh import shard_map as tpx_shard_map

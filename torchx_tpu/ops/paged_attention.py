@@ -32,6 +32,8 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
+from torchx_tpu.ops.attention import note_traced
+
 #: Physical block index every unassigned block-table entry points at.
 #: Writes from inactive/padded slots land here; masked attention never
 #: reads it as valid context.
@@ -67,6 +69,7 @@ def paged_attention(
     (trash) block — are masked out of slot ``i``'s softmax. Returns
     ``[slots, h, hd]``.
     """
+    note_traced("attention", "paged_xla")
     slots, h, d = q.shape
     k = gather_kv(k_pool, tables)  # [slots, S, kvh, hd]
     v = gather_kv(v_pool, tables)
@@ -102,6 +105,7 @@ def paged_attention_chunk(
     Padded query rows produce garbage that the caller never samples.
     Returns ``[slots, t, h, hd]``.
     """
+    note_traced("attention", "paged_xla")
     slots, t, h, d = q.shape
     k = gather_kv(k_pool, tables)  # [slots, S, kvh, hd]
     v = gather_kv(v_pool, tables)

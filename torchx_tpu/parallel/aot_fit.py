@@ -258,9 +258,14 @@ def _probe_main() -> int:
     import json
     import sys
 
+    from torchx_tpu.parallel.xla_cache import setup_compilation_cache
+
     requests = json.load(sys.stdin)
     if not isinstance(requests, list):
         raise SystemExit("expected a JSON list of probe requests on stdin")
+    # a candidate that fits is compiled again by its measured trial: the
+    # probe's compile is that trial's cache hit
+    setup_compilation_cache()
     print(json.dumps(probe_fits(requests)), flush=True)
     return 0
 

@@ -35,8 +35,8 @@ __all__ = [
     "make_mesh",
     "named_sharding",
     "shard_map",
-    "enable_shardy_if_supported",
     "manual_axes",
+    "device_info",
     "BATCH_SPEC",
     "ACT_SPEC",
     "ACT_TP_SPEC",
@@ -66,6 +66,17 @@ def named_sharding(mesh: Mesh, *spec) -> NamedSharding:
     return NamedSharding(mesh, P(*spec))
 
 
+def device_info() -> dict:
+    """The device as jax reports it — what every result that names where
+    it ran carries (``platform``, ``device_kind``, ``device_count``)."""
+    d = jax.devices()[0]
+    return {
+        "platform": d.platform,
+        "device_kind": d.device_kind,
+        "device_count": jax.device_count(),
+    }
+
+
 def shard_map(
     f,  # noqa: ANN001
     *,
@@ -75,86 +86,25 @@ def shard_map(
     axis_names: Optional[frozenset] = None,
     check_vma: bool = False,
 ):
-    """``jax.shard_map`` across the JAX versions in play.
-
-    Modern JAX exports ``jax.shard_map`` (``axis_names`` = the manual
-    axes, ``check_vma``); 0.4.x only has the experimental spelling, where
-    partial manualization is the complement (``auto`` = the axes left
-    automatic) and the replication check is ``check_rep``. The inherited-
-    mesh form (``mesh=None`` inside a parent manual region) needs modern
-    JAX — 0.4.x callers never reach it because partial-auto nesting is
-    rejected there anyway.
-    """
-    sm = getattr(jax, "shard_map", None)
-    if sm is not None:
-        kwargs = {} if axis_names is None else {"axis_names": axis_names}
-        return sm(
-            f,
-            mesh=mesh,
-            in_specs=in_specs,
-            out_specs=out_specs,
-            check_vma=check_vma,
-            **kwargs,
-        )
-    from jax.experimental.shard_map import shard_map as legacy_sm
-
-    if mesh is None:
-        raise NotImplementedError(
-            "shard_map with an inherited mesh (mesh=None) requires"
-            " jax.shard_map (jax >= 0.5)"
-        )
-    kwargs = {}
-    if axis_names is not None:
-        auto = frozenset(mesh.axis_names) - frozenset(axis_names)
-        if auto:
-            kwargs["auto"] = auto
-    return legacy_sm(
+    """``jax.shard_map`` with this repo's defaults: ``axis_names`` (the
+    manual axes) optional, ``check_vma`` off, and ``mesh=None`` meaning
+    the mesh inherited from a parent manual region."""
+    kwargs = {} if axis_names is None else {"axis_names": axis_names}
+    return jax.shard_map(
         f,
         mesh=mesh,
         in_specs=in_specs,
         out_specs=out_specs,
-        check_rep=check_vma,
+        check_vma=check_vma,
         **kwargs,
     )
 
 
-def enable_shardy_if_supported() -> bool:
-    """Opt into the Shardy partitioner on JAX versions that can carry it.
-
-    Every sharding construct in this repo (partial-auto ``shard_map``,
-    nested-manual-region rules, the embedding gather constraints) is
-    written against Shardy semantics; compiling through the legacy GSPMD
-    pipeline instead logs a deprecation warning per compile
-    (``sharding_propagation.cc``) and its gather heuristics are the source
-    of the involuntary-full-rematerialization warnings
-    (``spmd_partitioner.cc:652``). Gate on ``jax.shard_map`` existing: the
-    0.4.x stack pairs Shardy with the legacy ``auto=`` shard_map spelling,
-    which miscompiles (PartitionId) — there we stay on GSPMD. Returns
-    whether Shardy is now active; safe to call repeatedly.
-    """
-    if getattr(jax, "shard_map", None) is None:
-        return False
-    try:
-        jax.config.update("jax_use_shardy_partitioner", True)
-        return True
-    except Exception:  # pragma: no cover - option absent on this jax
-        return False
-
-
 def manual_axes() -> frozenset:
-    """Axis names manualized by an enclosing ``shard_map``, across the JAX
-    versions in play: ``jax.sharding.get_abstract_mesh`` where exported,
-    falling back to the ``jax._src.mesh`` spelling (0.4.x — where the
-    no-mesh sentinel is a bare tuple rather than an AbstractMesh carrying
-    ``.empty``/``.manual_axes``). Empty set = not inside a manual region."""
-    get = getattr(jax.sharding, "get_abstract_mesh", None)
-    if get is None:
-        try:
-            from jax._src.mesh import get_abstract_mesh as get
-        except ImportError:  # pragma: no cover - very old jax
-            return frozenset()
-    ctx = get()
-    if not hasattr(ctx, "manual_axes") or getattr(ctx, "empty", False):
+    """Axis names manualized by an enclosing ``shard_map`` (empty set =
+    not inside a manual region)."""
+    ctx = jax.sharding.get_abstract_mesh()
+    if ctx.empty:
         return frozenset()
     return frozenset(ctx.manual_axes)
 

@@ -11,8 +11,9 @@ time behind the rest of the backward pass (TorchTitan's async-TP result,
 
 Two execution modes, one semantics:
 
-* **gspmd** (the jax 0.4.x-safe default inside the jit train step) —
-  per-bucket :func:`jax.lax.optimization_barrier`. The barrier is a
+* **gspmd** (the default inside the jit train step, where the
+  partitioner owns the reduces) — per-bucket
+  :func:`jax.lax.optimization_barrier`. The barrier is a
   value-identity, so gradients are **bitwise identical** to the unbucketed
   step; what changes is scheduling: XLA can no longer fuse the per-leaf
   reduces into one giant post-backward collective, and its
@@ -223,24 +224,6 @@ def resolve_bucket_mb(
     return chosen, tuple(trials)
 
 
-def _axis_bound(name: str) -> bool:
-    """Is ``name`` a usable collective axis here? Modern JAX exposes the
-    enclosing manual region via the abstract mesh
-    (:func:`torchx_tpu.parallel.mesh.manual_axes`); the 0.4.x tracer
-    never populates that inside the legacy shard_map, but its axis env
-    does know every bound axis name."""
-    from torchx_tpu.parallel.mesh import manual_axes
-
-    if name in manual_axes():
-        return True
-    try:
-        from jax._src.core import get_axis_env
-
-        return bool(get_axis_env().axis_exists(name))
-    except Exception:  # pragma: no cover - core API drift
-        return False
-
-
 def _apply_bucketed(leaves: list, plan: BucketPlan, combine) -> list:
     """Shared walk: run ``combine(tuple_of_values, anchor)`` per bucket in
     plan order, threading an anchor value so bucket i+1 cannot issue
@@ -309,9 +292,8 @@ def bucketed_sync(
     (exactly today's single-sync step). ``mode``:
 
     * ``"auto"`` — ``"manual"`` inside a shard_map region that has the
-      reduce axis bound manually, else ``"gspmd"``. The jit train step on
-      jax 0.4.x lands on gspmd: the GSPMD-safe fallback that preserves
-      single-sync semantics bit for bit.
+      reduce axis bound manually, else ``"gspmd"`` (the jit train step,
+      which preserves single-sync semantics bit for bit).
     * ``"gspmd"`` — :func:`apply_bucketed_barriers` (no collectives of
       its own; the partitioner owns the reduces).
     * ``"manual"`` — :func:`bucketed_psum` over ``axis_name``.
@@ -323,12 +305,12 @@ def bucketed_sync(
     if plan is None:
         plan = plan_buckets(grads, int(bucket_mb) * _MIB)
     if mode == "auto":
+        from torchx_tpu.parallel.mesh import manual_axes
+
+        # a collective axis is usable here iff an enclosing shard_map
+        # manualized it
         names = (axis_name,) if isinstance(axis_name, str) else tuple(axis_name)
-        mode = (
-            "manual"
-            if names and all(_axis_bound(n) for n in names)
-            else "gspmd"
-        )
+        mode = "manual" if names and set(names) <= manual_axes() else "gspmd"
     if mode == "manual":
         return bucketed_psum(grads, axis_name, plan), plan
     if mode == "gspmd":

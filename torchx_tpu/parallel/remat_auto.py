@@ -53,16 +53,19 @@ class PolicyTrial:
 
 
 def device_hbm_bytes(default: int = V5P_HBM_BYTES) -> int:
-    """Per-device HBM budget: the addressable device's ``bytes_limit``
-    when the runtime reports one (TPU/GPU), else ``default`` (CPU and
-    compile-only backends report nothing useful — there the v5p budget
-    keeps auto-selection meaningful in dryruns)."""
-    try:
-        stats = jax.local_devices()[0].memory_stats()
-    except Exception:
-        return default
+    """Per-device HBM budget: the addressable device's ``bytes_limit``.
+    The CPU backend reports none — there ``default`` (the v5p budget) keeps
+    auto-selection meaningful in dryruns. A TPU that reports none is an
+    error: assuming 95 GiB on a 16 GB chip picks the largest footprint."""
+    device = jax.local_devices()[0]
+    stats = device.memory_stats()
     if stats and stats.get("bytes_limit", 0) > 0:
         return int(stats["bytes_limit"])
+    if device.platform == "tpu":
+        raise RuntimeError(
+            f"{device.device_kind} reports no memory_stats()['bytes_limit'];"
+            " pass hbm_bytes explicitly"
+        )
     return default
 
 

@@ -1,14 +1,17 @@
 """Persistent XLA compilation cache setup.
 
-Compilation dominates launch-to-first-step (the BASELINE north-star): the
-1B-model train step costs ~25 s to compile cold but ~4 s with a warm
-persistent cache (measured on v5e — docs/performance.md). Every relaunch
-— preemption recovery, elastic resize, hyperparameter sweeps over the
-same shapes — hits the cache, so the trainer enables it by default.
+Compilation dominates launch-to-first-step, and every relaunch —
+preemption recovery, elastic resize, a server restart, a sweep over the
+same shapes — recompiles programs an earlier process already built. Every
+entry point that compiles for the device (trainer, server, ``tune``
+measurement, the ``aot_fit`` probe, the benchmarks) calls
+:func:`setup_compilation_cache` once before its first compile.
 
-Set ``TPX_XLA_CACHE_DIR=""`` (empty) to disable, or point it at a shared
-filesystem (e.g. a GCS-fused path) so all hosts of a slice — and future
-jobs — share one cache.
+One knob, jax's own: where ``JAX_COMPILATION_CACHE_DIR`` is set, jax reads
+it itself and this module sets no directory in code. Where it is not set
+the cache goes to one fixed path inside the checkout (``<repo>/.jax_cache``)
+— the directory is part of the cache key, so a path that moves between
+launches (``~`` of another user, a tempdir, a pid) never hits.
 """
 
 from __future__ import annotations
@@ -18,50 +21,33 @@ import os
 
 logger = logging.getLogger(__name__)
 
-ENV_TPX_XLA_CACHE_DIR = "TPX_XLA_CACHE_DIR"
-DEFAULT_CACHE_DIR = "~/.cache/tpx/xla"
+ENV_JAX_COMPILATION_CACHE_DIR = "JAX_COMPILATION_CACHE_DIR"
 
-_configured = False
-_cache_dir_used: str | None = None
+#: the checkout root, resolved from this package's location
+#: (torchx_tpu/parallel/xla_cache.py -> three levels up)
+REPO_ROOT = os.path.dirname(
+    os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+)
+CACHE_DIRNAME = ".jax_cache"
 
 
-def setup_compilation_cache(cache_dir: str | None = None) -> str | None:
-    """Enable the persistent compilation cache (idempotent).
+def setup_compilation_cache() -> str:
+    """Enable the persistent compilation cache; returns the directory.
 
-    Resolution: explicit arg > $TPX_XLA_CACHE_DIR > default under ~/.cache.
-    An empty value disables. Returns the directory in use (or None).
-
-    Variant configs of one model (e.g. the int8 bench leg, a remat-policy
-    sweep) lower to DISTINCT programs, each with its own cache entry — the
-    cache keys on the optimized HLO — so every variant must be allowed to
-    persist: the entry-size floor is zeroed and any compile over 1s
-    qualifies. A variant's first compile is honest cold time; every
-    relaunch after that is a cache hit.
+    Idempotent (re-applying the same config values is a no-op). Variant
+    configs of one model (a remat-policy trial, a prefill bucket) lower to
+    distinct programs, each with its own entry, so every one must be
+    allowed to persist: the entry-size floor is zeroed and any compile over
+    one second qualifies.
     """
-    global _configured, _cache_dir_used
-    if _configured:
-        return _cache_dir_used
     import jax
 
-    if cache_dir is None:
-        cache_dir = os.environ.get(ENV_TPX_XLA_CACHE_DIR, DEFAULT_CACHE_DIR)
+    cache_dir = os.environ.get(ENV_JAX_COMPILATION_CACHE_DIR)
     if not cache_dir:
-        return None
-    cache_dir = os.path.expanduser(cache_dir)
-    try:
+        cache_dir = os.path.join(REPO_ROOT, CACHE_DIRNAME)
         os.makedirs(cache_dir, exist_ok=True)
         jax.config.update("jax_compilation_cache_dir", cache_dir)
-        jax.config.update("jax_persistent_cache_min_compile_time_secs", 1.0)
-        try:
-            # never skip persisting an entry because it is "small": the
-            # medium-sized variant programs are exactly the relaunch wins
-            jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
-        except Exception:  # noqa: BLE001 - knob absent on older jax
-            pass
-        _configured = True
-        _cache_dir_used = cache_dir
-        logger.info("persistent XLA compilation cache at %s", cache_dir)
-        return cache_dir
-    except Exception as e:  # noqa: BLE001 - cache is an optimization only
-        logger.warning("could not enable compilation cache: %s", e)
-        return None
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 1.0)
+    jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
+    logger.info("persistent XLA compilation cache at %s", cache_dir)
+    return cache_dir

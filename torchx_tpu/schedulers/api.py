@@ -21,6 +21,7 @@ from torchx_tpu.specs.api import (
     AppState,
     CfgVal,
     FailureClass,
+    RetryPolicy,
     Role,
     RoleStatus,
     runopts,
@@ -479,7 +480,11 @@ def role_replica_env(
         tpu = role.resource.tpu
         env["TPX_TPU_ACCELERATOR_TYPE"] = tpu.accelerator_type
         env["TPX_TPU_TOPOLOGY"] = tpu.default_topology()
-        if role.num_replicas > 1:  # multi-slice: DCN identity
+        # multi-slice: DCN identity — for a gang only. Replicas that
+        # restart alone (RetryPolicy.REPLICA: stateless services, e.g. N
+        # one-chip servers) are N worlds, and telling libtpu otherwise
+        # makes each wait for the others at backend init.
+        if role.num_replicas > 1 and role.retry_policy != RetryPolicy.REPLICA:
             from torchx_tpu import settings as s
 
             slice_id = replica_id // tpu.hosts

@@ -12,7 +12,11 @@ TPU-first departures:
   sharing one TPU host get ``TPU_VISIBLE_CHIPS`` partitioning; and when the
   role wants TPU but the host has none, ``tpu_simulate=True`` (default) runs
   the replica on CPU JAX with ``xla_force_host_platform_device_count`` equal
-  to the requested per-host chip count — so SPMD apps run anywhere.
+  to the requested per-host chip count — so SPMD apps run anywhere. With
+  ``tpu_simulate=False`` a TPU role on a host without chips is refused at
+  dryrun, and on a host with chips a TPU role always gets
+  ``JAX_PLATFORMS=tpu,cpu`` — whatever the launching shell exported — so it
+  runs on the chip or fails at backend init, never quietly on the CPU.
 * the injected rendezvous env is ``TPX_COORDINATOR_HOST=localhost`` plus the
   gang identity vars consumed by ``torchx_tpu.distributed.init_from_env``
   (the analog of TORCHX_RANK0_HOST at reference :990-993).
@@ -232,8 +236,14 @@ class CWDImageProvider(ImageProvider):
 
 
 def local_tpu_chip_count() -> int:
-    """Count TPU chips attached to this host (accel device nodes)."""
+    """Count TPU chips attached to this host: ``/dev/accel*`` nodes, or one
+    numbered ``/dev/vfio`` group per chip (how a v5e VM exposes them)."""
     return len(glob.glob("/dev/accel*")) or len(glob.glob("/dev/vfio/[0-9]*"))
+
+
+#: libtpu's per-process chip grid for a process that owns ``n`` of the
+#: host's chips (x,y,z — the host grid is at most 2x4).
+_CHIPS_PER_PROCESS_BOUNDS = {1: "1,1,1", 2: "1,2,1", 4: "2,2,1", 8: "2,4,1"}
 
 
 def tpu_device_env(
@@ -244,34 +254,65 @@ def tpu_device_env(
     simulate: bool,
     partition: bool = True,
 ) -> dict[str, str]:
-    """Env partitioning a host's chips among colocated replicas, or CPU
-    simulation when the host has no TPUs (analog of the reference's
-    CUDA_VISIBLE_DEVICES partitioning, local_scheduler.py:855-945).
+    """Env giving each replica the chips its role asked for — the host's
+    chips partitioned among colocated replicas, a one-chip role on a
+    four-chip host held to one chip — or CPU simulation when the host has
+    no TPUs (analog of the reference's CUDA_VISIBLE_DEVICES partitioning,
+    local_scheduler.py:855-945).
 
-    Raises at dryrun time when the gang is over-subscribed (more replicas
-    than chips) — better than a wedged collective at runtime.
+    Raises at dryrun time when the role cannot get what it asked for: no
+    chips and no simulation, or an over-subscribed gang (more replicas
+    than chips) — better than a CPU run under a TPU's name, or a wedged
+    collective at runtime.
     """
     if host_chips <= 0:
         if not simulate:
-            return {}
+            raise ValueError(
+                "role requests a TPU, tpu_simulate=False, and this host has"
+                " no TPU chip (/dev/accel*, /dev/vfio/<n>)"
+            )
         return {
             settings.ENV_JAX_PLATFORMS: "cpu",
             settings.ENV_XLA_FLAGS: (
                 f"--xla_force_host_platform_device_count={role_tpu_chips_per_host}"
             ),
         }
-    if not partition or replicas_on_host <= 1:
-        return {}  # replica sees all host chips
+    # the chip or nothing: an inherited JAX_PLATFORMS=cpu must not win, and
+    # with the platform named a failed TPU init is an error, not a fallback
+    env = {settings.ENV_JAX_PLATFORMS: "tpu,cpu"}
+    if not partition:
+        return env  # replica sees all host chips
     if replicas_on_host > host_chips:
         raise ValueError(
             f"{replicas_on_host} replicas cannot share {host_chips} TPU chips"
             " on this host (at least one chip per replica required);"
             " reduce replicas or disable auto_set_tpu_chips"
         )
-    per = host_chips // replicas_on_host
-    start = (replica_id % replicas_on_host) * per
-    chips = ",".join(str(c) for c in range(start, start + per))
-    return {settings.ENV_TPU_VISIBLE_CHIPS: chips, settings.ENV_TPU_SKIP_MDS_QUERY: "true"}
+    per = min(role_tpu_chips_per_host, host_chips // replicas_on_host)
+    if per == host_chips:
+        return env  # the one replica owns the whole host
+    if per not in _CHIPS_PER_PROCESS_BOUNDS:
+        raise ValueError(
+            f"{host_chips} chips over {replicas_on_host} replicas leaves {per}"
+            f" per process; libtpu takes {sorted(_CHIPS_PER_PROCESS_BOUNDS)}"
+        )
+    local_id = replica_id % replicas_on_host
+    start = local_id * per
+    env.update(
+        {
+            settings.ENV_TPU_VISIBLE_CHIPS: ",".join(
+                str(c) for c in range(start, start + per)
+            ),
+            settings.ENV_TPU_SKIP_MDS_QUERY: "true",
+            # each process is a world of its own over its chips: without
+            # these libtpu sizes the process to the host's whole chip grid
+            # and the second process to start fails to open the devices
+            settings.ENV_TPU_CHIPS_PER_PROCESS_BOUNDS: _CHIPS_PER_PROCESS_BOUNDS[per],
+            settings.ENV_TPU_PROCESS_BOUNDS: "1,1,1",
+            "TPU_PROCESS_PORT": str(8476 + local_id),
+        }
+    )
+    return env
 
 
 # =========================================================================

@@ -239,7 +239,10 @@ class ServeEngine:
         self._lock = threading.Lock()
         self._waiting: deque[ServeRequest] = deque()
         self._handoffs: deque[_Handoff] = deque()
-        self._prefilling = 0  # popped from _waiting, not yet slotted/done
+        #: popped from _waiting/_handoffs, not yet slotted or done: drain()
+        #: waits for them, and a step that raises mid-admission must fail
+        #: them too, or their callers wait for ever
+        self._admitting: list[ServeRequest] = []
         self._work = threading.Event()
         self._stop = threading.Event()
         self._draining = False
@@ -248,17 +251,23 @@ class ServeEngine:
         self.tokens_out = 0
         self.steps = 0
         self._steps_since_beat = 0
+        #: why the loop died (a step raised), else None; a dead engine
+        #: refuses work and fails the replica's health check
+        self.failed: Optional[str] = None
 
-        # one compiled decode step for the engine's lifetime; donation lets
-        # XLA update the pools in place (no-op on CPU, where jax warns —
-        # so only donate off-CPU)
-        donate = (3,) if jax.default_backend() != "cpu" else ()
-        params_c, cfg_c = self._params, self._cfg
+        # one compiled decode step for the engine's lifetime. The weights
+        # are an ARGUMENT of every jitted function: closed over, they lower
+        # to constants — at 1B parameters 2.5 GB of literals in each
+        # program's HLO and a private device copy in each executable.
+        # Donation lets XLA update the pools in place (no-op on CPU, where
+        # jax warns — so only donate off-CPU)
+        donate = (4,) if jax.default_backend() != "cpu" else ()
+        cfg_c = self._cfg
 
-        def _decode(tokens, positions, tables, pools, seeds, temps):  # noqa: ANN001
+        def _decode(params, tokens, positions, tables, pools, seeds, temps):  # noqa: ANN001
             keys = _fold_keys(seeds, positions)
             return gen.paged_decode_step(
-                params_c, tokens, positions, tables, pools, cfg_c, keys, temps
+                params, tokens, positions, tables, pools, cfg_c, keys, temps
             )
 
         self._decode = jax.jit(_decode, donate_argnums=donate)
@@ -307,6 +316,8 @@ class ServeEngine:
         if req.max_new_tokens < 1:
             raise ValueError("max_new_tokens must be >= 1")
         with self._lock:
+            if self.failed is not None:
+                raise EngineStopped(self.failed)
             if self._draining or self._stop.is_set():
                 raise EngineStopped("engine is draining; not admitting requests")
             req.t_enqueue = self._clock()
@@ -343,6 +354,8 @@ class ServeEngine:
                 f"({cache_len}+{remaining}) exceeds max_seq {self._cfg.max_seq}"
             )
         with self._lock:
+            if self.failed is not None:
+                raise EngineStopped(self.failed)
             if self._draining or self._stop.is_set():
                 raise EngineStopped("engine is draining; not accepting handoffs")
             if req.t_enqueue == 0.0:
@@ -367,7 +380,7 @@ class ServeEngine:
                 if blocks is None:
                     return worked  # pool pressure; retry next loop pass
                 self._handoffs.popleft()
-                self._prefilling += 1  # visible to drain() until slotted
+                self._admitting = [h.req]  # visible to drain() until slotted
             with obs_trace.span(
                 "serve.kv_import", blocks=len(blocks), cache_len=h.cache_len
             ):
@@ -393,7 +406,7 @@ class ServeEngine:
                 admit_seq=next(self._admit_counter),
             )
             with self._lock:
-                self._prefilling -= 1
+                self._admitting = []
             self._update_gauges()
             worked = True
 
@@ -438,6 +451,7 @@ class ServeEngine:
                 "tokens_out": self.tokens_out,
                 "steps": self.steps,
                 "draining": self._draining,
+                "failed": self.failed,
             }
         if self.prefix_cache is not None:
             out["prefix_cache"] = self.prefix_cache.stats()
@@ -467,7 +481,7 @@ class ServeEngine:
                 empty = (
                     not self._waiting
                     and not self._handoffs
-                    and self._prefilling == 0
+                    and not self._admitting
                     and all(s is None for s in self._slots)
                 )
             if empty:
@@ -494,7 +508,10 @@ class ServeEngine:
                 worked = self._decode_once() or worked
             except Exception as e:  # noqa: BLE001 — a step bug must not hang callers
                 logger.exception("serve engine step failed")
-                self._fail_all(f"engine step failed: {e}")
+                msg = f"engine step failed: {type(e).__name__}: {e}"
+                with self._lock:
+                    self.failed = msg  # before failing waiters: no re-queue race
+                self._fail_all(msg)
                 return
             if not worked:
                 self._work.wait(0.002)
@@ -504,9 +521,10 @@ class ServeEngine:
         with self._lock:
             pending = list(self._waiting)
             pending.extend(h.req for h in self._handoffs)
+            pending.extend(self._admitting)
             self._waiting.clear()
             self._handoffs.clear()
-            self._prefilling = 0
+            self._admitting = []
         for i, st in enumerate(self._slots):
             if st is not None:
                 self._slots[i] = None
@@ -523,16 +541,16 @@ class ServeEngine:
     def _prefill_fn(self, rows: int, width: int) -> Callable:
         fn = self._prefill_fns.get((rows, width))
         if fn is None:
-            donate = (4,) if jax.default_backend() != "cpu" else ()
-            params_c, cfg_c = self._params, self._cfg
+            donate = (5,) if jax.default_backend() != "cpu" else ()
+            cfg_c = self._cfg
 
-            def _prefill(tokens, prefix_lens, suffix_lens, tables, pools, seeds, temps):  # noqa: ANN001
+            def _prefill(params, tokens, prefix_lens, suffix_lens, tables, pools, seeds, temps):  # noqa: ANN001
                 # sampling key is a function of the *absolute* position of
                 # the last prompt token, so a cache-hit suffix prefill
                 # draws the same first token a cold prefill would
                 keys = _fold_keys(seeds, prefix_lens + suffix_lens - 1)
                 return gen.paged_prefill_chunk(
-                    params_c,
+                    params,
                     tokens,
                     prefix_lens,
                     suffix_lens,
@@ -603,7 +621,7 @@ class ServeEngine:
             for a in admitted:
                 self._waiting.remove(a.req)
             # visible to drain(): popped but not yet in a slot/completed
-            self._prefilling += len(admitted)
+            self._admitting = [a.req for a in admitted]
             obs_metrics.SERVE_QUEUE_DEPTH.set(len(self._waiting))
         if not admitted:
             return False
@@ -635,6 +653,7 @@ class ServeEngine:
         ):
             fn = self._prefill_fn(rows, width)
             first, self.pools = fn(
+                self._params,
                 jnp.asarray(tokens),
                 jnp.asarray(prefix_lens),
                 jnp.asarray(suffix_lens),
@@ -683,7 +702,7 @@ class ServeEngine:
                 admit_seq=next(self._admit_counter),
             )
         with self._lock:
-            self._prefilling -= len(admitted)
+            self._admitting = []
         self._update_gauges()
         return True
 
@@ -800,6 +819,7 @@ class ServeEngine:
             return False
 
         nxt, self.pools = self._decode(
+            self._params,
             jnp.asarray(tokens),
             jnp.asarray(positions),
             jnp.asarray(self.tables.tables),

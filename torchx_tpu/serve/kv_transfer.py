@@ -22,7 +22,7 @@ Three transports cover the current deployment shapes:
 A decode replica that is draining answers 503 / ``rejected`` — the
 sender raises :class:`TransferRejected` and the prefill side **requeues
 the handoff to the next decode target instead of dropping it** (the
-disaggregated twin of the engine's ``_prefilling`` drain accounting).
+disaggregated twin of the engine's ``_admitting`` drain accounting).
 
 The transfer *configuration* — ``TransferConfig``, serialized as a spec
 string in role args (``--kv-transfer``) and AppDef role metadata

@@ -45,11 +45,18 @@ def tune_dir() -> str:
 def generation_key(name: str) -> str:
     """Normalize an accelerator string to a calibration key.
 
-    ``"TPU v5e"`` / ``"v5litepod-8"`` / ``"v5e"`` -> ``"v5e"``; anything
-    without a recognizable generation (CPU sim, empty) -> ``"cpu-sim"``.
+    ``"TPU v5 lite"`` (the ``device_kind`` a v5e reports) / ``"TPU v5e"`` /
+    ``"v5litepod-8"`` / ``"v5e"`` -> ``"v5e"``; ``"TPU v5p"`` -> ``"v5p"``;
+    ``"TPU7x"`` -> ``"v7x"``; anything without a recognizable generation
+    (CPU sim, empty) -> ``"cpu-sim"``.
     """
-    m = re.search(r"v\d+[a-z]*", str(name).lower())
-    return m.group(0) if m else "cpu-sim"
+    m = re.search(r"(?:v|tpu)\s*(\d+)\s*([a-z]*)", str(name).lower())
+    if m is None:
+        return "cpu-sim"
+    gen, suffix = m.groups()
+    if suffix in ("lite", "litepod"):
+        suffix = "e"
+    return f"v{gen}{suffix}"
 
 
 @dataclasses.dataclass

@@ -123,6 +123,18 @@ class TuneResult:
         }
 
 
+def _assert_chip_free(child: str) -> None:
+    """The AOT probe and the measured trials need the chip, and a chip
+    belongs to one process at a time: a driver process that has imported
+    jax may already hold it, and the child would then fail or hang."""
+    if "jax" in sys.modules:
+        raise TuneError(
+            f"the tune driver's process imported jax before starting its"
+            f" {child} child; run `tpx tune` (or run_tune with children) from"
+            " a process that stays off jax"
+        )
+
+
 def _last_json(stdout: str, prefix: str = "") -> Optional[Any]:
     """The last parseable JSON line of a subprocess's stdout (the jax
     runtime chats on stdout/stderr around the payload)."""
@@ -320,6 +332,8 @@ def run_tune(
                 }
                 for c, plan, _cost in probe
             ]
+            if aot_cmd is None:
+                _assert_chip_free("aot_fit")
             cmd = aot_cmd or [
                 sys.executable,
                 "-m",
@@ -412,6 +426,8 @@ def run_tune(
                 "steps": space.measure_steps,
                 "data_path": data_path,
             }
+            if measure_cmd is None:
+                _assert_chip_free("measure")
             cmd = measure_cmd or [
                 sys.executable,
                 "-m",

@@ -55,9 +55,20 @@ PRESSURE_KNEE = 0.85
 
 
 def perf_for(generation: str) -> GenerationPerf:
+    """Roofline constants for an accelerator string. Only a string with no
+    TPU generation in it (CPU sim, empty) gets the CPU-sim default; a TPU
+    generation missing from :data:`GENERATION_PERF` is an error."""
     from torchx_tpu.tune.calibrate import generation_key
 
-    return GENERATION_PERF.get(generation_key(generation), _DEFAULT_PERF)
+    key = generation_key(generation)
+    if key == "cpu-sim":
+        return _DEFAULT_PERF
+    if key not in GENERATION_PERF:
+        raise ValueError(
+            f"no roofline constants for {generation!r} (key {key!r});"
+            f" have {sorted(GENERATION_PERF)}"
+        )
+    return GENERATION_PERF[key]
 
 
 @dataclasses.dataclass(frozen=True)
