@@ -38,6 +38,7 @@ MODULES = [
     ("torchx_tpu.obs.telemetry", "obs_telemetry", "fleet telemetry plane: /metricz scraping, ring-buffer series store, query reducers"),
     ("torchx_tpu.obs.slo", "obs_slo", "declarative SLOs evaluated as multi-window burn rates + journaled alerts"),
     ("torchx_tpu.obs.stitch", "obs_stitch", "cross-process trace stitching (`tpx trace --stitch`)"),
+    ("torchx_tpu.obs.hot", "obs_hot", "hot-path plane: the loops' spans on the profiler's clock and the programs' device scopes, by name"),
     ("torchx_tpu.obs.profile", "obs_profile", "per-step phase attribution: MFU/roofline accounting + measured-overlap calibration feedback (`tpx profile`)"),
     ("torchx_tpu.supervisor", "supervisor", "preemption-aware auto-resubmit with retry budgets + checkpoint resume"),
     ("torchx_tpu.supervisor.gang", "gang", "gang health: hang/straggler/partial-loss detection from heartbeats + leases"),

@@ -1085,12 +1085,28 @@ def main(argv: Optional[list[str]] = None) -> None:
         help="listen on port + stride * TPX_REPLICA_ID, so a serve pool's"
         " replicas co-located by the local scheduler get distinct ports",
     )
+    parser.add_argument(
+        "--profiler-port",
+        type=int,
+        default=0,
+        help="start jax.profiler's server on this port (0 = off; strides"
+        " like --port), so the standard tools can capture the live replica:"
+        " device operations by scope and the engine loop's serve.* spans on"
+        " one clock",
+    )
     args = parser.parse_args(argv)
-    if args.port_stride and args.port:
+    if args.port_stride and (args.port or args.profiler_port):
         from torchx_tpu.settings import ENV_TPX_REPLICA_ID
 
         replica_id = int(os.environ.get(ENV_TPX_REPLICA_ID, "0") or "0")
-        args.port += args.port_stride * replica_id
+        if args.port:
+            args.port += args.port_stride * replica_id
+        if args.profiler_port:
+            args.profiler_port += args.port_stride * replica_id
+    if args.profiler_port:
+        import jax
+
+        jax.profiler.start_server(args.profiler_port)
     from torchx_tpu.parallel.xla_cache import setup_compilation_cache
 
     # the decode step and every prefill bucket relaunch from the cache

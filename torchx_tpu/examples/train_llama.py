@@ -29,6 +29,7 @@ import optax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from torchx_tpu.models import llama
+from torchx_tpu.obs import hot
 from torchx_tpu.parallel.mesh import (
     BATCH_SPEC,
     MeshConfig,
@@ -85,9 +86,25 @@ def make_optimizer(
         end_value=lr * 0.1,
     )
     return optax.chain(
-        optax.clip_by_global_norm(1.0),
-        optax.adamw(schedule, b1=0.9, b2=0.95, weight_decay=weight_decay),
+        _scoped(hot.GRAD_CLIP, optax.clip_by_global_norm(1.0)),
+        _scoped(
+            hot.OPTIMIZER,
+            optax.adamw(schedule, b1=0.9, b2=0.95, weight_decay=weight_decay),
+        ),
     )
+
+
+def _scoped(
+    name: str, tx: optax.GradientTransformation
+) -> optax.GradientTransformation:
+    """``tx`` with its update's operations named ``name`` in the compiled
+    step (the state keeps ``tx``'s own structure, so checkpoints still fit)."""
+
+    def update(updates, state, params=None):  # noqa: ANN001
+        with jax.named_scope(name):
+            return tx.update(updates, state, params)
+
+    return optax.GradientTransformation(tx.init, update)
 
 
 @dataclasses.dataclass
@@ -190,7 +207,8 @@ def make_train_step(
         updates, opt_state = optimizer.update(
             grads, state.opt_state, state.params
         )
-        params = optax.apply_updates(state.params, updates)
+        with jax.named_scope(hot.OPTIMIZER):
+            params = optax.apply_updates(state.params, updates)
         return (
             TrainState(params=params, opt_state=opt_state, step=state.step + 1),
             loss,
@@ -827,83 +845,85 @@ def train(
     preempted = False
     try:
         for i in range(timed_steps):
-            if profiler is not None:
-                # the phase boundary is host-visible only behind a
-                # completion fence, so profiled steps serialize dispatch
-                # (a measured, documented perturbation — the headline
-                # bench legs run unprofiled)
-                profiler.begin_step()
-                b = next_batch()
-                with profiler.phase("forward_backward"):
-                    state, loss, aux = step_fn(state, b)
-                    jax.block_until_ready(loss)
-            else:
-                state, loss, aux = step_fn(state, next_batch())
-            global_step += 1
-            window_steps += 1
-            if ckpt is not None and global_step % ckpt_every == 0:
-                with _prof_phase("checkpoint"):
-                    ckpt.save(global_step, state)
-            if preempt_evt is not None and preempt_evt.is_set():
-                preempted = True
-                jax.block_until_ready(state.params)
-                if ckpt is not None:
-                    ckpt.save(global_step, state, force=True)
-                    ckpt.wait()  # durable BEFORE the hard kill lands
-                if jax.process_index() == 0:
-                    print(
-                        f"preemption notice: checkpointed step {global_step},"
-                        " exiting",
-                        flush=True,
-                    )
-                break
-            if (i + 1) % log_every == 0 or i + 1 == timed_steps:
-                with _prof_phase("host"):
-                    jax.block_until_ready(loss)  # completion fence: timing only
-                    now = time.monotonic()
-                    dt = (now - t0) / (i + 1)
-                    tps = tokens_per_step / dt
-                    window_dt = (now - window_t0) / window_steps
-                    window_mfu = (
-                        tokens_per_step / window_dt * flops_per_token / peak
-                    )
-                    wait_now = _batches.data_wait_s
-                    wait_per_step = (wait_now - window_wait) / window_steps
-                    window_wait = wait_now
-                    obs_metrics.STEP_SECONDS.observe(window_dt, phase="total")
-                    obs_metrics.STEP_SECONDS.observe(
-                        wait_per_step, phase="data_wait"
-                    )
-                    _step_heartbeat(
-                        step=global_step,
-                        avg_step_s=round(window_dt, 6),
-                        data_wait_s=round(wait_per_step, 6),
-                        mfu=round(window_mfu, 4),
-                        remat_policy=remat_policy_used,
-                    )
-                    # Logging must not stall the device: a synchronous
-                    # float(loss) here is a full device->host round trip
-                    # that lands INSIDE the next timed window. Instead
-                    # start an async copy and print the PREVIOUS window's
-                    # entry, so the transfer overlaps the next window's
-                    # compute.
-                    for arr in (loss, aux):
-                        copy_async = getattr(arr, "copy_to_host_async", None)
-                        if copy_async is not None:
-                            copy_async()
-                    if pending is not None and jax.process_index() == 0:
-                        _emit_log(pending)
-                    pending = {
-                        "step": global_step,
-                        "loss": loss,
-                        "aux": aux,
-                        "tps": tps,
-                        "mfu": tps * flops_per_token / peak,
-                        "window_mfu": window_mfu,
-                    }
-                    window_t0, window_steps = time.monotonic(), 0
-            if profiler is not None:
-                profiler.end_step(global_step)
+            with hot.step_span(global_step + 1):
+                if profiler is not None:
+                    # the phase boundary is host-visible only behind a
+                    # completion fence, so profiled steps serialize dispatch
+                    # (a measured, documented perturbation — the headline
+                    # bench legs run unprofiled)
+                    profiler.begin_step()
+                    b = next_batch()
+                    with profiler.phase("forward_backward"):
+                        state, loss, aux = step_fn(state, b)
+                        jax.block_until_ready(loss)
+                else:
+                    state, loss, aux = step_fn(state, next_batch())
+                global_step += 1
+                window_steps += 1
+                if ckpt is not None and global_step % ckpt_every == 0:
+                    with _prof_phase("checkpoint"), hot.span(hot.TRAIN_CHECKPOINT):
+                        ckpt.save(global_step, state)
+                if preempt_evt is not None and preempt_evt.is_set():
+                    preempted = True
+                    jax.block_until_ready(state.params)
+                    if ckpt is not None:
+                        ckpt.save(global_step, state, force=True)
+                        ckpt.wait()  # durable BEFORE the hard kill lands
+                    if jax.process_index() == 0:
+                        print(
+                            f"preemption notice: checkpointed step {global_step},"
+                            " exiting",
+                            flush=True,
+                        )
+                    break
+                if (i + 1) % log_every == 0 or i + 1 == timed_steps:
+                    with _prof_phase("host"), hot.span(hot.TRAIN_LOG):
+                        with hot.span(hot.TRAIN_FENCE):
+                            jax.block_until_ready(loss)  # completion fence: timing only
+                        now = time.monotonic()
+                        dt = (now - t0) / (i + 1)
+                        tps = tokens_per_step / dt
+                        window_dt = (now - window_t0) / window_steps
+                        window_mfu = (
+                            tokens_per_step / window_dt * flops_per_token / peak
+                        )
+                        wait_now = _batches.data_wait_s
+                        wait_per_step = (wait_now - window_wait) / window_steps
+                        window_wait = wait_now
+                        obs_metrics.STEP_SECONDS.observe(window_dt, phase="total")
+                        obs_metrics.STEP_SECONDS.observe(
+                            wait_per_step, phase="data_wait"
+                        )
+                        _step_heartbeat(
+                            step=global_step,
+                            avg_step_s=round(window_dt, 6),
+                            data_wait_s=round(wait_per_step, 6),
+                            mfu=round(window_mfu, 4),
+                            remat_policy=remat_policy_used,
+                        )
+                        # Logging must not stall the device: a synchronous
+                        # float(loss) here is a full device->host round trip
+                        # that lands INSIDE the next timed window. Instead
+                        # start an async copy and print the PREVIOUS window's
+                        # entry, so the transfer overlaps the next window's
+                        # compute.
+                        for arr in (loss, aux):
+                            copy_async = getattr(arr, "copy_to_host_async", None)
+                            if copy_async is not None:
+                                copy_async()
+                        if pending is not None and jax.process_index() == 0:
+                            _emit_log(pending)
+                        pending = {
+                            "step": global_step,
+                            "loss": loss,
+                            "aux": aux,
+                            "tps": tps,
+                            "mfu": tps * flops_per_token / peak,
+                            "window_mfu": window_mfu,
+                        }
+                        window_t0, window_steps = time.monotonic(), 0
+                if profiler is not None:
+                    profiler.end_step(global_step)
         jax.block_until_ready(state.params)
         total = time.monotonic() - t0
         data_wait_s = _batches.data_wait_s - wait_anchor

@@ -19,6 +19,7 @@ import jax
 import jax.numpy as jnp
 
 from torchx_tpu.models import llama
+from torchx_tpu.obs import hot
 from torchx_tpu.ops.norms import rms_norm
 from torchx_tpu.ops.paged_attention import (
     append_kv,
@@ -80,15 +81,20 @@ def _layer_step(
 ) -> tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
     b, t, d = x.shape
     h, kvh, hd = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
-    attn_in = rms_norm(x, layer["attn_norm"], cfg.norm_eps)
-    q = apply_rope(mm(attn_in, layer["wq"]).reshape(b, t, h, hd), cos, sin)
-    k = apply_rope(mm(attn_in, layer["wk"]).reshape(b, t, kvh, hd), cos, sin)
-    v = mm(attn_in, layer["wv"]).reshape(b, t, kvh, hd)
-    k_cache = jax.lax.dynamic_update_slice(k_cache, k, (0, start, 0, 0))
-    v_cache = jax.lax.dynamic_update_slice(v_cache, v, (0, start, 0, 0))
-    attn = _cached_attention(q, k_cache, v_cache, q_pos)
-    x = x + mm(attn.reshape(b, t, h * hd), layer["wo"])
-    mlp_in = rms_norm(x, layer["mlp_norm"], cfg.norm_eps)
+    with jax.named_scope(hot.NORM):
+        attn_in = rms_norm(x, layer["attn_norm"], cfg.norm_eps)
+    with jax.named_scope(hot.ATTN):
+        q = apply_rope(mm(attn_in, layer["wq"]).reshape(b, t, h, hd), cos, sin)
+        k = apply_rope(mm(attn_in, layer["wk"]).reshape(b, t, kvh, hd), cos, sin)
+        v = mm(attn_in, layer["wv"]).reshape(b, t, kvh, hd)
+        with jax.named_scope(hot.APPEND_KV):
+            k_cache = jax.lax.dynamic_update_slice(k_cache, k, (0, start, 0, 0))
+            v_cache = jax.lax.dynamic_update_slice(v_cache, v, (0, start, 0, 0))
+        with jax.named_scope(hot.ATTN_KERNEL):
+            attn = _cached_attention(q, k_cache, v_cache, q_pos)
+        x = x + mm(attn.reshape(b, t, h * hd), layer["wo"])
+    with jax.named_scope(hot.NORM):
+        mlp_in = rms_norm(x, layer["mlp_norm"], cfg.norm_eps)
     # the SAME dispatch as the training forward (dense SwiGLU or GShard
     # MoE — static shapes hold at t=1); the balancing aux is training-only
     down, _aux = llama.ffn(cfg, layer, mlp_in)
@@ -107,7 +113,8 @@ def forward_with_cache(
     (t = prompt length) and decode (t = 1)."""
     b, t = tokens.shape
     S = cache["k"].shape[2]
-    x = params["embed"][tokens].astype(cfg.dtype)
+    with jax.named_scope(hot.EMBED):
+        x = params["embed"][tokens].astype(cfg.dtype)
     q_pos = start + jnp.arange(t)
     cos_full, sin_full = rope_frequencies(cfg.head_dim, S, cfg.rope_theta)
     cos = jax.lax.dynamic_slice_in_dim(cos_full, start, t, axis=0)
@@ -119,20 +126,24 @@ def forward_with_cache(
         x, k_c, v_c = _layer_step(cfg, cos, sin, q_pos, x, layer, k_c, v_c, start)
         return x, (k_c, v_c)
 
-    x, (k_new, v_new) = jax.lax.scan(
-        scan_step, x, (params["layers"], cache["k"], cache["v"])
-    )
-    x = rms_norm(x, params["final_norm"], cfg.norm_eps)
-    head = llama.lm_head(params, cfg)
-    if isinstance(head, dict):  # int8-quantized lm_head: keep f32 accum
-        logits = mm(x, head, out_dtype=jnp.float32)
-    else:
-        logits = jnp.einsum(
-            "btd,dv->btv", x, head, preferred_element_type=jnp.float32
+    with jax.named_scope(hot.LAYERS):
+        x, (k_new, v_new) = jax.lax.scan(
+            scan_step, x, (params["layers"], cache["k"], cache["v"])
         )
+    with jax.named_scope(hot.NORM):
+        x = rms_norm(x, params["final_norm"], cfg.norm_eps)
+    with jax.named_scope(hot.LM_HEAD):
+        head = llama.lm_head(params, cfg)
+        if isinstance(head, dict):  # int8-quantized lm_head: keep f32 accum
+            logits = mm(x, head, out_dtype=jnp.float32)
+        else:
+            logits = jnp.einsum(
+                "btd,dv->btv", x, head, preferred_element_type=jnp.float32
+            )
     return logits, {"k": k_new, "v": v_new}
 
 
+@jax.named_scope(hot.SAMPLE)
 def _sample(logits_t: jnp.ndarray, key: jax.Array, temperature: float) -> jnp.ndarray:
     """Greedy at temperature 0, else categorical — the ONE sampling rule
     both the batch and streaming paths use (parity depends on it).
@@ -343,6 +354,7 @@ def _rope_rows(x: jnp.ndarray, cos: jnp.ndarray, sin: jnp.ndarray) -> jnp.ndarra
     return jnp.concatenate((x1 * c - x2 * s, x2 * c + x1 * s), axis=-1).astype(dtype)
 
 
+@jax.named_scope(hot.SAMPLE)
 def _sample_rows(
     logits: jnp.ndarray,  # [rows, vocab]
     keys: jnp.ndarray,  # [rows, 2] per-row PRNG keys
@@ -371,20 +383,24 @@ def _paged_layer_step(
 ) -> tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
     slots = x.shape[0]
     h, kvh, hd = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
-    attn_in = rms_norm(x, layer["attn_norm"], cfg.norm_eps)
-    q = _rope_rows(mm(attn_in, layer["wq"]).reshape(slots, h, hd), cos, sin)
-    k = _rope_rows(mm(attn_in, layer["wk"]).reshape(slots, kvh, hd), cos, sin)
-    v = mm(attn_in, layer["wv"]).reshape(slots, kvh, hd)
-    k_pool = append_kv(k_pool, tables, positions, k)
-    v_pool = append_kv(v_pool, tables, positions, v)
-    attn = paged_attention(q, k_pool, v_pool, tables, positions + 1)
-    x = x + mm(attn.reshape(slots, 1, h * hd), layer["wo"])
-    mlp_in = rms_norm(x, layer["mlp_norm"], cfg.norm_eps)
+    with jax.named_scope(hot.NORM):
+        attn_in = rms_norm(x, layer["attn_norm"], cfg.norm_eps)
+    with jax.named_scope(hot.ATTN):
+        q = _rope_rows(mm(attn_in, layer["wq"]).reshape(slots, h, hd), cos, sin)
+        k = _rope_rows(mm(attn_in, layer["wk"]).reshape(slots, kvh, hd), cos, sin)
+        v = mm(attn_in, layer["wv"]).reshape(slots, kvh, hd)
+        k_pool = append_kv(k_pool, tables, positions, k)
+        v_pool = append_kv(v_pool, tables, positions, v)
+        attn = paged_attention(q, k_pool, v_pool, tables, positions + 1)
+        x = x + mm(attn.reshape(slots, 1, h * hd), layer["wo"])
+    with jax.named_scope(hot.NORM):
+        mlp_in = rms_norm(x, layer["mlp_norm"], cfg.norm_eps)
     down, _aux = llama.ffn(cfg, layer, mlp_in)
     x = x + down
     return x, k_pool, v_pool
 
 
+@jax.named_scope(hot.LM_HEAD)
 def _lm_head_rows(params: llama.Params, x: jnp.ndarray, cfg: llama.LlamaConfig):
     # [rows, d] -> [rows, vocab] f32, same head dispatch as forward_with_cache
     head = llama.lm_head(params, cfg)
@@ -413,7 +429,8 @@ def paged_decode_step(
     Jit with ``donate_argnums`` on ``pools`` so the pool updates in place.
     """
     slots = tokens.shape[0]
-    x = params["embed"][tokens].astype(cfg.dtype)[:, None, :]  # [slots, 1, d]
+    with jax.named_scope(hot.EMBED):
+        x = params["embed"][tokens].astype(cfg.dtype)[:, None, :]  # [slots, 1, d]
     cos_full, sin_full = rope_frequencies(cfg.head_dim, cfg.max_seq, cfg.rope_theta)
     cos, sin = cos_full[positions], sin_full[positions]  # [slots, hd/2]
 
@@ -425,10 +442,12 @@ def paged_decode_step(
         )
         return x, (k_p, v_p)
 
-    x, (k_new, v_new) = jax.lax.scan(
-        scan_step, x, (params["layers"], pools["k"], pools["v"])
-    )
-    x = rms_norm(x, params["final_norm"], cfg.norm_eps)[:, 0, :]  # [slots, d]
+    with jax.named_scope(hot.LAYERS):
+        x, (k_new, v_new) = jax.lax.scan(
+            scan_step, x, (params["layers"], pools["k"], pools["v"])
+        )
+    with jax.named_scope(hot.NORM):
+        x = rms_norm(x, params["final_norm"], cfg.norm_eps)[:, 0, :]  # [slots, d]
     logits = _lm_head_rows(params, x, cfg)
     nxt = _sample_rows(logits, keys, temps)
     return nxt, {"k": k_new, "v": v_new}
@@ -495,15 +514,18 @@ def _paged_chunk_layer_step(
 ) -> tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
     b, t, _ = x.shape
     h, kvh, hd = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
-    attn_in = rms_norm(x, layer["attn_norm"], cfg.norm_eps)
-    q = _rope_chunk(mm(attn_in, layer["wq"]).reshape(b, t, h, hd), cos, sin)
-    k = _rope_chunk(mm(attn_in, layer["wk"]).reshape(b, t, kvh, hd), cos, sin)
-    v = mm(attn_in, layer["wv"]).reshape(b, t, kvh, hd)
-    k_pool = scatter_kv_chunk(k_pool, tables, positions, k, valid)
-    v_pool = scatter_kv_chunk(v_pool, tables, positions, v, valid)
-    attn = paged_attention_chunk(q, k_pool, v_pool, tables, positions)
-    x = x + mm(attn.reshape(b, t, h * hd), layer["wo"])
-    mlp_in = rms_norm(x, layer["mlp_norm"], cfg.norm_eps)
+    with jax.named_scope(hot.NORM):
+        attn_in = rms_norm(x, layer["attn_norm"], cfg.norm_eps)
+    with jax.named_scope(hot.ATTN):
+        q = _rope_chunk(mm(attn_in, layer["wq"]).reshape(b, t, h, hd), cos, sin)
+        k = _rope_chunk(mm(attn_in, layer["wk"]).reshape(b, t, kvh, hd), cos, sin)
+        v = mm(attn_in, layer["wv"]).reshape(b, t, kvh, hd)
+        k_pool = scatter_kv_chunk(k_pool, tables, positions, k, valid)
+        v_pool = scatter_kv_chunk(v_pool, tables, positions, v, valid)
+        attn = paged_attention_chunk(q, k_pool, v_pool, tables, positions)
+        x = x + mm(attn.reshape(b, t, h * hd), layer["wo"])
+    with jax.named_scope(hot.NORM):
+        mlp_in = rms_norm(x, layer["mlp_norm"], cfg.norm_eps)
     down, _aux = llama.ffn(cfg, layer, mlp_in)
     x = x + down
     return x, k_pool, v_pool
@@ -536,7 +558,8 @@ def paged_prefill_chunk(
     -> (first token [b], updated pools).
     """
     b, t = tokens.shape
-    x = params["embed"][tokens].astype(cfg.dtype)  # [b, t, d]
+    with jax.named_scope(hot.EMBED):
+        x = params["embed"][tokens].astype(cfg.dtype)  # [b, t, d]
     cos_full, sin_full = rope_frequencies(cfg.head_dim, cfg.max_seq, cfg.rope_theta)
     positions = prefix_lens[:, None] + jnp.arange(t, dtype=jnp.int32)[None, :]
     pos_safe = jnp.clip(positions, 0, cfg.max_seq - 1)
@@ -551,10 +574,12 @@ def paged_prefill_chunk(
         )
         return x, (k_p, v_p)
 
-    x, (k_new, v_new) = jax.lax.scan(
-        scan_step, x, (params["layers"], pools["k"], pools["v"])
-    )
-    x = rms_norm(x, params["final_norm"], cfg.norm_eps)  # [b, t, d]
+    with jax.named_scope(hot.LAYERS):
+        x, (k_new, v_new) = jax.lax.scan(
+            scan_step, x, (params["layers"], pools["k"], pools["v"])
+        )
+    with jax.named_scope(hot.NORM):
+        x = rms_norm(x, params["final_norm"], cfg.norm_eps)  # [b, t, d]
     last = x[jnp.arange(b), suffix_lens - 1]  # [b, d]
     logits = _lm_head_rows(params, last, cfg)
     return _sample_rows(logits, keys, temps), {"k": k_new, "v": v_new}

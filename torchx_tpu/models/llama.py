@@ -34,6 +34,7 @@ import jax.numpy as jnp
 from jax.ad_checkpoint import checkpoint_name
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
+from torchx_tpu.obs import hot
 from torchx_tpu.parallel import mesh as mesh_lib
 from torchx_tpu.ops.attention import attention
 from torchx_tpu.ops.norms import rms_norm
@@ -342,12 +343,11 @@ def ffn(
         return moe_ffn(cfg, layer, mlp_in)
 
     i8 = cfg.int8_matmuls
-    gate = jax.nn.silu(maybe_matmul(mlp_in, layer["w_gate"], int8_training=i8))
-    up = maybe_matmul(mlp_in, layer["w_up"], int8_training=i8)
-    return (
-        maybe_matmul(gate * up, layer["w_down"], int8_training=i8),
-        jnp.zeros((AUX_LEN,), jnp.float32),  # aux vector: dense = zeros
-    )
+    with jax.named_scope(hot.MLP):
+        gate = jax.nn.silu(maybe_matmul(mlp_in, layer["w_gate"], int8_training=i8))
+        up = maybe_matmul(mlp_in, layer["w_up"], int8_training=i8)
+        down = maybe_matmul(gate * up, layer["w_down"], int8_training=i8)
+    return down, jnp.zeros((AUX_LEN,), jnp.float32)  # aux vector: dense = zeros
 
 
 def _layer(
@@ -374,68 +374,74 @@ def _layer(
     # attention block
     i8 = cfg.int8_matmuls
     i8_attn = i8 and cfg.int8_scope == "all"
-    attn_in = rms_norm(x, layer["attn_norm"], cfg.norm_eps, mesh=mesh)
-    q = maybe_matmul(attn_in, layer["wq"], int8_training=i8_attn).reshape(b, s, h, hd)
-    k = maybe_matmul(attn_in, layer["wk"], int8_training=i8_attn).reshape(b, s, kvh, hd)
-    v = maybe_matmul(attn_in, layer["wv"], int8_training=i8_attn).reshape(b, s, kvh, hd)
-    q = apply_rope(q, cos, sin)
-    k = apply_rope(k, cos, sin)
-    if cfg.use_ring_attention and mesh is not None and mesh.shape.get("sp", 1) > 1:
-        attn_out = ring_attention(q, k, v, mesh)
-    else:
-        attn_out = None
-        if cfg.kernels != "reference":
-            from torchx_tpu.ops.fused import flash_attention as fused_flash
+    with jax.named_scope(hot.NORM):
+        attn_in = rms_norm(x, layer["attn_norm"], cfg.norm_eps, mesh=mesh)
+    with jax.named_scope(hot.ATTN):
+        q = maybe_matmul(attn_in, layer["wq"], int8_training=i8_attn).reshape(b, s, h, hd)
+        k = maybe_matmul(attn_in, layer["wk"], int8_training=i8_attn).reshape(b, s, kvh, hd)
+        v = maybe_matmul(attn_in, layer["wv"], int8_training=i8_attn).reshape(b, s, kvh, hd)
+        q = apply_rope(q, cos, sin)
+        k = apply_rope(k, cos, sin)
+        if cfg.use_ring_attention and mesh is not None and mesh.shape.get("sp", 1) > 1:
+            with jax.named_scope(hot.ATTN_KERNEL):
+                attn_out = ring_attention(q, k, v, mesh)
+        else:
+            attn_out = None
+            if cfg.kernels != "reference":
+                from torchx_tpu.ops.fused import flash_attention as fused_flash
 
-            # None when gating fails (shape/platform/mesh): stock path below
-            attn_out = fused_flash(
-                q,
-                k,
-                v,
-                causal=True,
-                kernels=cfg.kernels,
-                block_q=cfg.attn_block_q,
-                block_kv=cfg.attn_block_kv,
-                mesh=mesh,
-            )
-        if attn_out is None:
-            attn_out = attention(
-                q,
-                k,
-                v,
-                causal=True,
-                impl=cfg.attn_impl,
-                block_q=cfg.attn_block_q,
-                block_kv=cfg.attn_block_kv,
-                mesh=mesh,
-            )
-    # named so remat policies can SAVE the kernel output: the attention
-    # kernels are not dot_generals, so "dots" alone recomputes the whole
-    # flash/splash forward in the backward pass (see "dots_attn")
-    attn_out = checkpoint_name(attn_out, "attn_out")
-    attn_out = maybe_matmul(
-        attn_out.reshape(b, s, h * hd), layer["wo"], int8_training=i8_attn
-    )
+                # None when gating fails (shape/platform/mesh): stock path below
+                with jax.named_scope(hot.ATTN_KERNEL):
+                    attn_out = fused_flash(
+                        q,
+                        k,
+                        v,
+                        causal=True,
+                        kernels=cfg.kernels,
+                        block_q=cfg.attn_block_q,
+                        block_kv=cfg.attn_block_kv,
+                        mesh=mesh,
+                    )
+            if attn_out is None:
+                attn_out = attention(  # names itself attn_kernel
+                    q,
+                    k,
+                    v,
+                    causal=True,
+                    impl=cfg.attn_impl,
+                    block_q=cfg.attn_block_q,
+                    block_kv=cfg.attn_block_kv,
+                    mesh=mesh,
+                )
+        # named so remat policies can SAVE the kernel output: the attention
+        # kernels are not dot_generals, so "dots" alone recomputes the whole
+        # flash/splash forward in the backward pass (see "dots_attn")
+        attn_out = checkpoint_name(attn_out, "attn_out")
+        attn_out = maybe_matmul(
+            attn_out.reshape(b, s, h * hd), layer["wo"], int8_training=i8_attn
+        )
     if cfg.kernels != "reference":
         from torchx_tpu.ops.fused import rms_norm_residual
 
         # fused residual-add + RMSNorm: one VMEM pass yields both the mlp
         # input and the continued stream (degrades internally to the
         # reference op sequence when gating fails — identical values)
-        mlp_in, x = rms_norm_residual(
-            x,
-            attn_out,
-            layer["mlp_norm"],
-            cfg.norm_eps,
-            kernels=cfg.kernels,
-            mesh=mesh,
-        )
+        with jax.named_scope(hot.NORM):
+            mlp_in, x = rms_norm_residual(
+                x,
+                attn_out,
+                layer["mlp_norm"],
+                cfg.norm_eps,
+                kernels=cfg.kernels,
+                mesh=mesh,
+            )
         x = _constraint(x, mesh, ("dp", "fsdp"), "sp", None)
     else:
         x = x + attn_out
         x = _constraint(x, mesh, ("dp", "fsdp"), "sp", None)
         # mlp block: dense SwiGLU, or MoE when the config carries experts
-        mlp_in = rms_norm(x, layer["mlp_norm"], cfg.norm_eps, mesh=mesh)
+        with jax.named_scope(hot.NORM):
+            mlp_in = rms_norm(x, layer["mlp_norm"], cfg.norm_eps, mesh=mesh)
     down, aux = ffn(cfg, layer, mlp_in)
     x = x + down
     return _constraint(x, mesh, ("dp", "fsdp"), "sp", None), aux
@@ -503,8 +509,9 @@ def forward_features(
     sp = mesh.shape.get("sp", 1) if mesh is not None else 1
     seq_spec = "sp" if sp > 1 and tokens.shape[1] % sp == 0 else None
     tokens = _constraint(tokens, mesh, ("dp", "fsdp"), seq_spec)
-    table = _constraint(params["embed"], mesh, None, None)
-    x = _constraint(table[tokens], mesh, ("dp", "fsdp"), seq_spec, None)
+    with jax.named_scope(hot.EMBED):
+        table = _constraint(params["embed"], mesh, None, None)
+        x = _constraint(table[tokens], mesh, ("dp", "fsdp"), seq_spec, None)
     return features_from_embeddings(params, x.astype(cfg.dtype), cfg, mesh)
 
 
@@ -580,7 +587,8 @@ def features_from_embeddings(
             x, aux = body(x, layer_slice)
             return x, aux
 
-        x, aux_per_layer = jax.lax.scan(scan_step, x, params["layers"])
+        with jax.named_scope(hot.LAYERS):
+            x, aux_per_layer = jax.lax.scan(scan_step, x, params["layers"])
         # [L, AUX_LEN] per-layer aux: balance sums over layers (matches
         # the Switch loss), the monitoring stats average
         aux_total = jnp.stack(
@@ -590,7 +598,9 @@ def features_from_embeddings(
                 aux_per_layer[:, AUX_OVERFLOW].mean(),
             ]
         )
-    return rms_norm(x, params["final_norm"], cfg.norm_eps, mesh=mesh), aux_total
+    with jax.named_scope(hot.NORM):
+        x = rms_norm(x, params["final_norm"], cfg.norm_eps, mesh=mesh)
+    return x, aux_total
 
 
 def lm_head(params: Params, cfg: LlamaConfig) -> jnp.ndarray:
@@ -608,9 +618,10 @@ def forward_from_embeddings(
     """-> logits [b, s, vocab] f32 from input embeddings (see
     :func:`features_from_embeddings`)."""
     x, _ = features_from_embeddings(params, embeds, cfg, mesh)
-    logits = jnp.einsum(
-        "bsd,dv->bsv", x, lm_head(params, cfg), preferred_element_type=jnp.float32
-    )
+    with jax.named_scope(hot.LM_HEAD):
+        logits = jnp.einsum(
+            "bsd,dv->bsv", x, lm_head(params, cfg), preferred_element_type=jnp.float32
+        )
     return _constraint(logits, mesh, ("dp", "fsdp"), "sp", "tp")
 
 
@@ -623,9 +634,10 @@ def forward(
     """-> logits [b, s, vocab] float32 (full materialization — use
     :func:`loss_fn` for training, which never builds this tensor)."""
     x, _ = forward_features(params, tokens, cfg, mesh)
-    logits = jnp.einsum(
-        "bsd,dv->bsv", x, lm_head(params, cfg), preferred_element_type=jnp.float32
-    )
+    with jax.named_scope(hot.LM_HEAD):
+        logits = jnp.einsum(
+            "bsd,dv->bsv", x, lm_head(params, cfg), preferred_element_type=jnp.float32
+        )
     # keep the vocab axis tp-sharded: the lm_head einsum produces it that
     # way, and all-gathering [b, s, vocab] f32 logits would cost ~GBs of
     # HBM + ICI per step at 128k vocab (log_softmax is fine sharded)
@@ -655,19 +667,21 @@ def _token_nll(
       Loss trajectories match f32 to 3 decimals at 1B scale; flip
       ``LlamaConfig.ce_f32_logits`` for exact-f32 CE.
     """
-    logits = jnp.einsum(
-        "bcd,dv->bcv",
-        x,
-        head,
-        preferred_element_type=jnp.float32 if f32_logits else None,
-    )
-    # keep the vocab axis tp-sharded (same guard as forward(): never
-    # all-gather [b, *, vocab] logits on a tensor-parallel mesh)
-    logits = _constraint(logits, mesh, ("dp", "fsdp"), None, "tp")
-    lf = logits.astype(jnp.float32)
-    lse = jax.nn.logsumexp(lf, axis=-1)
-    tgt = jnp.take_along_axis(lf, targets[..., None], axis=-1)[..., 0]
-    return lse - tgt
+    with jax.named_scope(hot.LM_HEAD):
+        logits = jnp.einsum(
+            "bcd,dv->bcv",
+            x,
+            head,
+            preferred_element_type=jnp.float32 if f32_logits else None,
+        )
+        # keep the vocab axis tp-sharded (same guard as forward(): never
+        # all-gather [b, *, vocab] logits on a tensor-parallel mesh)
+        logits = _constraint(logits, mesh, ("dp", "fsdp"), None, "tp")
+    with jax.named_scope(hot.LOSS):
+        lf = logits.astype(jnp.float32)
+        lse = jax.nn.logsumexp(lf, axis=-1)
+        tgt = jnp.take_along_axis(lf, targets[..., None], axis=-1)[..., 0]
+        return lse - tgt
 
 
 def loss_fn(

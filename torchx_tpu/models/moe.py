@@ -25,6 +25,7 @@ import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
 from torchx_tpu.models import llama
+from torchx_tpu.obs import hot
 
 
 @dataclasses.dataclass(frozen=True)
@@ -176,53 +177,58 @@ def moe_ffn(
     E, k = cfg.n_experts, cfg.top_k
     capacity = max(1, int(cfg.capacity_factor * s * k / E))
 
-    router_logits = jnp.einsum(
-        "bsd,de->bse", x, layer["w_router"], preferred_element_type=jnp.float32
-    )
-    probs = jax.nn.softmax(router_logits, axis=-1)  # [b, s, E] f32
-    gate_vals, gate_idx = jax.lax.top_k(probs, k)  # [b, s, k]
-    gate_vals = gate_vals / jnp.maximum(
-        gate_vals.sum(axis=-1, keepdims=True), 1e-9
-    )
+    with jax.named_scope(hot.MOE_ROUTER):
+        router_logits = jnp.einsum(
+            "bsd,de->bse", x, layer["w_router"], preferred_element_type=jnp.float32
+        )
+        probs = jax.nn.softmax(router_logits, axis=-1)  # [b, s, E] f32
+        gate_vals, gate_idx = jax.lax.top_k(probs, k)  # [b, s, k]
+        gate_vals = gate_vals / jnp.maximum(
+            gate_vals.sum(axis=-1, keepdims=True), 1e-9
+        )
 
-    # expert one-hot per choice: [b, s, k, E]
-    choice_oh = jax.nn.one_hot(gate_idx, E, dtype=jnp.float32)
-    # position of each (token, choice) in its expert's capacity buffer:
-    # cumsum over the flattened (s, k) token-choice axis, per (b, E)
-    flat = choice_oh.reshape(b, s * k, E)
-    pos = jnp.cumsum(flat, axis=1) - flat  # [b, s*k, E]
-    pos = (pos * flat).sum(-1).reshape(b, s, k).astype(jnp.int32)  # [b, s, k]
-    within = pos < capacity
-    pos_oh = jax.nn.one_hot(pos, capacity, dtype=jnp.float32) * within[..., None]
+    with jax.named_scope(hot.MOE_DISPATCH):
+        # expert one-hot per choice: [b, s, k, E]
+        choice_oh = jax.nn.one_hot(gate_idx, E, dtype=jnp.float32)
+        # position of each (token, choice) in its expert's capacity buffer:
+        # cumsum over the flattened (s, k) token-choice axis, per (b, E)
+        flat = choice_oh.reshape(b, s * k, E)
+        pos = jnp.cumsum(flat, axis=1) - flat  # [b, s*k, E]
+        pos = (pos * flat).sum(-1).reshape(b, s, k).astype(jnp.int32)  # [b, s, k]
+        within = pos < capacity
+        pos_oh = jax.nn.one_hot(pos, capacity, dtype=jnp.float32) * within[..., None]
 
-    # dispatch [b, s, E, C] (0/1) and combine (gate-weighted)
-    dispatch = jnp.einsum("bske,bskc->bsec", choice_oh, pos_oh)
-    combine = jnp.einsum("bske,bskc,bsk->bsec", choice_oh, pos_oh, gate_vals)
+        # dispatch [b, s, E, C] (0/1) and combine (gate-weighted)
+        dispatch = jnp.einsum("bske,bskc->bsec", choice_oh, pos_oh)
+        combine = jnp.einsum("bske,bskc,bsk->bsec", choice_oh, pos_oh, gate_vals)
 
-    # tokens -> expert capacity slots: [b, E, C, d]
-    expert_in = jnp.einsum("bsec,bsd->becd", dispatch.astype(x.dtype), x)
-    # per-expert SwiGLU, expert axis stays leading (sharded over tp)
-    gate = jax.nn.silu(jnp.einsum("becd,edf->becf", expert_in, layer["w_gate"]))
-    up = jnp.einsum("becd,edf->becf", expert_in, layer["w_up"])
-    expert_out = jnp.einsum("becf,efd->becd", gate * up, layer["w_down"])
-    # load-balancing aux: fraction of top-1 routings per expert x mean
-    # router probability per expert (Switch Transformer eq. 4-6)
-    top1_oh = choice_oh[:, :, 0, :]  # [b, s, E]
-    frac_routed = top1_oh.mean(axis=(0, 1))  # [E]
-    mean_prob = probs.mean(axis=(0, 1))  # [E]
-    balance = E * jnp.sum(frac_routed * mean_prob)
-    # router health metrics (monitoring only; stop_gradient keeps them
-    # out of the backward pass)
-    p_safe = jnp.maximum(probs, 1e-9)
-    entropy = jax.lax.stop_gradient(
-        (-(p_safe * jnp.log(p_safe)).sum(-1).mean()) / jnp.log(float(E))
-    )
-    overflow = jax.lax.stop_gradient(1.0 - within.astype(jnp.float32).mean())
-    # order fixed by llama.AUX_BALANCE / AUX_ENTROPY / AUX_OVERFLOW
-    aux = jnp.stack([balance, entropy, overflow])
+        # tokens -> expert capacity slots: [b, E, C, d]
+        expert_in = jnp.einsum("bsec,bsd->becd", dispatch.astype(x.dtype), x)
+    with jax.named_scope(hot.MOE_EXPERTS):
+        # per-expert SwiGLU, expert axis stays leading (sharded over tp)
+        gate = jax.nn.silu(jnp.einsum("becd,edf->becf", expert_in, layer["w_gate"]))
+        up = jnp.einsum("becd,edf->becf", expert_in, layer["w_up"])
+        expert_out = jnp.einsum("becf,efd->becd", gate * up, layer["w_down"])
+    with jax.named_scope(hot.MOE_ROUTER):  # router health, beside the routing itself
+        # load-balancing aux: fraction of top-1 routings per expert x mean
+        # router probability per expert (Switch Transformer eq. 4-6)
+        top1_oh = choice_oh[:, :, 0, :]  # [b, s, E]
+        frac_routed = top1_oh.mean(axis=(0, 1))  # [E]
+        mean_prob = probs.mean(axis=(0, 1))  # [E]
+        balance = E * jnp.sum(frac_routed * mean_prob)
+        # router health metrics (monitoring only; stop_gradient keeps them
+        # out of the backward pass)
+        p_safe = jnp.maximum(probs, 1e-9)
+        entropy = jax.lax.stop_gradient(
+            (-(p_safe * jnp.log(p_safe)).sum(-1).mean()) / jnp.log(float(E))
+        )
+        overflow = jax.lax.stop_gradient(1.0 - within.astype(jnp.float32).mean())
+        # order fixed by llama.AUX_BALANCE / AUX_ENTROPY / AUX_OVERFLOW
+        aux = jnp.stack([balance, entropy, overflow])
 
     # back to tokens, gate-weighted
-    out = jnp.einsum("bsec,becd->bsd", combine.astype(x.dtype), expert_out)
+    with jax.named_scope(hot.MOE_COMBINE):
+        out = jnp.einsum("bsec,becd->bsd", combine.astype(x.dtype), expert_out)
     return out, aux
 
 

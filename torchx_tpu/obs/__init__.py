@@ -35,6 +35,50 @@ The subsystem answers "where did my launch time go" end to end:
   accounting, measured collective overlap, fsync'd ``profile.jsonl``
   journals rendered by ``tpx profile``, and the measured-residual feed
   into the tune calibration table.
+
+**Two planes.** Everything above is the *launcher plane*: spans on the wall
+clock, a JSON record each, durable in ``trace.jsonl``; right for a launch, a
+supervisor attempt or one request's route through the pool, wrong inside a
+loop that turns every 40 ms. The *hot-path plane* is
+:mod:`torchx_tpu.obs.hot` (import it by name; it needs jax and this package
+stays jax-free): the serving engine's loop and the trainer's loop are
+spanned with ``jax.profiler.TraceAnnotation`` and the compiled programs'
+operations are named with ``jax.named_scope``. Those are recorded only while
+a ``jax.profiler`` session runs, into the profiler's own ``.xplane.pb``, on
+the clock of the device's ``XLA Ops`` line; with no session they cost under a
+microsecond and write nothing. There is no switch of ours: the profiler's
+session is the switch.
+
+* host spans, engine thread, one tree per loop turn that did work:
+  ``serve.admit`` (``rows``, ``width``, ``cached_tokens``, ``queue_depth``)
+  over ``serve.admit.plan`` / ``serve.admit.build`` /
+  ``serve.prefill.dispatch`` / ``serve.prefill.fetch`` /
+  ``serve.admit.commit``; ``serve.decode`` (``step``, ``active``) over
+  ``serve.decode.prepare`` / ``.dispatch`` / ``.fetch`` / ``.commit``
+  (``finished``); ``serve.idle``; ``serve.kv_import``. They are per step,
+  not per request: the per-request spans (``serve.generate``,
+  ``serve.route``, ``serve.kv_transfer``) stay on the launcher plane;
+* host spans, training: the profiler's step marker ``train`` around each
+  iteration of ``train()``, ``train.log`` (holding ``train.fence``),
+  ``train.checkpoint``; ``train.data_wait`` and ``train.h2d`` in the
+  prefetcher;
+* device scopes: ``embed``, ``layers`` (the scan over the layer stack: its
+  own time is the slicing and stacking of weights and K/V pools), inside it
+  ``norm``, ``attn`` (holding ``attn_kernel``, or
+  ``append_kv`` and ``paged_attention`` with ``gather_kv`` / ``scores`` /
+  ``values``), ``mlp`` or ``moe_router`` / ``moe_dispatch`` /
+  ``moe_experts`` / ``moe_combine``, ``lm_head``, ``loss``, ``sample``,
+  ``grad_clip``, ``optimizer``. The path is each operation's ``tf_op`` in
+  the trace; autodiff wraps it (``transpose(jvp(attn))``) and
+  ``rematted_computation`` marks recomputation.
+
+To capture them: ``--profile-dir`` on the trainer; on ``generate_server``
+``--profiler-port N`` (off by default) starts ``jax.profiler.start_server``,
+and a live replica is then captured with the standard tools (TensorBoard's
+profile tab, ``xprof``, or ``jax.profiler.ProfileOptions`` with
+``python_tracer_level = 0`` to keep the Python tracer's tax off the loop
+being measured); ``benchmark/run.py --trace 1`` reads them into per-layer
+metrics (``benchmark/lib/host_spans.py``, ``benchmark/lib/scopes.py``).
 """
 
 from torchx_tpu.obs.metrics import (

@@ -1,0 +1,108 @@
+"""Hot-path spans and device scopes: the profiler is the recorder.
+
+The launcher's span model (:mod:`torchx_tpu.obs.trace`) times launches on the
+wall clock and writes JSON; it is the wrong tool inside a loop that turns
+every 40 ms. Spans of the serving engine's loop and of the trainer's loop are
+``jax.profiler.TraceAnnotation`` instead: they cost under a microsecond when
+no profiler session runs and record nothing, and while one runs (the
+trainer's ``--profile-dir``, ``generate_server --profiler-port``, any
+``jax.profiler.start_trace``) they land in the same ``.xplane.pb`` and on the
+same clock as the device's ``XLA Modules`` and ``XLA Ops`` lines, so a device
+idle gap can be named by the host phase that caused it. Operations inside a
+compiled program are named by ``jax.named_scope``: the scope path is each
+operation's ``tf_op`` in the trace. There is no switch, buffer or file here.
+
+Readers and tests import the names below, not strings.
+"""
+
+from __future__ import annotations
+
+from typing import Any
+
+import jax
+
+# -- host spans: serving engine loop (one line of /host:CPU) ----------------
+
+SERVE_ADMIT = "serve.admit"  # rows, width, cached_tokens, queue_depth
+SERVE_ADMIT_PLAN = "serve.admit.plan"
+SERVE_ADMIT_BUILD = "serve.admit.build"
+SERVE_PREFILL_DISPATCH = "serve.prefill.dispatch"
+SERVE_PREFILL_FETCH = "serve.prefill.fetch"
+SERVE_ADMIT_COMMIT = "serve.admit.commit"
+SERVE_DECODE = "serve.decode"  # step, active
+SERVE_DECODE_PREPARE = "serve.decode.prepare"
+SERVE_DECODE_DISPATCH = "serve.decode.dispatch"
+SERVE_DECODE_FETCH = "serve.decode.fetch"
+SERVE_DECODE_COMMIT = "serve.decode.commit"  # finished
+SERVE_IDLE = "serve.idle"
+SERVE_KV_IMPORT = "serve.kv_import"  # blocks, cache_len
+
+#: parent -> the children that tile it, in order
+SERVE_SPAN_TREE = {
+    SERVE_ADMIT: (
+        SERVE_ADMIT_PLAN,
+        SERVE_ADMIT_BUILD,
+        SERVE_PREFILL_DISPATCH,
+        SERVE_PREFILL_FETCH,
+        SERVE_ADMIT_COMMIT,
+    ),
+    SERVE_DECODE: (
+        SERVE_DECODE_PREPARE,
+        SERVE_DECODE_DISPATCH,
+        SERVE_DECODE_FETCH,
+        SERVE_DECODE_COMMIT,
+    ),
+}
+
+# -- host spans: training ----------------------------------------------------
+
+TRAIN_STEP = "train"  # StepTraceAnnotation, step_num
+TRAIN_DATA_WAIT = "train.data_wait"
+TRAIN_H2D = "train.h2d"
+TRAIN_FENCE = "train.fence"
+TRAIN_LOG = "train.log"
+TRAIN_CHECKPOINT = "train.checkpoint"
+
+# -- device scopes: ``with jax.named_scope(hot.ATTN): ...`` ---------------------
+
+LAYERS = "layers"  # the scan over the layer stack; its own time is the slicing and stacking
+ATTN = "attn"  # projections, rope, the kernel call, output projection
+ATTN_KERNEL = "attn_kernel"
+MLP = "mlp"
+NORM = "norm"
+LM_HEAD = "lm_head"
+LOSS = "loss"
+EMBED = "embed"
+MOE_ROUTER = "moe_router"
+MOE_DISPATCH = "moe_dispatch"
+MOE_EXPERTS = "moe_experts"
+MOE_COMBINE = "moe_combine"
+PAGED_ATTENTION = "paged_attention"
+GATHER_KV = "gather_kv"
+SCORES = "scores"
+VALUES = "values"
+APPEND_KV = "append_kv"
+SAMPLE = "sample"
+GRAD_CLIP = "grad_clip"
+OPTIMIZER = "optimizer"
+
+DEVICE_SCOPES = (
+    LAYERS, ATTN, ATTN_KERNEL, MLP, NORM, LM_HEAD, LOSS, EMBED, MOE_ROUTER, MOE_DISPATCH,
+    MOE_EXPERTS, MOE_COMBINE, PAGED_ATTENTION, GATHER_KV, SCORES, VALUES,
+    APPEND_KV, SAMPLE, GRAD_CLIP, OPTIMIZER,
+)  # fmt: skip
+
+
+def span(name: str, **attrs: Any) -> jax.profiler.TraceAnnotation:
+    """A host span named ``name`` on the calling thread's line of the
+    profiler's trace; ``attrs`` become the event's stats. Attributes known
+    only later go through the returned object's ``set_metadata(**attrs)``
+    before the span closes."""
+    return jax.profiler.TraceAnnotation(name, **attrs)
+
+
+def step_span(step_num: int) -> jax.profiler.StepTraceAnnotation:
+    """One training step, as the profiler's step marker (its tools group
+    device work by it)."""
+    return jax.profiler.StepTraceAnnotation(TRAIN_STEP, step_num=step_num)
+

@@ -36,6 +36,8 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
+from torchx_tpu.obs import hot
+
 
 #: op name -> implementations its call sites lowered to in this process
 #: (written at trace time, e.g. ``{"attention": {"splash"}}``).
@@ -300,6 +302,7 @@ def _shard_wrap(kernel, q, k, v, segment_ids, mesh, batch_axes, head_axis):
     return fn(q, k, v, segment_ids)
 
 
+@jax.named_scope(hot.ATTN_KERNEL)
 def attention(
     q: jnp.ndarray,
     k: jnp.ndarray,

@@ -32,6 +32,7 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
+from torchx_tpu.obs import hot
 from torchx_tpu.ops.attention import note_traced
 
 #: Physical block index every unassigned block-table entry points at.
@@ -54,6 +55,7 @@ def gather_kv(pool: jnp.ndarray, tables: jnp.ndarray) -> jnp.ndarray:
     return g.reshape(slots, bpr * bs, kvh, hd)
 
 
+@jax.named_scope(hot.PAGED_ATTENTION)
 def paged_attention(
     q: jnp.ndarray,  # [slots, h, hd] — ONE query token per slot
     k_pool: jnp.ndarray,  # [num_blocks, bs, kvh, hd]
@@ -71,23 +73,27 @@ def paged_attention(
     """
     note_traced("attention", "paged_xla")
     slots, h, d = q.shape
-    k = gather_kv(k_pool, tables)  # [slots, S, kvh, hd]
-    v = gather_kv(v_pool, tables)
-    n_rep = h // k.shape[2]
-    if n_rep > 1:
-        k = jnp.repeat(k, n_rep, axis=2)
-        v = jnp.repeat(v, n_rep, axis=2)
-    logits = (
-        jnp.einsum("shd,sthd->sht", q, k, preferred_element_type=jnp.float32)
-        * d**-0.5
-    )
-    S = k.shape[1]
-    mask = jnp.arange(S)[None, :] < lengths[:, None]  # [slots, S]
-    logits = jnp.where(mask[:, None, :], logits, -1e30)
-    probs = jax.nn.softmax(logits, axis=-1).astype(q.dtype)
-    return jnp.einsum("sht,sthd->shd", probs, v)
+    with jax.named_scope(hot.GATHER_KV):
+        k = gather_kv(k_pool, tables)  # [slots, S, kvh, hd]
+        v = gather_kv(v_pool, tables)
+        n_rep = h // k.shape[2]
+        if n_rep > 1:
+            k = jnp.repeat(k, n_rep, axis=2)
+            v = jnp.repeat(v, n_rep, axis=2)
+    with jax.named_scope(hot.SCORES):
+        logits = (
+            jnp.einsum("shd,sthd->sht", q, k, preferred_element_type=jnp.float32)
+            * d**-0.5
+        )
+        S = k.shape[1]
+        mask = jnp.arange(S)[None, :] < lengths[:, None]  # [slots, S]
+        logits = jnp.where(mask[:, None, :], logits, -1e30)
+        probs = jax.nn.softmax(logits, axis=-1).astype(q.dtype)
+    with jax.named_scope(hot.VALUES):
+        return jnp.einsum("sht,sthd->shd", probs, v)
 
 
+@jax.named_scope(hot.PAGED_ATTENTION)
 def paged_attention_chunk(
     q: jnp.ndarray,  # [slots, t, h, hd] — a chunk of query tokens per slot
     k_pool: jnp.ndarray,  # [num_blocks, bs, kvh, hd]
@@ -107,23 +113,27 @@ def paged_attention_chunk(
     """
     note_traced("attention", "paged_xla")
     slots, t, h, d = q.shape
-    k = gather_kv(k_pool, tables)  # [slots, S, kvh, hd]
-    v = gather_kv(v_pool, tables)
-    n_rep = h // k.shape[2]
-    if n_rep > 1:
-        k = jnp.repeat(k, n_rep, axis=2)
-        v = jnp.repeat(v, n_rep, axis=2)
-    logits = (
-        jnp.einsum("sqhd,skhd->shqk", q, k, preferred_element_type=jnp.float32)
-        * d**-0.5
-    )
-    S = k.shape[1]
-    mask = jnp.arange(S)[None, None, :] <= positions[:, :, None]  # [slots, t, S]
-    logits = jnp.where(mask[:, None, :, :], logits, -1e30)
-    probs = jax.nn.softmax(logits, axis=-1).astype(q.dtype)
-    return jnp.einsum("shqk,skhd->sqhd", probs, v)
+    with jax.named_scope(hot.GATHER_KV):
+        k = gather_kv(k_pool, tables)  # [slots, S, kvh, hd]
+        v = gather_kv(v_pool, tables)
+        n_rep = h // k.shape[2]
+        if n_rep > 1:
+            k = jnp.repeat(k, n_rep, axis=2)
+            v = jnp.repeat(v, n_rep, axis=2)
+    with jax.named_scope(hot.SCORES):
+        logits = (
+            jnp.einsum("sqhd,skhd->shqk", q, k, preferred_element_type=jnp.float32)
+            * d**-0.5
+        )
+        S = k.shape[1]
+        mask = jnp.arange(S)[None, None, :] <= positions[:, :, None]  # [slots, t, S]
+        logits = jnp.where(mask[:, None, :, :], logits, -1e30)
+        probs = jax.nn.softmax(logits, axis=-1).astype(q.dtype)
+    with jax.named_scope(hot.VALUES):
+        return jnp.einsum("shqk,skhd->sqhd", probs, v)
 
 
+@jax.named_scope(hot.APPEND_KV)
 def scatter_kv_chunk(
     pool: jnp.ndarray,  # [num_blocks, bs, kvh, hd]
     tables: jnp.ndarray,  # [slots, blocks_per_slot]
@@ -153,6 +163,7 @@ def scatter_kv_chunk(
     )
 
 
+@jax.named_scope(hot.APPEND_KV)
 def append_kv(
     pool: jnp.ndarray,  # [num_blocks, bs, kvh, hd]
     tables: jnp.ndarray,  # [slots, blocks_per_slot]
@@ -172,6 +183,7 @@ def append_kv(
     return pool.at[block_ids, offsets].set(new, mode="drop")
 
 
+@jax.named_scope(hot.APPEND_KV)
 def write_prefill(
     pool: jnp.ndarray,  # [num_blocks, bs, kvh, hd]
     block_ids: jnp.ndarray,  # [n_bucket_blocks] physical ids (trash-padded)

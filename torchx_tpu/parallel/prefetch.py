@@ -11,7 +11,9 @@ N. The consumer's ``next()`` then usually finds a finished device array
 waiting in the queue — and every microsecond it *does* block is accounted
 in :attr:`Prefetcher.data_wait_s`, so the trainer can report the
 data-wait vs compute split instead of guessing (bench.py surfaces it as
-``data_wait_frac``).
+``data_wait_frac``). Under a ``jax.profiler`` session the blocking ``get``
+is the span ``train.data_wait`` on the consumer's line and each placement
+``train.h2d`` on the producer's (:mod:`torchx_tpu.obs.hot`).
 
 Depth semantics:
 
@@ -40,6 +42,7 @@ import jax
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec
 
+from torchx_tpu.obs import hot
 from torchx_tpu.parallel.mesh import BATCH_SPEC
 
 _DONE = object()  # source exhausted
@@ -107,7 +110,9 @@ class Prefetcher:
     def _produce(self) -> None:
         try:
             for raw in self._source:
-                self._offer(self._place(raw))
+                with hot.span(hot.TRAIN_H2D):
+                    placed = self._place(raw)
+                self._offer(placed)
                 if self._stop.is_set():
                     return
             self._offer(_DONE)
@@ -126,11 +131,13 @@ class Prefetcher:
         try:
             if self._queue is None:  # depth=0 passthrough
                 try:
-                    return self._place(next(self._source))
+                    with hot.span(hot.TRAIN_DATA_WAIT):
+                        return self._place(next(self._source))
                 except StopIteration:
                     self._exhausted = True
                     raise
-            item = self._queue.get()
+            with hot.span(hot.TRAIN_DATA_WAIT):
+                item = self._queue.get()
             if item is _DONE:
                 self._exhausted = True
                 raise StopIteration

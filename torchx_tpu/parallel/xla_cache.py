@@ -18,6 +18,7 @@ from __future__ import annotations
 
 import logging
 import os
+import re
 
 logger = logging.getLogger(__name__)
 
@@ -47,6 +48,19 @@ def setup_compilation_cache() -> str:
         cache_dir = os.path.join(REPO_ROOT, CACHE_DIRNAME)
         os.makedirs(cache_dir, exist_ok=True)
         jax.config.update("jax_compilation_cache_dir", cache_dir)
+    # the scope names of obs/hot.py are metadata of the compiled program,
+    # and the key leaves metadata out by default: a program compiled before
+    # a scope was added or renamed would be loaded back with its old names,
+    # and a profile of it would name its operations wrongly. With metadata
+    # in the key a relaunch of the same code still hits; an edit that moves
+    # traced lines compiles once more.
+    jax.config.update("jax_compilation_cache_include_metadata_in_key", True)
+    # ... and the metadata names source files: without the checkout's own
+    # path in them the key is the same wherever the checkout lies
+    jax.config.update(
+        "jax_hlo_source_file_canonicalization_regex",
+        re.escape(REPO_ROOT + os.sep),
+    )
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 1.0)
     jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
     logger.info("persistent XLA compilation cache at %s", cache_dir)

@@ -41,8 +41,11 @@ This engine keeps a **fixed slot array** decoding continuously:
   sender requeues to another decode target.
 
 Requests carry per-sequence temperature, seed, and EOS, so unrelated
-requests share every device step. The engine emits ``serve.*`` spans /
-heartbeats and ``tpx_serve_*`` metrics through the obs registry.
+requests share every device step. The engine publishes ``tpx_serve_*``
+metrics through the obs registry, and its loop is spanned per step (not per
+request) with the ``serve.*`` names of :mod:`torchx_tpu.obs.hot`: those are
+recorded only while a ``jax.profiler`` session runs, on the device trace's
+clock. With no session the loop thread writes nothing to disk.
 """
 
 from __future__ import annotations
@@ -62,8 +65,8 @@ import numpy as np
 
 from torchx_tpu.models import generate as gen
 from torchx_tpu.models import llama
+from torchx_tpu.obs import hot
 from torchx_tpu.obs import metrics as obs_metrics
-from torchx_tpu.obs import trace as obs_trace
 from torchx_tpu.ops.paged_attention import TRASH_BLOCK
 from torchx_tpu.serve.kv_pool import BlockAllocator, PoolPlan, SlotTables
 from torchx_tpu.serve.kv_transfer import KvPayload, new_request_id
@@ -250,7 +253,6 @@ class ServeEngine:
         self.requests_done = 0
         self.tokens_out = 0
         self.steps = 0
-        self._steps_since_beat = 0
         #: why the loop died (a step raised), else None; a dead engine
         #: refuses work and fails the replica's health check
         self.failed: Optional[str] = None
@@ -381,8 +383,8 @@ class ServeEngine:
                     return worked  # pool pressure; retry next loop pass
                 self._handoffs.popleft()
                 self._admitting = [h.req]  # visible to drain() until slotted
-            with obs_trace.span(
-                "serve.kv_import", blocks=len(blocks), cache_len=h.cache_len
+            with hot.span(
+                hot.SERVE_KV_IMPORT, blocks=len(blocks), cache_len=h.cache_len
             ):
                 idx = jnp.asarray(np.asarray(blocks, np.int32))
                 self.pools = {
@@ -514,7 +516,8 @@ class ServeEngine:
                 self._fail_all(msg)
                 return
             if not worked:
-                self._work.wait(0.002)
+                with hot.span(hot.SERVE_IDLE):
+                    self._work.wait(0.002)
                 self._work.clear()
 
     def _fail_all(self, msg: str) -> None:
@@ -583,14 +586,72 @@ class ServeEngine:
 
     def _admit(self) -> bool:
         free_slots = [i for i, s in enumerate(self._slots) if s is None]
-        if not free_slots:
+        # an unlocked peek: most loop turns find nothing waiting, and those
+        # turns open no span
+        if not free_slots or not self._waiting:
             return False
-        admitted: list[_Admit] = []
-        with self._lock:
-            if not self._waiting:
+        with hot.span(hot.SERVE_ADMIT) as round_span:
+            with hot.span(hot.SERVE_ADMIT_PLAN):
+                admitted, width = self._plan_admission(len(free_slots))
+            if not admitted:
                 return False
-            width: Optional[int] = None
-            limit = min(len(free_slots), self.max_prefill_batch)
+            with hot.span(hot.SERVE_ADMIT_BUILD):
+                rows = _next_pow2(len(admitted))
+                tokens = np.zeros((rows, width), np.int32)
+                prefix_lens = np.zeros((rows,), np.int32)
+                suffix_lens = np.ones((rows,), np.int32)
+                tables_rows = np.full(
+                    (rows, self.blocks_per_slot), TRASH_BLOCK, np.int32
+                )
+                seeds = np.zeros((rows,), np.int32)
+                temps = np.zeros((rows,), np.float32)
+                cached_total = 0
+                for r, a in enumerate(admitted):
+                    blocks = a.cached_blocks + a.new_blocks
+                    sfx = a.toks[a.cached_tokens :]
+                    tokens[r, : len(sfx)] = sfx
+                    prefix_lens[r] = a.cached_tokens
+                    suffix_lens[r] = len(sfx)
+                    tables_rows[r, : len(blocks)] = blocks
+                    seeds[r] = np.int32(np.uint32(a.req.seed & 0xFFFFFFFF))
+                    temps[r] = a.req.temperature
+                    cached_total += a.cached_tokens
+            round_span.set_metadata(
+                rows=len(admitted),
+                width=width,
+                cached_tokens=cached_total,
+                queue_depth=len(self._waiting),
+            )
+
+            with hot.span(hot.SERVE_PREFILL_DISPATCH):
+                fn = self._prefill_fn(rows, width)
+                first, self.pools = fn(
+                    self._params,
+                    jnp.asarray(tokens),
+                    jnp.asarray(prefix_lens),
+                    jnp.asarray(suffix_lens),
+                    jnp.asarray(tables_rows),
+                    self.pools,
+                    jnp.asarray(seeds),
+                    jnp.asarray(temps),
+                )
+            with hot.span(hot.SERVE_PREFILL_FETCH):
+                first = np.asarray(first)
+
+            with hot.span(hot.SERVE_ADMIT_COMMIT):
+                self._commit_admission(admitted, first, free_slots)
+        return True
+
+    def _plan_admission(
+        self, free_slots: int
+    ) -> tuple[list[_Admit], Optional[int]]:
+        """Under the lock: match prefixes, allocate blocks and take the
+        requests of one prefill bucket off the queue.
+        -> (what to prefill, the bucket's width)."""
+        admitted: list[_Admit] = []
+        width: Optional[int] = None
+        with self._lock:
+            limit = min(free_slots, self.max_prefill_batch)
             for req in list(self._waiting):
                 if len(admitted) >= limit:
                     break
@@ -623,47 +684,13 @@ class ServeEngine:
             # visible to drain(): popped but not yet in a slot/completed
             self._admitting = [a.req for a in admitted]
             obs_metrics.SERVE_QUEUE_DEPTH.set(len(self._waiting))
-        if not admitted:
-            return False
+        return admitted, width
 
-        rows = _next_pow2(len(admitted))
-        tokens = np.zeros((rows, width), np.int32)
-        prefix_lens = np.zeros((rows,), np.int32)
-        suffix_lens = np.ones((rows,), np.int32)
-        tables_rows = np.full((rows, self.blocks_per_slot), TRASH_BLOCK, np.int32)
-        seeds = np.zeros((rows,), np.int32)
-        temps = np.zeros((rows,), np.float32)
-        cached_total = 0
-        for r, a in enumerate(admitted):
-            blocks = a.cached_blocks + a.new_blocks
-            sfx = a.toks[a.cached_tokens :]
-            tokens[r, : len(sfx)] = sfx
-            prefix_lens[r] = a.cached_tokens
-            suffix_lens[r] = len(sfx)
-            tables_rows[r, : len(blocks)] = blocks
-            seeds[r] = np.int32(np.uint32(a.req.seed & 0xFFFFFFFF))
-            temps[r] = a.req.temperature
-            cached_total += a.cached_tokens
-
-        with obs_trace.span(
-            "serve.prefill",
-            rows=len(admitted),
-            width=width,
-            cached_tokens=cached_total,
-        ):
-            fn = self._prefill_fn(rows, width)
-            first, self.pools = fn(
-                self._params,
-                jnp.asarray(tokens),
-                jnp.asarray(prefix_lens),
-                jnp.asarray(suffix_lens),
-                jnp.asarray(tables_rows),
-                self.pools,
-                jnp.asarray(seeds),
-                jnp.asarray(temps),
-            )
-            first = np.asarray(first)
-
+    def _commit_admission(
+        self, admitted: list[_Admit], first: np.ndarray, free_slots: list[int]
+    ) -> None:
+        """Hand each prefilled request its first token and a slot (or
+        complete it), index its blocks, publish the gauges."""
         now = self._clock()
         for r, a in enumerate(admitted):
             req = a.req
@@ -704,7 +731,6 @@ class ServeEngine:
         with self._lock:
             self._admitting = []
         self._update_gauges()
-        return True
 
     def _export_handoff(
         self, req: ServeRequest, toks: list[int], blocks: list[int]
@@ -797,69 +823,69 @@ class ServeEngine:
         active = [(i, st) for i, st in enumerate(self._slots) if st is not None]
         if not active:
             return False
-        for slot, st in active:
-            if self._slots[slot] is None:
-                continue  # preempted by an earlier slot's capacity grab
-            self._ensure_capacity(slot, st.cache_len)
+        with hot.span(hot.SERVE_DECODE, step=self.steps) as step_span:
+            with hot.span(hot.SERVE_DECODE_PREPARE):
+                for slot, st in active:
+                    if self._slots[slot] is None:
+                        continue  # preempted by an earlier slot's capacity grab
+                    self._ensure_capacity(slot, st.cache_len)
 
-        tokens = np.zeros((self.max_slots,), np.int32)
-        positions = np.zeros((self.max_slots,), np.int32)
-        seeds = np.zeros((self.max_slots,), np.int32)
-        temps = np.zeros((self.max_slots,), np.float32)
-        stepping: list[tuple[int, _SlotState]] = []
-        for slot, st in enumerate(self._slots):
-            if st is None:
-                continue
-            tokens[slot] = st.last_tok
-            positions[slot] = st.cache_len
-            seeds[slot] = np.int32(np.uint32(st.req.seed & 0xFFFFFFFF))
-            temps[slot] = st.req.temperature
-            stepping.append((slot, st))
-        if not stepping:
-            return False
+                tokens = np.zeros((self.max_slots,), np.int32)
+                positions = np.zeros((self.max_slots,), np.int32)
+                seeds = np.zeros((self.max_slots,), np.int32)
+                temps = np.zeros((self.max_slots,), np.float32)
+                stepping: list[tuple[int, _SlotState]] = []
+                for slot, st in enumerate(self._slots):
+                    if st is None:
+                        continue
+                    tokens[slot] = st.last_tok
+                    positions[slot] = st.cache_len
+                    seeds[slot] = np.int32(np.uint32(st.req.seed & 0xFFFFFFFF))
+                    temps[slot] = st.req.temperature
+                    stepping.append((slot, st))
+            step_span.set_metadata(active=len(stepping))
+            if not stepping:
+                return False
 
-        nxt, self.pools = self._decode(
-            self._params,
-            jnp.asarray(tokens),
-            jnp.asarray(positions),
-            jnp.asarray(self.tables.tables),
-            self.pools,
-            jnp.asarray(seeds),
-            jnp.asarray(temps),
-        )
-        nxt = np.asarray(nxt)
-        self.steps += 1
+            with hot.span(hot.SERVE_DECODE_DISPATCH):
+                nxt, self.pools = self._decode(
+                    self._params,
+                    jnp.asarray(tokens),
+                    jnp.asarray(positions),
+                    jnp.asarray(self.tables.tables),
+                    self.pools,
+                    jnp.asarray(seeds),
+                    jnp.asarray(temps),
+                )
+            with hot.span(hot.SERVE_DECODE_FETCH):
+                nxt = np.asarray(nxt)
+            self.steps += 1
 
-        now = self._clock()
-        for slot, st in stepping:
-            st.cache_len += 1
-            self.tables.lengths[slot] = st.cache_len
-            tok = int(nxt[slot])
-            st.last_tok = tok
-            st.req.generated.append(tok)
-            self.tokens_out += 1
-            obs_metrics.SERVE_TOKENS.inc(phase="decode")
-            if self._finished(st.req, tok):
-                self._slots[slot] = None
-                blocks = self.tables.release(slot)
-                if self.prefix_cache is not None:
-                    # index the completed sequence's full blocks (cache
-                    # holds cache_len tokens: everything but the final
-                    # sampled token) before dropping the slot's refs
-                    seq = list(st.req.prompt) + st.req.generated
-                    self.prefix_cache.insert(seq[: st.cache_len], blocks)
-                self.alloc.release(blocks)
-                self._complete(st.req, now)
-        self._update_gauges()
-        self._steps_since_beat += 1
-        if self._steps_since_beat >= 64:
-            self._steps_since_beat = 0
-            obs_trace.heartbeat(
-                "serve.window",
-                steps=self.steps,
-                tokens=self.tokens_out,
-                requests=self.requests_done,
-            )
+            with hot.span(hot.SERVE_DECODE_COMMIT) as commit_span:
+                finished = 0
+                now = self._clock()
+                for slot, st in stepping:
+                    st.cache_len += 1
+                    self.tables.lengths[slot] = st.cache_len
+                    tok = int(nxt[slot])
+                    st.last_tok = tok
+                    st.req.generated.append(tok)
+                    self.tokens_out += 1
+                    obs_metrics.SERVE_TOKENS.inc(phase="decode")
+                    if self._finished(st.req, tok):
+                        finished += 1
+                        self._slots[slot] = None
+                        blocks = self.tables.release(slot)
+                        if self.prefix_cache is not None:
+                            # index the completed sequence's full blocks (cache
+                            # holds cache_len tokens: everything but the final
+                            # sampled token) before dropping the slot's refs
+                            seq = list(st.req.prompt) + st.req.generated
+                            self.prefix_cache.insert(seq[: st.cache_len], blocks)
+                        self.alloc.release(blocks)
+                        self._complete(st.req, now)
+                self._update_gauges()
+                commit_span.set_metadata(finished=finished)
         return True
 
     def _update_gauges(self) -> None:
