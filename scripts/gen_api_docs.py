@@ -92,6 +92,7 @@ MODULES = [
     ("torchx_tpu.serve.pool", "serve_pool", "autoscaling serve pool: cache-aware routing + disaggregated gangs"),
     ("torchx_tpu.ops.attention", "ops_attention", "attention kernels (splash/flash/xla)"),
     ("torchx_tpu.ops.paged_attention", "ops_paged_attention", "single-token decode attention over the paged KV pool"),
+    ("torchx_tpu.ops.paged_attention_kernel", "ops_paged_attention_kernel", "the ragged Pallas TPU kernel behind paged decode attention"),
     ("torchx_tpu.ops.ring_attention", "ops_ring_attention", "ring attention over the sp axis"),
     ("torchx_tpu.ops.norms", "ops_norms", "RMSNorm (+ fused backward kernel)"),
     ("torchx_tpu.ops.fused", "ops_fused", "fused Pallas step kernels: flash attention + residual RMSNorm (--kernels)"),

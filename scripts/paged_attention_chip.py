@@ -1,0 +1,149 @@
+"""The decode attention kernel alone on the chip: is it right, and how fast.
+
+A one-off measurement (PR 25), not a tool of the benchmark. On one TPU it
+
+* compares ``paged_attention_pallas`` and ``paged_attention_xla`` with the
+  same attention in float64 on the host, on random pools at several shapes
+  (heads, block size, dtype, ragged lengths, an inactive slot): the largest
+  absolute error of each;
+* times both at the two serving cells' shapes, and at a window held full,
+  each as a loop of calls inside one program (the output of a call is the
+  next call's query, so nothing is hoisted), and prints the live K/V bytes a
+  call must read over its time as a share of the device's published HBM
+  bandwidth;
+* with ``--chunk-kib``, times the kernel at other chunk sizes.
+
+    chiprun -- python3 scripts/paged_attention_chip.py
+
+It needs a TPU: a time from the CPU's interpreter says nothing.
+"""
+
+from __future__ import annotations
+
+import argparse
+import json
+import os
+import sys
+import time
+
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+sys.path.insert(0, REPO)
+
+CALLS = 64  # kernel calls inside one timed program
+HBM_BYTES_PER_S = 819e9  # one v5e, Google Cloud's "TPU v5e" page (benchmark/lib/peaks.py)
+
+
+def make(rng, slots, h, kvh, hd, bs, bpr, lengths, dtype):  # noqa: ANN001, ANN201
+    """Random query, pools and disjoint block tables for ``lengths``; table
+    entries past a slot's live blocks are the trash block, as the engine's are."""
+    import jax.numpy as jnp
+    import numpy as np
+
+    live = [-(-int(n) // bs) for n in lengths]
+    nb = 1 + sum(live)
+    perm = rng.permutation(np.arange(1, nb))
+    tables = np.zeros((slots, bpr), np.int32)
+    at = 0
+    for i, n in enumerate(live):
+        tables[i, :n] = perm[at : at + n]
+        at += n
+    k = rng.standard_normal((nb, bs, kvh, hd), dtype=np.float32)
+    v = rng.standard_normal((nb, bs, kvh, hd), dtype=np.float32)
+    q = rng.standard_normal((slots, h, hd), dtype=np.float32)
+    as_dev = lambda x: jnp.asarray(x, dtype)  # noqa: E731
+    return as_dev(q), as_dev(k), as_dev(v), jnp.asarray(tables), jnp.asarray(lengths, jnp.int32)
+
+
+def main() -> int:
+    ap = argparse.ArgumentParser(description=__doc__)
+    ap.add_argument("--seed", type=int, default=25)
+    ap.add_argument("--chunk-kib", type=int, nargs="*", default=[], help="other chunk sizes to time")
+    args = ap.parse_args()
+
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from torchx_tpu.ops import paged_attention as pa
+    from torchx_tpu.ops import paged_attention_kernel as pk
+
+    dev = jax.devices()[0]
+    if dev.platform != "tpu":
+        print(f"paged_attention_chip: needs a TPU, found {dev.platform}", file=sys.stderr)
+        return 2
+    print(json.dumps({"platform": dev.platform, "device_kind": dev.device_kind}), flush=True)
+    rng = np.random.default_rng(args.seed)
+
+    def exact(q, k, v, tables, lengths):  # noqa: ANN001, ANN202
+        """The same attention in float64 on the host, a slot and a head at a time."""
+        q, k, v = (np.asarray(x.astype(jnp.float32), np.float64) for x in (q, k, v))
+        out = np.zeros_like(q)
+        group = q.shape[1] // k.shape[2]
+        for i, n in enumerate(np.asarray(lengths)):
+            ks = k[np.asarray(tables)[i]].reshape(-1, *k.shape[2:])[:n]  # [n, kvh, hd]
+            vs = v[np.asarray(tables)[i]].reshape(-1, *v.shape[2:])[:n]
+            for head in range(q.shape[1]):
+                s = ks[:, head // group] @ q[i, head] * q.shape[2] ** -0.5
+                p = np.exp(s - s.max())
+                out[i, head] = (p / p.sum()) @ vs[:, head // group]
+        return out
+
+    def difference(name, slots, h, kvh, hd, bs, bpr, lengths, dtype):  # noqa: ANN001, ANN202
+        a = make(rng, slots, h, kvh, hd, bs, bpr, lengths, dtype)
+        assert pa.kernel_eligible(a[0].shape, a[1].shape, a[0].dtype, a[1].dtype, "tpu"), name
+        want = exact(*a)
+        err = lambda fn: float(np.abs(np.asarray(jax.jit(fn)(*a).astype(jnp.float32), np.float64) - want).max())  # noqa: E731
+        row = {"check": name, "dtype": jnp.dtype(dtype).name, "pallas_max_abs_err": err(pk.paged_attention_pallas),
+               "xla_max_abs_err": err(pa.paged_attention_xla), "exact_abs_max": float(np.abs(want).max())}  # fmt: skip
+        print(json.dumps(row), flush=True)
+
+    ragged = [1, 15, 16, 17, 255, 256, 257, 700, 1024, 4096, 1, 33, 512, 513, 2047, 3000]
+    for dtype in (jnp.bfloat16, jnp.float32):
+        difference("h32.kvh8.bs16", 16, 32, 8, 128, 16, 256, ragged, dtype)
+        difference("h8.kvh8.bs16", 16, 8, 8, 128, 16, 256, ragged, dtype)
+        difference("h64.kvh8.bs32", 16, 64, 8, 128, 32, 128, ragged, dtype)
+        difference("h32.kvh16.bs8", 4, 32, 16, 128, 8, 64, [1, 9, 100, 512], dtype)
+
+    def timed(fn, a):  # noqa: ANN001, ANN202
+        @jax.jit
+        def loop(q, *pools_and_tables):  # noqa: ANN001, ANN202
+            return jax.lax.fori_loop(0, CALLS, lambda _, q: fn(q, *pools_and_tables), q)
+
+        loop(*a).block_until_ready()
+        best = float("inf")
+        for _ in range(5):
+            t0 = time.perf_counter()
+            loop(*a).block_until_ready()
+            best = min(best, time.perf_counter() - t0)
+        return best / CALLS
+
+    def speed(name, slots, bpr, lengths, chunk_kib=None, xla=True):  # noqa: ANN001, ANN202
+        a = make(rng, slots, 32, 8, 128, 16, bpr, lengths, jnp.bfloat16)
+        live_bytes = sum(-(-int(n) // 16) for n in lengths) * 16 * 8 * 128 * 2 * 2
+        row = {"shape": name, "slots": slots, "window": bpr * 16, "tokens_held": int(sum(lengths)),
+               "live_kv_bytes": live_bytes}  # fmt: skip
+        if chunk_kib is not None:
+            pk._CHUNK_BYTES, row["chunk_kib"] = chunk_kib * 1024, chunk_kib
+        t = timed(pk.paged_attention_pallas, a)
+        row["pallas_us"] = t * 1e6
+        row["pallas_hbm_share_pct"] = 100.0 * live_bytes / t / HBM_BYTES_PER_S
+        if xla:
+            row["xla_us"] = timed(pa.paged_attention_xla, a) * 1e6
+        print(json.dumps(row), flush=True)
+
+    # chat: 16 slots x 4096, about 11 held by prompts of 288-896 plus answers; backlog: 16 x 2048, all held
+    chat = [int(x) for x in rng.integers(300, 1100, 11)] + [1] * 5
+    backlog = [int(x) for x in rng.integers(100, 760, 16)]
+    shapes = {"chat": (16, 256, chat), "backlog": (16, 128, backlog), "chat.full_window": (16, 256, [4096] * 16)}
+    default_bytes = pk._CHUNK_BYTES
+    for name, (slots, bpr, lengths) in shapes.items():
+        speed(name, slots, bpr, lengths)
+    for kib in args.chunk_kib:
+        for name, (slots, bpr, lengths) in shapes.items():
+            speed(name, slots, bpr, lengths, chunk_kib=kib, xla=False)
+    pk._CHUNK_BYTES = default_bytes
+    return 0
+
+
+if __name__ == "__main__":
+    sys.exit(main())

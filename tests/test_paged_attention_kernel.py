@@ -1,0 +1,252 @@
+"""The ragged Pallas decode-attention kernel against the XLA function, on the CPU.
+
+The kernel runs in Pallas's interpreter (``interpret=True``), which executes
+the same program (scalar prefetch, the copies of live blocks, the two
+buffers, the online softmax) without a TPU. Tolerances, largest absolute
+difference plus a relative part: float32 pools ``2e-6 + 1e-5 |x|`` (both
+sides sum in float32, in another order); bfloat16 pools ``1e-2 + 1e-2 |x|``
+(the XLA function rounds normalised probabilities to bfloat16, the kernel
+rounds them before the division: one unit in the last place of a result
+near 2 is 0.0156).
+"""
+
+from __future__ import annotations
+
+import functools
+import importlib
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from torchx_tpu.models import generate as gen
+from torchx_tpu.models import llama, moe
+from torchx_tpu.ops import paged_attention as pa
+from torchx_tpu.ops import paged_attention_kernel as pk
+
+attn_ops = importlib.import_module("torchx_tpu.ops.attention")  # the package exports the function under this name
+TOLERANCE = {jnp.float32: dict(atol=2e-6, rtol=1e-5), jnp.bfloat16: dict(atol=1e-2, rtol=1e-2)}
+HD = 128
+
+
+def _problem(lengths, h, kvh, bs, bpr, dtype, shared_blocks=0, seed=0):
+    """Random query and pools, and block tables in shuffled physical order;
+    the first ``shared_blocks`` table entries of every slot are the same
+    physical blocks (a cached prefix). Entries past a slot's live blocks are
+    the trash block."""
+    rng = np.random.default_rng(seed)
+    slots = len(lengths)
+    live = [-(-n // bs) for n in lengths]
+    nb = 1 + shared_blocks + sum(max(n - shared_blocks, 0) for n in live)
+    perm = rng.permutation(np.arange(1, nb))
+    shared, own = perm[:shared_blocks], perm[shared_blocks:]
+    tables = np.full((slots, bpr), pa.TRASH_BLOCK, np.int32)
+    at = 0
+    for i, n in enumerate(live):
+        tables[i, : min(n, shared_blocks)] = shared[:n]
+        rest = max(n - shared_blocks, 0)
+        tables[i, shared_blocks : shared_blocks + rest] = own[at : at + rest]
+        at += rest
+    k = rng.standard_normal((nb, bs, kvh, HD)).astype(np.float32)
+    v = rng.standard_normal((nb, bs, kvh, HD)).astype(np.float32)
+    q = rng.standard_normal((slots, h, HD)).astype(np.float32)
+    return q, k, v, tables, np.asarray(lengths, np.int32)
+
+
+def _poison(k, v, tables, lengths, bs):
+    """NaN in the trash block and in every block no slot's live range reaches,
+    K and V; in K also past the last position any slot holds of its last
+    block. (V there must hold numbers for either path: 0 * NaN is NaN.)"""
+    k, v = k.copy(), v.copy()
+    live_upto = np.zeros(k.shape[0], np.int64)  # positions of a block some slot attends
+    for row, n in zip(tables, lengths):
+        for j in range(-(-int(n) // bs)):
+            live_upto[row[j]] = max(live_upto[row[j]], min(bs, int(n) - j * bs))
+    live_upto[pa.TRASH_BLOCK] = 0
+    for blk, upto in enumerate(live_upto):
+        k[blk, upto:] = np.nan
+        if upto == 0:
+            v[blk] = np.nan
+    return k, v
+
+
+# window = bs * bpr; the kernel's chunk is shrunk to 4 blocks below, so
+# "mid" spans several chunks and "full" ends on the table's last entry
+def _case(id, lengths, h=8, kvh=2, bs=16, bpr=12, dtype=jnp.float32, **kw):
+    return pytest.param(dict(lengths=lengths, h=h, kvh=kvh, bs=bs, bpr=bpr, dtype=dtype, **kw), id=id)
+
+
+CASES = [
+    _case("length-1", [1, 1]),
+    _case("length-bs-1", [15, 3]),
+    _case("length-bs", [16, 32]),
+    _case("length-bs+1", [17, 33]),
+    _case("length-mid-window", [100, 65, 7]),
+    _case("length-full-window", [192, 191, 1]),
+    _case("group-1", [40, 130, 1], h=2, kvh=2),
+    _case("group-4", [40, 130, 1], h=8, kvh=2),
+    _case("group-8", [40, 130, 1], h=16, kvh=2),
+    _case("block-16", [5, 77, 160], bs=16, bpr=10),
+    _case("block-32", [5, 77, 320], bs=32, bpr=10),
+    _case("inactive-slot", [50, 1, 90], inactive=(1,)),
+    _case("shared-prefix-blocks", [70, 100, 40], shared_blocks=2),
+    _case("nan-past-lengths", [1, 17, 100, 192], poison=True),
+    _case("nan-past-lengths-bf16", [1, 17, 100, 192], poison=True, dtype=jnp.bfloat16),
+    _case("pool-float32", [9, 120, 64, 33]),
+    _case("pool-bfloat16", [9, 120, 64, 33], dtype=jnp.bfloat16),
+    _case("benchmark-heads-real-chunk", [1, 300, 530], h=32, kvh=8, bs=16, bpr=34, real_chunk=True,
+          dtype=jnp.bfloat16),  # fmt: skip
+]
+
+
+@pytest.mark.parametrize("case", CASES)
+def test_kernel_matches_the_xla_function(case, monkeypatch):
+    case = dict(case)
+    dtype, bs = case.pop("dtype"), case["bs"]
+    poison, inactive = case.pop("poison", False), case.pop("inactive", ())
+    if not case.pop("real_chunk", False):
+        monkeypatch.setattr(pk, "_CHUNK_BYTES", 4 * bs * case["kvh"] * HD * jnp.dtype(dtype).itemsize)
+    q, k, v, tables, lengths = _problem(dtype=dtype, **case)
+    for i in inactive:  # as the engine leaves a slot nobody holds
+        tables[i, :] = pa.TRASH_BLOCK
+    as_dev = lambda x: jnp.asarray(x, dtype)  # noqa: E731
+    want = pa.paged_attention_xla(as_dev(q), as_dev(k), as_dev(v), jnp.asarray(tables), jnp.asarray(lengths))
+    if poison:
+        k, v = _poison(k, v, tables, lengths, bs)
+        assert np.isnan(k[pa.TRASH_BLOCK]).all() and np.isnan(v).any()
+    got = pk.paged_attention_pallas(
+        as_dev(q), as_dev(k), as_dev(v), jnp.asarray(tables), jnp.asarray(lengths), interpret=True
+    )
+    assert got.shape == want.shape and got.dtype == want.dtype
+    got, want = np.asarray(got, np.float32), np.asarray(want, np.float32)
+    assert np.isfinite(got).all()
+    keep = [i for i in range(len(lengths)) if i not in inactive]  # what an inactive slot returns is never read
+    np.testing.assert_allclose(got[keep], want[keep], **TOLERANCE[dtype])
+
+
+# -- which path a call takes -----------------------------------------------------------
+
+MISTRAL = dict(q=(16, 32, 128), pool=(2049, 16, 8, 128))  # both benchmark configurations: 32/8 heads of 128, block 16
+RULE = [
+    ("benchmark-shapes-on-tpu", MISTRAL, jnp.bfloat16, jnp.bfloat16, "tpu", True),
+    ("float32-on-tpu", MISTRAL, jnp.float32, jnp.float32, "tpu", True),
+    ("group-1-block-32", dict(q=(4, 8, 128), pool=(9, 32, 8, 128)), jnp.bfloat16, jnp.bfloat16, "tpu", True),
+    ("head-dim-256", dict(q=(4, 16, 256), pool=(9, 16, 8, 256)), jnp.bfloat16, jnp.bfloat16, "tpu", True),
+    ("cpu", MISTRAL, jnp.bfloat16, jnp.bfloat16, "cpu", False),
+    ("gpu", MISTRAL, jnp.bfloat16, jnp.bfloat16, "gpu", False),
+    ("head-dim-64", dict(q=(4, 32, 64), pool=(9, 16, 8, 64)), jnp.bfloat16, jnp.bfloat16, "tpu", False),
+    ("heads-do-not-group", dict(q=(4, 12, 128), pool=(9, 16, 8, 128)), jnp.bfloat16, jnp.bfloat16, "tpu", False),
+    ("two-cache-heads", dict(q=(4, 4, 128), pool=(9, 16, 2, 128)), jnp.float32, jnp.float32, "tpu", False),
+    ("block-4", dict(q=(4, 32, 128), pool=(9, 4, 8, 128)), jnp.bfloat16, jnp.bfloat16, "tpu", False),
+    ("query-and-pool-dtypes-differ", MISTRAL, jnp.float32, jnp.bfloat16, "tpu", False),
+    ("float16", MISTRAL, jnp.float16, jnp.float16, "tpu", False),
+]
+
+
+@pytest.mark.parametrize("shapes,q_dtype,pool_dtype,backend,want", [pytest.param(*r[1:], id=r[0]) for r in RULE])
+def test_eligibility_is_a_function_of_shapes_dtypes_and_backend(shapes, q_dtype, pool_dtype, backend, want):
+    assert pa.kernel_eligible(shapes["q"], shapes["pool"], jnp.dtype(q_dtype), jnp.dtype(pool_dtype), backend) is want
+
+
+def _pretend_tpu(monkeypatch):
+    """The real rule with the backend said to be a TPU, and the kernel it then
+    picks run in the interpreter."""
+    rule = pa.kernel_eligible
+    monkeypatch.setattr(pa, "kernel_eligible", lambda qs, ps, qd, pd, _backend: rule(qs, ps, qd, pd, "tpu"))
+    monkeypatch.setattr(pk, "paged_attention_pallas", functools.partial(pk.paged_attention_pallas, interpret=True))
+
+
+@pytest.mark.parametrize("h,kvh,hd,pretend,want", [
+    pytest.param(8, 8, 128, False, "paged_xla", id="cpu"),
+    pytest.param(8, 8, 64, True, "paged_xla", id="head-dim-64"),
+    pytest.param(8, 8, 128, True, "paged_pallas", id="eligible"),
+])  # fmt: skip
+def test_traced_says_which_path_lowered(h, kvh, hd, pretend, want, monkeypatch):
+    monkeypatch.setattr(attn_ops, "TRACED", {})
+    if pretend:
+        _pretend_tpu(monkeypatch)
+    q = jnp.ones((2, h, hd), jnp.float32)
+    pool = jnp.ones((3, 16, kvh, hd), jnp.float32)
+    tables = jnp.asarray([[1, 0], [2, 0]], jnp.int32)
+    out = pa.paged_attention(q, pool, pool, tables, jnp.asarray([5, 16], jnp.int32))
+    assert attn_ops.traced("attention") == want
+    np.testing.assert_allclose(np.asarray(out), 1.0, rtol=1e-6)  # the mean of ones, whatever the path
+
+
+# -- the decode program with the kernel in it --------------------------------------------
+
+
+def _greedy_tokens(cfg, steps=8):
+    init, _ = llama.model_fns(cfg)
+    params = init(cfg, jax.random.PRNGKey(3))
+    slots, bs = 3, 16
+    bps = cfg.max_seq // bs
+    pools = gen.init_kv_pools(cfg, 1 + slots * bps, bs)
+    tables = np.full((slots, bps), pa.TRASH_BLOCK, np.int32)
+    tables[0], tables[1] = 1 + np.arange(bps), 1 + bps + np.arange(bps)  # slot 2 stays inactive
+    tables = jnp.asarray(tables)
+    step = jax.jit(lambda p, t, pos, pl: gen.paged_decode_step(
+        p, t, pos, tables, pl, cfg, jnp.zeros((slots, 2), jnp.uint32), jnp.zeros((slots,), jnp.float32)))  # fmt: skip
+    tokens, out = jnp.asarray([5, 9, 0], jnp.int32), []
+    for i in range(steps):
+        tokens, pools = step(params, tokens, jnp.asarray([i, i, 0], jnp.int32), pools)
+        out.append(np.asarray(tokens)[:2])
+    return np.stack(out)
+
+
+@pytest.mark.parametrize("make", [
+    pytest.param(lambda: llama.llama_tiny(dim=1024, n_heads=8, n_kv_heads=8, max_seq=64), id="dense"),
+    pytest.param(lambda: moe.moe_tiny(dim=1024, n_heads=8, n_kv_heads=8, max_seq=64), id="moe"),
+])  # fmt: skip
+def test_decode_step_gives_the_same_greedy_tokens(make, monkeypatch):
+    cfg = make()
+    assert cfg.head_dim == 128
+    want = _greedy_tokens(cfg)
+    monkeypatch.setattr(attn_ops, "TRACED", {})
+    _pretend_tpu(monkeypatch)
+    got = _greedy_tokens(cfg)
+    assert attn_ops.traced("attention") == "paged_pallas"
+    np.testing.assert_array_equal(got, want)
+    assert len({tuple(r) for r in want}) > 1  # the tokens move: not a constant answer
+
+
+# -- the chip's compiler takes the kernel at the benchmark's shapes ------------------------
+# Interpret mode cannot see what Mosaic refuses (tiling, VMEM). The TPU's compiler is
+# installed without a chip; the topology is described inside a fixture and nowhere else,
+# so only the worker that runs this file loads the library.
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    import os
+
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    from jax.sharding import SingleDeviceSharding
+
+    try:
+        topo = topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.mark.parametrize("bpr,dtype", [
+    pytest.param(256, jnp.bfloat16, id="mistral7b-serve-chat"),
+    pytest.param(128, jnp.bfloat16, id="mixtral8x7b-serve-backlog"),
+    pytest.param(256, jnp.float32, id="float32-pools"),
+])  # fmt: skip
+def test_kernel_compiles_for_the_chip(one_chip, bpr, dtype):
+    slots, h, kvh, bs, nb = 16, 32, 8, 16, 2049
+    shape = lambda s, d: jax.ShapeDtypeStruct(s, d, sharding=one_chip)  # noqa: E731
+    pool = shape((nb, bs, kvh, HD), dtype)
+    assert pa.kernel_eligible((slots, h, HD), pool.shape, jnp.dtype(dtype), pool.dtype, "tpu")
+    compiled = jax.jit(pk.paged_attention_pallas).lower(
+        shape((slots, h, HD), dtype), pool, pool, shape((slots, bpr), jnp.int32), shape((slots,), jnp.int32)
+    ).compile()  # fmt: skip
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text
+    # the pools go in as they lie in HBM: no relayout of 67 MB a layer in front of the kernel
+    pool_type = f"{jnp.dtype(dtype).name.replace('bfloat16', 'bf16').replace('float32', 'f32')}[{nb},"
+    assert not [ln for ln in text.splitlines() if " copy(" in ln and pool_type in ln]

@@ -9,14 +9,30 @@ arrive, with a per-sequence *block table* mapping logical positions to
 physical blocks in one shared pool.
 
 This module is the device-side half: pure, jittable functions over a
-fixed ``[num_blocks, block_size, kvh, hd]`` pool per layer —
+fixed ``[num_blocks, block_size, kvh, hd]`` pool per layer (one block is
+``block_size * kvh * hd`` contiguous elements: every cache head of a
+position side by side) —
 
+* :func:`paged_attention` — single-query-token GQA attention of every slot
+  against the positions below its length. On a TPU, where
+  :func:`kernel_eligible` allows, it is the ragged Pallas kernel of
+  :mod:`torchx_tpu.ops.paged_attention_kernel`: per slot it walks the block
+  table only as far as ``ceil(lengths[i] / block_size)``, copies those
+  blocks from the pool in HBM to VMEM and folds them into an online
+  softmax, the query heads of a cache head together, so K and V are read
+  once, never gathered into a window and never repeated: bytes per step
+  follow the tokens held, not ``max_seq x slots``. Elsewhere (the CPU, a
+  ``head_dim`` that is no multiple of 128, cache heads that do not fill a
+  tile) it is :func:`paged_attention_xla`, which gathers the whole window
+  and masks; that function is also the reference the kernel is tested
+  against. ``ops.attention.traced("attention")`` says which one a program
+  lowered to (``paged_pallas`` / ``paged_xla``);
+* :func:`paged_attention_chunk` — the same for a chunk of query tokens per
+  slot (prefill); always the gather;
 * :func:`gather_kv` — block-table gather back to a contiguous
   ``[slots, S, kvh, hd]`` view (S = blocks_per_slot * block_size);
-* :func:`paged_attention` — single-query-token GQA attention against the
-  gathered view, masked by per-slot valid lengths;
-* :func:`append_kv` — scatter one new K/V token per slot into the pool at
-  its block-table position;
+* :func:`append_kv` / :func:`scatter_kv_chunk` — scatter one new K/V token
+  (a chunk of them) per slot into the pool at its block-table position;
 * :func:`write_prefill` — bulk-write a prefilled prompt's K/V into the
   blocks a slot was assigned.
 
@@ -24,7 +40,10 @@ Everything is static-shape (XLA compiles once per pool geometry); the
 host-side allocator that assigns blocks lives in
 :mod:`torchx_tpu.serve.kv_pool`. Block 0 is reserved as the trash block:
 unassigned table entries point at it, writes from inactive slots land in
-it, and the length mask keeps its contents out of every softmax.
+it, and no softmax takes it in: the XLA path masks it, the kernel does not
+read past a slot's live blocks. Either path multiplies the unwritten tail
+of a slot's last block by a probability of zero, so a pool must hold
+numbers there (zeros at start, an earlier request's K/V later).
 """
 
 from __future__ import annotations
@@ -55,6 +74,32 @@ def gather_kv(pool: jnp.ndarray, tables: jnp.ndarray) -> jnp.ndarray:
     return g.reshape(slots, bpr * bs, kvh, hd)
 
 
+def kernel_eligible(
+    q_shape: tuple[int, ...],  # [slots, h, hd]
+    pool_shape: tuple[int, ...],  # [num_blocks, bs, kvh, hd]
+    q_dtype: jnp.dtype,
+    pool_dtype: jnp.dtype,
+    backend: str,
+) -> bool:
+    """Whether :func:`paged_attention` lowers to the Pallas kernel: a pure
+    function of shapes, dtypes and backend. The kernel needs a TPU, lanes
+    full of one head (``hd`` a multiple of 128), query heads that group
+    evenly over the cache heads, the 8 cache heads of one position filling
+    one sublane tile (so a block lands in VMEM as whole ``[bs * kvh, hd]``
+    rows), a block of whole packed tiles, and bf16 or float32 throughout."""
+    _, h, hd = q_shape
+    _, bs, kvh, _ = pool_shape
+    return (
+        backend == "tpu"
+        and hd % 128 == 0
+        and h % kvh == 0
+        and kvh % 8 == 0
+        and bs % 8 == 0
+        and q_dtype == pool_dtype
+        and pool_dtype in (jnp.bfloat16, jnp.float32)
+    )
+
+
 @jax.named_scope(hot.PAGED_ATTENTION)
 def paged_attention(
     q: jnp.ndarray,  # [slots, h, hd] — ONE query token per slot
@@ -65,13 +110,36 @@ def paged_attention(
 ) -> jnp.ndarray:
     """Single-token decode attention against the paged cache.
 
-    GQA: query heads ``h`` fold onto ``kvh`` cache heads by repetition
-    (same as the dense path's ``_cached_attention``). Positions at or
-    beyond ``lengths[i]`` — unwritten block tails and every unassigned
-    (trash) block — are masked out of slot ``i``'s softmax. Returns
-    ``[slots, h, hd]``.
+    Positions at or beyond ``lengths[i]`` — unwritten block tails and every
+    unassigned (trash) block — are out of slot ``i``'s softmax. Returns
+    ``[slots, h, hd]``. Lowers to
+    :func:`~torchx_tpu.ops.paged_attention_kernel.paged_attention_pallas` where
+    :func:`kernel_eligible` says so and to :func:`paged_attention_xla`
+    elsewhere; ``ops.attention.traced("attention")`` tells which.
     """
+    if kernel_eligible(
+        q.shape, k_pool.shape, q.dtype, k_pool.dtype, jax.default_backend()
+    ):
+        # imported here: Pallas costs a second that no CPU process should pay
+        from torchx_tpu.ops.paged_attention_kernel import paged_attention_pallas
+
+        note_traced("attention", "paged_pallas")
+        return paged_attention_pallas(q, k_pool, v_pool, tables, lengths)
     note_traced("attention", "paged_xla")
+    return paged_attention_xla(q, k_pool, v_pool, tables, lengths)
+
+
+def paged_attention_xla(
+    q: jnp.ndarray,  # [slots, h, hd]
+    k_pool: jnp.ndarray,  # [num_blocks, bs, kvh, hd]
+    v_pool: jnp.ndarray,
+    tables: jnp.ndarray,  # [slots, blocks_per_slot] int32
+    lengths: jnp.ndarray,  # [slots] int32
+) -> jnp.ndarray:
+    """:func:`paged_attention` in plain XLA: gather every slot's whole
+    window, fold query heads onto cache heads by repetition (same as the
+    dense path's ``_cached_attention``), mask by ``lengths``. The CPU path
+    and the reference the kernel is tested against."""
     slots, h, d = q.shape
     with jax.named_scope(hot.GATHER_KV):
         k = gather_kv(k_pool, tables)  # [slots, S, kvh, hd]
