@@ -1,11 +1,11 @@
 """From a configuration file to the program's config object and to weights.
 
-The file carries the model's published ``config.json`` keys; the two model
-kinds the program has (``llama`` for a dense decoder, ``moe`` for Mixtral's
-block) each read the keys they need. The weights are the benchmark's own:
-one jitted call from the seed, on the device, in the type they are served
-or trained in, so that the reference can be given the very same numbers
-without taking anything the program made.
+The file carries the model's published ``config.json`` keys; which of them a
+model kind reads, and what parameter tree it has, is its adapter's to say
+(``kinds/<kind>.py``, found from the file's ``model``). The weights are the
+benchmark's own: one jitted call from the seed, on the device, in the type
+they are served or trained in, so that the reference can be given the very
+same numbers without taking anything the program made.
 """
 
 from __future__ import annotations
@@ -16,41 +16,16 @@ from typing import Any, Optional
 import jax
 import jax.numpy as jnp
 
+from . import kinds
+
 
 def _dtype(config: dict):  # noqa: ANN202
     return {"bfloat16": jnp.bfloat16, "float32": jnp.float32}[config["torch_dtype"]]
 
 
 def program_config(config: dict, **overrides: Any):
-    """``LlamaConfig`` / ``MoEConfig`` from the published keys."""
-    from torchx_tpu.models import llama, moe
-
-    if config.get("sliding_window"):
-        raise ValueError("the program has no sliding-window attention")
-    kw = dict(
-        vocab_size=config["vocab_size"],
-        dim=config["hidden_size"],
-        n_layers=config["num_hidden_layers"],
-        n_heads=config["num_attention_heads"],
-        n_kv_heads=config["num_key_value_heads"],
-        ffn_dim=config["intermediate_size"],
-        rope_theta=float(config["rope_theta"]),
-        norm_eps=float(config["rms_norm_eps"]),
-        tie_embeddings=bool(config["tie_word_embeddings"]),
-        dtype=_dtype(config),
-    )
-    if config["model"] == "moe":
-        kw.update(
-            n_experts=config["num_local_experts"],
-            top_k=config["num_experts_per_tok"],
-            capacity_factor=float(config["deployment"]["capacity_factor"]),
-        )
-        kw.update(overrides)
-        return moe.MoEConfig(**kw)
-    if config["model"] != "llama":
-        raise ValueError(f"unknown model kind {config['model']!r}")
-    kw.update(overrides)
-    return llama.LlamaConfig(**kw)
+    """The program's config object from the published keys, as the kind maps them."""
+    return kinds.of(config).program_config(config, **overrides)
 
 
 def seed_key(seed: int, stream: str) -> jax.Array:
@@ -61,53 +36,40 @@ def seed_key(seed: int, stream: str) -> jax.Array:
 
 
 def weight_shapes(config: dict) -> dict:
-    """The parameter tree's shapes and fan-ins, as the program lays it out:
-    layers stacked on a leading axis, experts on the next."""
-    d = config["hidden_size"]
-    h = config["num_attention_heads"]
-    kvh = config["num_key_value_heads"]
-    hd = config.get("head_dim") or d // h
-    f, L, v = (
-        config["intermediate_size"],
-        config["num_hidden_layers"],
-        config["vocab_size"],
-    )
-    E = config.get("num_local_experts", 0)
-    ex = (E,) if E else ()
-    layers = {
-        "attn_norm": ((L, d), 0),
-        "wq": ((L, d, h * hd), d),
-        "wk": ((L, d, kvh * hd), d),
-        "wv": ((L, d, kvh * hd), d),
-        "wo": ((L, h * hd, d), h * hd),
-        "mlp_norm": ((L, d), 0),
-        "w_gate": ((L, *ex, d, f), d),
-        "w_up": ((L, *ex, d, f), d),
-        "w_down": ((L, *ex, f, d), f),
-    }
-    if E:
-        layers["w_router"] = ((L, d, E), d)
-    tree = {"embed": ((v, d), d), "layers": layers, "final_norm": ((d,), 0)}
-    if not config.get("tie_word_embeddings", False):
-        tree["lm_head"] = ((d, v), d)
-    return tree
+    """The parameter tree as the program lays it out, each leaf ``(shape,
+    init)``. ``init`` is a fan-in (normal with standard deviation ``fan_in **
+    -0.5``; 0 means ones, a norm's gain), ``"zeros"`` (a bias), or ``("normal",
+    std)`` (a deviation the model states)."""
+    return kinds.of(config).weight_shapes(config)
+
+
+def _init_leaf(key: jax.Array, i: int, shape: tuple, init: Any, dtype):  # noqa: ANN001, ANN202
+    if init == 0:
+        return jnp.ones(shape, dtype)
+    if init == "zeros":
+        return jnp.zeros(shape, dtype)
+    if isinstance(init, tuple) and init[0] == "normal":
+        std = init[1]
+    elif isinstance(init, (int, float)) and init > 0:
+        std = init**-0.5
+    else:
+        raise ValueError(f"unknown initial distribution {init!r}")
+    w = jax.random.normal(jax.random.fold_in(key, i), shape, jnp.float32)
+    return (w * std).astype(dtype)
 
 
 def make_weights(config: dict, seed: int, shardings: Optional[Any] = None):
-    """Seeded weights on the device in one jitted call: normal with standard
-    deviation ``fan_in ** -0.5``, norm gains one, in the configuration's type."""
+    """Seeded weights on the device in one jitted call over the kind's tree,
+    in the configuration's type. Leaf ``i`` of the flattened tree draws from
+    ``fold_in(key, i)``, whatever the other leaves are."""
     dtype = _dtype(config)
     shapes = weight_shapes(config)
     leaves, treedef = jax.tree.flatten(shapes, is_leaf=lambda x: isinstance(x, tuple))
 
     def build(key):  # noqa: ANN001
-        out = []
-        for i, (shape, fan_in) in enumerate(leaves):
-            if fan_in == 0:
-                out.append(jnp.ones(shape, dtype))
-            else:
-                w = jax.random.normal(jax.random.fold_in(key, i), shape, jnp.float32)
-                out.append((w * fan_in**-0.5).astype(dtype))
-        return jax.tree.unflatten(treedef, out)
+        return jax.tree.unflatten(treedef, [
+            _init_leaf(key, i, shape, init, dtype)
+            for i, (shape, init) in enumerate(leaves)
+        ])
 
     return jax.jit(build, out_shardings=shardings)(seed_key(seed, "weights"))

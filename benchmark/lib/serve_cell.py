@@ -18,7 +18,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from . import check, device, models, peaks, stats, traffic as traffic_lib
+from . import check, device, kinds, models, peaks, stats, traffic as traffic_lib
 from . import trace as trace_lib
 from .spec import Cell, scratch_dir
 
@@ -270,11 +270,10 @@ def sample_finished(snap: list[dict], n: int, seed: int, t_from: float, t_to: fl
 
 def served_gaps(params, config: dict, sample: list[dict], quant: Optional[str] = None):  # noqa: ANN001
     """For each served token of each sampled request: how far its logit lies
-    below the reference's best at that position, from one reference pass over
+    below the best of the kind's reference at that position, from one pass over
     prompt + served tokens. With ``quant`` (the control) the served tokens are
     replaced by the ones the lower precision puts first at each position."""
-    from benchmark.reference import model as ref
-
+    ref = kinds.reference(config)
     gaps = []
     for s in sample:
         seq = s["prompt"] + s["generated"]

@@ -71,7 +71,8 @@ def load_cell(
     if not os.path.exists(cell_file):
         raise SystemExit(f"no such cell: {cell_file} is missing")
     w = _load(cell_file)
-    config = _load(os.path.join(bench_dir, "configs", f"{w['config']}.json"))
+    # where the configuration came from: its model kind's files are looked for there first
+    config = dict(_load(os.path.join(bench_dir, "configs", f"{w['config']}.json")), bench_dir=bench_dir)
     traffic = _load(os.path.join(bench_dir, "traffic", f"{w['traffic']}.json"))
     return Cell(
         name=name,

@@ -21,7 +21,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from . import check, counts, device, models, peaks, trace as trace_lib
+from . import check, counts, device, kinds, models, peaks, trace as trace_lib
 from .spec import Cell, scratch_dir
 
 F32 = jnp.float32
@@ -165,7 +165,6 @@ def run(
     control: Optional[str] = None,
 ) -> dict:
     from torchx_tpu.examples.data import TokenDataset
-    from torchx_tpu.models import llama
     from torchx_tpu.parallel.prefetch import device_prefetch
     from torchx_tpu.parallel.xla_cache import setup_compilation_cache
 
@@ -228,7 +227,7 @@ def run(
         feed.close()
     window_s = t_close - t_open
     final_loss = float(loss)
-    overflow = float(aux[llama.AUX_OVERFLOW]) if config.get("num_local_experts") else 0.0
+    aux_faults = kinds.of(config).aux_must_be_zero(aux)
     memory_peak = device.memory_peak_bytes(cell.chips)
     compiled_in_window = compiles.between(t_open, t_close)
     del state, step_fn, loss, aux, prev
@@ -257,7 +256,7 @@ def run(
             "flops_per_token": flops_per_token,
             "flops_per_step": flops_per_token * tokens_per_step,
             "chips": cell.chips,
-            "router_overflow": overflow,
+            **aux_faults,
         },
         "trace": trace_lib.reduce_trace(profile_dir, cell.chips) if trace else None,
     }
@@ -276,8 +275,9 @@ def run(
         verdict.flag(f"{compiled_in_window} compilations inside the window")
     if not math.isfinite(final_loss):
         verdict.flag("final loss is not finite")
-    if overflow:
-        verdict.flag(f"router_overflow {overflow} is not 0")
+    for name, value in aux_faults.items():
+        if value:
+            verdict.flag(f"{name} {value} is not 0")
     t0 = time.monotonic()
     reference = follow_reference(config, seed, fed, job, None)
     print(f"check: reference followed {len(fed)} steps in {time.monotonic() - t0:.1f}s", flush=True)
@@ -302,12 +302,14 @@ def run(
 
 
 def follow_reference(config: dict, seed: int, fed: list, job: dict, quant: Optional[str]) -> dict:
+    """The reference's readings of the fed steps: the kind's loss under
+    ``reference/train.py``'s clip and AdamW."""
     from benchmark.reference import train as ref_train
 
     weights = models.make_weights(config, seed)
     weights = jax.tree.map(lambda x: x.astype(F32), weights)
     opt = dict(ADAM, lr=job["lr"], warmup=job["warmup"])
-    return ref_train.follow(weights, fed, config, opt, quant)
+    return ref_train.follow(kinds.reference(config).mean_nll, weights, fed, config, opt, quant)
 
 
 def _shares(norms: dict) -> dict:

@@ -162,3 +162,24 @@ def logits(params: dict, tokens: jnp.ndarray, c: dict, quant: Optional[str] = No
         x = _layer_jit(x, params["layers"], jnp.int32(i), _static(c), quant)
     top = {k: w for k, w in params.items() if k != "layers"}
     return _head_jit(x, top, _static(c), quant)
+
+
+def mean_nll(params: dict, tokens: jnp.ndarray, c: dict, quant: Optional[str] = None):
+    """Mean next-token negative log-likelihood of ``tokens[b, s+1]``. Layers
+    run under ``lax.scan`` with each one recomputed in the backward pass."""
+    inputs, targets = tokens[:, :-1], tokens[:, 1:]
+    x = params["embed"][inputs].astype(F32)
+
+    def body(x, lw):  # noqa: ANN001
+        return layer(x, lw, c, quant), None
+
+    x, _ = jax.lax.scan(jax.checkpoint(body), x, params["layers"])
+    top = {k: w for k, w in params.items() if k != "layers"}
+
+    def row_nll(args):  # noqa: ANN001 - one row's [s, vocab] logits at a time
+        xr, tr = args
+        lg = head(xr[None], top, c, quant)[0]
+        return jax.nn.logsumexp(lg, axis=-1) - jnp.take_along_axis(lg, tr[:, None], axis=-1)[:, 0]
+
+    nll = jax.lax.map(jax.checkpoint(row_nll), (x, targets))
+    return jnp.mean(nll)

@@ -1,4 +1,6 @@
-"""Plain reference of the training step: loss, gradients, clipping, AdamW.
+"""Plain reference of the training step around a kind's loss: gradients,
+clipping, AdamW. What no model kind owns; the loss itself (``mean_nll``) is
+in the kind's reference, beside its layer.
 
 Float32 throughout at ``precision=HIGHEST``; the optimizer is written out
 here (global-norm clip, AdamW with bias correction and decoupled weight decay,
@@ -12,36 +14,13 @@ from __future__ import annotations
 
 import functools
 import math
-from typing import Optional
+from typing import Callable, Optional
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
-from . import model
-
 F32 = jnp.float32
-
-
-def mean_nll(params: dict, tokens: jnp.ndarray, c: dict, quant: Optional[str] = None):
-    """Mean next-token negative log-likelihood of ``tokens[b, s+1]``. Layers
-    run under ``lax.scan`` with each one recomputed in the backward pass."""
-    inputs, targets = tokens[:, :-1], tokens[:, 1:]
-    x = params["embed"][inputs].astype(F32)
-
-    def body(x, lw):  # noqa: ANN001
-        return model.layer(x, lw, c, quant), None
-
-    x, _ = jax.lax.scan(jax.checkpoint(body), x, params["layers"])
-    top = {k: w for k, w in params.items() if k != "layers"}
-
-    def row_nll(args):  # noqa: ANN001 - one row's [s, vocab] logits at a time
-        xr, tr = args
-        lg = model.head(xr[None], top, c, quant)[0]
-        return jax.nn.logsumexp(lg, axis=-1) - jnp.take_along_axis(lg, tr[:, None], axis=-1)[:, 0]
-
-    nll = jax.lax.map(jax.checkpoint(row_nll), (x, targets))
-    return jnp.mean(nll)
 
 
 def named_leaves(tree: dict) -> list:
@@ -78,8 +57,10 @@ def learning_rate(count: int, opt: dict) -> float:
     return opt["lr"] * (0.9 * cosine + 0.1)
 
 
-def follow(params: dict, batches: list, c: dict, opt: dict, quant: Optional[str] = None) -> dict:
-    """Follow ``len(batches)`` steps from float32 ``params``. Returns each
+def follow(mean_nll: Callable, params: dict, batches: list, c: dict, opt: dict,
+           quant: Optional[str] = None) -> dict:
+    """Follow ``len(batches)`` steps from float32 ``params`` under the kind's
+    ``mean_nll(params, tokens, c, quant)``. Returns each
     step's loss, the per-leaf norm of the first gradient as the optimizer gets
     it (after the clip), and the per-leaf norm of the parameters' change."""
     grad_fn = jax.jit(

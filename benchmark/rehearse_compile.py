@@ -22,7 +22,7 @@ import jax  # noqa: E402
 import jax.numpy as jnp  # noqa: E402
 from jax.sharding import NamedSharding, SingleDeviceSharding  # noqa: E402
 
-from benchmark.lib import models, spec, traffic as traffic_lib  # noqa: E402
+from benchmark.lib import kinds, models, spec, traffic as traffic_lib  # noqa: E402
 
 GIB = 2**30
 
@@ -86,12 +86,10 @@ def train_cell(cell: spec.Cell, topo) -> None:  # noqa: ANN001
     report(f"{cell.name}: program step (batch {batch} x seq {seq})", step.lower(state, batch_sds).compile())
 
     if cell.chips == 1:
-        from benchmark.reference import train as ref_train
-
         one = SingleDeviceSharding(topo.devices[0])
         p32 = shapes_of(config, jnp.float32, jax.tree.map(lambda _: one, shardings))
         toks = jax.ShapeDtypeStruct((batch, seq + 1), jnp.int32, sharding=one)
-        grad = jax.jit(jax.value_and_grad(lambda p, t: ref_train.mean_nll(p, t, config)))
+        grad = jax.jit(jax.value_and_grad(lambda p, t: kinds.reference(config).mean_nll(p, t, config)))
         report(f"{cell.name}: reference loss and gradients (float32)", grad.lower(p32, toks).compile())
 
 

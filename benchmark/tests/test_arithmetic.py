@@ -1,12 +1,13 @@
-"""Percentile, spread, FLOP and byte arithmetic, and the table of peaks."""
+"""Percentile, spread, FLOP and byte arithmetic (the counts through each
+configuration's model kind), and the table of peaks."""
 
 import pytest
 
 from benchmark.lib import counts, peaks, stats
 
-MISTRAL_7B = dict(hidden_size=4096, num_attention_heads=32, num_key_value_heads=8,
+MISTRAL_7B = dict(model="llama", hidden_size=4096, num_attention_heads=32, num_key_value_heads=8,
                   intermediate_size=14336, num_hidden_layers=32, vocab_size=32768)
-MIXTRAL = dict(MISTRAL_7B, vocab_size=32000, num_local_experts=8, num_experts_per_tok=2)
+MIXTRAL = dict(MISTRAL_7B, model="moe", vocab_size=32000, num_local_experts=8, num_experts_per_tok=2)
 
 
 @pytest.mark.parametrize("q,want", [(0, 1.0), (50, 2.5), (100, 4.0), (95, 3.85)])

@@ -1,4 +1,5 @@
-"""The plain reference against the program at tiny widths in float32: the
+"""The plain reference, reached through each configuration's model kind,
+against the program at tiny widths in float32: the
 dense block against ``llama.forward``, the Mixtral block against
 ``moe.forward`` (capacity large enough to drop nothing), and the written-out
 clip + AdamW against the program's optimizer."""
@@ -11,8 +12,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from benchmark.lib import models
-from benchmark.reference import model as ref
+from benchmark.lib import kinds, models
 from benchmark.reference import train as ref_train
 
 from .conftest import FIXTURES
@@ -33,7 +33,7 @@ def test_logits_match_the_program(name):
     tokens = jax.random.randint(jax.random.PRNGKey(1), (2, 48), 0, c["vocab_size"])
     with jax.default_matmul_precision("highest"):
         want = llama.forward(params, tokens, cfg)
-    got = ref.logits(params, tokens, c)
+    got = kinds.reference(c).logits(params, tokens, c)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=2e-5)
 
 
@@ -41,6 +41,7 @@ def test_fp8_control_moves_the_logits():
     c = config("tiny-dense")
     params = models.make_weights(c, 5)
     tokens = jax.random.randint(jax.random.PRNGKey(1), (1, 32), 0, c["vocab_size"])
+    ref = kinds.reference(c)
     gap = jnp.abs(ref.logits(params, tokens, c, "fp8") - ref.logits(params, tokens, c))
     assert float(jnp.max(gap)) > 1e-3
 
@@ -54,7 +55,8 @@ def test_training_steps_match_the_programs_optimizer():
     params = models.make_weights(c, 9)
     batches = [np.asarray(jax.random.randint(jax.random.PRNGKey(i), (2, 33), 0, 512)) for i in range(3)]
     opt = dict(b1=0.9, b2=0.95, eps=1e-8, weight_decay=0.1, clip=1.0, decay_steps=100_000, lr=1e-3, warmup=2)
-    got = ref_train.follow(jax.tree.map(lambda x: x.astype(jnp.float32), params), batches, c, opt)
+    got = ref_train.follow(kinds.reference(c).mean_nll, jax.tree.map(lambda x: x.astype(jnp.float32), params),
+                           batches, c, opt)
 
     optimizer = tl.make_optimizer(lr=1e-3, warmup=2)
     state, p, losses = optimizer.init(params), params, []
