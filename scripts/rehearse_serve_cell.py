@@ -1,0 +1,81 @@
+"""Compile a serving cell's programs for a described ``v5e:2x2`` chip from shapes alone,
+whatever pools its model kind has, and print ``memory_analysis`` of each. No chip time,
+nothing runs, never reported as a chip run.
+
+    JAX_PLATFORMS=cpu python3 scripts/rehearse_serve_cell.py kimi-vl-a3b-serve-backlog [rows x width ...]
+
+``benchmark/rehearse_compile.py::serve_cell`` builds K/V pools by hand and so cannot
+describe a latent pool; this asks ``generate.init_kv_pools`` for the pools' shapes.
+The process sees only the CPU, so the backend question every kernel's
+``kernel_eligible`` asks is answered "tpu" here, as the chip would answer it.
+"""
+
+from __future__ import annotations
+
+import os
+import sys
+
+os.environ.setdefault("TPU_LOG_DIR", "disabled")
+os.environ.setdefault("JAX_PLATFORMS", "cpu")
+sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+
+import jax  # noqa: E402
+import jax.numpy as jnp  # noqa: E402
+from jax.sharding import SingleDeviceSharding  # noqa: E402
+
+from benchmark.lib import models, spec, traffic as traffic_lib  # noqa: E402
+from benchmark.rehearse_compile import report, shapes_of  # noqa: E402
+
+
+def main() -> None:
+    from jax.experimental import topologies
+
+    from torchx_tpu.models import generate as gen
+    from torchx_tpu.serve import engine as eng
+
+    cell = spec.load_cell(sys.argv[1])
+    only = {tuple(int(x) for x in a.split("x")) for a in sys.argv[2:]}
+    topo = topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
+    one = SingleDeviceSharding(topo.devices[0])
+    jax.default_backend = lambda: "tpu"  # what kernel_eligible will be told on the chip
+    config, mix, dep = cell.config, cell.traffic, cell.config["deployment"]
+    cfg = models.program_config(config, max_seq=int(dep["max_seq"]))
+    slots, bs = int(dep["max_slots"]), int(dep["block_size"])
+    per_slot = -(-cfg.max_seq // bs)
+    n_blocks = 1 + slots * max(1, per_slot // 2)
+    sds = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=one)  # noqa: E731
+    is_leaf = lambda x: isinstance(x, tuple) and isinstance(x[0], tuple)  # noqa: E731
+    params = shapes_of(config, jnp.bfloat16, jax.tree.map(lambda _: one, models.weight_shapes(config), is_leaf=is_leaf))
+    pools = jax.tree.map(lambda p: sds(p.shape, p.dtype), jax.eval_shape(lambda: gen.init_kv_pools(cfg, n_blocks, bs)))
+    i32, f32 = jnp.int32, jnp.float32
+
+    def decode(params, tokens, positions, tables, pools, seeds, temps):  # noqa: ANN001
+        return gen.paged_decode_step(params, tokens, positions, tables, pools, cfg,
+                                     eng._fold_keys(seeds, positions), temps)
+
+    def prefill(params, tokens, pl, sl, tables, pools, seeds, temps):  # noqa: ANN001
+        return gen.paged_prefill_chunk(params, tokens, pl, sl, tables, pools, cfg,
+                                       eng._fold_keys(seeds, pl + sl - 1), temps)
+
+    if not only:
+        c = jax.jit(decode, donate_argnums=(4,)).lower(
+            params, sds((slots,), i32), sds((slots,), i32), sds((slots, per_slot), i32), pools,
+            sds((slots,), i32), sds((slots,), f32)).compile()
+        report(f"{cell.name}: decode step ({slots} slots, {n_blocks} blocks)", c)
+        text = c.as_text()
+        print("  kernels:", sorted({n for n in ("paged_mla_decode", "paged_attention_decode", "gmm") if n in text}))
+    plan = traffic_lib.build_schedule(mix, 1, 45, config["vocab_size"])
+    widths = traffic_lib.prefill_widths(plan, mix, bs)
+    rows_all = [1 << i for i in range(int(dep["max_prefill_batch"]).bit_length()) if 1 << i <= int(dep["max_prefill_batch"])]
+    for rows in rows_all:
+        for w in widths:
+            if only and (rows, w) not in only:
+                continue
+            c = jax.jit(prefill, donate_argnums=(5,)).lower(
+                params, sds((rows, w), i32), sds((rows,), i32), sds((rows,), i32),
+                sds((rows, per_slot), i32), pools, sds((rows,), i32), sds((rows,), f32)).compile()
+            report(f"{cell.name}: prefill rows {rows} x width {w}", c)
+
+
+if __name__ == "__main__":
+    main()

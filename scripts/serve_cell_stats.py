@@ -1,0 +1,46 @@
+"""One run of a serving cell as ``benchmark/run.py`` makes it, with the engine's own
+counters printed as the window's engine stops: ``preemptions``, ``kv_bytes_per_token``,
+blocks in use, the prefix cache's hits and evictions. The harness hands its readers
+neither ``engine.stats()`` nor the requests (PERF.md 7.2 (c)); this is how PR 27 read
+the preemptions of ``kimi-vl-a3b-serve-backlog``. Not a tool of the benchmark.
+
+    chiprun -- python3 scripts/serve_cell_stats.py --workload <cell> --seed <n> --seconds 45
+"""
+
+from __future__ import annotations
+
+import argparse
+import json
+import os
+import sys
+import time
+
+T_START = time.monotonic()
+sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+
+
+def main() -> int:
+    ap = argparse.ArgumentParser(description=__doc__)
+    ap.add_argument("--workload", required=True)
+    ap.add_argument("--seed", type=int, default=0)
+    ap.add_argument("--seconds", type=float, required=True)
+    args = ap.parse_args()
+    from benchmark.run import run_cell
+    from torchx_tpu.serve import engine as eng
+
+    stop = eng.ServeEngine.stop
+
+    def stop_and_tell(self, *a, **kw):  # noqa: ANN001, ANN002, ANN003, ANN202
+        s = self.stats()
+        keep = ("preemptions", "kv_bytes_per_token", "kv_blocks_used", "kv_blocks_free", "requests_done", "steps", "prefix_cache")
+        print("engine stats at stop:", json.dumps({k: s[k] for k in keep if k in s}), flush=True)
+        return stop(self, *a, **kw)
+
+    eng.ServeEngine.stop = stop_and_tell
+    out = run_cell(args.workload, args.seed, args.seconds, False, t_start=T_START)
+    print(json.dumps(out), flush=True)
+    return 0
+
+
+if __name__ == "__main__":
+    sys.exit(main())

@@ -250,3 +250,44 @@ def test_kernel_compiles_for_the_chip(one_chip, bpr, dtype):
     # the pools go in as they lie in HBM: no relayout of 67 MB a layer in front of the kernel
     pool_type = f"{jnp.dtype(dtype).name.replace('bfloat16', 'bf16').replace('float32', 'f32')}[{nb},"
     assert not [ln for ln in text.splitlines() if " copy(" in ln and pool_type in ln]
+
+
+# The latent-attention decode kernel and the grouped matmul of the expert layers (PR 27),
+# at the widths of the kimi-vl-a3b-serve-backlog cell. Kept in this file: the worker that
+# holds the TPU's library is the one that runs it.
+
+
+def test_latent_kernel_compiles_for_the_chip(one_chip):
+    from torchx_tpu.ops import paged_mla as pm
+    from torchx_tpu.ops import paged_mla_kernel as pmk
+
+    slots, h, bs, nb, bpr, rank, width = 64, 16, 16, 8449, 264, 512, 640
+    shape = lambda s, d: jax.ShapeDtypeStruct(s, d, sharding=one_chip)  # noqa: E731
+    pool = shape((nb, bs, width), jnp.bfloat16)
+    assert pm.kernel_eligible((slots, h, width), pool.shape, rank, jnp.dtype(jnp.bfloat16), pool.dtype, "tpu")
+    compiled = jax.jit(functools.partial(pmk.paged_mla_pallas, rank=rank, scale=192**-0.5)).lower(
+        shape((slots, h, width), jnp.bfloat16), pool, shape((slots, bpr), jnp.int32), shape((slots,), jnp.int32)
+    ).compile()  # fmt: skip
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text
+    # the pool goes in as it lies in HBM: no relayout of 168 MB a layer in front of the kernel
+    assert not [ln for ln in text.splitlines() if " copy(" in ln and f"bf16[{nb}," in ln]
+
+
+@pytest.mark.parametrize("m,k,n", [
+    pytest.param(384, 2048, 1408, id="decode-gate-up"),
+    pytest.param(384, 1408, 2048, id="decode-down"),
+    pytest.param(49152, 2048, 1408, id="widest-prefill-gate-up"),
+])  # fmt: skip
+def test_grouped_matmul_compiles_for_the_chip(one_chip, m, k, n):
+    from jax.experimental.pallas.ops.tpu.megablox import gmm
+
+    from torchx_tpu.ops import grouped_matmul as gm
+
+    shape = lambda s, d: jax.ShapeDtypeStruct(s, d, sharding=one_chip)  # noqa: E731
+    assert gm.kernel_eligible((m, k), (64, k, n), jnp.dtype(jnp.bfloat16), jnp.dtype(jnp.bfloat16), "tpu")
+    tiling = gm._tiling(m, k, n, 2, groups=64)
+    compiled = jax.jit(lambda l, r, g: gmm(l, r, g, preferred_element_type=jnp.bfloat16, tiling=tiling)).lower(
+        shape((m, k), jnp.bfloat16), shape((64, k, n), jnp.bfloat16), shape((64,), jnp.int32)
+    ).compile()  # fmt: skip
+    assert "tpu_custom_call" in compiled.as_text()

@@ -18,7 +18,7 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 
-from torchx_tpu.models import llama
+from torchx_tpu.models import llama, mla
 from torchx_tpu.obs import hot
 from torchx_tpu.ops.norms import rms_norm
 from torchx_tpu.ops.paged_attention import (
@@ -31,13 +31,19 @@ from torchx_tpu.ops.quant import maybe_matmul as mm
 from torchx_tpu.ops.rope import apply_rope, rope_frequencies
 
 KVCache = dict[str, jnp.ndarray]  # {"k": [L,b,S,kvh,hd], "v": ...}
-KVPools = dict[str, jnp.ndarray]  # {"k": [L,num_blocks,block_size,kvh,hd]}
+# every leaf [L_group, num_blocks, block_size, ...]: {"k", "v"} of [.., kvh, hd],
+# or one latent pool a layer group, {group: [.., cache_width]} (init_kv_pools)
+KVPools = dict[str, jnp.ndarray]
 
 
 def init_kv_cache(
     cfg: llama.LlamaConfig, batch: int, max_seq: int
 ) -> KVCache:
     """Zeroed [layers, batch, max_seq, kv_heads, head_dim] K/V buffers."""
+    if cfg.kv_lora_rank:
+        raise NotImplementedError(
+            "latent attention is served through the paged path (ServeEngine), not the dense cache"
+        )
     shape = (cfg.n_layers, batch, max_seq, cfg.n_kv_heads, cfg.head_dim)
     return {
         "k": jnp.zeros(shape, dtype=cfg.dtype),
@@ -331,16 +337,56 @@ def generate_stream(
 # RNG stream, and temperature so unrelated requests share one jitted step.
 
 
+def layer_group_sizes(cfg: llama.LlamaConfig) -> dict[str, int]:
+    """Layers in each group of the parameter tree (``llama.layer_groups``),
+    in the order they run."""
+    nd = getattr(cfg, "n_dense_layers", 0)
+    return {"dense_layers": nd, "layers": cfg.n_layers - nd} if nd else {"layers": cfg.n_layers}
+
+
 def init_kv_pools(
     cfg: llama.LlamaConfig, num_blocks: int, block_size: int
 ) -> KVPools:
-    """Zeroed paged K/V pools, ``[layers, num_blocks, block_size, kvh, hd]``
-    (block 0 is the trash block — see :mod:`torchx_tpu.ops.paged_attention`)."""
+    """Zeroed paged pools (block 0 is the trash block — see
+    :mod:`torchx_tpu.ops.paged_attention`). Grouped-query attention: K and V,
+    ``[layers, num_blocks, block_size, kvh, hd]`` each. Latent attention: one
+    pool a layer group under the group's name, ``[group's layers, num_blocks,
+    block_size, cache_width]``, a row a token. Every leaf leads with ``[layers
+    of a group, num_blocks, block_size]``: the engine allocates, copies,
+    exports and imports blocks over the tree without knowing which it holds."""
+    if cfg.kv_lora_rank:
+        return {
+            group: jnp.zeros((n, num_blocks, block_size, cfg.cache_width), dtype=cfg.dtype)
+            for group, n in layer_group_sizes(cfg).items()
+        }
     shape = (cfg.n_layers, num_blocks, block_size, cfg.n_kv_heads, cfg.head_dim)
     return {
         "k": jnp.zeros(shape, dtype=cfg.dtype),
         "v": jnp.zeros(shape, dtype=cfg.dtype),
     }
+
+
+def export_blocks(pools: KVPools, blocks: jnp.ndarray) -> tuple[jnp.ndarray, jnp.ndarray]:
+    """Blocks ``blocks`` of every layer as the ``(k, v)`` pair a hand-off
+    carries (:class:`~torchx_tpu.serve.kv_transfer.KvPayload`): ``[L, n,
+    block_size, ...]`` each. Latent pools travel as ``k``, the groups' layers
+    one after another as they run, beside a ``v`` of no width."""
+    if "k" in pools:
+        return pools["k"][:, blocks], pools["v"][:, blocks]
+    k = jnp.concatenate([pool[:, blocks] for pool in pools.values()])
+    return k, jnp.zeros((*k.shape[:3], 0), k.dtype)
+
+
+def import_blocks(pools: KVPools, blocks: jnp.ndarray, k, v) -> KVPools:  # noqa: ANN001
+    """Write a hand-off's ``(k, v)`` (:func:`export_blocks`) into ``blocks``."""
+    if "k" in pools:
+        new = {"k": k, "v": v}
+        return {name: pool.at[:, blocks].set(jnp.asarray(new[name], pool.dtype)) for name, pool in pools.items()}
+    out, at = {}, 0
+    for name, pool in pools.items():
+        out[name] = pool.at[:, blocks].set(jnp.asarray(k[at : at + pool.shape[0]], pool.dtype))
+        at += pool.shape[0]
+    return out
 
 
 def _rope_rows(x: jnp.ndarray, cos: jnp.ndarray, sin: jnp.ndarray) -> jnp.ndarray:
@@ -382,22 +428,46 @@ def _paged_layer_step(
     v_pool: jnp.ndarray,
 ) -> tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
     slots = x.shape[0]
-    h, kvh, hd = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
     with jax.named_scope(hot.NORM):
         attn_in = rms_norm(x, layer["attn_norm"], cfg.norm_eps)
     with jax.named_scope(hot.ATTN):
-        q = _rope_rows(mm(attn_in, layer["wq"]).reshape(slots, h, hd), cos, sin)
-        k = _rope_rows(mm(attn_in, layer["wk"]).reshape(slots, kvh, hd), cos, sin)
-        v = mm(attn_in, layer["wv"]).reshape(slots, kvh, hd)
-        k_pool = append_kv(k_pool, tables, positions, k)
-        v_pool = append_kv(v_pool, tables, positions, v)
-        attn = paged_attention(q, k_pool, v_pool, tables, positions + 1)
-        x = x + mm(attn.reshape(slots, 1, h * hd), layer["wo"])
+        if cfg.kv_lora_rank:  # one latent pool, handed through as k_pool
+            attn, k_pool = mla.paged_decode(cfg, layer, attn_in, cos, sin, positions, tables, k_pool)
+            x = x + attn
+        else:
+            h, kvh, hd = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
+            q = _rope_rows(mm(attn_in, layer["wq"]).reshape(slots, h, hd), cos, sin)
+            k = _rope_rows(mm(attn_in, layer["wk"]).reshape(slots, kvh, hd), cos, sin)
+            v = mm(attn_in, layer["wv"]).reshape(slots, kvh, hd)
+            k_pool = append_kv(k_pool, tables, positions, k)
+            v_pool = append_kv(v_pool, tables, positions, v)
+            attn = paged_attention(q, k_pool, v_pool, tables, positions + 1)
+            x = x + mm(attn.reshape(slots, 1, h * hd), layer["wo"])
     with jax.named_scope(hot.NORM):
         mlp_in = rms_norm(x, layer["mlp_norm"], cfg.norm_eps)
     down, _aux = llama.ffn(cfg, layer, mlp_in)
     x = x + down
     return x, k_pool, v_pool
+
+
+def _scan_groups(step, x, params: llama.Params, pools: KVPools, cfg: llama.LlamaConfig):  # noqa: ANN001, ANN202
+    """Run ``step(x, layer, k_pool, v_pool) -> (x, k_pool, v_pool)`` over every
+    layer, one scan a group of equal layers (``llama.scan_layers``), each
+    layer's slice of the pools riding along as ``xs``/``ys``. A latent pool is
+    its group's one array and goes through as ``k_pool`` with no ``v_pool``."""
+
+    def scan_step(x, layer, k_p, v_p):  # noqa: ANN001
+        x, k_p, v_p = step(x, layer, k_p, v_p)
+        return x, (k_p, v_p)
+
+    if "k" in pools:
+        (group,) = llama.layer_groups(params)  # K/V pools are one stack: a tree of two groups has latent pools
+        x, (k_new, v_new) = llama.scan_layers(cfg, scan_step, x, params[group], pools["k"], pools["v"])
+        return x, {"k": k_new, "v": v_new}
+    new = {}
+    for group in llama.layer_groups(params):
+        x, (new[group], _) = llama.scan_layers(cfg, scan_step, x, params[group], pools[group], None)
+    return x, new
 
 
 @jax.named_scope(hot.LM_HEAD)
@@ -431,26 +501,16 @@ def paged_decode_step(
     slots = tokens.shape[0]
     with jax.named_scope(hot.EMBED):
         x = params["embed"][tokens].astype(cfg.dtype)[:, None, :]  # [slots, 1, d]
-    cos_full, sin_full = rope_frequencies(cfg.head_dim, cfg.max_seq, cfg.rope_theta)
-    cos, sin = cos_full[positions], sin_full[positions]  # [slots, hd/2]
-
-    def scan_step(carry, layer_and_pools):  # noqa: ANN001
-        x = carry
-        layer, k_p, v_p = layer_and_pools
-        x, k_p, v_p = _paged_layer_step(
-            cfg, cos, sin, positions, tables, x, layer, k_p, v_p
-        )
-        return x, (k_p, v_p)
-
-    with jax.named_scope(hot.LAYERS):
-        x, (k_new, v_new) = jax.lax.scan(
-            scan_step, x, (params["layers"], pools["k"], pools["v"])
-        )
+    cos_full, sin_full = rope_frequencies(cfg.rope_dim, cfg.max_seq, cfg.rope_theta)
+    cos, sin = cos_full[positions], sin_full[positions]  # [slots, rope/2]
+    x, pools = _scan_groups(
+        functools.partial(_paged_layer_step, cfg, cos, sin, positions, tables), x, params, pools, cfg
+    )
     with jax.named_scope(hot.NORM):
         x = rms_norm(x, params["final_norm"], cfg.norm_eps)[:, 0, :]  # [slots, d]
     logits = _lm_head_rows(params, x, cfg)
     nxt = _sample_rows(logits, keys, temps)
-    return nxt, {"k": k_new, "v": v_new}
+    return nxt, pools
 
 
 def paged_prefill(
@@ -513,17 +573,23 @@ def _paged_chunk_layer_step(
     v_pool: jnp.ndarray,
 ) -> tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
     b, t, _ = x.shape
-    h, kvh, hd = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
     with jax.named_scope(hot.NORM):
         attn_in = rms_norm(x, layer["attn_norm"], cfg.norm_eps)
     with jax.named_scope(hot.ATTN):
-        q = _rope_chunk(mm(attn_in, layer["wq"]).reshape(b, t, h, hd), cos, sin)
-        k = _rope_chunk(mm(attn_in, layer["wk"]).reshape(b, t, kvh, hd), cos, sin)
-        v = mm(attn_in, layer["wv"]).reshape(b, t, kvh, hd)
-        k_pool = scatter_kv_chunk(k_pool, tables, positions, k, valid)
-        v_pool = scatter_kv_chunk(v_pool, tables, positions, v, valid)
-        attn = paged_attention_chunk(q, k_pool, v_pool, tables, positions)
-        x = x + mm(attn.reshape(b, t, h * hd), layer["wo"])
+        if cfg.kv_lora_rank:  # one latent pool, handed through as k_pool
+            attn, k_pool = mla.paged_prefill(
+                cfg, layer, attn_in, cos, sin, positions, valid, tables, k_pool
+            )
+            x = x + attn
+        else:
+            h, kvh, hd = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
+            q = _rope_chunk(mm(attn_in, layer["wq"]).reshape(b, t, h, hd), cos, sin)
+            k = _rope_chunk(mm(attn_in, layer["wk"]).reshape(b, t, kvh, hd), cos, sin)
+            v = mm(attn_in, layer["wv"]).reshape(b, t, kvh, hd)
+            k_pool = scatter_kv_chunk(k_pool, tables, positions, k, valid)
+            v_pool = scatter_kv_chunk(v_pool, tables, positions, v, valid)
+            attn = paged_attention_chunk(q, k_pool, v_pool, tables, positions)
+            x = x + mm(attn.reshape(b, t, h * hd), layer["wo"])
     with jax.named_scope(hot.NORM):
         mlp_in = rms_norm(x, layer["mlp_norm"], cfg.norm_eps)
     down, _aux = llama.ffn(cfg, layer, mlp_in)
@@ -560,26 +626,17 @@ def paged_prefill_chunk(
     b, t = tokens.shape
     with jax.named_scope(hot.EMBED):
         x = params["embed"][tokens].astype(cfg.dtype)  # [b, t, d]
-    cos_full, sin_full = rope_frequencies(cfg.head_dim, cfg.max_seq, cfg.rope_theta)
+    cos_full, sin_full = rope_frequencies(cfg.rope_dim, cfg.max_seq, cfg.rope_theta)
     positions = prefix_lens[:, None] + jnp.arange(t, dtype=jnp.int32)[None, :]
     pos_safe = jnp.clip(positions, 0, cfg.max_seq - 1)
-    cos, sin = cos_full[pos_safe], sin_full[pos_safe]  # [b, t, hd/2]
+    cos, sin = cos_full[pos_safe], sin_full[pos_safe]  # [b, t, rope/2]
     valid = jnp.arange(t)[None, :] < suffix_lens[:, None]
-
-    def scan_step(carry, layer_and_pools):  # noqa: ANN001
-        x = carry
-        layer, k_p, v_p = layer_and_pools
-        x, k_p, v_p = _paged_chunk_layer_step(
-            cfg, cos, sin, positions, valid, tables, x, layer, k_p, v_p
-        )
-        return x, (k_p, v_p)
-
-    with jax.named_scope(hot.LAYERS):
-        x, (k_new, v_new) = jax.lax.scan(
-            scan_step, x, (params["layers"], pools["k"], pools["v"])
-        )
+    x, pools = _scan_groups(
+        functools.partial(_paged_chunk_layer_step, cfg, cos, sin, positions, valid, tables),
+        x, params, pools, cfg,
+    )  # fmt: skip
     with jax.named_scope(hot.NORM):
         x = rms_norm(x, params["final_norm"], cfg.norm_eps)  # [b, t, d]
     last = x[jnp.arange(b), suffix_lens - 1]  # [b, d]
     logits = _lm_head_rows(params, last, cfg)
-    return _sample_rows(logits, keys, temps), {"k": k_new, "v": v_new}
+    return _sample_rows(logits, keys, temps), pools

@@ -105,8 +105,21 @@ class LlamaConfig:
     # launch time); "interpret" runs the same kernels in the Pallas
     # interpreter (CPU parity tests only — slow)
     kernels: str = "reference"
+    # multi-head latent attention (models/mla.py) when kv_lora_rank > 0: a
+    # token's keys and values of all heads are one normed latent of this
+    # width plus one rotary key of qk_rope_dim shared by the heads; each
+    # head's query is (qk_nope_dim, qk_rope_dim) wide and its value
+    # v_head_dim. 0 keeps grouped-query attention over n_kv_heads.
+    kv_lora_rank: int = 0
+    qk_nope_dim: int = 0
+    qk_rope_dim: int = 0
+    v_head_dim: int = 0
 
     def __post_init__(self) -> None:
+        if self.kv_lora_rank and not (self.qk_nope_dim and self.qk_rope_dim and self.v_head_dim):
+            raise ValueError(
+                "latent attention needs qk_nope_dim, qk_rope_dim and v_head_dim"
+            )
         if self.int8_scope not in ("all", "ffn"):
             raise ValueError(
                 f"int8_scope must be 'all' or 'ffn', got {self.int8_scope!r}"
@@ -122,6 +135,37 @@ class LlamaConfig:
         """Per-head projection width (dim / n_heads)."""
         return self.dim // self.n_heads
 
+    @property
+    def rope_dim(self) -> int:
+        """Width of what the rotary embedding turns in a head."""
+        return self.qk_rope_dim if self.kv_lora_rank else self.head_dim
+
+    @property
+    def cache_width(self) -> int:
+        """Values a token holds in one layer's cache: K and V of every cache
+        head, or one row of the latent and its rotary key, padded with zeros
+        to whole 128-value lanes (512 + 64 -> 640: the TPU's tiling pads the
+        row so in HBM whatever the shape says, and a row of whole lanes is
+        what a block copy and a matmul can take)."""
+        if self.kv_lora_rank:
+            return -(-(self.kv_lora_rank + self.qk_rope_dim) // 128) * 128
+        return 2 * self.n_kv_heads * self.head_dim
+
+    def attention_param_count(self) -> int:
+        """Matmul weights of one layer's attention."""
+        d, h = self.dim, self.n_heads
+        if self.kv_lora_rank:
+            r = self.kv_lora_rank
+            return (
+                d * h * (self.qk_nope_dim + self.qk_rope_dim)  # wq
+                + d * (r + self.qk_rope_dim)  # w_kva
+                + r * h * (self.qk_nope_dim + self.v_head_dim)  # w_kvb
+                + h * self.v_head_dim * d  # wo
+                + r  # kv_norm
+            )
+        hd = self.head_dim
+        return d * h * hd + 2 * d * self.n_kv_heads * hd + h * hd * d
+
     def flops_per_token(self) -> float:
         """Training FLOPs/token (fwd+bwd), 6N + attention quadratic term."""
         n_params = self.param_count()
@@ -136,11 +180,8 @@ class LlamaConfig:
     def param_count(self) -> int:
         """Exact parameter count for this shape (layers + embeddings)."""
         d, f, v = self.dim, self.ffn_dim, self.vocab_size
-        hd = self.head_dim
         per_layer = (
-            d * self.n_heads * hd  # wq
-            + 2 * d * self.n_kv_heads * hd  # wk, wv
-            + self.n_heads * hd * d  # wo
+            self.attention_param_count()
             + 3 * d * f  # gate, up, down
             + 2 * d  # norms
         )
@@ -222,14 +263,27 @@ def init_params(cfg: LlamaConfig, key: jax.Array) -> Params:
         ).astype(cfg.dtype)
 
     ks = jax.random.split(k_layers, 7)
-    params: Params = {
-        "embed": norm_init(k_embed, (cfg.vocab_size, d), d),
-        "layers": {
-            "attn_norm": jnp.ones((L, d), dtype=cfg.dtype),
+    if cfg.kv_lora_rank:
+        r, dn, dr, dv = cfg.kv_lora_rank, cfg.qk_nope_dim, cfg.qk_rope_dim, cfg.v_head_dim
+        attn = {
+            "wq": norm_init(ks[0], (L, d, h * (dn + dr)), d),
+            "w_kva": norm_init(ks[1], (L, d, r + dr), d),
+            "kv_norm": jnp.ones((L, r), dtype=cfg.dtype),
+            "w_kvb": norm_init(ks[2], (L, r, h * (dn + dv)), r),
+            "wo": norm_init(ks[3], (L, h * dv, d), h * dv),
+        }
+    else:
+        attn = {
             "wq": norm_init(ks[0], (L, d, h * hd), d),
             "wk": norm_init(ks[1], (L, d, kvh * hd), d),
             "wv": norm_init(ks[2], (L, d, kvh * hd), d),
             "wo": norm_init(ks[3], (L, h * hd, d), h * hd),
+        }
+    params: Params = {
+        "embed": norm_init(k_embed, (cfg.vocab_size, d), d),
+        "layers": {
+            "attn_norm": jnp.ones((L, d), dtype=cfg.dtype),
+            **attn,
             "mlp_norm": jnp.ones((L, d), dtype=cfg.dtype),
             "w_gate": norm_init(ks[4], (L, d, f), d),
             "w_up": norm_init(ks[5], (L, d, f), d),
@@ -252,16 +306,30 @@ def param_specs(cfg: LlamaConfig, pp: bool = False) -> Params:
     contiguous run of layers), else stays unsharded.
     """
     layer_axis = "pp" if pp else None
+    if cfg.kv_lora_rank:
+        # the latent is every head's: its down-projection and norm stay
+        # whole over tp, the per-head up-projection splits there
+        attn = {
+            "wq": P(layer_axis, "fsdp", "tp"),
+            "w_kva": P(layer_axis, "fsdp", None),
+            "kv_norm": P(layer_axis, None),
+            "w_kvb": P(layer_axis, None, "tp"),
+            "wo": P(layer_axis, "tp", "fsdp"),
+        }
+    else:
+        attn = {
+            "wq": P(layer_axis, "fsdp", "tp"),
+            "wk": P(layer_axis, "fsdp", "tp"),
+            "wv": P(layer_axis, "fsdp", "tp"),
+            "wo": P(layer_axis, "tp", "fsdp"),
+        }
     specs: Params = {
         # vocab axis unsharded: a gather over a vocab-sharded table forces
         # the SPMD partitioner into full rematerialization; dim shards fine
         "embed": P(None, "fsdp"),
         "layers": {
             "attn_norm": P(layer_axis, None),
-            "wq": P(layer_axis, "fsdp", "tp"),
-            "wk": P(layer_axis, "fsdp", "tp"),
-            "wv": P(layer_axis, "fsdp", "tp"),
-            "wo": P(layer_axis, "tp", "fsdp"),
+            **attn,
             "mlp_norm": P(layer_axis, None),
             "w_gate": P(layer_axis, "fsdp", "tp"),
             "w_up": P(layer_axis, "fsdp", "tp"),
@@ -272,6 +340,38 @@ def param_specs(cfg: LlamaConfig, pp: bool = False) -> Params:
     if not cfg.tie_embeddings:
         specs["lm_head"] = P("fsdp", "tp")
     return specs
+
+
+def layer_groups(params: Params) -> tuple[str, ...]:
+    """The parameter tree's groups of equal layers, in the order they run:
+    each is one ``[L_group, ...]`` stack, scanned by itself."""
+    return ("dense_layers", "layers") if "dense_layers" in params else ("layers",)
+
+
+#: an expert layer's leaves that a dropless dispatch reads through its grouped matmul
+_EXPERT_WEIGHTS = ("w_gate", "w_up", "w_down")
+
+
+def scan_layers(cfg: LlamaConfig, step, x, stack: Params, *more):  # noqa: ANN001, ANN201
+    """``lax.scan`` of ``step(x, layer, *a layer's slice of each of more) -> (x,
+    ys)`` over one group's stack of equal layers. A layer is its slice of every
+    leaf, except that the routed experts of a dropless expert layer go in whole,
+    with ``layer["layer_index"]`` beside them: their consumer is a kernel
+    (:mod:`torchx_tpu.ops.grouped_matmul`), which would be handed a copy of the
+    slice, and can read its layer where it lies instead. -> (x, stacked ys)."""
+    n = jax.tree.leaves(stack)[0].shape[0]
+    dropless = "w_router" in stack and getattr(cfg, "capacity_factor", 1.0) <= 0
+    whole = {k: stack[k] for k in _EXPERT_WEIGHTS} if dropless else {}
+    sliced = {k: w for k, w in stack.items() if k not in whole}
+
+    def body(x, xs):  # noqa: ANN001, ANN202
+        i, layer, *rest = xs
+        if whole:
+            layer = dict(layer, **whole, layer_index=i)
+        return step(x, layer, *rest)
+
+    with jax.named_scope(hot.LAYERS):
+        return jax.lax.scan(body, x, (jnp.arange(n, dtype=jnp.int32), sliced, *more))
 
 
 def model_fns(cfg: LlamaConfig):
@@ -319,10 +419,12 @@ def _constraint(x: jnp.ndarray, mesh: Optional[Mesh], *spec) -> jnp.ndarray:
 def ffn(
     cfg: LlamaConfig, layer: Params, mlp_in: jnp.ndarray
 ) -> tuple[jnp.ndarray, jnp.ndarray]:
-    """The FFN half of a layer: dense SwiGLU, or the GShard MoE dispatch
-    when the config carries experts. -> (down, aux). Shared by the training
-    forward and the KV-cache decode path so the two can never diverge."""
-    if getattr(cfg, "n_experts", 0):
+    """The FFN half of a layer: dense SwiGLU, or the expert layer when the
+    layer carries a router (a stack may lead with dense layers before its
+    expert layers: the layer's own tree says which it is). -> (down, aux).
+    Shared by the training forward and the KV-cache decode path so the two
+    can never diverge."""
+    if "w_router" in layer:
         if cfg.int8_matmuls:
             import warnings
 
@@ -350,6 +452,63 @@ def ffn(
     return down, jnp.zeros((AUX_LEN,), jnp.float32)  # aux vector: dense = zeros
 
 
+def _gqa_attention(
+    cfg: LlamaConfig,
+    mesh: Optional[Mesh],
+    cos: jnp.ndarray,
+    sin: jnp.ndarray,
+    attn_in: jnp.ndarray,  # [b, s, d], normed
+    layer: Params,
+) -> jnp.ndarray:
+    """Grouped-query attention of one layer, projections included: -> [b, s, d]."""
+    b, s, _ = attn_in.shape
+    h, kvh, hd = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
+    i8_attn = cfg.int8_matmuls and cfg.int8_scope == "all"
+    q = maybe_matmul(attn_in, layer["wq"], int8_training=i8_attn).reshape(b, s, h, hd)
+    k = maybe_matmul(attn_in, layer["wk"], int8_training=i8_attn).reshape(b, s, kvh, hd)
+    v = maybe_matmul(attn_in, layer["wv"], int8_training=i8_attn).reshape(b, s, kvh, hd)
+    q = apply_rope(q, cos, sin)
+    k = apply_rope(k, cos, sin)
+    if cfg.use_ring_attention and mesh is not None and mesh.shape.get("sp", 1) > 1:
+        with jax.named_scope(hot.ATTN_KERNEL):
+            attn_out = ring_attention(q, k, v, mesh)
+    else:
+        attn_out = None
+        if cfg.kernels != "reference":
+            from torchx_tpu.ops.fused import flash_attention as fused_flash
+
+            # None when gating fails (shape/platform/mesh): stock path below
+            with jax.named_scope(hot.ATTN_KERNEL):
+                attn_out = fused_flash(
+                    q,
+                    k,
+                    v,
+                    causal=True,
+                    kernels=cfg.kernels,
+                    block_q=cfg.attn_block_q,
+                    block_kv=cfg.attn_block_kv,
+                    mesh=mesh,
+                )
+        if attn_out is None:
+            attn_out = attention(  # names itself attn_kernel
+                q,
+                k,
+                v,
+                causal=True,
+                impl=cfg.attn_impl,
+                block_q=cfg.attn_block_q,
+                block_kv=cfg.attn_block_kv,
+                mesh=mesh,
+            )
+    # named so remat policies can SAVE the kernel output: the attention
+    # kernels are not dot_generals, so "dots" alone recomputes the whole
+    # flash/splash forward in the backward pass (see "dots_attn")
+    attn_out = checkpoint_name(attn_out, "attn_out")
+    return maybe_matmul(
+        attn_out.reshape(b, s, h * hd), layer["wo"], int8_training=i8_attn
+    )
+
+
 def _layer(
     cfg: LlamaConfig,
     mesh: Optional[Mesh],
@@ -365,61 +524,20 @@ def _layer(
     attention inside a pipeline stage): x holds only this device's shard of
     positions, so the RoPE frequencies are computed locally from the
     shard's global offset."""
-    b, s, d = x.shape
-    h, kvh, hd = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
     if cos is None:
-        start = jax.lax.axis_index("sp") * s
-        cos, sin = rope_frequencies(hd, s, cfg.rope_theta, start=start)
+        start = jax.lax.axis_index("sp") * x.shape[1]
+        cos, sin = rope_frequencies(cfg.rope_dim, x.shape[1], cfg.rope_theta, start=start)
 
     # attention block
-    i8 = cfg.int8_matmuls
-    i8_attn = i8 and cfg.int8_scope == "all"
     with jax.named_scope(hot.NORM):
         attn_in = rms_norm(x, layer["attn_norm"], cfg.norm_eps, mesh=mesh)
     with jax.named_scope(hot.ATTN):
-        q = maybe_matmul(attn_in, layer["wq"], int8_training=i8_attn).reshape(b, s, h, hd)
-        k = maybe_matmul(attn_in, layer["wk"], int8_training=i8_attn).reshape(b, s, kvh, hd)
-        v = maybe_matmul(attn_in, layer["wv"], int8_training=i8_attn).reshape(b, s, kvh, hd)
-        q = apply_rope(q, cos, sin)
-        k = apply_rope(k, cos, sin)
-        if cfg.use_ring_attention and mesh is not None and mesh.shape.get("sp", 1) > 1:
-            with jax.named_scope(hot.ATTN_KERNEL):
-                attn_out = ring_attention(q, k, v, mesh)
-        else:
-            attn_out = None
-            if cfg.kernels != "reference":
-                from torchx_tpu.ops.fused import flash_attention as fused_flash
+        if cfg.kv_lora_rank:
+            from torchx_tpu.models import mla
 
-                # None when gating fails (shape/platform/mesh): stock path below
-                with jax.named_scope(hot.ATTN_KERNEL):
-                    attn_out = fused_flash(
-                        q,
-                        k,
-                        v,
-                        causal=True,
-                        kernels=cfg.kernels,
-                        block_q=cfg.attn_block_q,
-                        block_kv=cfg.attn_block_kv,
-                        mesh=mesh,
-                    )
-            if attn_out is None:
-                attn_out = attention(  # names itself attn_kernel
-                    q,
-                    k,
-                    v,
-                    causal=True,
-                    impl=cfg.attn_impl,
-                    block_q=cfg.attn_block_q,
-                    block_kv=cfg.attn_block_kv,
-                    mesh=mesh,
-                )
-        # named so remat policies can SAVE the kernel output: the attention
-        # kernels are not dot_generals, so "dots" alone recomputes the whole
-        # flash/splash forward in the backward pass (see "dots_attn")
-        attn_out = checkpoint_name(attn_out, "attn_out")
-        attn_out = maybe_matmul(
-            attn_out.reshape(b, s, h * hd), layer["wo"], int8_training=i8_attn
-        )
+            attn_out = mla.attention_full(cfg, layer, attn_in, cos, sin)
+        else:
+            attn_out = _gqa_attention(cfg, mesh, cos, sin, attn_in, layer)
     if cfg.kernels != "reference":
         from torchx_tpu.ops.fused import rms_norm_residual
 
@@ -542,11 +660,15 @@ def features_from_embeddings(
     if ring_in_pp:
         cos = sin = None
     else:
-        cos, sin = rope_frequencies(cfg.head_dim, s, cfg.rope_theta)
+        cos, sin = rope_frequencies(cfg.rope_dim, s, cfg.rope_theta)
 
     body = _remat(functools.partial(_layer, cfg, mesh, cos, sin), cfg)
 
     if pp > 1:
+        if "dense_layers" in params:
+            raise NotImplementedError(
+                "pipeline parallelism over a stack that leads with dense layers"
+            )
         # pipeline the layer stack over the pp axis (embedding/head stay
         # outside the pipeline, replicated over pp)
         import math as _math
@@ -583,12 +705,13 @@ def features_from_embeddings(
             ]
         )
     else:
-        def scan_step(x, layer_slice):  # noqa: ANN001
-            x, aux = body(x, layer_slice)
-            return x, aux
-
-        with jax.named_scope(hot.LAYERS):
-            x, aux_per_layer = jax.lax.scan(scan_step, x, params["layers"])
+        # one scan a group of equal layers: leading dense layers, where
+        # the tree has them, then the stack proper
+        aux_groups = []
+        for group in layer_groups(params):
+            x, aux_group = scan_layers(cfg, body, x, params[group])
+            aux_groups.append(aux_group)
+        aux_per_layer = jnp.concatenate(aux_groups)
         # [L, AUX_LEN] per-layer aux: balance sums over layers (matches
         # the Switch loss), the monitoring stats average
         aux_total = jnp.stack(

@@ -13,6 +13,16 @@ A dedicated ``ep`` axis means ep and tp size independently — tp=1, ep=8
 runs a small MoE expert-parallel without tensor parallelism; at ep=1 the
 layout degenerates to experts-over-tp. Capacity overflow tokens are
 dropped (standard GShard semantics) — size capacity_factor accordingly.
+
+A model of many small experts (64 of width 1,408, six a token) cannot pay
+for capacity buffers: to drop nothing every expert would compute every
+token. ``capacity_factor = 0`` says the model drops no routing, and
+:func:`moe_ffn` then sorts the (token, choice) rows by expert and runs one
+grouped matmul over the experts held (:mod:`torchx_tpu.ops.grouped_matmul`),
+whatever the imbalance. The router's scoring (softmax, or sigmoid with a
+selection bias that chooses and never weighs), the scale on the routed sum,
+a shared expert beside the routed ones and leading dense layers are fields
+of the config, not functions of their own.
 """
 
 from __future__ import annotations
@@ -36,15 +46,49 @@ class MoEConfig(llama.LlamaConfig):
     # Switch/GShard load-balancing auxiliary loss coefficient: pushes the
     # router toward uniform expert utilization (0 disables)
     router_aux_coef: float = 0.01
+    # width of one routed expert; 0 = ffn_dim (Mixtral: every expert is a
+    # whole FFN). ffn_dim stays the width of a dense layer's SwiGLU
+    expert_ffn_dim: int = 0
+    # shared experts: one SwiGLU of n_shared_experts * expert width that
+    # every token takes, added to the routed sum
+    n_shared_experts: int = 0
+    # "softmax": probabilities over the experts, the top_k renormalised.
+    # "sigmoid": a score an expert, the top_k of score + router_bias chosen,
+    # weighed by their scores alone over their sum, times routed_scale
+    router_score: str = "softmax"
+    router_bias: bool = False
+    routed_scale: float = 1.0
+    # leading dense layers ahead of the expert layers (of n_layers in all):
+    # the tree then has two groups, "dense_layers" and "layers"
+    n_dense_layers: int = 0
+
+    def __post_init__(self) -> None:
+        super().__post_init__()
+        if self.router_score not in ("softmax", "sigmoid"):
+            raise ValueError(f"router_score must be 'softmax' or 'sigmoid', got {self.router_score!r}")
+        if not 0 <= self.n_dense_layers < self.n_layers:
+            raise ValueError("n_dense_layers must leave at least one expert layer")
+
+    @property
+    def expert_width(self) -> int:
+        """Intermediate width of one routed expert."""
+        return self.expert_ffn_dim or self.ffn_dim
+
+    def _expert_layer_extra(self, experts: int) -> int:
+        """What an expert layer holds beyond a dense layer's count, with
+        ``experts`` routed experts counted."""
+        d = self.dim
+        return (
+            3 * d * self.expert_width * (experts + self.n_shared_experts)
+            + d * self.n_experts  # router
+            + (self.n_experts if self.router_bias else 0)
+            - 3 * d * self.ffn_dim  # in place of the dense SwiGLU
+        )
 
     def param_count(self) -> int:
         """Exact parameter count (dense shapes + per-expert FFNs)."""
-        dense = super().param_count()
-        # replace the dense FFN with E experts + router
-        ffn = 3 * self.dim * self.ffn_dim
-        return dense + self.n_layers * (
-            (self.n_experts - 1) * ffn + self.dim * self.n_experts
-        )
+        n_expert_layers = self.n_layers - self.n_dense_layers
+        return super().param_count() + n_expert_layers * self._expert_layer_extra(self.n_experts)
 
     def flops_per_token(self) -> float:
         """MoE FLOPs count only the top_k ACTIVE experts per token."""
@@ -53,11 +97,8 @@ class MoEConfig(llama.LlamaConfig):
 
     def active_param_count(self) -> int:
         """Params touched per token (top_k experts) — the MFU-relevant N."""
-        ffn = 3 * self.dim * self.ffn_dim
-        dense = super().param_count()
-        return dense + self.n_layers * (
-            (self.top_k - 1) * ffn + self.dim * self.n_experts
-        )
+        n_expert_layers = self.n_layers - self.n_dense_layers
+        return super().param_count() + n_expert_layers * self._expert_layer_extra(self.top_k)
 
 
 def moe_tiny(**overrides: Any) -> MoEConfig:
@@ -103,37 +144,61 @@ CONFIGS = {"moe_tiny": moe_tiny, "mixtral_8x7b": mixtral_8x7b_shape}
 
 
 def init_params(cfg: MoEConfig, key: jax.Array) -> llama.Params:
-    """Dense-llama params with the FFN weights expanded to [L, E, ...] and a
-    router added."""
+    """Dense-llama params with the FFN weights of the expert layers expanded
+    to [L, E, ...], a router (and its selection bias) and the shared expert
+    added. With leading dense layers the stack splits into two groups:
+    ``dense_layers`` keeps the first ``n_dense_layers`` as they are."""
     params = llama.init_params(cfg, key)
-    L, E, d, f = cfg.n_layers, cfg.n_experts, cfg.dim, cfg.ffn_dim
-    k_router, k_g, k_u, k_d = jax.random.split(jax.random.fold_in(key, 17), 4)
+    nd = cfg.n_dense_layers
+    L, E, d, f = cfg.n_layers - nd, cfg.n_experts, cfg.dim, cfg.expert_width
+    k_router, k_g, k_u, k_d, k_s = jax.random.split(jax.random.fold_in(key, 17), 5)
 
     def init(key, shape, in_dim):  # noqa: ANN001
         return (
             jax.random.normal(key, shape, dtype=jnp.float32) * (in_dim**-0.5)
         ).astype(cfg.dtype)
 
+    if nd:
+        params["dense_layers"] = jax.tree.map(lambda w: w[:nd], params["layers"])
+        params["layers"] = jax.tree.map(lambda w: w[nd:], params["layers"])
     layers = params["layers"]
     layers["w_router"] = init(k_router, (L, d, E), d)
+    if cfg.router_bias:
+        layers["router_bias"] = jnp.zeros((L, E), dtype=cfg.dtype)
     layers["w_gate"] = init(k_g, (L, E, d, f), d)
     layers["w_up"] = init(k_u, (L, E, d, f), d)
     layers["w_down"] = init(k_d, (L, E, f, d), f)
+    if cfg.n_shared_experts:
+        fs = cfg.n_shared_experts * f
+        ks = jax.random.split(k_s, 3)
+        layers["ws_gate"] = init(ks[0], (L, d, fs), d)
+        layers["ws_up"] = init(ks[1], (L, d, fs), d)
+        layers["ws_down"] = init(ks[2], (L, fs, d), fs)
     return params
 
 
 def param_specs(cfg: MoEConfig, pp: bool = False) -> llama.Params:
     """Expert axis shards over ``("ep", "tp")`` combined (expert
     parallelism, independent of tensor-parallel size); within-expert dims
-    shard over ``fsdp`` like the dense model; the stacked layer axis shards
-    over ``pp`` when pipeline parallelism is on."""
+    shard over ``fsdp`` like the dense model; the shared expert shards like
+    a dense FFN; the stacked layer axis shards over ``pp`` when pipeline
+    parallelism is on."""
     layer_axis = "pp" if pp else None
     expert_axes = ("ep", "tp")
     specs = llama.param_specs(cfg, pp=pp)
-    specs["layers"]["w_router"] = P(layer_axis, "fsdp", None)
-    specs["layers"]["w_gate"] = P(layer_axis, expert_axes, "fsdp", None)
-    specs["layers"]["w_up"] = P(layer_axis, expert_axes, "fsdp", None)
-    specs["layers"]["w_down"] = P(layer_axis, expert_axes, None, "fsdp")
+    if cfg.n_dense_layers:
+        specs["dense_layers"] = dict(specs["layers"])
+    layers = specs["layers"]
+    layers["w_router"] = P(layer_axis, "fsdp", None)
+    if cfg.router_bias:
+        layers["router_bias"] = P(layer_axis, None)
+    layers["w_gate"] = P(layer_axis, expert_axes, "fsdp", None)
+    layers["w_up"] = P(layer_axis, expert_axes, "fsdp", None)
+    layers["w_down"] = P(layer_axis, expert_axes, None, "fsdp")
+    if cfg.n_shared_experts:
+        layers["ws_gate"] = P(layer_axis, "fsdp", "tp")
+        layers["ws_up"] = P(layer_axis, "fsdp", "tp")
+        layers["ws_down"] = P(layer_axis, "tp", "fsdp")
     return specs
 
 
@@ -152,41 +217,31 @@ def shard_params(params: llama.Params, cfg: MoEConfig, mesh) -> llama.Params:  #
 # -- MoE FFN ----------------------------------------------------------------
 
 
-def moe_ffn(
-    cfg: MoEConfig,
-    layer: llama.Params,  # one layer's slice (with w_router/w_gate/w_up/w_down)
-    x: jnp.ndarray,  # [b, s, d]
-) -> tuple[jnp.ndarray, jnp.ndarray]:
-    """GShard einsum dispatch: route -> dispatch to capacity slots ->
-    per-expert SwiGLU -> combine. Static shapes throughout.
+def _route(cfg: MoEConfig, layer: llama.Params, x: jnp.ndarray):
+    """-> (scores [b, s, E] f32 that sum to one or lie in (0, 1), the
+    ``top_k`` experts chosen [b, s, k], their weights [b, s, k] f32)."""
+    k = cfg.top_k
+    logits = jnp.einsum("bsd,de->bse", x, layer["w_router"], preferred_element_type=jnp.float32)
+    if cfg.router_score == "softmax":
+        scores = jax.nn.softmax(logits, axis=-1)
+        gate_vals, gate_idx = jax.lax.top_k(scores, k)
+        gate_vals = gate_vals / jnp.maximum(gate_vals.sum(axis=-1, keepdims=True), 1e-9)
+        return scores, gate_idx, gate_vals * cfg.routed_scale
+    scores = jax.nn.sigmoid(logits)
+    # the bias chooses and never weighs: an expert's weight is its own score
+    choose = scores + layer["router_bias"].astype(jnp.float32) if cfg.router_bias else scores
+    _, gate_idx = jax.lax.top_k(choose, k)
+    gate_vals = jnp.take_along_axis(scores, gate_idx, axis=-1)
+    gate_vals = gate_vals / (gate_vals.sum(axis=-1, keepdims=True) + 1e-20) * cfg.routed_scale
+    return scores, gate_idx, gate_vals
 
-    Returns (output, aux): aux is the router-health vector
-    ``[balance, entropy, overflow]`` —
 
-    * balance: the Switch-style load-balancing loss
-      ``E * Σ_e fraction_routed_e * mean_router_prob_e`` (≈1 when
-      balanced; this component, and only this, is scaled into the loss
-      by cfg.router_aux_coef),
-    * entropy: mean router-distribution entropy normalized by log(E)
-      (1 = uniform routing, →0 as the router collapses onto experts),
-    * overflow: fraction of (token, choice) routings dropped because
-      their expert's capacity buffer was full.
-
-    The trainer surfaces all three at log points."""
+def _capacity_experts(cfg: MoEConfig, layer: llama.Params, x, gate_idx, gate_vals):  # noqa: ANN001
+    """GShard einsum dispatch over fixed per-expert capacity buffers: a
+    routing past its expert's capacity is dropped. -> (out, overflow)."""
     b, s, d = x.shape
     E, k = cfg.n_experts, cfg.top_k
     capacity = max(1, int(cfg.capacity_factor * s * k / E))
-
-    with jax.named_scope(hot.MOE_ROUTER):
-        router_logits = jnp.einsum(
-            "bsd,de->bse", x, layer["w_router"], preferred_element_type=jnp.float32
-        )
-        probs = jax.nn.softmax(router_logits, axis=-1)  # [b, s, E] f32
-        gate_vals, gate_idx = jax.lax.top_k(probs, k)  # [b, s, k]
-        gate_vals = gate_vals / jnp.maximum(
-            gate_vals.sum(axis=-1, keepdims=True), 1e-9
-        )
-
     with jax.named_scope(hot.MOE_DISPATCH):
         # expert one-hot per choice: [b, s, k, E]
         choice_oh = jax.nn.one_hot(gate_idx, E, dtype=jnp.float32)
@@ -209,10 +264,82 @@ def moe_ffn(
         gate = jax.nn.silu(jnp.einsum("becd,edf->becf", expert_in, layer["w_gate"]))
         up = jnp.einsum("becd,edf->becf", expert_in, layer["w_up"])
         expert_out = jnp.einsum("becf,efd->becd", gate * up, layer["w_down"])
+    # back to tokens, gate-weighted
+    with jax.named_scope(hot.MOE_COMBINE):
+        out = jnp.einsum("bsec,becd->bsd", combine.astype(x.dtype), expert_out)
+    return out, jax.lax.stop_gradient(1.0 - within.astype(jnp.float32).mean())
+
+
+def _dropless_experts(cfg: MoEConfig, layer: llama.Params, x, gate_idx, gate_vals):  # noqa: ANN001
+    """Sorted dispatch: the (token, choice) rows in expert order, one
+    grouped matmul a projection over the experts held, every row computed
+    by its expert whatever the imbalance. -> out."""
+    from torchx_tpu.ops.grouped_matmul import grouped_matmul
+
+    b, s, d = x.shape
+    E, k = cfg.n_experts, cfg.top_k
+    rows = b * s * k
+    with jax.named_scope(hot.MOE_DISPATCH), jax.named_scope(hot.MOE_SORT):
+        expert_of = gate_idx.reshape(rows)
+        order = jnp.argsort(expert_of, stable=True)  # row r of the sorted batch is choice order[r]
+        group_sizes = jnp.zeros((E,), jnp.int32).at[expert_of].add(1)
+        sorted_in = x.reshape(b * s, d)[order // k]  # [rows, d]
+    with jax.named_scope(hot.MOE_EXPERTS):
+        # under a layer scan the experts come as the whole stack and the layer's number
+        # (llama.scan_layers): the grouped matmul reads its layer where it lies
+        at = layer.get("layer_index")
+        gate = jax.nn.silu(grouped_matmul(sorted_in, layer["w_gate"], group_sizes, at))
+        up = grouped_matmul(sorted_in, layer["w_up"], group_sizes, at)
+        sorted_out = grouped_matmul(gate * up, layer["w_down"], group_sizes, at)  # [rows, d]
+    with jax.named_scope(hot.MOE_COMBINE):
+        # back to (token, choice) order by gather, then the weighted sum in float32
+        unsorted = sorted_out[jnp.argsort(order)].reshape(b, s, k, d)
+        out = jnp.einsum("bskd,bsk->bsd", unsorted.astype(jnp.float32), gate_vals)
+    return out.astype(x.dtype)
+
+
+def moe_ffn(
+    cfg: MoEConfig,
+    layer: llama.Params,  # one layer's slice (with w_router/w_gate/w_up/w_down)
+    x: jnp.ndarray,  # [b, s, d]
+) -> tuple[jnp.ndarray, jnp.ndarray]:
+    """Route -> dispatch -> per-expert SwiGLU -> combine, plus the shared
+    expert where the config has one. ``capacity_factor > 0`` takes the
+    GShard einsum dispatch over capacity buffers (static shapes, routings
+    past capacity dropped); ``capacity_factor = 0`` the dropless sorted
+    dispatch.
+
+    Returns (output, aux): aux is the router-health vector
+    ``[balance, entropy, overflow]`` —
+
+    * balance: the Switch-style load-balancing loss
+      ``E * Σ_e fraction_routed_e * mean_router_prob_e`` (≈1 when
+      balanced; this component, and only this, is scaled into the loss
+      by cfg.router_aux_coef),
+    * entropy: mean router-distribution entropy normalized by log(E)
+      (1 = uniform routing, →0 as the router collapses onto experts),
+    * overflow: fraction of (token, choice) routings dropped because
+      their expert's capacity buffer was full (0 by construction on the
+      dropless path).
+
+    The trainer surfaces all three at log points."""
+    E = cfg.n_experts
+    with jax.named_scope(hot.MOE_ROUTER):
+        scores, gate_idx, gate_vals = _route(cfg, layer, x)
+    if cfg.capacity_factor > 0:
+        out, overflow = _capacity_experts(cfg, layer, x, gate_idx, gate_vals)
+    else:
+        out, overflow = _dropless_experts(cfg, layer, x, gate_idx, gate_vals), jnp.float32(0.0)
+    if cfg.n_shared_experts:
+        with jax.named_scope(hot.MOE_SHARED):
+            gate = jax.nn.silu(jnp.einsum("bsd,df->bsf", x, layer["ws_gate"]))
+            up = jnp.einsum("bsd,df->bsf", x, layer["ws_up"])
+            out = out + jnp.einsum("bsf,fd->bsd", gate * up, layer["ws_down"])
     with jax.named_scope(hot.MOE_ROUTER):  # router health, beside the routing itself
         # load-balancing aux: fraction of top-1 routings per expert x mean
         # router probability per expert (Switch Transformer eq. 4-6)
-        top1_oh = choice_oh[:, :, 0, :]  # [b, s, E]
+        probs = scores if cfg.router_score == "softmax" else scores / scores.sum(-1, keepdims=True)
+        top1_oh = jax.nn.one_hot(gate_idx[..., 0], E, dtype=jnp.float32)  # [b, s, E]
         frac_routed = top1_oh.mean(axis=(0, 1))  # [E]
         mean_prob = probs.mean(axis=(0, 1))  # [E]
         balance = E * jnp.sum(frac_routed * mean_prob)
@@ -222,13 +349,8 @@ def moe_ffn(
         entropy = jax.lax.stop_gradient(
             (-(p_safe * jnp.log(p_safe)).sum(-1).mean()) / jnp.log(float(E))
         )
-        overflow = jax.lax.stop_gradient(1.0 - within.astype(jnp.float32).mean())
         # order fixed by llama.AUX_BALANCE / AUX_ENTROPY / AUX_OVERFLOW
         aux = jnp.stack([balance, entropy, overflow])
-
-    # back to tokens, gate-weighted
-    with jax.named_scope(hot.MOE_COMBINE):
-        out = jnp.einsum("bsec,becd->bsd", combine.astype(x.dtype), expert_out)
     return out, aux
 
 

@@ -23,7 +23,7 @@ import jax
 
 # -- host spans: serving engine loop (one line of /host:CPU) ----------------
 
-SERVE_ADMIT = "serve.admit"  # rows, width, cached_tokens, queue_depth
+SERVE_ADMIT = "serve.admit"  # rows, width, cached_tokens, tokens (prefilled), queue_depth, kv_bytes_per_token
 SERVE_ADMIT_PLAN = "serve.admit.plan"
 SERVE_ADMIT_BUILD = "serve.admit.build"
 SERVE_PREFILL_DISPATCH = "serve.prefill.dispatch"
@@ -77,6 +77,11 @@ MOE_ROUTER = "moe_router"
 MOE_DISPATCH = "moe_dispatch"
 MOE_EXPERTS = "moe_experts"
 MOE_COMBINE = "moe_combine"
+MOE_SORT = "moe_sort"  # under moe_dispatch, dropless path: sort by expert, count each group, gather rows
+MOE_SHARED = "moe_shared"  # the shared expert beside the routed ones
+MLA_LATENT = "mla_latent"  # down-projection, latent norm, rotary key
+MLA_ABSORB = "mla_absorb"  # decode: W_kvb folded into the query and out of the result
+APPEND_LATENT = "append_latent"
 PAGED_ATTENTION = "paged_attention"
 GATHER_KV = "gather_kv"
 SCORES = "scores"
@@ -89,7 +94,8 @@ OPTIMIZER = "optimizer"
 DEVICE_SCOPES = (
     LAYERS, ATTN, ATTN_KERNEL, MLP, NORM, LM_HEAD, LOSS, EMBED, MOE_ROUTER, MOE_DISPATCH,
     MOE_EXPERTS, MOE_COMBINE, PAGED_ATTENTION, GATHER_KV, SCORES, VALUES,
-    APPEND_KV, SAMPLE, GRAD_CLIP, OPTIMIZER,
+    APPEND_KV, SAMPLE, GRAD_CLIP, OPTIMIZER, MOE_SORT, MOE_SHARED, MLA_LATENT, MLA_ABSORB,
+    APPEND_LATENT,
 )  # fmt: skip
 
 

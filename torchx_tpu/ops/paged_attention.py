@@ -69,9 +69,10 @@ def gather_kv(pool: jnp.ndarray, tables: jnp.ndarray) -> jnp.ndarray:
     slot ``i`` is ``pool[tables[i, p // bs], p % bs]``.
     """
     slots, bpr = tables.shape
-    _, bs, kvh, hd = pool.shape
+    bs = pool.shape[1]
     g = pool[tables]  # [slots, bpr, bs, kvh, hd]
-    return g.reshape(slots, bpr * bs, kvh, hd)
+    # whatever a position holds: [kvh, hd] here, one latent row in ops/paged_mla.py
+    return g.reshape(slots, bpr * bs, *pool.shape[2:])
 
 
 def kernel_eligible(

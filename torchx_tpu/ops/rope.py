@@ -41,3 +41,14 @@ def apply_rope(
     c = cos[None, :, None, :]
     s = sin[None, :, None, :]
     return jnp.concatenate((x1 * c - x2 * s, x2 * c + x1 * s), axis=-1).astype(dtype)
+
+
+def rotate(x: jnp.ndarray, cos: jnp.ndarray, sin: jnp.ndarray) -> jnp.ndarray:
+    """:func:`apply_rope`'s rotation for ``x`` ``[..., heads, rope]`` at
+    ``cos``/``sin`` ``[..., rope/2]``, one row of frequencies a token whatever
+    the leading axes are (they broadcast: ``[s, .]`` against ``[b, s, ., .]``,
+    ``[rows, .]`` against ``[rows, ., .]``)."""
+    dtype = x.dtype
+    x1, x2 = jnp.split(x.astype(jnp.float32), 2, axis=-1)
+    c, s = cos[..., None, :], sin[..., None, :]
+    return jnp.concatenate((x1 * c - x2 * s, x2 * c + x1 * s), axis=-1).astype(dtype)

@@ -162,8 +162,8 @@ class _Handoff:
     a decode slot."""
 
     req: ServeRequest
-    k: np.ndarray  # [L, n_blocks, bs, kvh, hd]
-    v: np.ndarray
+    k: np.ndarray  # [L, n_blocks, bs, kvh, hd]; latent pools: [L, n_blocks, bs, cache_width]
+    v: np.ndarray  # the same; latent pools: no width (generate.export_blocks)
     cache_len: int
     last_tok: int
 
@@ -224,6 +224,10 @@ class ServeEngine:
         self._sleep = sleep
 
         self.pools = gen.init_kv_pools(cfg, num_blocks, block_size)
+        #: bytes one cached token holds over all layers, as the pools are laid out
+        self.kv_bytes_per_token = sum(
+            p.shape[0] * math.prod(p.shape[3:]) * p.dtype.itemsize for p in jax.tree.leaves(self.pools)
+        )
         self.alloc = BlockAllocator(num_blocks)
         self.tables = SlotTables(max_slots, self.blocks_per_slot)
         self._slots: list[Optional[_SlotState]] = [None] * max_slots
@@ -253,6 +257,7 @@ class ServeEngine:
         self.requests_done = 0
         self.tokens_out = 0
         self.steps = 0
+        self.preemptions = 0  # slots evicted back to the queue under pool pressure
         #: why the loop died (a step raised), else None; a dead engine
         #: refuses work and fails the replica's health check
         self.failed: Optional[str] = None
@@ -338,7 +343,9 @@ class ServeEngine:
     ) -> ServeRequest:
         """Admit a sequence whose KV was prefilled on another replica.
 
-        ``k``/``v`` are block-granular ``[L, n, bs, kvh, hd]`` arrays
+        ``k``/``v`` are block-granular ``[L, n, bs, kvh, hd]`` arrays (a
+        latent pool's ``[L, n, bs, cache_width]`` as ``k``, beside a ``v``
+        of no width: :func:`~torchx_tpu.models.generate.export_blocks`)
         covering ``cache_len`` tokens; decode continues from ``last_tok``
         with no prefill pass. Raises :class:`EngineStopped` while
         draining — the transfer sender requeues to another decode
@@ -387,14 +394,7 @@ class ServeEngine:
                 hot.SERVE_KV_IMPORT, blocks=len(blocks), cache_len=h.cache_len
             ):
                 idx = jnp.asarray(np.asarray(blocks, np.int32))
-                self.pools = {
-                    "k": self.pools["k"].at[:, idx].set(
-                        jnp.asarray(h.k, dtype=self.pools["k"].dtype)
-                    ),
-                    "v": self.pools["v"].at[:, idx].set(
-                        jnp.asarray(h.v, dtype=self.pools["v"].dtype)
-                    ),
-                }
+                self.pools = gen.import_blocks(self.pools, idx, h.k, h.v)
             seq = list(h.req.prompt) + h.req.generated
             if self.prefix_cache is not None:
                 self.prefix_cache.insert(seq[: h.cache_len], blocks)
@@ -452,6 +452,8 @@ class ServeEngine:
                 "requests_done": self.requests_done,
                 "tokens_out": self.tokens_out,
                 "steps": self.steps,
+                "preemptions": self.preemptions,
+                "kv_bytes_per_token": self.kv_bytes_per_token,
                 "draining": self._draining,
                 "failed": self.failed,
             }
@@ -605,7 +607,7 @@ class ServeEngine:
                 )
                 seeds = np.zeros((rows,), np.int32)
                 temps = np.zeros((rows,), np.float32)
-                cached_total = 0
+                cached_total = suffix_total = 0
                 for r, a in enumerate(admitted):
                     blocks = a.cached_blocks + a.new_blocks
                     sfx = a.toks[a.cached_tokens :]
@@ -616,11 +618,14 @@ class ServeEngine:
                     seeds[r] = np.int32(np.uint32(a.req.seed & 0xFFFFFFFF))
                     temps[r] = a.req.temperature
                     cached_total += a.cached_tokens
+                    suffix_total += len(sfx)
             round_span.set_metadata(
                 rows=len(admitted),
                 width=width,
                 cached_tokens=cached_total,
+                tokens=suffix_total,
                 queue_depth=len(self._waiting),
+                kv_bytes_per_token=self.kv_bytes_per_token,
             )
 
             with hot.span(hot.SERVE_PREFILL_DISPATCH):
@@ -737,7 +742,7 @@ class ServeEngine:
     ) -> KvPayload:
         """Snapshot the prefilled K/V blocks for transfer to a decode
         replica (the ``prefill_only`` completion path)."""
-        idx = np.asarray(blocks, np.int32)
+        k, v = gen.export_blocks(self.pools, np.asarray(blocks, np.int32))
         return KvPayload(
             request_id=new_request_id(),
             tokens=list(toks),
@@ -748,8 +753,8 @@ class ServeEngine:
             seed=req.seed,
             eos_id=req.eos_id,
             block_size=self.block_size,
-            k=np.asarray(self.pools["k"][:, idx]),
-            v=np.asarray(self.pools["v"][:, idx]),
+            k=np.asarray(k),
+            v=np.asarray(v),
         )
 
     # -- decode ------------------------------------------------------------
@@ -781,14 +786,12 @@ class ServeEngine:
             self._waiting.appendleft(st.req)  # resumes via re-prefill
             obs_metrics.SERVE_QUEUE_DEPTH.set(len(self._waiting))
         obs_metrics.SERVE_PREEMPTIONS.inc()
+        self.preemptions += 1
         return True
 
     def _copy_block(self, src: int, dst: int) -> None:
         """Device-side copy of one physical block across all layers."""
-        self.pools = {
-            "k": self.pools["k"].at[:, dst].set(self.pools["k"][:, src]),
-            "v": self.pools["v"].at[:, dst].set(self.pools["v"][:, src]),
-        }
+        self.pools = jax.tree.map(lambda p: p.at[:, dst].set(p[:, src]), self.pools)
 
     def _ensure_capacity(self, slot: int, write_pos: int) -> bool:
         """Make sure ``slot`` holds a *writable* block for ``write_pos``:

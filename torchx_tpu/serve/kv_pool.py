@@ -119,8 +119,8 @@ def plan_pool(
             f"params ({param_bytes / GIB:.1f} GiB) exceed HBM budget "
             f"({hbm_bytes * headroom / GIB:.1f} GiB); no room for KV pool"
         )
-    # one block, all layers, K and V
-    block_bytes = cfg.n_layers * 2 * block_size * cfg.n_kv_heads * cfg.head_dim * itemsize
+    # one block, all layers: K and V of every cache head, or a latent row a token
+    block_bytes = cfg.n_layers * block_size * cfg.cache_width * itemsize
     num_blocks = budget // block_bytes
     blocks_per_slot = math.ceil(cfg.max_seq / block_size)
     if num_blocks < blocks_per_slot + 1:  # +1: trash block
@@ -129,9 +129,7 @@ def plan_pool(
             f"blocks; one {cfg.max_seq}-token sequence needs "
             f"{blocks_per_slot}"
         )
-    dense_seq_bytes = (
-        cfg.n_layers * 2 * cfg.max_seq * cfg.n_kv_heads * cfg.head_dim * itemsize
-    )
+    dense_seq_bytes = cfg.n_layers * cfg.max_seq * cfg.cache_width * itemsize
     dense_slots = budget // dense_seq_bytes
     if max_slots is None:
         mean_tokens = mean_tokens_per_seq or max(block_size, cfg.max_seq // 4)
