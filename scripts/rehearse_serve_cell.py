@@ -6,6 +6,8 @@ nothing runs, never reported as a chip run.
 
 ``benchmark/rehearse_compile.py::serve_cell`` builds K/V pools by hand and so cannot
 describe a latent pool; this asks ``generate.init_kv_pools`` for the pools' shapes.
+Under each program it prints what its layer loop moves of a layer's pool size or more
+(``torchx_tpu/obs/hlo.py::loop_moves``: nothing, since the pools ride the scan's carry).
 The process sees only the CPU, so the backend question every kernel's
 ``kernel_eligible`` asks is answered "tpu" here, as the chip would answer it.
 """
@@ -24,13 +26,14 @@ import jax.numpy as jnp  # noqa: E402
 from jax.sharding import SingleDeviceSharding  # noqa: E402
 
 from benchmark.lib import models, spec, traffic as traffic_lib  # noqa: E402
-from benchmark.rehearse_compile import report, shapes_of  # noqa: E402
+from benchmark.rehearse_compile import report as report_memory, shapes_of  # noqa: E402
 
 
 def main() -> None:
     from jax.experimental import topologies
 
     from torchx_tpu.models import generate as gen
+    from torchx_tpu.obs.hlo import loop_moves
     from torchx_tpu.serve import engine as eng
 
     cell = spec.load_cell(sys.argv[1])
@@ -48,6 +51,12 @@ def main() -> None:
     params = shapes_of(config, jnp.bfloat16, jax.tree.map(lambda _: one, models.weight_shapes(config), is_leaf=is_leaf))
     pools = jax.tree.map(lambda p: sds(p.shape, p.dtype), jax.eval_shape(lambda: gen.init_kv_pools(cfg, n_blocks, bs)))
     i32, f32 = jnp.int32, jnp.float32
+    layer_bytes = min(p.size // p.shape[0] * p.dtype.itemsize for p in jax.tree.leaves(pools))
+
+    def report(name, compiled):  # noqa: ANN001, ANN202
+        report_memory(name, compiled)
+        print(f"  moves of a layer's pool ({layer_bytes / 2**20:.0f} MiB) or more inside a loop:",
+              loop_moves(compiled.as_text(), layer_bytes) or "none", flush=True)  # fmt: skip
 
     def decode(params, tokens, positions, tables, pools, seeds, temps):  # noqa: ANN001
         return gen.paged_decode_step(params, tokens, positions, tables, pools, cfg,

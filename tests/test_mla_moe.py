@@ -278,6 +278,32 @@ def test_mla_kernel_matches_the_xla_function(lengths, dtype, monkeypatch):
     assert np.isfinite(np.asarray(got, np.float32)).all()
 
 
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16], ids=["float32", "bfloat16"])
+def test_mla_kernel_reads_its_layer_out_of_the_stack(dtype, monkeypatch):
+    """``layer=i`` on the stack ``[layers, num_blocks, bs, width]`` against the same call
+    on ``stack[i]`` (bit for bit) and against the XLA function at that layer: ragged
+    lengths, a slot of no length, an inactive slot whose table is all trash block."""
+    monkeypatch.setattr(pmk, "_CHUNK_ROWS", 32)
+    lengths = [40, 0, 96, 17, 1]
+    problems = [_latent_problem(lengths, seed=s, dtype=dtype) for s in range(3)]
+    q, _, tables, lens, rank = problems[0]
+    tables = tables.at[4].set(pa.TRASH_BLOCK)
+    stack = jnp.stack([p[1] for p in problems])  # a layer's rows differ; the trash block holds numbers in each
+    keep = np.asarray([0, 2, 3])  # what slots 1 (no length) and 4 (inactive) return is never read
+    tol = dict(atol=2e-6, rtol=1e-5) if dtype == jnp.float32 else dict(atol=2e-2, rtol=2e-2)
+    outs = []
+    for i in range(3):
+        got = pmk.paged_mla_pallas(q, stack, tables, lens, rank, 0.07, interpret=True, layer=jnp.int32(i))
+        one = pmk.paged_mla_pallas(q, stack[i], tables, lens, rank, 0.07, interpret=True)
+        np.testing.assert_array_equal(np.asarray(got, np.float32), np.asarray(one, np.float32))
+        want = pm.paged_mla_attention_xla(q, stack, tables, lens, rank, 0.07, jnp.int32(i))
+        np.testing.assert_array_equal(np.asarray(want), np.asarray(pm.paged_mla_attention_xla(q, stack[i], tables, lens, rank, 0.07)))
+        np.testing.assert_allclose(np.asarray(got, np.float32)[keep], np.asarray(want, np.float32)[keep], **tol)
+        assert np.isfinite(np.asarray(got, np.float32)).all()
+        outs.append(np.asarray(got, np.float32)[keep])
+    assert not np.array_equal(outs[0], outs[1]) and not np.array_equal(outs[1], outs[2])  # the layers differ
+
+
 @pytest.mark.parametrize("shapes,backend,want", [
     (((64, 16, 640), (8449, 16, 640), 512), "tpu", True),  # the cell's
     (((64, 16, 640), (8449, 16, 640), 512), "cpu", False),

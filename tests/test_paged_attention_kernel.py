@@ -125,6 +125,62 @@ def test_kernel_matches_the_xla_function(case, monkeypatch):
     np.testing.assert_allclose(got[keep], want[keep], **TOLERANCE[dtype])
 
 
+# -- the stack of a layer scan, read at a layer index ------------------------------------
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16], ids=["float32", "bfloat16"])
+def test_kernel_reads_its_layer_out_of_the_stack(dtype, monkeypatch):
+    """``layer=i`` on the stack ``[layers, num_blocks, ...]`` against the same call on
+    ``stack[i]`` (bit for bit: the same blocks, chunks and arithmetic) and against the XLA
+    function at that layer: ragged lengths, a slot of length 0, an inactive slot on the
+    trash block, which holds NaN in every layer."""
+    bs, kvh, h, bpr, layers = 16, 2, 8, 12, 3
+    monkeypatch.setattr(pk, "_CHUNK_BYTES", 4 * bs * kvh * HD * jnp.dtype(dtype).itemsize)
+    lengths = [100, 0, 17, 1, 192]
+    problems = [_problem(lengths, h, kvh, bs, bpr, dtype, seed=s) for s in range(layers)]
+    q, _, _, tables, lens = problems[0]  # one query and one set of tables; a layer's K and V differ
+    tables[3, :] = pa.TRASH_BLOCK
+    poisoned = [_poison(k, v, tables, lens, bs) for _, k, v, _, _ in problems]
+    as_dev = lambda x: jnp.asarray(x, dtype)  # noqa: E731
+    k_stack, v_stack = (as_dev(np.stack(x)) for x in zip(*poisoned))
+    clean_k, clean_v = (as_dev(np.stack([p[i] for p in problems])) for i in (1, 2))
+    q, tables, lens = as_dev(q), jnp.asarray(tables), jnp.asarray(lens)
+    keep = [0, 2, 4]  # slots 1 (no length) and 3 (inactive) return numbers nobody reads
+    for i in range(layers):
+        got = pk.paged_attention_pallas(q, k_stack, v_stack, tables, lens, interpret=True, layer=jnp.int32(i))
+        one = pk.paged_attention_pallas(q, k_stack[i], v_stack[i], tables, lens, interpret=True)
+        np.testing.assert_array_equal(np.asarray(got, np.float32), np.asarray(one, np.float32))
+        assert np.isfinite(np.asarray(got, np.float32)[keep]).all()
+        want = pa.paged_attention_xla(q, clean_k, clean_v, tables, lens, jnp.int32(i))
+        np.testing.assert_array_equal(np.asarray(want), np.asarray(pa.paged_attention_xla(q, clean_k[i], clean_v[i], tables, lens)))
+        np.testing.assert_allclose(np.asarray(got, np.float32)[keep], np.asarray(want, np.float32)[keep], **TOLERANCE[dtype])
+    assert not np.array_equal(np.asarray(got, np.float32)[keep], np.asarray(pk.paged_attention_pallas(
+        q, k_stack, v_stack, tables, lens, interpret=True, layer=0), np.float32)[keep])  # the layers differ
+
+
+def test_writes_land_in_their_layer_of_the_stack_and_nowhere_else():
+    """``append_kv`` and ``scatter_kv_chunk`` with ``layer=i`` against the one-layer form
+    on ``stack[i]``; every other layer is left as it was."""
+    rng = np.random.default_rng(2)
+    stack = jnp.asarray(rng.standard_normal((3, 7, 16, 2, 8)), jnp.float32)
+    tables = jnp.asarray([[1, 2, 3], [4, 5, 6], [0, 0, 0]], jnp.int32)
+    new = jnp.asarray(rng.standard_normal((3, 2, 8)), jnp.float32)
+    chunk = jnp.asarray(rng.standard_normal((3, 20, 2, 8)), jnp.float32)
+    positions = jnp.asarray([17, 40, 0], jnp.int32)
+    at = jnp.asarray([10, 28, 0], jnp.int32)[:, None] + jnp.arange(20, dtype=jnp.int32)[None]
+    valid = jnp.arange(20)[None] < jnp.asarray([20, 13, 0])[:, None]
+    for i in range(3):
+        for got, want in (
+            (pa.append_kv(stack, tables, positions, new, jnp.int32(i)), pa.append_kv(stack[i], tables, positions, new)),
+            (pa.scatter_kv_chunk(stack, tables, at, chunk, valid, jnp.int32(i)),
+             pa.scatter_kv_chunk(stack[i], tables, at, chunk, valid)),
+        ):  # fmt: skip
+            np.testing.assert_array_equal(got[i], want)
+            assert not np.array_equal(got[i], stack[i])
+            others = [j for j in range(3) if j != i]
+            np.testing.assert_array_equal(got[jnp.asarray(others)], stack[jnp.asarray(others)])
+
+
 # -- which path a call takes -----------------------------------------------------------
 
 MISTRAL = dict(q=(16, 32, 128), pool=(2049, 16, 8, 128))  # both benchmark configurations: 32/8 heads of 128, block 16
@@ -272,6 +328,49 @@ def test_latent_kernel_compiles_for_the_chip(one_chip):
     assert "tpu_custom_call" in text
     # the pool goes in as it lies in HBM: no relayout of 168 MB a layer in front of the kernel
     assert not [ln for ln in text.splitlines() if " copy(" in ln and f"bf16[{nb}," in ln]
+
+
+# The serving programs whole, with the pools donated: the chip's compiler keeps the stack the
+# layer scan carries where it lies (tests/test_pools_in_carry.py holds the CPU's to the same).
+
+
+@pytest.mark.parametrize("program", ["decode", "prefill"])
+@pytest.mark.parametrize("make,kernel", [
+    pytest.param(lambda: llama.llama_tiny(dim=1024, n_heads=8, n_kv_heads=8, n_layers=3, max_seq=256, dtype=jnp.bfloat16),
+                 "paged_attention_decode", id="kv-pools"),
+    pytest.param(lambda: moe.moe_tiny(
+        dim=256, n_heads=8, n_kv_heads=8, n_layers=3, max_seq=256, dtype=jnp.bfloat16, ffn_dim=256, n_experts=8, top_k=3,
+        expert_ffn_dim=128, n_shared_experts=1, router_score="sigmoid", router_bias=True, n_dense_layers=1,
+        capacity_factor=0.0, kv_lora_rank=128, qk_nope_dim=64, qk_rope_dim=64, v_head_dim=64), "paged_mla_decode",
+        id="latent-pools-two-groups"),
+])  # fmt: skip
+def test_serving_programs_keep_the_pools_where_they_lie_on_the_chip(one_chip, make, kernel, program, monkeypatch):
+    from torchx_tpu.obs.hlo import loop_moves
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")  # what every kernel_eligible will be told there
+    monkeypatch.setattr(attn_ops, "TRACED", {})
+    cfg = make()
+    slots, rows, width, bs = 8, 2, 128, 16
+    bpr = cfg.max_seq // bs
+    on_chip = lambda tree: jax.tree.map(lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip), tree)  # noqa: E731
+    shape = lambda s, d=jnp.int32: jax.ShapeDtypeStruct(s, d, sharding=one_chip)  # noqa: E731
+    params = on_chip(jax.eval_shape(lambda: llama.model_fns(cfg)[0](cfg, jax.random.PRNGKey(0))))
+    pools = on_chip(jax.eval_shape(lambda: gen.init_kv_pools(cfg, 4097, bs)))
+    if program == "decode":
+        fn = lambda p, tok, pos, tab, pl, keys, temps: gen.paged_decode_step(p, tok, pos, tab, pl, cfg, keys, temps)  # noqa: E731
+        args = (params, shape((slots,)), shape((slots,)), shape((slots, bpr)), pools,
+                shape((slots, 2), jnp.uint32), shape((slots,), jnp.float32))  # fmt: skip
+    else:
+        fn = lambda p, tok, pre, suf, tab, pl, keys, temps: gen.paged_prefill_chunk(p, tok, pre, suf, tab, pl, cfg, keys, temps)  # noqa: E731
+        args = (params, shape((rows, width)), shape((rows,)), shape((rows,)), shape((rows, bpr)), pools,
+                shape((rows, 2), jnp.uint32), shape((rows,), jnp.float32))  # fmt: skip
+    text = jax.jit(fn, donate_argnums=(len(args) - 3,)).lower(*args).compile().as_text()
+    assert attn_ops.traced("kv_pools") == "carried"
+    assert " while(" in text
+    if program == "decode":
+        assert kernel in text  # the Pallas call is in the loop, handed the stack
+    layer_bytes = min(p.size // p.shape[0] * p.dtype.itemsize for p in jax.tree.leaves(pools))
+    assert loop_moves(text, layer_bytes) == []
 
 
 @pytest.mark.parametrize("m,k,n", [

@@ -20,6 +20,7 @@ import jax.numpy as jnp
 
 from torchx_tpu.models import llama, mla
 from torchx_tpu.obs import hot
+from torchx_tpu.ops.attention import note_traced
 from torchx_tpu.ops.norms import rms_norm
 from torchx_tpu.ops.paged_attention import (
     append_kv,
@@ -424,10 +425,13 @@ def _paged_layer_step(
     tables: jnp.ndarray,  # [slots, blocks_per_slot] int32
     x: jnp.ndarray,  # [slots, 1, d]
     layer: llama.Params,
-    k_pool: jnp.ndarray,  # [num_blocks, bs, kvh, hd] this layer's pool
+    # this layer's pool [num_blocks, bs, kvh, hd], or with layer["layer_index"]
+    # (under _scan_groups) the group's stack [layers, num_blocks, ...]
+    k_pool: jnp.ndarray,
     v_pool: jnp.ndarray,
 ) -> tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
     slots = x.shape[0]
+    at = layer.get("layer_index")
     with jax.named_scope(hot.NORM):
         attn_in = rms_norm(x, layer["attn_norm"], cfg.norm_eps)
     with jax.named_scope(hot.ATTN):
@@ -439,9 +443,9 @@ def _paged_layer_step(
             q = _rope_rows(mm(attn_in, layer["wq"]).reshape(slots, h, hd), cos, sin)
             k = _rope_rows(mm(attn_in, layer["wk"]).reshape(slots, kvh, hd), cos, sin)
             v = mm(attn_in, layer["wv"]).reshape(slots, kvh, hd)
-            k_pool = append_kv(k_pool, tables, positions, k)
-            v_pool = append_kv(v_pool, tables, positions, v)
-            attn = paged_attention(q, k_pool, v_pool, tables, positions + 1)
+            k_pool = append_kv(k_pool, tables, positions, k, at)
+            v_pool = append_kv(v_pool, tables, positions, v, at)
+            attn = paged_attention(q, k_pool, v_pool, tables, positions + 1, at)
             x = x + mm(attn.reshape(slots, 1, h * hd), layer["wo"])
     with jax.named_scope(hot.NORM):
         mlp_in = rms_norm(x, layer["mlp_norm"], cfg.norm_eps)
@@ -452,21 +456,25 @@ def _paged_layer_step(
 
 def _scan_groups(step, x, params: llama.Params, pools: KVPools, cfg: llama.LlamaConfig):  # noqa: ANN001, ANN202
     """Run ``step(x, layer, k_pool, v_pool) -> (x, k_pool, v_pool)`` over every
-    layer, one scan a group of equal layers (``llama.scan_layers``), each
-    layer's slice of the pools riding along as ``xs``/``ys``. A latent pool is
-    its group's one array and goes through as ``k_pool`` with no ``v_pool``."""
+    layer, one scan a group of equal layers (``llama.scan_layers``). The group's
+    pools ride the scan's carry whole, beside ``x``: ``k_pool`` and ``v_pool``
+    are the stacks ``[layers of the group, num_blocks, ...]``, and the step
+    writes and reads them at ``layer["layer_index"]`` where they lie, so nothing
+    the size of a layer's pool is sliced out, copied or stacked back. A latent
+    pool is its group's one array and goes through as ``k_pool`` with no
+    ``v_pool``. ``ops.attention.traced("kv_pools")`` answers ``carried``."""
 
-    def scan_step(x, layer, k_p, v_p):  # noqa: ANN001
-        x, k_p, v_p = step(x, layer, k_p, v_p)
-        return x, (k_p, v_p)
+    def scan_step(carry, layer):  # noqa: ANN001
+        return step(carry[0], layer, *carry[1:]), None
 
+    note_traced("kv_pools", "carried")
     if "k" in pools:
         (group,) = llama.layer_groups(params)  # K/V pools are one stack: a tree of two groups has latent pools
-        x, (k_new, v_new) = llama.scan_layers(cfg, scan_step, x, params[group], pools["k"], pools["v"])
+        (x, k_new, v_new), _ = llama.scan_layers(cfg, scan_step, (x, pools["k"], pools["v"]), params[group])
         return x, {"k": k_new, "v": v_new}
     new = {}
     for group in llama.layer_groups(params):
-        x, (new[group], _) = llama.scan_layers(cfg, scan_step, x, params[group], pools[group], None)
+        (x, new[group], _), _ = llama.scan_layers(cfg, scan_step, (x, pools[group], None), params[group])
     return x, new
 
 
@@ -496,7 +504,11 @@ def paged_decode_step(
     (table all trash, position 0) compute garbage that lands in the trash
     block and is never read. Static shapes: one XLA compile per
     (slots, pool geometry), regardless of which requests occupy the slots.
-    Jit with ``donate_argnums`` on ``pools`` so the pool updates in place.
+    Jit with ``donate_argnums`` on ``pools`` so the pool updates in place: the
+    pools ride the layer scan's carry whole (``_scan_groups``), each layer
+    scatters its ``slots`` rows into the stack at its own index and the
+    attention kernel reads the stack at that index, so a step moves the rows it
+    appends and the blocks the slots hold, never a layer's pool.
     """
     slots = tokens.shape[0]
     with jax.named_scope(hot.EMBED):
@@ -569,10 +581,11 @@ def _paged_chunk_layer_step(
     tables: jnp.ndarray,  # [b, blocks_per_slot] int32
     x: jnp.ndarray,  # [b, t, d]
     layer: llama.Params,
-    k_pool: jnp.ndarray,
+    k_pool: jnp.ndarray,  # one layer's pool or the group's stack, as in _paged_layer_step
     v_pool: jnp.ndarray,
 ) -> tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
     b, t, _ = x.shape
+    at = layer.get("layer_index")
     with jax.named_scope(hot.NORM):
         attn_in = rms_norm(x, layer["attn_norm"], cfg.norm_eps)
     with jax.named_scope(hot.ATTN):
@@ -586,9 +599,9 @@ def _paged_chunk_layer_step(
             q = _rope_chunk(mm(attn_in, layer["wq"]).reshape(b, t, h, hd), cos, sin)
             k = _rope_chunk(mm(attn_in, layer["wk"]).reshape(b, t, kvh, hd), cos, sin)
             v = mm(attn_in, layer["wv"]).reshape(b, t, kvh, hd)
-            k_pool = scatter_kv_chunk(k_pool, tables, positions, k, valid)
-            v_pool = scatter_kv_chunk(v_pool, tables, positions, v, valid)
-            attn = paged_attention_chunk(q, k_pool, v_pool, tables, positions)
+            k_pool = scatter_kv_chunk(k_pool, tables, positions, k, valid, at)
+            v_pool = scatter_kv_chunk(v_pool, tables, positions, v, valid, at)
+            attn = paged_attention_chunk(q, k_pool, v_pool, tables, positions, at)
             x = x + mm(attn.reshape(b, t, h * hd), layer["wo"])
     with jax.named_scope(hot.NORM):
         mlp_in = rms_norm(x, layer["mlp_norm"], cfg.norm_eps)

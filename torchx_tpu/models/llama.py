@@ -352,26 +352,25 @@ def layer_groups(params: Params) -> tuple[str, ...]:
 _EXPERT_WEIGHTS = ("w_gate", "w_up", "w_down")
 
 
-def scan_layers(cfg: LlamaConfig, step, x, stack: Params, *more):  # noqa: ANN001, ANN201
-    """``lax.scan`` of ``step(x, layer, *a layer's slice of each of more) -> (x,
-    ys)`` over one group's stack of equal layers. A layer is its slice of every
-    leaf, except that the routed experts of a dropless expert layer go in whole,
-    with ``layer["layer_index"]`` beside them: their consumer is a kernel
-    (:mod:`torchx_tpu.ops.grouped_matmul`), which would be handed a copy of the
-    slice, and can read its layer where it lies instead. -> (x, stacked ys)."""
+def scan_layers(cfg: LlamaConfig, step, x, stack: Params):  # noqa: ANN001, ANN201
+    """``lax.scan`` of ``step(x, layer) -> (x, ys)`` over one group's stack of
+    equal layers, ``x`` any carry. A layer is its slice of every leaf and its
+    number in the group, ``layer["layer_index"]``: what a kernel reads (the
+    routed experts of a dropless expert layer, which go in whole beside it, for
+    :mod:`torchx_tpu.ops.grouped_matmul`; the paged pools a serving step carries
+    in ``x``) would be copied if sliced out of its stack first, and is read at
+    that index where it lies instead. -> (x, stacked ys)."""
     n = jax.tree.leaves(stack)[0].shape[0]
     dropless = "w_router" in stack and getattr(cfg, "capacity_factor", 1.0) <= 0
     whole = {k: stack[k] for k in _EXPERT_WEIGHTS} if dropless else {}
     sliced = {k: w for k, w in stack.items() if k not in whole}
 
     def body(x, xs):  # noqa: ANN001, ANN202
-        i, layer, *rest = xs
-        if whole:
-            layer = dict(layer, **whole, layer_index=i)
-        return step(x, layer, *rest)
+        i, layer = xs
+        return step(x, dict(layer, **whole, layer_index=i))
 
     with jax.named_scope(hot.LAYERS):
-        return jax.lax.scan(body, x, (jnp.arange(n, dtype=jnp.int32), sliced, *more))
+        return jax.lax.scan(body, x, (jnp.arange(n, dtype=jnp.int32), sliced))
 
 
 def model_fns(cfg: LlamaConfig):

@@ -106,16 +106,20 @@ def paged_prefill(
     ``_PREFILL_K_ROWS`` cached rows a step, as far as the block's last real
     token reaches and no further (an online softmax: running maximum, sum and
     accumulator in float32), so a round costs what its rows hold and not
-    ``max_seq``. -> (attention output ``[b, t, d]``, the pool)."""
+    ``max_seq``. Under a layer scan (``llama.scan_layers``) ``pool`` is the
+    group's whole stack ``[layers, num_blocks, ...]``, written and gathered at
+    ``layer["layer_index"]`` where it lies. -> (attention output ``[b, t,
+    d]``, the pool)."""
     b, t, _ = u.shape
     h, dv = cfg.n_heads, cfg.v_head_dim
+    pool_layer = layer.get("layer_index")
     q_nope, q_rope, cached = project(cfg, layer, u, cos, sin)
     with jax.named_scope(hot.APPEND_LATENT):
-        pool = scatter_kv_chunk(pool, tables, positions, cached, valid)
+        pool = scatter_kv_chunk(pool, tables, positions, cached, valid, pool_layer)
     note_traced("attention", "paged_mla_xla")
     with jax.named_scope(hot.PAGED_ATTENTION):
         q = jnp.concatenate((q_nope, q_rope), axis=-1)  # [b, t, h, nope + rope]
-        bs, bpr = pool.shape[1], tables.shape[1]
+        bs, bpr = pool.shape[-2], tables.shape[1]
         step_blocks = max(1, min(bpr, _PREFILL_K_ROWS // bs))
         step_rows = step_blocks * bs
         # whole steps: the blocks past the table are the trash block, past every position
@@ -128,7 +132,7 @@ def paged_prefill(
                 m, l, acc = carry
                 with jax.named_scope(hot.GATHER_KV):
                     held = jax.lax.dynamic_slice_in_dim(tables, c * step_blocks, step_blocks, axis=1)
-                    k, v = _expand(cfg, layer, gather_kv(pool, held))  # [b, step_rows, h, .]
+                    k, v = _expand(cfg, layer, gather_kv(pool, held, pool_layer))  # [b, step_rows, h, .]
                 with jax.named_scope(hot.SCORES):
                     s = (
                         jnp.einsum("bqhd,bkhd->bhqk", q_rows, k, preferred_element_type=jnp.float32)
@@ -179,19 +183,22 @@ def paged_decode(
     pool: jnp.ndarray,  # [num_blocks, bs, cache_width]
 ) -> tuple[jnp.ndarray, jnp.ndarray]:
     """Append each slot's latent row, then attend absorbed over the latent
-    rows its blocks hold. -> (attention output ``[slots, 1, d]``, the pool)."""
+    rows its blocks hold; ``pool`` is one layer's, or under a layer scan the
+    group's stack, as in :func:`paged_prefill`. -> (attention output ``[slots,
+    1, d]``, the pool)."""
     slots = u.shape[0]
     h, dn, dv, r = cfg.n_heads, cfg.qk_nope_dim, cfg.v_head_dim, cfg.kv_lora_rank
+    pool_layer = layer.get("layer_index")
     q_nope, q_rope, cached = project(cfg, layer, u[:, 0], cos, sin)
     with jax.named_scope(hot.APPEND_LATENT):
-        pool = append_kv(pool, tables, positions, cached)
+        pool = append_kv(pool, tables, positions, cached, pool_layer)
     w_kvb = layer["w_kvb"].reshape(r, h, dn + dv)
     with jax.named_scope(hot.MLA_ABSORB):
         q_lat = jnp.einsum("shn,rhn->shr", q_nope, w_kvb[..., :dn])
     q_pad = jnp.zeros((slots, h, cached.shape[-1] - r - cfg.qk_rope_dim), q_lat.dtype)
     o_lat = paged_mla_attention(
         jnp.concatenate((q_lat, q_rope, q_pad), axis=-1), pool, tables, positions + 1, r,
-        (dn + cfg.qk_rope_dim) ** -0.5,
+        (dn + cfg.qk_rope_dim) ** -0.5, pool_layer,
     )  # [slots, h, rank]
     with jax.named_scope(hot.MLA_ABSORB):
         out = jnp.einsum("shr,rhv->shv", o_lat, w_kvb[..., dn:])

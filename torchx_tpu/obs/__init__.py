@@ -63,7 +63,8 @@ session is the switch.
   ``train.checkpoint``; ``train.data_wait`` and ``train.h2d`` in the
   prefetcher;
 * device scopes: ``embed``, ``layers`` (the scan over the layer stack: its
-  own time is the slicing and stacking of weights and K/V pools), inside it
+  own time is the slicing of each layer's weights; the paged pools ride its
+  carry whole and are written and read where they lie), inside it
   ``norm``, ``attn`` (holding ``attn_kernel``, or
   ``append_kv`` and ``paged_attention`` with ``gather_kv`` / ``scores`` /
   ``values``), ``mlp`` or ``moe_router`` / ``moe_dispatch`` /

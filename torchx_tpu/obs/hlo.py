@@ -1,0 +1,99 @@
+"""Read a compiled program's optimized HLO text for what a loop moves.
+
+``jax.jit(f).lower(...).compile().as_text()`` is the program as the backend
+will run it: whether a buffer carried through a ``lax.scan`` is updated where
+it lies or sliced out, copied and written back is decided there and nowhere
+in the jaxpr. :func:`loop_moves` is the check the serving programs are held to
+(``tests/test_pools_in_carry.py``, ``scripts/rehearse_serve_cell.py``): no
+pass over a layer's paged pool inside the layer loop.
+"""
+
+from __future__ import annotations
+
+import math
+import re
+
+_COMPUTATION = re.compile(r"^(?:ENTRY )?%?([\w.\-]+) \(.*\) -> .* \{$")
+_INSTRUCTION = re.compile(r"^\s+(?:ROOT )?%?([\w.\-]+) = (.*)$")
+_ARRAY = re.compile(r"\b([a-z]+)(\d+)?\[([\d,]*)\]")
+_CALLED = re.compile(r"(?:calls|to_apply|body|condition)=%?([\w.\-]+)|branch_computations=\{([^}]*)\}")
+_MOVES = ("copy", "copy-start", "dynamic-slice", "dynamic-update-slice")
+
+
+def _bytes(type_text: str) -> int:
+    """The largest array of an HLO type (a tuple's largest element)."""
+    sizes = [0]
+    for dtype, bits, dims in _ARRAY.findall(type_text):
+        item = 1 if dtype == "pred" else int(bits or 8) // 8
+        sizes.append(item * math.prod(int(d) for d in dims.split(",") if d))
+    return max(sizes)
+
+
+def _split(rest: str) -> tuple[str, str, str]:
+    """``type opcode(operands), attributes`` -> (type, opcode, the rest)."""
+    if rest.startswith("("):  # a tuple type: to its closing parenthesis
+        depth = 0
+        for end, ch in enumerate(rest):
+            depth += (ch == "(") - (ch == ")")
+            if depth == 0:
+                break
+        type_text, rest = rest[: end + 1], rest[end + 2 :]
+    else:
+        type_text, _, rest = rest.partition(" ")
+    opcode, _, rest = rest.partition("(")
+    return type_text, opcode, rest
+
+
+def loop_moves(hlo_text: str, at_least_bytes: int) -> list[str]:
+    """The ``copy``, ``dynamic-slice`` and ``dynamic-update-slice``
+    instructions inside any ``while`` loop of the program (its body, and the
+    fusions and loops the body calls) that make a buffer of ``at_least_bytes``
+    or more: the result of a copy or a slice, the update of an update-slice
+    (its result is the operand, updated where it lies). Inside a fusion only
+    what the fusion hands out counts (its root, through bitcasts and tuples): a
+    slice that feeds the fusion's own matmul is read where it lies. ->
+    ``computation: instruction`` lines, empty when the loops move nothing that
+    large."""
+    computations: dict[str, dict[str, tuple[bool, str, str, list[str], str]]] = {}
+    current = None
+    for line in hlo_text.splitlines():
+        head = _COMPUTATION.match(line)
+        if head:
+            current = computations.setdefault(head.group(1), {})
+        elif current is not None and (m := _INSTRUCTION.match(line)):
+            type_text, opcode, rest = _split(m.group(2))
+            operands = re.findall(r"%([\w.\-]+)", rest.split("), ")[0])
+            current[m.group(1)] = ("ROOT " in line[: m.start(1)], type_text, opcode, operands, rest)
+    found, seen = [], set()
+
+    def visit(name: str, fused: bool) -> None:
+        if name in seen:
+            return
+        seen.add(name)
+        body = computations.get(name, {})
+        handed_out = set(body)
+        if fused:
+            handed_out, walk = set(), [inst for inst, (root, *_) in body.items() if root]
+            while walk:
+                inst = walk.pop()
+                handed_out.add(inst)
+                if inst in body and body[inst][2] in ("bitcast", "tuple"):
+                    walk.extend(body[inst][3])
+        for inst, (_, type_text, opcode, operands, rest) in body.items():
+            if opcode == "fusion" and inst not in handed_out:
+                continue  # its result stays inside this fusion
+            for a, b in _CALLED.findall(rest):
+                for c in filter(None, [a, *re.findall(r"[\w.\-]+", b)]):
+                    visit(c, opcode == "fusion")
+            if opcode in _MOVES and inst in handed_out:
+                moved = type_text
+                if opcode == "dynamic-update-slice" and len(operands) > 1 and operands[1] in body:
+                    moved = body[operands[1]][1]
+                if _bytes(moved) >= at_least_bytes:
+                    found.append(f"{name}: {inst} = {type_text} {opcode}")
+
+    for body in computations.values():
+        for *_, opcode, _, rest in body.values():
+            if opcode == "while" and (m := re.search(r"body=%?([\w.\-]+)", rest)):
+                visit(m.group(1), False)
+    return sorted(found)

@@ -65,7 +65,7 @@ TRAIN_CHECKPOINT = "train.checkpoint"
 
 # -- device scopes: ``with jax.named_scope(hot.ATTN): ...`` ---------------------
 
-LAYERS = "layers"  # the scan over the layer stack; its own time is the slicing and stacking
+LAYERS = "layers"  # the scan over the layer stack; its own time is the slicing of each layer's weights
 ATTN = "attn"  # projections, rope, the kernel call, output projection
 ATTN_KERNEL = "attn_kernel"
 MLP = "mlp"

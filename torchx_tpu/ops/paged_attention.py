@@ -11,7 +11,15 @@ physical blocks in one shared pool.
 This module is the device-side half: pure, jittable functions over a
 fixed ``[num_blocks, block_size, kvh, hd]`` pool per layer (one block is
 ``block_size * kvh * hd`` contiguous elements: every cache head of a
-position side by side) —
+position side by side). Every function also takes the layers' pools as one
+stack ``[layers, num_blocks, block_size, kvh, hd]`` with ``layer``, the index
+to work at: the serving programs carry the stack through their layer scan
+whole (``models/generate.py::_scan_groups``), writes scatter at ``[layer,
+block, offset]``, the XLA reads gather at ``[layer, block]`` and the kernel
+copies block ``layer * num_blocks + block`` of the stack seen flat, so no
+layer's pool is ever sliced out of the stack (a 134 MB copy a layer at the
+chat cell's sizes). One layer's pool with no ``layer`` is a stack of one
+(:func:`stack_of`): the same code, not a second path —
 
 * :func:`paged_attention` — single-query-token GQA attention of every slot
   against the positions below its length. On a TPU, where
@@ -60,19 +68,27 @@ from torchx_tpu.ops.attention import note_traced
 TRASH_BLOCK = 0
 
 
-def gather_kv(pool: jnp.ndarray, tables: jnp.ndarray) -> jnp.ndarray:
+def stack_of(pool: jnp.ndarray, layer):  # noqa: ANN001, ANN201
+    """``(stack, layer)`` as every function here works on them: one layer's
+    pool (``layer`` None) is layer 0 of a stack of one, a reshape that moves
+    nothing."""
+    return (pool[None], 0) if layer is None else (pool, layer)
+
+
+def gather_kv(pool: jnp.ndarray, tables: jnp.ndarray, layer=None) -> jnp.ndarray:  # noqa: ANN001
     """Gather one layer's pooled K (or V) into per-slot contiguous views.
 
     ``pool``: ``[num_blocks, block_size, kvh, hd]``; ``tables``:
     ``[slots, blocks_per_slot]`` int32 physical block ids. Returns
     ``[slots, blocks_per_slot * block_size, kvh, hd]`` — position ``p`` of
-    slot ``i`` is ``pool[tables[i, p // bs], p % bs]``.
+    slot ``i`` is ``pool[tables[i, p // bs], p % bs]``. With ``layer`` the
+    pool is a stack and the layer goes into the gather's own index.
     """
     slots, bpr = tables.shape
-    bs = pool.shape[1]
-    g = pool[tables]  # [slots, bpr, bs, kvh, hd]
+    stack, layer = stack_of(pool, layer)
+    g = stack[layer, tables]  # [slots, bpr, bs, kvh, hd]: one gather, no slice of the stack in front of it
     # whatever a position holds: [kvh, hd] here, one latent row in ops/paged_mla.py
-    return g.reshape(slots, bpr * bs, *pool.shape[2:])
+    return g.reshape(slots, bpr * stack.shape[2], *stack.shape[3:])
 
 
 def kernel_eligible(
@@ -108,6 +124,7 @@ def paged_attention(
     v_pool: jnp.ndarray,
     tables: jnp.ndarray,  # [slots, blocks_per_slot] int32
     lengths: jnp.ndarray,  # [slots] int32 — valid tokens (incl. current)
+    layer=None,  # noqa: ANN001 — the pools are stacks [layers, num_blocks, ...]: attend this layer's
 ) -> jnp.ndarray:
     """Single-token decode attention against the paged cache.
 
@@ -119,15 +136,15 @@ def paged_attention(
     elsewhere; ``ops.attention.traced("attention")`` tells which.
     """
     if kernel_eligible(
-        q.shape, k_pool.shape, q.dtype, k_pool.dtype, jax.default_backend()
+        q.shape, k_pool.shape[-4:], q.dtype, k_pool.dtype, jax.default_backend()
     ):
         # imported here: Pallas costs a second that no CPU process should pay
         from torchx_tpu.ops.paged_attention_kernel import paged_attention_pallas
 
         note_traced("attention", "paged_pallas")
-        return paged_attention_pallas(q, k_pool, v_pool, tables, lengths)
+        return paged_attention_pallas(q, k_pool, v_pool, tables, lengths, layer=layer)
     note_traced("attention", "paged_xla")
-    return paged_attention_xla(q, k_pool, v_pool, tables, lengths)
+    return paged_attention_xla(q, k_pool, v_pool, tables, lengths, layer)
 
 
 def paged_attention_xla(
@@ -136,6 +153,7 @@ def paged_attention_xla(
     v_pool: jnp.ndarray,
     tables: jnp.ndarray,  # [slots, blocks_per_slot] int32
     lengths: jnp.ndarray,  # [slots] int32
+    layer=None,  # noqa: ANN001
 ) -> jnp.ndarray:
     """:func:`paged_attention` in plain XLA: gather every slot's whole
     window, fold query heads onto cache heads by repetition (same as the
@@ -143,8 +161,8 @@ def paged_attention_xla(
     and the reference the kernel is tested against."""
     slots, h, d = q.shape
     with jax.named_scope(hot.GATHER_KV):
-        k = gather_kv(k_pool, tables)  # [slots, S, kvh, hd]
-        v = gather_kv(v_pool, tables)
+        k = gather_kv(k_pool, tables, layer)  # [slots, S, kvh, hd]
+        v = gather_kv(v_pool, tables, layer)
         n_rep = h // k.shape[2]
         if n_rep > 1:
             k = jnp.repeat(k, n_rep, axis=2)
@@ -169,6 +187,7 @@ def paged_attention_chunk(
     v_pool: jnp.ndarray,
     tables: jnp.ndarray,  # [slots, blocks_per_slot] int32
     positions: jnp.ndarray,  # [slots, t] int32 — absolute position of each query
+    layer=None,  # noqa: ANN001
 ) -> jnp.ndarray:
     """Multi-query-token attention against the paged cache.
 
@@ -183,8 +202,8 @@ def paged_attention_chunk(
     note_traced("attention", "paged_xla")
     slots, t, h, d = q.shape
     with jax.named_scope(hot.GATHER_KV):
-        k = gather_kv(k_pool, tables)  # [slots, S, kvh, hd]
-        v = gather_kv(v_pool, tables)
+        k = gather_kv(k_pool, tables, layer)  # [slots, S, kvh, hd]
+        v = gather_kv(v_pool, tables, layer)
         n_rep = h // k.shape[2]
         if n_rep > 1:
             k = jnp.repeat(k, n_rep, axis=2)
@@ -202,6 +221,15 @@ def paged_attention_chunk(
         return jnp.einsum("shqk,skhd->sqhd", probs, v)
 
 
+def _write_rows(pool, layer, block_ids, offsets, rows):  # noqa: ANN001, ANN202
+    """``rows[i]`` to ``[layer, block_ids[i], offsets[i]]`` of the stack where
+    it lies (one scatter; in place on a donated or carried stack). -> the pool
+    in the form it came in."""
+    stack, at = stack_of(pool, layer)
+    stack = stack.at[at, block_ids, offsets].set(rows, mode="drop")
+    return stack[0] if layer is None else stack
+
+
 @jax.named_scope(hot.APPEND_KV)
 def scatter_kv_chunk(
     pool: jnp.ndarray,  # [num_blocks, bs, kvh, hd]
@@ -209,6 +237,7 @@ def scatter_kv_chunk(
     positions: jnp.ndarray,  # [slots, t] — logical position of each new token
     new: jnp.ndarray,  # [slots, t, kvh, hd]
     valid: jnp.ndarray | None = None,  # [slots, t] bool — False: write trash
+    layer=None,  # noqa: ANN001
 ) -> jnp.ndarray:
     """Scatter a chunk of new K (or V) tokens per slot into table positions.
 
@@ -220,16 +249,14 @@ def scatter_kv_chunk(
     gather would otherwise alias a live block.
     """
     slots, t = positions.shape
-    bs = pool.shape[1]
+    bs = pool.shape[1 if layer is None else 2]
     block_idx = jnp.clip(positions // bs, 0, tables.shape[1] - 1)
     block_ids = jnp.take_along_axis(tables, block_idx, axis=1)  # [slots, t]
     if valid is not None:
         block_ids = jnp.where(valid, block_ids, TRASH_BLOCK)
     offsets = positions % bs
     flat_new = new.reshape(slots * t, *new.shape[2:])
-    return pool.at[block_ids.reshape(-1), offsets.reshape(-1)].set(
-        flat_new, mode="drop"
-    )
+    return _write_rows(pool, layer, block_ids.reshape(-1), offsets.reshape(-1), flat_new)
 
 
 @jax.named_scope(hot.APPEND_KV)
@@ -238,6 +265,7 @@ def append_kv(
     tables: jnp.ndarray,  # [slots, blocks_per_slot]
     positions: jnp.ndarray,  # [slots] — logical position being written
     new: jnp.ndarray,  # [slots, kvh, hd]
+    layer=None,  # noqa: ANN001
 ) -> jnp.ndarray:
     """Scatter one new K (or V) token per slot into its table position.
 
@@ -246,10 +274,10 @@ def append_kv(
     there don't matter because nothing masked-in ever reads it.
     """
     slots = tables.shape[0]
-    bs = pool.shape[1]
+    bs = pool.shape[1 if layer is None else 2]
     block_ids = tables[jnp.arange(slots), positions // bs]  # [slots]
     offsets = positions % bs
-    return pool.at[block_ids, offsets].set(new, mode="drop")
+    return _write_rows(pool, layer, block_ids, offsets, new)
 
 
 @jax.named_scope(hot.APPEND_KV)

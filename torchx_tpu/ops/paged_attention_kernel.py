@@ -124,8 +124,17 @@ def paged_attention_pallas(
     tables: jnp.ndarray,  # [slots, blocks_per_slot] int32
     lengths: jnp.ndarray,  # [slots] int32
     interpret: bool = False,
+    layer=None,  # noqa: ANN001
 ) -> jnp.ndarray:
     """:func:`paged_attention` as one ragged Pallas TPU kernel.
+
+    With ``layer`` the pools are the stacks ``[layers, num_blocks, bs, kvh,
+    hd]`` a layer scan carries, read where they lie: block ``b`` of layer
+    ``i`` is block ``i * num_blocks + b`` of the stack seen flat (a reshape
+    that moves nothing), so the layer goes into the block ids and the kernel
+    is the same for a stack as for one layer's pool. (As one more index of
+    every block copy it cost the latent kernel 4% of its time alone on the
+    chip: PERF.md section 6, PR 28.)
 
     One grid step per slot. The pools stay in HBM; ``tables`` and
     ``lengths`` are scalar-prefetched, and the step copies only the slot's
@@ -143,6 +152,9 @@ def paged_attention_pallas(
     in Pallas's interpreter (the CPU tests).
     """
     slots, h, hd = q.shape
+    if layer is not None:
+        tables = tables + layer * k_pool.shape[1]
+        k_pool, v_pool = (p.reshape(-1, *p.shape[2:]) for p in (k_pool, v_pool))
     _, bs, kvh, _ = k_pool.shape
     bpr = tables.shape[1]
     chunk = max(1, min(bpr, _CHUNK_BYTES // (bs * kvh * hd * k_pool.dtype.itemsize)))

@@ -6,7 +6,9 @@ row whatever the head count, zeros behind them up to ``width``, a whole
 number of 128-value lanes (:mod:`torchx_tpu.models.mla`; 512 + 64 -> 640). Block tables, the trash block and
 the allocator are those of :mod:`torchx_tpu.ops.paged_attention`; a row is
 appended and scattered by that module's ``append_kv`` / ``scatter_kv_chunk``,
-which take any row shape.
+which take any row shape. As there, every function also takes a layer group's
+pools as one stack ``[layers, num_blocks, block_size, width]`` with ``layer``,
+the index to read at: the stack a layer scan carries, never sliced.
 
 :func:`paged_mla_attention` takes each slot's query already absorbed, ``[h,
 width]`` (``q_nope W_kvb[K]^T`` beside the rotated ``q_rope``), scores
@@ -65,17 +67,18 @@ def paged_mla_attention(
     lengths: jnp.ndarray,  # [slots] int32: valid rows (incl. the current token's)
     rank: int,
     scale: float,
+    layer=None,  # noqa: ANN001 — the pool is a stack [layers, num_blocks, bs, width]: attend this layer's
 ) -> jnp.ndarray:
     """-> ``[slots, h, rank]``: per head the softmax of ``scale * q . row``
     over the slot's rows below ``lengths[i]``, times the rows' first ``rank``
     values."""
-    if kernel_eligible(q.shape, pool.shape, rank, q.dtype, pool.dtype, jax.default_backend()):
+    if kernel_eligible(q.shape, pool.shape[-3:], rank, q.dtype, pool.dtype, jax.default_backend()):
         from torchx_tpu.ops.paged_mla_kernel import paged_mla_pallas
 
         note_traced("attention", "paged_mla_pallas")
-        return paged_mla_pallas(q, pool, tables, lengths, rank, scale)
+        return paged_mla_pallas(q, pool, tables, lengths, rank, scale, layer=layer)
     note_traced("attention", "paged_mla_xla")
-    return paged_mla_attention_xla(q, pool, tables, lengths, rank, scale)
+    return paged_mla_attention_xla(q, pool, tables, lengths, rank, scale, layer)
 
 
 def paged_mla_attention_xla(
@@ -85,11 +88,12 @@ def paged_mla_attention_xla(
     lengths: jnp.ndarray,
     rank: int,
     scale: float,
+    layer=None,  # noqa: ANN001
 ) -> jnp.ndarray:
     """:func:`paged_mla_attention` in plain XLA: gather every slot's whole
     window, mask by ``lengths``."""
     with jax.named_scope(hot.GATHER_KV):
-        rows = gather_kv(pool, tables)  # [slots, S, width]
+        rows = gather_kv(pool, tables, layer)  # [slots, S, width]
     with jax.named_scope(hot.SCORES):
         logits = jnp.einsum("shc,stc->sht", q, rows, preferred_element_type=jnp.float32) * scale
         mask = jnp.arange(rows.shape[1])[None, :] < lengths[:, None]  # [slots, S]

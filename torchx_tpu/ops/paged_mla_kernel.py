@@ -117,10 +117,15 @@ def paged_mla_pallas(
     rank: int,
     scale: float,
     interpret: bool = False,
+    layer=None,  # noqa: ANN001
 ) -> jnp.ndarray:
     """:func:`~torchx_tpu.ops.paged_mla.paged_mla_attention` as one ragged
     Pallas TPU kernel, the latent twin of
     :func:`~torchx_tpu.ops.paged_attention_kernel.paged_attention_pallas`.
+
+    With ``layer`` the pool is the stack ``[layers, num_blocks, bs, width]`` a
+    layer scan carries, read where it lies: the layer goes into the block ids
+    over the stack seen flat, as in ``paged_attention_pallas``.
 
     One grid step per slot. The pool stays in HBM; ``tables`` and ``lengths``
     are scalar-prefetched, and the step copies only the slot's
@@ -135,6 +140,9 @@ def paged_mla_pallas(
     the kernel in Pallas's interpreter (the CPU tests).
     """
     slots, h, width = q.shape
+    if layer is not None:
+        tables = tables + layer * pool.shape[1]
+        pool = pool.reshape(-1, *pool.shape[2:])
     _, bs, _ = pool.shape
     bpr = tables.shape[1]
     chunk = max(1, min(bpr, _CHUNK_ROWS // bs))
