@@ -63,7 +63,7 @@ def main() -> None:
 
     os.environ.setdefault(tpx_settings.ENV_TPX_TRACE_ID, obs_trace.new_trace_id())
 
-    from torchx_tpu.examples.train_llama import train
+    from torchx_tpu.train.run import train
     from torchx_tpu.models import llama
 
     # 32 steps, log every 8: each log point is a block_until_ready

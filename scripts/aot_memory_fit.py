@@ -59,7 +59,7 @@ def main(argv: Optional[list[str]] = None) -> int:
 
     base = north_star_cfg()
     if args.config != "llama3_8b":
-        from torchx_tpu.examples.train_llama import all_configs
+        from torchx_tpu.models import all_configs
 
         base = all_configs()[args.config]()
 

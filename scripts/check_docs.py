@@ -11,6 +11,7 @@ Exit 0 = docs are buildable and current.
 
 from __future__ import annotations
 
+import os
 import re
 import subprocess
 import sys
@@ -24,11 +25,15 @@ LINK_RE = re.compile(r"\[[^\]]*\]\(([^)#\s]+)(#[^)]*)?\)")
 
 def check_generated() -> list[str]:
     errors = []
+    # the generators import the live package: this checkout's, wherever
+    # the check was started from and whether or not it is installed
+    path = os.pathsep.join(filter(None, [str(REPO), os.environ.get("PYTHONPATH")]))
     for script in ("gen_scheduler_docs.py", "gen_api_docs.py"):
         proc = subprocess.run(
             [sys.executable, str(REPO / "scripts" / script), "--check"],
             capture_output=True,
             text=True,
+            env={**os.environ, "PYTHONPATH": path},
         )
         if proc.returncode != 0:
             errors.append(

@@ -82,6 +82,10 @@ MODULES = [
     ("torchx_tpu.parallel.aot_fit", "parallel_aot_fit", "AOT HBM-fit analysis for TPU topologies"),
     ("torchx_tpu.parallel.prefetch", "parallel_prefetch", "double-buffered device input prefetch"),
     ("torchx_tpu.parallel.remat_auto", "parallel_remat_auto", "auto remat policy from AOT memory analysis"),
+    ("torchx_tpu.train.step", "train_step", "what a training step is: optimizer, TrainState, the jitted SPMD step, the state's shapes and shardings"),
+    ("torchx_tpu.train.run", "train_run", "`train()` as stages that return values: resolve, open, compile, join, first step, loop, summarize"),
+    ("torchx_tpu.train.report", "train_report", "what a training job says about itself: launch spans, heartbeats and leases, the step profiler, peak FLOP/s"),
+    ("torchx_tpu.train.data", "train_data", "token-memmap input pipeline: per-process shards, seeded resumable batches"),
     ("torchx_tpu.models.llama", "models_llama", "the Llama model family"),
     ("torchx_tpu.models.moe", "models_moe", "mixture-of-experts family"),
     ("torchx_tpu.models.generate", "models_generate", "KV-cache generation"),

@@ -39,7 +39,8 @@ def main() -> None:
     )
     args = parser.parse_args()
 
-    from torchx_tpu.examples.train_llama import all_configs, train
+    from torchx_tpu.models import all_configs
+    from torchx_tpu.train.run import train
     from torchx_tpu.parallel.mesh import MeshConfig
 
     candidates = [int(b) for b in args.blocks.split(",")]

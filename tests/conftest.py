@@ -41,3 +41,27 @@ def _isolated_registries(tmp_path, monkeypatch):
         lambda: str(tmp_path / "tpx_slurm_dirs"),
         raising=False,
     )
+
+
+def _early_stop_case(generate, max_new, prompt_len=3):
+    """A prompt on which "stop at this id" can be told from both "stop at
+    the first token" and "never stop": ``(prompt, full, cut)``, where
+    ``full = generate(prompt, max_new)`` is the prompt and its greedy
+    continuation, and ``full[cut - 1]`` is a token the continuation emits
+    for the first time at its third position or later and before its last.
+    A run given that id as EOS must return ``full[:cut]``. A continuation
+    that repeats one token (``406, 406, 406``) has no such position: every
+    id it holds stops it at its first token, so the next prompt is tried."""
+    for start in range(1, 64):
+        prompt = list(range(start, start + prompt_len))
+        full = generate(prompt, max_new)
+        for cut in range(prompt_len + 3, len(full)):
+            if full[cut - 1] not in full[: cut - 1]:
+                return prompt, full, cut
+    raise AssertionError("no prompt's continuation emits a new token third or later")
+
+
+@pytest.fixture(scope="session")
+def early_stop_case():
+    """:func:`_early_stop_case`, for the EOS tests of the three servers."""
+    return _early_stop_case

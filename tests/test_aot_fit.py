@@ -20,7 +20,6 @@ import pytest
 from torchx_tpu.parallel.aot_fit import (
     DEFAULT_HEADROOM,
     V5P_HBM_BYTES,
-    abstract_train_state,
     compile_fit,
     model_state_bytes_per_device,
     north_star_cfg,
@@ -38,7 +37,7 @@ def _mesh():
 
 class TestAbstractState:
     def test_state_shardings_cover_every_leaf(self):
-        from torchx_tpu.examples.train_llama import make_optimizer
+        from torchx_tpu.train.step import abstract_train_state, make_optimizer
         from torchx_tpu.models import llama
 
         cfg = llama.llama_tiny()

@@ -79,20 +79,20 @@ class TestUnknownDevice:
         monkeypatch.setattr(jax, "local_devices", lambda *a: [dev])
 
     def test_peak_flops_resolves_what_the_v5e_reports(self, monkeypatch):
-        from torchx_tpu.examples.train_llama import device_peak_flops
+        from torchx_tpu.train.report import device_peak_flops
 
         self.fake_device(monkeypatch, "tpu", "TPU v5 lite")
         assert device_peak_flops() == 197e12
 
     def test_peak_flops_unknown_tpu_raises(self, monkeypatch):
-        from torchx_tpu.examples.train_llama import device_peak_flops
+        from torchx_tpu.train.report import device_peak_flops
 
         self.fake_device(monkeypatch, "tpu", "TPU v9 mega")
         with pytest.raises(ValueError, match="TPU v9 mega"):
             device_peak_flops()
 
     def test_peak_flops_cpu_keeps_its_nominal_value(self):
-        from torchx_tpu.examples.train_llama import PEAK_FLOPS, device_peak_flops
+        from torchx_tpu.train.report import PEAK_FLOPS, device_peak_flops
 
         assert device_peak_flops() == PEAK_FLOPS["cpu"]
 
@@ -127,7 +127,7 @@ class TestUnknownDevice:
 
 class TestTrainerSaysWhereItRan:
     def test_results_name_device_and_traced_ops(self):
-        from torchx_tpu.examples.train_llama import train
+        from torchx_tpu.train.run import train
         from torchx_tpu.models import llama
         from torchx_tpu.parallel.mesh import MeshConfig
 

@@ -5,14 +5,11 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from torchx_tpu.examples.train_llama import (
-    init_state,
-    make_optimizer,
-    train,
-)
 from torchx_tpu.models import llama
 from torchx_tpu.parallel.checkpoint import Checkpointer
 from torchx_tpu.parallel.mesh import MeshConfig, make_mesh
+from torchx_tpu.train.run import train
+from torchx_tpu.train.step import init_state, make_optimizer
 
 
 class TestCheckpointer:

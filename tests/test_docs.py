@@ -50,7 +50,10 @@ def test_quickstart_local_path_executes(tmp_path):
 
     # redirect HOME so subprocesses' per-user registries (~/.tpx_local_apps
     # etc.) land in the scratch dir, not the developer's real home
-    env = {**os.environ, "HOME": str(tmp_path)}
+    # ... and the checkout on PYTHONPATH: the commands run in the scratch
+    # dir, where `-m torchx_tpu.cli.main` finds the package no other way
+    path = os.pathsep.join(filter(None, [str(REPO), os.environ.get("PYTHONPATH")]))
+    env = {**os.environ, "HOME": str(tmp_path), "PYTHONPATH": path}
     outputs: dict[str, str] = {}
     for lang, marker, body in quickstart_blocks():
         if lang == "python" and marker.startswith("verify-write:"):

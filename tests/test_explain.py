@@ -62,7 +62,7 @@ def test_model_shapes_match_jax_configs():
     """The honesty contract from plan.py's docstring: the arithmetic-only
     ModelShape mirror must agree exactly with the real (jax-importing)
     model configs on parameter counts."""
-    from torchx_tpu.examples.train_llama import all_configs
+    from torchx_tpu.models import all_configs
 
     cfgs = all_configs()
     for name, shape in MODEL_SHAPES.items():

@@ -599,7 +599,7 @@ class TestLineEmitter:
 class TestLaunchBreakdown:
     def test_launch_span_noop_without_trace_id(self, monkeypatch):
         from torchx_tpu import settings
-        from torchx_tpu.examples.train_llama import _launch_span
+        from torchx_tpu.train.report import _launch_span
         from torchx_tpu.obs import sinks
 
         monkeypatch.delenv(settings.ENV_TPX_TRACE_ID, raising=False)
@@ -609,7 +609,7 @@ class TestLaunchBreakdown:
 
     def test_launch_span_written_under_trace_id(self, monkeypatch):
         from torchx_tpu import settings
-        from torchx_tpu.examples.train_llama import _launch_span
+        from torchx_tpu.train.report import _launch_span
         from torchx_tpu.obs import sinks
         from torchx_tpu.obs import trace as obs_trace
 

@@ -397,6 +397,18 @@ class TestDaemonCellLifecycle:
         event = cell_daemon.store.latest("local", app_id)
         assert event is not None and event.cell == "us-east1"
 
+    def test_watch_stream_records_carry_cell(self, cell_daemon):
+        """A watch adapter knows no cell: what it observes is stamped where
+        it enters the daemon's journal, not only the daemon's own events."""
+        from torchx_tpu.control.events import StateEvent
+        from torchx_tpu.specs.api import AppState
+
+        cell_daemon.reconciler.ingest(
+            StateEvent("local", "seen-by-sidecar", AppState.RUNNING, source="sidecar")
+        )
+        event = cell_daemon.store.latest("local", "seen-by-sidecar")
+        assert event.source == "sidecar" and event.cell == "us-east1"
+
     def test_router_treats_unrehydrated_cell_as_drained(self, cell_daemon):
         handle = CellHandle(
             CellSpec(name="us-east1", addr=cell_daemon.addr),

@@ -760,7 +760,7 @@ class TestLivenessLeaseHelper:
         first-step lease exactly when lease evidence matters most (before
         ``step.window`` heartbeats start). None must degrade to the
         'step unknown' sentinel, not to no lease at all."""
-        from torchx_tpu.examples.train_llama import _renew_liveness_lease
+        from torchx_tpu.train.report import _renew_liveness_lease
 
         _renew_liveness_lease(None)
         leases = read_leases()

@@ -354,19 +354,19 @@ class TestContinuousEngineServer:
         _, b = post(f"{server_url}/v1/generate", payload)
         assert a["tokens"] == b["tokens"]
 
-    def test_eos_id_field_respected(self, server_url):
-        _, full = post(
-            f"{server_url}/v1/generate",
-            {"tokens": [[1, 2, 3]], "max_new_tokens": 6},
-        )
-        (seq,) = full["tokens"]
-        eos = seq[4]  # second generated token
-        _, cut = post(
-            f"{server_url}/v1/generate",
-            {"tokens": [[1, 2, 3]], "max_new_tokens": 6, "eos_id": eos},
-        )
-        (short,) = cut["tokens"]
-        assert short == seq[:5] and short[-1] == eos
+    def test_eos_id_field_respected(self, server_url, early_stop_case):
+        def generate(prompt, max_new, **fields):
+            _, body = post(
+                f"{server_url}/v1/generate",
+                {"tokens": [prompt], "max_new_tokens": max_new, **fields},
+            )
+            (seq,) = body["tokens"]
+            return seq
+
+        prompt, seq, cut = early_stop_case(generate, 6)
+        eos = seq[cut - 1]  # first emitted third or later
+        short = generate(prompt, 6, eos_id=eos)
+        assert short == seq[:cut] and short[-1] == eos
 
     def test_bad_engine_name_rejected(self):
         with pytest.raises(ValueError, match="engine"):

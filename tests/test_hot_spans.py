@@ -185,7 +185,7 @@ def _serving_programs(cfg):
 
 
 def _train_step(cfg):
-    from torchx_tpu.examples import train_llama as tl
+    from torchx_tpu.train import step as tl
     from torchx_tpu.parallel.mesh import MeshConfig, make_mesh
 
     mesh = make_mesh(MeshConfig(fsdp=1), devices=jax.devices()[:1])

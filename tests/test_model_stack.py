@@ -384,7 +384,7 @@ class TestLlama:
         )
 
     def test_loss_decreases(self):
-        from torchx_tpu.examples.train_llama import train
+        from torchx_tpu.train.run import train
         from torchx_tpu.parallel.mesh import MeshConfig as MC
 
         metrics = train(
@@ -437,7 +437,7 @@ class TestLlama:
     def test_llama8b_shardings_trace(self):
         """AOT-validate the full-scale 8B shardings: abstract trace of the
         train step over a 4x2 mesh — no weights materialize."""
-        from torchx_tpu.examples.train_llama import TrainState, make_optimizer
+        from torchx_tpu.train.step import TrainState, make_optimizer
 
         import optax
 
@@ -542,7 +542,7 @@ class TestTrainStepTimeKnobs:
     """The --grad-bucket-mb / --kernels / launch-anchor trainer wiring."""
 
     def _train(self, **kw):
-        from torchx_tpu.examples.train_llama import train
+        from torchx_tpu.train.run import train
         from torchx_tpu.parallel.mesh import MeshConfig as MC
 
         return train(

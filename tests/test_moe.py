@@ -142,7 +142,8 @@ class TestMoEModel:
 
     def test_moe_via_trainer(self):
         """MoE end-to-end through the shared trainer (CLI --config path)."""
-        from torchx_tpu.examples.train_llama import all_configs, train
+        from torchx_tpu.models import all_configs
+        from torchx_tpu.train.run import train
         from torchx_tpu.parallel.mesh import MeshConfig
 
         assert "moe_tiny" in all_configs() and "mixtral_8x7b" in all_configs()

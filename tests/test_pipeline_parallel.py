@@ -157,7 +157,7 @@ class TestPipelineApply:
     def test_ring_attention_inside_pp_trains(self):
         """Grads flow through the nested shard_map (GSPMD fallback) and the
         loss decreases."""
-        from torchx_tpu.examples.train_llama import train
+        from torchx_tpu.train.run import train
         from torchx_tpu.parallel.mesh import MeshConfig
 
         cfg = llama.llama_tiny(use_ring_attention=True)
@@ -173,7 +173,7 @@ class TestPipelineApply:
         assert m["loss"] < 6.2
 
     def test_pp_train_step_loss_decreases(self):
-        from torchx_tpu.examples.train_llama import train
+        from torchx_tpu.train.run import train
         from torchx_tpu.parallel.mesh import MeshConfig
 
         m = train(

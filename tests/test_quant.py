@@ -179,7 +179,7 @@ class TestInt8TrainingMatmul:
         int8_matmuls on real dp/fsdp/tp meshes, where AQT's internal
         quantize/dequantize ops get partitioned too."""
         pytest.importorskip("aqt")
-        from torchx_tpu.examples.train_llama import train
+        from torchx_tpu.train.run import train
         from torchx_tpu.models import llama
         from torchx_tpu.parallel.mesh import MeshConfig
 

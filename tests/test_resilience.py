@@ -13,6 +13,7 @@ import json
 import logging
 import os
 import random
+import shlex
 import subprocess
 import sys
 import textwrap
@@ -1157,7 +1158,7 @@ class TestAcceptance:
             app = AppDef(
                 name="crashjob",
                 roles=[Role(name="t", image="", entrypoint="sh",
-                            args=["-c", "sleep 2"])],
+                            args=["-c", "until [ -e %s ]; do sleep 0.05; done"])],
             )
             info = runner.dryrun(app, "local")
             sup = Supervisor(
@@ -1167,10 +1168,18 @@ class TestAcceptance:
             )
             sup.run()
             """
+            # the job stays open until the kill has happened: however slow
+            # the machine, the supervisor dies with its job still running
+            % shlex.quote(str(tmp_path / "supervisor_killed"))
         )
         script = tmp_path / "crash_child.py"
         script.write_text(child_src)
-        env = dict(os.environ, HOME=str(tmp_path))
+        # the child is a script outside the checkout: it finds the package
+        # on PYTHONPATH or (stderr discarded) exits before it submits
+        path = os.pathsep.join(
+            filter(None, [str(REPO_ROOT), os.environ.get("PYTHONPATH")])
+        )
+        env = dict(os.environ, HOME=str(tmp_path), PYTHONPATH=path)
         child = subprocess.Popen(
             [sys.executable, str(script)],
             cwd=str(REPO_ROOT),
@@ -1203,6 +1212,7 @@ class TestAcceptance:
         finally:
             child.kill()  # SIGKILL: no cleanup handlers run
             child.wait()
+            (tmp_path / "supervisor_killed").touch()  # now the job may end
 
         # the replica (its own session) survives the supervisor's death;
         # a fresh client reattaches to the recorded handle

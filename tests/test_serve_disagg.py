@@ -229,15 +229,17 @@ class TestDisaggParity:
             dec.stop()
             uni.stop()
 
-    def test_decode_side_respects_eos(self, tiny):
+    def test_decode_side_respects_eos(self, tiny, early_stop_case):
         cfg, params = tiny
         pre = make_engine(tiny)
         dec = make_engine(tiny)
         try:
-            full = dense_generate(params, cfg, [1, 2, 3], 8)
-            eos = full[3 + 2]  # emitted 3rd: decode side must stop there
-            got = self._disagg_generate(pre, dec, [1, 2, 3], 8, eos_id=eos)
-            assert got == full[: 3 + 3]
+            prompt, full, cut = early_stop_case(
+                lambda p, n: dense_generate(params, cfg, p, n), 8
+            )
+            eos = full[cut - 1]  # first emitted 3rd or later: decode side must stop there
+            got = self._disagg_generate(pre, dec, prompt, 8, eos_id=eos)
+            assert got == full[:cut]
         finally:
             pre.stop()
             dec.stop()

@@ -226,12 +226,14 @@ class TestServeEngine:
             assert r.wait(timeout=120)
         assert engine.steps - steps0 < 4 * 6
 
-    def test_eos_evicts_early(self, tiny, engine):
+    def test_eos_evicts_early(self, tiny, engine, early_stop_case):
         cfg, params = tiny
-        full = dense_generate(params, cfg, [1, 2, 3], 8)
-        eos = full[3 + 2]  # token the model emits 3rd; use it as EOS
-        r = engine.generate([1, 2, 3], max_new_tokens=8, eos_id=eos, timeout=120)
-        assert r.tokens == full[: 3 + 3]  # stopped right after emitting EOS
+        prompt, full, cut = early_stop_case(
+            lambda p, n: dense_generate(params, cfg, p, n), 8
+        )
+        eos = full[cut - 1]  # first emitted third or later; use it as EOS
+        r = engine.generate(prompt, max_new_tokens=8, eos_id=eos, timeout=120)
+        assert r.tokens == full[:cut]  # stopped right after emitting EOS
         assert r.generated[-1] == eos
 
     def test_sampled_determinism_and_seed_sensitivity(self, tiny, engine):

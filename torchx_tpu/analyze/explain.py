@@ -497,7 +497,7 @@ def _aot_cross_check(
         import numpy as np
         from jax.sharding import Mesh
 
-        from torchx_tpu.examples.train_llama import all_configs
+        from torchx_tpu.models import all_configs
         from torchx_tpu.parallel.aot_fit import compile_fit
         from torchx_tpu.parallel.mesh_config import AXES
 

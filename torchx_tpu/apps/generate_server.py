@@ -161,7 +161,7 @@ class GenerateService:
             raise ValueError(
                 "prefill role needs a --kv-transfer spec (decode targets)"
             )
-        from torchx_tpu.examples.train_llama import all_configs
+        from torchx_tpu.models import all_configs
 
         configs = all_configs()
         if config not in configs:
@@ -992,8 +992,8 @@ def _install_drain_handler(
     service: GenerateService,
     grace_s: float = 30.0,
 ) -> bool:
-    """Arm SIGTERM -> graceful drain (mirrors train_llama's preemption
-    handler: main thread only, previous handler semantics preserved by
+    """Arm SIGTERM -> graceful drain (mirrors the trainer's preemption
+    handler in ``train/run.py``: main thread only, previous handler semantics preserved by
     process exit). The handler thread exists because ``server.shutdown``
     must not run on the thread ``serve_forever`` occupies."""
     import signal

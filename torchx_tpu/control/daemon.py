@@ -386,7 +386,7 @@ class ControlDaemon:
         )
         self.store = JobStateStore(os.path.join(self.state_dir, "store"))
         self.rehydration["journal_jobs"] = len(self.store)
-        self.reconciler = Reconciler(store=self.store, clock=clock)
+        self.reconciler = Reconciler(store=self.store, clock=clock, cell=self.cell)
         runner.attach_reconciler(self.reconciler)
         self.root_token = secrets.token_hex(16)
         self._tokens: dict[str, str] = {self.root_token: "root"}

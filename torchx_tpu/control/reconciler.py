@@ -24,6 +24,7 @@ wait path never breaks).
 
 from __future__ import annotations
 
+import dataclasses
 import logging
 import threading
 import time
@@ -44,14 +45,19 @@ class Reconciler:
             in-memory state changes (crash ordering: disk first).
         clock: injectable monotonic clock for :meth:`wait_event` deadlines
             (the sim harness runs the reconciler on virtual time).
+        cell: the owning daemon's federation cell; stamped onto every
+            event that carries none (the watch adapters know no cell), so
+            that every journal record is cell-addressable.
     """
 
     def __init__(
         self,
         store: Optional[JobStateStore] = None,
         clock: Callable[[], float] = time.monotonic,
+        cell: str = "",
     ) -> None:
         self.store = store
+        self.cell = cell
         self._clock = clock
         self._cond = threading.Condition()
         # (scheduler, app_id) -> (seq, event); seq is a global monotonic
@@ -136,6 +142,8 @@ class Reconciler:
 
         Public so the daemon's submit path can seed SUBMITTED events and
         tests can inject transitions without a live watcher."""
+        if self.cell and not event.cell:
+            event = dataclasses.replace(event, cell=self.cell)
         if self.store is not None:
             self.store.append(event)
         with self._lock:

@@ -77,7 +77,7 @@ def main(argv: Optional[list[str]] = None) -> None:
     parser.add_argument("--ig-steps", type=int, default=16)
     args = parser.parse_args(argv)
 
-    from torchx_tpu.examples.train_llama import all_configs
+    from torchx_tpu.models import all_configs
 
     cfg = all_configs()[args.config]()
     params = llama.init_params(cfg, jax.random.PRNGKey(0))

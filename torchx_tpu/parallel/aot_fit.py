@@ -23,8 +23,14 @@ from typing import Any
 
 import jax
 import jax.numpy as jnp
-import jax.tree_util as jtu
-from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+from jax.sharding import Mesh, NamedSharding
+
+from torchx_tpu.models import all_configs
+from torchx_tpu.train.step import (
+    abstract_train_state,
+    make_optimizer,
+    make_train_step,
+)
 
 GIB = 1024**3
 
@@ -46,64 +52,6 @@ def tpu_topology_mesh(topology: str, mesh_axes: Any) -> Mesh:
 
     topo = topologies.get_topology_desc(topology, "tpu")
     return make_mesh(mesh_axes, devices=topo.devices)
-
-
-def _specs_for_state(state_shapes: Any, param_specs: Any) -> Any:
-    """PartitionSpec tree matching a TrainState shape tree.
-
-    Optimizer-state subtrees that mirror the params tree (Adam's mu/nu)
-    inherit the param specs wholesale; everything else (step counters,
-    empty states) replicates. Matching is by pytree structure, so this
-    stays correct for any optax chain whose stateful members mirror params.
-    """
-    params_treedef = jtu.tree_structure(state_shapes.params)
-
-    def rec(node: Any) -> Any:
-        try:
-            if jtu.tree_structure(node) == params_treedef:
-                return param_specs
-        except Exception:
-            pass
-        if isinstance(node, dict):
-            return {k: rec(v) for k, v in node.items()}
-        if isinstance(node, tuple) and hasattr(node, "_fields"):  # namedtuple
-            return type(node)(*(rec(c) for c in node))
-        if isinstance(node, (list, tuple)):
-            return type(node)(rec(c) for c in node)
-        return P()  # scalar / unrecognized leaf: replicated
-
-    return dataclasses.replace(
-        state_shapes,
-        params=param_specs,
-        opt_state=rec(state_shapes.opt_state),
-        step=P(),
-    )
-
-
-def abstract_train_state(cfg: Any, mesh: Mesh, optimizer: Any):
-    """TrainState of ShapeDtypeStructs carrying the training shardings."""
-    from torchx_tpu.examples.train_llama import TrainState
-    from torchx_tpu.models import llama
-
-    init_fn, specs_fn = llama.model_fns(cfg)  # dense vs MoE dispatch
-    params_shapes = jax.eval_shape(
-        lambda: init_fn(cfg, jax.random.PRNGKey(0))
-    )
-    opt_shapes = jax.eval_shape(optimizer.init, params_shapes)
-    state_shapes = TrainState(
-        params=params_shapes,
-        opt_state=opt_shapes,
-        step=jax.ShapeDtypeStruct((), jnp.int32),
-    )
-    pspecs = specs_fn(cfg, pp=mesh.shape.get("pp", 1) > 1)
-    spec_tree = _specs_for_state(state_shapes, pspecs)
-    return jax.tree.map(
-        lambda s, p: jax.ShapeDtypeStruct(
-            s.shape, s.dtype, sharding=NamedSharding(mesh, p)
-        ),
-        state_shapes,
-        spec_tree,
-    )
 
 
 @dataclasses.dataclass
@@ -136,7 +84,6 @@ def compile_fit(
     headroom: float = DEFAULT_HEADROOM,
 ) -> FitResult:
     """AOT-compile one (config, mesh, batch, seq) and read the memory fit."""
-    from torchx_tpu.examples.train_llama import make_optimizer, make_train_step
     from torchx_tpu.parallel.mesh import BATCH_SPEC
 
     cfg = dataclasses.replace(cfg, max_seq=seq)
@@ -206,7 +153,6 @@ def probe_fits(requests: list[dict[str, Any]]) -> list[dict[str, Any]]:
     result mirrors :class:`FitResult` plus the echoed request, or carries
     ``error`` — per-candidate failures never kill the batch.
     """
-    from torchx_tpu.examples.train_llama import all_configs
     from torchx_tpu.parallel.mesh import make_mesh
     from torchx_tpu.parallel.mesh_config import MeshConfig, parse_mesh_spec
 

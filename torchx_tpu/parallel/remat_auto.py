@@ -17,7 +17,7 @@ the persistent XLA compilation cache, so the winner's real compile in the
 trainer is a cache hit — the selection's marginal cost is roughly the
 compiles of the candidates that did NOT fit.
 
-The trainer (examples/train_llama.py) resolves "auto" before building the
+The trainer (train/run.py) resolves "auto" before building the
 train step and reports the chosen policy in its result dict and the
 ``step.*`` trace family; :mod:`bench` records it per bench leg.
 """

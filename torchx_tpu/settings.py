@@ -241,7 +241,7 @@ ENV_TPX_ERROR_FILE = "TPX_ERROR_FILE"
 ENV_TPX_LOG_DIR = "TPX_LOG_DIR"
 
 # Trace correlation: the client injects these at submit so in-job spans
-# (spmd_main bootstrap, train_llama heartbeats) join the client-side trace
+# (spmd_main bootstrap, the trainer's heartbeats) join the client-side trace
 # instead of starting orphan traces. See obs/trace.py.
 ENV_TPX_TRACE_ID = "TPX_TRACE_ID"
 ENV_TPX_PARENT_SPAN = "TPX_PARENT_SPAN"

@@ -9,7 +9,7 @@ forever while no step ever completes.
 This module closes that gap from the *client* side, with no new agent on
 the workers: training jobs already emit ``job.first_step``/``step.window``
 heartbeats into the session's shared ``trace.jsonl`` (see
-``examples/train_llama.py``), and may additionally renew small per-replica
+``train/report.py``), and may additionally renew small per-replica
 liveness leases via :func:`renew_lease`. :class:`GangMonitor` tails both
 between status polls and folds them into a :class:`GangVerdict`; the
 supervisor turns a ``HANG``/``PARTIAL_LOSS`` verdict into kill + classify

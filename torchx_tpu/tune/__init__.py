@@ -15,7 +15,7 @@ spending device time on configs the static analyzer can already kill:
    (:mod:`~torchx_tpu.tune.rank`: collective bytes over ICI/DCN
    bandwidth + an HBM-pressure penalty) and only the top-k run short
    seeded bench trials (``tune/measure.py`` subprocess reusing the
-   ``train_llama`` harness).
+   ``train.run.train`` harness).
 4. **Emit + recalibrate** — the winner becomes a content-digested
    **plan artifact** (:mod:`~torchx_tpu.tune.artifact`) the submit gate
    can pin (``$TPX_PLAN_ARTIFACT``, TPX706/707) and ``tpx explain`` can

@@ -2,7 +2,7 @@
 
 ``python -m torchx_tpu.tune.measure`` reads one trial spec (JSON) on
 stdin, runs a short seeded training trial through the real
-``examples/train_llama.train`` harness (the same code path bench.py
+``torchx_tpu.train.run.train`` harness (the same code path bench.py
 measures), and prints ONE JSON result line prefixed ``TUNE_METRICS ``
 on stdout. All jax imports live inside function bodies: the module
 itself stays importable under the package's jax-free lint, and only
@@ -34,7 +34,8 @@ _KEEP = (
 
 def measure(spec: dict[str, Any]) -> dict[str, Any]:
     """Run one trial and return the trimmed metrics dict."""
-    from torchx_tpu.examples.train_llama import all_configs, train
+    from torchx_tpu.models import all_configs
+    from torchx_tpu.train.run import train
     from torchx_tpu.parallel.mesh_config import MeshConfig, parse_mesh_spec
     from torchx_tpu.tune.space import Candidate
 
