@@ -58,7 +58,8 @@ def main() -> None:
         print(f"  moves of a layer's pool ({layer_bytes / 2**20:.0f} MiB) or more inside a loop:",
               loop_moves(compiled.as_text(), layer_bytes) or "none", flush=True)  # fmt: skip
 
-    def decode(params, tokens, positions, tables, pools, seeds, temps):  # noqa: ANN001
+    def decode(params, tokens, prev, positions, tables, pools, seeds, temps):  # noqa: ANN001
+        tokens = jnp.where(tokens == eng._FROM_DEVICE, prev, tokens)  # as ServeEngine's _decode merges them
         return gen.paged_decode_step(params, tokens, positions, tables, pools, cfg,
                                      eng._fold_keys(seeds, positions), temps)
 
@@ -67,8 +68,8 @@ def main() -> None:
                                        eng._fold_keys(seeds, pl + sl - 1), temps)
 
     if not only:
-        c = jax.jit(decode, donate_argnums=(4,)).lower(
-            params, sds((slots,), i32), sds((slots,), i32), sds((slots, per_slot), i32), pools,
+        c = jax.jit(decode, donate_argnums=(5,)).lower(
+            params, sds((slots,), i32), sds((slots,), i32), sds((slots,), i32), sds((slots, per_slot), i32), pools,
             sds((slots,), i32), sds((slots,), f32)).compile()
         report(f"{cell.name}: decode step ({slots} slots, {n_blocks} blocks)", c)
         text = c.as_text()

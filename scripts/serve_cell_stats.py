@@ -1,10 +1,11 @@
 """One run of a serving cell as ``benchmark/run.py`` makes it, with the engine's own
 counters printed as the window's engine stops: ``preemptions``, ``kv_bytes_per_token``,
-blocks in use, the prefix cache's hits and evictions. The harness hands its readers
+``steps_overlapped``, ``tokens_discarded`` (PR 30), blocks in use, the prefix cache's hits and
+evictions. The harness hands its readers
 neither ``engine.stats()`` nor the requests (PERF.md 7.2 (c)); this is how PR 27 read
 the preemptions of ``kimi-vl-a3b-serve-backlog``. Not a tool of the benchmark.
 
-    chiprun -- python3 scripts/serve_cell_stats.py --workload <cell> --seed <n> --seconds 45
+    chiprun -- python3 scripts/serve_cell_stats.py --workload <cell> --seed <n> --seconds 45 [--trace 1]
 """
 
 from __future__ import annotations
@@ -24,6 +25,7 @@ def main() -> int:
     ap.add_argument("--workload", required=True)
     ap.add_argument("--seed", type=int, default=0)
     ap.add_argument("--seconds", type=float, required=True)
+    ap.add_argument("--trace", type=int, default=0)
     args = ap.parse_args()
     from benchmark.run import run_cell
     from torchx_tpu.serve import engine as eng
@@ -32,12 +34,13 @@ def main() -> int:
 
     def stop_and_tell(self, *a, **kw):  # noqa: ANN001, ANN002, ANN003, ANN202
         s = self.stats()
-        keep = ("preemptions", "kv_bytes_per_token", "kv_blocks_used", "kv_blocks_free", "requests_done", "steps", "prefix_cache")
+        keep = ("preemptions", "steps_overlapped", "tokens_discarded", "kv_bytes_per_token", "kv_blocks_used",
+                "kv_blocks_free", "requests_done", "steps", "prefix_cache")  # fmt: skip
         print("engine stats at stop:", json.dumps({k: s[k] for k in keep if k in s}), flush=True)
         return stop(self, *a, **kw)
 
     eng.ServeEngine.stop = stop_and_tell
-    out = run_cell(args.workload, args.seed, args.seconds, False, t_start=T_START)
+    out = run_cell(args.workload, args.seed, args.seconds, bool(args.trace), t_start=T_START)
     print(json.dumps(out), flush=True)
     return 0
 

@@ -110,7 +110,7 @@ def test_children_lie_inside_their_parent_and_tile_it(engine_line, parent):
 @pytest.mark.parametrize(
     "name,attrs",
     [
-        (hot.SERVE_DECODE, {"step", "active"}),
+        (hot.SERVE_DECODE, {"step", "active", "steps_overlapped", "tokens_discarded"}),
         (hot.SERVE_ADMIT, {"rows", "width", "cached_tokens", "queue_depth"}),
         (hot.SERVE_DECODE_COMMIT, {"finished"}),
         (hot.SERVE_KV_IMPORT, {"blocks", "cache_len"}),
@@ -121,7 +121,12 @@ def test_span_attributes(engine_line, name, attrs):
     assert events and all(attrs <= set(ev[3]) for ev in events)
     if name == hot.SERVE_DECODE:
         steps = [ev[3]["step"] for ev in events]
-        assert steps == sorted(steps) and all(1 <= ev[3]["active"] <= 4 for ev in events)
+        # active 0: a turn that enqueues nothing and only fetches the step in flight
+        assert steps == sorted(steps) and all(0 <= ev[3]["active"] <= 4 for ev in events)
+        assert any(ev[3]["active"] for ev in events)
+        overlapped = [ev[3]["steps_overlapped"] for ev in events]
+        assert overlapped == sorted(overlapped) and overlapped[-1] > overlapped[0]
+        assert all(ev[3]["tokens_discarded"] == 0 for ev in events)  # no EOS, no pool pressure
     if name == hot.SERVE_ADMIT:
         assert {ev[3]["rows"] for ev in events} <= {1, 2} and all(ev[3]["width"] >= 16 for ev in events)
 

@@ -2,6 +2,8 @@
 decode equivalence, and the continuous-batching engine end to end (slots,
 EOS eviction, preemption under block pressure, drain)."""
 
+import threading
+
 import numpy as np
 import pytest
 
@@ -10,7 +12,7 @@ import jax.numpy as jnp
 
 from torchx_tpu.models import generate as gen, llama
 from torchx_tpu.ops.paged_attention import TRASH_BLOCK
-from torchx_tpu.serve.engine import EngineStopped, ServeEngine, ServeRequest
+from torchx_tpu.serve.engine import EngineStopped, ServeEngine, ServeRequest, _fold_keys
 from torchx_tpu.serve.kv_pool import (
     BlockAllocator,
     SlotTables,
@@ -266,21 +268,26 @@ class TestServeEngine:
 
     def test_preemption_under_block_pressure_preserves_tokens(self, tiny):
         cfg, params = tiny
-        # pool deliberately too small for 4 growing sequences: the engine
-        # must preempt the youngest and resume it, with identical output
+        # pool deliberately too small for 4 growing sequences (16 blocks
+        # where they come to need 24): the engine must preempt the youngest
+        # and resume it, with identical output
         eng = ServeEngine(
-            params, cfg, max_slots=4, block_size=8, num_blocks=20
+            params, cfg, max_slots=4, block_size=8, num_blocks=17
         ).start()
         try:
             prompts = [[i + 1, i + 2, i + 3] for i in range(4)]
             reqs = [
-                eng.submit(ServeRequest(prompt=p, max_new_tokens=24))
+                eng.submit(ServeRequest(prompt=p, max_new_tokens=40))
                 for p in prompts
             ]
             for r in reqs:
                 assert r.wait(timeout=240) and r.error is None
             for p, r in zip(prompts, reqs):
-                assert r.tokens == dense_generate(params, cfg, p, 24)
+                assert r.tokens == dense_generate(params, cfg, p, 40)
+            # the victim's step was in flight: that token was dropped, and
+            # its re-prefill drew the same one again
+            assert eng.stats()["preemptions"] > 0
+            assert eng.stats()["tokens_discarded"] > 0
         finally:
             eng.stop()
 
@@ -311,3 +318,210 @@ class TestServeEngine:
         eng = ServeEngine.from_plan(params, cfg, plan)
         assert eng.max_slots == 2 and eng.block_size == 8
         assert eng.num_blocks == plan.num_blocks
+
+
+# -- one decode step always in flight -----------------------------------------
+
+
+class _Unfetched:
+    """What the spied ``_decode`` hands the engine in place of a step's
+    tokens: converting it to numpy is the engine's fetch of that step."""
+
+    def __init__(self, n, value, log, fail=False):
+        self.n, self.value, self.log, self.fail = n, value, log, fail
+
+    def __array__(self, dtype=None, copy=None):
+        self.log.append(("fetch", self.n))
+        if self.fail:
+            raise RuntimeError("device fell over")
+        return np.asarray(self.value)
+
+
+def spy_on_decode(eng, at_dispatch=None, fail_fetch_of=None):
+    """Record ``("dispatch", n, host tokens)`` and ``("fetch", n)`` in the
+    order the engine's thread makes them; ``at_dispatch(n)`` runs on that
+    thread before step ``n`` is enqueued."""
+    log, real = [], eng._decode
+
+    def decode(params, tokens, prev, *rest):
+        n = sum(ev[0] == "dispatch" for ev in log)
+        if at_dispatch is not None:
+            at_dispatch(n)
+        log.append(("dispatch", n, np.asarray(tokens).copy()))
+        nxt, pools = real(params, tokens, getattr(prev, "value", prev), *rest)
+        return _Unfetched(n, nxt, log, fail=n == fail_fetch_of), pools
+
+    eng._decode = decode
+    return log
+
+
+def paged_generate(params, cfg, prompt, max_new, temperature, seed, slots=4, bs=8):
+    """The blocking loop, written out for one sequence: prefill, then one
+    step at a time with every token fetched before the next step is built.
+    Keys as the engine folds them, so sampled tokens are comparable."""
+    per_slot = cfg.max_seq // bs
+    pools = gen.init_kv_pools(cfg, num_blocks=per_slot + 1, block_size=bs)
+    table = np.full((slots, per_slot), TRASH_BLOCK, np.int32)
+    table[0] = np.arange(1, per_slot + 1)
+    width = max(bs, 1 << (len(prompt) - 1).bit_length())
+    toks = np.zeros((1, width), np.int32)
+    toks[0, : len(prompt)] = prompt
+    seeds = jnp.full((slots,), seed, jnp.int32)
+    temps = jnp.full((slots,), temperature, jnp.float32)
+    n = jnp.asarray([len(prompt)], jnp.int32)
+    first, pools = gen.paged_prefill_chunk(
+        params, jnp.asarray(toks), jnp.zeros((1,), jnp.int32), n, jnp.asarray(table[:1]), pools, cfg,
+        _fold_keys(seeds[:1], n - 1), temps[:1],
+    )  # fmt: skip
+    out = list(prompt) + [int(first[0])]
+    while len(out) < len(prompt) + max_new:
+        positions = jnp.zeros((slots,), jnp.int32).at[0].set(len(out) - 1)
+        tokens = jnp.zeros((slots,), jnp.int32).at[0].set(out[-1])
+        nxt, pools = gen.paged_decode_step(
+            params, tokens, positions, jnp.asarray(table), pools, cfg, _fold_keys(seeds, positions), temps
+        )
+        out.append(int(nxt[0]))
+    return out
+
+
+class TestStepInFlight:
+    def test_next_step_is_enqueued_before_this_one_is_fetched(self, tiny):
+        cfg, params = tiny
+        eng = ServeEngine(params, cfg, max_slots=2, block_size=8)
+        log = spy_on_decode(eng)
+        eng.start()
+        try:
+            r = eng.generate([1, 2, 3], max_new_tokens=12, timeout=120)
+        finally:
+            eng.stop()
+        assert r.tokens == dense_generate(params, cfg, [1, 2, 3], 12)
+        order = [ev[:2] for ev in log]
+        steps = eng.stats()["steps"]
+        assert steps == 11  # the first token is prefill's
+        for n in range(steps - 1):
+            assert order.index(("dispatch", n + 1)) < order.index(("fetch", n))
+        assert order[-1] == ("fetch", steps - 1)  # the last step: nothing to enqueue, still fetched
+        assert eng.stats()["steps_overlapped"] == steps - 1
+        assert eng.stats()["tokens_discarded"] == 0
+        # only the first step reads the host's token; every later one the device's
+        assert [int(ev[2][0]) for ev in log if ev[0] == "dispatch"] == [r.generated[0]] + [-1] * (steps - 1)
+
+    @pytest.mark.parametrize("temperature", [0.0, 0.9], ids=["greedy", "sampled"])
+    def test_arrivals_while_a_step_is_in_flight(self, tiny, temperature):
+        """A slot admitted while a step is in flight reads the host's token
+        in its first step, its neighbours the device's, in one program."""
+        cfg, params = tiny
+        eng = ServeEngine(params, cfg, max_slots=4, block_size=8, max_prefill_batch=2)
+        prompts = [[1, 2, 3], [7, 8], [4, 5, 6, 7, 8, 9, 10, 11, 12], [11], [3, 1]]
+        lengths = [14, 6, 9, 12, 5]
+        reqs = [
+            ServeRequest(prompt=p, max_new_tokens=n, temperature=temperature, seed=40 + i)
+            for i, (p, n) in enumerate(zip(prompts, lengths))
+        ]
+        arrive = {2: reqs[1:3], 4: reqs[3:4], 7: reqs[4:]}  # before dispatch n, on the engine's thread
+        log = spy_on_decode(eng, at_dispatch=lambda n: [eng.submit(r) for r in arrive.get(n, [])])
+        eng.submit(reqs[0])
+        eng.start()
+        try:
+            for r in reqs:
+                assert r.wait(timeout=120) and r.error is None
+        finally:
+            eng.stop()
+        for r in reqs:
+            if temperature == 0.0:
+                assert r.tokens == dense_generate(params, cfg, list(r.prompt), r.max_new_tokens)
+            assert r.tokens == paged_generate(
+                params, cfg, list(r.prompt), r.max_new_tokens, temperature, r.seed
+            )
+        mixed = [ev[2] for ev in log if ev[0] == "dispatch" and (ev[2] == -1).any() and (ev[2] > 0).any()]
+        assert len(mixed) >= 3  # each arrival's first step sat beside slots already stepping
+        assert eng.stats()["tokens_discarded"] == 0
+
+    def test_a_slot_whose_last_token_is_in_flight_writes_no_row(self, tiny):
+        """Known to finish by count, a slot is not stepped again, but the
+        program writes a row for every slot: that one's must not land at
+        position 0 of its first block, which the prefix cache shares out."""
+        cfg, params = tiny
+        head = list(range(20, 36))  # two full blocks, shared
+        eng = ServeEngine(params, cfg, max_slots=2, block_size=8).start()
+        try:
+            short = eng.submit(ServeRequest(prompt=head + [1], max_new_tokens=3))
+            long = eng.submit(ServeRequest(prompt=head + [2, 3], max_new_tokens=12))
+            assert short.wait(timeout=120) and long.wait(timeout=120)
+            later = eng.generate(head + [4], max_new_tokens=4, timeout=120)
+            assert eng.stats()["prefix_cache"]["hit_tokens"] >= 16
+        finally:
+            eng.stop()
+        for r in (short, long, later):
+            assert r.tokens == dense_generate(params, cfg, list(r.prompt), r.max_new_tokens)
+
+    def test_eos_overrun_is_dropped_and_its_block_reused(self, tiny, early_stop_case):
+        cfg, params = tiny
+        prompt, full, cut = early_stop_case(lambda p, n: dense_generate(params, cfg, p, n), 8)
+        # the smallest pool: the long request after the EOS must take the
+        # block that holds the overrun's row
+        eng = ServeEngine(
+            params, cfg, max_slots=2, block_size=8, num_blocks=cfg.max_seq // 8 + 1, enable_prefix_cache=False
+        ).start()
+        try:
+            free = eng.alloc.free_blocks
+            r = eng.generate(prompt, max_new_tokens=8, eos_id=full[cut - 1], timeout=120)
+            assert r.tokens == full[:cut] and cut < len(full)
+            long_prompt = list(range(5, 5 + cfg.max_seq - 16))
+            after = eng.generate(long_prompt, max_new_tokens=16, timeout=120)
+            assert after.tokens == dense_generate(params, cfg, long_prompt, 16)
+            assert eng.drain(timeout=120) and eng.alloc.free_blocks == free
+            # learnt a step late: the slot was stepped once more, that token dropped
+            assert eng.stats()["tokens_discarded"] == 1
+            assert eng.stats()["steps"] == len(r.generated) + 15  # one more than the tokens decode gave
+        finally:
+            eng.stop()
+
+    def test_drain_leaves_no_token_on_the_device(self, tiny):
+        cfg, params = tiny
+        eng = ServeEngine(params, cfg, max_slots=2, block_size=8).start()
+        try:
+            reqs = [
+                eng.submit(ServeRequest(prompt=[i + 1, i + 2], max_new_tokens=4 + 3 * i)) for i in range(3)
+            ]
+            assert eng.drain(timeout=120) is True
+            for r in reqs:
+                assert r.done.is_set() and r.error is None
+                assert r.tokens == dense_generate(params, cfg, list(r.prompt), r.max_new_tokens)
+        finally:
+            eng.stop()
+
+    def test_stop_fails_the_request_whose_step_is_in_flight(self, tiny):
+        cfg, params = tiny
+        eng = ServeEngine(params, cfg, max_slots=2, block_size=8)
+        stepping = threading.Event()
+        spy_on_decode(eng, at_dispatch=lambda n: stepping.set() if n == 2 else None)
+        eng.start()
+        r = eng.submit(ServeRequest(prompt=[1, 2, 3], max_new_tokens=100))
+        assert stepping.wait(timeout=120)
+        eng.stop()
+        assert r.done.is_set() and r.error == "engine stopped"
+        assert eng._in_flight is None and all(s is None for s in eng._slots)
+
+    @pytest.mark.parametrize("where", ["dispatch", "fetch"])
+    def test_a_raising_step_fails_every_waiter(self, tiny, where):
+        """An error of the device surfaces where the host waits for it: at
+        the fetch, a turn after the step was enqueued."""
+        cfg, params = tiny
+        eng = ServeEngine(params, cfg, max_slots=2, block_size=8)
+
+        def boom(n):
+            if where == "dispatch" and n == 3:
+                raise RuntimeError("device fell over")
+
+        spy_on_decode(eng, at_dispatch=boom, fail_fetch_of=3 if where == "fetch" else None)
+        eng.start()
+        try:
+            reqs = [eng.submit(ServeRequest(prompt=[i + 1, 2], max_new_tokens=20)) for i in range(3)]
+            for r in reqs:  # two in slots (one step in flight), one still queued
+                assert r.wait(timeout=120) and "device fell over" in r.error
+            assert "device fell over" in eng.failed and eng._in_flight is None
+            with pytest.raises(EngineStopped):
+                eng.submit(ServeRequest(prompt=[1], max_new_tokens=1))
+        finally:
+            eng.stop()

@@ -53,9 +53,20 @@ session is the switch.
   ``serve.admit`` (``rows``, ``width``, ``cached_tokens``, ``queue_depth``)
   over ``serve.admit.plan`` / ``serve.admit.build`` /
   ``serve.prefill.dispatch`` / ``serve.prefill.fetch`` /
-  ``serve.admit.commit``; ``serve.decode`` (``step``, ``active``) over
+  ``serve.admit.commit``; ``serve.decode`` (``step``, ``active``,
+  ``steps_overlapped``, ``tokens_discarded``) over
   ``serve.decode.prepare`` / ``.dispatch`` / ``.fetch`` / ``.commit``
-  (``finished``); ``serve.idle``; ``serve.kv_import``. They are per step,
+  (``finished``); ``serve.idle``; ``serve.kv_import``. The engine keeps one
+  decode step in flight, so one ``serve.decode`` turn spans two steps:
+  ``.prepare`` and ``.dispatch`` build and enqueue step N+1 (``active`` is
+  how many slots it steps; 0 when there is nothing left to enqueue), then
+  ``.fetch`` and ``.commit`` wait for and hand out the tokens of step N,
+  which ran on the device meanwhile. A turn with nothing in flight (the first
+  step after an idle spell) has no ``.fetch`` / ``.commit``.
+  ``steps_overlapped`` (steps enqueued while the one before was unfetched)
+  and ``tokens_discarded`` (slot-steps dropped at commit: the step after an
+  EOS, a slot preempted with its step in flight) are the engine's running
+  counts, also in ``ServeEngine.stats()``. They are per step,
   not per request: the per-request spans (``serve.generate``,
   ``serve.route``, ``serve.kv_transfer``) stay on the launcher plane;
 * host spans, training: the profiler's step marker ``train`` around each

@@ -22,6 +22,14 @@ from typing import Any
 import jax
 
 # -- host spans: serving engine loop (one line of /host:CPU) ----------------
+#
+# The engine keeps one decode step in flight, so one ``serve.decode`` turn
+# spans two steps: ``.prepare`` and ``.dispatch`` build and enqueue step N+1,
+# then ``.fetch`` and ``.commit`` wait for and hand out step N's tokens, which
+# the device computed meanwhile. A turn with nothing in flight has no
+# ``.fetch``/``.commit``; one with nothing left to enqueue no ``.dispatch``.
+# An admission round is enqueued behind the step in flight; its ``first`` is
+# still fetched before the round commits.
 
 SERVE_ADMIT = "serve.admit"  # rows, width, cached_tokens, tokens (prefilled), queue_depth, kv_bytes_per_token
 SERVE_ADMIT_PLAN = "serve.admit.plan"
@@ -29,7 +37,7 @@ SERVE_ADMIT_BUILD = "serve.admit.build"
 SERVE_PREFILL_DISPATCH = "serve.prefill.dispatch"
 SERVE_PREFILL_FETCH = "serve.prefill.fetch"
 SERVE_ADMIT_COMMIT = "serve.admit.commit"
-SERVE_DECODE = "serve.decode"  # step, active
+SERVE_DECODE = "serve.decode"  # step, active (slots step N+1 steps); running counts: steps_overlapped, tokens_discarded
 SERVE_DECODE_PREPARE = "serve.decode.prepare"
 SERVE_DECODE_DISPATCH = "serve.decode.dispatch"
 SERVE_DECODE_FETCH = "serve.decode.fetch"

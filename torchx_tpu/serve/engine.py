@@ -16,9 +16,18 @@ This engine keeps a **fixed slot array** decoding continuously:
   width-bucketed groups (a handful of compiles total) and dropped into
   free slots, with KV blocks allocated from the shared paged pool
   (:mod:`torchx_tpu.serve.kv_pool`);
+* **one decode step always in flight**: a step's sampled tokens stay on the
+  device as the next step's input, and the loop enqueues step N+1 *before*
+  it fetches and commits step N, so the host's work for a step runs while
+  the device runs the step before and decode program follows decode program
+  on the chip. Everything else step N+1 needs is known ahead: positions,
+  block tables, seeds, and a finish by token budget (such a slot is not
+  stepped again). A finish by EOS is learnt one step late: the slot has
+  then been stepped once too often, and that token is dropped at commit
+  (:meth:`ServeEngine._commit_step`);
 * **eviction** per step: a slot that hits EOS or its token budget
-  completes immediately — its caller unblocks, its blocks return to the
-  pool, and the slot is free for the next admission *that same step*;
+  completes as soon as the host holds that token — its caller unblocks, its
+  blocks return to the pool, and the slot is free for the next admission;
 * **preemption** under pool pressure: if a mid-decode slot can't get its
   next block, the youngest slot is evicted back to the wait queue (its
   finished tokens kept; decode resumes exactly — sampling keys are a pure
@@ -141,8 +150,25 @@ class ServeRequest:
 class _SlotState:
     req: ServeRequest
     cache_len: int  # tokens currently in the KV cache for this sequence
-    last_tok: int  # most recent sampled token (next step's input)
+    last_tok: int  # most recent sampled token the host holds
     admit_seq: int  # admission order; highest = youngest = preemption victim
+    #: steps enqueued for this slot whose token is not committed yet: 1 while
+    #: a step is in flight, 2 between a dispatch and the commit that follows it
+    unfetched: int = 0
+
+    @property
+    def more_to_decode(self) -> bool:
+        """False once the tokens held plus those in flight reach the budget: a
+        finish by count is known a step ahead, so the slot is not stepped again."""
+        return len(self.req.generated) + self.unfetched < self.req.max_new_tokens
+
+
+@dataclasses.dataclass
+class _InFlight:
+    """A decode step enqueued and not yet fetched."""
+
+    nxt: jax.Array  # [max_slots] sampled tokens, still on the device
+    stepping: list[tuple[int, _SlotState]]  # the slots it stepped, as they were then
 
 
 @dataclasses.dataclass
@@ -166,6 +192,10 @@ class _Handoff:
     v: np.ndarray  # the same; latent pools: no width (generate.export_blocks)
     cache_len: int
     last_tok: int
+
+
+#: in the host's token vector: "take the token the step before left on the device"
+_FROM_DEVICE = -1
 
 
 def _next_pow2(n: int) -> int:
@@ -258,6 +288,11 @@ class ServeEngine:
         self.tokens_out = 0
         self.steps = 0
         self.preemptions = 0  # slots evicted back to the queue under pool pressure
+        self.steps_overlapped = 0  # steps enqueued while the step before was unfetched
+        #: slot-steps whose token was dropped at commit: the step after an EOS,
+        #: a slot preempted with its step in flight
+        self.tokens_discarded = 0
+        self._in_flight: Optional[_InFlight] = None
         #: why the loop died (a step raised), else None; a dead engine
         #: refuses work and fails the replica's health check
         self.failed: Optional[str] = None
@@ -268,10 +303,14 @@ class ServeEngine:
         # program's HLO and a private device copy in each executable.
         # Donation lets XLA update the pools in place (no-op on CPU, where
         # jax warns — so only donate off-CPU)
-        donate = (4,) if jax.default_backend() != "cpu" else ()
+        donate = (5,) if jax.default_backend() != "cpu" else ()
         cfg_c = self._cfg
 
-        def _decode(params, tokens, positions, tables, pools, seeds, temps):  # noqa: ANN001
+        def _decode(params, tokens, prev, positions, tables, pools, seeds, temps):  # noqa: ANN001
+            # a slot's input is the token the host holds for it (just admitted,
+            # handed off) or, where the host says _FROM_DEVICE, the one the step
+            # before sampled for that slot, which never left the device
+            tokens = jnp.where(tokens == _FROM_DEVICE, prev, tokens)
             keys = _fold_keys(seeds, positions)
             return gen.paged_decode_step(
                 params, tokens, positions, tables, pools, cfg_c, keys, temps
@@ -453,6 +492,8 @@ class ServeEngine:
                 "tokens_out": self.tokens_out,
                 "steps": self.steps,
                 "preemptions": self.preemptions,
+                "steps_overlapped": self.steps_overlapped,
+                "tokens_discarded": self.tokens_discarded,
                 "kv_bytes_per_token": self.kv_bytes_per_token,
                 "draining": self._draining,
                 "failed": self.failed,
@@ -487,6 +528,8 @@ class ServeEngine:
                     and not self._handoffs
                     and not self._admitting
                     and all(s is None for s in self._slots)
+                    # the step after an EOS: stepped nobody that is left, still to be fetched
+                    and self._in_flight is None
                 )
             if empty:
                 return True
@@ -530,6 +573,7 @@ class ServeEngine:
             self._waiting.clear()
             self._handoffs.clear()
             self._admitting = []
+        self._in_flight = None
         for i, st in enumerate(self._slots):
             if st is not None:
                 self._slots[i] = None
@@ -823,73 +867,116 @@ class ServeEngine:
                 return False  # preempted ourselves: nothing else to evict
 
     def _decode_once(self) -> bool:
-        active = [(i, st) for i, st in enumerate(self._slots) if st is not None]
-        if not active:
+        """One turn of the decode pipeline: prepare and enqueue the next step,
+        then fetch and commit the one in flight. The step enqueued reads, on
+        the device, the tokens the one in flight will have sampled; with
+        nothing left to step, the turn still fetches what is in flight, so
+        the loop never idles on an unfetched token."""
+        before = self._in_flight
+        if before is None and all(s is None for s in self._slots):
             return False
         with hot.span(hot.SERVE_DECODE, step=self.steps) as step_span:
             with hot.span(hot.SERVE_DECODE_PREPARE):
-                for slot, st in active:
-                    if self._slots[slot] is None:
-                        continue  # preempted by an earlier slot's capacity grab
-                    self._ensure_capacity(slot, st.cache_len)
+                for slot, st in enumerate(self._slots):
+                    # None by now: preempted by an earlier slot's capacity grab
+                    if st is not None and st.more_to_decode:
+                        self._ensure_capacity(slot, st.cache_len + st.unfetched)
 
                 tokens = np.zeros((self.max_slots,), np.int32)
                 positions = np.zeros((self.max_slots,), np.int32)
                 seeds = np.zeros((self.max_slots,), np.int32)
                 temps = np.zeros((self.max_slots,), np.float32)
+                # a copy: the loop goes on to change the table while the step
+                # is in flight, and the CPU backend reads a numpy array where
+                # it lies
+                tables = self.tables.tables.copy()
                 stepping: list[tuple[int, _SlotState]] = []
                 for slot, st in enumerate(self._slots):
                     if st is None:
                         continue
-                    tokens[slot] = st.last_tok
-                    positions[slot] = st.cache_len
+                    if not st.more_to_decode:
+                        # its last token is in flight. The program writes a row
+                        # for every slot: this one's goes where an empty slot's does
+                        tables[slot] = TRASH_BLOCK
+                        continue
+                    tokens[slot] = _FROM_DEVICE if st.unfetched else st.last_tok
+                    positions[slot] = st.cache_len + st.unfetched
                     seeds[slot] = np.int32(np.uint32(st.req.seed & 0xFFFFFFFF))
                     temps[slot] = st.req.temperature
                     stepping.append((slot, st))
-            step_span.set_metadata(active=len(stepping))
-            if not stepping:
-                return False
 
-            with hot.span(hot.SERVE_DECODE_DISPATCH):
-                nxt, self.pools = self._decode(
-                    self._params,
-                    jnp.asarray(tokens),
-                    jnp.asarray(positions),
-                    jnp.asarray(self.tables.tables),
-                    self.pools,
-                    jnp.asarray(seeds),
-                    jnp.asarray(temps),
-                )
-            with hot.span(hot.SERVE_DECODE_FETCH):
-                nxt = np.asarray(nxt)
-            self.steps += 1
+            enqueued = None
+            if stepping:
+                with hot.span(hot.SERVE_DECODE_DISPATCH):
+                    host_tokens = jnp.asarray(tokens)
+                    nxt, self.pools = self._decode(
+                        self._params,
+                        host_tokens,
+                        # with no step in flight every slot reads the host's token
+                        host_tokens if before is None else before.nxt,
+                        jnp.asarray(positions),
+                        jnp.asarray(tables),
+                        self.pools,
+                        jnp.asarray(seeds),
+                        jnp.asarray(temps),
+                    )
+                for _, st in stepping:
+                    st.unfetched += 1
+                enqueued = _InFlight(nxt, stepping)
+                self.steps_overlapped += before is not None
 
-            with hot.span(hot.SERVE_DECODE_COMMIT) as commit_span:
-                finished = 0
-                now = self._clock()
-                for slot, st in stepping:
-                    st.cache_len += 1
-                    self.tables.lengths[slot] = st.cache_len
-                    tok = int(nxt[slot])
-                    st.last_tok = tok
-                    st.req.generated.append(tok)
-                    self.tokens_out += 1
-                    obs_metrics.SERVE_TOKENS.inc(phase="decode")
-                    if self._finished(st.req, tok):
-                        finished += 1
-                        self._slots[slot] = None
-                        blocks = self.tables.release(slot)
-                        if self.prefix_cache is not None:
-                            # index the completed sequence's full blocks (cache
-                            # holds cache_len tokens: everything but the final
-                            # sampled token) before dropping the slot's refs
-                            seq = list(st.req.prompt) + st.req.generated
-                            self.prefix_cache.insert(seq[: st.cache_len], blocks)
-                        self.alloc.release(blocks)
-                        self._complete(st.req, now)
-                self._update_gauges()
-                commit_span.set_metadata(finished=finished)
-        return True
+            if before is not None:
+                with hot.span(hot.SERVE_DECODE_FETCH):
+                    sampled = np.asarray(before.nxt)
+                self.steps += 1
+                with hot.span(hot.SERVE_DECODE_COMMIT) as commit_span:
+                    commit_span.set_metadata(finished=self._commit_step(before.stepping, sampled))
+            # only now: drain() must not see "nothing in flight" between the two
+            self._in_flight = enqueued
+            step_span.set_metadata(
+                active=len(stepping),
+                steps_overlapped=self.steps_overlapped,
+                tokens_discarded=self.tokens_discarded,
+            )
+        return bool(stepping) or before is not None
+
+    def _commit_step(self, stepping: list[tuple[int, _SlotState]], sampled: np.ndarray) -> int:
+        """Hand each slot a fetched step stepped its token; -> how many
+        finished. A slot that no longer holds the state it was stepped with
+        finished by EOS or was preempted while the step was in flight: its
+        token is dropped (a preempted request draws it again at its
+        re-prefill: the key is seed and position). The row that step wrote
+        for it lies in a block the slot owned unshared at dispatch, past every
+        token the prefix cache indexes, and whatever reuses the block is
+        enqueued after the step."""
+        finished = 0
+        now = self._clock()
+        for slot, st in stepping:
+            if self._slots[slot] is not st:
+                self.tokens_discarded += 1
+                continue
+            st.unfetched -= 1
+            st.cache_len += 1
+            self.tables.lengths[slot] = st.cache_len
+            tok = int(sampled[slot])
+            st.last_tok = tok
+            st.req.generated.append(tok)
+            self.tokens_out += 1
+            obs_metrics.SERVE_TOKENS.inc(phase="decode")
+            if self._finished(st.req, tok):
+                finished += 1
+                self._slots[slot] = None
+                blocks = self.tables.release(slot)
+                if self.prefix_cache is not None:
+                    # index the completed sequence's full blocks (cache
+                    # holds cache_len tokens: everything but the final
+                    # sampled token) before dropping the slot's refs
+                    seq = list(st.req.prompt) + st.req.generated
+                    self.prefix_cache.insert(seq[: st.cache_len], blocks)
+                self.alloc.release(blocks)
+                self._complete(st.req, now)
+        self._update_gauges()
+        return finished
 
     def _update_gauges(self) -> None:
         active = sum(1 for s in self._slots if s is not None)
