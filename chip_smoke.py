@@ -67,7 +67,9 @@ class Mode:
             "kernels": "xla" if cpu_tiny else "fused_flash",
         }
         self.norm_residual = "reference" if cpu_tiny else "fused"
-        self.serve_attention = "paged_xla"
+        # prefill walks the key blocks; decode takes the XLA function on both
+        # configs (llama3_1b's head_dim 64 fails the decode kernel's gate too)
+        self.serve_attention = "paged_walk+paged_xla"
         # prompt + MAX_NEW must fit tiny's max_seq of 128
         self.prompt_lens = (24, 50, 90) if cpu_tiny else (24, 100, 300)
         self.simulate = cpu_tiny

@@ -35,6 +35,7 @@ def main() -> None:
     from torchx_tpu.models import generate as gen
     from torchx_tpu.obs.hlo import loop_moves
     from torchx_tpu.serve import engine as eng
+    from torchx_tpu.serve.kv_pool import window_ring
 
     cell = spec.load_cell(sys.argv[1])
     only = {tuple(int(x) for x in a.split("x")) for a in sys.argv[2:]}
@@ -49,8 +50,18 @@ def main() -> None:
     sds = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=one)  # noqa: E731
     is_leaf = lambda x: isinstance(x, tuple) and isinstance(x[0], tuple)  # noqa: E731
     params = shapes_of(config, jnp.bfloat16, jax.tree.map(lambda _: one, models.weight_shapes(config), is_leaf=is_leaf))
-    pools = jax.tree.map(lambda p: sds(p.shape, p.dtype), jax.eval_shape(lambda: gen.init_kv_pools(cfg, n_blocks, bs)))
+    # a stack with sliding layers: the engine's default window pool, and a ring table beside the full one
+    window = cfg.sliding_window if cfg.layers_of("window") else 0
+    ring = window_ring(window, bs) if window else 0
+    n_window = 1 + slots * ring + int(dep["max_prefill_batch"]) * per_slot if window else None
+    pools = jax.tree.map(lambda p: sds(p.shape, p.dtype),
+                         jax.eval_shape(lambda: gen.init_kv_pools(cfg, n_blocks, bs, n_window)))  # fmt: skip
     i32, f32 = jnp.int32, jnp.float32
+
+    def tables(rows: int, window_width: int):  # noqa: ANN202
+        full = sds((rows, per_slot), i32)
+        return {"full": full, "window": sds((rows, window_width), i32)} if window else full
+
     layer_bytes = min(p.size // p.shape[0] * p.dtype.itemsize for p in jax.tree.leaves(pools))
 
     def report(name, compiled):  # noqa: ANN001, ANN202
@@ -69,9 +80,10 @@ def main() -> None:
 
     if not only:
         c = jax.jit(decode, donate_argnums=(5,)).lower(
-            params, sds((slots,), i32), sds((slots,), i32), sds((slots,), i32), sds((slots, per_slot), i32), pools,
+            params, sds((slots,), i32), sds((slots,), i32), sds((slots,), i32), tables(slots, ring), pools,
             sds((slots,), i32), sds((slots,), f32)).compile()
-        report(f"{cell.name}: decode step ({slots} slots, {n_blocks} blocks)", c)
+        report(f"{cell.name}: decode step ({slots} slots, {n_blocks} blocks"
+               + (f", {n_window} window blocks in rings of {ring}" if window else "") + ")", c)
         text = c.as_text()
         print("  kernels:", sorted({n for n in ("paged_mla_decode", "paged_attention_decode", "gmm") if n in text}))
     plan = traffic_lib.build_schedule(mix, 1, 45, config["vocab_size"])
@@ -83,7 +95,7 @@ def main() -> None:
                 continue
             c = jax.jit(prefill, donate_argnums=(5,)).lower(
                 params, sds((rows, w), i32), sds((rows,), i32), sds((rows,), i32),
-                sds((rows, per_slot), i32), pools, sds((rows,), i32), sds((rows,), f32)).compile()
+                tables(rows, per_slot), pools, sds((rows,), i32), sds((rows,), f32)).compile()
             report(f"{cell.name}: prefill rows {rows} x width {w}", c)
 
 

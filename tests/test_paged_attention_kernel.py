@@ -313,6 +313,18 @@ def test_kernel_compiles_for_the_chip(one_chip, bpr, dtype):
 # holds the TPU's library is the one that runs it.
 
 
+def test_windowed_kernel_compiles_for_the_chip(one_chip):
+    """The new cell's sliding layers: 64 slots, rings of 10 blocks into a stack of six layers' window pools."""
+    shape = lambda s, d: jax.ShapeDtypeStruct(s, d, sharding=one_chip)  # noqa: E731
+    pool = shape((6, 1169, 16, 8, 128), jnp.bfloat16)
+    fn = lambda q, k, v, t, n, i: pk.paged_attention_pallas(q, k, v, t, n, layer=i, window=128)  # noqa: E731
+    text = jax.jit(fn).lower(
+        shape((64, 64, 128), jnp.bfloat16), pool, pool, shape((64, 10), jnp.int32), shape((64,), jnp.int32), shape((), jnp.int32)
+    ).compile().as_text()  # fmt: skip
+    assert "paged_attention_decode" in text
+    assert not [ln for ln in text.splitlines() if " copy(" in ln and "bf16[6,1169," in ln]
+
+
 def test_latent_kernel_compiles_for_the_chip(one_chip):
     from torchx_tpu.ops import paged_mla as pm
     from torchx_tpu.ops import paged_mla_kernel as pmk
@@ -343,6 +355,12 @@ def test_latent_kernel_compiles_for_the_chip(one_chip):
         expert_ffn_dim=128, n_shared_experts=1, router_score="sigmoid", router_bias=True, n_dense_layers=1,
         capacity_factor=0.0, kv_lora_rank=128, qk_nope_dim=64, qk_rope_dim=64, v_head_dim=64), "paged_mla_decode",
         id="latent-pools-two-groups"),
+    pytest.param(lambda: moe.moe_tiny(
+        dim=512, n_heads=8, n_kv_heads=8, attn_head_dim=128, n_layers=12, max_seq=256, dtype=jnp.bfloat16, ffn_dim=256,
+        n_experts=16, experts_held=4, experts_held_from=4, top_k=3, expert_ffn_dim=128, n_shared_experts=1,
+        router_score="sigmoid", router_bias=True, n_dense_layers=1, capacity_factor=0.0, qk_norm=True, rope_full_layers=False,
+        layer_types=("sliding", "sliding", "sliding", "full") * 3, sliding_window=40), "paged_attention_decode",
+        id="window-and-full-pools-two-groups-periods-scanned"),
 ])  # fmt: skip
 def test_serving_programs_keep_the_pools_where_they_lie_on_the_chip(one_chip, make, kernel, program, monkeypatch):
     from torchx_tpu.obs.hlo import loop_moves
@@ -355,14 +373,18 @@ def test_serving_programs_keep_the_pools_where_they_lie_on_the_chip(one_chip, ma
     on_chip = lambda tree: jax.tree.map(lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip), tree)  # noqa: E731
     shape = lambda s, d=jnp.int32: jax.ShapeDtypeStruct(s, d, sharding=one_chip)  # noqa: E731
     params = on_chip(jax.eval_shape(lambda: llama.model_fns(cfg)[0](cfg, jax.random.PRNGKey(0))))
-    pools = on_chip(jax.eval_shape(lambda: gen.init_kv_pools(cfg, 4097, bs)))
+    pools = on_chip(jax.eval_shape(lambda: gen.init_kv_pools(cfg, 4097, bs, 513)))
+
+    def tables(n, window_width):  # one array, or one a cache kind where sliding and full layers mix
+        return {"full": shape((n, bpr)), "window": shape((n, window_width))} if cfg.layer_types else shape((n, bpr))
+
     if program == "decode":
         fn = lambda p, tok, pos, tab, pl, keys, temps: gen.paged_decode_step(p, tok, pos, tab, pl, cfg, keys, temps)  # noqa: E731
-        args = (params, shape((slots,)), shape((slots,)), shape((slots, bpr)), pools,
+        args = (params, shape((slots,)), shape((slots,)), tables(slots, 5), pools,
                 shape((slots, 2), jnp.uint32), shape((slots,), jnp.float32))  # fmt: skip
     else:
         fn = lambda p, tok, pre, suf, tab, pl, keys, temps: gen.paged_prefill_chunk(p, tok, pre, suf, tab, pl, cfg, keys, temps)  # noqa: E731
-        args = (params, shape((rows, width)), shape((rows,)), shape((rows,)), shape((rows, bpr)), pools,
+        args = (params, shape((rows, width)), shape((rows,)), shape((rows,)), tables(rows, bpr), pools,
                 shape((rows, 2), jnp.uint32), shape((rows,), jnp.float32))  # fmt: skip
     text = jax.jit(fn, donate_argnums=(len(args) - 3,)).lower(*args).compile().as_text()
     assert attn_ops.traced("kv_pools") == "carried"
@@ -373,20 +395,25 @@ def test_serving_programs_keep_the_pools_where_they_lie_on_the_chip(one_chip, ma
     assert loop_moves(text, layer_bytes) == []
 
 
-@pytest.mark.parametrize("m,k,n", [
-    pytest.param(384, 2048, 1408, id="decode-gate-up"),
-    pytest.param(384, 1408, 2048, id="decode-down"),
-    pytest.param(49152, 2048, 1408, id="widest-prefill-gate-up"),
+@pytest.mark.parametrize("m,k,n,held,spread", [
+    pytest.param(384, 2048, 1408, 64, 64, id="decode-gate-up"),
+    pytest.param(384, 1408, 2048, 64, 64, id="decode-down"),
+    pytest.param(49152, 2048, 1408, 64, 64, id="widest-prefill-gate-up"),
+    # a chip's share: 16 of 128 experts held, the rows spread over all 128 (a tile of 512 rows, which
+    # 16 groups alone would ask for, ran the kernel out of VMEM at these widths: rehearsal, PR 31)
+    pytest.param(512, 6144, 2048, 16, 128, id="share-decode-gate-up"),
+    pytest.param(16384, 6144, 2048, 16, 128, id="share-widest-prefill-gate-up"),
+    pytest.param(16384, 2048, 6144, 16, 128, id="share-widest-prefill-down"),
 ])  # fmt: skip
-def test_grouped_matmul_compiles_for_the_chip(one_chip, m, k, n):
+def test_grouped_matmul_compiles_for_the_chip(one_chip, m, k, n, held, spread):
     from jax.experimental.pallas.ops.tpu.megablox import gmm
 
     from torchx_tpu.ops import grouped_matmul as gm
 
     shape = lambda s, d: jax.ShapeDtypeStruct(s, d, sharding=one_chip)  # noqa: E731
-    assert gm.kernel_eligible((m, k), (64, k, n), jnp.dtype(jnp.bfloat16), jnp.dtype(jnp.bfloat16), "tpu")
-    tiling = gm._tiling(m, k, n, 2, groups=64)
+    assert gm.kernel_eligible((m, k), (held, k, n), jnp.dtype(jnp.bfloat16), jnp.dtype(jnp.bfloat16), "tpu")
+    tiling = gm._tiling(m, k, n, 2, groups=spread)
     compiled = jax.jit(lambda l, r, g: gmm(l, r, g, preferred_element_type=jnp.bfloat16, tiling=tiling)).lower(
-        shape((m, k), jnp.bfloat16), shape((64, k, n), jnp.bfloat16), shape((64,), jnp.int32)
+        shape((m, k), jnp.bfloat16), shape((held, k, n), jnp.bfloat16), shape((held,), jnp.int32)
     ).compile()  # fmt: skip
     assert "tpu_custom_call" in compiled.as_text()

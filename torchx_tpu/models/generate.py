@@ -17,6 +17,7 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from torchx_tpu.models import llama, mla
 from torchx_tpu.obs import hot
@@ -346,43 +347,72 @@ def layer_group_sizes(cfg: llama.LlamaConfig) -> dict[str, int]:
 
 
 def init_kv_pools(
-    cfg: llama.LlamaConfig, num_blocks: int, block_size: int
+    cfg: llama.LlamaConfig, num_blocks: int, block_size: int, num_window_blocks: Optional[int] = None
 ) -> KVPools:
     """Zeroed paged pools (block 0 is the trash block — see
     :mod:`torchx_tpu.ops.paged_attention`). Grouped-query attention: K and V,
     ``[layers, num_blocks, block_size, kvh, hd]`` each. Latent attention: one
     pool a layer group under the group's name, ``[group's layers, num_blocks,
-    block_size, cache_width]``, a row a token. Every leaf leads with ``[layers
-    of a group, num_blocks, block_size]``: the engine allocates, copies,
-    exports and imports blocks over the tree without knowing which it holds."""
+    block_size, cache_width]``, a row a token. A stack that mixes sliding and
+    full layers (``cfg.layer_types``): a K and a V a cache kind, ``{"full":
+    {"k", "v"}, "window": {"k", "v"}}``, each over the layers of its kind in
+    the order they run; the ``window`` pools have ``num_window_blocks`` blocks
+    of their own, handed out by an allocator of their own, since a slot holds
+    there only the blocks its window touches. Every leaf leads with ``[layers,
+    blocks, block_size]``: the engine allocates, copies, exports and imports
+    blocks over the tree a cache kind at a time."""
     if cfg.kv_lora_rank:
         return {
             group: jnp.zeros((n, num_blocks, block_size, cfg.cache_width), dtype=cfg.dtype)
             for group, n in layer_group_sizes(cfg).items()
         }
-    shape = (cfg.n_layers, num_blocks, block_size, cfg.n_kv_heads, cfg.head_dim)
-    return {
-        "k": jnp.zeros(shape, dtype=cfg.dtype),
-        "v": jnp.zeros(shape, dtype=cfg.dtype),
-    }
+
+    def kv(layers: int, blocks: int) -> KVPools:
+        shape = (layers, blocks, block_size, cfg.n_kv_heads, cfg.head_dim)
+        return {"k": jnp.zeros(shape, dtype=cfg.dtype), "v": jnp.zeros(shape, dtype=cfg.dtype)}
+
+    if not cfg.layer_types:
+        return kv(cfg.n_layers, num_blocks)
+    blocks = {"full": num_blocks, "window": num_window_blocks or num_blocks}
+    return {kind: kv(cfg.layers_of(kind), blocks[kind]) for kind in ("full", "window") if cfg.layers_of(kind)}
 
 
-def export_blocks(pools: KVPools, blocks: jnp.ndarray) -> tuple[jnp.ndarray, jnp.ndarray]:
+def export_blocks(
+    pools: KVPools, blocks: jnp.ndarray, kinds: tuple[str, ...] = (), window_blocks: Optional[jnp.ndarray] = None
+) -> tuple[jnp.ndarray, jnp.ndarray]:
     """Blocks ``blocks`` of every layer as the ``(k, v)`` pair a hand-off
     carries (:class:`~torchx_tpu.serve.kv_transfer.KvPayload`): ``[L, n,
     block_size, ...]`` each. Latent pools travel as ``k``, the groups' layers
-    one after another as they run, beside a ``v`` of no width."""
+    one after another as they run, beside a ``v`` of no width. Pools a cache
+    kind (``kinds``: ``cfg.cache_kinds``) travel in the order the layers run
+    too: a full layer's blocks are ``blocks``, a sliding layer's
+    ``window_blocks`` (as many; the trash block where the sender no longer
+    holds one, below the window: the receiver does not read those)."""
     if "k" in pools:
         return pools["k"][:, blocks], pools["v"][:, blocks]
+    if kinds:
+        ids = {"full": blocks, "window": window_blocks}
+        at = [kinds[:i].count(kind) for i, kind in enumerate(kinds)]  # a layer's place in its kind's stack
+        k, v = (jnp.stack([pools[kind][name][i][ids[kind]] for kind, i in zip(kinds, at)]) for name in ("k", "v"))
+        return k, v
     k = jnp.concatenate([pool[:, blocks] for pool in pools.values()])
     return k, jnp.zeros((*k.shape[:3], 0), k.dtype)
 
 
-def import_blocks(pools: KVPools, blocks: jnp.ndarray, k, v) -> KVPools:  # noqa: ANN001
-    """Write a hand-off's ``(k, v)`` (:func:`export_blocks`) into ``blocks``."""
+def import_blocks(pools: KVPools, blocks: jnp.ndarray, k, v, kinds: tuple[str, ...] = (), window_blocks=None) -> KVPools:  # noqa: ANN001
+    """Write a hand-off's ``(k, v)`` (:func:`export_blocks`) into ``blocks``
+    (a sliding layer's into ``window_blocks``: the trash block for those below
+    the window, which nothing reads)."""
     if "k" in pools:
         new = {"k": k, "v": v}
         return {name: pool.at[:, blocks].set(jnp.asarray(new[name], pool.dtype)) for name, pool in pools.items()}
+    if kinds:
+        ids, new = {"full": blocks, "window": window_blocks}, {"k": k, "v": v}
+        layers = {kind: np.asarray([i for i, of in enumerate(kinds) if of == kind]) for kind in pools}
+        return {
+            kind: {name: pool.at[:, ids[kind]].set(jnp.asarray(new[name][layers[kind]], pool.dtype)) for name, pool in kv.items()}
+            for kind, kv in pools.items()
+        }
     out, at = {}, 0
     for name, pool in pools.items():
         out[name] = pool.at[:, blocks].set(jnp.asarray(k[at : at + pool.shape[0]], pool.dtype))
@@ -417,35 +447,44 @@ def _sample_rows(
     return jnp.where(temps > 0, sampled, greedy)
 
 
+def _table_of(tables, layer: llama.Params):  # noqa: ANN001, ANN202
+    """The block table of ``layer``'s cache kind: one array serves a stack of
+    one kind, ``{"full": .., "window": ..}`` a stack that mixes them."""
+    return tables[layer["attn_kind"]] if isinstance(tables, dict) else tables
+
+
 def _paged_layer_step(
     cfg: llama.LlamaConfig,
     cos: jnp.ndarray,  # [slots, hd/2] rope rows at each slot's position
     sin: jnp.ndarray,
     positions: jnp.ndarray,  # [slots] — cache index the new token writes to
-    tables: jnp.ndarray,  # [slots, blocks_per_slot] int32
+    tables,  # noqa: ANN001 — [slots, blocks_per_slot] int32, or one a cache kind (_table_of)
     x: jnp.ndarray,  # [slots, 1, d]
     layer: llama.Params,
-    # this layer's pool [num_blocks, bs, kvh, hd], or with layer["layer_index"]
-    # (under _scan_groups) the group's stack [layers, num_blocks, ...]
+    # this layer's pool [num_blocks, bs, kvh, hd], or with layer["kind_index"]
+    # (under _scan_groups) the stack of its cache kind [layers, num_blocks, ...]
     k_pool: jnp.ndarray,
     v_pool: jnp.ndarray,
 ) -> tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
     slots = x.shape[0]
-    at = layer.get("layer_index")
+    at = layer.get("kind_index")
+    window = llama.window_of(cfg, layer)
+    tables = _table_of(tables, layer)
     with jax.named_scope(hot.NORM):
         attn_in = rms_norm(x, layer["attn_norm"], cfg.norm_eps)
-    with jax.named_scope(hot.ATTN):
+    with jax.named_scope(hot.ATTN), hot.attn_kind_scope(cfg, layer):
         if cfg.kv_lora_rank:  # one latent pool, handed through as k_pool
             attn, k_pool = mla.paged_decode(cfg, layer, attn_in, cos, sin, positions, tables, k_pool)
             x = x + attn
         else:
             h, kvh, hd = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
-            q = _rope_rows(mm(attn_in, layer["wq"]).reshape(slots, h, hd), cos, sin)
-            k = _rope_rows(mm(attn_in, layer["wk"]).reshape(slots, kvh, hd), cos, sin)
+            q = mm(attn_in, layer["wq"]).reshape(slots, h, hd)
+            k = mm(attn_in, layer["wk"]).reshape(slots, kvh, hd)
+            q, k = llama.norm_and_rotate(cfg, layer, q, k, cos, sin, _rope_rows)
             v = mm(attn_in, layer["wv"]).reshape(slots, kvh, hd)
-            k_pool = append_kv(k_pool, tables, positions, k, at)
-            v_pool = append_kv(v_pool, tables, positions, v, at)
-            attn = paged_attention(q, k_pool, v_pool, tables, positions + 1, at)
+            k_pool = append_kv(k_pool, tables, positions, k, at, ring=bool(window))
+            v_pool = append_kv(v_pool, tables, positions, v, at, ring=bool(window))
+            attn = paged_attention(q, k_pool, v_pool, tables, positions + 1, at, window)
             x = x + mm(attn.reshape(slots, 1, h * hd), layer["wo"])
     with jax.named_scope(hot.NORM):
         mlp_in = rms_norm(x, layer["mlp_norm"], cfg.norm_eps)
@@ -456,26 +495,43 @@ def _paged_layer_step(
 
 def _scan_groups(step, x, params: llama.Params, pools: KVPools, cfg: llama.LlamaConfig):  # noqa: ANN001, ANN202
     """Run ``step(x, layer, k_pool, v_pool) -> (x, k_pool, v_pool)`` over every
-    layer, one scan a group of equal layers (``llama.scan_layers``). The group's
-    pools ride the scan's carry whole, beside ``x``: ``k_pool`` and ``v_pool``
-    are the stacks ``[layers of the group, num_blocks, ...]``, and the step
-    writes and reads them at ``layer["layer_index"]`` where they lie, so nothing
-    the size of a layer's pool is sliced out, copied or stacked back. A latent
-    pool is its group's one array and goes through as ``k_pool`` with no
-    ``v_pool``. ``ops.attention.traced("kv_pools")`` answers ``carried``."""
-
-    def scan_step(carry, layer):  # noqa: ANN001
-        return step(carry[0], layer, *carry[1:]), None
-
+    layer in the order the layers run, a group of the parameter tree at a time
+    (``llama.scan_layers``: one scan a group of equal layers, a scan over whole
+    periods where attention kinds alternate). The pools the group's layers
+    touch ride the scan's carry whole, beside ``x``: ``k_pool`` and ``v_pool``
+    are the stacks of the layer's cache kind ``[layers of that kind,
+    num_blocks, ...]``, and the step writes and reads them at
+    ``layer["kind_index"]`` where they lie, so nothing the size of a layer's
+    pool is sliced out, copied or stacked back. A latent pool is its group's
+    one array (read at ``layer["layer_index"]``) and goes through as ``k_pool``
+    with no ``v_pool``. ``ops.attention.traced("kv_pools")`` answers ``carried``."""
     note_traced("kv_pools", "carried")
-    if "k" in pools:
-        (group,) = llama.layer_groups(params)  # K/V pools are one stack: a tree of two groups has latent pools
-        (x, k_new, v_new), _ = llama.scan_layers(cfg, scan_step, (x, pools["k"], pools["v"]), params[group])
-        return x, {"k": k_new, "v": v_new}
-    new = {}
+    latent, mixed = bool(cfg.kv_lora_rank), bool(cfg.layer_types)
+    first = 0
     for group in llama.layer_groups(params):
-        (x, new[group], _), _ = llama.scan_layers(cfg, scan_step, (x, pools[group], None), params[group])
-    return x, new
+        n = jax.tree.leaves(params[group])[0].shape[0]
+        if latent:
+            held = {group: (pools[group], None)}
+        elif mixed:
+            held = {kind: (pools[kind]["k"], pools[kind]["v"]) for kind in sorted(set(cfg.cache_kinds[first : first + n]))}
+        else:
+            held = {"full": (pools["k"], pools["v"])}
+
+        def scan_step(carry, layer, group=group):  # noqa: ANN001
+            x, held = carry
+            key = group if latent else layer["attn_kind"]
+            x, k_pool, v_pool = step(x, layer, *held[key])
+            return (x, {**held, key: (k_pool, v_pool)}), None
+
+        (x, held), _ = llama.scan_layers(cfg, scan_step, (x, held), params[group], first)
+        if latent:
+            pools = {**pools, group: held[group][0]}
+        elif mixed:
+            pools = {**pools, **{kind: {"k": k, "v": v} for kind, (k, v) in held.items()}}
+        else:
+            pools = dict(zip(("k", "v"), held["full"]))
+        first += n
+    return x, pools
 
 
 @jax.named_scope(hot.LM_HEAD)
@@ -491,7 +547,7 @@ def paged_decode_step(
     params: llama.Params,
     tokens: jnp.ndarray,  # [slots] int32 — last sampled token per slot
     positions: jnp.ndarray,  # [slots] int32 — where each token's K/V goes
-    tables: jnp.ndarray,  # [slots, blocks_per_slot] int32 block tables
+    tables,  # noqa: ANN001 — [slots, blocks_per_slot] int32 block tables; {"full": .., "window": [slots, ring]} where kinds mix
     pools: KVPools,
     cfg: llama.LlamaConfig,
     keys: jnp.ndarray,  # [slots, 2] per-slot PRNG keys for THIS position
@@ -578,17 +634,19 @@ def _paged_chunk_layer_step(
     sin: jnp.ndarray,
     positions: jnp.ndarray,  # [b, t] absolute cache positions
     valid: jnp.ndarray,  # [b, t] bool — real suffix tokens
-    tables: jnp.ndarray,  # [b, blocks_per_slot] int32
+    tables,  # noqa: ANN001 — [b, blocks_per_slot] int32, or one a cache kind (_table_of); block b at entry b in each
     x: jnp.ndarray,  # [b, t, d]
     layer: llama.Params,
-    k_pool: jnp.ndarray,  # one layer's pool or the group's stack, as in _paged_layer_step
+    k_pool: jnp.ndarray,  # one layer's pool or its cache kind's stack, as in _paged_layer_step
     v_pool: jnp.ndarray,
 ) -> tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
     b, t, _ = x.shape
-    at = layer.get("layer_index")
+    at = layer.get("kind_index")
+    window = llama.window_of(cfg, layer)
+    tables = _table_of(tables, layer)
     with jax.named_scope(hot.NORM):
         attn_in = rms_norm(x, layer["attn_norm"], cfg.norm_eps)
-    with jax.named_scope(hot.ATTN):
+    with jax.named_scope(hot.ATTN), hot.attn_kind_scope(cfg, layer):
         if cfg.kv_lora_rank:  # one latent pool, handed through as k_pool
             attn, k_pool = mla.paged_prefill(
                 cfg, layer, attn_in, cos, sin, positions, valid, tables, k_pool
@@ -596,12 +654,13 @@ def _paged_chunk_layer_step(
             x = x + attn
         else:
             h, kvh, hd = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
-            q = _rope_chunk(mm(attn_in, layer["wq"]).reshape(b, t, h, hd), cos, sin)
-            k = _rope_chunk(mm(attn_in, layer["wk"]).reshape(b, t, kvh, hd), cos, sin)
+            q = mm(attn_in, layer["wq"]).reshape(b, t, h, hd)
+            k = mm(attn_in, layer["wk"]).reshape(b, t, kvh, hd)
+            q, k = llama.norm_and_rotate(cfg, layer, q, k, cos, sin, _rope_chunk)
             v = mm(attn_in, layer["wv"]).reshape(b, t, kvh, hd)
             k_pool = scatter_kv_chunk(k_pool, tables, positions, k, valid, at)
             v_pool = scatter_kv_chunk(v_pool, tables, positions, v, valid, at)
-            attn = paged_attention_chunk(q, k_pool, v_pool, tables, positions, at)
+            attn = paged_attention_chunk(q, k_pool, v_pool, tables, positions, valid, at, window)
             x = x + mm(attn.reshape(b, t, h * hd), layer["wo"])
     with jax.named_scope(hot.NORM):
         mlp_in = rms_norm(x, layer["mlp_norm"], cfg.norm_eps)
@@ -615,7 +674,7 @@ def paged_prefill_chunk(
     tokens: jnp.ndarray,  # [b, t] int32 suffix tokens, right-padded
     prefix_lens: jnp.ndarray,  # [b] int32 — cached tokens already in the pool
     suffix_lens: jnp.ndarray,  # [b] int32 — real suffix lengths (>= 1)
-    tables: jnp.ndarray,  # [b, blocks_per_slot] full per-row block tables
+    tables,  # noqa: ANN001 — [b, blocks_per_slot] full per-row block tables, one a cache kind where kinds mix
     pools: KVPools,
     cfg: llama.LlamaConfig,
     keys: jnp.ndarray,  # [b, 2] per-row PRNG keys for the first token

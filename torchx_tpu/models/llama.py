@@ -61,6 +61,9 @@ class LlamaConfig:
     n_heads: int = 32
     n_kv_heads: int = 8
     ffn_dim: int = 14336
+    # width of one attention head where the model states it apart from
+    # dim / n_heads (64 heads of 128 over a hidden size of 6,144); 0: dim / n_heads
+    attn_head_dim: int = 0
     max_seq: int = 8192
     rope_theta: float = 500000.0
     norm_eps: float = 1e-5
@@ -114,8 +117,30 @@ class LlamaConfig:
     qk_nope_dim: int = 0
     qk_rope_dim: int = 0
     v_head_dim: int = 0
+    # the attention of each layer, in the order the layers run: "sliding" (query
+    # i admits key j where i - sliding_window < j <= i) or "full" (j <= i).
+    # Empty: every layer is full. The pattern is data: a stack of mixed kinds
+    # runs in this order (scan_layers), and a sliding layer's cache is a pool of
+    # its own that holds a slot's window and no more (generate.init_kv_pools)
+    layer_types: tuple[str, ...] = ()
+    sliding_window: int = 0
+    # RMSNorm over each head's width on q and on k, a learned gain each
+    # (q_norm, k_norm), ahead of the rotary embedding
+    qk_norm: bool = False
+    # False: full-attention layers take no rotary embedding, sliding layers do
+    rope_full_layers: bool = True
 
     def __post_init__(self) -> None:
+        object.__setattr__(self, "layer_types", tuple(self.layer_types))
+        if self.layer_types:
+            if len(self.layer_types) != self.n_layers or set(self.layer_types) - {"sliding", "full"}:
+                raise ValueError(
+                    f"layer_types must name {self.n_layers} layers 'sliding' or 'full', got {self.layer_types!r}"
+                )
+            if "sliding" in self.layer_types and self.sliding_window < 1:
+                raise ValueError("a sliding layer needs sliding_window >= 1")
+            if self.kv_lora_rank:
+                raise ValueError("latent attention has no sliding layers")
         if self.kv_lora_rank and not (self.qk_nope_dim and self.qk_rope_dim and self.v_head_dim):
             raise ValueError(
                 "latent attention needs qk_nope_dim, qk_rope_dim and v_head_dim"
@@ -132,13 +157,29 @@ class LlamaConfig:
 
     @property
     def head_dim(self) -> int:
-        """Per-head projection width (dim / n_heads)."""
-        return self.dim // self.n_heads
+        """Per-head projection width (dim / n_heads unless stated)."""
+        return self.attn_head_dim or self.dim // self.n_heads
 
     @property
     def rope_dim(self) -> int:
         """Width of what the rotary embedding turns in a head."""
         return self.qk_rope_dim if self.kv_lora_rank else self.head_dim
+
+    @property
+    def cache_kinds(self) -> tuple[str, ...]:
+        """The cache kind of each layer: ``"window"`` for a sliding layer (its
+        pool holds the blocks that still touch a slot's window), else ``"full"``."""
+        return tuple("window" if t == "sliding" else "full" for t in self.layer_types) or ("full",) * self.n_layers
+
+    @property
+    def layer_period(self) -> int:
+        """The shortest period of the layers' kinds from layer 0 on (1: all alike)."""
+        kinds = self.cache_kinds
+        return next(p for p in range(1, len(kinds) + 1) if all(k == kinds[i % p] for i, k in enumerate(kinds)))
+
+    def layers_of(self, kind: str) -> int:
+        """How many layers keep a cache of ``kind``."""
+        return self.cache_kinds.count(kind)
 
     @property
     def cache_width(self) -> int:
@@ -164,7 +205,7 @@ class LlamaConfig:
                 + r  # kv_norm
             )
         hd = self.head_dim
-        return d * h * hd + 2 * d * self.n_kv_heads * hd + h * hd * d
+        return d * h * hd + 2 * d * self.n_kv_heads * hd + h * hd * d + (2 * hd if self.qk_norm else 0)
 
     def flops_per_token(self) -> float:
         """Training FLOPs/token (fwd+bwd), 6N + attention quadratic term."""
@@ -279,6 +320,9 @@ def init_params(cfg: LlamaConfig, key: jax.Array) -> Params:
             "wv": norm_init(ks[2], (L, d, kvh * hd), d),
             "wo": norm_init(ks[3], (L, h * hd, d), h * hd),
         }
+        if cfg.qk_norm:
+            attn["q_norm"] = jnp.ones((L, hd), dtype=cfg.dtype)
+            attn["k_norm"] = jnp.ones((L, hd), dtype=cfg.dtype)
     params: Params = {
         "embed": norm_init(k_embed, (cfg.vocab_size, d), d),
         "layers": {
@@ -323,6 +367,9 @@ def param_specs(cfg: LlamaConfig, pp: bool = False) -> Params:
             "wv": P(layer_axis, "fsdp", "tp"),
             "wo": P(layer_axis, "tp", "fsdp"),
         }
+        if cfg.qk_norm:
+            attn["q_norm"] = P(layer_axis, None)
+            attn["k_norm"] = P(layer_axis, None)
     specs: Params = {
         # vocab axis unsharded: a gather over a vocab-sharded table forces
         # the SPMD partitioner into full rematerialization; dim shards fine
@@ -352,25 +399,85 @@ def layer_groups(params: Params) -> tuple[str, ...]:
 _EXPERT_WEIGHTS = ("w_gate", "w_up", "w_down")
 
 
-def scan_layers(cfg: LlamaConfig, step, x, stack: Params):  # noqa: ANN001, ANN201
-    """``lax.scan`` of ``step(x, layer) -> (x, ys)`` over one group's stack of
-    equal layers, ``x`` any carry. A layer is its slice of every leaf and its
-    number in the group, ``layer["layer_index"]``: what a kernel reads (the
-    routed experts of a dropless expert layer, which go in whole beside it, for
+@jax.tree_util.register_static
+class _Kind(str):
+    """A layer's ``attn_kind`` as it rides in the layer's dict: a string to
+    whoever reads it, and to jax a node without leaves, so that the dict still
+    goes through ``jax.checkpoint`` and ``lax.scan`` as a tree of arrays."""
+
+
+def scan_layers(cfg: LlamaConfig, step, x, stack: Params, first: int = 0):  # noqa: ANN001, ANN201
+    """Run ``step(x, layer) -> (x, ys)`` over one group's stack of layers in
+    the order they lie, ``x`` any carry; ``first`` is the group's first layer's
+    number in the model. A layer is its slice of every leaf plus what says
+    where it stands: ``layer_index`` (its number in the group),
+    ``attn_kind`` (``cfg.cache_kinds``: ``"full"`` or ``"window"``, static) and
+    ``kind_index`` (how many layers of that kind run before it in the model:
+    its place in that kind's pool stack). What a kernel reads (the routed
+    experts of a dropless expert layer, which go in whole beside it, for
     :mod:`torchx_tpu.ops.grouped_matmul`; the paged pools a serving step carries
     in ``x``) would be copied if sliced out of its stack first, and is read at
-    that index where it lies instead. -> (x, stacked ys)."""
+    those indices where it lies instead.
+
+    Layers of one kind are one ``lax.scan`` with the stack as ``xs``, as it
+    always was. Where kinds alternate (``cfg.layer_types``) the scan runs over
+    whole periods of the pattern with the period's layers, each of its own
+    kind, in the body; the layers ahead of the group's first period boundary
+    and behind its last run one by one. There a layer is indexed out of the
+    stack where it lies, inside the loop: a slice or a reshape of the stack in
+    front of the loop is a copy of the weights every step (a reshape of ``xs``
+    to ``[periods, period, ...]`` made XLA re-lay and copy whole expert stacks,
+    3.5 GB each at Mixtral's widths: my chip run, PR 31). -> (x, stacked ys)."""
     n = jax.tree.leaves(stack)[0].shape[0]
     dropless = "w_router" in stack and getattr(cfg, "capacity_factor", 1.0) <= 0
     whole = {k: stack[k] for k in _EXPERT_WEIGHTS} if dropless else {}
     sliced = {k: w for k, w in stack.items() if k not in whole}
+    kinds, period = cfg.cache_kinds, cfg.layer_period
 
-    def body(x, xs):  # noqa: ANN001, ANN202
-        i, layer = xs
-        return step(x, dict(layer, **whole, layer_index=i))
+    def place(k: int, p=0):  # noqa: ANN001, ANN202 - layer k of the model, moved on by p periods
+        kind = _Kind(kinds[k])
+        return kind, p * kinds[:period].count(kind) + kinds[:k].count(kind)
+
+    def run(x, layer, i, kind, kind_index):  # noqa: ANN001, ANN202
+        return step(x, dict(layer, **whole, layer_index=i, attn_kind=kind, kind_index=kind_index))
+
+    if period == 1:
+
+        def body(x, xs):  # noqa: ANN001, ANN202
+            i, layer = xs
+            return run(x, layer, i, _Kind(kinds[first]), i + first if first else i)
+
+        with jax.named_scope(hot.LAYERS):
+            return jax.lax.scan(body, x, (jnp.arange(n, dtype=jnp.int32), sliced))
+
+    head = min(n, -first % period)
+    n_periods = (n - head) // period
+    stacked = lambda ys: jax.tree.map(lambda *a: jnp.stack(a), *ys)  # noqa: E731
+
+    def one_by_one(x, lo: int, hi: int):  # noqa: ANN001, ANN202
+        ys = []
+        for i in range(lo, hi):
+            x, y = run(x, {k: w[i] for k, w in sliced.items()}, i, *place(first + i))
+            ys.append(y)
+        return x, ([stacked(ys)] if ys else [])
+
+    def body(x, p):  # noqa: ANN001, ANN202
+        ys = []
+        for j in range(period):
+            i = head + p * period + j
+            layer = {k: jax.lax.dynamic_index_in_dim(w, i, keepdims=False) for k, w in sliced.items()}
+            x, y = run(x, layer, i, *place(first + head + j, p))
+            ys.append(y)
+        return x, stacked(ys)
 
     with jax.named_scope(hot.LAYERS):
-        return jax.lax.scan(body, x, (jnp.arange(n, dtype=jnp.int32), sliced))
+        x, parts = one_by_one(x, 0, head)
+        if n_periods:
+            x, ys = jax.lax.scan(body, x, jnp.arange(n_periods, dtype=jnp.int32))
+            parts.append(jax.tree.map(lambda y: y.reshape(-1, *y.shape[2:]), ys))
+        x, tail = one_by_one(x, head + n_periods * period, n)
+    parts += tail
+    return x, (parts[0] if len(parts) == 1 else jax.tree.map(lambda *a: jnp.concatenate(a), *parts))
 
 
 def model_fns(cfg: LlamaConfig):
@@ -451,6 +558,27 @@ def ffn(
     return down, jnp.zeros((AUX_LEN,), jnp.float32)  # aux vector: dense = zeros
 
 
+def window_of(cfg: LlamaConfig, layer: Params) -> int:
+    """The window of ``layer``'s attention: ``cfg.sliding_window`` on a sliding
+    layer (``scan_layers`` says which it is), 0 on a full one."""
+    return cfg.sliding_window if layer.get("attn_kind") == "window" else 0
+
+
+def norm_and_rotate(cfg: LlamaConfig, layer: Params, q, k, cos, sin, rope):  # noqa: ANN001, ANN201
+    """One layer's q and k ``[..., heads, hd]`` as attention takes them: normed
+    a head where the model has QK-norm, then rotated by ``rope(x, cos, sin)``
+    where this layer takes the rotary embedding (every layer, or with
+    ``rope_full_layers`` off the sliding ones alone). The uncached forward and
+    both serving programs share it, each with the rotation of its own layout."""
+    if cfg.qk_norm:
+        with jax.named_scope(hot.QK_NORM):
+            q = rms_norm(q, layer["q_norm"], cfg.norm_eps)
+            k = rms_norm(k, layer["k_norm"], cfg.norm_eps)
+    if cfg.rope_full_layers or window_of(cfg, layer):
+        q, k = rope(q, cos, sin), rope(k, cos, sin)
+    return q, k
+
+
 def _gqa_attention(
     cfg: LlamaConfig,
     mesh: Optional[Mesh],
@@ -466,8 +594,10 @@ def _gqa_attention(
     q = maybe_matmul(attn_in, layer["wq"], int8_training=i8_attn).reshape(b, s, h, hd)
     k = maybe_matmul(attn_in, layer["wk"], int8_training=i8_attn).reshape(b, s, kvh, hd)
     v = maybe_matmul(attn_in, layer["wv"], int8_training=i8_attn).reshape(b, s, kvh, hd)
-    q = apply_rope(q, cos, sin)
-    k = apply_rope(k, cos, sin)
+    window = window_of(cfg, layer)
+    q, k = norm_and_rotate(cfg, layer, q, k, cos, sin, apply_rope)
+    if window and (cfg.kernels != "reference" or cfg.use_ring_attention):
+        raise NotImplementedError("a sliding layer runs through ops.attention only, not the fused or ring kernels")
     if cfg.use_ring_attention and mesh is not None and mesh.shape.get("sp", 1) > 1:
         with jax.named_scope(hot.ATTN_KERNEL):
             attn_out = ring_attention(q, k, v, mesh)
@@ -498,6 +628,7 @@ def _gqa_attention(
                 block_q=cfg.attn_block_q,
                 block_kv=cfg.attn_block_kv,
                 mesh=mesh,
+                window=window,
             )
     # named so remat policies can SAVE the kernel output: the attention
     # kernels are not dot_generals, so "dots" alone recomputes the whole
@@ -530,7 +661,7 @@ def _layer(
     # attention block
     with jax.named_scope(hot.NORM):
         attn_in = rms_norm(x, layer["attn_norm"], cfg.norm_eps, mesh=mesh)
-    with jax.named_scope(hot.ATTN):
+    with jax.named_scope(hot.ATTN), hot.attn_kind_scope(cfg, layer):
         if cfg.kv_lora_rank:
             from torchx_tpu.models import mla
 
@@ -664,9 +795,9 @@ def features_from_embeddings(
     body = _remat(functools.partial(_layer, cfg, mesh, cos, sin), cfg)
 
     if pp > 1:
-        if "dense_layers" in params:
+        if "dense_layers" in params or cfg.layer_types:
             raise NotImplementedError(
-                "pipeline parallelism over a stack that leads with dense layers"
+                "pipeline parallelism over a stack that leads with dense layers or mixes attention kinds"
             )
         # pipeline the layer stack over the pp axis (embedding/head stay
         # outside the pipeline, replicated over pp)
@@ -706,10 +837,11 @@ def features_from_embeddings(
     else:
         # one scan a group of equal layers: leading dense layers, where
         # the tree has them, then the stack proper
-        aux_groups = []
+        aux_groups, first = [], 0
         for group in layer_groups(params):
-            x, aux_group = scan_layers(cfg, body, x, params[group])
+            x, aux_group = scan_layers(cfg, body, x, params[group], first)
             aux_groups.append(aux_group)
+            first += aux_group.shape[0]
         aux_per_layer = jnp.concatenate(aux_groups)
         # [L, AUX_LEN] per-layer aux: balance sums over layers (matches
         # the Switch loss), the monitoring stats average

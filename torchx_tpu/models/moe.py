@@ -28,6 +28,7 @@ of the config, not functions of their own.
 from __future__ import annotations
 
 import dataclasses
+import functools
 from typing import Any
 
 import jax
@@ -61,9 +62,20 @@ class MoEConfig(llama.LlamaConfig):
     # leading dense layers ahead of the expert layers (of n_layers in all):
     # the tree then has two groups, "dense_layers" and "layers"
     n_dense_layers: int = 0
+    # a chip's share of a layer's experts: of the n_experts the router scores
+    # and chooses over, the experts_held from experts_held_from on live here
+    # (0: all of them). The expert weights are [layers, held, ...]; a routing to
+    # an expert that lives elsewhere is weighed as published and not computed:
+    # what it would add is the other chips' to add (dropless dispatch only)
+    experts_held: int = 0
+    experts_held_from: int = 0
 
     def __post_init__(self) -> None:
         super().__post_init__()
+        if self.experts_held and not (
+            self.capacity_factor <= 0 and 0 <= self.experts_held_from <= self.n_experts - self.experts_held
+        ):
+            raise ValueError("a share of the experts needs the dropless dispatch and a range inside n_experts")
         if self.router_score not in ("softmax", "sigmoid"):
             raise ValueError(f"router_score must be 'softmax' or 'sigmoid', got {self.router_score!r}")
         if not 0 <= self.n_dense_layers < self.n_layers:
@@ -73,6 +85,11 @@ class MoEConfig(llama.LlamaConfig):
     def expert_width(self) -> int:
         """Intermediate width of one routed expert."""
         return self.expert_ffn_dim or self.ffn_dim
+
+    @property
+    def n_experts_held(self) -> int:
+        """Routed experts whose weights live here."""
+        return self.experts_held or self.n_experts
 
     def _expert_layer_extra(self, experts: int) -> int:
         """What an expert layer holds beyond a dense layer's count, with
@@ -86,9 +103,9 @@ class MoEConfig(llama.LlamaConfig):
         )
 
     def param_count(self) -> int:
-        """Exact parameter count (dense shapes + per-expert FFNs)."""
+        """Exact parameter count (dense shapes + the FFNs of the experts held)."""
         n_expert_layers = self.n_layers - self.n_dense_layers
-        return super().param_count() + n_expert_layers * self._expert_layer_extra(self.n_experts)
+        return super().param_count() + n_expert_layers * self._expert_layer_extra(self.n_experts_held)
 
     def flops_per_token(self) -> float:
         """MoE FLOPs count only the top_k ACTIVE experts per token."""
@@ -151,6 +168,7 @@ def init_params(cfg: MoEConfig, key: jax.Array) -> llama.Params:
     params = llama.init_params(cfg, key)
     nd = cfg.n_dense_layers
     L, E, d, f = cfg.n_layers - nd, cfg.n_experts, cfg.dim, cfg.expert_width
+    held = cfg.n_experts_held
     k_router, k_g, k_u, k_d, k_s = jax.random.split(jax.random.fold_in(key, 17), 5)
 
     def init(key, shape, in_dim):  # noqa: ANN001
@@ -165,9 +183,9 @@ def init_params(cfg: MoEConfig, key: jax.Array) -> llama.Params:
     layers["w_router"] = init(k_router, (L, d, E), d)
     if cfg.router_bias:
         layers["router_bias"] = jnp.zeros((L, E), dtype=cfg.dtype)
-    layers["w_gate"] = init(k_g, (L, E, d, f), d)
-    layers["w_up"] = init(k_u, (L, E, d, f), d)
-    layers["w_down"] = init(k_d, (L, E, f, d), f)
+    layers["w_gate"] = init(k_g, (L, held, d, f), d)
+    layers["w_up"] = init(k_u, (L, held, d, f), d)
+    layers["w_down"] = init(k_d, (L, held, f, d), f)
     if cfg.n_shared_experts:
         fs = cfg.n_shared_experts * f
         ks = jax.random.split(k_s, 3)
@@ -273,27 +291,37 @@ def _capacity_experts(cfg: MoEConfig, layer: llama.Params, x, gate_idx, gate_val
 def _dropless_experts(cfg: MoEConfig, layer: llama.Params, x, gate_idx, gate_vals):  # noqa: ANN001
     """Sorted dispatch: the (token, choice) rows in expert order, one
     grouped matmul a projection over the experts held, every row computed
-    by its expert whatever the imbalance. -> out."""
+    by its expert whatever the imbalance. Where this chip holds a share of
+    the experts (``cfg.experts_held``) the rows of experts that live elsewhere
+    sort behind every held expert's, belong to no group, are not multiplied
+    and add nothing: the sum is this chip's part of the routed sum. -> out."""
     from torchx_tpu.ops.grouped_matmul import grouped_matmul
 
     b, s, d = x.shape
-    E, k = cfg.n_experts, cfg.top_k
+    held, k = cfg.n_experts_held, cfg.top_k
     rows = b * s * k
     with jax.named_scope(hot.MOE_DISPATCH), jax.named_scope(hot.MOE_SORT):
         expert_of = gate_idx.reshape(rows)
+        if cfg.experts_held:
+            expert_of = expert_of - cfg.experts_held_from
+            here = (expert_of >= 0) & (expert_of < held)
+            expert_of = jnp.where(here, expert_of, held)  # elsewhere: behind the last held group, counted in none
         order = jnp.argsort(expert_of, stable=True)  # row r of the sorted batch is choice order[r]
-        group_sizes = jnp.zeros((E,), jnp.int32).at[expert_of].add(1)
+        group_sizes = jnp.zeros((held,), jnp.int32).at[expert_of].add(1)
         sorted_in = x.reshape(b * s, d)[order // k]  # [rows, d]
     with jax.named_scope(hot.MOE_EXPERTS):
         # under a layer scan the experts come as the whole stack and the layer's number
         # (llama.scan_layers): the grouped matmul reads its layer where it lies
         at = layer.get("layer_index")
-        gate = jax.nn.silu(grouped_matmul(sorted_in, layer["w_gate"], group_sizes, at))
-        up = grouped_matmul(sorted_in, layer["w_up"], group_sizes, at)
-        sorted_out = grouped_matmul(gate * up, layer["w_down"], group_sizes, at)  # [rows, d]
+        gmm = functools.partial(grouped_matmul, group_sizes=group_sizes, layer=at, spread_over=cfg.n_experts)
+        gate = jax.nn.silu(gmm(sorted_in, layer["w_gate"]))
+        up = gmm(sorted_in, layer["w_up"])
+        sorted_out = gmm(gate * up, layer["w_down"])  # [rows, d]
     with jax.named_scope(hot.MOE_COMBINE):
         # back to (token, choice) order by gather, then the weighted sum in float32
         unsorted = sorted_out[jnp.argsort(order)].reshape(b, s, k, d)
+        if cfg.experts_held:  # a row of no group is whatever the kernel's output buffer held
+            unsorted = jnp.where(here.reshape(b, s, k, 1), unsorted, 0)
         out = jnp.einsum("bskd,bsk->bsd", unsorted.astype(jnp.float32), gate_vals)
     return out.astype(x.dtype)
 

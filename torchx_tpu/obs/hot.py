@@ -17,6 +17,7 @@ Readers and tests import the names below, not strings.
 
 from __future__ import annotations
 
+import contextlib
 from typing import Any
 
 import jax
@@ -31,13 +32,15 @@ import jax
 # An admission round is enqueued behind the step in flight; its ``first`` is
 # still fetched before the round commits.
 
-SERVE_ADMIT = "serve.admit"  # rows, width, cached_tokens, tokens (prefilled), queue_depth, kv_bytes_per_token
+# kv_blocks_full, kv_blocks_window: blocks the slots hold in the full and in the window pools (a decode
+# turn: as its step is dispatched; an admission round: after it); window_blocks_released: a running count
+SERVE_ADMIT = "serve.admit"  # rows, width, cached_tokens, tokens (prefilled), queue_depth, kv_bytes_per_token, kv_blocks_*
 SERVE_ADMIT_PLAN = "serve.admit.plan"
 SERVE_ADMIT_BUILD = "serve.admit.build"
 SERVE_PREFILL_DISPATCH = "serve.prefill.dispatch"
 SERVE_PREFILL_FETCH = "serve.prefill.fetch"
 SERVE_ADMIT_COMMIT = "serve.admit.commit"
-SERVE_DECODE = "serve.decode"  # step, active (slots step N+1 steps); running counts: steps_overlapped, tokens_discarded
+SERVE_DECODE = "serve.decode"  # step, active (slots step N+1 steps), kv_blocks_*; running counts: steps_overlapped, tokens_discarded
 SERVE_DECODE_PREPARE = "serve.decode.prepare"
 SERVE_DECODE_DISPATCH = "serve.decode.dispatch"
 SERVE_DECODE_FETCH = "serve.decode.fetch"
@@ -76,6 +79,9 @@ TRAIN_CHECKPOINT = "train.checkpoint"
 LAYERS = "layers"  # the scan over the layer stack; its own time is the slicing of each layer's weights
 ATTN = "attn"  # projections, rope, the kernel call, output projection
 ATTN_KERNEL = "attn_kernel"
+ATTN_WINDOW = "attn_window"  # under attn, a stack of mixed kinds only: a sliding layer's attention
+ATTN_FULL = "attn_full"  # ... and a full layer's, so that a kernel's time divides by kind
+QK_NORM = "qk_norm"  # the per-head RMSNorm of q and k
 MLP = "mlp"
 NORM = "norm"
 LM_HEAD = "lm_head"
@@ -103,8 +109,17 @@ DEVICE_SCOPES = (
     LAYERS, ATTN, ATTN_KERNEL, MLP, NORM, LM_HEAD, LOSS, EMBED, MOE_ROUTER, MOE_DISPATCH,
     MOE_EXPERTS, MOE_COMBINE, PAGED_ATTENTION, GATHER_KV, SCORES, VALUES,
     APPEND_KV, SAMPLE, GRAD_CLIP, OPTIMIZER, MOE_SORT, MOE_SHARED, MLA_LATENT, MLA_ABSORB,
-    APPEND_LATENT,
+    APPEND_LATENT, ATTN_WINDOW, ATTN_FULL, QK_NORM,
 )  # fmt: skip
+
+
+def attn_kind_scope(cfg: Any, layer: dict):  # noqa: ANN201
+    """The scope of one layer's attention by kind, where the stack mixes kinds
+    (``cfg.layer_types``): ``attn_window`` or ``attn_full``. A stack of one
+    kind gets no further level: its paths stay as they were."""
+    if not getattr(cfg, "layer_types", ()):
+        return contextlib.nullcontext()
+    return jax.named_scope(ATTN_WINDOW if layer.get("attn_kind") == "window" else ATTN_FULL)
 
 
 def span(name: str, **attrs: Any) -> jax.profiler.TraceAnnotation:

@@ -75,9 +75,12 @@ def xla_attention(
     v: jnp.ndarray,
     causal: bool = True,
     segment_ids: Optional[jnp.ndarray] = None,
+    window: int = 0,
 ) -> jnp.ndarray:
     """Plain einsum softmax attention (f32 softmax, GQA via KV repeat);
-    runs everywhere and is the numerical reference for the kernels."""
+    runs everywhere and is the numerical reference for the kernels.
+    ``window`` > 0 (causal only) admits key ``j`` for query ``i`` where
+    ``i - window < j <= i``: a sliding layer's local mask."""
     n_rep = q.shape[2] // k.shape[2]
     k = _repeat_kv(k, n_rep)
     v = _repeat_kv(v, n_rep)
@@ -88,6 +91,8 @@ def xla_attention(
     s_q, s_k = q.shape[1], k.shape[1]
     if causal:
         mask = jnp.tril(jnp.ones((s_q, s_k), dtype=bool))
+        if window:
+            mask = mask & ~jnp.tril(jnp.ones((s_q, s_k), dtype=bool), -window)
         logits = jnp.where(mask[None, None], logits, -1e30)
     if segment_ids is not None:
         seg_mask = segment_ids[:, :, None] == segment_ids[:, None, :]
@@ -174,8 +179,11 @@ def splash_attention(
     block_kv: int = 0,
     segment_ids: Optional[jnp.ndarray] = None,
     interpret: bool = False,
+    window: int = 0,
 ) -> jnp.ndarray:
-    """Splash attention: GQA-native flash (no KV head repeat).
+    """Splash attention: GQA-native flash (no KV head repeat). ``window`` > 0
+    (causal) is the kernel's local mask: ``window - 1`` keys to the left of
+    the diagonal and none to the right, whole blocks outside it skipped.
 
     KV stays at ``n_kv_heads`` all the way into the kernel — at Llama-3
     GQA ratios that is 4x less KV HBM traffic than ``pallas_attention``'s
@@ -186,6 +194,7 @@ def splash_attention(
         BlockSizes,
         CausalMask,
         FullMask,
+        LocalMask,
         MultiHeadMask,
         SegmentIds,
         make_splash_mha,
@@ -204,7 +213,10 @@ def splash_attention(
             f" of 128; got q_seq={s_q}, kv_seq={s_k}"
             " (use impl='xla' for ragged shapes)"
         )
-    one_head = CausalMask((s_q, s_k)) if causal else FullMask((s_q, s_k))
+    if window and causal:
+        one_head = LocalMask((s_q, s_k), window_size=(window - 1, 0), offset=0)
+    else:
+        one_head = CausalMask((s_q, s_k)) if causal else FullMask((s_q, s_k))
     mask = MultiHeadMask([one_head] * h)
     kernel = make_splash_mha(
         mask,
@@ -313,14 +325,21 @@ def attention(
     block_q: int = 0,
     block_kv: int = 0,
     mesh=None,
+    window: int = 0,
 ) -> jnp.ndarray:
     """[b, s, heads, head_dim] x3 -> [b, s, heads, head_dim].
+
+    ``window`` > 0 makes the causal mask local (a sliding layer): splash's
+    local mask where splash is chosen, the XLA function's elsewhere;
+    ``traced("attention")`` answers ``splash_local`` / ``xla_local``.
 
     ``mesh`` (a jax.sharding.Mesh) must be passed when batch or heads are
     sharded and a Pallas kernel may be selected: Mosaic kernels cannot be
     automatically partitioned, so the kernel runs under a shard_map over
     the (dp, fsdp) batch axes and the tp head axis.
     """
+    if window and (impl == "pallas" or not causal):
+        raise ValueError("a window needs the causal mask and the splash or XLA path")
     if impl == "pallas" and segment_ids is not None:
         raise ValueError(
             "the pallas flash-attention path does not support segment_ids;"
@@ -342,7 +361,7 @@ def attention(
             def kernel(q, k, v, seg):  # noqa: ANN001
                 return splash_attention(
                     q, k, v, causal=causal, block_q=block_q,
-                    block_kv=block_kv, segment_ids=seg,
+                    block_kv=block_kv, segment_ids=seg, window=window,
                 )
         else:
 
@@ -351,11 +370,11 @@ def attention(
                     q, k, v, causal=causal, block_q=block_q, block_kv=block_kv
                 )
 
-        note_traced("attention", "splash" if use_splash else "pallas_flash")
+        note_traced("attention", ("splash_local" if window else "splash") if use_splash else "pallas_flash")
         if mesh is None:
             return kernel(q, k, v, segment_ids)
         return _shard_wrap(
             kernel, q, k, v, segment_ids, mesh, ("dp", "fsdp"), "tp"
         )
-    note_traced("attention", "xla")
-    return xla_attention(q, k, v, causal=causal, segment_ids=segment_ids)
+    note_traced("attention", "xla_local" if window else "xla")
+    return xla_attention(q, k, v, causal=causal, segment_ids=segment_ids, window=window)

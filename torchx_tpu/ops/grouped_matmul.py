@@ -89,8 +89,15 @@ def grouped_matmul(
     group_sizes: jnp.ndarray,  # [g] int32, summing to m
     layer: jnp.ndarray | None = None,  # scalar int32: which of ``rhs``'s L layers
     interpret: bool = False,
+    spread_over: int = 0,
 ) -> jnp.ndarray:
     """-> ``[m, n]`` in ``lhs``'s dtype, accumulated in float32.
+    ``group_sizes`` may sum to less than ``m`` (a chip's share of the experts:
+    the rows of experts held elsewhere lie behind the last group): those rows
+    of the result are not computed and hold whatever the buffer held.
+    ``spread_over`` is the number of groups the ``m`` rows were drawn over where
+    that is more than ``rhs`` holds (the published experts): a group's mean
+    size, which the row tile is chosen by, is ``m / spread_over``.
     ``interpret`` runs the Pallas kernel in its interpreter (the CPU tests)."""
     group_sizes = group_sizes.astype(jnp.int32)
     g = rhs.shape[-3]
@@ -103,7 +110,7 @@ def grouped_matmul(
             every = jnp.zeros((rhs.shape[0] * g,), jnp.int32)
             group_sizes = jax.lax.dynamic_update_slice(every, group_sizes, (layer * g,))
             rhs = rhs.reshape(rhs.shape[0] * g, *rhs.shape[2:])
-        tiling = _tiling(lhs.shape[0], lhs.shape[1], rhs.shape[2], rhs.dtype.itemsize, g)
+        tiling = _tiling(lhs.shape[0], lhs.shape[1], rhs.shape[2], rhs.dtype.itemsize, spread_over or g)
         return gmm(lhs, rhs, group_sizes, preferred_element_type=lhs.dtype, tiling=tiling, interpret=interpret)
     note_traced("grouped_matmul", "ragged_dot")
     return jax.lax.ragged_dot(lhs, rhs[layer] if rhs.ndim == 4 else rhs, group_sizes)

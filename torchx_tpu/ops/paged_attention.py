@@ -36,7 +36,12 @@ chat cell's sizes). One layer's pool with no ``layer`` is a stack of one
   against. ``ops.attention.traced("attention")`` says which one a program
   lowered to (``paged_pallas`` / ``paged_xla``);
 * :func:`paged_attention_chunk` — the same for a chunk of query tokens per
-  slot (prefill); always the gather;
+  slot (prefill): a walk over the key blocks with a running softmax, as far as
+  the chunk's last token reaches (``traced`` answers ``paged_walk``);
+* a sliding layer (``window``) in both: decode reads a ring table that holds
+  only the blocks its window touches (``paged_pallas_window`` /
+  ``paged_xla_window``), prefill skips the key blocks below the window
+  (``paged_walk_window``);
 * :func:`gather_kv` — block-table gather back to a contiguous
   ``[slots, S, kvh, hd]`` view (S = blocks_per_slot * block_size);
 * :func:`append_kv` / :func:`scatter_kv_chunk` — scatter one new K/V token
@@ -125,8 +130,14 @@ def paged_attention(
     tables: jnp.ndarray,  # [slots, blocks_per_slot] int32
     lengths: jnp.ndarray,  # [slots] int32 — valid tokens (incl. current)
     layer=None,  # noqa: ANN001 — the pools are stacks [layers, num_blocks, ...]: attend this layer's
+    window: int = 0,
 ) -> jnp.ndarray:
     """Single-token decode attention against the paged cache.
+
+    ``window`` > 0 is a sliding layer's: slot ``i`` attends the positions
+    ``lengths[i] - window <= p < lengths[i]`` only, and ``tables`` is a ring
+    (block ``b`` of the sequence at entry ``b % blocks_per_slot``) that holds
+    the blocks the window touches and no others (:func:`ring_positions`).
 
     Positions at or beyond ``lengths[i]`` — unwritten block tails and every
     unassigned (trash) block — are out of slot ``i``'s softmax. Returns
@@ -141,10 +152,19 @@ def paged_attention(
         # imported here: Pallas costs a second that no CPU process should pay
         from torchx_tpu.ops.paged_attention_kernel import paged_attention_pallas
 
-        note_traced("attention", "paged_pallas")
-        return paged_attention_pallas(q, k_pool, v_pool, tables, lengths, layer=layer)
-    note_traced("attention", "paged_xla")
-    return paged_attention_xla(q, k_pool, v_pool, tables, lengths, layer)
+        note_traced("attention", "paged_pallas_window" if window else "paged_pallas")
+        return paged_attention_pallas(q, k_pool, v_pool, tables, lengths, layer=layer, window=window)
+    note_traced("attention", "paged_xla_window" if window else "paged_xla")
+    return paged_attention_xla(q, k_pool, v_pool, tables, lengths, layer, window)
+
+
+def ring_positions(lengths: jnp.ndarray, window: int, bpr: int, bs: int) -> jnp.ndarray:
+    """The sequence position of every row of a gathered ring table, ``[slots,
+    bpr * bs]``: entry ``e`` holds the one block ``b`` with ``b % bpr == e``
+    among the ``bpr`` blocks from the window's first on."""
+    first = jnp.maximum(lengths - window, 0)[:, None] // bs  # [slots, 1]
+    block = first + (jnp.arange(bpr)[None, :] - first) % bpr  # [slots, bpr]
+    return (block[:, :, None] * bs + jnp.arange(bs)).reshape(lengths.shape[0], bpr * bs)
 
 
 def paged_attention_xla(
@@ -154,10 +174,12 @@ def paged_attention_xla(
     tables: jnp.ndarray,  # [slots, blocks_per_slot] int32
     lengths: jnp.ndarray,  # [slots] int32
     layer=None,  # noqa: ANN001
+    window: int = 0,
 ) -> jnp.ndarray:
     """:func:`paged_attention` in plain XLA: gather every slot's whole
     window, fold query heads onto cache heads by repetition (same as the
-    dense path's ``_cached_attention``), mask by ``lengths``. The CPU path
+    dense path's ``_cached_attention``), mask by ``lengths`` (and below the
+    window, the rows placed by :func:`ring_positions`). The CPU path
     and the reference the kernel is tested against."""
     slots, h, d = q.shape
     with jax.named_scope(hot.GATHER_KV):
@@ -173,11 +195,23 @@ def paged_attention_xla(
             * d**-0.5
         )
         S = k.shape[1]
-        mask = jnp.arange(S)[None, :] < lengths[:, None]  # [slots, S]
+        if window:
+            at = ring_positions(lengths, window, tables.shape[1], S // tables.shape[1])
+            mask = (at < lengths[:, None]) & (at >= lengths[:, None] - window)
+        else:
+            mask = jnp.arange(S)[None, :] < lengths[:, None]  # [slots, S]
         logits = jnp.where(mask[:, None, :], logits, -1e30)
         probs = jax.nn.softmax(logits, axis=-1).astype(q.dtype)
     with jax.named_scope(hot.VALUES):
         return jnp.einsum("sht,sthd->shd", probs, v)
+
+
+#: query rows of one block of the prefill scores, and the cached rows one step
+#: of its walk over the key blocks gathers and scores: ``[slots, h, 512, 512]``
+#: float32 a step, whatever ``max_seq`` is
+_PREFILL_Q_ROWS = 512
+_PREFILL_K_ROWS = 512
+_MASKED = -1e30
 
 
 @jax.named_scope(hot.PAGED_ATTENTION)
@@ -185,40 +219,90 @@ def paged_attention_chunk(
     q: jnp.ndarray,  # [slots, t, h, hd] — a chunk of query tokens per slot
     k_pool: jnp.ndarray,  # [num_blocks, bs, kvh, hd]
     v_pool: jnp.ndarray,
-    tables: jnp.ndarray,  # [slots, blocks_per_slot] int32
+    tables: jnp.ndarray,  # [slots, blocks_per_slot] int32, block b of the sequence at entry b
     positions: jnp.ndarray,  # [slots, t] int32 — absolute position of each query
+    valid: jnp.ndarray | None = None,  # [slots, t] bool — real tokens (None: all)
     layer=None,  # noqa: ANN001
+    window: int = 0,
 ) -> jnp.ndarray:
     """Multi-query-token attention against the paged cache.
 
     The chunked-prefill generalisation of :func:`paged_attention`: query
     ``j`` of slot ``i`` sits at absolute position ``positions[i, j]`` and
-    attends causally to every cached position ``s <= positions[i, j]`` —
-    which covers both a previously-cached shared prefix *and* the chunk's
+    attends causally to every cached position ``s <= positions[i, j]`` (on a
+    sliding layer, ``window`` > 0, to those above ``positions[i, j] - window``)
+    — which covers both a previously-cached shared prefix *and* the chunk's
     own K/V, provided the caller scattered the chunk into the pool first.
-    Padded query rows produce garbage that the caller never samples.
-    Returns ``[slots, t, h, hd]``.
+
+    A walk over the key blocks: :data:`_PREFILL_Q_ROWS` query rows at a time
+    gather :data:`_PREFILL_K_ROWS` cached rows a step through the table, from
+    the block the first real query's window starts in (block 0 on a full
+    layer) as far as the last real query reaches and no further, and fold them
+    into an online softmax (running maximum, sum and accumulator in float32):
+    no ``[.., t, max_seq]`` array exists, and a round costs what its rows hold.
+    The query heads of a cache head are scored together against K and V as
+    they lie, not repeated. Padded query rows produce finite garbage that the
+    caller never samples. Returns ``[slots, t, h, hd]``.
     """
-    note_traced("attention", "paged_xla")
+    note_traced("attention", "paged_walk_window" if window else "paged_walk")
     slots, t, h, d = q.shape
-    with jax.named_scope(hot.GATHER_KV):
-        k = gather_kv(k_pool, tables, layer)  # [slots, S, kvh, hd]
-        v = gather_kv(v_pool, tables, layer)
-        n_rep = h // k.shape[2]
-        if n_rep > 1:
-            k = jnp.repeat(k, n_rep, axis=2)
-            v = jnp.repeat(v, n_rep, axis=2)
-    with jax.named_scope(hot.SCORES):
-        logits = (
-            jnp.einsum("sqhd,skhd->shqk", q, k, preferred_element_type=jnp.float32)
-            * d**-0.5
+    kvh, bs, bpr = k_pool.shape[-2], k_pool.shape[-3], tables.shape[1]
+    step_blocks = max(1, min(bpr, _PREFILL_K_ROWS // bs))
+    step_rows = step_blocks * bs
+    # whole steps: the blocks past the table are the trash block, past every position
+    tables = jnp.pad(tables, ((0, 0), (0, -bpr % step_blocks)), constant_values=TRASH_BLOCK)
+    valid = jnp.ones((slots, t), bool) if valid is None else valid
+    grouped = q.reshape(slots, t, kvh, h // kvh, d)
+
+    def rows(q_rows, pos_rows, valid_rows):  # noqa: ANN001, ANN202
+        n_q = q_rows.shape[1]
+
+        def step(c, carry):  # noqa: ANN001, ANN202
+            m, l, acc = carry
+            with jax.named_scope(hot.GATHER_KV):
+                held = jax.lax.dynamic_slice_in_dim(tables, c * step_blocks, step_blocks, axis=1)
+                k = gather_kv(k_pool, held, layer)  # [slots, step_rows, kvh, hd]
+                v = gather_kv(v_pool, held, layer)
+            with jax.named_scope(hot.SCORES):
+                s = jnp.einsum("bqgrd,bkgd->bgrqk", q_rows, k, preferred_element_type=jnp.float32) * d**-0.5
+                at = c * step_rows + jnp.arange(step_rows)
+                admitted = at[None, None, :] <= pos_rows[:, :, None]  # [slots, n_q, step_rows]
+                if window:
+                    admitted &= at[None, None, :] > pos_rows[:, :, None] - window
+                s = jnp.where(admitted[:, None, None], s, _MASKED)
+                m_new = jnp.maximum(m, s.max(axis=-1))
+                alpha = jnp.exp(m - m_new)
+                p = jnp.exp(s - m_new[..., None])
+                l = alpha * l + p.sum(axis=-1)
+            with jax.named_scope(hot.VALUES):
+                pv = jnp.einsum("bgrqk,bkgd->bgrqd", p.astype(q.dtype), v, preferred_element_type=jnp.float32)
+            return m_new, l, alpha[..., None] * acc + pv
+
+        # a query admits its own position, so its maximum ends finite; a step in
+        # which a row admits nothing adds exp(0) terms that the first admitted
+        # score's alpha = exp(-1e30 - m) = 0 wipes out
+        last = jnp.max(jnp.where(valid_rows, pos_rows, 0))
+        first = jnp.minimum(jnp.min(jnp.where(valid_rows, pos_rows, 2**30)), last)
+        lo = jnp.maximum(first - window + 1, 0) // step_rows if window else 0
+        _, l, acc = jax.lax.fori_loop(
+            lo,
+            last // step_rows + 1,
+            step,
+            (
+                jnp.full((slots, kvh, h // kvh, n_q), _MASKED, jnp.float32),
+                jnp.zeros((slots, kvh, h // kvh, n_q), jnp.float32),
+                jnp.zeros((slots, kvh, h // kvh, n_q, d), jnp.float32),
+            ),
         )
-        S = k.shape[1]
-        mask = jnp.arange(S)[None, None, :] <= positions[:, :, None]  # [slots, t, S]
-        logits = jnp.where(mask[:, None, :, :], logits, -1e30)
-        probs = jax.nn.softmax(logits, axis=-1).astype(q.dtype)
-    with jax.named_scope(hot.VALUES):
-        return jnp.einsum("shqk,skhd->sqhd", probs, v)
+        out = (acc / l[..., None]).astype(q.dtype)  # [slots, kvh, rep, n_q, hd]
+        return jnp.moveaxis(out, 3, 1).reshape(slots, n_q, h, d)
+
+    n = max(1, t // _PREFILL_Q_ROWS)
+    if n == 1:
+        return rows(grouped, positions, valid)
+    split = lambda x: jnp.moveaxis(x.reshape(slots, n, t // n, *x.shape[2:]), 1, 0)  # noqa: E731
+    out = jax.lax.map(lambda a: rows(*a), (split(grouped), split(positions), split(valid)))
+    return jnp.moveaxis(out, 0, 1).reshape(slots, t, h, d)
 
 
 def _write_rows(pool, layer, block_ids, offsets, rows):  # noqa: ANN001, ANN202
@@ -266,8 +350,10 @@ def append_kv(
     positions: jnp.ndarray,  # [slots] — logical position being written
     new: jnp.ndarray,  # [slots, kvh, hd]
     layer=None,  # noqa: ANN001
+    ring: bool = False,
 ) -> jnp.ndarray:
-    """Scatter one new K (or V) token per slot into its table position.
+    """Scatter one new K (or V) token per slot into its table position
+    (``ring``: a sliding layer's table, block ``b`` at entry ``b % blocks_per_slot``).
 
     Slots whose table entry for ``positions[i] // block_size`` is the
     trash block (inactive slots) harmlessly overwrite trash; collisions
@@ -275,7 +361,8 @@ def append_kv(
     """
     slots = tables.shape[0]
     bs = pool.shape[1 if layer is None else 2]
-    block_ids = tables[jnp.arange(slots), positions // bs]  # [slots]
+    entry = positions // bs
+    block_ids = tables[jnp.arange(slots), entry % tables.shape[1] if ring else entry]  # [slots]
     offsets = positions % bs
     return _write_rows(pool, layer, block_ids, offsets, new)
 

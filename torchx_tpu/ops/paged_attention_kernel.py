@@ -36,6 +36,7 @@ def _decode_kernel(
     *,
     bpr: int,
     scale: float,
+    window: int,
 ):
     _, chunk, bs, kvh, hd = k_buf.shape
     h = q_ref.shape[0]
@@ -45,7 +46,14 @@ def _decode_kernel(
     # einsum on the TPU does not (errors of 1e-2 against 2e-6, PERF.md section 6).
     precision = jax.lax.Precision.HIGHEST if k_buf.dtype == jnp.float32 else None
 
+    def first_block(s):  # noqa: ANN001, ANN202
+        """The lowest block a slot's query reads: 0, or on a sliding layer the
+        block of the window's first position (``window`` is static)."""
+        return jnp.maximum(lengths_ref[s] - window, 0) // bs if window else 0
+
     def live_blocks(s):  # noqa: ANN001, ANN202
+        if window:  # the table is a ring: the blocks from the window's first to the last written
+            return jnp.maximum(pl.cdiv(lengths_ref[s], bs), 1) - first_block(s)
         return jnp.clip(pl.cdiv(lengths_ref[s], bs), 1, bpr)
 
     def each_copy(s, c, buf, act):  # noqa: ANN001, ANN202
@@ -53,7 +61,8 @@ def _decode_kernel(
         first = c * chunk
 
         def one(j, _):  # noqa: ANN001, ANN202
-            blk = tables_ref[s * bpr + first + j]
+            at = (first_block(s) + first + j) % bpr if window else first + j
+            blk = tables_ref[s * bpr + at]
             act(pltpu.make_async_copy(k_hbm.at[blk], k_buf.at[buf, j], sems.at[0, buf]))
             act(pltpu.make_async_copy(v_hbm.at[blk], v_buf.at[buf, j], sems.at[1, buf]))
 
@@ -95,7 +104,12 @@ def _decode_kernel(
         s = jax.lax.dot_general(
             q, k, (((1,), (1,)), ((), ())), precision=precision, preferred_element_type=jnp.float32
         ) * scale  # [h, rows]: every query head against every cache head
-        s = jnp.where(pos_ref[...] < length - c * chunk * bs, s, _MASKED)
+        if window:
+            base = (first_block(slot) + c * chunk) * bs
+            admitted = jnp.logical_and(pos_ref[...] < length - base, pos_ref[...] >= length - window - base)
+        else:
+            admitted = pos_ref[...] < length - c * chunk * bs
+        s = jnp.where(admitted, s, _MASKED)
         m_new = jnp.maximum(m, s.max(axis=-1, keepdims=True))
         alpha = jnp.exp(m - m_new)
         p = jnp.exp(s - m_new)
@@ -125,8 +139,15 @@ def paged_attention_pallas(
     lengths: jnp.ndarray,  # [slots] int32
     interpret: bool = False,
     layer=None,  # noqa: ANN001
+    window: int = 0,
 ) -> jnp.ndarray:
     """:func:`paged_attention` as one ragged Pallas TPU kernel.
+
+    ``window`` > 0 is a sliding layer: slot ``i`` attends the positions
+    ``lengths[i] - window <= p < lengths[i]`` and its table is a ring, block
+    ``b`` of the sequence at entry ``b % blocks_per_slot``; the step copies the
+    ``ceil(window / bs) + 1`` blocks at most that the window touches, whatever
+    the context, and masks the positions below the window in the first of them.
 
     With ``layer`` the pools are the stacks ``[layers, num_blocks, bs, kvh,
     hd]`` a layer scan carries, read where they lie: block ``b`` of layer
@@ -162,7 +183,7 @@ def paged_attention_pallas(
     per_slot = pl.BlockSpec((None, h, hd), lambda i, *_: (i, 0, 0))
     in_hbm = pl.BlockSpec(memory_space=pl.ANY)
     return pl.pallas_call(
-        functools.partial(_decode_kernel, bpr=bpr, scale=hd**-0.5),
+        functools.partial(_decode_kernel, bpr=bpr, scale=hd**-0.5, window=window),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2,
             grid=(slots,),

@@ -40,6 +40,14 @@ This engine keeps a **fixed slot array** decoding continuously:
   Cached blocks are shared by refcount — a shared tail block about to be
   written is copy-on-write copied first, and under pool pressure the
   engine evicts cache-only blocks before preempting live slots;
+* **two kinds of cache in one manager**: a model that mixes sliding and full
+  attention layers (``cfg.layer_types``) keeps a pool a kind. The full layers'
+  blocks are paged by a slot's table as above; in the sliding layers' pools a
+  slot holds a ring of :func:`~torchx_tpu.serve.kv_pool.window_ring` blocks,
+  and the oldest goes back to that pool's allocator as soon as every
+  position in it is below every future query's window (while decoding, and at
+  the end of a prefill round, which stages a block for every block of its
+  rows). Preemption frees both; a prefix-cache node holds a block of each;
 * **disaggregation seams**: a request marked ``prefill_only`` completes
   at prefill with its KV blocks exported as a
   :class:`~torchx_tpu.serve.kv_transfer.KvPayload` (the prefill-replica
@@ -77,7 +85,7 @@ from torchx_tpu.models import llama
 from torchx_tpu.obs import hot
 from torchx_tpu.obs import metrics as obs_metrics
 from torchx_tpu.ops.paged_attention import TRASH_BLOCK
-from torchx_tpu.serve.kv_pool import BlockAllocator, PoolPlan, SlotTables
+from torchx_tpu.serve.kv_pool import BlockAllocator, PoolPlan, SlotTables, WindowTables, window_ring
 from torchx_tpu.serve.kv_transfer import KvPayload, new_request_id
 from torchx_tpu.serve.prefix_cache import PrefixCache
 
@@ -180,6 +188,9 @@ class _Admit:
     cached_blocks: list[int]  # retained from the prefix cache
     cached_tokens: int  # block-aligned prefix length served from cache
     new_blocks: list[int]  # freshly allocated for the suffix
+    #: the sliding layers' pool, where the model has one: block of the sequence
+    #: -> block, for the cached prefix's last blocks and for every new block
+    window_blocks: dict[int, int] = dataclasses.field(default_factory=dict)
 
 
 @dataclasses.dataclass
@@ -228,6 +239,7 @@ class ServeEngine:
         max_slots: int = 8,
         block_size: int = 16,
         num_blocks: Optional[int] = None,
+        num_window_blocks: Optional[int] = None,
         max_prefill_batch: int = 4,
         enable_prefix_cache: bool = True,
         prefix_cache_reserve: float = 0.0,
@@ -241,6 +253,7 @@ class ServeEngine:
         self.max_slots = max_slots
         self.block_size = block_size
         self.blocks_per_slot = math.ceil(cfg.max_seq / block_size)
+        self.max_prefill_batch = max(1, max_prefill_batch)
         if num_blocks is None:
             num_blocks = 1 + max_slots * max(1, self.blocks_per_slot // 2)
         if num_blocks < self.blocks_per_slot + 1:
@@ -249,17 +262,32 @@ class ServeEngine:
                 f" ({self.blocks_per_slot} blocks + trash)"
             )
         self.num_blocks = num_blocks
-        self.max_prefill_batch = max(1, max_prefill_batch)
         self._clock = clock
         self._sleep = sleep
 
-        self.pools = gen.init_kv_pools(cfg, num_blocks, block_size)
-        #: bytes one cached token holds over all layers, as the pools are laid out
-        self.kv_bytes_per_token = sum(
-            p.shape[0] * math.prod(p.shape[3:]) * p.dtype.itemsize for p in jax.tree.leaves(self.pools)
+        #: the sliding layers' window (0: the model has none) and the entries of a
+        #: slot's ring table in their pools
+        self.window = cfg.sliding_window if cfg.layers_of("window") else 0
+        self.window_ring = window_ring(self.window, block_size) if self.window else 0
+        if self.window and num_window_blocks is None:
+            # every slot's ring, and the rows of one prefill round staged whole
+            num_window_blocks = 1 + max_slots * self.window_ring + self.max_prefill_batch * self.blocks_per_slot
+        self.num_window_blocks = num_window_blocks if self.window else 0
+        self.pools = gen.init_kv_pools(cfg, num_blocks, block_size, self.num_window_blocks)
+        row_bytes = lambda pools: sum(  # noqa: E731 - a token's bytes over the layers of a pool tree
+            p.shape[0] * math.prod(p.shape[3:]) * p.dtype.itemsize for p in jax.tree.leaves(pools)
+        )
+        #: bytes a further token of context holds, as the pools are laid out:
+        #: every layer's, but for the sliding layers, whose cost a slot is constant
+        self.kv_bytes_per_token = row_bytes(self._pools_of("full"))
+        self.kv_bytes_per_slot_window = (
+            self.window_ring * block_size * row_bytes(self.pools["window"]) if self.window else 0
         )
         self.alloc = BlockAllocator(num_blocks)
         self.tables = SlotTables(max_slots, self.blocks_per_slot)
+        self.window_alloc = BlockAllocator(self.num_window_blocks) if self.window else None
+        self.window_tables = WindowTables(max_slots, self.window_ring) if self.window else None
+        self.window_blocks_released = 0  # window blocks slots gave back as their windows moved on
         self._slots: list[Optional[_SlotState]] = [None] * max_slots
         self._admit_counter = itertools.count()
         self.prefix_cache: Optional[PrefixCache] = None
@@ -270,7 +298,12 @@ class ServeEngine:
                 else None
             )
             self.prefix_cache = PrefixCache(
-                self.alloc, block_size, max_blocks=cap
+                self.alloc,
+                block_size,
+                max_blocks=cap,
+                window_alloc=self.window_alloc,
+                # the blocks ahead of a suffix that its first query's window reaches into
+                window_back=math.ceil((self.window - 1) / block_size) if self.window else 0,
             )
 
         self._lock = threading.Lock()
@@ -335,8 +368,22 @@ class ServeEngine:
             max_slots=plan.max_slots,
             block_size=plan.block_size,
             num_blocks=plan.num_blocks,
+            num_window_blocks=plan.num_window_blocks or None,
             **kwargs,
         )
+
+    def _window_first_block(self, query_pos: int) -> int:
+        """The lowest block of a sequence that the window of a query at
+        ``query_pos``, and so of every later one, still touches."""
+        return max(0, query_pos - self.window + 1) // self.block_size
+
+    def _pools_of(self, kind: str):  # noqa: ANN202
+        """The pools of one cache kind: the whole tree where the model has one kind."""
+        return self.pools[kind] if self.window else self.pools
+
+    def _tables_arg(self, full, window):  # noqa: ANN001, ANN202
+        """What the programs take as ``tables``: one array, or one a cache kind."""
+        return {"full": jnp.asarray(full), "window": jnp.asarray(window)} if self.window else jnp.asarray(full)
 
     # -- public API --------------------------------------------------------
 
@@ -422,24 +469,34 @@ class ServeEngine:
                 if not self._handoffs or not free:
                     return worked
                 h = self._handoffs[0]
-                blocks = self._alloc_pressure(
-                    math.ceil(h.cache_len / self.block_size)
-                )
-                if blocks is None:
+                n = math.ceil(h.cache_len / self.block_size)
+                blocks = self._alloc_pressure(n)
+                # of a sliding layer's blocks, those the next query's window still touches
+                keep_from = self._window_first_block(h.cache_len) if self.window else n
+                kept = self._alloc_pressure(n - keep_from, "window") if self.window and blocks is not None else []
+                if blocks is None or kept is None:
+                    if blocks:
+                        self.alloc.release(blocks)
                     return worked  # pool pressure; retry next loop pass
+                window_blocks = dict(zip(range(keep_from, n), kept))
                 self._handoffs.popleft()
                 self._admitting = [h.req]  # visible to drain() until slotted
             with hot.span(
                 hot.SERVE_KV_IMPORT, blocks=len(blocks), cache_len=h.cache_len
             ):
                 idx = jnp.asarray(np.asarray(blocks, np.int32))
-                self.pools = gen.import_blocks(self.pools, idx, h.k, h.v)
+                self.pools = gen.import_blocks(
+                    self.pools, idx, h.k, h.v, self._cfg.layer_types and self._cfg.cache_kinds,
+                    self._window_ids(window_blocks, n),
+                )  # fmt: skip
             seq = list(h.req.prompt) + h.req.generated
             if self.prefix_cache is not None:
-                self.prefix_cache.insert(seq[: h.cache_len], blocks)
+                self.prefix_cache.insert(seq[: h.cache_len], blocks, window_blocks)
             slot = free[0]
             self.tables.assign(slot, blocks)
             self.tables.lengths[slot] = h.cache_len
+            for b, block in window_blocks.items():
+                self.window_tables.assign(slot, b, block)
             self._slots[slot] = _SlotState(
                 req=h.req,
                 cache_len=h.cache_len,
@@ -495,6 +552,9 @@ class ServeEngine:
                 "steps_overlapped": self.steps_overlapped,
                 "tokens_discarded": self.tokens_discarded,
                 "kv_bytes_per_token": self.kv_bytes_per_token,
+                "kv_bytes_per_slot_window": self.kv_bytes_per_slot_window,
+                "kv_blocks_window_used": self.window_alloc.used_blocks if self.window else 0,
+                **self._kv_blocks(),
                 "draining": self._draining,
                 "failed": self.failed,
             }
@@ -620,15 +680,27 @@ class ServeEngine:
             _next_pow2(self._cfg.max_seq),
         )
 
-    def _alloc_pressure(self, n: int) -> Optional[list[int]]:
-        """:meth:`BlockAllocator.alloc` that spills cache-only blocks
-        first: under pool pressure, LRU prefix-cache entries are cheaper
-        to reclaim than preempting a live slot."""
-        blocks = self.alloc.alloc(n)
+    def _alloc_pressure(self, n: int, kind: str = "full") -> Optional[list[int]]:
+        """:meth:`BlockAllocator.alloc` from the pool of ``kind`` that spills
+        cache-only blocks first: under pool pressure, LRU prefix-cache entries
+        are cheaper to reclaim than preempting a live slot."""
+        alloc = self.window_alloc if kind == "window" else self.alloc
+        blocks = alloc.alloc(n)
         if blocks is None and self.prefix_cache is not None:
-            self.prefix_cache.evict(n - self.alloc.free_blocks)
-            blocks = self.alloc.alloc(n)
+            evict = self.prefix_cache.evict_window if kind == "window" else self.prefix_cache.evict
+            evict(n - alloc.free_blocks)
+            blocks = alloc.alloc(n)
         return blocks
+
+    def _kv_blocks(self) -> dict[str, int]:
+        """Blocks the slots hold in each kind of pool (not what the prefix
+        cache keeps beside them), and window blocks given back so far: what the
+        ``serve.decode`` and ``serve.admit`` spans carry."""
+        return {
+            "kv_blocks_full": self.tables.held_blocks,
+            "kv_blocks_window": self.window_tables.held_blocks if self.window else 0,
+            "window_blocks_released": self.window_blocks_released,
+        }
 
     def _admit(self) -> bool:
         free_slots = [i for i, s in enumerate(self._slots) if s is None]
@@ -649,6 +721,8 @@ class ServeEngine:
                 tables_rows = np.full(
                     (rows, self.blocks_per_slot), TRASH_BLOCK, np.int32
                 )
+                # the sliding layers' blocks of a round lie as the full layers' do, block b at entry b
+                window_rows = np.full_like(tables_rows, TRASH_BLOCK)
                 seeds = np.zeros((rows,), np.int32)
                 temps = np.zeros((rows,), np.float32)
                 cached_total = suffix_total = 0
@@ -659,6 +733,8 @@ class ServeEngine:
                     prefix_lens[r] = a.cached_tokens
                     suffix_lens[r] = len(sfx)
                     tables_rows[r, : len(blocks)] = blocks
+                    for b, block in a.window_blocks.items():
+                        window_rows[r, b] = block
                     seeds[r] = np.int32(np.uint32(a.req.seed & 0xFFFFFFFF))
                     temps[r] = a.req.temperature
                     cached_total += a.cached_tokens
@@ -679,7 +755,7 @@ class ServeEngine:
                     jnp.asarray(tokens),
                     jnp.asarray(prefix_lens),
                     jnp.asarray(suffix_lens),
-                    jnp.asarray(tables_rows),
+                    self._tables_arg(tables_rows, window_rows),
                     self.pools,
                     jnp.asarray(seeds),
                     jnp.asarray(temps),
@@ -689,6 +765,7 @@ class ServeEngine:
 
             with hot.span(hot.SERVE_ADMIT_COMMIT):
                 self._commit_admission(admitted, first, free_slots)
+            round_span.set_metadata(**self._kv_blocks())
         return True
 
     def _plan_admission(
@@ -706,27 +783,40 @@ class ServeEngine:
                     break
                 toks = list(req.prompt) + req.generated
                 cached_blocks: list[int] = []
+                cached_window: dict[int, int] = {}
                 cached_tokens = 0
                 if self.prefix_cache is not None:
                     # retains the matched blocks on our behalf; never
                     # covers the last token, so suffix_len >= 1
-                    cached_blocks, cached_tokens = self.prefix_cache.match(toks)
+                    cached_blocks, cached_window, cached_tokens = self.prefix_cache.match_kinds(toks)
+
+                def give_back() -> None:
+                    if cached_blocks:
+                        self.alloc.release(cached_blocks)  # noqa: B023
+                    if cached_window:
+                        self.window_alloc.release(list(cached_window.values()))  # noqa: B023
+
                 suffix_len = len(toks) - cached_tokens
                 w = self._bucket_width(suffix_len)
                 if width is None:
                     width = w  # head of queue picks this round's bucket
                 if w != width:
-                    if cached_blocks:
-                        self.alloc.release(cached_blocks)
+                    give_back()
                     continue
                 need = math.ceil(len(toks) / self.block_size) - len(cached_blocks)
                 new_blocks = self._alloc_pressure(need)
-                if new_blocks is None:
-                    if cached_blocks:
-                        self.alloc.release(cached_blocks)
+                # the round stages a window block for every new block of a row;
+                # _commit_admission hands back those below the row's window
+                new_window = self._alloc_pressure(need, "window") if self.window and new_blocks is not None else []
+                if new_blocks is None or new_window is None:
+                    if new_blocks:
+                        self.alloc.release(new_blocks)
+                    give_back()
                     break  # pool pressure: admit what fits, retry later
+                window_blocks = dict(cached_window)
+                window_blocks.update({len(cached_blocks) + j: block for j, block in enumerate(new_window)})
                 admitted.append(
-                    _Admit(req, toks, cached_blocks, cached_tokens, new_blocks)
+                    _Admit(req, toks, cached_blocks, cached_tokens, new_blocks, window_blocks)
                 )
             for a in admitted:
                 self._waiting.remove(a.req)
@@ -755,22 +845,35 @@ class ServeEngine:
             # index the freshly computed full blocks while they're valid —
             # the next same-prefix request prefills only its tail
             if self.prefix_cache is not None:
-                self.prefix_cache.insert(a.toks, blocks)
+                self.prefix_cache.insert(a.toks, blocks, a.window_blocks)
             if req.prefill_only:
                 # a request its first token already finishes never needs
                 # the decode side: no handoff, the caller reads .tokens
                 if not self._finished(req, tok):
-                    req.handoff = self._export_handoff(req, a.toks, blocks)
+                    req.handoff = self._export_handoff(req, a.toks, blocks, a.window_blocks)
                 self.alloc.release(blocks)
+                if self.window:
+                    self.window_alloc.release(list(a.window_blocks.values()))
                 self._complete(req, now)
                 continue
             if self._finished(req, tok):
                 self.alloc.release(blocks)
+                if self.window:
+                    self.window_alloc.release(list(a.window_blocks.values()))
                 self._complete(req, now)
                 continue
             slot = free_slots.pop(0)
             self.tables.assign(slot, blocks)
             self.tables.lengths[slot] = len(a.toks)
+            if self.window:
+                # the next query sits at len(toks): what lies below its window goes back now
+                keep_from = self._window_first_block(len(a.toks))
+                below = [block for b, block in a.window_blocks.items() if b < keep_from]
+                self.window_alloc.release(below)
+                self.window_blocks_released += len(below)
+                for b, block in a.window_blocks.items():
+                    if b >= keep_from:
+                        self.window_tables.assign(slot, b, block)
             self._slots[slot] = _SlotState(
                 req=req,
                 cache_len=len(a.toks),
@@ -781,12 +884,25 @@ class ServeEngine:
             self._admitting = []
         self._update_gauges()
 
+    def _window_ids(self, window_blocks: dict[int, int], n: int) -> Optional[np.ndarray]:
+        """A sequence's ``n`` blocks in the window pools as an array: the trash
+        block where none is held. None for a model of one cache kind."""
+        if not self.window:
+            return None
+        ids = np.full((n,), TRASH_BLOCK, np.int32)
+        for b, block in window_blocks.items():
+            ids[b] = block
+        return ids
+
     def _export_handoff(
-        self, req: ServeRequest, toks: list[int], blocks: list[int]
+        self, req: ServeRequest, toks: list[int], blocks: list[int], window_blocks: dict[int, int]
     ) -> KvPayload:
         """Snapshot the prefilled K/V blocks for transfer to a decode
         replica (the ``prefill_only`` completion path)."""
-        k, v = gen.export_blocks(self.pools, np.asarray(blocks, np.int32))
+        k, v = gen.export_blocks(
+            self.pools, np.asarray(blocks, np.int32), self._cfg.layer_types and self._cfg.cache_kinds,
+            self._window_ids(window_blocks, len(blocks)),
+        )  # fmt: skip
         return KvPayload(
             request_id=new_request_id(),
             tokens=list(toks),
@@ -826,6 +942,8 @@ class ServeEngine:
         st = self._slots[slot]
         self._slots[slot] = None
         self.alloc.free(self.tables.release(slot))
+        if self.window:
+            self.window_alloc.release(self.window_tables.release(slot))
         with self._lock:
             self._waiting.appendleft(st.req)  # resumes via re-prefill
             obs_metrics.SERVE_QUEUE_DEPTH.set(len(self._waiting))
@@ -834,8 +952,11 @@ class ServeEngine:
         return True
 
     def _copy_block(self, src: int, dst: int) -> None:
-        """Device-side copy of one physical block across all layers."""
-        self.pools = jax.tree.map(lambda p: p.at[:, dst].set(p[:, src]), self.pools)
+        """Device-side copy of one physical block across all layers (of the
+        full kind: a block being written in a window pool is never a cached one,
+        the cache adopts whole blocks only)."""
+        copied = jax.tree.map(lambda p: p.at[:, dst].set(p[:, src]), self._pools_of("full"))
+        self.pools = {**self.pools, "full": copied} if self.window else copied
 
     def _ensure_capacity(self, slot: int, write_pos: int) -> bool:
         """Make sure ``slot`` holds a *writable* block for ``write_pos``:
@@ -849,14 +970,14 @@ class ServeEngine:
             if have >= idx + 1:
                 tail = self.tables.blocks_of(slot)[idx]
                 if not self.alloc.is_shared(tail):
-                    return True
+                    return self._ensure_window(slot, write_pos) if self.window else True
                 fresh = self._alloc_pressure(1)
                 if fresh is not None:
                     self._copy_block(tail, fresh[0])
                     self.tables.replace_block(slot, idx, fresh[0])
                     self.alloc.release([tail])
                     obs_metrics.SERVE_COW_COPIES.inc()
-                    return True
+                    continue  # re-check the (fresh, unshared) tail
             else:
                 blocks = self._alloc_pressure(idx + 1 - have)
                 if blocks is not None:
@@ -865,6 +986,27 @@ class ServeEngine:
             self._preempt_youngest()
             if self._slots[slot] is None:
                 return False  # preempted ourselves: nothing else to evict
+
+    def _ensure_window(self, slot: int, write_pos: int) -> bool:
+        """The sliding layers' side of :meth:`_ensure_capacity`: hand back the
+        blocks of ``slot`` whose every position is below the window of the
+        query at ``write_pos`` (every later query's window lies higher), then
+        make sure the ring holds a block for ``write_pos``. False if ``slot``
+        itself was preempted away for it."""
+        tables = self.window_tables
+        below = tables.release_below(slot, self._window_first_block(write_pos))
+        self.window_alloc.release(below)
+        self.window_blocks_released += len(below)
+        idx = write_pos // self.block_size
+        while not tables.has(slot, idx):
+            block = self._alloc_pressure(1, "window")
+            if block is not None:
+                tables.assign(slot, idx, block[0])
+                break
+            self._preempt_youngest()
+            if self._slots[slot] is None:
+                return False
+        return True
 
     def _decode_once(self) -> bool:
         """One turn of the decode pipeline: prepare and enqueue the next step,
@@ -890,6 +1032,7 @@ class ServeEngine:
                 # is in flight, and the CPU backend reads a numpy array where
                 # it lies
                 tables = self.tables.tables.copy()
+                window_tables = self.window_tables.tables.copy() if self.window else None
                 stepping: list[tuple[int, _SlotState]] = []
                 for slot, st in enumerate(self._slots):
                     if st is None:
@@ -898,6 +1041,8 @@ class ServeEngine:
                         # its last token is in flight. The program writes a row
                         # for every slot: this one's goes where an empty slot's does
                         tables[slot] = TRASH_BLOCK
+                        if self.window:
+                            window_tables[slot] = TRASH_BLOCK
                         continue
                     tokens[slot] = _FROM_DEVICE if st.unfetched else st.last_tok
                     positions[slot] = st.cache_len + st.unfetched
@@ -906,6 +1051,7 @@ class ServeEngine:
                     stepping.append((slot, st))
 
             enqueued = None
+            step_span.set_metadata(**self._kv_blocks())  # as the step is dispatched
             if stepping:
                 with hot.span(hot.SERVE_DECODE_DISPATCH):
                     host_tokens = jnp.asarray(tokens)
@@ -915,7 +1061,7 @@ class ServeEngine:
                         # with no step in flight every slot reads the host's token
                         host_tokens if before is None else before.nxt,
                         jnp.asarray(positions),
-                        jnp.asarray(tables),
+                        self._tables_arg(tables, window_tables),
                         self.pools,
                         jnp.asarray(seeds),
                         jnp.asarray(temps),
@@ -967,13 +1113,16 @@ class ServeEngine:
                 finished += 1
                 self._slots[slot] = None
                 blocks = self.tables.release(slot)
+                window_blocks = self.window_tables.blocks_of(slot) if self.window else {}
                 if self.prefix_cache is not None:
                     # index the completed sequence's full blocks (cache
                     # holds cache_len tokens: everything but the final
                     # sampled token) before dropping the slot's refs
                     seq = list(st.req.prompt) + st.req.generated
-                    self.prefix_cache.insert(seq[: st.cache_len], blocks)
+                    self.prefix_cache.insert(seq[: st.cache_len], blocks, window_blocks)
                 self.alloc.release(blocks)
+                if self.window:
+                    self.window_alloc.release(self.window_tables.release(slot))
                 self._complete(st.req, now)
         self._update_gauges()
         return finished

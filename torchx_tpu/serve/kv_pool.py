@@ -36,6 +36,8 @@ __all__ = [
     "plan_pool",
     "BlockAllocator",
     "SlotTables",
+    "WindowTables",
+    "window_ring",
 ]
 
 
@@ -55,6 +57,10 @@ class PoolPlan:
     kv_bytes: int
     kv_budget_bytes: int
     dense_slots: int
+    #: a stack with sliding layers (``cfg.layer_types``): the blocks of the
+    #: window pools, and the entries of a slot's ring table there
+    num_window_blocks: int = 0
+    window_ring: int = 0
 
     @property
     def pool_tokens(self) -> int:
@@ -91,6 +97,14 @@ def _kv_itemsize(cfg) -> int:
     return np.dtype(cfg.dtype).itemsize
 
 
+def window_ring(window: int, block_size: int) -> int:
+    """Entries of a slot's ring table in a sliding layer's pool: the blocks a
+    window of ``window`` positions can touch (``ceil(window / block_size) + 1``
+    when it starts inside a block) and one more, for the block a step in
+    flight writes into before the oldest falls out."""
+    return math.ceil(window / block_size) + 2
+
+
 def plan_pool(
     cfg,
     *,
@@ -99,6 +113,7 @@ def plan_pool(
     block_size: int = 16,
     max_slots: int | None = None,
     mean_tokens_per_seq: int | None = None,
+    max_prefill_batch: int = 4,
 ) -> PoolPlan:
     """Size a paged KV pool against an HBM budget for ``cfg``.
 
@@ -110,6 +125,13 @@ def plan_pool(
     sequences average ``mean_tokens_per_seq`` tokens (default
     ``max_seq / 4`` — serving traffic rarely decodes to the cap), capped
     so a single full-length sequence always fits.
+
+    A sliding layer (``cfg.layer_types``) is charged its window, not
+    ``max_seq``: a slot holds there a ring of :func:`window_ring` blocks
+    whatever its context, and a prefill round stages its rows' blocks
+    (``max_prefill_batch`` rows of a whole sequence). That constant comes off
+    the budget first; ``num_blocks`` fills the rest with blocks of the full
+    layers alone.
     """
     itemsize = _kv_itemsize(cfg)
     param_bytes = cfg.param_count() * itemsize
@@ -119,10 +141,21 @@ def plan_pool(
             f"params ({param_bytes / GIB:.1f} GiB) exceed HBM budget "
             f"({hbm_bytes * headroom / GIB:.1f} GiB); no room for KV pool"
         )
-    # one block, all layers: K and V of every cache head, or a latent row a token
-    block_bytes = cfg.n_layers * block_size * cfg.cache_width * itemsize
-    num_blocks = budget // block_bytes
+    # one block of one layer: K and V of every cache head, or a latent row a token
+    layer_block_bytes = block_size * cfg.cache_width * itemsize
+    n_window = cfg.layers_of("window")
+    block_bytes = (cfg.n_layers - n_window) * layer_block_bytes  # a further block of context: the full layers'
+    if not block_bytes:
+        raise ValueError("a stack of sliding layers alone has no pool to plan")
     blocks_per_slot = math.ceil(cfg.max_seq / block_size)
+    ring = window_ring(cfg.sliding_window, block_size) if n_window else 0
+    slot_bytes = n_window * ring * layer_block_bytes  # what a slot holds in the window pools, whatever its context
+    staged_bytes = n_window * (1 + max_prefill_batch * blocks_per_slot) * layer_block_bytes if n_window else 0
+    mean_blocks = math.ceil((mean_tokens_per_seq or max(block_size, cfg.max_seq // 4)) / block_size)
+    if n_window and max_slots is None:
+        max_slots = max(1, (budget - staged_bytes) // (mean_blocks * block_bytes + slot_bytes))
+    window_bytes = staged_bytes + (max_slots or 0) * slot_bytes
+    num_blocks = (budget - window_bytes) // block_bytes
     if num_blocks < blocks_per_slot + 1:  # +1: trash block
         raise ValueError(
             f"KV budget ({budget / GIB:.2f} GiB) fits only {num_blocks} "
@@ -132,10 +165,8 @@ def plan_pool(
     dense_seq_bytes = cfg.n_layers * cfg.max_seq * cfg.cache_width * itemsize
     dense_slots = budget // dense_seq_bytes
     if max_slots is None:
-        mean_tokens = mean_tokens_per_seq or max(block_size, cfg.max_seq // 4)
-        mean_blocks = math.ceil(mean_tokens / block_size)
         max_slots = max(1, (num_blocks - 1) // mean_blocks)
-    kv_bytes = num_blocks * block_bytes
+    kv_bytes = num_blocks * block_bytes + window_bytes
     return PoolPlan(
         num_blocks=int(num_blocks),
         block_size=block_size,
@@ -144,6 +175,8 @@ def plan_pool(
         kv_bytes=int(kv_bytes),
         kv_budget_bytes=int(budget),
         dense_slots=int(dense_slots),
+        num_window_blocks=int(1 + max_prefill_batch * blocks_per_slot + max_slots * ring) if n_window else 0,
+        window_ring=ring,
     )
 
 
@@ -289,6 +322,11 @@ class SlotTables:
         """Physical blocks currently held by ``slot``."""
         return list(self._blocks[slot])
 
+    @property
+    def held_blocks(self) -> int:
+        """Blocks all slots hold together (a block two slots share counts twice)."""
+        return sum(len(b) for b in self._blocks)
+
     def replace_block(self, slot: int, index: int, block: int) -> None:
         """Swap the physical block at table ``index`` — the engine's
         copy-on-write path after duplicating a shared tail block."""
@@ -311,4 +349,61 @@ class SlotTables:
         self._blocks[slot] = []
         self.tables[slot, :] = TRASH_BLOCK
         self.lengths[slot] = 0
+        return blocks
+
+
+class WindowTables:
+    """Per-slot ring tables of a sliding layer's pool, host side (numpy).
+
+    A slot holds there only the blocks its window still touches: block ``b``
+    of its sequence sits at entry ``b % ring`` of its row, the engine assigns
+    a block as the sequence grows into it and takes back, oldest first, every
+    block whose positions are all below every future query's window. The
+    decode programs read the row through
+    :func:`torchx_tpu.ops.paged_attention.ring_positions`'s rule; entries of
+    blocks not held are the trash block and are never read.
+    """
+
+    def __init__(self, max_slots: int, ring: int) -> None:
+        self.max_slots = max_slots
+        self.ring = ring
+        self.tables = np.full((max_slots, ring), TRASH_BLOCK, np.int32)
+        self._held: list[dict[int, int]] = [{} for _ in range(max_slots)]  # block of the sequence -> physical block
+
+    def assign(self, slot: int, logical: int, block: int) -> None:
+        """Block ``logical`` of ``slot``'s sequence now lives in ``block``."""
+        held = self._held[slot]
+        if any(b % self.ring == logical % self.ring for b in held):
+            raise ValueError(f"slot {slot}: block {logical} meets a held block in a ring of {self.ring}")
+        held[logical] = block
+        self.tables[slot, logical % self.ring] = block
+
+    def has(self, slot: int, logical: int) -> bool:
+        """Whether ``slot`` holds block ``logical`` of its sequence."""
+        return logical in self._held[slot]
+
+    def blocks_of(self, slot: int) -> dict[int, int]:
+        """Block of the sequence -> physical block, for what ``slot`` holds."""
+        return dict(self._held[slot])
+
+    @property
+    def held_blocks(self) -> int:
+        """Blocks all slots hold together."""
+        return sum(len(h) for h in self._held)
+
+    def release_below(self, slot: int, logical: int) -> list[int]:
+        """Take back every block of ``slot`` below block ``logical`` of its
+        sequence, for :meth:`BlockAllocator.release`."""
+        held = self._held[slot]
+        out = []
+        for b in sorted(b for b in held if b < logical):
+            out.append(held.pop(b))
+            self.tables[slot, b % self.ring] = TRASH_BLOCK
+        return out
+
+    def release(self, slot: int) -> list[int]:
+        """Clear ``slot`` back to trash and return its blocks."""
+        blocks = list(self._held[slot].values())
+        self._held[slot] = {}
+        self.tables[slot, :] = TRASH_BLOCK
         return blocks

@@ -25,6 +25,15 @@ under pool pressure, and an optional ``max_blocks`` cap bounds how much
 of the pool the cache may pin (the ``--prefix-cache-reserve`` fraction
 the cost model accounts for).
 
+A model that mixes sliding and full layers keeps two pools, and a node then
+holds a block of each (``window_alloc``): the full layers' block as above, and
+the sliding layers' block of the same tokens for as long as it lasts. Window
+blocks are the scarcer (their pool is a few blocks a slot) and are given up
+first, least recently used first, the node staying (:meth:`evict_window`). A
+suffix's first query reads the window ahead of it, so a match is cut back to
+the longest cached prefix whose last ``window_back`` blocks all still have
+their window block (:meth:`match_kinds`).
+
 Hit/miss accounting feeds ``tpx_serve_prefix_*`` metrics and the serving
 bench's prefix-hit-rate scorecard. Routers use :func:`prefix_chain` /
 :meth:`summary` — positionally-chained digests of block keys — to score
@@ -72,7 +81,7 @@ def prefix_chain(
 
 
 class _Node:
-    __slots__ = ("chunk", "block", "children", "parent", "last_used", "digest", "live")
+    __slots__ = ("chunk", "block", "window", "children", "parent", "last_used", "digest", "live")
 
     def __init__(
         self,
@@ -83,6 +92,7 @@ class _Node:
     ) -> None:
         self.chunk = chunk
         self.block = block
+        self.window: Optional[int] = None  # the sliding layers' block of the same tokens, while it lasts
         self.parent = parent
         self.children: dict[tuple[int, ...], _Node] = {}
         self.last_used = stamp
@@ -105,8 +115,18 @@ class PrefixCache:
         block_size: int,
         *,
         max_blocks: Optional[int] = None,
+        window_alloc: Optional[BlockAllocator] = None,
+        window_back: int = 0,
     ) -> None:
         self.alloc = alloc
+        #: the sliding layers' allocator, and how many blocks ahead of a
+        #: suffix its first query's window reaches into
+        self.window_alloc = window_alloc
+        self.window_back = window_back
+        #: (last_used, tie, node) for nodes given a window block: the order
+        #: :meth:`evict_window` gives them up in, stale entries skipped
+        self._windows: list[tuple[int, int, _Node]] = []
+        self.window_evictions = 0
         self.block_size = block_size
         self.max_blocks = max_blocks  # None: bounded only by pool pressure
         self._root: dict[tuple[int, ...], _Node] = {}
@@ -133,38 +153,52 @@ class PrefixCache:
     # -- lookup ------------------------------------------------------------
 
     def match(self, tokens: Sequence[int]) -> tuple[list[int], int]:
+        """:meth:`match_kinds` for a cache of one pool: ``(blocks, n_tokens)``."""
+        blocks, _, matched = self.match_kinds(tokens)
+        return blocks, matched
+
+    def match_kinds(self, tokens: Sequence[int]) -> tuple[list[int], dict[int, int], int]:
         """Longest cached block-aligned prefix of ``tokens``.
 
-        Returns ``(blocks, n_tokens)`` with one reference **retained per
-        returned block on behalf of the caller** (release them through
-        the normal slot-release path). Never covers the final token:
-        the engine always has at least one position left to prefill, so
-        the sampled "first" token has logits to come from.
+        Returns ``(blocks, window_blocks, n_tokens)`` with one reference
+        **retained per returned block on behalf of the caller** (release them
+        through the normal slot-release path). ``window_blocks`` maps a block
+        of the sequence to the sliding layers' block of it, for the last
+        ``window_back`` blocks of the prefix (empty without a window pool); the
+        prefix is cut back until all of those are still cached. Never covers
+        the final token: the engine always has at least one position left to
+        prefill, so the sampled "first" token has logits to come from.
         """
         bs = self.block_size
         # at least one token must remain uncached
         limit = max(0, (len(tokens) - 1) // bs)
-        blocks: list[int] = []
         with self._lock:
             stamp = next(self._stamp)
-            node: Optional[_Node] = None
+            path: list[_Node] = []
             children = self._root
             for i in range(limit):
                 chunk = tuple(tokens[i * bs : (i + 1) * bs])
                 child = children.get(chunk)
                 if child is None:
                     break
-                child.last_used = stamp
-                blocks.append(child.block)
-                node = child
+                path.append(child)
                 children = child.children
-            self._note_leaf(node)
+            window: dict[int, int] = {}
+            if self.window_alloc is not None:
+                n = len(path)
+                while n and any(node.window is None for node in path[max(0, n - self.window_back) : n]):
+                    n -= 1
+                del path[n:]
+                window = {i: path[i].window for i in range(max(0, n - self.window_back), n)}
+            blocks = [node.block for node in path]
+            self._note_leaf(path[-1] if path else None, stamp)
             # touch the whole path so LRU evicts leaves before their parents
-            while node is not None:
+            for node in path:
                 node.last_used = stamp
-                node = node.parent
             if blocks:
                 self.alloc.retain(blocks)
+                if window:
+                    self.window_alloc.retain(list(window.values()))
                 self.hits += 1
                 obs_metrics.SERVE_PREFIX_HITS.inc()
             else:
@@ -175,11 +209,13 @@ class PrefixCache:
             self.lookup_tokens += len(tokens)
             if matched:
                 obs_metrics.SERVE_PREFIX_HIT_TOKENS.inc(matched)
-        return blocks, matched
+        return blocks, window, matched
 
     # -- insertion ---------------------------------------------------------
 
-    def insert(self, tokens: Sequence[int], blocks: Sequence[int]) -> int:
+    def insert(
+        self, tokens: Sequence[int], blocks: Sequence[int], window_blocks: Optional[dict[int, int]] = None
+    ) -> int:
         """Index the full blocks of a prefilled/completed sequence.
 
         ``blocks[i]`` must hold tokens ``tokens[i*bs : (i+1)*bs]``; only
@@ -187,7 +223,10 @@ class PrefixCache:
         nodes adopt the caller's block with a cache-owned reference
         (:meth:`BlockAllocator.retain`); chunks already present keep the
         existing node's block — the caller's duplicate stays the
-        caller's to release. Returns the number of newly adopted blocks.
+        caller's to release. ``window_blocks`` maps a block of the sequence to
+        the sliding layers' block of it, where the caller still holds one: a
+        node without a window block adopts it the same way. Returns the number
+        of newly adopted blocks (of the full layers).
         """
         bs = self.block_size
         n_full = min(len(tokens) // bs, len(blocks))
@@ -213,6 +252,10 @@ class PrefixCache:
                     self._nodes += 1
                     adopted += 1
                 node.last_used = stamp
+                if node.window is None and window_blocks and i in window_blocks and self.window_alloc is not None:
+                    node.window = int(window_blocks[i])
+                    self.window_alloc.retain([node.window])
+                    heapq.heappush(self._windows, (stamp, next(self._tie), node))
                 parent = node
                 children = node.children
             self._note_leaf(parent)
@@ -230,9 +273,36 @@ class PrefixCache:
             obs_metrics.SERVE_PREFIX_CACHED_BLOCKS.set(self._nodes)
             return freed
 
-    def _note_leaf(self, node: Optional[_Node]) -> None:
-        """``node`` was just stamped, or just lost its last child: if it is
-        a leaf, it joins the eviction order at its stamp."""
+    def evict_window(self, n_blocks: int) -> int:
+        """Give up to ``n_blocks`` window blocks that only the cache holds
+        back to the sliding layers' allocator, least recently used first; the
+        nodes stay, and a later match is cut back past them. -> how many."""
+        freed, in_use = 0, []
+        with self._lock:
+            while freed < n_blocks and self._windows:
+                stamp, tie, node = heapq.heappop(self._windows)
+                if not node.live or node.window is None:
+                    continue
+                if stamp != node.last_used:  # touched since: back in at its new place
+                    heapq.heappush(self._windows, (node.last_used, tie, node))
+                    continue
+                if self.window_alloc.refcount(node.window) != 1:
+                    in_use.append((stamp, tie, node))
+                    continue
+                self.window_alloc.release([node.window])
+                node.window = None
+                self.window_evictions += 1
+                freed += 1
+            for entry in in_use:
+                heapq.heappush(self._windows, entry)
+        return freed
+
+    def _note_leaf(self, node: Optional[_Node], stamp: Optional[int] = None) -> None:
+        """``node`` was just stamped (or is about to be, with ``stamp``), or
+        just lost its last child: if it is a leaf, it joins the eviction order
+        at its stamp."""
+        if node is not None and stamp is not None:
+            node.last_used = stamp
         if node is not None and not node.children:
             heapq.heappush(self._leaves, (node.last_used, next(self._tie), node))
             if len(self._leaves) > 4 * self._nodes + 1024:  # mostly stale: start again
@@ -267,6 +337,9 @@ class PrefixCache:
             victim.live = False
             self._nodes -= 1
             self.alloc.release([victim.block])
+            if victim.window is not None:
+                self.window_alloc.release([victim.window])
+                victim.window = None
             self.evictions += 1
             obs_metrics.SERVE_PREFIX_EVICTIONS.inc()
             freed += 1
@@ -294,6 +367,7 @@ class PrefixCache:
                     else 0.0
                 ),
                 "evictions": self.evictions,
+                "window_evictions": self.window_evictions,
             }
 
     def summary(self, max_entries: int = 128) -> list[str]:
