@@ -7,7 +7,10 @@ nothing runs, never reported as a chip run.
 ``benchmark/rehearse_compile.py::serve_cell`` builds K/V pools by hand and so cannot
 describe a latent pool; this asks ``generate.init_kv_pools`` for the pools' shapes.
 Under each program it prints what its layer loop moves of a layer's pool size or more
-(``torchx_tpu/obs/hlo.py::loop_moves``: nothing, since the pools ride the scan's carry).
+(``torchx_tpu/obs/hlo.py::loop_moves``: nothing, since the pools ride the scan's carry),
+and every pure data movement anywhere in the program of the size of a layer's smallest
+attention projection or more (``program_moves``: since PR 32 no weight among them, the
+projections are multiplied where they lie; a wide prefill round still re-lays activations).
 The process sees only the CPU, so the backend question every kernel's
 ``kernel_eligible`` asks is answered "tpu" here, as the chip would answer it.
 """
@@ -33,7 +36,8 @@ def main() -> None:
     from jax.experimental import topologies
 
     from torchx_tpu.models import generate as gen
-    from torchx_tpu.obs.hlo import loop_moves
+    from torchx_tpu.models import llama
+    from torchx_tpu.obs.hlo import loop_moves, program_moves
     from torchx_tpu.serve import engine as eng
     from torchx_tpu.serve.kv_pool import window_ring
 
@@ -63,11 +67,17 @@ def main() -> None:
         return {"full": full, "window": sds((rows, window_width), i32)} if window else full
 
     layer_bytes = min(p.size // p.shape[0] * p.dtype.itemsize for p in jax.tree.leaves(pools))
+    projection_bytes = min(w.size // w.shape[0] * w.dtype.itemsize for group in llama.layer_groups(params)
+                           for name, w in params[group].items() if name in ("wq", "wk", "wv", "wo", "w_kva", "w_kvb"))  # fmt: skip
 
     def report(name, compiled):  # noqa: ANN001, ANN202
         report_memory(name, compiled)
+        text = compiled.as_text()
         print(f"  moves of a layer's pool ({layer_bytes / 2**20:.0f} MiB) or more inside a loop:",
-              loop_moves(compiled.as_text(), layer_bytes) or "none", flush=True)  # fmt: skip
+              loop_moves(text, layer_bytes) or "none", flush=True)  # fmt: skip
+        moves = program_moves(text, projection_bytes)
+        print(f"  pure data movements of a layer's smallest attention projection ({projection_bytes / 2**20:.1f} MiB) or more,"
+              f" anywhere in the program: {len(moves) or 'none'}", *moves, sep="\n    ", flush=True)  # fmt: skip
 
     def decode(params, tokens, prev, positions, tables, pools, seeds, temps):  # noqa: ANN001
         tokens = jnp.where(tokens == eng._FROM_DEVICE, prev, tokens)  # as ServeEngine's _decode merges them

@@ -395,6 +395,54 @@ def test_serving_programs_keep_the_pools_where_they_lie_on_the_chip(one_chip, ma
     assert loop_moves(text, layer_bytes) == []
 
 
+# The decode program's attention projections (PR 32): each layer's wq/wk/wv/wo is multiplied where it
+# lies in its stack. Before, the head split that followed a projection was folded into its matmul, the
+# chip's compiler asked for the weight as [heads, hd, d], and every layer's wq/wk/wv was sliced out and
+# written transposed each step: inside the layer loop of a scanned stack, in the entry computation where
+# the loop is short enough to be unrolled (where ``loop_moves`` does not look).
+
+
+@pytest.mark.parametrize("make,slots", [
+    # Mistral-7B's attention (d 4096, 32/8 heads of 128) and FFN, 3 layers: one scan over one kind
+    pytest.param(lambda: llama.llama_tiny(
+        vocab_size=32768, dim=4096, n_heads=32, n_kv_heads=8, ffn_dim=14336, n_layers=3, max_seq=256, dtype=jnp.bfloat16),
+        16, id="dense-gqa-one-scan"),
+    # Mixtral-8x7B's: the same attention in front of 8 experts, top-2, routed by capacity as its cell runs it
+    pytest.param(lambda: moe.moe_tiny(
+        vocab_size=32000, dim=4096, n_heads=32, n_kv_heads=8, ffn_dim=14336, n_experts=8, top_k=2, capacity_factor=4.0,
+        n_layers=2, max_seq=256, dtype=jnp.bfloat16), 16, id="mixtral-shaped"),
+    # K-EXAONE's (d 6144, 64/8 heads of 128: h * hd != d), QK-norm, one dense layer and one period L L L G of
+    # expert layers: the layer loop is a few layers and one period, which the compiler unrolls
+    pytest.param(lambda: moe.moe_tiny(
+        vocab_size=19200, dim=6144, n_heads=64, n_kv_heads=8, attn_head_dim=128, n_layers=5, max_seq=256, dtype=jnp.bfloat16,
+        ffn_dim=18432, n_experts=128, experts_held=16, top_k=8, expert_ffn_dim=2048, n_shared_experts=1,
+        router_score="sigmoid", router_bias=True, n_dense_layers=1, capacity_factor=0.0, qk_norm=True, rope_full_layers=False,
+        layer_types=("sliding", "sliding", "sliding", "sliding", "full"), sliding_window=128), 64,
+        id="window-and-full-unrolled"),
+])  # fmt: skip
+def test_decode_program_multiplies_the_projections_where_they_lie_on_the_chip(one_chip, make, slots, monkeypatch):
+    from torchx_tpu.obs.hlo import program_moves
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    monkeypatch.setattr(attn_ops, "TRACED", {})
+    cfg = make()
+    bs, bpr = 16, cfg.max_seq // 16
+    on_chip = lambda tree: jax.tree.map(lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip), tree)  # noqa: E731
+    shape = lambda s, d=jnp.int32: jax.ShapeDtypeStruct(s, d, sharding=one_chip)  # noqa: E731
+    params = on_chip(jax.eval_shape(lambda: llama.model_fns(cfg)[0](cfg, jax.random.PRNGKey(0))))
+    pools = on_chip(jax.eval_shape(lambda: gen.init_kv_pools(cfg, 1 + slots * bpr, bs, 1 + slots * 10)))
+    tables = {"full": shape((slots, bpr)), "window": shape((slots, 10))} if cfg.layer_types else shape((slots, bpr))
+    fn = lambda p, tok, pos, tab, pl, keys, temps: gen.paged_decode_step(p, tok, pos, tab, pl, cfg, keys, temps)  # noqa: E731
+    text = jax.jit(fn, donate_argnums=(4,)).lower(
+        params, shape((slots,)), shape((slots,)), tables, pools, shape((slots, 2), jnp.uint32), shape((slots,), jnp.float32)
+    ).compile().as_text()  # fmt: skip
+    assert attn_ops.traced("projections") == "in_place"
+    assert "paged_attention_decode" in text
+    stacks = [params[g][w] for g in llama.layer_groups(params) for w in ("wq", "wk", "wv", "wo")]
+    smallest = min(w.size // w.shape[0] * w.dtype.itemsize for w in stacks)  # a layer's wk
+    assert program_moves(text, smallest) == []
+
+
 @pytest.mark.parametrize("m,k,n,held,spread", [
     pytest.param(384, 2048, 1408, 64, 64, id="decode-gate-up"),
     pytest.param(384, 1408, 2048, 64, 64, id="decode-down"),

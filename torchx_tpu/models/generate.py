@@ -53,6 +53,28 @@ def init_kv_cache(
     }
 
 
+def _project_heads(x: jnp.ndarray, w, heads: int, hd: int) -> jnp.ndarray:  # noqa: ANN001
+    """``x @ w`` split into heads, ``[..., d] -> [..., heads, hd]``: head ``j``
+    is columns ``[j * hd, (j + 1) * hd)`` of the product.
+
+    The barrier pins the product as the 2-D ``[rows, heads * hd]`` value it is,
+    so the split is a reshape of the activation and never reaches the weight:
+    the matmul takes its layer's slice of the parameter stack inside its own
+    fusion, in the layout the tree has, as ``wo`` and the MLP do. With the
+    reshape adjacent, XLA folds it into the matmul (however the product is
+    written: flattened first, float32 out, operands swapped or transposed), the
+    weight becomes ``[d, heads, hd]``, the chip's compiler runs the contraction
+    as a convolution over the heads and asks for the weight as ``[heads, hd,
+    d]``: every layer's ``wq``/``wk``/``wv`` sliced out of its stack and written
+    transposed in front of a 16-64 row matmul, every step, 5 of the 23 ms
+    ``k-exaone`` decode program (PERF.md section 6, PR 32). The decode programs
+    are held to it on the chip's compiler (``obs.hlo.program_moves``,
+    ``tests/test_paged_attention_kernel.py``);
+    ``ops.attention.traced("projections")`` answers ``in_place``."""
+    note_traced("projections", "in_place")
+    return jax.lax.optimization_barrier(mm(x, w)).reshape(*x.shape[:-1], heads, hd)
+
+
 def _cached_attention(
     q: jnp.ndarray,  # [b, t, h, d] (t = tokens this call)
     k_cache: jnp.ndarray,  # [b, S, kvh, d] — positions >= valid_len are zeros
@@ -92,9 +114,9 @@ def _layer_step(
     with jax.named_scope(hot.NORM):
         attn_in = rms_norm(x, layer["attn_norm"], cfg.norm_eps)
     with jax.named_scope(hot.ATTN):
-        q = apply_rope(mm(attn_in, layer["wq"]).reshape(b, t, h, hd), cos, sin)
-        k = apply_rope(mm(attn_in, layer["wk"]).reshape(b, t, kvh, hd), cos, sin)
-        v = mm(attn_in, layer["wv"]).reshape(b, t, kvh, hd)
+        q = apply_rope(_project_heads(attn_in, layer["wq"], h, hd), cos, sin)
+        k = apply_rope(_project_heads(attn_in, layer["wk"], kvh, hd), cos, sin)
+        v = _project_heads(attn_in, layer["wv"], kvh, hd)
         with jax.named_scope(hot.APPEND_KV):
             k_cache = jax.lax.dynamic_update_slice(k_cache, k, (0, start, 0, 0))
             v_cache = jax.lax.dynamic_update_slice(v_cache, v, (0, start, 0, 0))
@@ -478,10 +500,11 @@ def _paged_layer_step(
             x = x + attn
         else:
             h, kvh, hd = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
-            q = mm(attn_in, layer["wq"]).reshape(slots, h, hd)
-            k = mm(attn_in, layer["wk"]).reshape(slots, kvh, hd)
+            rows = attn_in[:, 0]  # [slots, d]
+            q = _project_heads(rows, layer["wq"], h, hd)
+            k = _project_heads(rows, layer["wk"], kvh, hd)
             q, k = llama.norm_and_rotate(cfg, layer, q, k, cos, sin, _rope_rows)
-            v = mm(attn_in, layer["wv"]).reshape(slots, kvh, hd)
+            v = _project_heads(rows, layer["wv"], kvh, hd)
             k_pool = append_kv(k_pool, tables, positions, k, at, ring=bool(window))
             v_pool = append_kv(v_pool, tables, positions, v, at, ring=bool(window))
             attn = paged_attention(q, k_pool, v_pool, tables, positions + 1, at, window)
@@ -564,7 +587,9 @@ def paged_decode_step(
     pools ride the layer scan's carry whole (``_scan_groups``), each layer
     scatters its ``slots`` rows into the stack at its own index and the
     attention kernel reads the stack at that index, so a step moves the rows it
-    appends and the blocks the slots hold, never a layer's pool.
+    appends and the blocks the slots hold, never a layer's pool. Nor a layer's
+    weights: every matmul takes its layer's slice of the parameter stack inside
+    its own fusion, the attention projections included (``_project_heads``).
     """
     slots = tokens.shape[0]
     with jax.named_scope(hot.EMBED):
@@ -654,10 +679,10 @@ def _paged_chunk_layer_step(
             x = x + attn
         else:
             h, kvh, hd = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
-            q = mm(attn_in, layer["wq"]).reshape(b, t, h, hd)
-            k = mm(attn_in, layer["wk"]).reshape(b, t, kvh, hd)
+            q = _project_heads(attn_in, layer["wq"], h, hd)
+            k = _project_heads(attn_in, layer["wk"], kvh, hd)
             q, k = llama.norm_and_rotate(cfg, layer, q, k, cos, sin, _rope_chunk)
-            v = mm(attn_in, layer["wv"]).reshape(b, t, kvh, hd)
+            v = _project_heads(attn_in, layer["wv"], kvh, hd)
             k_pool = scatter_kv_chunk(k_pool, tables, positions, k, valid, at)
             v_pool = scatter_kv_chunk(v_pool, tables, positions, v, valid, at)
             attn = paged_attention_chunk(q, k_pool, v_pool, tables, positions, valid, at, window)

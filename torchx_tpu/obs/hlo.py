@@ -5,7 +5,9 @@ will run it: whether a buffer carried through a ``lax.scan`` is updated where
 it lies or sliced out, copied and written back is decided there and nowhere
 in the jaxpr. :func:`loop_moves` is the check the serving programs are held to
 (``tests/test_pools_in_carry.py``, ``scripts/rehearse_serve_cell.py``): no
-pass over a layer's paged pool inside the layer loop.
+pass over a layer's paged pool inside the layer loop. :func:`program_moves`
+reads the whole program, loops or none: no layer's attention projection re-laid
+in front of its matmul (``tests/test_paged_attention_kernel.py``).
 """
 
 from __future__ import annotations
@@ -44,16 +46,8 @@ def _split(rest: str) -> tuple[str, str, str]:
     return type_text, opcode, rest
 
 
-def loop_moves(hlo_text: str, at_least_bytes: int) -> list[str]:
-    """The ``copy``, ``dynamic-slice`` and ``dynamic-update-slice``
-    instructions inside any ``while`` loop of the program (its body, and the
-    fusions and loops the body calls) that make a buffer of ``at_least_bytes``
-    or more: the result of a copy or a slice, the update of an update-slice
-    (its result is the operand, updated where it lies). Inside a fusion only
-    what the fusion hands out counts (its root, through bitcasts and tuples): a
-    slice that feeds the fusion's own matmul is read where it lies. ->
-    ``computation: instruction`` lines, empty when the loops move nothing that
-    large."""
+def _parse(hlo_text: str) -> dict[str, dict[str, tuple[bool, str, str, list[str], str]]]:
+    """computation -> instruction -> (is the root, type, opcode, operands, the rest of its line)."""
     computations: dict[str, dict[str, tuple[bool, str, str, list[str], str]]] = {}
     current = None
     for line in hlo_text.splitlines():
@@ -64,6 +58,20 @@ def loop_moves(hlo_text: str, at_least_bytes: int) -> list[str]:
             type_text, opcode, rest = _split(m.group(2))
             operands = re.findall(r"%([\w.\-]+)", rest.split("), ")[0])
             current[m.group(1)] = ("ROOT " in line[: m.start(1)], type_text, opcode, operands, rest)
+    return computations
+
+
+def loop_moves(hlo_text: str, at_least_bytes: int) -> list[str]:
+    """The ``copy``, ``dynamic-slice`` and ``dynamic-update-slice``
+    instructions inside any ``while`` loop of the program (its body, and the
+    fusions and loops the body calls) that make a buffer of ``at_least_bytes``
+    or more: the result of a copy or a slice, the update of an update-slice
+    (its result is the operand, updated where it lies). Inside a fusion only
+    what the fusion hands out counts (its root, through bitcasts and tuples): a
+    slice that feeds the fusion's own matmul is read where it lies. ->
+    ``computation: instruction`` lines, empty when the loops move nothing that
+    large."""
+    computations = _parse(hlo_text)
     found, seen = [], set()
 
     def visit(name: str, fused: bool) -> None:
@@ -96,4 +104,46 @@ def loop_moves(hlo_text: str, at_least_bytes: int) -> list[str]:
         for *_, opcode, _, rest in body.values():
             if opcode == "while" and (m := re.search(r"body=%?([\w.\-]+)", rest)):
                 visit(m.group(1), False)
+    return sorted(found)
+
+
+_PURE_MOVES = frozenset(("copy", "slice", "dynamic-slice", "transpose"))
+# what a fusion may hold beside its moves and still compute nothing
+_CARRIES = frozenset(("parameter", "constant", "bitcast", "tuple", "get-tuple-element"))
+_FUSED = re.compile(r"calls=%?([\w.\-]+)")
+
+
+def _elements(type_text: str) -> int:
+    """Elements of the largest array of an HLO type: 1 for a scalar."""
+    return max([math.prod(int(d) for d in dims.split(",") if d) for _, _, dims in _ARRAY.findall(type_text)] or [1])
+
+
+def program_moves(hlo_text: str, at_least_bytes: int) -> list[str]:
+    """Every pure data movement of ``at_least_bytes`` or more **anywhere** in
+    the program, loop or not (a layer loop short enough to be unrolled leaves
+    its moves in the entry computation, where :func:`loop_moves` does not
+    look): a ``copy``, ``slice``, ``dynamic-slice`` or ``transpose`` that stands
+    alone, or a fusion of nothing but those and bitcasts (scalar index
+    arithmetic aside), by the size of what it writes. A slice fused **into** a
+    matmul's fusion is read where it lies and does not count; neither does
+    XLA's asynchronous ``copy-start``/``copy-done`` prefetch of an operand
+    into fast memory, which is no operation of the program's own. ->
+    ``computation: instruction`` lines, empty when nothing that large moves."""
+    computations = _parse(hlo_text)
+    fusions = [rest for body in computations.values() for _, _, opcode, _, rest in body.values() if opcode == "fusion"]
+    fused = {called for rest in fusions for called in _FUSED.findall(rest)}
+
+    def only_moves(name: str) -> bool:
+        opcodes = {op for _, type_text, op, _, _ in computations.get(name, {}).values() if _elements(type_text) > 1}
+        return bool(opcodes & _PURE_MOVES) and opcodes <= _PURE_MOVES | _CARRIES
+
+    found = []
+    for name, body in computations.items():
+        if name in fused:
+            continue  # judged whole, at the fusion that calls it
+        for inst, (_, type_text, opcode, _, rest) in body.items():
+            if _bytes(type_text) < at_least_bytes:
+                continue
+            if opcode in _PURE_MOVES or (opcode == "fusion" and all(map(only_moves, _FUSED.findall(rest)))):
+                found.append(f"{name}: {inst} = {type_text} {opcode}")
     return sorted(found)
