@@ -5,7 +5,10 @@ nothing runs, never reported as a chip run.
     JAX_PLATFORMS=cpu python3 scripts/rehearse_serve_cell.py kimi-vl-a3b-serve-backlog [rows x width ...]
 
 ``benchmark/rehearse_compile.py::serve_cell`` builds K/V pools by hand and so cannot
-describe a latent pool; this asks ``generate.init_kv_pools`` for the pools' shapes.
+describe a latent pool; this asks ``generate.init_kv_pools`` for the pools' shapes: K/V pools,
+a pool a cache kind (``k-exaone-serve-decode-long``), or a latent pool a layer group at 64 slots
+(``kimi-vl-a3b-serve-backlog``) or 128 (``xing4-serve-decode-long``: 16,897 blocks x 8 layers x
+1,280 B, 2.58 GiB beside 10.55 GiB of weights; decode 13.14 GiB live, widest prefill 13.32).
 Under each program it prints what its layer loop moves of a layer's pool size or more
 (``torchx_tpu/obs/hlo.py::loop_moves``: nothing, since the pools ride the scan's carry),
 and every pure data movement anywhere in the program of the size of a layer's smallest
@@ -68,7 +71,7 @@ def main() -> None:
 
     layer_bytes = min(p.size // p.shape[0] * p.dtype.itemsize for p in jax.tree.leaves(pools))
     projection_bytes = min(w.size // w.shape[0] * w.dtype.itemsize for group in llama.layer_groups(params)
-                           for name, w in params[group].items() if name in ("wq", "wk", "wv", "wo", "w_kva", "w_kvb"))  # fmt: skip
+                           for name, w in params[group].items() if name in ("wq", "wk", "wv", "wo", "w_qa", "w_qb", "w_kva", "w_kvb", "w_uk", "w_uv"))  # fmt: skip
 
     def report(name, compiled):  # noqa: ANN001, ANN202
         report_memory(name, compiled)

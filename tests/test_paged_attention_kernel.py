@@ -24,6 +24,7 @@ from torchx_tpu.models import generate as gen
 from torchx_tpu.models import llama, moe
 from torchx_tpu.ops import paged_attention as pa
 from torchx_tpu.ops import paged_attention_kernel as pk
+from torchx_tpu.ops.rope import YarnScaling
 
 attn_ops = importlib.import_module("torchx_tpu.ops.attention")  # the package exports the function under this name
 TOLERANCE = {jnp.float32: dict(atol=2e-6, rtol=1e-5), jnp.bfloat16: dict(atol=1e-2, rtol=1e-2)}
@@ -402,15 +403,15 @@ def test_serving_programs_keep_the_pools_where_they_lie_on_the_chip(one_chip, ma
 # the loop is short enough to be unrolled (where ``loop_moves`` does not look).
 
 
-@pytest.mark.parametrize("make,slots", [
+@pytest.mark.parametrize("make,slots,kernel", [
     # Mistral-7B's attention (d 4096, 32/8 heads of 128) and FFN, 3 layers: one scan over one kind
     pytest.param(lambda: llama.llama_tiny(
         vocab_size=32768, dim=4096, n_heads=32, n_kv_heads=8, ffn_dim=14336, n_layers=3, max_seq=256, dtype=jnp.bfloat16),
-        16, id="dense-gqa-one-scan"),
+        16, "paged_attention_decode", id="dense-gqa-one-scan"),
     # Mixtral-8x7B's: the same attention in front of 8 experts, top-2, routed by capacity as its cell runs it
     pytest.param(lambda: moe.moe_tiny(
         vocab_size=32000, dim=4096, n_heads=32, n_kv_heads=8, ffn_dim=14336, n_experts=8, top_k=2, capacity_factor=4.0,
-        n_layers=2, max_seq=256, dtype=jnp.bfloat16), 16, id="mixtral-shaped"),
+        n_layers=2, max_seq=256, dtype=jnp.bfloat16), 16, "paged_attention_decode", id="mixtral-shaped"),
     # K-EXAONE's (d 6144, 64/8 heads of 128: h * hd != d), QK-norm, one dense layer and one period L L L G of
     # expert layers: the layer loop is a few layers and one period, which the compiler unrolls
     pytest.param(lambda: moe.moe_tiny(
@@ -418,9 +419,21 @@ def test_serving_programs_keep_the_pools_where_they_lie_on_the_chip(one_chip, ma
         ffn_dim=18432, n_experts=128, experts_held=16, top_k=8, expert_ffn_dim=2048, n_shared_experts=1,
         router_score="sigmoid", router_bias=True, n_dense_layers=1, capacity_factor=0.0, qk_norm=True, rope_full_layers=False,
         layer_types=("sliding", "sliding", "sliding", "sliding", "full"), sliding_window=128), 64,
-        id="window-and-full-unrolled"),
+        "paged_attention_decode", id="window-and-full-unrolled"),
+    # Xing4.0's (PR 33): latent attention at d 3584, 32 heads of 128 + 64, the query through its own latent of 768
+    # (W_qb split into heads behind the same barrier), W_kvb held as the absorbed decode multiplies it (w_uk, w_uv:
+    # the heads outermost, each read by its one batched product), four residual streams round every sublayer; 2 dense
+    # + 3 expert layers. 64 slots, so that no activation is as large as the smallest weight (at the cell's 128 the
+    # absorbed query [32, 512, 128] is re-laid, 4 MiB a layer: rehearsal, PR 33)
+    pytest.param(lambda: moe.moe_tiny(
+        vocab_size=16384, dim=3584, n_heads=32, n_kv_heads=32, n_layers=5, max_seq=256, dtype=jnp.bfloat16, ffn_dim=9216,
+        n_experts=64, top_k=4, expert_ffn_dim=1024, n_shared_experts=1, router_score="sigmoid", router_bias=True,
+        routed_scale=2.0, n_dense_layers=2, capacity_factor=0.0, kv_lora_rank=512, q_lora_rank=768, qk_nope_dim=128,
+        qk_rope_dim=64, v_head_dim=128, hc_mult=4, hc_sinkhorn_iters=20, norm_eps=1e-6, rope_theta=10000.0,
+        rope_scaling=YarnScaling(factor=64.0, original_max_seq=4096, mscale_all_dim=1.0)), 64,
+        "paged_mla_decode", id="latent-compressed-query-four-streams"),
 ])  # fmt: skip
-def test_decode_program_multiplies_the_projections_where_they_lie_on_the_chip(one_chip, make, slots, monkeypatch):
+def test_decode_program_multiplies_the_projections_where_they_lie_on_the_chip(one_chip, make, slots, kernel, monkeypatch):
     from torchx_tpu.obs.hlo import program_moves
 
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
@@ -437,9 +450,10 @@ def test_decode_program_multiplies_the_projections_where_they_lie_on_the_chip(on
         params, shape((slots,)), shape((slots,)), tables, pools, shape((slots, 2), jnp.uint32), shape((slots,), jnp.float32)
     ).compile().as_text()  # fmt: skip
     assert attn_ops.traced("projections") == "in_place"
-    assert "paged_attention_decode" in text
-    stacks = [params[g][w] for g in llama.layer_groups(params) for w in ("wq", "wk", "wv", "wo")]
-    smallest = min(w.size // w.shape[0] * w.dtype.itemsize for w in stacks)  # a layer's wk
+    assert kernel in text
+    names = ("wq", "wk", "wv", "wo", "w_qa", "w_qb", "w_kva", "w_uk", "w_uv")
+    stacks = [params[g][w] for g in llama.layer_groups(params) for w in names if w in params[g]]
+    smallest = min(w.size // w.shape[0] * w.dtype.itemsize for w in stacks)  # a layer's wk, or w_kva
     assert program_moves(text, smallest) == []
 
 

@@ -19,9 +19,9 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from torchx_tpu.models import llama, mla
+from torchx_tpu.models import hyper, llama, mla
 from torchx_tpu.obs import hot
-from torchx_tpu.ops.attention import note_traced
+from torchx_tpu.ops.attention import note_traced, project_heads as _project_heads
 from torchx_tpu.ops.norms import rms_norm
 from torchx_tpu.ops.paged_attention import (
     append_kv,
@@ -30,7 +30,7 @@ from torchx_tpu.ops.paged_attention import (
     scatter_kv_chunk,
 )
 from torchx_tpu.ops.quant import maybe_matmul as mm
-from torchx_tpu.ops.rope import apply_rope, rope_frequencies
+from torchx_tpu.ops.rope import apply_rope
 
 KVCache = dict[str, jnp.ndarray]  # {"k": [L,b,S,kvh,hd], "v": ...}
 # every leaf [L_group, num_blocks, block_size, ...]: {"k", "v"} of [.., kvh, hd],
@@ -51,28 +51,6 @@ def init_kv_cache(
         "k": jnp.zeros(shape, dtype=cfg.dtype),
         "v": jnp.zeros(shape, dtype=cfg.dtype),
     }
-
-
-def _project_heads(x: jnp.ndarray, w, heads: int, hd: int) -> jnp.ndarray:  # noqa: ANN001
-    """``x @ w`` split into heads, ``[..., d] -> [..., heads, hd]``: head ``j``
-    is columns ``[j * hd, (j + 1) * hd)`` of the product.
-
-    The barrier pins the product as the 2-D ``[rows, heads * hd]`` value it is,
-    so the split is a reshape of the activation and never reaches the weight:
-    the matmul takes its layer's slice of the parameter stack inside its own
-    fusion, in the layout the tree has, as ``wo`` and the MLP do. With the
-    reshape adjacent, XLA folds it into the matmul (however the product is
-    written: flattened first, float32 out, operands swapped or transposed), the
-    weight becomes ``[d, heads, hd]``, the chip's compiler runs the contraction
-    as a convolution over the heads and asks for the weight as ``[heads, hd,
-    d]``: every layer's ``wq``/``wk``/``wv`` sliced out of its stack and written
-    transposed in front of a 16-64 row matmul, every step, 5 of the 23 ms
-    ``k-exaone`` decode program (PERF.md section 6, PR 32). The decode programs
-    are held to it on the chip's compiler (``obs.hlo.program_moves``,
-    ``tests/test_paged_attention_kernel.py``);
-    ``ops.attention.traced("projections")`` answers ``in_place``."""
-    note_traced("projections", "in_place")
-    return jax.lax.optimization_barrier(mm(x, w)).reshape(*x.shape[:-1], heads, hd)
 
 
 def _cached_attention(
@@ -109,26 +87,25 @@ def _layer_step(
     v_cache: jnp.ndarray,
     start: jnp.ndarray,  # scalar: where these t tokens go in the cache
 ) -> tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
-    b, t, d = x.shape
+    b, t = x.shape[:2]
     h, kvh, hd = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
-    with jax.named_scope(hot.NORM):
-        attn_in = rms_norm(x, layer["attn_norm"], cfg.norm_eps)
-    with jax.named_scope(hot.ATTN):
-        q = apply_rope(_project_heads(attn_in, layer["wq"], h, hd), cos, sin)
-        k = apply_rope(_project_heads(attn_in, layer["wk"], kvh, hd), cos, sin)
-        v = _project_heads(attn_in, layer["wv"], kvh, hd)
-        with jax.named_scope(hot.APPEND_KV):
-            k_cache = jax.lax.dynamic_update_slice(k_cache, k, (0, start, 0, 0))
-            v_cache = jax.lax.dynamic_update_slice(v_cache, v, (0, start, 0, 0))
-        with jax.named_scope(hot.ATTN_KERNEL):
-            attn = _cached_attention(q, k_cache, v_cache, q_pos)
-        x = x + mm(attn.reshape(b, t, h * hd), layer["wo"])
-    with jax.named_scope(hot.NORM):
-        mlp_in = rms_norm(x, layer["mlp_norm"], cfg.norm_eps)
-    # the SAME dispatch as the training forward (dense SwiGLU or GShard
-    # MoE — static shapes hold at t=1); the balancing aux is training-only
-    down, _aux = llama.ffn(cfg, layer, mlp_in)
-    x = x + down
+
+    def attend(stream_in):  # noqa: ANN001, ANN202
+        with jax.named_scope(hot.NORM):
+            attn_in = rms_norm(stream_in, layer["attn_norm"], cfg.norm_eps)
+        with jax.named_scope(hot.ATTN):
+            q = apply_rope(_project_heads(attn_in, layer["wq"], h, hd), cos, sin)
+            k = apply_rope(_project_heads(attn_in, layer["wk"], kvh, hd), cos, sin)
+            v = _project_heads(attn_in, layer["wv"], kvh, hd)
+            with jax.named_scope(hot.APPEND_KV):
+                k_new = jax.lax.dynamic_update_slice(k_cache, k, (0, start, 0, 0))
+                v_new = jax.lax.dynamic_update_slice(v_cache, v, (0, start, 0, 0))
+            with jax.named_scope(hot.ATTN_KERNEL):
+                attn = _cached_attention(q, k_new, v_new, q_pos)
+            return mm(attn.reshape(b, t, h * hd), layer["wo"]), (k_new, v_new)
+
+    x, (k_cache, v_cache) = hyper.residual(cfg, layer, "attn", x, attend)
+    x, _aux = hyper.residual(cfg, layer, "mlp", x, functools.partial(_feed_forward, cfg, layer))
     return x, k_cache, v_cache
 
 
@@ -144,9 +121,9 @@ def forward_with_cache(
     b, t = tokens.shape
     S = cache["k"].shape[2]
     with jax.named_scope(hot.EMBED):
-        x = params["embed"][tokens].astype(cfg.dtype)
+        x = hyper.expand(cfg, params["embed"][tokens].astype(cfg.dtype))
     q_pos = start + jnp.arange(t)
-    cos_full, sin_full = rope_frequencies(cfg.head_dim, S, cfg.rope_theta)
+    cos_full, sin_full = llama.rope_table(cfg, S)
     cos = jax.lax.dynamic_slice_in_dim(cos_full, start, t, axis=0)
     sin = jax.lax.dynamic_slice_in_dim(sin_full, start, t, axis=0)
 
@@ -160,6 +137,7 @@ def forward_with_cache(
         x, (k_new, v_new) = jax.lax.scan(
             scan_step, x, (params["layers"], cache["k"], cache["v"])
         )
+    x = hyper.collapse(cfg, x)
     with jax.named_scope(hot.NORM):
         x = rms_norm(x, params["final_norm"], cfg.norm_eps)
     with jax.named_scope(hot.LM_HEAD):
@@ -475,6 +453,15 @@ def _table_of(tables, layer: llama.Params):  # noqa: ANN001, ANN202
     return tables[layer["attn_kind"]] if isinstance(tables, dict) else tables
 
 
+def _feed_forward(cfg: llama.LlamaConfig, layer: llama.Params, stream_in: jnp.ndarray):  # noqa: ANN202
+    """The FFN sublayer of a cached or paged step, its norm included: the SAME
+    dispatch as the training forward (dense SwiGLU or GShard MoE: static
+    shapes hold at t=1); the balancing aux is training-only."""
+    with jax.named_scope(hot.NORM):
+        mlp_in = rms_norm(stream_in, layer["mlp_norm"], cfg.norm_eps)
+    return llama.ffn(cfg, layer, mlp_in)
+
+
 def _paged_layer_step(
     cfg: llama.LlamaConfig,
     cos: jnp.ndarray,  # [slots, hd/2] rope rows at each slot's position
@@ -492,27 +479,27 @@ def _paged_layer_step(
     at = layer.get("kind_index")
     window = llama.window_of(cfg, layer)
     tables = _table_of(tables, layer)
-    with jax.named_scope(hot.NORM):
-        attn_in = rms_norm(x, layer["attn_norm"], cfg.norm_eps)
-    with jax.named_scope(hot.ATTN), hot.attn_kind_scope(cfg, layer):
-        if cfg.kv_lora_rank:  # one latent pool, handed through as k_pool
-            attn, k_pool = mla.paged_decode(cfg, layer, attn_in, cos, sin, positions, tables, k_pool)
-            x = x + attn
-        else:
+
+    def attend(stream_in):  # noqa: ANN001, ANN202
+        with jax.named_scope(hot.NORM):
+            attn_in = rms_norm(stream_in, layer["attn_norm"], cfg.norm_eps)
+        with jax.named_scope(hot.ATTN), hot.attn_kind_scope(cfg, layer):
+            if cfg.kv_lora_rank:  # one latent pool, handed through as k_pool
+                attn, pool = mla.paged_decode(cfg, layer, attn_in, cos, sin, positions, tables, k_pool)
+                return attn, (pool, v_pool)
             h, kvh, hd = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
             rows = attn_in[:, 0]  # [slots, d]
             q = _project_heads(rows, layer["wq"], h, hd)
             k = _project_heads(rows, layer["wk"], kvh, hd)
             q, k = llama.norm_and_rotate(cfg, layer, q, k, cos, sin, _rope_rows)
             v = _project_heads(rows, layer["wv"], kvh, hd)
-            k_pool = append_kv(k_pool, tables, positions, k, at, ring=bool(window))
-            v_pool = append_kv(v_pool, tables, positions, v, at, ring=bool(window))
-            attn = paged_attention(q, k_pool, v_pool, tables, positions + 1, at, window)
-            x = x + mm(attn.reshape(slots, 1, h * hd), layer["wo"])
-    with jax.named_scope(hot.NORM):
-        mlp_in = rms_norm(x, layer["mlp_norm"], cfg.norm_eps)
-    down, _aux = llama.ffn(cfg, layer, mlp_in)
-    x = x + down
+            k_new = append_kv(k_pool, tables, positions, k, at, ring=bool(window))
+            v_new = append_kv(v_pool, tables, positions, v, at, ring=bool(window))
+            attn = paged_attention(q, k_new, v_new, tables, positions + 1, at, window)
+            return mm(attn.reshape(slots, 1, h * hd), layer["wo"]), (k_new, v_new)
+
+    x, (k_pool, v_pool) = hyper.residual(cfg, layer, "attn", x, attend)
+    x, _aux = hyper.residual(cfg, layer, "mlp", x, functools.partial(_feed_forward, cfg, layer))
     return x, k_pool, v_pool
 
 
@@ -593,12 +580,13 @@ def paged_decode_step(
     """
     slots = tokens.shape[0]
     with jax.named_scope(hot.EMBED):
-        x = params["embed"][tokens].astype(cfg.dtype)[:, None, :]  # [slots, 1, d]
-    cos_full, sin_full = rope_frequencies(cfg.rope_dim, cfg.max_seq, cfg.rope_theta)
+        x = hyper.expand(cfg, params["embed"][tokens].astype(cfg.dtype)[:, None, :])  # [slots, 1, d]
+    cos_full, sin_full = llama.rope_table(cfg, cfg.max_seq)
     cos, sin = cos_full[positions], sin_full[positions]  # [slots, rope/2]
     x, pools = _scan_groups(
         functools.partial(_paged_layer_step, cfg, cos, sin, positions, tables), x, params, pools, cfg
     )
+    x = hyper.collapse(cfg, x)
     with jax.named_scope(hot.NORM):
         x = rms_norm(x, params["final_norm"], cfg.norm_eps)[:, 0, :]  # [slots, d]
     logits = _lm_head_rows(params, x, cfg)
@@ -665,32 +653,30 @@ def _paged_chunk_layer_step(
     k_pool: jnp.ndarray,  # one layer's pool or its cache kind's stack, as in _paged_layer_step
     v_pool: jnp.ndarray,
 ) -> tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
-    b, t, _ = x.shape
+    b, t = x.shape[:2]
     at = layer.get("kind_index")
     window = llama.window_of(cfg, layer)
     tables = _table_of(tables, layer)
-    with jax.named_scope(hot.NORM):
-        attn_in = rms_norm(x, layer["attn_norm"], cfg.norm_eps)
-    with jax.named_scope(hot.ATTN), hot.attn_kind_scope(cfg, layer):
-        if cfg.kv_lora_rank:  # one latent pool, handed through as k_pool
-            attn, k_pool = mla.paged_prefill(
-                cfg, layer, attn_in, cos, sin, positions, valid, tables, k_pool
-            )
-            x = x + attn
-        else:
+
+    def attend(stream_in):  # noqa: ANN001, ANN202
+        with jax.named_scope(hot.NORM):
+            attn_in = rms_norm(stream_in, layer["attn_norm"], cfg.norm_eps)
+        with jax.named_scope(hot.ATTN), hot.attn_kind_scope(cfg, layer):
+            if cfg.kv_lora_rank:  # one latent pool, handed through as k_pool
+                attn, pool = mla.paged_prefill(cfg, layer, attn_in, cos, sin, positions, valid, tables, k_pool)
+                return attn, (pool, v_pool)
             h, kvh, hd = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
             q = _project_heads(attn_in, layer["wq"], h, hd)
             k = _project_heads(attn_in, layer["wk"], kvh, hd)
             q, k = llama.norm_and_rotate(cfg, layer, q, k, cos, sin, _rope_chunk)
             v = _project_heads(attn_in, layer["wv"], kvh, hd)
-            k_pool = scatter_kv_chunk(k_pool, tables, positions, k, valid, at)
-            v_pool = scatter_kv_chunk(v_pool, tables, positions, v, valid, at)
-            attn = paged_attention_chunk(q, k_pool, v_pool, tables, positions, valid, at, window)
-            x = x + mm(attn.reshape(b, t, h * hd), layer["wo"])
-    with jax.named_scope(hot.NORM):
-        mlp_in = rms_norm(x, layer["mlp_norm"], cfg.norm_eps)
-    down, _aux = llama.ffn(cfg, layer, mlp_in)
-    x = x + down
+            k_new = scatter_kv_chunk(k_pool, tables, positions, k, valid, at)
+            v_new = scatter_kv_chunk(v_pool, tables, positions, v, valid, at)
+            attn = paged_attention_chunk(q, k_new, v_new, tables, positions, valid, at, window)
+            return mm(attn.reshape(b, t, h * hd), layer["wo"]), (k_new, v_new)
+
+    x, (k_pool, v_pool) = hyper.residual(cfg, layer, "attn", x, attend)
+    x, _aux = hyper.residual(cfg, layer, "mlp", x, functools.partial(_feed_forward, cfg, layer))
     return x, k_pool, v_pool
 
 
@@ -722,8 +708,8 @@ def paged_prefill_chunk(
     """
     b, t = tokens.shape
     with jax.named_scope(hot.EMBED):
-        x = params["embed"][tokens].astype(cfg.dtype)  # [b, t, d]
-    cos_full, sin_full = rope_frequencies(cfg.rope_dim, cfg.max_seq, cfg.rope_theta)
+        x = hyper.expand(cfg, params["embed"][tokens].astype(cfg.dtype))  # [b, t, d]
+    cos_full, sin_full = llama.rope_table(cfg, cfg.max_seq)
     positions = prefix_lens[:, None] + jnp.arange(t, dtype=jnp.int32)[None, :]
     pos_safe = jnp.clip(positions, 0, cfg.max_seq - 1)
     cos, sin = cos_full[pos_safe], sin_full[pos_safe]  # [b, t, rope/2]
@@ -732,6 +718,7 @@ def paged_prefill_chunk(
         functools.partial(_paged_chunk_layer_step, cfg, cos, sin, positions, valid, tables),
         x, params, pools, cfg,
     )  # fmt: skip
+    x = hyper.collapse(cfg, x)
     with jax.named_scope(hot.NORM):
         x = rms_norm(x, params["final_norm"], cfg.norm_eps)  # [b, t, d]
     last = x[jnp.arange(b), suffix_lens - 1]  # [b, d]

@@ -34,13 +34,14 @@ import jax.numpy as jnp
 from jax.ad_checkpoint import checkpoint_name
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
+from torchx_tpu.models import hyper
 from torchx_tpu.obs import hot
 from torchx_tpu.parallel import mesh as mesh_lib
 from torchx_tpu.ops.attention import attention
 from torchx_tpu.ops.norms import rms_norm
 from torchx_tpu.ops.quant import maybe_matmul
 from torchx_tpu.ops.ring_attention import ring_attention
-from torchx_tpu.ops.rope import apply_rope, rope_frequencies
+from torchx_tpu.ops.rope import YarnScaling, apply_rope, rope_frequencies
 
 Params = dict[str, Any]
 
@@ -129,9 +130,28 @@ class LlamaConfig:
     qk_norm: bool = False
     # False: full-attention layers take no rotary embedding, sliding layers do
     rope_full_layers: bool = True
+    # latent attention's query through a latent of its own when > 0: q = RMSNorm(u W_qa) W_qb
+    q_lora_rank: int = 0
+    # YaRN's blend of two rotary frequency sets and its scale on the softmax
+    # (ops/rope.py::YarnScaling); None: the one theta
+    rope_scaling: Optional[YarnScaling] = None
+    # hyper-connections (models/hyper.py) when hc_mult > 0: the residual stream is
+    # hc_mult rows a token; each sublayer reads one mix of them and writes back
+    # through a per-token matrix that hc_sinkhorn_iters row-then-column
+    # normalisations make doubly stochastic, its logits clipped to hc_res_clamp
+    # first. 0: x = x + f(x)
+    hc_mult: int = 0
+    hc_sinkhorn_iters: int = 0
+    hc_eps: float = 1e-6
+    hc_res_clamp: tuple[float, float] = (-30.0, 30.0)
 
     def __post_init__(self) -> None:
         object.__setattr__(self, "layer_types", tuple(self.layer_types))
+        object.__setattr__(self, "hc_res_clamp", tuple(float(v) for v in self.hc_res_clamp))
+        if self.hc_mult and (self.kernels != "reference" or self.use_ring_attention):
+            raise ValueError("hyper-connections run through the stock layer ops only, not the fused or ring kernels")
+        if self.q_lora_rank and not self.kv_lora_rank:
+            raise ValueError("q_lora_rank compresses latent attention's query: it needs kv_lora_rank")
         if self.layer_types:
             if len(self.layer_types) != self.n_layers or set(self.layer_types) - {"sliding", "full"}:
                 raise ValueError(
@@ -166,6 +186,15 @@ class LlamaConfig:
         return self.qk_rope_dim if self.kv_lora_rank else self.head_dim
 
     @property
+    def attn_scale(self) -> float:
+        """What the scores are multiplied by ahead of the softmax: the width the
+        query and key are dotted over to the power -1/2, times the square of
+        YaRN's ``mscale`` where the model scales its rotary frequencies."""
+        width = self.qk_nope_dim + self.qk_rope_dim if self.kv_lora_rank else self.head_dim
+        scale = width**-0.5
+        return scale * self.rope_scaling.attention_mscale**2 if self.rope_scaling else scale
+
+    @property
     def cache_kinds(self) -> tuple[str, ...]:
         """The cache kind of each layer: ``"window"`` for a sliding layer (its
         pool holds the blocks that still touch a slot's window), else ``"full"``."""
@@ -196,9 +225,9 @@ class LlamaConfig:
         """Matmul weights of one layer's attention."""
         d, h = self.dim, self.n_heads
         if self.kv_lora_rank:
-            r = self.kv_lora_rank
+            r, rq, q_width = self.kv_lora_rank, self.q_lora_rank, h * (self.qk_nope_dim + self.qk_rope_dim)
             return (
-                d * h * (self.qk_nope_dim + self.qk_rope_dim)  # wq
+                (d * rq + rq + rq * q_width if rq else d * q_width)  # w_qa, q_latent_norm, w_qb | wq
                 + d * (r + self.qk_rope_dim)  # w_kva
                 + r * h * (self.qk_nope_dim + self.v_head_dim)  # w_kvb
                 + h * self.v_head_dim * d  # wo
@@ -221,10 +250,12 @@ class LlamaConfig:
     def param_count(self) -> int:
         """Exact parameter count for this shape (layers + embeddings)."""
         d, f, v = self.dim, self.ffn_dim, self.vocab_size
+        n = self.hc_mult
         per_layer = (
             self.attention_param_count()
             + 3 * d * f  # gate, up, down
             + 2 * d  # norms
+            + (2 * ((n * d + 1) * (2 * n + n * n) + 3) if n else 0)  # hyper-connections: phi, b, a of two sublayers
         )
         total = self.n_layers * per_layer + v * d + d  # embed + final norm
         if not self.tie_embeddings:
@@ -306,11 +337,27 @@ def init_params(cfg: LlamaConfig, key: jax.Array) -> Params:
     ks = jax.random.split(k_layers, 7)
     if cfg.kv_lora_rank:
         r, dn, dr, dv = cfg.kv_lora_rank, cfg.qk_nope_dim, cfg.qk_rope_dim, cfg.v_head_dim
+        rq = cfg.q_lora_rank
+        if rq:
+            k_qa, k_qb = jax.random.split(ks[0])
+            query = {
+                "w_qa": norm_init(k_qa, (L, d, rq), d),
+                "q_latent_norm": jnp.ones((L, rq), dtype=cfg.dtype),
+                "w_qb": norm_init(k_qb, (L, rq, h * (dn + dr)), rq),
+            }
+        else:
+            query = {"wq": norm_init(ks[0], (L, d, h * (dn + dr)), d)}
         attn = {
-            "wq": norm_init(ks[0], (L, d, h * (dn + dr)), d),
+            **query,
             "w_kva": norm_init(ks[1], (L, d, r + dr), d),
             "kv_norm": jnp.ones((L, r), dtype=cfg.dtype),
-            "w_kvb": norm_init(ks[2], (L, r, h * (dn + dv)), r),
+            # beside a compressed query the up-projection's key and value parts lie apart,
+            # each transposed, the heads outermost (models/mla.py)
+            **(
+                {"w_uk": norm_init(ks[2], (L, h, dn, r), r), "w_uv": norm_init(jax.random.fold_in(ks[2], 1), (L, h, dv, r), r)}
+                if rq
+                else {"w_kvb": norm_init(ks[2], (L, r, h * (dn + dv)), r)}
+            ),
             "wo": norm_init(ks[3], (L, h * dv, d), h * dv),
         }
     else:
@@ -332,6 +379,7 @@ def init_params(cfg: LlamaConfig, key: jax.Array) -> Params:
             "w_gate": norm_init(ks[4], (L, d, f), d),
             "w_up": norm_init(ks[5], (L, d, f), d),
             "w_down": norm_init(ks[6], (L, f, d), f),
+            **hyper.init_leaves(cfg, jax.random.fold_in(k_layers, 7), L),
         },
         "final_norm": jnp.ones((d,), dtype=cfg.dtype),
     }
@@ -353,11 +401,20 @@ def param_specs(cfg: LlamaConfig, pp: bool = False) -> Params:
     if cfg.kv_lora_rank:
         # the latent is every head's: its down-projection and norm stay
         # whole over tp, the per-head up-projection splits there
+        query = (
+            {"w_qa": P(layer_axis, "fsdp", None), "q_latent_norm": P(layer_axis, None), "w_qb": P(layer_axis, None, "tp")}
+            if cfg.q_lora_rank
+            else {"wq": P(layer_axis, "fsdp", "tp")}
+        )
         attn = {
-            "wq": P(layer_axis, "fsdp", "tp"),
+            **query,
             "w_kva": P(layer_axis, "fsdp", None),
             "kv_norm": P(layer_axis, None),
-            "w_kvb": P(layer_axis, None, "tp"),
+            **(
+                {"w_uk": P(layer_axis, "tp", None, None), "w_uv": P(layer_axis, "tp", None, None)}
+                if cfg.q_lora_rank
+                else {"w_kvb": P(layer_axis, None, "tp")}
+            ),
             "wo": P(layer_axis, "tp", "fsdp"),
         }
     else:
@@ -381,6 +438,8 @@ def param_specs(cfg: LlamaConfig, pp: bool = False) -> Params:
             "w_gate": P(layer_axis, "fsdp", "tp"),
             "w_up": P(layer_axis, "fsdp", "tp"),
             "w_down": P(layer_axis, "tp", "fsdp"),
+            # a sublayer's mixing coefficients are every chip's: a few rows a token, read whole
+            **{name: P(layer_axis, *(None,) * len(shape)) for name, shape in hyper.leaf_shapes(cfg).items()},
         },
         "final_norm": P(None),
     }
@@ -522,6 +581,12 @@ def _constraint(x: jnp.ndarray, mesh: Optional[Mesh], *spec) -> jnp.ndarray:
     return jax.lax.with_sharding_constraint(x, NamedSharding(mesh, P(*spec)))
 
 
+def rope_table(cfg: LlamaConfig, positions: int, start=0) -> tuple[jnp.ndarray, jnp.ndarray]:  # noqa: ANN001
+    """(cos, sin) ``[positions, rope_dim / 2]`` of the model's rotary embedding
+    from position ``start`` on: its theta, and its YaRN blend where it has one."""
+    return rope_frequencies(cfg.rope_dim, positions, cfg.rope_theta, start=start, scaling=cfg.rope_scaling)
+
+
 def ffn(
     cfg: LlamaConfig, layer: Params, mlp_in: jnp.ndarray
 ) -> tuple[jnp.ndarray, jnp.ndarray]:
@@ -656,21 +721,27 @@ def _layer(
     shard's global offset."""
     if cos is None:
         start = jax.lax.axis_index("sp") * x.shape[1]
-        cos, sin = rope_frequencies(cfg.rope_dim, x.shape[1], cfg.rope_theta, start=start)
+        cos, sin = rope_table(cfg, x.shape[1], start)
 
-    # attention block
-    with jax.named_scope(hot.NORM):
-        attn_in = rms_norm(x, layer["attn_norm"], cfg.norm_eps, mesh=mesh)
-    with jax.named_scope(hot.ATTN), hot.attn_kind_scope(cfg, layer):
-        if cfg.kv_lora_rank:
-            from torchx_tpu.models import mla
+    def attend(stream_in):  # noqa: ANN001, ANN202 - the attention sublayer, its norm included
+        with jax.named_scope(hot.NORM):
+            attn_in = rms_norm(stream_in, layer["attn_norm"], cfg.norm_eps, mesh=mesh)
+        with jax.named_scope(hot.ATTN), hot.attn_kind_scope(cfg, layer):
+            if cfg.kv_lora_rank:
+                from torchx_tpu.models import mla
 
-            attn_out = mla.attention_full(cfg, layer, attn_in, cos, sin)
-        else:
-            attn_out = _gqa_attention(cfg, mesh, cos, sin, attn_in, layer)
+                return mla.attention_full(cfg, layer, attn_in, cos, sin), None
+            return _gqa_attention(cfg, mesh, cos, sin, attn_in, layer), None
+
+    def feed_forward(stream_in):  # noqa: ANN001, ANN202 - dense SwiGLU, or MoE when the config carries experts
+        with jax.named_scope(hot.NORM):
+            mlp_in = rms_norm(stream_in, layer["mlp_norm"], cfg.norm_eps, mesh=mesh)
+        return ffn(cfg, layer, mlp_in)
+
     if cfg.kernels != "reference":
         from torchx_tpu.ops.fused import rms_norm_residual
 
+        attn_out, _ = attend(x)
         # fused residual-add + RMSNorm: one VMEM pass yields both the mlp
         # input and the continued stream (degrades internally to the
         # reference op sequence when gating fails — identical values)
@@ -684,14 +755,12 @@ def _layer(
                 mesh=mesh,
             )
         x = _constraint(x, mesh, ("dp", "fsdp"), "sp", None)
-    else:
-        x = x + attn_out
-        x = _constraint(x, mesh, ("dp", "fsdp"), "sp", None)
-        # mlp block: dense SwiGLU, or MoE when the config carries experts
-        with jax.named_scope(hot.NORM):
-            mlp_in = rms_norm(x, layer["mlp_norm"], cfg.norm_eps, mesh=mesh)
-    down, aux = ffn(cfg, layer, mlp_in)
-    x = x + down
+        down, aux = ffn(cfg, layer, mlp_in)
+        x = x + down
+        return _constraint(x, mesh, ("dp", "fsdp"), "sp", None), aux
+    x, _ = hyper.residual(cfg, layer, "attn", x, attend)
+    x = _constraint(x, mesh, ("dp", "fsdp"), "sp", None)
+    x, aux = hyper.residual(cfg, layer, "mlp", x, feed_forward)
     return _constraint(x, mesh, ("dp", "fsdp"), "sp", None), aux
 
 
@@ -773,7 +842,7 @@ def features_from_embeddings(
     continuous-input entry point interpretability needs (gradients w.r.t.
     embeddings, e.g. saliency / integrated gradients over tokens)."""
     s = x.shape[1]
-    x = x.astype(cfg.dtype)
+    x = hyper.expand(cfg, x.astype(cfg.dtype))
     x = _constraint(x, mesh, ("dp", "fsdp"), "sp", None)
 
     pp = mesh.shape.get("pp", 1) if mesh is not None else 1
@@ -790,14 +859,15 @@ def features_from_embeddings(
     if ring_in_pp:
         cos = sin = None
     else:
-        cos, sin = rope_frequencies(cfg.rope_dim, s, cfg.rope_theta)
+        cos, sin = rope_table(cfg, s)
 
     body = _remat(functools.partial(_layer, cfg, mesh, cos, sin), cfg)
 
     if pp > 1:
-        if "dense_layers" in params or cfg.layer_types:
+        if "dense_layers" in params or cfg.layer_types or cfg.hc_mult:
             raise NotImplementedError(
-                "pipeline parallelism over a stack that leads with dense layers or mixes attention kinds"
+                "pipeline parallelism over a stack that leads with dense layers, mixes attention kinds"
+                " or carries several residual streams"
             )
         # pipeline the layer stack over the pp axis (embedding/head stay
         # outside the pipeline, replicated over pp)
@@ -852,6 +922,7 @@ def features_from_embeddings(
                 aux_per_layer[:, AUX_OVERFLOW].mean(),
             ]
         )
+    x = hyper.collapse(cfg, x)
     with jax.named_scope(hot.NORM):
         x = rms_norm(x, params["final_norm"], cfg.norm_eps, mesh=mesh)
     return x, aux_total

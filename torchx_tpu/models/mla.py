@@ -7,7 +7,16 @@ A token's keys and values of all ``h`` heads are one latent ``c`` of
     q = u W_q            as [h, qk_nope_dim + qk_rope_dim] = (q_nope, q_rope)
     u W_kva              as [kv_lora_rank + qk_rope_dim]   = (c, k_rope);  c = RMSNorm_kv(c)
     c W_kvb              as [h, qk_nope_dim + v_head_dim]  = (k_nope, v)
-    k = (k_nope, k_rope for every head);  softmax(q.k / sqrt(qk_nope_dim + qk_rope_dim)) v;  W_o
+    k = (k_nope, k_rope for every head);  softmax(q.k * cfg.attn_scale) v;  W_o
+
+With ``q_lora_rank > 0`` the query goes through a latent of its own, ``c_q =
+RMSNorm_q(u W_qa)``, ``q = c_q W_qb``, and the tree holds ``W_kvb`` as the absorbed
+decode multiplies it, its key and its value columns apart, each transposed with
+the heads outermost: ``w_uk [h, qk_nope_dim, kv_lora_rank]`` and ``w_uv [h,
+v_head_dim, kv_lora_rank]`` (a loader writes them so once; :func:`_head_blocks`
+has the rehearsal's reason). ``cfg.attn_scale`` is ``(qk_nope_dim +
+qk_rope_dim)^-0.5``, times the square of YaRN's ``mscale`` where the model
+scales its rotary frequencies; all three forms below read that one number.
 
 What a token leaves in the cache is ``(c, k_rope after rotation)``, one row a
 layer whatever the head count, padded with zeros to ``cfg.cache_width`` (whole
@@ -38,7 +47,7 @@ import jax
 import jax.numpy as jnp
 
 from torchx_tpu.obs import hot
-from torchx_tpu.ops.attention import note_traced, xla_attention
+from torchx_tpu.ops.attention import note_traced, project_heads, xla_attention
 from torchx_tpu.ops.norms import rms_norm
 from torchx_tpu.ops.paged_attention import TRASH_BLOCK, append_kv, gather_kv, scatter_kv_chunk
 from torchx_tpu.ops.paged_mla import paged_mla_attention
@@ -58,7 +67,12 @@ def project(cfg, layer, u: jnp.ndarray, cos: jnp.ndarray, sin: jnp.ndarray):  # 
     (rotated), and what the token leaves in the cache, ``[..., cache_width]``:
     the normed latent and the rotated key side by side, zeros behind them."""
     h, dn, dr, r = cfg.n_heads, cfg.qk_nope_dim, cfg.qk_rope_dim, cfg.kv_lora_rank
-    q = mm(u, layer["wq"]).reshape(*u.shape[:-1], h, dn + dr)
+    if "w_qa" in layer:
+        with jax.named_scope(hot.MLA_Q_LATENT):
+            c_q = rms_norm(mm(u, layer["w_qa"]), layer["q_latent_norm"], cfg.norm_eps)
+        q = project_heads(c_q, layer["w_qb"], h, dn + dr)
+    else:
+        q = mm(u, layer["wq"]).reshape(*u.shape[:-1], h, dn + dr)
     q_nope, q_rope = q[..., :dn], rotate(q[..., dn:], cos, sin)
     with jax.named_scope(hot.MLA_LATENT):
         kva = mm(u, layer["w_kva"])
@@ -73,8 +87,12 @@ def _expand(cfg, layer, cached: jnp.ndarray):  # noqa: ANN001
     """Cached rows ``[..., cache_width]`` -> every head's key ``[..., h, nope
     + rope]`` and value ``[..., h, v]``."""
     h, dn, dv, r, dr = cfg.n_heads, cfg.qk_nope_dim, cfg.v_head_dim, cfg.kv_lora_rank, cfg.qk_rope_dim
-    kv = mm(cached[..., :r], layer["w_kvb"]).reshape(*cached.shape[:-1], h, dn + dv)
     k_rope = jnp.broadcast_to(cached[..., None, r : r + dr], (*cached.shape[:-1], h, dr))
+    if "w_uk" in layer:  # split into heads as W_qb is: each weight read where it lies in its stack
+        k_nope = project_heads(cached[..., :r], layer["w_uk"].reshape(h * dn, r).T, h, dn)
+        v = project_heads(cached[..., :r], layer["w_uv"].reshape(h * dv, r).T, h, dv)
+        return jnp.concatenate((k_nope, k_rope), axis=-1), v
+    kv = mm(cached[..., :r], layer["w_kvb"]).reshape(*cached.shape[:-1], h, dn + dv)
     return jnp.concatenate((kv[..., :dn], k_rope), axis=-1), kv[..., dn:]
 
 
@@ -85,7 +103,7 @@ def attention_full(cfg, layer, u: jnp.ndarray, cos: jnp.ndarray, sin: jnp.ndarra
     k, v = _expand(cfg, layer, cached)
     note_traced("attention", "xla")
     with jax.named_scope(hot.ATTN_KERNEL):
-        out = xla_attention(jnp.concatenate((q_nope, q_rope), axis=-1), k, v, causal=True)
+        out = xla_attention(jnp.concatenate((q_nope, q_rope), axis=-1), k, v, causal=True, scale=cfg.attn_scale)
     return mm(out.reshape(b, s, cfg.n_heads * cfg.v_head_dim), layer["wo"])
 
 
@@ -134,10 +152,7 @@ def paged_prefill(
                     held = jax.lax.dynamic_slice_in_dim(tables, c * step_blocks, step_blocks, axis=1)
                     k, v = _expand(cfg, layer, gather_kv(pool, held, pool_layer))  # [b, step_rows, h, .]
                 with jax.named_scope(hot.SCORES):
-                    s = (
-                        jnp.einsum("bqhd,bkhd->bhqk", q_rows, k, preferred_element_type=jnp.float32)
-                        * q.shape[-1] ** -0.5
-                    )
+                    s = jnp.einsum("bqhd,bkhd->bhqk", q_rows, k, preferred_element_type=jnp.float32) * cfg.attn_scale
                     at = c * step_rows + jnp.arange(step_rows)
                     s = jnp.where((at[None, None, :] <= pos_rows[:, :, None])[:, None], s, _MASKED)
                     m_new = jnp.maximum(m, s.max(axis=-1))
@@ -192,14 +207,31 @@ def paged_decode(
     q_nope, q_rope, cached = project(cfg, layer, u[:, 0], cos, sin)
     with jax.named_scope(hot.APPEND_LATENT):
         pool = append_kv(pool, tables, positions, cached, pool_layer)
-    w_kvb = layer["w_kvb"].reshape(r, h, dn + dv)
+    (w_k, k_axes), (w_v, v_axes) = _head_blocks(cfg, layer)
     with jax.named_scope(hot.MLA_ABSORB):
-        q_lat = jnp.einsum("shn,rhn->shr", q_nope, w_kvb[..., :dn])
+        q_lat = jnp.einsum(f"shn,{k_axes}->shr", q_nope, w_k)
     q_pad = jnp.zeros((slots, h, cached.shape[-1] - r - cfg.qk_rope_dim), q_lat.dtype)
     o_lat = paged_mla_attention(
-        jnp.concatenate((q_lat, q_rope, q_pad), axis=-1), pool, tables, positions + 1, r,
-        (dn + cfg.qk_rope_dim) ** -0.5, pool_layer,
+        jnp.concatenate((q_lat, q_rope, q_pad), axis=-1), pool, tables, positions + 1, r, cfg.attn_scale, pool_layer,
     )  # [slots, h, rank]
     with jax.named_scope(hot.MLA_ABSORB):
-        out = jnp.einsum("shr,rhv->shv", o_lat, w_kvb[..., dn:])
+        out = jnp.einsum(f"shr,{v_axes}->shv", o_lat, w_v)
     return mm(out.reshape(slots, 1, h * dv), layer["wo"]), pool
+
+
+def _head_blocks(cfg, layer):  # noqa: ANN001, ANN202
+    """``W_kvb`` as the absorbed form multiplies it, a product a head: -> (its
+    key part, that array's axes as an einsum names them: ``h`` heads, ``n`` nope,
+    ``r`` rank), (its value part, its axes: ``v``). A product batched over the
+    heads takes the heads outermost. A tree that holds the two parts so
+    (``w_uk [h, nope, rank]``, ``w_uv [h, v, rank]``: the compressed query's, see
+    the module's docstring) has each read where it lies, by its one product: a
+    reshape between a layer's slice of the stack and the batched product is
+    enough to have the slice written out first (rehearsal, PR 33). ``w_kvb
+    [rank, h (nope + v)]`` is sliced out of its stack and written transposed in
+    front of the products, every step (ROADMAP queue 1 item 6)."""
+    if "w_uk" in layer:
+        return (layer["w_uk"], "hnr"), (layer["w_uv"], "hvr")
+    h, dn, dv, r = cfg.n_heads, cfg.qk_nope_dim, cfg.v_head_dim, cfg.kv_lora_rank
+    w = layer["w_kvb"].reshape(r, h, dn + dv)
+    return (w[..., :dn], "rhn"), (w[..., dn:], "rhv")

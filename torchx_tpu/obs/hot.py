@@ -95,6 +95,11 @@ MOE_SORT = "moe_sort"  # under moe_dispatch, dropless path: sort by expert, coun
 MOE_SHARED = "moe_shared"  # the shared expert beside the routed ones
 MLA_LATENT = "mla_latent"  # down-projection, latent norm, rotary key
 MLA_ABSORB = "mla_absorb"  # decode: W_kvb folded into the query and out of the result
+MLA_Q_LATENT = "mla_q_latent"  # under attn: the query's own down-projection and its norm (q_lora_rank)
+HC_PRE = "hc_pre"  # hyper-connections, a sublayer: the stream's norm, its projection, the gates, the read-in
+HC_SINKHORN = "hc_sinkhorn"  # ... the write-back matrix made doubly stochastic
+HC_POST = "hc_post"  # ... the write-back: the streams mixed, the sublayer's output added
+HC_HEAD = "hc_head"  # the streams summed ahead of the final norm
 APPEND_LATENT = "append_latent"
 PAGED_ATTENTION = "paged_attention"
 GATHER_KV = "gather_kv"
@@ -109,7 +114,7 @@ DEVICE_SCOPES = (
     LAYERS, ATTN, ATTN_KERNEL, MLP, NORM, LM_HEAD, LOSS, EMBED, MOE_ROUTER, MOE_DISPATCH,
     MOE_EXPERTS, MOE_COMBINE, PAGED_ATTENTION, GATHER_KV, SCORES, VALUES,
     APPEND_KV, SAMPLE, GRAD_CLIP, OPTIMIZER, MOE_SORT, MOE_SHARED, MLA_LATENT, MLA_ABSORB,
-    APPEND_LATENT, ATTN_WINDOW, ATTN_FULL, QK_NORM,
+    APPEND_LATENT, ATTN_WINDOW, ATTN_FULL, QK_NORM, MLA_Q_LATENT, HC_PRE, HC_SINKHORN, HC_POST, HC_HEAD,
 )  # fmt: skip
 
 

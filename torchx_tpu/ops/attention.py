@@ -37,6 +37,7 @@ import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
 from torchx_tpu.obs import hot
+from torchx_tpu.ops.quant import maybe_matmul
 
 
 #: op name -> implementations its call sites lowered to in this process
@@ -53,6 +54,30 @@ def traced(op: str) -> str:
     """What ``op`` lowered to so far: ``"splash"``, ``"splash+xla"`` when
     call sites differed, ``""`` when nothing traced it yet."""
     return "+".join(sorted(TRACED.get(op, ())))
+
+
+def project_heads(x: jnp.ndarray, w, heads: int, hd: int) -> jnp.ndarray:  # noqa: ANN001
+    """``x @ w`` split into heads, ``[..., d] -> [..., heads, hd]``: head ``j``
+    is columns ``[j * hd, (j + 1) * hd)`` of the product.
+
+    The barrier pins the product as the 2-D ``[rows, heads * hd]`` value it is,
+    so the split is a reshape of the activation and never reaches the weight:
+    the matmul takes its layer's slice of the parameter stack inside its own
+    fusion, in the layout the tree has, as ``wo`` and the MLP do. With the
+    reshape adjacent, XLA folds it into the matmul (however the product is
+    written: flattened first, float32 out, operands swapped or transposed), the
+    weight becomes ``[d, heads, hd]``, the chip's compiler runs the contraction
+    as a convolution over the heads and asks for the weight as ``[heads, hd,
+    d]``: every layer's ``wq``/``wk``/``wv`` sliced out of its stack and written
+    transposed in front of a 16-64 row matmul, every step, 5 of the 23 ms
+    ``k-exaone`` decode program (PERF.md section 6, PR 32). The decode programs
+    are held to it on the chip's compiler (``obs.hlo.program_moves``,
+    ``tests/test_paged_attention_kernel.py``);
+    ``traced("projections")`` answers ``in_place``. The serving steps know it as
+    ``generate._project_heads``; latent attention's ``W_qb`` goes through it too
+    (``models/mla.py``)."""
+    note_traced("projections", "in_place")
+    return jax.lax.optimization_barrier(maybe_matmul(x, w)).reshape(*x.shape[:-1], heads, hd)
 
 
 def _on_tpu() -> bool:
@@ -76,15 +101,18 @@ def xla_attention(
     causal: bool = True,
     segment_ids: Optional[jnp.ndarray] = None,
     window: int = 0,
+    scale: Optional[float] = None,
 ) -> jnp.ndarray:
     """Plain einsum softmax attention (f32 softmax, GQA via KV repeat);
     runs everywhere and is the numerical reference for the kernels.
     ``window`` > 0 (causal only) admits key ``j`` for query ``i`` where
-    ``i - window < j <= i``: a sliding layer's local mask."""
+    ``i - window < j <= i``: a sliding layer's local mask. ``scale``
+    multiplies the scores; None: the head width to the power -1/2."""
     n_rep = q.shape[2] // k.shape[2]
     k = _repeat_kv(k, n_rep)
     v = _repeat_kv(v, n_rep)
-    scale = q.shape[-1] ** -0.5
+    if scale is None:
+        scale = q.shape[-1] ** -0.5
     logits = jnp.einsum(
         "bqhd,bkhd->bhqk", q, k, preferred_element_type=jnp.float32
     ) * scale

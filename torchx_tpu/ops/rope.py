@@ -7,27 +7,88 @@ a pair of fused multiplies XLA folds into the attention projections.
 
 from __future__ import annotations
 
+import dataclasses
+import math
+from typing import Optional
+
 import jax.numpy as jnp
+
+from torchx_tpu.ops.attention import note_traced
+
+
+@dataclasses.dataclass(frozen=True)
+class YarnScaling:
+    """YaRN's rotary scaling as a model's ``rope_scaling`` publishes it: the
+    frequencies a context of ``original_max_seq`` turns fewer than ``beta_slow``
+    times are divided by ``factor``, those it turns more than ``beta_fast``
+    times are kept, and a linear ramp over the pair index blends the two sets
+    between. ``mscale`` over ``mscale_all_dim`` scales cos and sin;
+    ``mscale_all_dim`` alone scales the softmax (:meth:`attention_mscale`)."""
+
+    factor: float
+    original_max_seq: int
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    mscale: float = 1.0
+    mscale_all_dim: float = 0.0
+
+    def _mscale(self, m: float) -> float:
+        return 0.1 * m * math.log(self.factor) + 1.0 if self.factor > 1 else 1.0
+
+    @property
+    def attention_mscale(self) -> float:
+        """``m`` of the softmax scale ``head_width ** -0.5 * m * m``."""
+        return self._mscale(self.mscale_all_dim)
+
+    @property
+    def rotation_mscale(self) -> float:
+        """What cos and sin are multiplied by."""
+        return self._mscale(self.mscale) / self._mscale(self.mscale_all_dim)
+
+    def ramp_bounds(self, head_dim: int, theta: float) -> tuple[int, int]:
+        """The pair indices the ramp runs between: below ``low`` a frequency is
+        kept, from ``high`` on it is divided by ``factor``."""
+
+        def pair_turning(rotations: float) -> float:
+            return head_dim * math.log(self.original_max_seq / (rotations * 2 * math.pi)) / (2 * math.log(theta))
+
+        low, high = math.floor(pair_turning(self.beta_fast)), math.ceil(pair_turning(self.beta_slow))
+        return max(low, 0), min(high, head_dim - 1)
+
+    def inv_freq(self, head_dim: int, theta: float) -> jnp.ndarray:
+        """``[head_dim / 2]`` float32: ``f / factor`` where the ramp is 1, ``f`` where it is 0."""
+        f = 1.0 / (theta ** (jnp.arange(0, head_dim, 2, dtype=jnp.float32) / head_dim))
+        low, high = self.ramp_bounds(head_dim, theta)
+        span = high - low if high != low else 0.001
+        ramp = jnp.clip((jnp.arange(head_dim // 2, dtype=jnp.float32) - low) / span, 0.0, 1.0)
+        return f / self.factor * ramp + f * (1.0 - ramp)
 
 
 def rope_frequencies(
-    head_dim: int, max_seq: int, theta: float = 500000.0, start=0
+    head_dim: int, max_seq: int, theta: float = 500000.0, start=0, scaling: Optional[YarnScaling] = None
 ) -> tuple[jnp.ndarray, jnp.ndarray]:
     """-> (cos, sin), each [max_seq, head_dim//2], float32.
 
     ``start`` offsets the position index (static int or traced scalar):
     sequence-sharded layouts (ring attention under a manualized ``sp``
     axis) compute the frequencies for their own shard of positions with
-    ``start = axis_index("sp") * local_seq``.
+    ``start = axis_index("sp") * local_seq``. ``scaling`` blends the
+    frequencies as YaRN does (:class:`YarnScaling`);
+    ``ops.attention.traced("rope")`` answers ``yarn`` or ``plain``.
     """
-    inv_freq = 1.0 / (
-        theta ** (jnp.arange(0, head_dim, 2, dtype=jnp.float32) / head_dim)
-    )
+    note_traced("rope", "yarn" if scaling else "plain")
+    if scaling is None:
+        inv_freq = 1.0 / (
+            theta ** (jnp.arange(0, head_dim, 2, dtype=jnp.float32) / head_dim)
+        )
+    else:
+        inv_freq = scaling.inv_freq(head_dim, theta)
     t = jnp.arange(max_seq, dtype=jnp.float32) + jnp.asarray(
         start, dtype=jnp.float32
     )
     freqs = jnp.outer(t, inv_freq)  # [seq, head_dim/2]
-    return jnp.cos(freqs), jnp.sin(freqs)
+    m = scaling.rotation_mscale if scaling else 1.0
+    return (jnp.cos(freqs), jnp.sin(freqs)) if m == 1.0 else (jnp.cos(freqs) * m, jnp.sin(freqs) * m)
 
 
 def apply_rope(
