@@ -1,23 +1,39 @@
-"""The two new kernels alone on the chip, and the served path with and without them.
+"""The latent decode kernel and the experts' grouped matmul alone on the chip, and the
+served path with and without them.
 
-A one-off measurement (PR 27), not a tool of the benchmark. On one TPU, at the widths
-of ``kimi-vl-a3b-serve-backlog``, it
+A one-off measurement (PR 27; the latent kernel's part rewritten by PR 36), not a tool of
+the benchmark: no cell runs it. On one TPU
 
-* compares ``paged_mla_pallas`` and ``paged_mla_attention_xla`` with the same
-  attention in float64 on the host (64 slots of ragged lengths near 2 k rows), and
-  times both as a loop of calls inside one program (a call's output feeds the next
-  call's query, so nothing is hoisted): microseconds a call, and the latent bytes
-  the slots hold (1,152 B a row) over that time as a share of 819 GB/s;
-* compares the Pallas grouped matmul (``megablox``, as ``ops/grouped_matmul.py``
-  tiles it, the layer read out of the stack) and ``jax.lax.ragged_dot`` with float64
-  at decode's 384 rows and at a prefill's 12,288, and times both;
-* with ``--parity``, serves one prompt through ``ServeEngine`` twice, with the kernels
-  and with ``kernel_eligible`` answering no (the XLA functions), and prints for each
-  how far the served tokens' logits lie below the reference's best (the numbers the
-  cell's ``correct`` compares), so that a fault in a kernel shows as a difference
-  between the two.
+* ``--cell kimi-vl-a3b-serve-backlog | xing4-serve-decode-long`` runs ``paged_mla_pallas``
+  and ``paged_mla_attention_xla`` at that cell's shapes, read from its configuration through
+  ``benchmark/lib`` (slots, heads, block, table, row, pool blocks, the larger layer group's
+  depth), into the group's pool stack with a traced ``layer`` (the last: block ids reach the
+  stack's end). Both are held to the same attention in float64 on the host over four sets of
+  lengths: every edge of the kernel's bookkeeping (0, 1, 16, 17, 512, 513, 1,024, 1,025, one
+  row short of the table, the table), the same in reverse, every slot at the table's end, and
+  a ragged draw round the cell's mean. The ragged draw is timed as a loop of calls inside one
+  program (a call's output feeds the next call's query, so nothing is hoisted): microseconds a
+  call, nanoseconds a 20 KB block, and the rows' bytes over that time as a share of 819 GB/s by
+  both counts (1,152 B a row needed, 1,280 B as it lies);
+* ``--protocol`` makes 200 calls in one program and 200 programs of one call at the ragged
+  draw with the edges among its slots: none may differ from the first, and ``--watchdog-s``
+  ends a run that hangs;
+* ``--grouped`` compares the Pallas grouped matmul (``megablox``, as ``ops/grouped_matmul.py``
+  tiles it, the layer read out of the stack) and ``jax.lax.ragged_dot`` with float64 at
+  decode's 384 rows and at a prefill's 12,288, and times both (``kimi``'s widths);
+* ``--parity`` serves one prompt through ``ServeEngine`` twice, with the kernels and with
+  ``kernel_eligible`` answering no (the XLA functions), and prints for each how far the served
+  tokens' logits lie below the reference's best (the numbers the cell's ``correct`` compares).
 
-    chiprun -- python3 scripts/mla_moe_chip.py [--parity]
+    chiprun -- python3 scripts/mla_moe_chip.py --cell xing4-serve-decode-long [--protocol]
+
+What each lever of PR 36 gave, nanoseconds a block at ``kimi``'s / ``xing4``'s shapes (my chip
+runs, PR 36; 25 is the wire): the parent 56.0 / 59.6; the compiler's bounds check in front of
+every copy taken out 41.9 / 44.8; eight copies a group started unrolled and waited for as one
+38.9 / 41.7; 1,024 rows a buffer, multiplied 512 at a time over the live parts 33.7 / 37.3. The
+copies alone, nothing multiplied, take 31.5 / 32.7; the products alone 23.6 / 26.4. Products
+cut into 128-row pieces with the starts between them were slower (59.5 / 70.1): PERF.md section
+6, PR 36.
 
 It needs a TPU: a time from the CPU's interpreter says nothing.
 """
@@ -35,7 +51,7 @@ sys.path.insert(0, REPO)
 
 CALLS = 32
 HBM_BYTES_PER_S = 819e9  # one v5e (benchmark/lib/peaks.py)
-CELL = "kimi-vl-a3b-serve-backlog"
+CELLS = ("kimi-vl-a3b-serve-backlog", "xing4-serve-decode-long")
 
 
 def timed(fn, *args) -> float:  # noqa: ANN001
@@ -48,7 +64,93 @@ def timed(fn, *args) -> float:  # noqa: ANN001
     return time.perf_counter() - t0
 
 
-def attention(rng) -> dict:  # noqa: ANN001
+def cell_shapes(cell: str) -> dict:
+    """The latent kernel's shapes in ``cell``, read from the cell's configuration: slots,
+    heads, block, table, row, the pool's blocks and the larger layer group's depth."""
+    from benchmark.lib import models, spec
+    from torchx_tpu.models import generate as gen
+
+    loaded = spec.load_cell(cell)
+    config, mix, dep = loaded.config, loaded.traffic, loaded.config["deployment"]
+    cfg = models.program_config(config, max_seq=int(dep["max_seq"]))
+    slots, bs = int(dep["max_slots"]), int(dep["block_size"])
+    bpr = -(-cfg.max_seq // bs)
+    return dict(
+        cell=cell, slots=slots, h=cfg.n_heads, bs=bs, bpr=bpr, rank=cfg.kv_lora_rank, rope=cfg.qk_rope_dim, width=cfg.cache_width,
+        nb=1 + slots * (bpr // 2),  # ServeEngine's default pool, which both cells take
+        layers=max(gen.layer_group_sizes(cfg).values()), scale=float(cfg.attn_scale),
+        # a slot midway through its answer: the prompt's median and half the answer's
+        mean_rows=int(mix["prompt"]["median"] + mix["output"]["median"] // 2), min_rows=int(mix["prompt"]["min"]),
+    )  # fmt: skip
+
+
+def latent_inputs(seed: int, sh: dict):  # noqa: ANN201
+    """A layer group's pool stack and the slots' queries, made on the device; the lanes
+    behind ``rank + rope`` hold zeros as the program leaves them."""
+    import jax
+    import jax.numpy as jnp
+
+    kq, kp = jax.random.split(jax.random.PRNGKey(seed))
+    lanes = jnp.arange(sh["width"]) < sh["rank"] + sh["rope"]
+    stack = jax.jit(lambda: jnp.where(lanes, jax.random.normal(
+        kp, (sh["layers"], sh["nb"], sh["bs"], sh["width"]), jnp.bfloat16), 0).astype(jnp.bfloat16))()  # fmt: skip
+    q = jnp.where(lanes, jax.random.normal(kq, (sh["slots"], sh["h"], sh["width"]), jnp.float32) * 0.5, 0)
+    return q.astype(jnp.bfloat16), stack
+
+
+def tables_for(rng, sh: dict, lengths) -> "np.ndarray":  # noqa: ANN001, F821
+    """Block tables in shuffled physical order. Slots share blocks when they hold more than
+    the pool has (every slot at ``max_seq``): the kernel only reads. The pool's last block
+    is always somebody's, so with the last layer the copies reach the stack's end."""
+    import numpy as np
+
+    live = [-(-int(n) // sh["bs"]) for n in lengths]
+    ids = rng.permutation(np.arange(1, sh["nb"]))
+    ids = np.resize(ids, max(sum(live), 1))
+    ids[rng.integers(0, len(ids))] = sh["nb"] - 1
+    tables = np.zeros((sh["slots"], sh["bpr"]), np.int32)
+    at = 0
+    for i, n in enumerate(live):
+        tables[i, :n] = ids[at : at + n]
+        at += n
+    return tables
+
+
+def float64_attention(q, pool, tables, lengths, sh: dict) -> "np.ndarray":  # noqa: ANN001, F821
+    """The same attention in float64 on the host over one layer's pool (a numpy array)."""
+    import numpy as np
+
+    q64 = np.asarray(q, np.float64)
+    want = np.zeros((sh["slots"], sh["h"], sh["rank"]))
+    for i, n in enumerate(lengths):
+        n = max(int(n), 1)  # a slot of no length attends its first row: nobody reads the answer
+        rows = pool[tables[i, : -(-n // sh["bs"])]].astype(np.float64).reshape(-1, sh["width"])[:n]
+        s = q64[i] @ rows.T * sh["scale"]
+        p = np.exp(s - s.max(-1, keepdims=True))
+        want[i] = (p / p.sum(-1, keepdims=True)) @ rows[:, : sh["rank"]]
+    return want
+
+
+def edge_lengths(rng, sh: dict) -> dict:  # noqa: ANN001
+    """The length sets the kernel is held to float64 over: every edge of its bookkeeping
+    (a slot of no length and of one row, a block's, a chunk's and the table's edges), every
+    slot at the table's end, and the cell's own ragged draw."""
+    import numpy as np
+
+    top = sh["bpr"] * sh["bs"]
+    edges = np.minimum([0, 1, 16, 17, 512, 513, 1024, 1025, top - 1, top], top)
+    lo, hi = sh["min_rows"], 2 * sh["mean_rows"] - sh["min_rows"]
+    return {
+        "edges": np.resize(edges, sh["slots"]),
+        "edges_reversed": np.resize(edges[::-1], sh["slots"]),
+        "all_at_the_tables_end": np.full(sh["slots"], top),
+        "ragged": rng.integers(lo, hi, sh["slots"]),
+    }
+
+
+def attention(rng, sh: dict, qd, stack, calls: int = CALLS) -> dict:  # noqa: ANN001
+    """``paged_mla_pallas`` alone at a cell's shapes (``cell_shapes``), into the stack of the
+    cell's larger layer group (``latent_inputs``) with a traced ``layer``."""
     import jax
     import jax.numpy as jnp
     import numpy as np
@@ -56,46 +158,72 @@ def attention(rng) -> dict:  # noqa: ANN001
     from torchx_tpu.ops import paged_mla as pm
     from torchx_tpu.ops import paged_mla_kernel as pmk
 
-    slots, h, bs, bpr, rank, rope, width = 64, 16, 16, 264, 512, 64, 640
-    lengths = rng.integers(1300, 3400, slots)
-    live = [-(-int(n) // bs) for n in lengths]
-    nb = 1 + sum(live)
-    perm = rng.permutation(np.arange(1, nb))
-    tables = np.zeros((slots, bpr), np.int32)
-    at = 0
-    for i, n in enumerate(live):
-        tables[i, :n] = perm[at : at + n]
-        at += n
-    pool = rng.standard_normal((nb, bs, width), dtype=np.float32)
-    q = rng.standard_normal((slots, h, width), dtype=np.float32) * 0.5
-    pool[..., rank + rope :] = 0.0
-    q[..., rank + rope :] = 0.0
-    scale = 192**-0.5
-    dev = lambda x: jnp.asarray(x, jnp.bfloat16)  # noqa: E731
-    qd, pd, td, ld = dev(q), dev(pool), jnp.asarray(tables), jnp.asarray(lengths, jnp.int32)
-    q64, p64 = np.asarray(qd, np.float64), np.asarray(pd, np.float64)
-    want = np.zeros((slots, h, rank))
-    for i, n in enumerate(lengths):
-        rows = p64[tables[i, : live[i]]].reshape(-1, width)[:n]
-        s = q64[i] @ rows.T * scale
-        p = np.exp(s - s.max(-1, keepdims=True))
-        want[i] = (p / p.sum(-1, keepdims=True)) @ rows[:, :rank]
-    out = {"rows_held": int(lengths.sum()), "blocks": nb}
-    for name, fn in (("pallas", pmk.paged_mla_pallas), ("xla", pm.paged_mla_attention_xla)):
-        one = jax.jit(lambda q, p, t, n, fn=fn: fn(q, p, t, n, rank, scale))
-        out[f"{name}_max_err"] = float(np.abs(np.asarray(one(qd, pd, td, ld), np.float64) - want).max())
+    rank, scale, row = sh["rank"], sh["scale"], sh["rank"] + sh["rope"]
+    layer = jnp.int32(sh["layers"] - 1)
+    pool = np.asarray(stack[sh["layers"] - 1].astype(jnp.float32))
+    fns = {"pallas": pmk.paged_mla_pallas, "xla": pm.paged_mla_attention_xla}
+    one = {name: jax.jit(lambda q, p, t, n, i, fn=fn: fn(q, p, t, n, rank, scale, layer=i)) for name, fn in fns.items()}
+    out = {k: sh[k] for k in ("cell", "slots", "h", "bpr", "nb", "layers")}
+    for case, lengths in edge_lengths(rng, sh).items():
+        tables = tables_for(rng, sh, lengths)
+        want = float64_attention(qd, pool, tables, lengths, sh)
+        td, ld = jnp.asarray(tables), jnp.asarray(lengths, jnp.int32)
+        keep = np.asarray(lengths) > 0
+        for name in fns:
+            got = np.asarray(one[name](qd, stack, td, ld, layer), np.float64)
+            out[f"{case}.{name}_max_err"] = float(np.abs(got - want)[keep].max())
+            out[f"{case}.{name}_finite"] = bool(np.isfinite(got).all())
+    # the last case is the cell's ragged draw: time that one
+    blocks = int(sum(-(-int(n) // sh["bs"]) for n in lengths))
+    out.update(rows_held=int(lengths.sum()), blocks_held=blocks)
+    for name, fn in fns.items():
 
-        def loop(q, p, t, n, fn=fn):  # noqa: ANN001, ANN202
+        def loop(q, p, t, n, i, fn=fn):  # noqa: ANN001, ANN202
             def body(_, q):  # noqa: ANN001, ANN202
-                o = fn(q, p, t, n, rank, scale)
+                o = fn(q, p, t, n, rank, scale, layer=i)
                 return q.at[..., :rank].set(o)
 
-            return jax.lax.fori_loop(0, CALLS, body, q)
+            return jax.lax.fori_loop(0, calls, body, q)
 
-        us = timed(jax.jit(loop), qd, pd, td, ld) / CALLS * 1e6
-        out[f"{name}_us"] = us
-        out[f"{name}_hbm_pct"] = 100.0 * lengths.sum() * (rank + rope) * 2 / HBM_BYTES_PER_S / (us * 1e-6)
+        s = timed(jax.jit(loop), qd, stack, td, ld, layer) / calls
+        out[f"{name}_us"] = s * 1e6
+        if name == "pallas":
+            out["pallas_ns_a_block"] = s * 1e9 / blocks
+        for label, width in (("needed", row), ("as_laid_out", sh["width"])):
+            out[f"{name}_hbm_pct_{label}"] = 100.0 * lengths.sum() * width * 2 / HBM_BYTES_PER_S / s
     return out
+
+
+def protocol(rng, sh: dict, qd, stack, calls: int = 200) -> dict:  # noqa: ANN001
+    """``calls`` calls in one program and ``calls`` programs of one call at the cell's
+    ragged draw: none may differ from the first (or hang: the caller's watchdog)."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from torchx_tpu.ops import paged_mla_kernel as pmk
+
+    rank, scale = sh["rank"], sh["scale"]
+    lengths = edge_lengths(rng, sh)["ragged"]
+    top = sh["bpr"] * sh["bs"]
+    every = lengths[:: max(1, sh["slots"] // 8)]  # the edges among them, at a few slots
+    every[:] = np.minimum(np.resize([0, 1, 17, 513, top, 512, 16, 1025], len(every)), top)
+    td, ld = jnp.asarray(tables_for(rng, sh, lengths)), jnp.asarray(lengths, jnp.int32)
+    layer = jnp.int32(sh["layers"] - 1)
+    one = jax.jit(lambda q, p, t, n, i: pmk.paged_mla_pallas(q, p, t, n, rank, scale, layer=i))
+    first = np.asarray(one(qd, stack, td, ld, layer), np.float32)
+
+    def many(q, p, t, n, i):  # noqa: ANN001, ANN202
+        def body(_, same):  # noqa: ANN001, ANN202
+            o = pmk.paged_mla_pallas(q, p, t, n, rank, scale, layer=i)
+            return same & jnp.array_equal(o.astype(jnp.float32), first)
+
+        return jax.lax.fori_loop(0, calls, body, jnp.bool_(True))
+
+    in_one_program = bool(jax.jit(many)(qd, stack, td, ld, layer))
+    one_a_program = all(np.array_equal(np.asarray(one(qd, stack, td, ld, layer), np.float32), first) for _ in range(calls))
+    return {"cell": sh["cell"], "calls": calls, "same_in_one_program": in_one_program, "same_one_a_program": one_a_program,
+            "finite": bool(np.isfinite(first).all())}  # fmt: skip
 
 
 def grouped(rng, m: int) -> dict:  # noqa: ANN001
@@ -142,7 +270,7 @@ def parity(seed: int) -> dict:
     from torchx_tpu.ops import paged_mla as pm
     from torchx_tpu.serve.engine import ServeEngine
 
-    config = spec.load_cell(CELL).config
+    config = spec.load_cell(CELLS[0]).config
     cfg = models.program_config(config, max_seq=1024)
     params = models.make_weights(config, seed)
     rng = np.random.default_rng(seed)
@@ -173,8 +301,14 @@ def parity(seed: int) -> dict:
 def main() -> int:
     ap = argparse.ArgumentParser(description=__doc__)
     ap.add_argument("--seed", type=int, default=27)
+    ap.add_argument("--cell", choices=CELLS, default=CELLS[0], help="whose shapes the latent kernel is run at")
+    ap.add_argument("--protocol", action="store_true", help="200 calls in one program and 200 programs of one call")
+    ap.add_argument("--grouped", action="store_true", help="the grouped matmul too (kimi's widths)")
     ap.add_argument("--parity", action="store_true")
+    ap.add_argument("--watchdog-s", type=int, default=1500, help="exit if the whole run takes longer: a kernel that hangs")
     args = ap.parse_args()
+    import faulthandler
+
     import jax
     import numpy as np
 
@@ -185,10 +319,16 @@ def main() -> int:
     from torchx_tpu.parallel.xla_cache import setup_compilation_cache
 
     setup_compilation_cache()
+    faulthandler.dump_traceback_later(args.watchdog_s, exit=True)
     rng = np.random.default_rng(args.seed)
-    out = {"device_kind": dev.device_kind, "attention": attention(rng),
-           "grouped_decode": grouped(rng, 384), "grouped_prefill": grouped(rng, 12288)}  # fmt: skip
-    print(json.dumps(out), flush=True)
+    sh = cell_shapes(args.cell)
+    qd, stack = latent_inputs(args.seed, sh)
+    print(json.dumps({"device_kind": dev.device_kind, "attention": attention(rng, sh, qd, stack)}), flush=True)
+    if args.protocol:
+        print(json.dumps({"protocol": protocol(rng, sh, qd, stack)}), flush=True)
+    del qd, stack
+    if args.grouped:
+        print(json.dumps({"grouped_decode": grouped(rng, 384), "grouped_prefill": grouped(rng, 12288)}), flush=True)
     if args.parity:
         print(json.dumps({"parity": parity(args.seed)}), flush=True)
     return 0

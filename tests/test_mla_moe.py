@@ -21,6 +21,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from jax.experimental.pallas import tpu as pltpu
 
 from benchmark.lib import models
 from benchmark.reference import mla_moe as ref
@@ -257,17 +258,24 @@ def test_paged_prefill_walks_the_window_to_the_same_attention(model, monkeypatch
     assert np.isfinite(np.asarray(got)).all()  # padded query rows too: the next layer multiplies them
 
 
+def _shrink_the_kernels_geometry(monkeypatch):
+    """A chunk of 4 blocks in parts of 2 and groups of 1: a table of 6 blocks then spans two
+    chunks, both buffers, several parts and groups."""
+    for name, rows in (("_CHUNK_ROWS", 64), ("_PART_ROWS", 32), ("_GROUP_ROWS", 16)):
+        monkeypatch.setattr(pmk, name, rows)
+
+
 @pytest.mark.parametrize("lengths,dtype", [
     pytest.param([1, 16, 17, 96], jnp.float32, id="ragged-float32"),
     pytest.param([40, 3, 96, 64, 0], jnp.float32, id="a-slot-of-no-length"),
     pytest.param([33, 80, 7], jnp.bfloat16, id="bfloat16"),
 ])  # fmt: skip
 def test_mla_kernel_matches_the_xla_function(lengths, dtype, monkeypatch):
-    """The Pallas kernel in the interpreter, its chunk shrunk to 2 blocks so that a
-    slot spans several chunks and both buffers. Tolerances as for the K/V kernel
+    """The Pallas kernel in the interpreter, its chunk shrunk to 4 blocks in parts of 2
+    and groups of 1, so that a slot spans several chunks and both buffers. Tolerances as for the K/V kernel
     (tests/test_paged_attention_kernel.py): float32 sums in another order; bfloat16
     rounds probabilities before the division where XLA rounds them after."""
-    monkeypatch.setattr(pmk, "_CHUNK_ROWS", 32)
+    _shrink_the_kernels_geometry(monkeypatch)
     q, pool, tables, lens, rank = _latent_problem(lengths, dtype=dtype)
     # the trash block and blocks past a slot's length hold what a decode step left there: never read
     want = pm.paged_mla_attention_xla(q, pool, tables, lens, rank, 0.07)
@@ -283,7 +291,7 @@ def test_mla_kernel_reads_its_layer_out_of_the_stack(dtype, monkeypatch):
     """``layer=i`` on the stack ``[layers, num_blocks, bs, width]`` against the same call
     on ``stack[i]`` (bit for bit) and against the XLA function at that layer: ragged
     lengths, a slot of no length, an inactive slot whose table is all trash block."""
-    monkeypatch.setattr(pmk, "_CHUNK_ROWS", 32)
+    _shrink_the_kernels_geometry(monkeypatch)
     lengths = [40, 0, 96, 17, 1]
     problems = [_latent_problem(lengths, seed=s, dtype=dtype) for s in range(3)]
     q, _, tables, lens, rank = problems[0]
@@ -304,8 +312,70 @@ def test_mla_kernel_reads_its_layer_out_of_the_stack(dtype, monkeypatch):
     assert not np.array_equal(outs[0], outs[1]) and not np.array_equal(outs[1], outs[2])  # the layers differ
 
 
+# Every edge the kernel's bookkeeping has, at its real geometry (two buffers of 1,024 rows, copied in
+# groups of 128 and multiplied 512 at a time): lengths are rows, a table holds `bpr` blocks of 16.
+EDGES = [
+    pytest.param([0, 1, 40], 72, id="no-length-then-one-row"),
+    pytest.param([127, 128, 129], 72, id="a-groups-edge-and-one-past"),
+    pytest.param([512, 513], 72, id="a-parts-edge-and-one-past"),
+    pytest.param([1024, 1025], 72, id="a-chunks-edge-and-one-past"),
+    pytest.param([1152, 16], 72, id="every-block-of-the-table"),
+    pytest.param([2100], 136, id="one-slot-more-chunks-than-buffers"),
+    pytest.param([2000, 5], 136, id="long-then-short"),
+    pytest.param([5, 2000], 136, id="short-then-long"),
+    pytest.param([512, 1536, 1536, 512], 136, id="whole-parts-only"),
+    pytest.param([1024, 2048, 1024, 2048], 136, id="whole-chunks-only-the-first-buffer-alternates"),
+    pytest.param([600, 0, 0, 700], 72, id="slots-of-no-length-between"),
+    pytest.param([1024, 700], 72, id="the-last-slot-ragged"),
+    pytest.param([1], 72, id="one-slot-one-row"),
+]
+
+
+def _held_in_the_tpu_interpreter(capfd, dma, want, q, pool, tables, lens, rank, **layer):
+    """``paged_mla_pallas`` in the TPU interpreter (semaphores simulated, scratch full of NaN)
+    against ``want`` on every slot that has rows, and nothing left over or raced for."""
+    got = pmk.paged_mla_pallas(
+        q, pool, tables, lens, rank, 0.07, **layer, interpret=pltpu.InterpretParams(dma_execution_mode=dma, detect_races=True))
+    live = np.asarray(lens) > 0
+    np.testing.assert_allclose(np.asarray(got)[live], np.asarray(want)[live], atol=2e-6, rtol=1e-5)
+    assert np.isfinite(np.asarray(got)[live]).all()
+    out = capfd.readouterr().out
+    assert "non-zero count" not in out and "RACE DETECTED" not in out, out
+
+
+@pytest.mark.parametrize("dma", ["on_wait", "eager"])
+@pytest.mark.parametrize("lengths,bpr", EDGES)
+def test_mla_kernel_bookkeeping_on_every_edge(lengths, bpr, dma, capfd):
+    """The kernel in the TPU interpreter, which simulates the copies' semaphores and hands
+    out scratch memory full of NaN, against the XLA function in float32. ``on_wait``: a copy
+    lands only when its semaphore is waited for, so a chunk read before its wait, or a group
+    started and never waited for, reads NaN or stale rows. ``eager``: every byte started
+    must have been waited for when the kernel ends, or the interpreter says so. (A wait for
+    bytes nobody started would hang here as on the chip.) The trash block holds NaN where
+    no slot is empty: past its last live block a slot reads that block again, never the table."""
+    q, pool, tables, lens, rank = _latent_problem(lengths, bpr=bpr)
+    want = pm.paged_mla_attention_xla(q, pool, tables, lens, rank, 0.07)
+    if min(lengths) > 0:
+        pool = pool.at[pa.TRASH_BLOCK].set(jnp.nan)
+    _held_in_the_tpu_interpreter(capfd, dma, want, q, pool, tables, lens, rank)
+
+
+@pytest.mark.parametrize("layer", [0, 2], ids=["first-layer", "last-layer"])
+def test_mla_kernel_bookkeeping_into_a_stack(layer, capfd):
+    """The same accounting with the block ids moved into layer ``layer`` of a stack seen flat,
+    up to the stack's last block."""
+    lengths = [700, 0, 1025, 16]
+    problems = [_latent_problem(lengths, bpr=72, seed=s) for s in range(3)]
+    q, _, tables, lens, rank = problems[0]
+    tables = tables.at[0, 0].set(problems[0][1].shape[0] - 1)  # the pool's last block is somebody's
+    stack = jnp.stack([p[1] for p in problems])
+    want = pm.paged_mla_attention_xla(q, stack, tables, lens, rank, 0.07, jnp.int32(layer))
+    _held_in_the_tpu_interpreter(capfd, "eager", want, q, stack, tables, lens, rank, layer=jnp.int32(layer))
+
+
 @pytest.mark.parametrize("shapes,backend,want", [
-    (((64, 16, 640), (8449, 16, 640), 512), "tpu", True),  # the cell's
+    (((64, 16, 640), (8449, 16, 640), 512), "tpu", True),  # kimi-vl-a3b-serve-backlog's
+    (((128, 32, 640), (16897, 16, 640), 512), "tpu", True),  # xing4-serve-decode-long's
     (((64, 16, 640), (8449, 16, 640), 512), "cpu", False),
     (((64, 16, 576), (8449, 16, 576), 512), "tpu", False),  # rows of 4.5 lanes: the chip cannot slice them
     (((4, 4, 128), (9, 16, 128), 32), "tpu", False),  # a latent of a quarter lane, four heads
@@ -323,8 +393,21 @@ def test_traced_says_which_latent_attention_lowered(model, monkeypatch):
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     attn_ops.TRACED.pop("attention", None)
     q16, pool16 = q.astype(jnp.bfloat16), pool.astype(jnp.bfloat16)
+    attn_ops.TRACED.pop("paged_mla_geometry", None)
     jax.make_jaxpr(lambda *a: pm.paged_mla_attention(*a, rank, 0.1))(q16, pool16, tables, lens)
     assert attn_ops.traced("attention") == "paged_mla_pallas"
+    # what the lowering chose from the shapes: a table of 6 blocks is one chunk, one part, one group
+    assert attn_ops.traced("paged_mla_geometry") == "chunk 96 part 96 group 96 rows, 2 buffers"
+
+
+def test_the_kernels_geometry_at_both_cells_shapes():
+    """Blocks a chunk, a part and a group from the block size and the table alone: both
+    latent cells (blocks of 16, tables of 264) copy 1,024 rows a buffer in groups of 128 and
+    multiply 512 at a time; a short table shrinks all three."""
+    assert pmk._geometry(16, 264) == (64, 32, 8)
+    assert pmk._geometry(32, 132) == (32, 16, 4)
+    assert pmk._geometry(16, 40) == (32, 32, 8)
+    assert pmk._geometry(16, 3) == (3, 3, 3)
 
 
 # -- (d) dropless routing under imbalance --------------------------------------------

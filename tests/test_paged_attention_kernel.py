@@ -14,6 +14,7 @@ from __future__ import annotations
 
 import functools
 import importlib
+import math
 
 import jax
 import jax.numpy as jnp
@@ -326,21 +327,36 @@ def test_windowed_kernel_compiles_for_the_chip(one_chip):
     assert not [ln for ln in text.splitlines() if " copy(" in ln and "bf16[6,1169," in ln]
 
 
-def test_latent_kernel_compiles_for_the_chip(one_chip):
+@pytest.mark.parametrize("slots,h,pool_shape", [
+    pytest.param(64, 16, (8449, 16, 640), id="one-layers-pool"),
+    # the two cells that run the kernel, as their decode programs call it: the larger layer group's stack, a traced layer
+    pytest.param(64, 16, (8, 8449, 16, 640), id="kimi-vl-a3b-serve-backlog"),
+    pytest.param(128, 32, (6, 16897, 16, 640), id="xing4-serve-decode-long"),
+])  # fmt: skip
+def test_latent_kernel_compiles_for_the_chip(one_chip, slots, h, pool_shape):
+    """The chip's compiler takes the latent kernel at each cell's exact shapes (what it
+    refuses costs a run on the chip otherwise: VMEM, SMEM for a table of 128 x 264 entries,
+    an operation Mosaic has not at 32 heads), and the pool goes in as it lies."""
     from torchx_tpu.ops import paged_mla as pm
     from torchx_tpu.ops import paged_mla_kernel as pmk
 
-    slots, h, bs, nb, bpr, rank, width = 64, 16, 16, 8449, 264, 512, 640
+    bpr, rank, width = 264, 512, 640
     shape = lambda s, d: jax.ShapeDtypeStruct(s, d, sharding=one_chip)  # noqa: E731
-    pool = shape((nb, bs, width), jnp.bfloat16)
-    assert pm.kernel_eligible((slots, h, width), pool.shape, rank, jnp.dtype(jnp.bfloat16), pool.dtype, "tpu")
-    compiled = jax.jit(functools.partial(pmk.paged_mla_pallas, rank=rank, scale=192**-0.5)).lower(
-        shape((slots, h, width), jnp.bfloat16), pool, shape((slots, bpr), jnp.int32), shape((slots,), jnp.int32)
-    ).compile()  # fmt: skip
+    pool = shape(pool_shape, jnp.bfloat16)
+    assert pm.kernel_eligible((slots, h, width), pool.shape[-3:], rank, jnp.dtype(jnp.bfloat16), pool.dtype, "tpu")
+    args = [shape((slots, h, width), jnp.bfloat16), pool, shape((slots, bpr), jnp.int32), shape((slots,), jnp.int32)]
+    if len(pool_shape) == 4:
+        fn = lambda q, p, t, n, i: pmk.paged_mla_pallas(q, p, t, n, rank, 192**-0.5, layer=i)  # noqa: E731
+        args.append(shape((), jnp.int32))
+    else:
+        fn = functools.partial(pmk.paged_mla_pallas, rank=rank, scale=192**-0.5)
+    compiled = jax.jit(fn).lower(*args).compile()
     text = compiled.as_text()
-    assert "tpu_custom_call" in text
-    # the pool goes in as it lies in HBM: no relayout of 168 MB a layer in front of the kernel
-    assert not [ln for ln in text.splitlines() if " copy(" in ln and f"bf16[{nb}," in ln]
+    assert "tpu_custom_call" in text and "paged_mla_decode" in text
+    # the pool goes in as it lies in HBM: no relayout of 168 MB a layer in front of the kernel, no temporary of its order
+    pool_types = ["bf16[" + ",".join(map(str, pool_shape[:2])), f"bf16[{math.prod(pool_shape[:-2])},"]  # as given, and seen flat
+    assert not [ln for ln in text.splitlines() if " copy(" in ln and any(t in ln for t in pool_types)]
+    assert compiled.memory_analysis().temp_size_in_bytes < 64 << 20
 
 
 # The serving programs whole, with the pools donated: the chip's compiler keeps the stack the
