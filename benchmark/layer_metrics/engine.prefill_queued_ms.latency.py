@@ -1,0 +1,19 @@
+"""``engine.prefill_queued_ms`` in a cell that is judged on its tails: the same reading,
+listed apart because there it moves ``tpot_p95_ms`` and not the tokens per second.
+On an idle engine a prompt's program starts at once; behind a decode step it waits that
+step out."""
+
+import importlib.util
+import os
+
+_spec = importlib.util.spec_from_file_location(
+    "_base", os.path.join(os.path.dirname(os.path.abspath(__file__)), "engine.prefill_queued_ms.py"))
+_base = importlib.util.module_from_spec(_spec)
+_spec.loader.exec_module(_base)
+
+NAME = "engine.prefill_queued_ms.latency"
+UNIT = _base.UNIT
+LAYER = _base.LAYER
+MOVES = "tpot_p95_ms"
+SOURCE = _base.SOURCE
+read = _base.read

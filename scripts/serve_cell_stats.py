@@ -1,9 +1,14 @@
 """One run of a serving cell as ``benchmark/run.py`` makes it, with the engine's own
 counters printed as the window's engine stops: ``preemptions``, ``kv_bytes_per_token``,
 ``steps_overlapped``, ``tokens_discarded`` (PR 30), blocks in use, the prefix cache's hits and
-evictions. The harness hands its readers
+evictions, and the admission rounds' running counts (PR 38: ``prefill_rounds``,
+``prefill_tokens``, ``prefill_padded_tokens``, ``prefill_programs_built``,
+``slot_steps_stalled``). The harness hands its readers
 neither ``engine.stats()`` nor the requests (PERF.md 7.2 (c)); this is how PR 27 read
-the preemptions of ``kimi-vl-a3b-serve-backlog``. Not a tool of the benchmark.
+the preemptions of ``kimi-vl-a3b-serve-backlog``. With ``--trace 1`` it also prints what
+``benchmark/lib/program_runs.py`` reads of the traced 4 s: the runs linked by ``run_id``,
+the offset's bounds, how many programs the midpoint rule gives to another turn, the
+exposed turn after a round in its parts. Not a tool of the benchmark.
 
     chiprun -- python3 scripts/serve_cell_stats.py --workload <cell> --seed <n> --seconds 45 [--trace 1]
 """
@@ -35,12 +40,21 @@ def main() -> int:
     def stop_and_tell(self, *a, **kw):  # noqa: ANN001, ANN002, ANN003, ANN202
         s = self.stats()
         keep = ("preemptions", "steps_overlapped", "tokens_discarded", "kv_bytes_per_token", "kv_blocks_used",
-                "kv_blocks_free", "requests_done", "steps", "prefix_cache")  # fmt: skip
+                "kv_blocks_free", "requests_done", "steps", "prefix_cache", "prefill_rounds", "prefill_tokens",
+                "prefill_padded_tokens", "prefill_programs_built", "slot_steps_stalled")  # fmt: skip
         print("engine stats at stop:", json.dumps({k: s[k] for k in keep if k in s}), flush=True)
         return stop(self, *a, **kw)
 
     eng.ServeEngine.stop = stop_and_tell
     out = run_cell(args.workload, args.seed, args.seconds, bool(args.trace), t_start=T_START)
+    if args.trace:
+        from benchmark.lib import program_runs, spec, trace
+
+        try:  # the harness's trace is still where it wrote it
+            reading = program_runs.read(trace.find_xplane(os.path.join(spec.scratch_dir(spec.load_cell(args.workload)), "trace")))
+        except FileNotFoundError:
+            reading = None
+        print("program runs of the traced window:", json.dumps(program_runs.account(reading) if reading else None), flush=True)
     print(json.dumps(out), flush=True)
     return 0
 

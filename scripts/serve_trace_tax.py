@@ -11,13 +11,15 @@ process and on one seed, reads the decode step's period
 
 with untraced stretches between them. For each traced stretch it prints the
 device's idle share, the readings of ``benchmark/lib/host_spans.py`` and the
-by-scope shares of ``benchmark/lib/scopes.py``. It also records the short
-trace that ``benchmark/tests/data/serve_spans.xplane.pb`` is (a few decode
-steps and one admission round, Python tracer off).
+by-scope shares of ``benchmark/lib/scopes.py``. It also records a short
+trace, Python tracer off: ``benchmark/tests/data/serve_spans.xplane.pb`` is
+PR 24's (a few decode steps and one admission round), and
+``benchmark/tests/data/serve_runs.xplane.pb`` PR 38's (the engine that keeps a
+step in flight: a few decode turns and two rounds, each behind a step).
 
     chiprun -- python3 scripts/serve_trace_tax.py --workload mixtral8x7b-serve-backlog --seed 7
 
-Everything it writes goes under ``chiprun_out/trace_tax/``. It needs a TPU.
+Everything it writes goes under ``chiprun_out/trace_tax/<workload>/``. It needs a TPU.
 """
 
 from __future__ import annotations
@@ -38,7 +40,7 @@ def main() -> int:
     ap.add_argument("--workload", default="mixtral8x7b-serve-backlog")
     ap.add_argument("--seed", type=int, default=7)
     ap.add_argument("--stretch", type=float, default=4.0, help="seconds of each traced stretch")
-    ap.add_argument("--fixture-s", type=float, default=0.4, help="seconds of the short recorded trace")
+    ap.add_argument("--fixture-s", type=float, default=0.25, help="seconds of the short recorded trace")
     ap.add_argument("--bench-dir", default=None, help="another benchmark directory (the tests' fixtures)")
     ap.add_argument("--allow-cpu", action="store_true", help="rehearse on the CPU: no device numbers")
     args = ap.parse_args()
@@ -53,7 +55,7 @@ def main() -> int:
     from torchx_tpu.parallel.xla_cache import setup_compilation_cache
     from torchx_tpu.serve.engine import ServeEngine, ServeRequest
 
-    out_dir = os.path.join(REPO, "chiprun_out", "trace_tax")
+    out_dir = os.path.join(REPO, "chiprun_out", "trace_tax", args.workload)
     shutil.rmtree(out_dir, ignore_errors=True)
     os.makedirs(out_dir)
     print("env JAX_COMPILATION_CACHE_DIR =", os.environ.get("JAX_COMPILATION_CACHE_DIR"), flush=True)
@@ -162,11 +164,11 @@ def main() -> int:
     results.append(stretch("tracer_off", args.stretch, quiet, os.path.join(out_dir, "tracer_off_2")))
     results.append(stretch("untraced", args.stretch))
 
-    # the short recorded trace: try until one holds an admission round
+    # the short recorded trace: try until one holds two rounds (a chat cell's never does: its last try stands)
     fixture_dir = os.path.join(out_dir, "fixture")
-    for attempt in range(8):
+    for attempt in range(24):
         rec = stretch(f"fixture.{attempt}", args.fixture_s, quiet, fixture_dir)
-        if rec.get("admit_spans", 0) >= 1 and rec.get("decode_spans", 0) >= 3:
+        if rec.get("admit_spans", 0) >= 2 and rec.get("decode_spans", 0) >= 3:
             break
     results.append(rec)
 
@@ -177,7 +179,7 @@ def main() -> int:
     for name in ("tracer_default", "tracer_off_2"):
         shutil.rmtree(os.path.join(out_dir, name))
     kept = os.path.join(out_dir, "tracer_off")
-    if sum(os.path.getsize(os.path.join(r, f)) for r, _, fs in os.walk(kept) for f in fs) > 24e6:
+    if sum(os.path.getsize(os.path.join(r, f)) for r, _, fs in os.walk(kept) for f in fs) > 48e6:
         shutil.rmtree(kept)
     with open(os.path.join(out_dir, "results.json"), "w") as f:
         json.dump({"device": dev, "workload": args.workload, "seed": args.seed, "engine_failed": failed,

@@ -14,6 +14,7 @@ import jax.numpy as jnp
 
 from torchx_tpu.models import generate as gen, llama, moe
 from torchx_tpu.obs import hot
+from torchx_tpu.obs import metrics as obs_metrics
 from torchx_tpu.obs import trace as obs_trace
 from torchx_tpu.serve.engine import ServeEngine, ServeRequest
 
@@ -24,7 +25,20 @@ ENGINE_SPANS = [
     *hot.SERVE_SPAN_TREE[hot.SERVE_DECODE],
     hot.SERVE_IDLE,
     hot.SERVE_KV_IMPORT,
+    hot.SERVE_COW_COPY,
 ]
+#: stats() count -> what of a span adds up to it over a run
+RUNNING_COUNTS = {
+    "prefill_rounds": lambda evs: sum(1 for ev in _rounds(evs)),
+    "prefill_tokens": lambda evs: sum(ev[3]["tokens"] for ev in _rounds(evs)),
+    "prefill_padded_tokens": lambda evs: sum(ev[3]["rows_padded"] * ev[3]["width"] for ev in _rounds(evs)),
+    "prefill_programs_built": lambda evs: sum(ev[3]["built"] for ev in _rounds(evs)),
+    "slot_steps_stalled": lambda evs: sum(ev[3]["slots_stalled"] for ev in _rounds(evs)),
+}
+
+
+def _rounds(events):
+    return [ev for ev in events if ev[0] == hot.SERVE_ADMIT and "rows" in ev[3]]
 
 
 def _session(tmp_path, body):
@@ -63,6 +77,20 @@ def _drive(engine):
     assert moved.wait(timeout=120) and not moved.error
     idle = threading.Event()
     idle.wait(0.05)
+    _force_copy_on_write(engine)
+
+
+def _force_copy_on_write(engine):
+    """On an idle engine, from the calling thread: slot 0 gets a tail block that
+    another holder shares, and is made to write into it (no request of a
+    running engine comes to that: the cache adopts whole blocks only)."""
+    blocks = engine.alloc.alloc(2)
+    engine.tables.assign(0, blocks)
+    engine.alloc.retain([blocks[1]])
+    assert engine._ensure_capacity(0, engine.block_size)
+    assert engine.tables.blocks_of(0)[1] != blocks[1]
+    engine.alloc.release([blocks[1]])
+    engine.alloc.free(engine.tables.release(0))
 
 
 @pytest.fixture(scope="module")
@@ -72,23 +100,45 @@ def tiny():
 
 
 @pytest.fixture(scope="module")
-def engine_line(tiny, tmp_path_factory):
-    """The engine thread's line of a traced run: every ``serve.*`` event."""
+def traced_drive(tiny, tmp_path_factory):
+    """A traced ``_drive``: the host plane's lines, ``stats()`` and the decode
+    tokens counted before and after it."""
     cfg, params = tiny
     engine = ServeEngine(params, cfg, max_slots=4, block_size=16, max_prefill_batch=2).start()
+    counted = lambda: {phase: obs_metrics.SERVE_TOKENS.value(phase=phase) for phase in ("prefill", "decode")}  # noqa: E731
     try:
         _drive(engine)  # compile outside the session
+        before, tokens_before = engine.stats(), counted()
         lines = _session(tmp_path_factory.mktemp("trace"), lambda: _drive(engine))
+        after, tokens_after = engine.stats(), counted()
     finally:
         engine.stop()
-    with_spans = [ln for ln in lines if any(name == hot.SERVE_DECODE for name, *_ in ln)]
+    return {
+        "lines": lines,
+        "before": before,
+        "after": after,
+        "tokens": {phase: tokens_after[phase] - tokens_before[phase] for phase in tokens_after},
+    }
+
+
+@pytest.fixture(scope="module")
+def engine_line(traced_drive):
+    """The engine thread's line of a traced run: every ``serve.*`` event."""
+    with_spans = [ln for ln in traced_drive["lines"] if any(name == hot.SERVE_DECODE for name, *_ in ln)]
     assert len(with_spans) == 1, "the engine's spans lie on one thread's line"
     return [ev for ev in with_spans[0] if ev[0].startswith("serve.")]
 
 
+@pytest.fixture(scope="module")
+def serve_events(traced_drive):
+    """Every ``serve.*`` event of the traced run in time order, whatever
+    thread it lies on (the forced copy-on-write is the test thread's)."""
+    return sorted((ev for ln in traced_drive["lines"] for ev in ln if ev[0].startswith("serve.")), key=lambda ev: ev[1])
+
+
 @pytest.mark.parametrize("name", ENGINE_SPANS)
-def test_engine_span_is_recorded(engine_line, name):
-    assert any(ev[0] == name for ev in engine_line)
+def test_engine_span_is_recorded(serve_events, name):
+    assert any(ev[0] == name for ev in serve_events)
 
 
 @pytest.mark.parametrize("parent", sorted(hot.SERVE_SPAN_TREE))
@@ -111,7 +161,7 @@ def test_children_lie_inside_their_parent_and_tile_it(engine_line, parent):
     "name,attrs",
     [
         (hot.SERVE_DECODE, {"step", "active", "steps_overlapped", "tokens_discarded"}),
-        (hot.SERVE_ADMIT, {"rows", "width", "cached_tokens", "queue_depth"}),
+        (hot.SERVE_ADMIT, {"rows", "width", "cached_tokens", "tokens", "queue_depth", "rows_padded", "slots_stalled", "built"}),
         (hot.SERVE_DECODE_COMMIT, {"finished"}),
         (hot.SERVE_KV_IMPORT, {"blocks", "cache_len"}),
     ],
@@ -129,6 +179,102 @@ def test_span_attributes(engine_line, name, attrs):
         assert all(ev[3]["tokens_discarded"] == 0 for ev in events)  # no EOS, no pool pressure
     if name == hot.SERVE_ADMIT:
         assert {ev[3]["rows"] for ev in events} <= {1, 2} and all(ev[3]["width"] >= 16 for ev in events)
+
+
+@pytest.mark.parametrize(
+    "name,calls",
+    [
+        (hot.SERVE_DECODE_DISPATCH, {"_decode"}),
+        (hot.SERVE_PREFILL_DISPATCH, {"_prefill"}),
+        (hot.SERVE_KV_IMPORT, {"scatter"}),  # eager updates of the pools' leaves, as the copy is
+        (hot.SERVE_COW_COPY, {"scatter"}),
+    ],
+)
+def test_a_compiled_call_lies_inside_the_span_that_names_it(traced_drive, name, calls):
+    """What a reader links a program run by: the runtime's own ``PjitFunction(...)``
+    event of each compiled call, on the calling thread's line, inside the span
+    (rounds, steps, the block import, the forced copy-on-write)."""
+    found = 0
+    for line in traced_drive["lines"]:
+        made = [(ev[0][len("PjitFunction("):-1], ev[1]) for ev in line if ev[0].startswith("PjitFunction(")]
+        for span in (ev for ev in line if ev[0] == name):
+            inside = {fn for fn, t in made if span[1] <= t < span[2]}
+            assert inside & calls, (name, inside)
+            found += 1
+    assert found >= 1
+
+
+@pytest.mark.parametrize("count", sorted(RUNNING_COUNTS))
+def test_running_count_is_the_sum_of_its_span_attribute(traced_drive, serve_events, count):
+    """What ``stats()`` counts over a traced run is what the spans of that run add up to."""
+    grown = traced_drive["after"][count] - traced_drive["before"][count]
+    assert grown == RUNNING_COUNTS[count](serve_events) and (grown > 0 or count == "prefill_programs_built")
+
+
+def test_decode_tokens_are_counted_once_a_step_and_add_up_as_before(traced_drive):
+    """``_commit_step`` adds a step's tokens to ``SERVE_TOKENS`` in one call: over
+    ``_drive`` that is three requests' four decoded tokens each and the moved
+    request's three, as when it was once a slot."""
+    assert traced_drive["tokens"] == {"prefill": 4.0, "decode": 15.0}
+    assert traced_drive["after"]["tokens_out"] - traced_drive["before"]["tokens_out"] == 19
+
+
+# -- an admission round counted where it happens: a hand-built queue, the loop turned by hand ---------------
+
+
+@pytest.fixture(scope="module")
+def hand_driven(tiny, tmp_path_factory):
+    """Four slots, rounds of at most two rows, a clock the test sets: two long
+    requests take two slots; then three more arrive for the two that are free."""
+    cfg, params = tiny
+    now = [1.0]
+    engine = ServeEngine(params, cfg, max_slots=4, block_size=16, max_prefill_batch=2, clock=lambda: now[0])
+    ask = lambda n, new: engine.submit(ServeRequest(prompt=list(range(1, n + 1)), max_new_tokens=new))  # noqa: E731
+    out = {"engine": engine}
+
+    def body():
+        first = [ask(3, 2), ask(4, 50)]
+        assert engine._admit()
+        now[0] = 2.0
+        third = ask(5, 50)
+        now[0] = 2.5
+        late = [ask(6, 50), ask(7, 50)]
+        now[0] = 3.0
+        assert engine._admit() and not engine._admit()  # two rows into the two free slots; then none is free
+        while not first[0].done.is_set():
+            assert engine._decode_once()
+        now[0] = 5.0
+        assert engine._admit()
+        assert engine._preempt_youngest()  # the last one in: back to the head of the queue
+        now[0] = 7.0
+        assert engine._admit()
+        assert third.t_first == 3.0 and late[1].t_first == 5.0  # kept from the first admission
+
+    lines = _session(tmp_path_factory.mktemp("rounds"), body)
+    out["rounds"] = [ev[3] for ln in lines for ev in ln if ev[0] == hot.SERVE_ADMIT and "rows" in ev[3]]
+    return out
+
+
+@pytest.mark.parametrize(
+    "round_no,expected",
+    [
+        (0, {"rows": 2, "rows_padded": 2, "slots_stalled": 0, "built": 1, "tokens": 7, "queue_depth": 0}),
+        (1, {"rows": 2, "rows_padded": 2, "slots_stalled": 2, "built": 0, "tokens": 11, "queue_depth": 1}),
+        (2, {"rows": 1, "rows_padded": 1, "slots_stalled": 3, "built": 1, "tokens": 7, "queue_depth": 0}),
+        # the preempted request comes back alone, into the slot it left
+        (3, {"rows": 1, "rows_padded": 1, "slots_stalled": 3, "built": 0, "queue_depth": 0}),
+    ],
+)
+def test_round_attributes_against_a_hand_built_queue(hand_driven, round_no, expected):
+    got = hand_driven["rounds"][round_no]
+    assert {k: got[k] for k in expected} == expected and got["width"] == 16
+
+
+def test_stats_sum_the_rounds_of_a_hand_built_queue(hand_driven):
+    rounds, counts = hand_driven["rounds"], hand_driven["engine"].stats()
+    assert counts["prefill_rounds"] == 4 and counts["slot_steps_stalled"] == 0 + 2 + 3 + 3
+    assert counts["prefill_tokens"] == sum(r["tokens"] for r in rounds)
+    assert counts["prefill_padded_tokens"] == 16 * (2 + 2 + 1 + 1) and counts["prefill_programs_built"] == 2
 
 
 def test_without_a_session_the_engine_leaves_no_record(tiny, tmp_path, monkeypatch):
@@ -153,8 +299,13 @@ def test_without_a_session_the_engine_leaves_no_record(tiny, tmp_path, monkeypat
         for r in reqs:
             assert r.wait(timeout=120) and not r.error
         assert engine.steps >= 64
+        counts = engine.stats()
     finally:
         engine.stop()
+    # ... and the running counts count all the same
+    assert counts["prefill_rounds"] >= 4 and counts["prefill_tokens"] >= 3 * 3 + 19 + 2 * 3
+    assert counts["prefill_padded_tokens"] >= counts["prefill_tokens"] and counts["prefill_programs_built"] >= 2
+    assert counts["slot_steps_stalled"] >= 0
     assert made == []
     assert not obs_dir.exists() or not [f for _, _, fs in os.walk(obs_dir) for f in fs]
 

@@ -50,24 +50,41 @@ microsecond and write nothing. There is no switch of ours: the profiler's
 session is the switch.
 
 * host spans, engine thread, one tree per loop turn that did work:
-  ``serve.admit`` (``rows``, ``width``, ``cached_tokens``, ``queue_depth``)
-  over ``serve.admit.plan`` / ``serve.admit.build`` /
+  ``serve.admit`` over ``serve.admit.plan`` / ``serve.admit.build`` /
   ``serve.prefill.dispatch`` / ``serve.prefill.fetch`` /
   ``serve.admit.commit``; ``serve.decode`` (``step``, ``active``,
   ``steps_overlapped``, ``tokens_discarded``) over
   ``serve.decode.prepare`` / ``.dispatch`` / ``.fetch`` / ``.commit``
-  (``finished``); ``serve.idle``; ``serve.kv_import``. The engine keeps one
+  (``finished``); ``serve.idle``; ``serve.kv_import``; ``serve.cow_copy``
+  inside ``.prepare`` where a shared tail block is copied. The engine keeps one
   decode step in flight, so one ``serve.decode`` turn spans two steps:
   ``.prepare`` and ``.dispatch`` build and enqueue step N+1 (``active`` is
   how many slots it steps; 0 when there is nothing left to enqueue), then
   ``.fetch`` and ``.commit`` wait for and hand out the tokens of step N,
   which ran on the device meanwhile. A turn with nothing in flight (the first
   step after an idle spell) has no ``.fetch`` / ``.commit``.
+  **Which program a span enqueued is the runtime's to say**: it numbers its
+  program runs (``run_id`` on the device's ``XLA Modules`` events and on the
+  host's ``DoEnqueueProgram`` / ``CompleteCallbacks``) and records every
+  compiled call on the thread that made it, inside the span that made it, so
+  a reader ties each run to its turn and bounds the two clocks' offset from
+  the trace alone; the spans carry nothing for that.
+  **An admission round is counted where it happens**: ``serve.admit`` carries
+  ``rows``, ``rows_padded`` (the power of two its program was built for),
+  ``width``, ``cached_tokens``, ``tokens`` (prefilled), ``queue_depth``,
+  ``slots_stalled`` (slots that hold a request as the round is dispatched:
+  no token for them while its program runs), ``built`` (1: the round made its
+  ``(rows, width)`` program, so a compile or a cache load lies inside its
+  ``serve.prefill.dispatch``: a bucket the warm-up missed, named),
+  ``kv_bytes_per_token`` and the blocks held after it.
   ``steps_overlapped`` (steps enqueued while the one before was unfetched)
   and ``tokens_discarded`` (slot-steps dropped at commit: the step after an
   EOS, a slot preempted with its step in flight) are the engine's running
-  counts, also in ``ServeEngine.stats()``. They are per step,
-  not per request: the per-request spans (``serve.generate``,
+  counts, also in ``ServeEngine.stats()``; ``stats()`` alone keeps the sums
+  over all rounds (``prefill_rounds``, ``prefill_tokens``,
+  ``prefill_padded_tokens``, ``prefill_programs_built``,
+  ``slot_steps_stalled``), each added to once a round. They are per step and
+  per round, not per request: the per-request spans (``serve.generate``,
   ``serve.route``, ``serve.kv_transfer``) stay on the launcher plane;
 * host spans, training: the profiler's step marker ``train`` around each
   iteration of ``train()``, ``train.log`` (holding ``train.fence``),

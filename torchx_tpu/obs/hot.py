@@ -8,9 +8,15 @@ no profiler session runs and record nothing, and while one runs (the
 trainer's ``--profile-dir``, ``generate_server --profiler-port``, any
 ``jax.profiler.start_trace``) they land in the same ``.xplane.pb`` and on the
 same clock as the device's ``XLA Modules`` and ``XLA Ops`` lines, so a device
-idle gap can be named by the host phase that caused it. Operations inside a
-compiled program are named by ``jax.named_scope``: the scope path is each
-operation's ``tf_op`` in the trace. There is no switch, buffer or file here.
+idle gap can be named by the host phase that caused it. The runtime numbers
+its program runs itself (``run_id``, on the device's ``XLA Modules`` events and
+on the host's ``DoEnqueueProgram`` and ``CompleteCallbacks`` events) and records
+every compiled call on the thread that made it, so a reader ties each run to
+the span that holds its call and bounds the two clocks' offset from the trace
+alone (``benchmark/lib/program_runs.py``): the spans carry nothing for that.
+Operations inside a compiled program are named by ``jax.named_scope``: the
+scope path is each operation's ``tf_op`` in the trace. There is no switch,
+buffer or file here.
 
 Readers and tests import the names below, not strings.
 """
@@ -34,7 +40,15 @@ import jax
 
 # kv_blocks_full, kv_blocks_window: blocks the slots hold in the full and in the window pools (a decode
 # turn: as its step is dispatched; an admission round: after it); window_blocks_released: a running count
-SERVE_ADMIT = "serve.admit"  # rows, width, cached_tokens, tokens (prefilled), queue_depth, kv_bytes_per_token, kv_blocks_*
+#
+# serve.admit, a round counted where it happens: rows, rows_padded (the power of two the program was built
+# for), width, cached_tokens, tokens (prefilled: real suffix tokens of rows_padded x width), queue_depth
+# (after the round's requests left it), slots_stalled (slots that hold a request as the round is
+# dispatched: they get no token while its program runs), built (1: this round made its (rows, width)
+# program, whose compile or cache load lies inside this serve.prefill.dispatch), kv_bytes_per_token,
+# kv_blocks_*. ServeEngine.stats() keeps the running sums over all rounds: prefill_rounds, prefill_tokens,
+# prefill_padded_tokens (rows_padded x width), prefill_programs_built (built), slot_steps_stalled (slots_stalled)
+SERVE_ADMIT = "serve.admit"
 SERVE_ADMIT_PLAN = "serve.admit.plan"
 SERVE_ADMIT_BUILD = "serve.admit.build"
 SERVE_PREFILL_DISPATCH = "serve.prefill.dispatch"
@@ -47,6 +61,9 @@ SERVE_DECODE_FETCH = "serve.decode.fetch"
 SERVE_DECODE_COMMIT = "serve.decode.commit"  # finished
 SERVE_IDLE = "serve.idle"
 SERVE_KV_IMPORT = "serve.kv_import"  # blocks, cache_len
+# inside serve.decode.prepare, not one of its tiling children: a shared tail block copied before its slot
+# writes to it (eager updates of the pool's leaves: several program runs, named by this span)
+SERVE_COW_COPY = "serve.cow_copy"
 
 #: parent -> the children that tile it, in order
 SERVE_SPAN_TREE = {

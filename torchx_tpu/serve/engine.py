@@ -325,6 +325,12 @@ class ServeEngine:
         #: slot-steps whose token was dropped at commit: the step after an EOS,
         #: a slot preempted with its step in flight
         self.tokens_discarded = 0
+        # the admission rounds so far, each added to once a round
+        self.prefill_rounds = 0
+        self.prefill_tokens = 0  # suffix tokens prefilled
+        self.prefill_padded_tokens = 0  # rows x width of the programs that prefilled them
+        self.prefill_programs_built = 0  # (rows, width) programs made: each compiles or loads in its round
+        self.slot_steps_stalled = 0  # slots that held a request while a round's program ran, summed
         self._in_flight: Optional[_InFlight] = None
         #: why the loop died (a step raised), else None; a dead engine
         #: refuses work and fails the replica's health check
@@ -551,6 +557,11 @@ class ServeEngine:
                 "preemptions": self.preemptions,
                 "steps_overlapped": self.steps_overlapped,
                 "tokens_discarded": self.tokens_discarded,
+                "prefill_rounds": self.prefill_rounds,
+                "prefill_tokens": self.prefill_tokens,
+                "prefill_padded_tokens": self.prefill_padded_tokens,
+                "prefill_programs_built": self.prefill_programs_built,
+                "slot_steps_stalled": self.slot_steps_stalled,
                 "kv_bytes_per_token": self.kv_bytes_per_token,
                 "kv_bytes_per_slot_window": self.kv_bytes_per_slot_window,
                 "kv_blocks_window_used": self.window_alloc.used_blocks if self.window else 0,
@@ -739,14 +750,24 @@ class ServeEngine:
                     temps[r] = a.req.temperature
                     cached_total += a.cached_tokens
                     suffix_total += len(sfx)
+            built = (rows, width) not in self._prefill_fns
+            stalled = self.max_slots - len(free_slots)
             round_span.set_metadata(
                 rows=len(admitted),
+                rows_padded=rows,
                 width=width,
                 cached_tokens=cached_total,
                 tokens=suffix_total,
                 queue_depth=len(self._waiting),
+                slots_stalled=stalled,
+                built=int(built),
                 kv_bytes_per_token=self.kv_bytes_per_token,
             )
+            self.prefill_rounds += 1
+            self.prefill_tokens += suffix_total
+            self.prefill_padded_tokens += rows * width
+            self.prefill_programs_built += built
+            self.slot_steps_stalled += stalled
 
             with hot.span(hot.SERVE_PREFILL_DISPATCH):
                 fn = self._prefill_fn(rows, width)
@@ -955,8 +976,9 @@ class ServeEngine:
         """Device-side copy of one physical block across all layers (of the
         full kind: a block being written in a window pool is never a cached one,
         the cache adopts whole blocks only)."""
-        copied = jax.tree.map(lambda p: p.at[:, dst].set(p[:, src]), self._pools_of("full"))
-        self.pools = {**self.pools, "full": copied} if self.window else copied
+        with hot.span(hot.SERVE_COW_COPY):
+            copied = jax.tree.map(lambda p: p.at[:, dst].set(p[:, src]), self._pools_of("full"))
+            self.pools = {**self.pools, "full": copied} if self.window else copied
 
     def _ensure_capacity(self, slot: int, write_pos: int) -> bool:
         """Make sure ``slot`` holds a *writable* block for ``write_pos``:
@@ -1095,12 +1117,13 @@ class ServeEngine:
         for it lies in a block the slot owned unshared at dispatch, past every
         token the prefix cache indexes, and whatever reuses the block is
         enqueued after the step."""
-        finished = 0
+        finished = kept = 0
         now = self._clock()
         for slot, st in stepping:
             if self._slots[slot] is not st:
                 self.tokens_discarded += 1
                 continue
+            kept += 1
             st.unfetched -= 1
             st.cache_len += 1
             self.tables.lengths[slot] = st.cache_len
@@ -1108,7 +1131,6 @@ class ServeEngine:
             st.last_tok = tok
             st.req.generated.append(tok)
             self.tokens_out += 1
-            obs_metrics.SERVE_TOKENS.inc(phase="decode")
             if self._finished(st.req, tok):
                 finished += 1
                 self._slots[slot] = None
@@ -1124,6 +1146,8 @@ class ServeEngine:
                 if self.window:
                     self.window_alloc.release(self.window_tables.release(slot))
                 self._complete(st.req, now)
+        if kept:
+            obs_metrics.SERVE_TOKENS.inc(kept, phase="decode")
         self._update_gauges()
         return finished
 
