@@ -178,14 +178,33 @@ def _attention_keys(c: dict, seq: float) -> float:
     return m["n_full"] * seq / 2 + m["n_sliding"] * sliding
 
 
+def _active_matmul_params(c: dict, head: bool = True) -> float:
+    """Matmul weights one token multiplies on this chip: of its ``k`` routings
+    the ``held / E`` expected to land here, the shared expert, everything
+    outside the experts, with ``head`` the head's slice; no norm, no bias."""
+    m = _dims(c)
+    active = m["k"] * m["held"] / m["E"]
+    return (_stack_params(c, active) - m["L"] * _norms(c) - (m["L"] - m["nd"]) * m["E"]
+            + (m["d"] * m["v"] if head else 0))  # fmt: skip
+
+
 def train_flops_per_token(c: dict, seq: int) -> float:
     """6 per active matmul weight of this chip's share (of a token's ``k``
     routings the ``held / E`` that land here, the shared expert, the head's
     slice), plus causal and windowed attention, forward and twice that backward."""
     m = _dims(c)
-    active = m["k"] * m["held"] / m["E"]
-    matmul = _stack_params(c, active) - m["L"] * _norms(c) - (m["L"] - m["nd"]) * m["E"] + m["d"] * m["v"]
-    return 6.0 * matmul + 3.0 * 2 * 2 * m["h"] * m["hd"] * _attention_keys(c, seq)
+    return 6.0 * _active_matmul_params(c) + 3.0 * 2 * 2 * m["h"] * m["hd"] * _attention_keys(c, seq)
+
+
+def forward_flops_per_token(c: dict, keys: float, head: bool = True) -> float:
+    """The forward FLOPs this chip spends on a token that has ``keys``
+    positions to attend: all of them on a full layer, at most the window on a
+    sliding one. The experts a token's routings reach **here** count (``k held
+    / E`` = 1 expected), not the ``k`` it picks over the eight chips: the other
+    seven's work is done on no chip of this cell."""
+    m = _dims(c)
+    attended = m["n_full"] * keys + m["n_sliding"] * min(m["window"], keys)
+    return 2.0 * _active_matmul_params(c, head) + 2 * 2 * m["h"] * m["hd"] * attended
 
 
 def kv_bytes_per_token(c: dict, dtype_bytes: int = 2) -> int:

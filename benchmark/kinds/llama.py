@@ -71,6 +71,10 @@ def train_flops_per_token(c: dict, seq: int) -> float:
     return counts.decoder_train_flops_per_token(c, seq, layer_matmul_params(c))
 
 
+def forward_flops_per_token(c: dict, keys: float, head: bool = True) -> float:
+    return counts.decoder_forward_flops_per_token(c, keys, layer_matmul_params(c), head)
+
+
 kv_bytes_per_token = counts.gqa_kv_bytes_per_token
 
 

@@ -148,15 +148,31 @@ def param_count(c: dict) -> int:
     return int(_stack_params(c, m["E"])) + m["v"] * m["d"] + m["d"] + head
 
 
-def train_flops_per_token(c: dict, seq: int) -> float:
-    """6 per active matmul weight (the chosen experts, the shared one, the
-    head), plus causal attention over ``seq/2`` keys of ``nope + rope`` and as
-    many values of ``v``, forward and twice that backward."""
+def _active_matmul_params(c: dict, head: bool = True) -> float:
+    """Matmul weights one token multiplies: the chosen experts, the shared one,
+    everything outside the experts, with ``head`` the head; no norm, no bias."""
     m = _dims(c)
     norms = m["L"] * _norms(c)
-    matmul = _stack_params(c, m["k"]) - norms - (m["L"] - m["nd"]) * m["E"] + m["d"] * m["v"]
-    attention_fwd = m["L"] * 2 * m["h"] * (m["dn"] + m["dr"] + m["dv"]) * (seq / 2)
-    return 6.0 * matmul + 3.0 * attention_fwd
+    return _stack_params(c, m["k"]) - norms - (m["L"] - m["nd"]) * m["E"] + (m["d"] * m["v"] if head else 0)
+
+
+def _attention_flops_per_key(c: dict) -> float:
+    """Scores over ``nope + rope`` and values over ``v``, every head of every layer, 2 a multiply-add."""
+    m = _dims(c)
+    return m["L"] * 2 * m["h"] * (m["dn"] + m["dr"] + m["dv"])
+
+
+def forward_flops_per_token(c: dict, keys: float, head: bool = True) -> float:
+    """2 per active matmul weight (the chosen experts, the shared one, with
+    ``head`` the head), plus scores over ``keys`` keys of ``nope + rope`` and as
+    many values of ``v``: the published (expanded) form; the program's absorbed
+    decode multiplies more a key and reads less."""
+    return 2.0 * _active_matmul_params(c, head) + _attention_flops_per_key(c) * keys
+
+
+def train_flops_per_token(c: dict, seq: int) -> float:
+    """Forward and twice that backward, causal attention over ``seq/2`` keys."""
+    return 3.0 * forward_flops_per_token(c, seq / 2)
 
 
 def kv_bytes_per_token(c: dict, dtype_bytes: int = 2) -> int:

@@ -170,14 +170,26 @@ def param_count(c: dict) -> int:
     return int(_stack_params(c, m["E"])) + m["v"] * m["d"] + m["d"] + head
 
 
-def train_flops_per_token(c: dict, seq: int) -> float:
-    """Kind ``mla_moe``'s count with the query's two matmuls in ``W_q``'s place
-    and each sublayer's ``phi``; the mixes themselves are ``4 n^2 d`` a layer."""
+def _more_than_mla_moe(c: dict) -> tuple[float, float]:
+    """-> (matmul weights a token multiplies beyond kind ``mla_moe``'s, all
+    layers: the query's two matmuls in ``W_q``'s place and each sublayer's
+    ``phi``; forward FLOPs of the mixes themselves, ``4 n^2 d`` a layer)."""
     m = _dims(c)
     q_width = m["h"] * (m["dn"] + m["dr"])
     more = m["d"] * m["rq"] + m["rq"] * q_width - m["d"] * q_width + 2 * m["n"] * m["d"] * m["hc_width"]
     mixes = 2 * 2.0 * (m["n"] * m["n"] + 2 * m["n"]) * m["d"]
-    return _mla_moe.train_flops_per_token(c, seq) + 6.0 * m["L"] * more + 3.0 * m["L"] * mixes
+    return m["L"] * more, m["L"] * mixes
+
+
+def forward_flops_per_token(c: dict, keys: float, head: bool = True) -> float:
+    """Kind ``mla_moe``'s count with :func:`_more_than_mla_moe`'s beside it."""
+    more, mixes = _more_than_mla_moe(c)
+    return _mla_moe.forward_flops_per_token(c, keys, head) + 2.0 * more + mixes
+
+
+def train_flops_per_token(c: dict, seq: int) -> float:
+    """Forward and twice that backward, causal attention over ``seq/2`` keys."""
+    return 3.0 * forward_flops_per_token(c, seq / 2)
 
 
 def hc_bytes(c: dict, rows: float, dtype_bytes: int = 2) -> float:

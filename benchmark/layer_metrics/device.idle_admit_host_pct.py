@@ -1,6 +1,6 @@
 """Device idle time, as a share of the traced window, that the engine thread's time inside
 ``serve.admit`` and outside ``serve.prefill.fetch`` accounts for, gap by gap: planning,
-building, dispatching or committing an admission round (``lib/host_spans.py``)."""
+building, dispatching or committing an admission round (``lib/program_runs.py::idle_by_class``)."""
 
 NAME = "device.idle_admit_host_pct"
 UNIT = "%"
@@ -10,7 +10,6 @@ SOURCE = "program_span"
 
 
 def read(run: dict):
-    from benchmark.lib import host_spans
+    from benchmark.lib import program_runs
 
-    r = host_spans.of_run(run)
-    return host_spans.idle_pct(r, "admit_host") if r else None
+    return program_runs.idle_split_pct(run, "admit_host")

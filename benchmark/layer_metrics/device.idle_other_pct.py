@@ -9,7 +9,6 @@ SOURCE = "program_span"
 
 
 def read(run: dict):
-    from benchmark.lib import host_spans
+    from benchmark.lib import program_runs
 
-    r = host_spans.of_run(run)
-    return host_spans.idle_pct(r, "other") if r else None
+    return program_runs.idle_split_pct(run, "other")

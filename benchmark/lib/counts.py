@@ -27,6 +27,15 @@ def train_flops_per_token(c: dict, seq: int) -> float:
     return kinds.of(c).train_flops_per_token(c, seq)
 
 
+def forward_flops_per_token(c: dict, keys: float, head: bool = True) -> float:
+    """Forward FLOPs of one token whose query attends ``keys`` positions: 2 per
+    active matmul weight, scores and values over ``keys``; ``head`` False leaves
+    the output head out (a prompt's positions but the last need none). A third
+    of :func:`train_flops_per_token` at a sequence of ``2 x keys``, where every
+    layer attends all that came before."""
+    return kinds.of(c).forward_flops_per_token(c, keys, head)
+
+
 def kv_bytes_per_token(c: dict, dtype_bytes: int = 2) -> int:
     """Bytes of attention state one cached token holds, over all layers."""
     return kinds.of(c).kv_bytes_per_token(c, dtype_bytes)
@@ -66,13 +75,18 @@ def decoder_param_count(c: dict, layer_matmul_params: int) -> int:
     return total
 
 
-def decoder_train_flops_per_token(c: dict, seq: int, active_layer_matmul_params: int) -> float:
-    """6 per active matmul weight (layers and head), plus causal attention,
-    whose scores and values cost ``2 * 2 * d * seq/2`` forward."""
+def decoder_forward_flops_per_token(c: dict, keys: float, active_layer_matmul_params: int, head: bool = True) -> float:
+    """2 per active matmul weight (layers and, with ``head``, the head), plus
+    scores and values over ``keys`` positions, ``2 * 2 * d`` each."""
     d, h, _, hd, _, L, v = gqa_dims(c)
-    matmul = L * active_layer_matmul_params + d * v
-    attention_fwd = L * 2 * 2 * (h * hd) * (seq / 2)
-    return 6.0 * matmul + 3.0 * attention_fwd
+    matmul = L * active_layer_matmul_params + (d * v if head else 0)
+    return 2.0 * matmul + L * 2 * 2 * (h * hd) * keys
+
+
+def decoder_train_flops_per_token(c: dict, seq: int, active_layer_matmul_params: int) -> float:
+    """Forward and twice that backward: 6 per active matmul weight (layers and
+    head), plus causal attention, whose query sees ``seq/2`` keys on average."""
+    return 3.0 * decoder_forward_flops_per_token(c, seq / 2, active_layer_matmul_params)
 
 
 def gqa_kv_bytes_per_token(c: dict, dtype_bytes: int = 2) -> int:

@@ -26,11 +26,16 @@ def require_chips(chips: int, allow_cpu: bool = False) -> dict:
 
 def memory_peak_bytes(chips: int) -> int:
     """Peak bytes in use on the fullest chip, 0 where the backend reports none."""
-    peaks = []
-    for d in jax.devices()[:chips]:
-        stats = d.memory_stats() or {}
-        peaks.append(int(stats.get("peak_bytes_in_use", 0)))
-    return max(peaks) if peaks else 0
+    return memory_stats(chips)["peak_bytes_in_use"]
+
+
+def memory_stats(chips: int = 1) -> dict:
+    """``bytes_in_use``, ``peak_bytes_in_use`` and ``bytes_limit`` of the chip
+    whose peak is highest; each 0 where the backend reports none."""
+    keys = ("bytes_in_use", "peak_bytes_in_use", "bytes_limit")
+    all_stats = [d.memory_stats() or {} for d in jax.devices()[:chips]]
+    fullest = max(all_stats, key=lambda st: st.get("peak_bytes_in_use", 0), default={})
+    return {k: int(fullest.get(k, 0)) for k in keys}
 
 
 class CompileCounter:

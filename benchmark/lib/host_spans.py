@@ -1,36 +1,18 @@
-"""The serving engine's spans and the device's idle time, from one trace.
+"""The serving engine's spans, from one trace.
 
 The program's hot-path spans (``torchx_tpu/obs/hot.py``) are
 ``jax.profiler.TraceAnnotation`` events on the engine thread's line of the
 ``/host:CPU`` plane, in the same ``.xplane.pb`` as the device's ``XLA Ops``.
-This module reads that line into a span tree, and splits the device's idle
-time (the gaps of ``lib/trace.py``'s busy union, over the same window) by what
-the engine thread did between the two programs on either side of each gap:
-
-* ``decode_host``: inside ``serve.decode`` but not waiting in
-  ``serve.decode.fetch`` (commit of the step before, prepare and dispatch of
-  the step after, the steps' self time),
-* ``admit_host``: inside ``serve.admit`` but not waiting in
-  ``serve.prefill.fetch`` (plan, build, dispatch, commit),
-* ``other``: the rest: the wait of a fetch after the device finished, the way
-  from a dispatch to the device's first operation, ``serve.idle``, time under
-  no span, bubbles inside a program.
-
-The engine is one thread, so between the fetch that returned program A's
-result and the dispatch that enqueued program B everything it did lies inside
-the device's gap between A and B; a gap shorter than that host work (the
-device starts before the dispatch call returns) is shared out in proportion.
-Only durations are taken from each clock and the clocks are used together
-only to find which span ran which program, because the profiler's alignment
-of the device's clock with the host's moved by 1.3 ms between two captures of
-one process (PERF.md, PR 24), half of a gap. The three add up to the window's
-idle time exactly. A program built before the spans existed has none of
+This module reads that line into a span tree and answers what the tree alone
+can say (a turn's host time, a round's, the decode period). What ties a span
+to the device's programs, the split of the device's idle time among what the
+thread did between two of them included, is ``lib/program_runs.py``'s, which
+builds on this tree. A program built before the spans existed has none of
 them: every function here then returns None.
 """
 
 from __future__ import annotations
 
-import bisect
 import dataclasses
 import functools
 import os
@@ -38,7 +20,6 @@ import statistics
 from typing import Iterable, Optional
 
 from . import scopes
-from . import trace as trace_lib
 
 HOST_PLANE = "/host:CPU"
 Interval = tuple[float, float]
@@ -81,62 +62,25 @@ def build_tree(events: Iterable[tuple[str, float, float, dict]]) -> list[Span]:
 @dataclasses.dataclass
 class Reading:
     spans: list[Span]  # the engine thread's top-level spans, in time order
-    window: Interval  # first to last device operation, as lib/trace.py has it
-    idle_by_class: dict[str, float]  # decode_host, admit_host, other: seconds
 
     def named(self, name: str) -> list[Span]:
         return [s for s in self.spans if s.name == name]
 
 
 def read(path: str) -> Optional[Reading]:
-    """The engine thread's spans and the device's idle time from one trace
-    file; None where the file has no engine span or no device work."""
+    """The engine thread's spans from one trace file; None where the file has
+    no engine span or no device work."""
     return _read(path, os.path.getmtime(path))
 
 
 @functools.lru_cache(maxsize=2)
 def _read(path: str, _mtime: float) -> Optional[Reading]:
-    hot = scopes.names()
-    if hot is None:
+    if scopes.names() is None:
         return None
     spans = engine_spans(path)
-    planes = [p for p in scopes.read_planes(path) if p["ops"] or p["modules"]]
-    if not spans or not planes:
+    if not spans or not any(p["ops"] or p["modules"] for p in scopes.read_planes(path)):
         return None
-    # the engine is one thread driving one chip; on a sharded engine every
-    # chip runs the same programs and the first stands for all
-    ops = planes[0]["ops"] or planes[0]["modules"]
-    _, busy = trace_lib._union((s, e) for _, s, e, _ in ops)
-    window = (busy[0][0], busy[-1][1])
-    idle = [(a_end, b_start) for (_, a_end), (b_start, _) in zip(busy, busy[1:]) if b_start > a_end]
-    host = {
-        "decode_host": _host_intervals(spans, hot.SERVE_DECODE, hot.SERVE_DECODE_FETCH),
-        "admit_host": _host_intervals(spans, hot.SERVE_ADMIT, hot.SERVE_PREFILL_FETCH),
-    }
-    by_class = dict.fromkeys(host, 0.0)
-    fetch = (hot.SERVE_DECODE_FETCH, hot.SERVE_PREFILL_FETCH)
-    dispatch = (hot.SERVE_DECODE_DISPATCH, hot.SERVE_PREFILL_DISPATCH)
-    runs = sorted((s, e) for _, s, e, _ in planes[0]["modules"])
-    starts = [sp.start for sp in spans]
-    owners = [_owner(spans, starts, (s + e) / 2) for s, e in runs]
-    for (_, a_end), (b_start, _), a, b in zip(runs, runs[1:], owners, owners[1:]):
-        gap = overlap(idle, [(a_end, b_start)])
-        after, until = _child(a, fetch), _child(b, dispatch)
-        if gap <= 0.0 or after is None or until is None:
-            continue
-        work = {cls: overlap([(after.end, until.end)], iv) for cls, iv in host.items()}
-        total = sum(work.values())
-        for cls in work:
-            by_class[cls] += work[cls] * min(1.0, gap / total) if total > 0 else 0.0
-    by_class["other"] = sum(e - s for s, e in idle) - sum(by_class.values())
-    return Reading(spans, window, by_class)
-
-
-def _owner(spans: list[Span], starts: list[float], t: float) -> Optional[Span]:
-    """The top-level span that holds the instant ``t``: a program's midpoint
-    lies in the step or round that ran it whatever the clocks' offset."""
-    i = bisect.bisect_right(starts, t) - 1
-    return spans[i] if i >= 0 and t < spans[i].end else None
+    return Reading(spans)
 
 
 def _child(span: Optional[Span], names: tuple[str, ...]) -> Optional[Span]:
@@ -162,22 +106,6 @@ def engine_spans(path: str) -> list[Span]:
             if any(name in ours for name, *_ in events):
                 return build_tree(events)
     return []
-
-
-def _host_intervals(spans: list[Span], parent: str, waiting: str) -> list[Interval]:
-    """Where the thread was inside a ``parent`` span and not in its
-    ``waiting`` child: sorted, disjoint."""
-    out = []
-    for sp in spans:
-        if sp.name != parent:
-            continue
-        t = sp.start
-        for c in sp.children:
-            if c.name == waiting:
-                out.append((t, c.start))
-                t = c.end
-        out.append((t, sp.end))
-    return [(s, e) for s, e in out if e > s]
 
 
 def overlap(a: list[Interval], b: list[Interval]) -> float:
@@ -224,14 +152,6 @@ def admit_host_ms(r: Reading) -> Optional[float]:
     return sum(rounds) / len(rounds) if rounds else None
 
 
-def prefill_stall_pct(r: Reading) -> float:
-    """Share of the window the engine thread spent inside ``serve.admit``:
-    no running request gets a token then."""
-    hot = scopes.names()
-    inside = overlap([r.window], [(s.start, s.end) for s in r.named(hot.SERVE_ADMIT)])
-    return 100.0 * inside / (r.window[1] - r.window[0])
-
-
 def traced_step_ms(r: Reading) -> Optional[float]:
     """Median start-to-start period of two decode steps with nothing between
     them on the engine thread (no admission, no idle wait)."""
@@ -242,10 +162,6 @@ def traced_step_ms(r: Reading) -> Optional[float]:
         if a.name == hot.SERVE_DECODE and b.name == hot.SERVE_DECODE
     ]
     return statistics.median(periods) if periods else None
-
-
-def idle_pct(r: Reading, cls: str) -> float:
-    return 100.0 * r.idle_by_class[cls] / (r.window[1] - r.window[0])
 
 
 def coverage(r: Reading, parent: str) -> Optional[float]:

@@ -23,7 +23,7 @@ from .spec import BENCH_DIR
 # what every adapter provides, under these names
 ADAPTER = (
     "REFERENCE", "program_config", "weight_shapes", "param_count", "train_flops_per_token",
-    "kv_bytes_per_token", "decode_step_bytes", "aux_must_be_zero",
+    "forward_flops_per_token", "kv_bytes_per_token", "decode_step_bytes", "aux_must_be_zero",
 )
 
 
@@ -62,5 +62,7 @@ def of(config: dict) -> ModuleType:
 
 
 def reference(config: dict) -> ModuleType:
-    """The kind's plain reference: ``logits`` and ``mean_nll``."""
+    """The kind's plain reference: ``stream`` and ``head`` (a serving cell's
+    check gives the head the served positions alone), ``logits`` (the head over
+    the whole stream) and ``mean_nll`` (a training cell)."""
     return _find("reference", of(config).REFERENCE, config.get("bench_dir"))

@@ -30,6 +30,9 @@ carry nothing for the link. So, from one ``.xplane.pb``:
   instant to the next run's first (device clock alone), shared out by overlap
   among the engine thread's spans.
 * **per fetch**: ``busy`` (before its run's last instant) and ``return`` (after).
+* **the idle split**: each gap between two runs goes to the turn that enqueued
+  the second, shared among ``decode_host``, ``admit_host`` and the rest by what
+  the engine thread did while it could have enqueued it (``idle_by_class``).
 
 Where the runtime's events are absent (another jaxlib, a CPU trace), or a
 program built before the spans existed, every function returns None.
@@ -37,6 +40,7 @@ program built before the spans existed, every function returns None.
 
 from __future__ import annotations
 
+import bisect
 import dataclasses
 import functools
 import os
@@ -46,7 +50,7 @@ from typing import Optional
 
 from . import host_spans, scopes
 from .host_spans import Interval, Span
-from .trace import MODULE_LINE, module_name
+from .trace import MODULE_LINE, _union, module_name
 
 ENQUEUE_EVENT = "DoEnqueueProgram"
 COMPLETE_EVENT = "CompleteCallbacks"
@@ -198,7 +202,9 @@ def build(modules: list, lines: list[list], enqueues: list, completes: list) -> 
     ordered = [by_id[i] for i in sorted(by_id)]
     runs, edges = [r for r in ordered if r.whole], [r for r in ordered if not r.whole]
 
-    first_call = _link_calls(runs, lines)
+    # every run that has its enqueue takes its call, an edge too: one that lacked a side mid-trace (a completion the
+    # profiler dropped in a stall of the host) would leave its call to the next run, and every later link one turn off
+    first_call = _link_calls([r for r in ordered if r.enqueue is not None], lines)
     unlinked: dict[str, int] = {}
     for r in runs:
         if r.call is None and r.enqueue < first_call:
@@ -245,7 +251,7 @@ def _link_calls(runs: list[Run], lines: list[list]) -> float:
             start, module, span = calls[i]
             if start > run.enqueue:
                 break
-            if not taken[i] and module == run.module:
+            if not taken[i] and module == (run.module or module):  # no device event, no name: the next call is its
                 taken[i], run.call, run.span = True, start, span
                 break
     return calls[0][0] if calls else float("inf")
@@ -302,6 +308,116 @@ def _fetches(reading: Reading, hot) -> list[Fetch]:  # noqa: ANN001
     return out
 
 
+# -- the device's idle time, shared out among what the engine thread did ---------
+
+
+def host_classes(spans: list[Span], hot) -> dict[str, list[Interval]]:  # noqa: ANN001
+    """Where the engine thread worked and did not wait for the device:
+    ``decode_host`` inside ``serve.decode`` and outside its ``.fetch`` (commit
+    of the step before, prepare and dispatch of the step after, the step's
+    self time), ``admit_host`` inside ``serve.admit`` and outside
+    ``serve.prefill.fetch`` (plan, build, dispatch, commit). Sorted, disjoint."""
+
+    def outside(parent: str, waiting: str) -> list[Interval]:
+        out = []
+        for sp in spans:
+            if sp.name != parent:
+                continue
+            t = sp.start
+            for c in sp.children:
+                if c.name == waiting:
+                    out.append((t, c.start))
+                    t = c.end
+            out.append((t, sp.end))
+        return [(s, e) for s, e in out if e > s]
+
+    return {"decode_host": outside(hot.SERVE_DECODE, hot.SERVE_DECODE_FETCH),
+            "admit_host": outside(hot.SERVE_ADMIT, hot.SERVE_PREFILL_FETCH)}
+
+
+def idle_by_class(r: Reading, idle: list[Interval], host: dict[str, list[Interval]]) -> dict[str, float]:
+    """Each of the device's ``idle`` gaps between two consecutive runs A and B
+    goes to the turn that enqueued B (found by ``run_id``, never by where an
+    instant falls on the other clock), shared out among the classes of ``host``
+    (class -> the host intervals that count for it) by what the engine thread
+    did of each from the last result it had in hand before that enqueue (the
+    end of the latest ``.fetch`` span that precedes the span that enqueued B)
+    to the end of that span: the stretch in which it could have enqueued B and
+    had not yet. With a step in flight that fetch is the one before A's (B is
+    enqueued while A runs), so a thread late to enqueue B, the chip done with A
+    and waiting, reads as the class it was late in; behind a round it is the
+    round's own fetch. A gap shorter than that host work (the usual case: A ran
+    through most of it; and every microsecond gap between two programs both
+    already enqueued) is shared in proportion; of a longer one the rest is no
+    class's (a result on its way back, ``serve.idle``, time under no span).
+    A run that lacks its link gives its gap to no class. Durations alone are
+    taken from each clock. -> class -> seconds"""
+    hot = scopes.names()
+    fetch_ends = sorted(c.end for top in r.spans for c in top.children
+                        if c.name in (hot.SERVE_DECODE_FETCH, hot.SERVE_PREFILL_FETCH))
+    on_device = sorted({x.run_id: x for x in r.runs + r.edges if x.start is not None}.values(), key=lambda x: x.start)
+    by_class = dict.fromkeys(host, 0.0)
+    for a, b in zip(on_device, on_device[1:]):
+        gap = host_spans.overlap(idle, [(a.end, b.start)])
+        i = bisect.bisect_right(fetch_ends, b.span.start) - 1 if b.span is not None else -1
+        if gap <= 0.0 or i < 0:
+            continue
+        work = {cls: host_spans.overlap([(fetch_ends[i], b.span.end)], iv) for cls, iv in host.items()}
+        total = sum(work.values())
+        for cls in work:
+            by_class[cls] += work[cls] * min(1.0, gap / total) if total > 0 else 0.0
+    return by_class
+
+
+@dataclasses.dataclass
+class IdleSplit:
+    by_class: dict[str, float]  # decode_host, admit_host: seconds of the device's idle time
+    idle_s: float  # all of it: the gaps of the first device's busy union, this module's own reading
+    window_s: float  # first to last device operation
+
+
+def idle_split(path: str) -> Optional[IdleSplit]:
+    """The first device's idle time in one trace file (the engine is one thread
+    driving one chip; on a sharded engine every chip runs the same programs and
+    the first stands for all) and the host classes' parts of it; None where
+    the file has no engine span, no device work or none of the runtime's events."""
+    return _idle_split(path, os.path.getmtime(path))
+
+
+@functools.lru_cache(maxsize=2)
+def _idle_split(path: str, _mtime: float) -> Optional[IdleSplit]:
+    r = read(path)
+    planes = [p for p in scopes.read_planes(path) if p["ops"] or p["modules"]]
+    if r is None or not planes:
+        return None
+    _, busy = _union((s, e) for _, s, e, _ in planes[0]["ops"] or planes[0]["modules"])
+    idle = [(a_end, b_start) for (_, a_end), (b_start, _) in zip(busy, busy[1:]) if b_start > a_end]
+    by_class = idle_by_class(r, idle, host_classes(r.spans, scopes.names()))
+    return IdleSplit(by_class, sum(e - s for s, e in idle), busy[-1][1] - busy[0][0])
+
+
+def idle_split_pct(run: dict, cls: str) -> Optional[float]:
+    """Class ``cls`` of a traced serving run's idle time as a share of the
+    traced window: a host class's seconds over ``device.idle_pct.serve``'s own
+    window (``run["trace"]``), ``other`` the rest of that idle share, so that
+    the three add up to it to the last digit whichever reader's instants each
+    was made from (``lib/trace.py`` reads whole nanoseconds, ``lib/scopes.py``
+    picoseconds: half a nanosecond an operation, 3 to 100 us of a 4 s trace,
+    0.003 points at most on the chip runs made). What holds the split itself
+    is in the tests: the module's own idle total is ``lib/trace.py``'s to that
+    rounding, and a class exceeds neither the gaps between programs nor what
+    the thread worked in it."""
+    tr = run.get("trace")
+    if not tr or run["cell"].kind != "serve" or scopes.names() is None:
+        return None
+    path = scopes.trace_file(run)
+    split = idle_split(path) if path else None
+    if split is None:
+        return None
+    host = {c: 100.0 * s / tr["window_s"] for c, s in split.by_class.items()}
+    return host[cls] if cls in host else 100.0 * (1.0 - tr["busy_s"] / tr["window_s"]) - sum(host.values())
+
+
 # -- what the readers under layer_metrics/ return --------------------------------
 
 
@@ -344,28 +460,6 @@ def fetch_return_ms(r: Reading, name: str, part: str = "back") -> Optional[float
     return statistics.median(parts) * 1e3 if parts else None
 
 
-def turn_of(r: Reading, t: float) -> Optional[Span]:
-    """The engine thread's top-level span that holds the host instant ``t``."""
-    return next((s for s in r.spans if s.start <= t < s.end), None)
-
-
-def midpoint_rule_misplaces(r: Reading) -> dict[str, int]:
-    """How many runs ``lib/host_spans.py``'s rule (a program belongs to the
-    top-level span that holds its midpoint, on the profiler's alignment of the
-    clocks) gives to another turn than the link by ``run_id`` does: than the
-    turn that enqueued the run (since the engine keeps a step in flight that is
-    every step enqueued behind another, by construction), and than the turn that
-    fetched it (what the rule is used for: the one it should never miss)."""
-    starts = [s.start for s in r.spans]
-    held = lambda x: host_spans._owner(r.spans, starts, (x.start + x.end) / 2)  # noqa: E731
-    return {
-        "enqueued_elsewhere": sum(held(x) is not turn_of(r, x.call) for x in r.linked()),
-        "of_linked": len(r.linked()),
-        "fetched_elsewhere": sum(held(f.run) is not turn_of(r, f.span.start) for f in r.fetches),
-        "of_fetched": len(r.fetches),
-    }
-
-
 def account(r: Reading) -> dict:
     """One traced run in the numbers PERF.md records of it."""
     out = {
@@ -381,7 +475,6 @@ def account(r: Reading) -> dict:
     if r.problem is None:
         fetches = (scopes.names().SERVE_DECODE_FETCH, scopes.names().SERVE_PREFILL_FETCH)
         out.update({
-            "midpoint_rule_misplaces": midpoint_rule_misplaces(r),
             "rounds": len(r.rounds),
             "prefill_queued_ms": prefill_queued_ms(r),
             "idle_after_prefill_s": idle_after_prefill_s(r),

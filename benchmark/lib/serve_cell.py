@@ -18,7 +18,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from . import check, device, kinds, models, peaks, stats, traffic as traffic_lib
+from . import check, counts, device, kinds, models, peaks, stats, traffic as traffic_lib
 from . import trace as trace_lib
 from .spec import Cell, scratch_dir
 
@@ -144,6 +144,15 @@ def run(
     run_rec["trace"] = trace_lib.reduce_trace(profile_dir, cell.chips) if trace else None
     if dev["platform"] == "tpu":
         run_rec["counters"]["peak_hbm_bytes_per_s"] = peaks.peak(dev["kind"], "hbm_bytes_per_s")
+        c = run_rec["counters"]
+        c["serve_mfu_pct"] = 100.0 * c["forward_flops"] / (
+            window_s * cell.chips * peaks.peak(dev["kind"], "bf16_flops_per_s"))
+        # the one per-layer metric an untraced run can read: printed, since its result line has no place for it
+        print(f"serve: model.serve_mfu_pct {c['serve_mfu_pct']:.4f}"
+              f" = {c['forward_flops']:.6g} forward FLOPs ({c['tokens']} tokens out at a mean context of"
+              f" {c['decode_context_mean']:.1f}, {c['prefilled_tokens']} prompt tokens prefilled over"
+              f" {c['prefill_keys_mean']:.1f} keys; the engine's rounds counted {c['engine_prefill_tokens']})"
+              f" in {window_s:.3f}s", flush=True)
 
     verdict = check.Verdict()
     if compiled_in_window:
@@ -218,6 +227,13 @@ def _metrics(cell, dev, snap, polls, s_open, s_close, t_open, t_close, window_s,
     pc_open, pc_close = s_open.get("prefix_cache") or {}, s_close.get("prefix_cache") or {}
     traced_polls = [p for p in polls if p["tracing"]] or polls
     done_in_window = sum(1 for s in snap if s["done"] and not s["error"] and t_open <= s["t_done"] <= t_close)
+    held, active = sum(p["tokens_held"] for p in polls), sum(p["active"] for p in polls)
+    admitted = sorted((s for s in snap if s["t_first"]), key=lambda s: s["t_first"])
+    cached = cached_prefix_lens([s["prompt"] for s in admitted], int(cell.config["deployment"]["block_size"]))
+    forward = forward_flops(
+        cell.config, tokens, held / active if active else 0.0,
+        [(len(s["prompt"]), c) for s, c in zip(admitted, cached) if t_open <= s["t_first"] <= t_close])
+    forward["engine_prefill_tokens"] = s_close.get("prefill_tokens", 0) - s_open.get("prefill_tokens", 0)
     print(
         f"serve: {len(due)} requests due, {failed} failed, {done_in_window} finished in the window;"
         f" samples ttft {len(ttft)} tpot {len(tpot)}; {tokens} tokens, {steps} steps in {window_s:.3f}s;"
@@ -240,6 +256,7 @@ def _metrics(cell, dev, snap, polls, s_open, s_close, t_open, t_close, window_s,
             "queue_depth_mean": sum(p["queue_depth"] for p in polls) / len(polls),
             "prefix_hit_tokens": pc_close.get("hit_tokens", 0) - pc_open.get("hit_tokens", 0),
             "prefix_lookup_tokens": pc_close.get("lookup_tokens", 0) - pc_open.get("lookup_tokens", 0),
+            **forward,
             "traced_active_mean": sum(p["active"] for p in traced_polls) / len(traced_polls),
             "traced_tokens_held_mean": sum(p["tokens_held"] for p in traced_polls) / len(traced_polls),
             "generator_late_max_s": max(late, default=0.0),
@@ -247,6 +264,49 @@ def _metrics(cell, dev, snap, polls, s_open, s_close, t_open, t_close, window_s,
             "ttft_ms": ttft_seen if not backlog else [],
         },
     }
+
+
+def cached_prefix_lens(prompts: list[list[int]], block: int) -> list[int]:
+    """For each of ``prompts``, in the order their first tokens came: the whole
+    blocks at its head that a prompt ahead of it began with too, never all of
+    it (a token is left to prefill). What a prefix cache of whole blocks that
+    forgets nothing serves a prompt from, so what is left is the work the
+    prompt needed; the harness's own reckoning from the tokens it sent, no
+    counter of the engine's."""
+    seen: set[int] = set()
+    out = []
+    for p in prompts:
+        chain, h = [], 0
+        for k in range(len(p) // block):
+            h = hash((h, tuple(p[k * block : (k + 1) * block])))
+            chain.append(h)
+        n = next((i for i, h in enumerate(chain) if h not in seen), len(chain))
+        out.append(min(n, (len(p) - 1) // block) * block)
+        seen.update(chain)
+    return out
+
+
+def forward_flops(config: dict, decoded: int, context: float, prefills: list[tuple[int, int]]) -> dict:
+    """The forward FLOPs the window needed, by the kind's count
+    (``kinds/<kind>.py::forward_flops_per_token``) and from what the harness
+    itself holds: every token the window put out the whole forward at
+    ``context``, the mean tokens a slot held over the polls (a request's first
+    token, which its prefill made, among them: one in some hundreds); every
+    prompt token it prefilled the forward less the head. ``prefills`` is
+    ``(prompt, cached)`` tokens of each request whose first token came inside
+    the window: it prefilled ``prompt - cached`` positions, the first of which
+    attends ``cached + 1`` keys and the last ``prompt``, request by request
+    (:func:`cached_prefix_lens`; not the prefix cache's ``hit_tokens``, which
+    count every waiting request the engine walks past in a round and again in
+    the next: 1.4 times the tokens kept in ``kimi``'s cell, and a later PR may
+    move how often it plans).
+    -> the counters ``model.serve_mfu_pct`` reads, with what they were made of."""
+    prefilled = sum(p - c for p, c in prefills)
+    keys = sum((p - c) * (p + c + 1) / 2 for p, c in prefills) / prefilled if prefilled else 0.0
+    flops = (decoded * counts.forward_flops_per_token(config, context)
+             + prefilled * counts.forward_flops_per_token(config, keys, head=False))
+    return {"forward_flops": flops, "decode_context_mean": context, "prefilled_tokens": prefilled,
+            "prefill_keys_mean": keys}
 
 
 def sample_finished(snap: list[dict], n: int, seed: int, t_from: float, t_to: float) -> tuple[list[dict], int]:
@@ -268,29 +328,83 @@ def sample_finished(snap: list[dict], n: int, seed: int, t_from: float, t_to: fl
     return [done[i] for i in picked], len(set(picked) & set(together))
 
 
-def served_gaps(params, config: dict, sample: list[dict], quant: Optional[str] = None):  # noqa: ANN001
+def _padded_tokens(s: dict):  # noqa: ANN202
+    """``[1, padded]`` prompt + served tokens of a sampled request, padded to
+    a multiple of 128: few distinct shapes for the reference's layers to compile."""
+    seq = s["prompt"] + s["generated"]
+    return jnp.asarray([seq + [0] * (-len(seq) % 128)], jnp.int32)
+
+
+SLICE = 512  # served positions the head multiplies at once: 512 x 131,072 float32 logits are 256 MiB
+
+
+def _compare_slice(ref, config: dict, quant: Optional[str]):  # noqa: ANN001, ANN202
+    """The comparison of one slice of served positions, to jit: the kind's
+    ``head`` over ``rows`` (the stream at those positions), reduced where the
+    logits are made to each position's gap, so that no ``[positions, vocab]``
+    array outlives the program. ``other`` is the served tokens or, with
+    ``quant``, the control's stream at the same positions, whose first choice
+    under the lower precision then stands for the served token."""
+
+    def compare(rows, top, other):  # noqa: ANN001, ANN202
+        lg = ref.head(rows[None], top, config, None)[0]
+        served = other if quant is None else jnp.argmax(ref.head(other[None], top, config, quant)[0], axis=-1)
+        return jnp.max(lg, axis=-1) - jnp.take_along_axis(lg, served[:, None], axis=-1)[:, 0]
+
+    return compare
+
+
+def served_gaps(params, config: dict, sample: list[dict], quant: Optional[str] = None) -> list[float]:  # noqa: ANN001
     """For each served token of each sampled request: how far its logit lies
     below the best of the kind's reference at that position, from one pass over
-    prompt + served tokens. With ``quant`` (the control) the served tokens are
+    prompt + served tokens. The reference's layers give the stream ahead of the
+    head; the head runs over the served positions alone, ``SLICE`` at a time
+    through one compiled program (one compile a cell, not one a length), each
+    slice reduced to its gaps under the jit: the ``[padded, vocab]`` logits
+    never exist, and the check needs what the layer pass needs whatever a
+    request's length. With ``quant`` (the control) the served tokens are
     replaced by the ones the lower precision puts first at each position."""
     ref = kinds.reference(config)
+    top = {k: w for k, w in params.items() if not isinstance(w, dict)}  # what the head reads: no group of layers
+    compare, compiled = jax.jit(_compare_slice(ref, config, quant)), None
+    before = device.memory_stats()
     gaps = []
     for s in sample:
-        seq = s["prompt"] + s["generated"]
-        n_p, n_g = len(s["prompt"]), len(s["generated"])
-        padded = -(-len(seq) // 128) * 128  # few distinct shapes to compile
-        toks = jnp.asarray([seq + [0] * (padded - len(seq))], jnp.int32)
-        # the logits at position p-1+i predict served token i
-        lg = ref.logits(params, toks, config)[0, n_p - 1 : n_p - 1 + n_g]
-        if quant is None:
-            served = jnp.asarray(s["generated"], jnp.int32)
-        else:
-            low = ref.logits(params, toks, config, quant)[0, n_p - 1 : n_p - 1 + n_g]
-            served = jnp.argmax(low, axis=-1)
-        best = jnp.max(lg, axis=-1)
-        got = jnp.take_along_axis(lg, served[:, None], axis=-1)[:, 0]
-        gaps.extend(np.asarray(best - got).tolist())
+        toks, n_g = _padded_tokens(s), len(s["generated"])
+        x = ref.stream(params, toks, config)[0]
+        x_low = ref.stream(params, toks, config, quant)[0] if quant is not None else None
+        at = len(s["prompt"]) - 1  # the stream at position at + i predicts served token i
+        for i in range(0, n_g, SLICE):
+            n = min(SLICE, n_g - i)
+            # a short last slice repeats its last position; what it gives for those is dropped
+            idx = jnp.asarray(np.minimum(np.arange(SLICE) + at + i, at + i + n - 1), jnp.int32)
+            rows = jnp.take(x, idx, axis=0)
+            if quant is None:
+                other = jnp.asarray((s["generated"][i : i + n] + [0] * SLICE)[:SLICE], jnp.int32)
+            else:
+                other = jnp.take(x_low, idx, axis=0)
+            if compiled is None:
+                compiled = compare.lower(rows, top, other).compile()
+            gaps.extend(np.asarray(compiled(rows, top, other))[:n].tolist())
+        del x, x_low  # not held through the next request's layer pass
+    _print_memory(before, compiled)
     return gaps
+
+
+def _print_memory(before: dict, compiled) -> None:  # noqa: ANN001
+    """One line from which the check's need is read off a log. The peak is the
+    process's and cannot be reset: it shows the check's need only where that
+    passed the window's, so the slice's own program is printed beside it.
+    ``temporary`` counts device memory alone: a slice's logits of up to some
+    64 MiB (a vocabulary of 32,768 and under) the compiler keeps in the chip's
+    fast memory (layout ``S(1)`` in the compiled text) and they read 0."""
+    after = device.memory_stats()
+    m = compiled.memory_analysis() if compiled is not None else None
+    program = "not compiled" if m is None else (
+        f"temporary {m.temp_size_in_bytes} arguments {m.argument_size_in_bytes} output {m.output_size_in_bytes}")
+    print(f"check: memory in use {before['bytes_in_use']} as the check starts, peak {before['peak_bytes_in_use']}"
+          f" -> {after['peak_bytes_in_use']}, limit {after['bytes_limit']}; the slice of {SLICE} positions: {program}"
+          " (bytes)", flush=True)
 
 
 SHARES_OVER = (0.25, 0.5, 1.0, 2.0)  # printed for every run; a cell compares the one it names

@@ -158,9 +158,10 @@ def _sliding(c: dict) -> list[bool]:
     return [t == "sliding_attention" for t in c["layer_types"]]
 
 
-def logits(params: dict, tokens: jnp.ndarray, c: dict, quant: Optional[str] = None) -> jnp.ndarray:
-    """``[b, s, vocab]`` float32 logits of ``tokens[b, s]``, layer by layer so
-    that only one layer's float32 copies are alive beside the given weights."""
+def stream(params: dict, tokens: jnp.ndarray, c: dict, quant: Optional[str] = None) -> jnp.ndarray:
+    """``[b, s, d]`` float32: what :func:`head` is given, the residual stream
+    behind the last layer, layer by layer so that only one layer's float32
+    copies are alive beside the given weights."""
     x = params["embed"][tokens].astype(F32)
     sliding, at = _sliding(c), 0
     for group in GROUPS:
@@ -168,7 +169,14 @@ def logits(params: dict, tokens: jnp.ndarray, c: dict, quant: Optional[str] = No
             for i in range(params[group]["wq"].shape[0]):
                 x = _layer_jit(x, params[group], jnp.int32(i), _static(c), sliding[at], quant)
                 at += 1
-    return _head_jit(x, _top(params), _static(c), quant)
+    return x
+
+
+def logits(params: dict, tokens: jnp.ndarray, c: dict, quant: Optional[str] = None) -> jnp.ndarray:
+    """``[b, s, vocab]`` float32 logits of ``tokens[b, s]``: the head over the
+    whole :func:`stream`. The benchmark's check never holds these: it gives
+    :func:`head` the served positions a slice at a time (``lib/serve_cell.py``)."""
+    return _head_jit(stream(params, tokens, c, quant), _top(params), _static(c), quant)
 
 
 def mean_nll(params: dict, tokens: jnp.ndarray, c: dict, quant: Optional[str] = None):

@@ -231,15 +231,23 @@ def _top(params: dict) -> dict:
     return {k: w for k, w in params.items() if k not in GROUPS}
 
 
-def logits(params: dict, tokens: jnp.ndarray, c: dict, quant: Optional[str] = None) -> jnp.ndarray:
-    """``[b, s, vocab]`` float32 logits of ``tokens[b, s]``, layer by layer so
-    that only one layer's float32 copies are alive beside the given weights."""
+def stream(params: dict, tokens: jnp.ndarray, c: dict, quant: Optional[str] = None) -> jnp.ndarray:
+    """``[b, s, n, d]`` float32: what :func:`head` is given, the residual
+    streams behind the last layer, layer by layer so that only one layer's
+    float32 copies are alive beside the given weights."""
     x = embed(params, tokens, c)
     for group in GROUPS:
         if group in params:
             for i in range(params[group]["w_qa"].shape[0]):
                 x = _layer_jit(x, params[group], jnp.int32(i), _static(c), quant)
-    return _head_jit(x, _top(params), _static(c), quant)
+    return x
+
+
+def logits(params: dict, tokens: jnp.ndarray, c: dict, quant: Optional[str] = None) -> jnp.ndarray:
+    """``[b, s, vocab]`` float32 logits of ``tokens[b, s]``: the head over the
+    whole :func:`stream`. The benchmark's check never holds these: it gives
+    :func:`head` the served positions a slice at a time (``lib/serve_cell.py``)."""
+    return _head_jit(stream(params, tokens, c, quant), _top(params), _static(c), quant)
 
 
 def mean_nll(params: dict, tokens: jnp.ndarray, c: dict, quant: Optional[str] = None):

@@ -153,15 +153,26 @@ def _static(c: dict) -> tuple:
     return tuple((k, c[k]) for k in keys if c.get(k) is not None)
 
 
-def logits(params: dict, tokens: jnp.ndarray, c: dict, quant: Optional[str] = None) -> jnp.ndarray:
-    """``[b, s, vocab]`` float32 logits of ``tokens[b, s]``, layer by layer so
-    that only one layer's float32 copy is alive beside the given weights."""
+def _top(params: dict) -> dict:
+    return {k: w for k, w in params.items() if k != "layers"}
+
+
+def stream(params: dict, tokens: jnp.ndarray, c: dict, quant: Optional[str] = None) -> jnp.ndarray:
+    """``[b, s, d]`` float32: what :func:`head` is given, the residual stream
+    behind the last layer, layer by layer so that only one layer's float32
+    copy is alive beside the given weights."""
     x = params["embed"][tokens].astype(F32)
     n_layers = params["layers"]["wq"].shape[0]
     for i in range(n_layers):
         x = _layer_jit(x, params["layers"], jnp.int32(i), _static(c), quant)
-    top = {k: w for k, w in params.items() if k != "layers"}
-    return _head_jit(x, top, _static(c), quant)
+    return x
+
+
+def logits(params: dict, tokens: jnp.ndarray, c: dict, quant: Optional[str] = None) -> jnp.ndarray:
+    """``[b, s, vocab]`` float32 logits of ``tokens[b, s]``: the head over the
+    whole :func:`stream`. The benchmark's check never holds these: it gives
+    :func:`head` the served positions a slice at a time (``lib/serve_cell.py``)."""
+    return _head_jit(stream(params, tokens, c, quant), _top(params), _static(c), quant)
 
 
 def mean_nll(params: dict, tokens: jnp.ndarray, c: dict, quant: Optional[str] = None):
@@ -174,7 +185,7 @@ def mean_nll(params: dict, tokens: jnp.ndarray, c: dict, quant: Optional[str] = 
         return layer(x, lw, c, quant), None
 
     x, _ = jax.lax.scan(jax.checkpoint(body), x, params["layers"])
-    top = {k: w for k, w in params.items() if k != "layers"}
+    top = _top(params)
 
     def row_nll(args):  # noqa: ANN001 - one row's [s, vocab] logits at a time
         xr, tr = args

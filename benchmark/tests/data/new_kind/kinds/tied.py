@@ -58,6 +58,11 @@ def train_flops_per_token(c: dict, seq: int) -> float:
     return 6.0 * (L * _layer_matmul_params(c) + d * v) + 3.0 * L * 2 * 2 * d * (seq / 2)
 
 
+def forward_flops_per_token(c: dict, keys: float, head: bool = True) -> float:
+    d, _, _, _, _, L, v = _dims(c)
+    return 2.0 * (L * _layer_matmul_params(c) + (d * v if head else 0)) + L * 2 * 2 * d * keys
+
+
 def kv_bytes_per_token(c: dict, dtype_bytes: int = 2) -> int:
     _, _, kvh, hd, _, L, _ = _dims(c)
     return L * 2 * kvh * hd * dtype_bytes

@@ -29,12 +29,20 @@ def layer(x: jnp.ndarray, lw: dict, c: dict, quant: Optional[str]) -> jnp.ndarra
     return x + swiglu(m, lw["w_gate"], lw["w_up"], lw["w_down"], quant)
 
 
-def logits(params: dict, tokens: jnp.ndarray, c: dict, quant: Optional[str] = None) -> jnp.ndarray:
+def stream(params: dict, tokens: jnp.ndarray, c: dict, quant: Optional[str] = None) -> jnp.ndarray:
     x = params["embed"][tokens].astype(F32)
     for i in range(c["n_layer"]):
         x = layer(x, {k: w[i] for k, w in params["layers"].items()}, c, quant)
+    return x
+
+
+def head(x: jnp.ndarray, params: dict, c: dict, quant: Optional[str] = None) -> jnp.ndarray:
     x = rms_norm(x, params["final_norm"], c["layer_norm_epsilon"])
     return SCALE * matmul(x, params["embed"].T, quant)
+
+
+def logits(params: dict, tokens: jnp.ndarray, c: dict, quant: Optional[str] = None) -> jnp.ndarray:
+    return head(stream(params, tokens, c, quant), params, c, quant)
 
 
 def mean_nll(params: dict, tokens: jnp.ndarray, c: dict, quant: Optional[str] = None):

@@ -36,8 +36,8 @@ def test_the_kinds_reference_is_the_one_consulted(tmp_path, scale, correct):
     os.makedirs(bench / "reference")
     src = open(os.path.join(spec.BENCH_DIR, "reference", "exaone_moe.py")).read()
     (bench / "reference" / "exaone_moe.py").write_text(
-        src.replace("return _head_jit(x, _top(params), _static(c), quant)",
-                    f"return {scale} * _head_jit(x, _top(params), _static(c), quant)"))
+        src.replace("    return jnp.concatenate(\n        [matmul(x, w[:, i",  # the head's last statement
+                    f"    return {scale} * jnp.concatenate(\n        [matmul(x, w[:, i"))
     out = bench_run.run_cell(CELL, 3, 1.5, False, bench_dir=str(bench), allow_cpu=True)
     assert out["correct"] is correct
 

@@ -112,7 +112,7 @@ def test_the_cell_and_its_traffic_are_issue_33s_to_the_digit():
     assert t["max_total_tokens"] == 4096 and t["sampling"] == "greedy"
     assert set(cell.end_to_end) == {"serve_tokens_per_s", "setup_s"}
     for name in (*NEW_READERS, "kernels.decode_mla_hbm_pct", "kernels.decode_hbm_pct", "kernels.decode_moe_routing_pct",
-                 "kernels.prefill_experts_mxu_pct", "engine.prefill_stall_pct", "device.idle_pct.serve"):  # fmt: skip
+                 "kernels.prefill_experts_mxu_pct", "engine.prefill_device_pct", "device.idle_pct.serve"):  # fmt: skip
         assert name in cell.per_layer
     for name in NEW_READERS:  # read in the new cell alone
         assert next(m for m in manifest["per_layer"] if m["name"] == name)["workloads"] == [REAL]

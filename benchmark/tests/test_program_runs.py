@@ -154,12 +154,168 @@ def test_runs_at_the_edges_that_lack_a_side_are_kept_apart():
     assert rnd.exposed is None and program_runs.idle_after_prefill_s(r) is None  # nothing follows the round's run
 
 
+def test_a_run_that_lacks_a_side_inside_the_trace_still_takes_its_call():
+    """A completion the profiler dropped (seen on the chip, PR 39: one ``CompleteCallbacks`` missing behind a
+    0.4 s stall of the host) makes its run an edge; it must not leave its call to the run after it."""
+    modules, lines, enqueues, completes = _made_up()
+    completes = [c for c in completes if c[0] != 101]
+    r = program_runs.build(modules, lines, enqueues, completes)
+    assert [x.run_id for x in r.edges] == [101] and r.edges[0].span.start == pytest.approx(2.4 * MS) and not r.unlinked
+    assert [(x.run_id, x.span.start / MS) for x in r.runs] == [(100, pytest.approx(0.5)), (102, pytest.approx(13.6)),
+                                                                 (103, pytest.approx(31.4))]
+    idle = [(12.2 * MS, 12.21 * MS), (22.21 * MS, 22.22 * MS), (30.22 * MS, 32.8 * MS)]
+    want = {"decode_host": 1.6 + 0.01 * 0.1 / 1.9, "admit_host": 0.1 + 0.01 * 1.8 / 1.9}
+    assert _ms(program_runs.idle_by_class(r, idle, _host(r))) == pytest.approx(want)  # as with every side there
+
+
 def test_a_run_called_before_the_trace_began_is_an_edge_not_an_unaccounted_program():
     modules, lines, enqueues, completes = _made_up()
     lines[0] = [ev for ev in lines[0] if ev[1] >= 2.0 * MS]  # the trace begins between run 100's call and its enqueue
     r = program_runs.build(modules, lines, enqueues, completes)
     assert r.problem is None and not r.unlinked and [x.run_id for x in r.edges] == [100]
     assert [x.run_id for x in r.linked()] == [101, 102, 103] and r.hi == pytest.approx(0.15 * MS)  # it still bounds the offset
+
+
+# -- the idle split by run_id (PR 39; until then by the span that held a program's midpoint) ---------
+
+
+def _host(r):
+    return program_runs.host_classes(r.spans, hot)
+
+
+def _ms(by_class):
+    return {k: v / MS for k, v in by_class.items()}
+
+
+def test_a_gap_goes_to_the_turn_that_enqueued_b_by_what_the_thread_did_since_its_last_result():
+    r = program_runs.build(*_made_up())
+    idle = [(12.2 * MS, 12.21 * MS), (22.21 * MS, 22.22 * MS), (30.22 * MS, 32.8 * MS)]
+    # behind the round (2.58): from the round's fetch, serve.admit.commit 30.9-31.0, then the next turn's prepare and
+    # dispatch 31.0-32.6; ahead of the round (0.01): enqueued with a step in flight, since the fetch that ended at 12.9
+    # the thread did .commit 12.9-13.0 and the round's plan, build and dispatch 13.0-14.8; the first gap has no
+    # fetch ahead of its enqueue in the trace: no class's
+    want = {"decode_host": 1.6 + 0.01 * 0.1 / 1.9, "admit_host": 0.1 + 0.01 * 1.8 / 1.9}
+    assert _ms(program_runs.idle_by_class(r, idle, _host(r))) == pytest.approx(want)
+
+
+def test_a_gap_shorter_than_the_host_work_it_holds_is_shared_in_proportion():
+    r = program_runs.build(*_made_up())
+    # of the 2.58 ms between the round's program and the next the device idles 0.85 (bubbles aside, as where
+    # the device starts before the dispatch call returns): less than the 1.7 ms of host work between them
+    got = _ms(program_runs.idle_by_class(r, [(30.22 * MS, 31.07 * MS)], _host(r)))
+    assert got == pytest.approx({"decode_host": 0.85 * 1.6 / 1.7, "admit_host": 0.85 * 0.1 / 1.7})
+
+
+def _made_up_stall():
+    """Four decode turns with a step always in flight (a program of 18 ms); the third turn's dispatch takes
+    51.2 ms where the others take 1.2, so the chip ends run 101 at 38.21 and waits until 72.4 for run 102."""
+    call = lambda fn, s, e: [(f"PjitFunction({fn})", s, e, {})]  # noqa: E731
+    engine = [
+        (hot.SERVE_DECODE, 0.0, 2.0, {"step": 1}),
+        (hot.SERVE_DECODE_PREPARE, 0.0, 0.5, {}),
+        (hot.SERVE_DECODE_DISPATCH, 0.5, 2.0, {}), *call("_decode", 1.5, 1.9),
+        (hot.SERVE_DECODE, 2.0, 20.6, {"step": 1}),
+        (hot.SERVE_DECODE_PREPARE, 2.0, 2.4, {}),
+        (hot.SERVE_DECODE_DISPATCH, 2.4, 3.6, {}), *call("_decode", 3.2, 3.5),
+        (hot.SERVE_DECODE_FETCH, 3.6, 20.5, {}),
+        (hot.SERVE_DECODE_COMMIT, 20.5, 20.6, {"finished": 0}),
+        (hot.SERVE_DECODE, 20.6, 72.4, {"step": 2}),
+        (hot.SERVE_DECODE_PREPARE, 20.6, 21.0, {}),
+        (hot.SERVE_DECODE_DISPATCH, 21.0, 72.2, {}), *call("_decode", 71.8, 72.1),  # the thread 50 ms late
+        (hot.SERVE_DECODE_FETCH, 72.2, 72.3, {}),  # run 101's result had long been there
+        (hot.SERVE_DECODE_COMMIT, 72.3, 72.4, {"finished": 0}),
+        (hot.SERVE_DECODE, 72.4, 90.8, {"step": 3}),
+        (hot.SERVE_DECODE_PREPARE, 72.4, 72.8, {}),
+        (hot.SERVE_DECODE_DISPATCH, 72.8, 74.0, {}), *call("_decode", 73.6, 73.9),
+        (hot.SERVE_DECODE_FETCH, 74.0, 90.7, {}),
+        (hot.SERVE_DECODE_COMMIT, 90.7, 90.8, {"finished": 0}),
+    ]
+    modules = [(100, "jit__decode", 2.2, 20.2), (101, "jit__decode", 20.21, 38.21), (102, "jit__decode", 72.4, 90.4),
+               (103, "jit__decode", 90.41, 108.41)]
+    enqueues = [(100, 1.95), (101, 3.55), (102, 72.15), (103, 73.95)]
+    completes = [(100, 20.4), (101, 38.5), (102, 90.6), (103, 108.7)]
+    scale = lambda evs: [tuple(x * MS if isinstance(x, float) else x for x in ev) for ev in evs]  # noqa: E731
+    return scale(modules), [scale(engine)], scale(enqueues), scale(completes)
+
+
+def test_a_dispatch_that_comes_late_with_a_step_in_flight_is_the_decode_hosts():
+    """The engine keeps a step in flight, so B is always enqueued before A is fetched; a thread 50 ms late to
+    enqueue B leaves the chip done with A and waiting, and that wait is how far the decode host holds it back."""
+    r = program_runs.build(*_made_up_stall())
+    assert r.problem is None and not r.unlinked and [x.span.start / MS for x in r.runs] == pytest.approx([0.5, 2.4, 21.0, 72.8])
+    idle = [(20.2 * MS, 20.21 * MS), (38.21 * MS, 72.4 * MS), (90.4 * MS, 90.41 * MS)]
+    # 34.19 of the 51.7 ms the thread worked between the fetch that ended at 20.5 and the late enqueue (run 101
+    # ran through the rest); the 0.01 behind run 102 goes to the turn that enqueued run 103 too; the first gap
+    # has no fetch ahead of its enqueue
+    assert _ms(program_runs.idle_by_class(r, idle, _host(r))) == pytest.approx({"decode_host": 34.19 + 0.01, "admit_host": 0.0})
+
+
+def test_a_run_that_lacks_its_link_gives_its_gap_to_no_class():
+    modules, lines, enqueues, completes = _made_up()
+    lines[0] = [ev for ev in lines[0] if not (ev[0].startswith("PjitFunction") and ev[1] > 32 * MS)]  # 103 has no call
+    r = program_runs.build(modules, lines, enqueues, completes)
+    assert r.unlinked == {"jit__decode": 1}
+    assert _ms(program_runs.idle_by_class(r, [(30.22 * MS, 32.8 * MS)], _host(r))) == {"decode_host": 0.0, "admit_host": 0.0}
+    r = program_runs.build(*_made_up())  # ... and so does one enqueued before the trace's first fetch
+    assert _ms(program_runs.idle_by_class(r, [(12.2 * MS, 12.21 * MS)], _host(r))) == {"decode_host": 0.0, "admit_host": 0.0}
+
+
+IDLE_READERS = ("device.idle_decode_host_pct", "device.idle_admit_host_pct", "device.idle_other_pct")
+
+
+@pytest.mark.parametrize("suffix", ["", ".latency"])
+@pytest.mark.parametrize("path", [RUNS_TRACE, OLDER_TRACE])
+def test_the_three_idle_readers_add_up_to_the_idle_share_on_the_recorded_traces(path, suffix, monkeypatch):
+    monkeypatch.setattr(scopes, "trace_file", lambda run: path)
+    run = _traced(path)
+    parts = [bench_run.load_reader(name + suffix, spec.BENCH_DIR).read(run) for name in IDLE_READERS]
+    whole = bench_run.load_reader("device.idle_pct.serve" + suffix, spec.BENCH_DIR).read(run)
+    assert all(p is not None and p >= 0 for p in parts) and sum(parts) == pytest.approx(whole, abs=1e-6)
+    assert parts[0] > 0 and parts[1] > 0  # both traces hold a round and the turn behind it
+
+
+@pytest.mark.parametrize("path", [RUNS_TRACE, OLDER_TRACE])
+def test_the_split_is_of_the_idle_time_the_harness_reads(path):
+    """``device.idle_other_pct`` is the rest of ``device.idle_pct.serve``, so the sum above holds whatever the
+    split says; what holds the split: its own reading of the device's idle time (``lib/scopes.py``'s instants) is
+    ``lib/trace.py``'s, a host class takes no more than the gaps behind the programs its turns enqueued, and
+    no more than the thread worked in that class."""
+    split, ref = program_runs.idle_split(path), _traced(path)["trace"]
+    # picoseconds against rounded nanoseconds: a quarter of a microsecond over the shorter trace's quarter second
+    assert split.idle_s == pytest.approx(ref["window_s"] - ref["busy_s"], abs=5e-7)
+    assert split.window_s == pytest.approx(ref["window_s"], rel=1e-6)
+    assert min(split.by_class.values()) >= 0.0 and sum(split.by_class.values()) <= split.idle_s
+    r = program_runs.read(path)
+    between_programs = sum(seconds for _, seconds in ref["idle_gaps"])
+    assert sum(split.by_class.values()) <= between_programs + 1e-9
+    for cls, intervals in _host(r).items():
+        assert split.by_class[cls] <= sum(e - s for s, e in intervals)
+
+
+def test_without_a_step_in_flight_the_link_shares_the_idle_time_out_as_the_midpoint_rule_did():
+    """PR 24's engine fetched every program before it enqueued the next, so the span that held a program's
+    midpoint was the turn that ran it and the latest fetch ahead of an enqueue the one that waited for the
+    program before: the midpoint rule's seconds over this trace (PR 38's ``lib/host_spans.py``) were 0.01884477,
+    0.009453179 and 0.013704605294, and the link by ``run_id`` gives the same to the nanosecond but for the gap
+    ahead of the trace's last program (67.34 us of ``decode_host``), whose turn the trace cuts off before its
+    fetch, so that the midpoint rule let it fall to ``other``."""
+    split = program_runs.idle_split(OLDER_TRACE)
+    assert split.by_class["decode_host"] == pytest.approx(0.01884477 + 0.00006734, abs=1e-9)
+    assert split.by_class["admit_host"] == pytest.approx(0.009453179, abs=1e-9)
+    assert split.idle_s - sum(split.by_class.values()) == pytest.approx(0.013704605294 - 0.00006734, abs=1e-9)
+
+
+def test_with_a_step_in_flight_the_link_takes_the_results_way_back_out_of_decode_host():
+    """On the engine that keeps a step in flight the midpoint rule gave a program to the turn after the one
+    that enqueued it, found a later dispatch, more host work than the gap, and shared the whole gap out among
+    the classes (5.678 + 0.430 + 0.023 ms here). By ``run_id`` the interval ends at the dispatch that did
+    enqueue it: each round's result on its way back (0.8-0.9 ms) is no host work and no class's."""
+    split = program_runs.idle_split(RUNS_TRACE)
+    got = dict(_ms(split.by_class), other=(split.idle_s - sum(split.by_class.values())) / MS)
+    assert got == pytest.approx({"decode_host": 3.660802, "admit_host": 0.569637, "other": 1.900785}, abs=1e-4)
+    rounds = program_runs.read(RUNS_TRACE).rounds
+    on_its_way = sum(x.parts["result_on_its_way"] for x in rounds if x.exposed is not None) / MS
+    assert 0.5 * on_its_way < got["other"] < 2.0 * on_its_way + 0.5
 
 
 @pytest.mark.parametrize("missing", ["enqueues", "completes", "modules", "engine"])

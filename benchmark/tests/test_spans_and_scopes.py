@@ -9,7 +9,7 @@ import os
 import pytest
 
 from benchmark import run as bench_run
-from benchmark.lib import host_spans, scopes, spec, trace
+from benchmark.lib import host_spans, program_runs, scopes, spec, trace
 
 from .conftest import DATA
 
@@ -19,7 +19,7 @@ TRAIN_TRACE = os.path.join(DATA, "train_8steps.xplane.pb")
 NEW_READERS = sorted(
     m["name"] for m in json.load(open(os.path.join(spec.REPO_ROOT, "BENCHMARK.json")))["per_layer"]
     if m["name"].split(".latency")[0] in {
-        "engine.host_ms_per_step", "engine.admit_host_ms", "engine.prefill_stall_pct", "engine.traced_step_ms",
+        "engine.host_ms_per_step", "engine.admit_host_ms", "engine.traced_step_ms",
         "device.idle_decode_host_pct", "device.idle_admit_host_pct", "device.idle_other_pct",
         "kernels.decode_attention_pct", "kernels.decode_experts_pct", "kernels.train_attention_pct",
         "kernels.train_optimizer_pct"}
@@ -37,8 +37,7 @@ def test_tree_and_self_times():
     assert [c.name for c in first.children] == [e[0] for e in ev[1:5]]
     assert first.self_time == pytest.approx(0.5) and first.child_time("serve.decode.fetch") == 5.0
     assert roots[2].children == [] and roots[2].self_time == 8.0
-    assert host_spans._host_intervals(roots, "serve.decode", "serve.decode.fetch") == [
-        (0.0, 3.0), (8.0, 10.0), (12.0, 20.0)]
+    assert program_runs.host_classes(roots, hot) == {"decode_host": [(0.0, 3.0), (8.0, 10.0), (12.0, 20.0)], "admit_host": []}
 
 
 def test_overlap_of_interval_lists():
@@ -113,15 +112,7 @@ def test_serving_trace_span_tree(serving):
         assert host_spans.coverage(serving, parent) > 0.95
     assert {"step", "active"} <= set(full[0].attrs) and {"rows", "width"} <= set(rounds[0].attrs)
     assert 0 < host_spans.host_ms_per_step(serving) < host_spans.traced_step_ms(serving)
-    assert host_spans.admit_host_ms(serving) > 0 and 0 < host_spans.prefill_stall_pct(serving) < 100
-
-
-def test_serving_trace_idle_classes_add_up_to_the_harness_idle_share(serving):
-    ref = trace.reduce_planes(trace.read_planes(SERVE_TRACE), chips=1)
-    idle_pct = 100.0 * (1.0 - ref["busy_s"] / ref["window_s"])
-    parts = [host_spans.idle_pct(serving, c) for c in ("decode_host", "admit_host", "other")]
-    assert all(p >= 0 for p in parts) and sum(parts) == pytest.approx(idle_pct, abs=0.01)
-    assert serving.window[1] - serving.window[0] == pytest.approx(ref["window_s"], rel=1e-6)
+    assert host_spans.admit_host_ms(serving) > 0
 
 
 @pytest.mark.parametrize("module", ["jit__decode", "jit__prefill"])
