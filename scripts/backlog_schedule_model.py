@@ -12,6 +12,10 @@ With a decode step of 32 + 4 x rows held / 148 k ms and a round of 30 + 0.022 x 
 (``kimi-vl-a3b-serve-backlog``; my chip run, PR 27) it gave six seeds' steps as 957, 969, 942, 980, 975, 962
 where the chip counted 956, 974, 946, 986, 982, 976. With seeds it prints each seed's window; without, the
 spread (quartile distance over the median) of ``serve_tokens_per_s`` over 200 seeds.
+
+**The loop it walks is the engine's until PR 40** (an admission round with a program of its own, every
+slot waiting through it). Since then a prompt rides the decode steps in chunks and no slot waits; the
+model still says which requests a seed's window serves, and its round times no longer apply.
 """
 
 from __future__ import annotations

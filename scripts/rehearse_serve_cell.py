@@ -2,24 +2,30 @@
 whatever pools its model kind has, and print ``memory_analysis`` of each. No chip time,
 nothing runs, never reported as a chip run.
 
-    JAX_PLATFORMS=cpu python3 scripts/rehearse_serve_cell.py kimi-vl-a3b-serve-backlog [rows x width ...]
+    JAX_PLATFORMS=cpu python3 scripts/rehearse_serve_cell.py kimi-vl-a3b-serve-backlog [chunk width ...]
+
+The two programs an engine has since PR 40: the decode step, and the decode step that
+carries a chunk of a prompt (``ServeEngine``'s ``_decode`` and ``_decode_chunk``, written out
+here as the engine writes them), the second at the engine's default ``chunk_width`` or at
+each width given.
 
 ``benchmark/rehearse_compile.py::serve_cell`` builds K/V pools by hand and so cannot
 describe a latent pool; this asks ``generate.init_kv_pools`` for the pools' shapes: K/V pools,
 a pool a cache kind (``k-exaone-serve-decode-long``), or a latent pool a layer group at 64 slots
 (``kimi-vl-a3b-serve-backlog``) or 128 (``xing4-serve-decode-long``: 16,897 blocks x 8 layers x
-1,280 B, 2.58 GiB beside 10.55 GiB of weights; decode 13.14 GiB live, widest prefill 13.32).
+1,280 B, 2.58 GiB beside 10.55 GiB of weights; decode 13.14 GiB live).
 Under each program it prints what its layer loop moves of a layer's pool size or more
 (``torchx_tpu/obs/hlo.py::loop_moves``: nothing, since the pools ride the scan's carry),
 and every pure data movement anywhere in the program of the size of a layer's smallest
 attention projection or more (``program_moves``: since PR 32 no weight among them, the
-projections are multiplied where they lie; a wide prefill round still re-lays activations).
+projections are multiplied where they lie).
 The process sees only the CPU, so the backend question every kernel's
 ``kernel_eligible`` asks is answered "tpu" here, as the chip would answer it.
 """
 
 from __future__ import annotations
 
+import inspect
 import os
 import sys
 
@@ -31,8 +37,11 @@ import jax  # noqa: E402
 import jax.numpy as jnp  # noqa: E402
 from jax.sharding import SingleDeviceSharding  # noqa: E402
 
-from benchmark.lib import models, spec, traffic as traffic_lib  # noqa: E402
+from benchmark.lib import models, spec  # noqa: E402
 from benchmark.rehearse_compile import report as report_memory, shapes_of  # noqa: E402
+
+
+KERNELS = ("paged_mla_decode", "paged_attention_decode", "gmm")
 
 
 def main() -> None:
@@ -45,7 +54,7 @@ def main() -> None:
     from torchx_tpu.serve.kv_pool import window_ring
 
     cell = spec.load_cell(sys.argv[1])
-    only = {tuple(int(x) for x in a.split("x")) for a in sys.argv[2:]}
+    widths = [int(a) for a in sys.argv[2:]] or [inspect.signature(eng.ServeEngine).parameters["chunk_width"].default]
     topo = topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
     one = SingleDeviceSharding(topo.devices[0])
     jax.default_backend = lambda: "tpu"  # what kernel_eligible will be told on the chip
@@ -87,29 +96,24 @@ def main() -> None:
         return gen.paged_decode_step(params, tokens, positions, tables, pools, cfg,
                                      eng._fold_keys(seeds, positions), temps)
 
-    def prefill(params, tokens, pl, sl, tables, pools, seeds, temps):  # noqa: ANN001
-        return gen.paged_prefill_chunk(params, tokens, pl, sl, tables, pools, cfg,
-                                       eng._fold_keys(seeds, pl + sl - 1), temps)
+    def decode_chunk(params, tokens, prev, positions, tables, pools, seeds, temps, chunk, at, chunk_tables):  # noqa: ANN001
+        tokens = jnp.where(tokens == eng._FROM_DEVICE, prev, tokens)  # as ServeEngine's _decode_chunk does
+        keys = eng._fold_keys(seeds, jnp.append(positions, at[0] + at[1] - 1))
+        sampled, pools = gen.paged_decode_chunk_step(params, tokens, positions, tables, chunk, at[0], at[1],
+                                                     chunk_tables, pools, cfg, keys, temps)
+        return jnp.where(jnp.arange(slots) == at[2], sampled[-1], sampled[:-1]), pools
 
-    if not only:
-        c = jax.jit(decode, donate_argnums=(5,)).lower(
-            params, sds((slots,), i32), sds((slots,), i32), sds((slots,), i32), tables(slots, ring), pools,
-            sds((slots,), i32), sds((slots,), f32)).compile()
-        report(f"{cell.name}: decode step ({slots} slots, {n_blocks} blocks"
-               + (f", {n_window} window blocks in rings of {ring}" if window else "") + ")", c)
-        text = c.as_text()
-        print("  kernels:", sorted({n for n in ("paged_mla_decode", "paged_attention_decode", "gmm") if n in text}))
-    plan = traffic_lib.build_schedule(mix, 1, 45, config["vocab_size"])
-    widths = traffic_lib.prefill_widths(plan, mix, bs)
-    rows_all = [1 << i for i in range(int(dep["max_prefill_batch"]).bit_length()) if 1 << i <= int(dep["max_prefill_batch"])]
-    for rows in rows_all:
-        for w in widths:
-            if only and (rows, w) not in only:
-                continue
-            c = jax.jit(prefill, donate_argnums=(5,)).lower(
-                params, sds((rows, w), i32), sds((rows,), i32), sds((rows,), i32),
-                tables(rows, per_slot), pools, sds((rows,), i32), sds((rows,), f32)).compile()
-            report(f"{cell.name}: prefill rows {rows} x width {w}", c)
+    geometry = f"{slots} slots, {n_blocks} blocks" + (f", {n_window} window blocks in rings of {ring}" if window else "")
+    slot_args = (sds((slots,), i32), sds((slots,), i32), sds((slots,), i32), tables(slots, ring), pools)
+    c = jax.jit(decode, donate_argnums=(5,)).lower(params, *slot_args, sds((slots,), i32), sds((slots,), f32)).compile()
+    report(f"{cell.name}: decode step ({geometry})", c)
+    print("  kernels:", sorted({n for n in KERNELS if n in c.as_text()}))
+    for width in widths:
+        c = jax.jit(decode_chunk, donate_argnums=(5,)).lower(
+            params, *slot_args, sds((slots + 1,), i32), sds((slots + 1,), f32),
+            sds((width,), i32), sds((3,), i32), tables(1, per_slot)).compile()
+        report(f"{cell.name}: decode step carrying a chunk of {width} ({geometry})", c)
+        print("  kernels:", sorted({n for n in KERNELS if n in c.as_text()}))
 
 
 if __name__ == "__main__":

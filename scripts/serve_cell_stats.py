@@ -1,14 +1,13 @@
 """One run of a serving cell as ``benchmark/run.py`` makes it, with the engine's own
 counters printed as the window's engine stops: ``preemptions``, ``kv_bytes_per_token``,
 ``steps_overlapped``, ``tokens_discarded`` (PR 30), blocks in use, the prefix cache's hits and
-evictions, and the admission rounds' running counts (PR 38: ``prefill_rounds``,
-``prefill_tokens``, ``prefill_padded_tokens``, ``prefill_programs_built``,
-``slot_steps_stalled``). The harness hands its readers
+evictions, and the running counts of the prompts' chunks (PR 40: ``chunk_steps`` of
+``chunk_width``, ``prefill_tokens``, ``prefill_padded_tokens``). The harness hands its readers
 neither ``engine.stats()`` nor the requests (PERF.md 7.2 (c)); this is how PR 27 read
 the preemptions of ``kimi-vl-a3b-serve-backlog``. With ``--trace 1`` it also prints what
 ``benchmark/lib/program_runs.py`` reads of the traced 4 s: the runs linked by ``run_id``,
-the offset's bounds, how many programs the midpoint rule gives to another turn, the
-exposed turn after a round in its parts. Not a tool of the benchmark.
+the offset's bounds, a fetch's wait in its two parts (a trace of an engine from before
+PR 40 also the exposed turn after a round in its parts). Not a tool of the benchmark.
 
     chiprun -- python3 scripts/serve_cell_stats.py --workload <cell> --seed <n> --seconds 45 [--trace 1]
 """
@@ -40,8 +39,8 @@ def main() -> int:
     def stop_and_tell(self, *a, **kw):  # noqa: ANN001, ANN002, ANN003, ANN202
         s = self.stats()
         keep = ("preemptions", "steps_overlapped", "tokens_discarded", "kv_bytes_per_token", "kv_blocks_used",
-                "kv_blocks_free", "requests_done", "steps", "prefix_cache", "prefill_rounds", "prefill_tokens",
-                "prefill_padded_tokens", "prefill_programs_built", "slot_steps_stalled")  # fmt: skip
+                "kv_blocks_free", "requests_done", "steps", "prefix_cache", "chunk_steps", "chunk_width",
+                "prefill_tokens", "prefill_padded_tokens")  # fmt: skip
         print("engine stats at stop:", json.dumps({k: s[k] for k in keep if k in s}), flush=True)
         return stop(self, *a, **kw)
 

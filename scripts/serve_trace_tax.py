@@ -15,7 +15,9 @@ by-scope shares of ``benchmark/lib/scopes.py``. It also records a short
 trace, Python tracer off: ``benchmark/tests/data/serve_spans.xplane.pb`` is
 PR 24's (a few decode steps and one admission round), and
 ``benchmark/tests/data/serve_runs.xplane.pb`` PR 38's (the engine that keeps a
-step in flight: a few decode turns and two rounds, each behind a step).
+step in flight: a few decode turns and two rounds, each behind a step). Since
+PR 40 an admission is no program: the short trace it waits for now holds two
+steps that carried a chunk of a prompt and one that carried none.
 
     chiprun -- python3 scripts/serve_trace_tax.py --workload mixtral8x7b-serve-backlog --seed 7
 
@@ -48,7 +50,7 @@ def main() -> int:
     import jax
     import numpy as np
 
-    from benchmark.lib import device, host_spans, models, scopes, serve_cell, spec
+    from benchmark.lib import device, host_spans, models, program_runs, scopes, serve_cell, spec
     from benchmark.lib import trace as trace_lib
     from benchmark.lib import traffic as traffic_lib
     from torchx_tpu.obs import hot
@@ -123,7 +125,8 @@ def main() -> int:
         if r is None:
             return out
         decode = r.named(hot.SERVE_DECODE)
-        admit = [s for s in r.named(hot.SERVE_ADMIT) if s.child_time(hot.SERVE_PREFILL_DISPATCH) > 0]
+        admit = r.named(hot.SERVE_ADMIT)
+        split = program_runs.idle_split(path)
 
         def children_ms(spans, parent):  # noqa: ANN001, ANN202
             return {c: 1e3 * sum(s.child_time(c) for s in spans) / max(len(spans), 1)
@@ -132,11 +135,11 @@ def main() -> int:
         out.update({
             "decode_spans": len(decode),
             "admit_spans": len(admit),
+            "chunk_spans": sum(int(s.attrs.get("chunk_tokens", 0)) > 0 for s in decode),
             "traced_step_ms": host_spans.traced_step_ms(r),
             "host_ms_per_step": host_spans.host_ms_per_step(r),
             "admit_host_ms": host_spans.admit_host_ms(r),
-            "prefill_stall_pct": host_spans.prefill_stall_pct(r),
-            "idle_by_class_pct": {k: host_spans.idle_pct(r, k) for k in r.idle_by_class},
+            "idle_by_class_pct": {k: 100.0 * v / split.window_s for k, v in split.by_class.items()} if split else None,
             "decode_children_ms": children_ms(decode, hot.SERVE_DECODE),
             "admit_children_ms": children_ms(admit, hot.SERVE_ADMIT),
             "coverage": {p: host_spans.coverage(r, p) for p in hot.SERVE_SPAN_TREE},
@@ -164,11 +167,11 @@ def main() -> int:
     results.append(stretch("tracer_off", args.stretch, quiet, os.path.join(out_dir, "tracer_off_2")))
     results.append(stretch("untraced", args.stretch))
 
-    # the short recorded trace: try until one holds two rounds (a chat cell's never does: its last try stands)
+    # the short recorded trace: try until one holds two steps that carried a chunk and one that carried none
     fixture_dir = os.path.join(out_dir, "fixture")
     for attempt in range(24):
         rec = stretch(f"fixture.{attempt}", args.fixture_s, quiet, fixture_dir)
-        if rec.get("admit_spans", 0) >= 2 and rec.get("decode_spans", 0) >= 3:
+        if rec.get("chunk_spans", 0) >= 2 and rec.get("decode_spans", 0) >= rec.get("chunk_spans", 0) + 1:
             break
     results.append(rec)
 

@@ -217,28 +217,26 @@ class TestDeadEngine:
             srv.shutdown()
             srv.service.close()
 
-    def test_prefill_failure_fails_the_request_being_admitted(self, monkeypatch):
-        """A request popped for admission sits in no queue and no slot. On
-        the chip an 8192-wide prefill bucket ran the compile out of HBM and
-        the caller waited out its own timeout."""
+    def test_a_failing_chunk_fails_the_request_whose_prompt_it_fed(self):
+        """A request whose prompt is being fed sits in a slot and has no token
+        yet. On the chip an 8192-wide prefill bucket once ran the compile out
+        of HBM and the caller waited out its own timeout; the program that
+        carries a prompt's chunk must fail its request the same way."""
         from torchx_tpu.models import llama
         from torchx_tpu.serve.engine import ServeEngine
 
-        def no_hbm(self, rows, width):
-            def fn(*args):
-                raise RuntimeError("RESOURCE_EXHAUSTED: ran out of hbm")
+        def no_hbm(*args):
+            raise RuntimeError("RESOURCE_EXHAUSTED: ran out of hbm")
 
-            return fn
-
-        monkeypatch.setattr(ServeEngine, "_prefill_fn", no_hbm)
         cfg = llama.llama_tiny()
-        engine = ServeEngine(
-            llama.init_params(cfg, jax.random.PRNGKey(0)), cfg, max_slots=2
-        ).start()
+        engine = ServeEngine(llama.init_params(cfg, jax.random.PRNGKey(0)), cfg, max_slots=2)
+        engine._decode_chunk = no_hbm
+        engine.start()
         try:
             with pytest.raises(RuntimeError, match="ran out of hbm"):
                 engine.generate([1, 2, 3], max_new_tokens=4, timeout=30)
             assert "ran out of hbm" in engine.failed
+            assert engine.alloc.used_blocks == 0  # the slot's blocks went back with it
         finally:
             engine.stop()
 

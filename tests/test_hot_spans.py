@@ -18,27 +18,26 @@ from torchx_tpu.obs import metrics as obs_metrics
 from torchx_tpu.obs import trace as obs_trace
 from torchx_tpu.serve.engine import ServeEngine, ServeRequest
 
+#: what the engine records: parent -> the children that tile it (admission is planning alone; the other
+#: children ``hot.SERVE_SPAN_TREE`` names under ``serve.admit`` are those of traces from before PR 40)
+RECORDED_TREE = {hot.SERVE_ADMIT: (hot.SERVE_ADMIT_PLAN,), hot.SERVE_DECODE: hot.SERVE_SPAN_TREE[hot.SERVE_DECODE]}
 ENGINE_SPANS = [
-    hot.SERVE_ADMIT,
-    *hot.SERVE_SPAN_TREE[hot.SERVE_ADMIT],
-    hot.SERVE_DECODE,
-    *hot.SERVE_SPAN_TREE[hot.SERVE_DECODE],
+    *(name for parent, kids in RECORDED_TREE.items() for name in (parent, *kids)),
     hot.SERVE_IDLE,
     hot.SERVE_KV_IMPORT,
     hot.SERVE_COW_COPY,
 ]
 #: stats() count -> what of a span adds up to it over a run
 RUNNING_COUNTS = {
-    "prefill_rounds": lambda evs: sum(1 for ev in _rounds(evs)),
-    "prefill_tokens": lambda evs: sum(ev[3]["tokens"] for ev in _rounds(evs)),
-    "prefill_padded_tokens": lambda evs: sum(ev[3]["rows_padded"] * ev[3]["width"] for ev in _rounds(evs)),
-    "prefill_programs_built": lambda evs: sum(ev[3]["built"] for ev in _rounds(evs)),
-    "slot_steps_stalled": lambda evs: sum(ev[3]["slots_stalled"] for ev in _rounds(evs)),
+    "chunk_steps": lambda evs: sum(1 for ev in _chunk_turns(evs)),
+    "prefill_tokens": lambda evs: sum(ev[3]["chunk_tokens"] for ev in _chunk_turns(evs)),
+    "prefill_padded_tokens": lambda evs: sum(ev[3]["chunk_width"] for ev in _chunk_turns(evs)),
 }
 
 
-def _rounds(events):
-    return [ev for ev in events if ev[0] == hot.SERVE_ADMIT and "rows" in ev[3]]
+def _chunk_turns(events):
+    """The ``serve.decode`` turns whose step carried a chunk of a prompt."""
+    return [ev for ev in events if ev[0] == hot.SERVE_DECODE and ev[3].get("chunk_tokens", 0) > 0]
 
 
 def _session(tmp_path, body):
@@ -64,8 +63,8 @@ def _session(tmp_path, body):
 
 
 def _drive(engine):
-    """Two admission rounds, some decode steps, a transferred prefill, and an
-    idle turn of the loop."""
+    """Prompts fed while others decode, one of them in two chunks, some
+    decode steps, a transferred prefill, and an idle turn of the loop."""
     reqs = [engine.submit(ServeRequest(prompt=[i + 1, i + 2, i + 3], max_new_tokens=5)) for i in range(3)]
     for r in reqs:
         assert r.wait(timeout=120) and not r.error
@@ -104,7 +103,7 @@ def traced_drive(tiny, tmp_path_factory):
     """A traced ``_drive``: the host plane's lines, ``stats()`` and the decode
     tokens counted before and after it."""
     cfg, params = tiny
-    engine = ServeEngine(params, cfg, max_slots=4, block_size=16, max_prefill_batch=2).start()
+    engine = ServeEngine(params, cfg, max_slots=4, block_size=16, max_prefill_batch=2, chunk_width=16).start()
     counted = lambda: {phase: obs_metrics.SERVE_TOKENS.value(phase=phase) for phase in ("prefill", "decode")}  # noqa: E731
     try:
         _drive(engine)  # compile outside the session
@@ -141,10 +140,10 @@ def test_engine_span_is_recorded(serve_events, name):
     assert any(ev[0] == name for ev in serve_events)
 
 
-@pytest.mark.parametrize("parent", sorted(hot.SERVE_SPAN_TREE))
+@pytest.mark.parametrize("parent", sorted(RECORDED_TREE))
 def test_children_lie_inside_their_parent_and_tile_it(engine_line, parent):
     parents = [ev for ev in engine_line if ev[0] == parent]
-    kids = hot.SERVE_SPAN_TREE[parent]
+    kids = RECORDED_TREE[parent]
     assert parents
     for child in kids:
         for _, s, e, _ in (ev for ev in engine_line if ev[0] == child):
@@ -153,15 +152,15 @@ def test_children_lie_inside_their_parent_and_tile_it(engine_line, parent):
         [c for c in engine_line if c[0] in kids and ps <= c[1] and c[2] <= pe]
         for _, ps, pe, _ in parents
     ]
-    # a round that prefilled, a step that ran: every child once, in order
+    # an admission that gave a slot, a step that ran: every child once, in order
     assert any([c[0] for c in sorted(inside, key=lambda c: c[1])] == list(kids) for inside in full)
 
 
 @pytest.mark.parametrize(
     "name,attrs",
     [
-        (hot.SERVE_DECODE, {"step", "active", "steps_overlapped", "tokens_discarded"}),
-        (hot.SERVE_ADMIT, {"rows", "width", "cached_tokens", "tokens", "queue_depth", "rows_padded", "slots_stalled", "built"}),
+        (hot.SERVE_DECODE, {"step", "active", "chunk_tokens", "chunk_width", "steps_overlapped", "tokens_discarded"}),
+        (hot.SERVE_ADMIT, {"admitted", "cached_tokens", "queue_depth", "kv_bytes_per_token"}),
         (hot.SERVE_DECODE_COMMIT, {"finished"}),
         (hot.SERVE_KV_IMPORT, {"blocks", "cache_len"}),
     ],
@@ -177,15 +176,19 @@ def test_span_attributes(engine_line, name, attrs):
         overlapped = [ev[3]["steps_overlapped"] for ev in events]
         assert overlapped == sorted(overlapped) and overlapped[-1] > overlapped[0]
         assert all(ev[3]["tokens_discarded"] == 0 for ev in events)  # no EOS, no pool pressure
+        # three prompts of 3 tokens, and of the 19-token one what its cached head of a block leaves to feed
+        assert sorted(ev[3]["chunk_tokens"] for ev in events if ev[3]["chunk_tokens"]) == [3, 3, 3, 3]
+        assert {ev[3]["chunk_width"] for ev in events} == {16}
     if name == hot.SERVE_ADMIT:
-        assert {ev[3]["rows"] for ev in events} <= {1, 2} and all(ev[3]["width"] >= 16 for ev in events)
+        # nothing a reader of the admission rounds looks for: no round's attribute outlives the rounds
+        assert {ev[3]["admitted"] for ev in events} <= {1, 2} and max(ev[3]["cached_tokens"] for ev in events) == 16
+        assert not any({"rows", "tokens", "rows_padded", "slots_stalled"} & set(ev[3]) for ev in events)
 
 
 @pytest.mark.parametrize(
     "name,calls",
     [
-        (hot.SERVE_DECODE_DISPATCH, {"_decode"}),
-        (hot.SERVE_PREFILL_DISPATCH, {"_prefill"}),
+        (hot.SERVE_DECODE_DISPATCH, {"_decode", "_decode_chunk"}),
         (hot.SERVE_KV_IMPORT, {"scatter"}),  # eager updates of the pools' leaves, as the copy is
         (hot.SERVE_COW_COPY, {"scatter"}),
     ],
@@ -193,7 +196,7 @@ def test_span_attributes(engine_line, name, attrs):
 def test_a_compiled_call_lies_inside_the_span_that_names_it(traced_drive, name, calls):
     """What a reader links a program run by: the runtime's own ``PjitFunction(...)``
     event of each compiled call, on the calling thread's line, inside the span
-    (rounds, steps, the block import, the forced copy-on-write)."""
+    (steps with and without a chunk, the block import, the forced copy-on-write)."""
     found = 0
     for line in traced_drive["lines"]:
         made = [(ev[0][len("PjitFunction("):-1], ev[1]) for ev in line if ev[0].startswith("PjitFunction(")]
@@ -208,73 +211,98 @@ def test_a_compiled_call_lies_inside_the_span_that_names_it(traced_drive, name, 
 def test_running_count_is_the_sum_of_its_span_attribute(traced_drive, serve_events, count):
     """What ``stats()`` counts over a traced run is what the spans of that run add up to."""
     grown = traced_drive["after"][count] - traced_drive["before"][count]
-    assert grown == RUNNING_COUNTS[count](serve_events) and (grown > 0 or count == "prefill_programs_built")
+    assert grown == RUNNING_COUNTS[count](serve_events) and grown > 0
 
 
 def test_decode_tokens_are_counted_once_a_step_and_add_up_as_before(traced_drive):
-    """``_commit_step`` adds a step's tokens to ``SERVE_TOKENS`` in one call: over
-    ``_drive`` that is three requests' four decoded tokens each and the moved
-    request's three, as when it was once a slot."""
+    """``_commit_step`` counts a prompt's first token once as ``prefill``, where
+    the step that carried its last chunk is committed, and a step's other tokens
+    in one call as ``decode``: over ``_drive`` that is four first tokens (three
+    requests' and the ``prefill_only`` one's), three requests' four decoded
+    tokens each and the moved request's three, as when a round fetched ``first``."""
     assert traced_drive["tokens"] == {"prefill": 4.0, "decode": 15.0}
     assert traced_drive["after"]["tokens_out"] - traced_drive["before"]["tokens_out"] == 19
 
 
-# -- an admission round counted where it happens: a hand-built queue, the loop turned by hand ---------------
+def test_every_mixed_program_is_called_inside_a_turn_that_says_what_rides_it(traced_drive, engine_line):
+    """``_decode_chunk`` inside the ``.dispatch`` of the turns with ``chunk_tokens``
+    and of no other, ``_decode`` inside the others': a reader tells the two
+    programs' turns apart by the span alone."""
+    (line,) = [ln for ln in traced_drive["lines"] if any(ev[0] == hot.SERVE_DECODE for ev in ln)]
+    calls = [(ev[0][len("PjitFunction("):-1], ev[1]) for ev in line if ev[0].startswith("PjitFunction(_decode")]
+    turns = [ev for ev in engine_line if ev[0] == hot.SERVE_DECODE]
+    called = [{fn for fn, t in calls if turn[1] <= t < turn[2]} for turn in turns]  # a call shows at two levels: a set
+    assert all(len(fns) <= 1 for fns in called)  # one program a turn, or none where the turn only fetched
+    for turn, fns in zip(turns, called):
+        assert (turn[3]["chunk_tokens"] > 0) == (fns == {"_decode_chunk"}), (fns, turn[3])
+    assert called.count({"_decode_chunk"}) == len(_chunk_turns(engine_line)) == 4 and {"_decode"} in called
+
+
+# -- a prompt counted where it is fed: a hand-built queue, the loop turned by hand ---------------------------
 
 
 @pytest.fixture(scope="module")
 def hand_driven(tiny, tmp_path_factory):
-    """Four slots, rounds of at most two rows, a clock the test sets: two long
-    requests take two slots; then three more arrive for the two that are free."""
+    """Four slots, at most two requests mid-prompt, chunks of 16, a clock the
+    test sets: a short and a long request arrive, then three more."""
     cfg, params = tiny
     now = [1.0]
-    engine = ServeEngine(params, cfg, max_slots=4, block_size=16, max_prefill_batch=2, clock=lambda: now[0])
+    engine = ServeEngine(
+        params, cfg, max_slots=4, block_size=16, max_prefill_batch=2, chunk_width=16, clock=lambda: now[0]
+    )
     ask = lambda n, new: engine.submit(ServeRequest(prompt=list(range(1, n + 1)), max_new_tokens=new))  # noqa: E731
     out = {"engine": engine}
 
-    def body():
-        first = [ask(3, 2), ask(4, 50)]
-        assert engine._admit()
-        now[0] = 2.0
-        third = ask(5, 50)
-        now[0] = 2.5
-        late = [ask(6, 50), ask(7, 50)]
-        now[0] = 3.0
-        assert engine._admit() and not engine._admit()  # two rows into the two free slots; then none is free
-        while not first[0].done.is_set():
-            assert engine._decode_once()
-        now[0] = 5.0
-        assert engine._admit()
-        assert engine._preempt_youngest()  # the last one in: back to the head of the queue
-        now[0] = 7.0
-        assert engine._admit()
-        assert third.t_first == 3.0 and late[1].t_first == 5.0  # kept from the first admission
+    def turn(at):
+        now[0] = at
+        admitted = engine._admit()
+        assert engine._decode_once()
+        return admitted
 
-    lines = _session(tmp_path_factory.mktemp("rounds"), body)
-    out["rounds"] = [ev[3] for ln in lines for ev in ln if ev[0] == hot.SERVE_ADMIT and "rows" in ev[3]]
+    def body():
+        first = [ask(3, 2), ask(40, 50)]
+        assert turn(2.0)  # both get a slot; the step carries the short prompt, whole
+        late = [ask(5, 50), ask(6, 50), ask(7, 50)]
+        assert turn(3.0)  # one is mid-prompt, so one more may be: a slot for the first of the three
+        assert first[0].t_first == 3.0 and not first[1].t_first  # its first token came with that step's fetch
+        assert not turn(4.0) and not turn(5.0)  # two mid-prompt: nobody is admitted, though a slot came free
+        assert first[0].done.is_set() and not first[1].t_first  # the long prompt's last chunk is in flight
+        assert turn(6.0) and turn(7.0)  # the long prompt is fed: the last two come in one by one
+        assert first[1].t_first == 6.0
+        assert engine._preempt_youngest()  # the last one in, its prompt not yet fed: back to the head of the queue
+        assert turn(8.0) and not turn(9.0)
+        assert late[2].t_first == 9.0 and engine.stats()["queue_depth"] == 0
+
+    lines = _session(tmp_path_factory.mktemp("chunks"), body)
+    out["turns"] = [ev[3] for ln in lines for ev in ln if ev[0] == hot.SERVE_DECODE]
+    out["admits"] = [ev[3] for ln in lines for ev in ln if ev[0] == hot.SERVE_ADMIT and "admitted" in ev[3]]
     return out
 
 
 @pytest.mark.parametrize(
-    "round_no,expected",
+    "turn_no,expected",
     [
-        (0, {"rows": 2, "rows_padded": 2, "slots_stalled": 0, "built": 1, "tokens": 7, "queue_depth": 0}),
-        (1, {"rows": 2, "rows_padded": 2, "slots_stalled": 2, "built": 0, "tokens": 11, "queue_depth": 1}),
-        (2, {"rows": 1, "rows_padded": 1, "slots_stalled": 3, "built": 1, "tokens": 7, "queue_depth": 0}),
-        # the preempted request comes back alone, into the slot it left
-        (3, {"rows": 1, "rows_padded": 1, "slots_stalled": 3, "built": 0, "queue_depth": 0}),
+        (0, {"chunk_tokens": 3, "active": 1}),  # the short prompt ends in this step: its slot has a token coming
+        (1, {"chunk_tokens": 16, "active": 1}),  # the long one's first chunk, beside the short request's second token
+        (2, {"chunk_tokens": 16, "active": 0}),  # whose budget is then spent: the chunk rides alone
+        (3, {"chunk_tokens": 8, "active": 1}),  # its last: 40 = 16 + 16 + 8
+        (4, {"chunk_tokens": 5, "active": 2}),  # oldest first: the third request's prompt, beside the long one's decode row
+        (5, {"chunk_tokens": 6, "active": 3}),
+        (6, {"chunk_tokens": 7, "active": 4}),  # given a slot again after its preemption
+        (7, {"chunk_tokens": 0, "active": 4}),  # nothing left to feed: a pure decode step
     ],
 )
-def test_round_attributes_against_a_hand_built_queue(hand_driven, round_no, expected):
-    got = hand_driven["rounds"][round_no]
-    assert {k: got[k] for k in expected} == expected and got["width"] == 16
+def test_chunk_attributes_against_a_hand_built_queue(hand_driven, turn_no, expected):
+    got = hand_driven["turns"][turn_no]
+    assert {k: got[k] for k in expected} == expected and got["chunk_width"] == 16
 
 
-def test_stats_sum_the_rounds_of_a_hand_built_queue(hand_driven):
-    rounds, counts = hand_driven["rounds"], hand_driven["engine"].stats()
-    assert counts["prefill_rounds"] == 4 and counts["slot_steps_stalled"] == 0 + 2 + 3 + 3
-    assert counts["prefill_tokens"] == sum(r["tokens"] for r in rounds)
-    assert counts["prefill_padded_tokens"] == 16 * (2 + 2 + 1 + 1) and counts["prefill_programs_built"] == 2
+def test_stats_sum_the_chunks_of_a_hand_built_queue(hand_driven):
+    turns, counts = hand_driven["turns"], hand_driven["engine"].stats()
+    assert [a["admitted"] for a in hand_driven["admits"]] == [2, 1, 1, 1, 1]
+    assert counts["chunk_steps"] == sum(t["chunk_tokens"] > 0 for t in turns) == 7
+    assert counts["prefill_tokens"] == sum(t["chunk_tokens"] for t in turns) == 3 + 40 + 5 + 6 + 7
+    assert counts["prefill_padded_tokens"] == 16 * 7 and counts["preemptions"] == 1
 
 
 def test_without_a_session_the_engine_leaves_no_record(tiny, tmp_path, monkeypatch):
@@ -303,9 +331,8 @@ def test_without_a_session_the_engine_leaves_no_record(tiny, tmp_path, monkeypat
     finally:
         engine.stop()
     # ... and the running counts count all the same
-    assert counts["prefill_rounds"] >= 4 and counts["prefill_tokens"] >= 3 * 3 + 19 + 2 * 3
-    assert counts["prefill_padded_tokens"] >= counts["prefill_tokens"] and counts["prefill_programs_built"] >= 2
-    assert counts["slot_steps_stalled"] >= 0
+    assert counts["chunk_steps"] >= 6 and counts["prefill_tokens"] >= 3 * 3 + 19 + 2 * 3
+    assert counts["prefill_padded_tokens"] == counts["chunk_steps"] * counts["chunk_width"] >= counts["prefill_tokens"]
     assert made == []
     assert not obs_dir.exists() or not [f for _, _, fs in os.walk(obs_dir) for f in fs]
 

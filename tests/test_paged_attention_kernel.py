@@ -419,7 +419,7 @@ def test_serving_programs_keep_the_pools_where_they_lie_on_the_chip(one_chip, ma
 # the loop is short enough to be unrolled (where ``loop_moves`` does not look).
 
 
-@pytest.mark.parametrize("make,slots,kernel", [
+CELL_STACKS = [
     # Mistral-7B's attention (d 4096, 32/8 heads of 128) and FFN, 3 layers: one scan over one kind
     pytest.param(lambda: llama.llama_tiny(
         vocab_size=32768, dim=4096, n_heads=32, n_kv_heads=8, ffn_dim=14336, n_layers=3, max_seq=256, dtype=jnp.bfloat16),
@@ -448,7 +448,18 @@ def test_serving_programs_keep_the_pools_where_they_lie_on_the_chip(one_chip, ma
         qk_rope_dim=64, v_head_dim=128, hc_mult=4, hc_sinkhorn_iters=20, norm_eps=1e-6, rope_theta=10000.0,
         rope_scaling=YarnScaling(factor=64.0, original_max_seq=4096, mscale_all_dim=1.0)), 64,
         "paged_mla_decode", id="latent-compressed-query-four-streams"),
-])  # fmt: skip
+]  # fmt: skip
+# Kimi-VL-A3B's language model: latent attention at d 2048, 16 heads of 128 + 64 behind an uncompressed query, one dense
+# layer ahead of the expert layers, 64 experts top-6 + 2 shared. Its wq and w_kvb are still sliced out of their stacks
+# (ROADMAP queue 1 item 6), so it is not among the stacks whose projections are held to "where they lie"
+KIMI_STACK = pytest.param(lambda: moe.moe_tiny(
+    vocab_size=16384, dim=2048, n_heads=16, n_kv_heads=16, n_layers=4, max_seq=256, dtype=jnp.bfloat16, ffn_dim=11264,
+    n_experts=64, top_k=6, expert_ffn_dim=1408, n_shared_experts=2, router_score="sigmoid", router_bias=True,
+    routed_scale=2.446, n_dense_layers=1, capacity_factor=0.0, kv_lora_rank=512, qk_nope_dim=128, qk_rope_dim=64,
+    v_head_dim=128), 64, "paged_mla_decode", id="latent-uncompressed-query")  # fmt: skip
+
+
+@pytest.mark.parametrize("make,slots,kernel", CELL_STACKS)
 def test_decode_program_multiplies_the_projections_where_they_lie_on_the_chip(one_chip, make, slots, kernel, monkeypatch):
     from torchx_tpu.obs.hlo import program_moves
 
@@ -495,3 +506,59 @@ def test_grouped_matmul_compiles_for_the_chip(one_chip, m, k, n, held, spread):
         shape((m, k), jnp.bfloat16), shape((held, k, n), jnp.bfloat16), shape((held,), jnp.int32)
     ).compile()  # fmt: skip
     assert "tpu_custom_call" in compiled.as_text()
+
+
+# The step that carries a chunk of a prompt (PR 40), at the five serving cells' widths and slots (their layers
+# cut to a few: a scan's temporaries do not grow with its length), compiled for the chip: the slots' rows still
+# go through the decode kernel, the pools still ride the carry, the experts' and the FFN's stacks are read where
+# they lie, and what the program needs beside its arguments fits beside the cell's weights and pools.
+
+#: a serving cell's weights and pools as its programs take them, GiB of the chip's 15.75 (rehearsal, PR 40:
+#: ``scripts/rehearse_serve_cell.py``, ``args`` of the decode step and of the step carrying a chunk alike)
+CELL_ARGS_GIB = {
+    "dense-gqa-one-scan": 9.00,  # mistral7b-serve-chat
+    "mixtral-shaped": 11.55,
+    "window-and-full-unrolled": 12.60,  # k-exaone-serve-decode-long
+    "latent-compressed-query-four-streams": 13.13,  # xing4-serve-decode-long, at its 128 slots below
+    "latent-uncompressed-query": 11.57,  # kimi-vl-a3b-serve-backlog
+}
+
+
+@pytest.mark.parametrize("make,slots,kernel", [*CELL_STACKS, KIMI_STACK])
+def test_the_step_that_carries_a_chunk_compiles_for_the_chip(one_chip, make, slots, kernel, monkeypatch, request):
+    from torchx_tpu.obs.hlo import loop_moves
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    monkeypatch.setattr(attn_ops, "TRACED", {})
+    cfg = make()
+    cell = request.node.callspec.id
+    slots = 128 if cfg.hc_mult else slots  # the cell's own: the projections' test above stays under one weight's size
+    width, bs, bpr = 256, 16, cfg.max_seq // 16
+    on_chip = lambda tree: jax.tree.map(lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip), tree)  # noqa: E731
+    shape = lambda s, d=jnp.int32: jax.ShapeDtypeStruct(s, d, sharding=one_chip)  # noqa: E731
+    params = on_chip(jax.eval_shape(lambda: llama.model_fns(cfg)[0](cfg, jax.random.PRNGKey(0))))
+    # the pools at the cells' block counts (half of 4,096 positions a slot), so that "a layer's pool" is the size it is there
+    pools = on_chip(jax.eval_shape(lambda: gen.init_kv_pools(cfg, 1 + slots * 128, bs, 1 + slots * 10 + 2 * 256)))
+
+    def tables(n, window_width):
+        return {"full": shape((n, bpr)), "window": shape((n, window_width))} if cfg.layer_types else shape((n, bpr))
+
+    def fn(p, tok, pos, tab, chunk, start, n, chunk_tab, pl, keys, temps):
+        return gen.paged_decode_chunk_step(p, tok, pos, tab, chunk, start, n, chunk_tab, pl, cfg, keys, temps)
+
+    compiled = jax.jit(fn, donate_argnums=(8,)).lower(
+        params, shape((slots,)), shape((slots,)), tables(slots, 10), shape((width,)), shape(()), shape(()),
+        tables(1, bpr), pools, shape((slots + 1, 2), jnp.uint32), shape((slots + 1,), jnp.float32),
+    ).compile()  # fmt: skip
+    text = compiled.as_text()
+    assert attn_ops.traced("kv_pools") == "carried"
+    assert "tpu_custom_call" in text and kernel in text  # the slots' rows: the decode kernel, handed the stack
+    layer_bytes = min(p.size // p.shape[0] * p.dtype.itemsize for p in jax.tree.leaves(pools))
+    # the stacks a step's bytes are made of: a layer's experts or its FFN (a latent layer's w_uk / w_uv, 4 MiB each,
+    # are still written out in front of the chunk's expanded keys and values: ROADMAP queue 1 item 6)
+    heavy = [params[g][w] for g in llama.layer_groups(params) for w in ("w_gate", "w_up", "w_down") if w in params[g]]
+    stack_bytes = min(w.size // w.shape[0] * w.dtype.itemsize for w in heavy)
+    assert loop_moves(text, min(layer_bytes, stack_bytes)) == []
+    # what the program needs beside its arguments, at this width of rows whatever the depth: within the cell's HBM
+    assert compiled.memory_analysis().temp_size_in_bytes <= (15.75 - CELL_ARGS_GIB[cell]) * 2**30
+

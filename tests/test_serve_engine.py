@@ -338,20 +338,26 @@ class _Unfetched:
 
 
 def spy_on_decode(eng, at_dispatch=None, fail_fetch_of=None):
-    """Record ``("dispatch", n, host tokens)`` and ``("fetch", n)`` in the
-    order the engine's thread makes them; ``at_dispatch(n)`` runs on that
-    thread before step ``n`` is enqueued."""
-    log, real = [], eng._decode
+    """Record ``("dispatch", n, host tokens, chunk)`` and ``("fetch", n)`` in
+    the order the engine's thread makes them, whichever of its two programs
+    step ``n`` is; ``chunk`` is ``(start, real tokens, slot or -1)`` of the
+    chunk a step carries, None on a pure decode step. ``at_dispatch(n)`` runs on
+    that thread before step ``n`` is enqueued."""
+    log = []
 
-    def decode(params, tokens, prev, *rest):
-        n = sum(ev[0] == "dispatch" for ev in log)
-        if at_dispatch is not None:
-            at_dispatch(n)
-        log.append(("dispatch", n, np.asarray(tokens).copy()))
-        nxt, pools = real(params, tokens, getattr(prev, "value", prev), *rest)
-        return _Unfetched(n, nxt, log, fail=n == fail_fetch_of), pools
+    def spied(real):
+        def program(params, tokens, prev, *rest):
+            n = sum(ev[0] == "dispatch" for ev in log)
+            if at_dispatch is not None:
+                at_dispatch(n)
+            chunk = tuple(int(x) for x in np.asarray(rest[-2])) if len(rest) > 5 else None
+            log.append(("dispatch", n, np.asarray(tokens).copy(), chunk))
+            nxt, pools = real(params, tokens, getattr(prev, "value", prev), *rest)
+            return _Unfetched(n, nxt, log, fail=n == fail_fetch_of), pools
 
-    eng._decode = decode
+        return program
+
+    eng._decode, eng._decode_chunk = spied(eng._decode), spied(eng._decode_chunk)
     return log
 
 
@@ -397,22 +403,26 @@ class TestStepInFlight:
         assert r.tokens == dense_generate(params, cfg, [1, 2, 3], 12)
         order = [ev[:2] for ev in log]
         steps = eng.stats()["steps"]
-        assert steps == 11  # the first token is prefill's
+        assert steps == 12  # the first token is the step's that carried the prompt
         for n in range(steps - 1):
             assert order.index(("dispatch", n + 1)) < order.index(("fetch", n))
         assert order[-1] == ("fetch", steps - 1)  # the last step: nothing to enqueue, still fetched
         assert eng.stats()["steps_overlapped"] == steps - 1
         assert eng.stats()["tokens_discarded"] == 0
-        # only the first step reads the host's token; every later one the device's
-        assert [int(ev[2][0]) for ev in log if ev[0] == "dispatch"] == [r.generated[0]] + [-1] * (steps - 1)
+        dispatches = [ev for ev in log if ev[0] == "dispatch"]
+        # the first step carries the whole prompt and ends it in slot 0; no step reads a token of the
+        # host's: the first token too is read where the step before left it, on the device
+        assert [ev[3] for ev in dispatches] == [(0, 3, 0)] + [None] * (steps - 1)
+        assert [int(ev[2][0]) for ev in dispatches] == [0] + [-1] * (steps - 1)
 
     @pytest.mark.parametrize("temperature", [0.0, 0.9], ids=["greedy", "sampled"])
     def test_arrivals_while_a_step_is_in_flight(self, tiny, temperature):
-        """A slot admitted while a step is in flight reads the host's token
-        in its first step, its neighbours the device's, in one program."""
+        """A prompt that arrives while others decode rides their steps, a
+        step in flight across each of its chunks, and its slot's first step
+        reads its first token on the device as its neighbours read theirs."""
         cfg, params = tiny
-        eng = ServeEngine(params, cfg, max_slots=4, block_size=8, max_prefill_batch=2)
-        prompts = [[1, 2, 3], [7, 8], [4, 5, 6, 7, 8, 9, 10, 11, 12], [11], [3, 1]]
+        eng = ServeEngine(params, cfg, max_slots=4, block_size=8, max_prefill_batch=2, chunk_width=8)
+        prompts = [[1, 2, 3], [7, 8], list(range(4, 23)), [11], [3, 1]]
         lengths = [14, 6, 9, 12, 5]
         reqs = [
             ServeRequest(prompt=p, max_new_tokens=n, temperature=temperature, seed=40 + i)
@@ -433,8 +443,17 @@ class TestStepInFlight:
             assert r.tokens == paged_generate(
                 params, cfg, list(r.prompt), r.max_new_tokens, temperature, r.seed
             )
-        mixed = [ev[2] for ev in log if ev[0] == "dispatch" and (ev[2] == -1).any() and (ev[2] > 0).any()]
-        assert len(mixed) >= 3  # each arrival's first step sat beside slots already stepping
+        dispatches = [ev for ev in log if ev[0] == "dispatch"]
+        riding = [ev for ev in dispatches if ev[3] is not None and (ev[2] == -1).any()]
+        assert len(riding) >= 5  # every arrival's chunks sat beside slots already stepping
+        assert all((ev[2] <= 0).all() for ev in dispatches)  # no token goes through the host
+        # the 19-token prompt went in three chunks, one behind the other, each enqueued with the step before in flight
+        long_chunks = [ev for ev in dispatches if ev[3] is not None and ev[3][:2] in ((0, 8), (8, 8), (16, 3))]
+        assert [ev[3][2] >= 0 for ev in long_chunks] == [False, False, True]
+        assert [ev[1] for ev in long_chunks] == list(range(long_chunks[0][1], long_chunks[0][1] + 3))
+        order = [ev[:2] for ev in log]
+        for ev in long_chunks[1:]:
+            assert order.index(("dispatch", ev[1])) < order.index(("fetch", ev[1] - 1))
         assert eng.stats()["tokens_discarded"] == 0
 
     def test_a_slot_whose_last_token_is_in_flight_writes_no_row(self, tiny):
@@ -473,7 +492,8 @@ class TestStepInFlight:
             assert eng.drain(timeout=120) and eng.alloc.free_blocks == free
             # learnt a step late: the slot was stepped once more, that token dropped
             assert eng.stats()["tokens_discarded"] == 1
-            assert eng.stats()["steps"] == len(r.generated) + 15  # one more than the tokens decode gave
+            # one more than the tokens it gave: each request's first token is a step's too
+            assert eng.stats()["steps"] == len(r.generated) + 1 + 16
         finally:
             eng.stop()
 

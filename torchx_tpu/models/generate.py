@@ -13,7 +13,7 @@ forward), temperature>0 samples from the softmax.
 from __future__ import annotations
 
 import functools
-from typing import Optional
+from typing import Any, NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
@@ -462,41 +462,113 @@ def _feed_forward(cfg: llama.LlamaConfig, layer: llama.Params, stream_in: jnp.nd
     return llama.ffn(cfg, layer, mlp_in)
 
 
+class _Rows(NamedTuple):
+    """One part of the rows a paged step runs through the layer stack side by
+    side: a decode step's rows, one query a slot at ``positions [slots]``
+    (``valid`` None), or a chunk of consecutive prompt tokens a row of
+    ``tables``, ``positions [b, t]`` flattened to ``b * t`` rows, ``valid [b, t]``
+    saying which are real. The parts differ in how their rows reach the cache
+    and attend it, and in nothing else."""
+
+    cos: jnp.ndarray  # [rows, rope/2] rope rows at each row's position
+    sin: jnp.ndarray
+    positions: jnp.ndarray  # the cache index each row's token is written to
+    tables: Any  # [slots or b, blocks_per_slot] int32 block tables, or one a cache kind (_table_of)
+    valid: Optional[jnp.ndarray] = None
+
+    @property
+    def rows(self) -> int:
+        return self.cos.shape[0]
+
+    def cache(self, pool, tables, new, at, ring):  # noqa: ANN001, ANN201
+        """``new [rows, ...]`` into ``pool`` at the rows' positions."""
+        if self.valid is None:
+            return append_kv(pool, tables, self.positions, new, at, ring=ring)
+        return scatter_kv_chunk(pool, tables, self.positions, self._chunked(new), self.valid, at)
+
+    def _chunked(self, x: jnp.ndarray) -> jnp.ndarray:
+        return x.reshape(*self.positions.shape, *x.shape[1:])  # [rows, ...] -> [b, t, ...]
+
+    def attend(self, q, k_pool, v_pool, tables, at, window):  # noqa: ANN001, ANN201
+        """``q [rows, h, hd]`` over the K/V pools, the rows' own K/V already
+        in them -> ``[rows, h, hd]``."""
+        if self.valid is None:
+            return paged_attention(q, k_pool, v_pool, tables, self.positions + 1, at, window)
+        out = paged_attention_chunk(self._chunked(q), k_pool, v_pool, tables, self.positions, self.valid, at, window)
+        return out.reshape(self.rows, *out.shape[2:])
+
+    def attend_latent(self, cfg, layer, q_nope, q_rope, tables, pool):  # noqa: ANN001, ANN201
+        """The same over a latent pool -> ``[rows, h, v]``."""
+        if self.valid is None:
+            return mla.attend_decode(cfg, layer, q_nope, q_rope, self.positions, tables, pool)
+        out = mla.attend_chunk(
+            cfg, layer, self._chunked(q_nope), self._chunked(q_rope), self.positions, self.valid, tables, pool
+        )
+        return out.reshape(self.rows, *out.shape[2:])
+
+
+def _cat(xs: list[jnp.ndarray]) -> jnp.ndarray:
+    return xs[0] if len(xs) == 1 else jnp.concatenate(xs)
+
+
 def _paged_layer_step(
     cfg: llama.LlamaConfig,
-    cos: jnp.ndarray,  # [slots, hd/2] rope rows at each slot's position
+    parts: tuple[_Rows, ...],
+    cos: jnp.ndarray,  # [rows, rope/2]: the parts' rope rows one after another, joined once ahead of the layer loop
     sin: jnp.ndarray,
-    positions: jnp.ndarray,  # [slots] — cache index the new token writes to
-    tables,  # noqa: ANN001 — [slots, blocks_per_slot] int32, or one a cache kind (_table_of)
-    x: jnp.ndarray,  # [slots, 1, d]
+    x: jnp.ndarray,  # [rows, 1, d]: the parts' rows in the same order
     layer: llama.Params,
     # this layer's pool [num_blocks, bs, kvh, hd], or with layer["kind_index"]
     # (under _scan_groups) the stack of its cache kind [layers, num_blocks, ...]
     k_pool: jnp.ndarray,
     v_pool: jnp.ndarray,
 ) -> tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
-    slots = x.shape[0]
-    at = layer.get("kind_index")
+    """One layer over every part's rows at once: norms, projections, the
+    output projection, the feed-forward (every row a group of its own: a
+    capacity of one a group and expert never drops a routing) and the residual
+    mix run once over all rows, so a chunk that rides a decode step reads no
+    weight a second time. Between the projections each part writes its rows to
+    the pools and attends them its own way (:class:`_Rows`): all writes, then
+    all reads, the parts' blocks being disjoint but for the trash block."""
     window = llama.window_of(cfg, layer)
-    tables = _table_of(tables, layer)
+    bounds = np.cumsum([0] + [part.rows for part in parts])
+    split = lambda a: [a[lo:hi] for lo, hi in zip(bounds, bounds[1:])] if len(parts) > 1 else [a]  # noqa: E731
+    tables = [_table_of(part.tables, layer) for part in parts]
 
     def attend(stream_in):  # noqa: ANN001, ANN202
         with jax.named_scope(hot.NORM):
             attn_in = rms_norm(stream_in, layer["attn_norm"], cfg.norm_eps)
         with jax.named_scope(hot.ATTN), hot.attn_kind_scope(cfg, layer):
+            rows = attn_in[:, 0]  # [rows, d]
             if cfg.kv_lora_rank:  # one latent pool, handed through as k_pool
-                attn, pool = mla.paged_decode(cfg, layer, attn_in, cos, sin, positions, tables, k_pool)
-                return attn, (pool, v_pool)
-            h, kvh, hd = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
-            rows = attn_in[:, 0]  # [slots, d]
-            q = _project_heads(rows, layer["wq"], h, hd)
-            k = _project_heads(rows, layer["wk"], kvh, hd)
-            q, k = llama.norm_and_rotate(cfg, layer, q, k, cos, sin, _rope_rows)
-            v = _project_heads(rows, layer["wv"], kvh, hd)
-            k_new = append_kv(k_pool, tables, positions, k, at, ring=bool(window))
-            v_new = append_kv(v_pool, tables, positions, v, at, ring=bool(window))
-            attn = paged_attention(q, k_new, v_new, tables, positions + 1, at, window)
-            return mm(attn.reshape(slots, 1, h * hd), layer["wo"]), (k_new, v_new)
+                at = layer.get("layer_index")
+                q_nope, q_rope, cached = mla.project(cfg, layer, rows, cos, sin)
+                pool = k_pool
+                with jax.named_scope(hot.APPEND_LATENT):
+                    for part, table, new in zip(parts, tables, split(cached)):
+                        pool = part.cache(pool, table, new, at, False)
+                out = _cat([
+                    part.attend_latent(cfg, layer, qn, qr, table, pool)
+                    for part, table, qn, qr in zip(parts, tables, split(q_nope), split(q_rope))
+                ])  # fmt: skip
+                pools = (pool, v_pool)
+            else:
+                at = layer.get("kind_index")
+                h, kvh, hd = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
+                q = _project_heads(rows, layer["wq"], h, hd)
+                k = _project_heads(rows, layer["wk"], kvh, hd)
+                q, k = llama.norm_and_rotate(cfg, layer, q, k, cos, sin, _rope_rows)
+                v = _project_heads(rows, layer["wv"], kvh, hd)
+                k_new, v_new = k_pool, v_pool
+                for part, table, k_rows, v_rows in zip(parts, tables, split(k), split(v)):
+                    k_new = part.cache(k_new, table, k_rows, at, bool(window))
+                    v_new = part.cache(v_new, table, v_rows, at, bool(window))
+                out = _cat([
+                    part.attend(q_rows, k_new, v_new, table, at, window)
+                    for part, table, q_rows in zip(parts, tables, split(q))
+                ])  # fmt: skip
+                pools = (k_new, v_new)
+            return mm(out.reshape(out.shape[0], 1, -1), layer["wo"]), pools
 
     x, (k_pool, v_pool) = hyper.residual(cfg, layer, "attn", x, attend)
     x, _aux = hyper.residual(cfg, layer, "mlp", x, functools.partial(_feed_forward, cfg, layer))
@@ -553,6 +625,38 @@ def _lm_head_rows(params: llama.Params, x: jnp.ndarray, cfg: llama.LlamaConfig):
     return jnp.einsum("rd,dv->rv", x, head, preferred_element_type=jnp.float32)
 
 
+def _decode_rows(cfg: llama.LlamaConfig, positions: jnp.ndarray, tables) -> _Rows:  # noqa: ANN001
+    cos, sin = llama.rope_table(cfg, cfg.max_seq)
+    return _Rows(cos[positions], sin[positions], positions, tables)
+
+
+def _chunk_rows(cfg: llama.LlamaConfig, prefix_lens: jnp.ndarray, suffix_lens: jnp.ndarray, t: int, tables) -> _Rows:  # noqa: ANN001
+    """``t`` positions a row from ``prefix_lens [b]`` on, the first
+    ``suffix_lens [b]`` of them real."""
+    cos, sin = llama.rope_table(cfg, cfg.max_seq)
+    positions = prefix_lens[:, None] + jnp.arange(t, dtype=jnp.int32)[None, :]
+    pos_safe = jnp.clip(positions, 0, cfg.max_seq - 1).reshape(-1)  # padding may lie past the table
+    valid = jnp.arange(t)[None, :] < suffix_lens[:, None]
+    return _Rows(cos[pos_safe], sin[pos_safe], positions, tables, valid)
+
+
+def _paged_stream(params: llama.Params, tokens: jnp.ndarray, parts: tuple[_Rows, ...], pools: KVPools, cfg: llama.LlamaConfig):  # noqa: ANN202
+    """``tokens [rows]``, the parts' one after another, through the layer
+    stack -> (the stream ahead of the final norm ``[rows, d]``, the pools)."""
+    with jax.named_scope(hot.EMBED):
+        x = hyper.expand(cfg, params["embed"][tokens].astype(cfg.dtype)[:, None, :])  # [rows, 1, d]
+    cos, sin = _cat([part.cos for part in parts]), _cat([part.sin for part in parts])
+    x, pools = _scan_groups(functools.partial(_paged_layer_step, cfg, parts, cos, sin), x, params, pools, cfg)
+    return hyper.collapse(cfg, x)[:, 0, :], pools
+
+
+def _head_rows(params: llama.Params, x: jnp.ndarray, cfg: llama.LlamaConfig, keys: jnp.ndarray, temps: jnp.ndarray) -> jnp.ndarray:
+    """The stream at ``x [rows, d]`` -> a sampled token a row."""
+    with jax.named_scope(hot.NORM):
+        x = rms_norm(x, params["final_norm"], cfg.norm_eps)
+    return _sample_rows(_lm_head_rows(params, x, cfg), keys, temps)
+
+
 def paged_decode_step(
     params: llama.Params,
     tokens: jnp.ndarray,  # [slots] int32 — last sampled token per slot
@@ -578,20 +682,47 @@ def paged_decode_step(
     weights: every matmul takes its layer's slice of the parameter stack inside
     its own fusion, the attention projections included (``_project_heads``).
     """
-    slots = tokens.shape[0]
-    with jax.named_scope(hot.EMBED):
-        x = hyper.expand(cfg, params["embed"][tokens].astype(cfg.dtype)[:, None, :])  # [slots, 1, d]
-    cos_full, sin_full = llama.rope_table(cfg, cfg.max_seq)
-    cos, sin = cos_full[positions], sin_full[positions]  # [slots, rope/2]
-    x, pools = _scan_groups(
-        functools.partial(_paged_layer_step, cfg, cos, sin, positions, tables), x, params, pools, cfg
+    x, pools = _paged_stream(params, tokens, (_decode_rows(cfg, positions, tables),), pools, cfg)
+    return _head_rows(params, x, cfg, keys, temps), pools
+
+
+def paged_decode_chunk_step(
+    params: llama.Params,
+    tokens: jnp.ndarray,  # [slots] int32, as in paged_decode_step
+    positions: jnp.ndarray,  # [slots]
+    tables,  # noqa: ANN001 — the slots' block tables
+    chunk_tokens: jnp.ndarray,  # [width] int32: the next tokens of ONE prompt, right-padded
+    chunk_start: jnp.ndarray,  # scalar int32: tokens of that prompt already in the pool
+    chunk_len: jnp.ndarray,  # scalar int32: real tokens in the chunk (>= 1)
+    chunk_tables,  # noqa: ANN001 — [1, blocks_per_slot] that request's table, block b at entry b; one a cache kind where kinds mix
+    pools: KVPools,
+    cfg: llama.LlamaConfig,
+    keys: jnp.ndarray,  # [slots + 1, 2]: the slots' keys, then the chunk's last position's
+    temps: jnp.ndarray,  # [slots + 1]
+) -> tuple[jnp.ndarray, KVPools]:
+    """A decode step that carries a chunk of a prompt: :func:`paged_decode_step`
+    over the slots and :func:`paged_prefill_chunk` over one row of ``width``
+    positions, in one pass over the layer stack.
+
+    The slots' rows go through the decode attention and the chunk's through
+    the chunk attention (cached prefix + this chunk through the request's own
+    table: ``chunk_start`` is what a prefix hit's ``prefix_lens`` is), both
+    writing the pools the scan carries; everything else of a layer (norms,
+    projections, experts or MLP, the residual mix) runs once over ``slots +
+    width`` rows, so the chunk pays no weight read of its own. The slot that
+    holds the request whose prompt is being fed must not decode (its table row
+    all trash), the chunk's blocks belong to nobody else. The head runs over
+    ``slots + 1`` rows. -> (sampled ``[slots + 1]``: every slot's next token,
+    then the token after the chunk's last real position, which is the request's
+    first where the chunk ends its prompt; updated pools)."""
+    slots, width = tokens.shape[0], chunk_tokens.shape[0]
+    parts = (
+        _decode_rows(cfg, positions, tables),
+        _chunk_rows(cfg, chunk_start[None], chunk_len[None], width, chunk_tables),
     )
-    x = hyper.collapse(cfg, x)
-    with jax.named_scope(hot.NORM):
-        x = rms_norm(x, params["final_norm"], cfg.norm_eps)[:, 0, :]  # [slots, d]
-    logits = _lm_head_rows(params, x, cfg)
-    nxt = _sample_rows(logits, keys, temps)
-    return nxt, pools
+    x, pools = _paged_stream(params, jnp.concatenate((tokens, chunk_tokens)), parts, pools, cfg)
+    last = jax.lax.dynamic_slice_in_dim(x, slots + chunk_len - 1, 1)
+    return _head_rows(params, jnp.concatenate((x[:slots], last)), cfg, keys, temps), pools
 
 
 def paged_prefill(
@@ -630,56 +761,6 @@ def paged_prefill(
     return _sample_rows(last, keys, temps), pools
 
 
-def _rope_chunk(x: jnp.ndarray, cos: jnp.ndarray, sin: jnp.ndarray) -> jnp.ndarray:
-    """:func:`apply_rope` for a chunk of tokens at per-(row, token)
-    positions: ``x`` [b, t, heads, hd], ``cos``/``sin`` [b, t, hd/2] —
-    the same float32 rotation as :func:`_rope_rows`."""
-    dtype = x.dtype
-    x1, x2 = jnp.split(x.astype(jnp.float32), 2, axis=-1)
-    c = cos[:, :, None, :]
-    s = sin[:, :, None, :]
-    return jnp.concatenate((x1 * c - x2 * s, x2 * c + x1 * s), axis=-1).astype(dtype)
-
-
-def _paged_chunk_layer_step(
-    cfg: llama.LlamaConfig,
-    cos: jnp.ndarray,  # [b, t, hd/2] rope rows at each token's position
-    sin: jnp.ndarray,
-    positions: jnp.ndarray,  # [b, t] absolute cache positions
-    valid: jnp.ndarray,  # [b, t] bool — real suffix tokens
-    tables,  # noqa: ANN001 — [b, blocks_per_slot] int32, or one a cache kind (_table_of); block b at entry b in each
-    x: jnp.ndarray,  # [b, t, d]
-    layer: llama.Params,
-    k_pool: jnp.ndarray,  # one layer's pool or its cache kind's stack, as in _paged_layer_step
-    v_pool: jnp.ndarray,
-) -> tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
-    b, t = x.shape[:2]
-    at = layer.get("kind_index")
-    window = llama.window_of(cfg, layer)
-    tables = _table_of(tables, layer)
-
-    def attend(stream_in):  # noqa: ANN001, ANN202
-        with jax.named_scope(hot.NORM):
-            attn_in = rms_norm(stream_in, layer["attn_norm"], cfg.norm_eps)
-        with jax.named_scope(hot.ATTN), hot.attn_kind_scope(cfg, layer):
-            if cfg.kv_lora_rank:  # one latent pool, handed through as k_pool
-                attn, pool = mla.paged_prefill(cfg, layer, attn_in, cos, sin, positions, valid, tables, k_pool)
-                return attn, (pool, v_pool)
-            h, kvh, hd = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
-            q = _project_heads(attn_in, layer["wq"], h, hd)
-            k = _project_heads(attn_in, layer["wk"], kvh, hd)
-            q, k = llama.norm_and_rotate(cfg, layer, q, k, cos, sin, _rope_chunk)
-            v = _project_heads(attn_in, layer["wv"], kvh, hd)
-            k_new = scatter_kv_chunk(k_pool, tables, positions, k, valid, at)
-            v_new = scatter_kv_chunk(v_pool, tables, positions, v, valid, at)
-            attn = paged_attention_chunk(q, k_new, v_new, tables, positions, valid, at, window)
-            return mm(attn.reshape(b, t, h * hd), layer["wo"]), (k_new, v_new)
-
-    x, (k_pool, v_pool) = hyper.residual(cfg, layer, "attn", x, attend)
-    x, _aux = hyper.residual(cfg, layer, "mlp", x, functools.partial(_feed_forward, cfg, layer))
-    return x, k_pool, v_pool
-
-
 def paged_prefill_chunk(
     params: llama.Params,
     tokens: jnp.ndarray,  # [b, t] int32 suffix tokens, right-padded
@@ -707,20 +788,7 @@ def paged_prefill_chunk(
     -> (first token [b], updated pools).
     """
     b, t = tokens.shape
-    with jax.named_scope(hot.EMBED):
-        x = hyper.expand(cfg, params["embed"][tokens].astype(cfg.dtype))  # [b, t, d]
-    cos_full, sin_full = llama.rope_table(cfg, cfg.max_seq)
-    positions = prefix_lens[:, None] + jnp.arange(t, dtype=jnp.int32)[None, :]
-    pos_safe = jnp.clip(positions, 0, cfg.max_seq - 1)
-    cos, sin = cos_full[pos_safe], sin_full[pos_safe]  # [b, t, rope/2]
-    valid = jnp.arange(t)[None, :] < suffix_lens[:, None]
-    x, pools = _scan_groups(
-        functools.partial(_paged_chunk_layer_step, cfg, cos, sin, positions, valid, tables),
-        x, params, pools, cfg,
-    )  # fmt: skip
-    x = hyper.collapse(cfg, x)
-    with jax.named_scope(hot.NORM):
-        x = rms_norm(x, params["final_norm"], cfg.norm_eps)  # [b, t, d]
-    last = x[jnp.arange(b), suffix_lens - 1]  # [b, d]
-    logits = _lm_head_rows(params, last, cfg)
-    return _sample_rows(logits, keys, temps), pools
+    parts = (_chunk_rows(cfg, prefix_lens, suffix_lens, t, tables),)
+    x, pools = _paged_stream(params, tokens.reshape(b * t), parts, pools, cfg)
+    last = x.reshape(b, t, -1)[jnp.arange(b), suffix_lens - 1]  # [b, d]
+    return _head_rows(params, last, cfg, keys, temps), pools

@@ -31,8 +31,8 @@ its read-in) and writes the stream once a layer, as the scan's carry; the whole
 path is 0.2 of a 21.6 ms step at 128 rows (my chip run, PR 33).
 
 :func:`residual` is what every layer step calls around each of its two
-sublayers (``llama._layer``, ``generate._layer_step``, ``_paged_layer_step``,
-``_paged_chunk_layer_step``); with ``hc_mult = 0`` it is the plain add and
+sublayers (``llama._layer``, ``generate._layer_step``, ``_paged_layer_step``);
+with ``hc_mult = 0`` it is the plain add and
 traces nothing else. ``ops.attention.traced("residual")`` answers ``hyper`` or
 ``add``.
 """

@@ -107,33 +107,25 @@ def attention_full(cfg, layer, u: jnp.ndarray, cos: jnp.ndarray, sin: jnp.ndarra
     return mm(out.reshape(b, s, cfg.n_heads * cfg.v_head_dim), layer["wo"])
 
 
-def paged_prefill(
+def attend_chunk(
     cfg,  # noqa: ANN001
     layer,  # noqa: ANN001
-    u: jnp.ndarray,  # [b, t, d] normed chunk
-    cos: jnp.ndarray,  # [b, t, rope/2]
-    sin: jnp.ndarray,
+    q_nope: jnp.ndarray,  # [b, t, h, nope]
+    q_rope: jnp.ndarray,  # [b, t, h, rope], rotated
     positions: jnp.ndarray,  # [b, t] absolute cache positions
     valid: jnp.ndarray,  # [b, t] bool: real suffix tokens
     tables: jnp.ndarray,  # [b, blocks_per_slot]
-    pool: jnp.ndarray,  # [num_blocks, bs, cache_width] this layer's latent pool
-) -> tuple[jnp.ndarray, jnp.ndarray]:
-    """Scatter the chunk's latent rows into the pool, then attend each chunk
-    token causally over cached prefix + chunk, K and V expanded from the
-    latent rows. ``_PREFILL_Q_ROWS`` query rows at a time walk the window
+    pool: jnp.ndarray,  # the latent pool, the chunk's rows already in it
+) -> jnp.ndarray:
+    """Each chunk token causally over cached prefix + chunk, K and V expanded
+    from the latent rows. ``_PREFILL_Q_ROWS`` query rows at a time walk the window
     ``_PREFILL_K_ROWS`` cached rows a step, as far as the block's last real
     token reaches and no further (an online softmax: running maximum, sum and
-    accumulator in float32), so a round costs what its rows hold and not
-    ``max_seq``. Under a layer scan (``llama.scan_layers``) ``pool`` is the
-    group's whole stack ``[layers, num_blocks, ...]``, written and gathered at
-    ``layer["layer_index"]`` where it lies. -> (attention output ``[b, t,
-    d]``, the pool)."""
-    b, t, _ = u.shape
-    h, dv = cfg.n_heads, cfg.v_head_dim
+    accumulator in float32), so a chunk costs what its rows hold and not
+    ``max_seq``. -> ``[b, t, h, v]``, ahead of ``W_o``."""
+    b, t, h, _ = q_nope.shape
+    dv = cfg.v_head_dim
     pool_layer = layer.get("layer_index")
-    q_nope, q_rope, cached = project(cfg, layer, u, cos, sin)
-    with jax.named_scope(hot.APPEND_LATENT):
-        pool = scatter_kv_chunk(pool, tables, positions, cached, valid, pool_layer)
     note_traced("attention", "paged_mla_xla")
     with jax.named_scope(hot.PAGED_ATTENTION):
         q = jnp.concatenate((q_nope, q_rope), axis=-1)  # [b, t, h, nope + rope]
@@ -179,12 +171,61 @@ def paged_prefill(
 
         n = max(1, t // _PREFILL_Q_ROWS)
         if n == 1:
-            out = rows(q, positions, valid)
-        else:
-            split = lambda x: jnp.moveaxis(x.reshape(b, n, t // n, *x.shape[2:]), 1, 0)  # noqa: E731
-            out = jax.lax.map(lambda a: rows(*a), (split(q), split(positions), split(valid)))
-            out = jnp.moveaxis(out, 0, 1).reshape(b, t, h, dv)
-    return mm(out.reshape(b, t, h * dv), layer["wo"]), pool
+            return rows(q, positions, valid)
+        split = lambda x: jnp.moveaxis(x.reshape(b, n, t // n, *x.shape[2:]), 1, 0)  # noqa: E731
+        out = jax.lax.map(lambda a: rows(*a), (split(q), split(positions), split(valid)))
+        return jnp.moveaxis(out, 0, 1).reshape(b, t, h, dv)
+
+
+def attend_decode(
+    cfg,  # noqa: ANN001
+    layer,  # noqa: ANN001
+    q_nope: jnp.ndarray,  # [slots, h, nope]
+    q_rope: jnp.ndarray,  # [slots, h, rope], rotated
+    positions: jnp.ndarray,  # [slots] where each slot's newest row lies
+    tables: jnp.ndarray,  # [slots, blocks_per_slot]
+    pool: jnp.ndarray,  # the latent pool, the slots' rows already in it
+) -> jnp.ndarray:
+    """One token a slot, absorbed, over the latent rows its blocks hold.
+    -> ``[slots, h, v]``, ahead of ``W_o``."""
+    slots, h = q_nope.shape[:2]
+    r = cfg.kv_lora_rank
+    pool_layer = layer.get("layer_index")
+    (w_k, k_axes), (w_v, v_axes) = _head_blocks(cfg, layer)
+    with jax.named_scope(hot.MLA_ABSORB):
+        q_lat = jnp.einsum(f"shn,{k_axes}->shr", q_nope, w_k)
+    q_pad = jnp.zeros((slots, h, pool.shape[-1] - r - cfg.qk_rope_dim), q_lat.dtype)
+    o_lat = paged_mla_attention(
+        jnp.concatenate((q_lat, q_rope, q_pad), axis=-1), pool, tables, positions + 1, r, cfg.attn_scale, pool_layer,
+    )  # [slots, h, rank]
+    with jax.named_scope(hot.MLA_ABSORB):
+        return jnp.einsum(f"shr,{v_axes}->shv", o_lat, w_v)
+
+
+def paged_prefill(
+    cfg,  # noqa: ANN001
+    layer,  # noqa: ANN001
+    u: jnp.ndarray,  # [b, t, d] normed chunk
+    cos: jnp.ndarray,  # [b, t, rope/2]
+    sin: jnp.ndarray,
+    positions: jnp.ndarray,  # [b, t] absolute cache positions
+    valid: jnp.ndarray,  # [b, t] bool: real suffix tokens
+    tables: jnp.ndarray,  # [b, blocks_per_slot]
+    pool: jnp.ndarray,  # [num_blocks, bs, cache_width] this layer's latent pool
+) -> tuple[jnp.ndarray, jnp.ndarray]:
+    """Scatter the chunk's latent rows into the pool, then attend each chunk
+    token causally over cached prefix + chunk (:func:`attend_chunk`). Under a layer scan (``llama.scan_layers``) ``pool``
+    is the group's whole stack ``[layers, num_blocks, ...]``, written and
+    gathered at ``layer["layer_index"]`` where it lies. The serving programs
+    (``generate._paged_layer_step``) call the three pieces themselves, so that a
+    step's decode rows and a chunk share one projection; this is the layer
+    alone, as the tests hold it. -> (attention output ``[b, t, d]``, the pool)."""
+    b, t, _ = u.shape
+    q_nope, q_rope, cached = project(cfg, layer, u, cos, sin)
+    with jax.named_scope(hot.APPEND_LATENT):
+        pool = scatter_kv_chunk(pool, tables, positions, cached, valid, layer.get("layer_index"))
+    out = attend_chunk(cfg, layer, q_nope, q_rope, positions, valid, tables, pool)
+    return mm(out.reshape(b, t, cfg.n_heads * cfg.v_head_dim), layer["wo"]), pool
 
 
 def paged_decode(
@@ -198,25 +239,15 @@ def paged_decode(
     pool: jnp.ndarray,  # [num_blocks, bs, cache_width]
 ) -> tuple[jnp.ndarray, jnp.ndarray]:
     """Append each slot's latent row, then attend absorbed over the latent
-    rows its blocks hold; ``pool`` is one layer's, or under a layer scan the
-    group's stack, as in :func:`paged_prefill`. -> (attention output ``[slots,
-    1, d]``, the pool)."""
+    rows its blocks hold (:func:`attend_decode`); ``pool`` is one layer's, or
+    under a layer scan the group's stack, as in :func:`paged_prefill`. ->
+    (attention output ``[slots, 1, d]``, the pool)."""
     slots = u.shape[0]
-    h, dn, dv, r = cfg.n_heads, cfg.qk_nope_dim, cfg.v_head_dim, cfg.kv_lora_rank
-    pool_layer = layer.get("layer_index")
     q_nope, q_rope, cached = project(cfg, layer, u[:, 0], cos, sin)
     with jax.named_scope(hot.APPEND_LATENT):
-        pool = append_kv(pool, tables, positions, cached, pool_layer)
-    (w_k, k_axes), (w_v, v_axes) = _head_blocks(cfg, layer)
-    with jax.named_scope(hot.MLA_ABSORB):
-        q_lat = jnp.einsum(f"shn,{k_axes}->shr", q_nope, w_k)
-    q_pad = jnp.zeros((slots, h, cached.shape[-1] - r - cfg.qk_rope_dim), q_lat.dtype)
-    o_lat = paged_mla_attention(
-        jnp.concatenate((q_lat, q_rope, q_pad), axis=-1), pool, tables, positions + 1, r, cfg.attn_scale, pool_layer,
-    )  # [slots, h, rank]
-    with jax.named_scope(hot.MLA_ABSORB):
-        out = jnp.einsum(f"shr,{v_axes}->shv", o_lat, w_v)
-    return mm(out.reshape(slots, 1, h * dv), layer["wo"]), pool
+        pool = append_kv(pool, tables, positions, cached, layer.get("layer_index"))
+    out = attend_decode(cfg, layer, q_nope, q_rope, positions, tables, pool)
+    return mm(out.reshape(slots, 1, cfg.n_heads * cfg.v_head_dim), layer["wo"]), pool
 
 
 def _head_blocks(cfg, layer):  # noqa: ANN001, ANN202

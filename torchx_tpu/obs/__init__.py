@@ -50,9 +50,11 @@ microsecond and write nothing. There is no switch of ours: the profiler's
 session is the switch.
 
 * host spans, engine thread, one tree per loop turn that did work:
-  ``serve.admit`` over ``serve.admit.plan`` / ``serve.admit.build`` /
-  ``serve.prefill.dispatch`` / ``serve.prefill.fetch`` /
-  ``serve.admit.commit``; ``serve.decode`` (``step``, ``active``,
+  ``serve.admit`` over ``serve.admit.plan`` (admission is planning alone
+  since prompts ride the decode steps: ``serve.admit.build``,
+  ``serve.prefill.dispatch``, ``serve.prefill.fetch`` and
+  ``serve.admit.commit`` are names of older traces, kept for their readers);
+  ``serve.decode`` (``step``, ``active``, ``chunk_tokens``, ``chunk_width``,
   ``steps_overlapped``, ``tokens_discarded``) over
   ``serve.decode.prepare`` / ``.dispatch`` / ``.fetch`` / ``.commit``
   (``finished``); ``serve.idle``; ``serve.kv_import``; ``serve.cow_copy``
@@ -69,22 +71,20 @@ session is the switch.
   compiled call on the thread that made it, inside the span that made it, so
   a reader ties each run to its turn and bounds the two clocks' offset from
   the trace alone; the spans carry nothing for that.
-  **An admission round is counted where it happens**: ``serve.admit`` carries
-  ``rows``, ``rows_padded`` (the power of two its program was built for),
-  ``width``, ``cached_tokens``, ``tokens`` (prefilled), ``queue_depth``,
-  ``slots_stalled`` (slots that hold a request as the round is dispatched:
-  no token for them while its program runs), ``built`` (1: the round made its
-  ``(rows, width)`` program, so a compile or a cache load lies inside its
-  ``serve.prefill.dispatch``: a bucket the warm-up missed, named),
+  **A prompt is counted where it is fed**: a ``serve.decode`` turn whose step
+  carries a chunk of a prompt says so in ``chunk_tokens`` (its real tokens; 0
+  on a pure decode step) beside ``chunk_width`` (the positions the program
+  computes for them), and the call inside its ``.dispatch`` is
+  ``_decode_chunk`` where a pure step's is ``_decode``. ``serve.admit`` carries
+  ``admitted`` (requests given a slot), ``cached_tokens``, ``queue_depth``,
   ``kv_bytes_per_token`` and the blocks held after it.
   ``steps_overlapped`` (steps enqueued while the one before was unfetched)
   and ``tokens_discarded`` (slot-steps dropped at commit: the step after an
   EOS, a slot preempted with its step in flight) are the engine's running
   counts, also in ``ServeEngine.stats()``; ``stats()`` alone keeps the sums
-  over all rounds (``prefill_rounds``, ``prefill_tokens``,
-  ``prefill_padded_tokens``, ``prefill_programs_built``,
-  ``slot_steps_stalled``), each added to once a round. They are per step and
-  per round, not per request: the per-request spans (``serve.generate``,
+  over all chunks (``chunk_steps``, ``prefill_tokens`` = the sum of
+  ``chunk_tokens``, ``prefill_padded_tokens`` = ``chunk_steps`` x
+  ``chunk_width``). They are per step, not per request: the per-request spans (``serve.generate``,
   ``serve.route``, ``serve.kv_transfer``) stay on the launcher plane;
 * host spans, training: the profiler's step marker ``train`` around each
   iteration of ``train()``, ``train.log`` (holding ``train.fence``),

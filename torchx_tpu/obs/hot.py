@@ -35,26 +35,28 @@ import jax
 # then ``.fetch`` and ``.commit`` wait for and hand out step N's tokens, which
 # the device computed meanwhile. A turn with nothing in flight has no
 # ``.fetch``/``.commit``; one with nothing left to enqueue no ``.dispatch``.
-# An admission round is enqueued behind the step in flight; its ``first`` is
-# still fetched before the round commits.
+# Admission is planning alone (serve.admit over serve.admit.plan: prefix match, blocks, a slot); a prompt
+# is then fed in chunks that ride the decode steps, so the step a turn enqueues may carry one, and the
+# compiled call inside its .dispatch is then _decode_chunk where a pure step's is _decode.
 
 # kv_blocks_full, kv_blocks_window: blocks the slots hold in the full and in the window pools (a decode
-# turn: as its step is dispatched; an admission round: after it); window_blocks_released: a running count
+# turn: as its step is dispatched; an admission: after it); window_blocks_released: a running count
 #
-# serve.admit, a round counted where it happens: rows, rows_padded (the power of two the program was built
-# for), width, cached_tokens, tokens (prefilled: real suffix tokens of rows_padded x width), queue_depth
-# (after the round's requests left it), slots_stalled (slots that hold a request as the round is
-# dispatched: they get no token while its program runs), built (1: this round made its (rows, width)
-# program, whose compile or cache load lies inside this serve.prefill.dispatch), kv_bytes_per_token,
-# kv_blocks_*. ServeEngine.stats() keeps the running sums over all rounds: prefill_rounds, prefill_tokens,
-# prefill_padded_tokens (rows_padded x width), prefill_programs_built (built), slot_steps_stalled (slots_stalled)
+# serve.admit: admitted (requests given a slot), cached_tokens, queue_depth (after they left it),
+# kv_bytes_per_token, kv_blocks_*. Until PR 40 an admission was a round with a program of its own, a
+# serve.admit over all five children below with rows, rows_padded, width, tokens, slots_stalled, built:
+# the names stay for the readers of such traces (benchmark/lib/host_spans.py, program_runs.py), the
+# engine records serve.admit and serve.admit.plan alone
 SERVE_ADMIT = "serve.admit"
 SERVE_ADMIT_PLAN = "serve.admit.plan"
 SERVE_ADMIT_BUILD = "serve.admit.build"
 SERVE_PREFILL_DISPATCH = "serve.prefill.dispatch"
 SERVE_PREFILL_FETCH = "serve.prefill.fetch"
 SERVE_ADMIT_COMMIT = "serve.admit.commit"
-SERVE_DECODE = "serve.decode"  # step, active (slots step N+1 steps), kv_blocks_*; running counts: steps_overlapped, tokens_discarded
+# step, active (slots step N+1 has a token for), kv_blocks_*, chunk_tokens (real prompt tokens riding step N+1; 0 on a
+# pure decode step) of chunk_width; running counts: steps_overlapped, tokens_discarded. ServeEngine.stats() keeps the
+# sums over all chunks: chunk_steps, prefill_tokens (chunk_tokens), prefill_padded_tokens (chunk_steps x chunk_width)
+SERVE_DECODE = "serve.decode"
 SERVE_DECODE_PREPARE = "serve.decode.prepare"
 SERVE_DECODE_DISPATCH = "serve.decode.dispatch"
 SERVE_DECODE_FETCH = "serve.decode.fetch"
@@ -65,7 +67,7 @@ SERVE_KV_IMPORT = "serve.kv_import"  # blocks, cache_len
 # writes to it (eager updates of the pool's leaves: several program runs, named by this span)
 SERVE_COW_COPY = "serve.cow_copy"
 
-#: parent -> the children that tile it, in order
+#: parent -> the children that tile it, in order (serve.admit: in a trace from before PR 40; since then .plan alone)
 SERVE_SPAN_TREE = {
     SERVE_ADMIT: (
         SERVE_ADMIT_PLAN,

@@ -9,13 +9,22 @@ stragglers do. Decode on TPU is HBM-bandwidth-bound, so throughput is
 
 This engine keeps a **fixed slot array** decoding continuously:
 
-* one jitted :func:`torchx_tpu.models.generate.paged_decode_step` per
-  engine — static ``[max_slots]`` shapes, XLA compiles once regardless of
-  which requests occupy the slots;
-* **admission** between steps: waiting requests are prefilled in
-  width-bucketed groups (a handful of compiles total) and dropped into
-  free slots, with KV blocks allocated from the shared paged pool
-  (:mod:`torchx_tpu.serve.kv_pool`);
+* **two compiled programs** per engine, whatever the traffic: the decode
+  step (:func:`torchx_tpu.models.generate.paged_decode_step`, static
+  ``[max_slots]`` shapes) and the same step carrying one chunk of a prompt
+  (:func:`~torchx_tpu.models.generate.paged_decode_chunk_step`, ``[max_slots]``
+  + ``[chunk_width]``);
+* **admission is no program**: a waiting request gets a free slot at once,
+  with its KV blocks allocated from the shared paged pool
+  (:mod:`torchx_tpu.serve.kv_pool`), and its prompt is then **fed in chunks
+  of** ``chunk_width`` **tokens that ride the decode steps**: each step takes,
+  beside every decoding slot's row, the next chunk of the oldest request that
+  is mid-prompt, through the layer stack in the same pass, so a prompt reads
+  no weight that the slots are not already paying for and stalls nobody. A
+  slot that holds an unfinished prompt decodes nothing; chunks need no result
+  of the device, so chunk k+1 is enqueued with chunk k's step still in
+  flight; the step that carries a prompt's last chunk samples its first
+  token into that slot's place on the device, where the next step reads it;
 * **one decode step always in flight**: a step's sampled tokens stay on the
   device as the next step's input, and the loop enqueues step N+1 *before*
   it fetches and commits step N, so the host's work for a step runs while
@@ -34,9 +43,10 @@ This engine keeps a **fixed slot array** decoding continuously:
   function of (seed, position));
 * **prefix reuse**: admission consults the refcounted radix
   :class:`~torchx_tpu.serve.prefix_cache.PrefixCache` and prefills only
-  the *uncached suffix* of each prompt (width-bucketed on suffix length,
-  via :func:`~torchx_tpu.models.generate.paged_prefill_chunk`); newly
-  computed full blocks are inserted back on prefill and on completion.
+  the *uncached suffix* of each prompt (its chunks start at the cached
+  length); newly computed full blocks are inserted back as soon as the chunk
+  that fills them is enqueued (device order makes them valid for every later
+  program) and on completion.
   Cached blocks are shared by refcount — a shared tail block about to be
   written is copy-on-write copied first, and under pool pressure the
   engine evicts cache-only blocks before preempting live slots;
@@ -45,11 +55,12 @@ This engine keeps a **fixed slot array** decoding continuously:
   blocks are paged by a slot's table as above; in the sliding layers' pools a
   slot holds a ring of :func:`~torchx_tpu.serve.kv_pool.window_ring` blocks,
   and the oldest goes back to that pool's allocator as soon as every
-  position in it is below every future query's window (while decoding, and at
-  the end of a prefill round, which stages a block for every block of its
-  rows). Preemption frees both; a prefix-cache node holds a block of each;
+  position in it is below every future query's window (while decoding, and
+  between two chunks of a prompt, whose blocks are staged block ``b`` at entry
+  ``b`` until its last chunk hands those still in reach to the ring).
+  Preemption frees both; a prefix-cache node holds a block of each;
 * **disaggregation seams**: a request marked ``prefill_only`` completes
-  at prefill with its KV blocks exported as a
+  with its first token, its KV blocks exported as a
   :class:`~torchx_tpu.serve.kv_transfer.KvPayload` (the prefill-replica
   role), and :meth:`ServeEngine.submit_prefilled` admits a transferred
   payload straight into a decode slot — scattering the received blocks
@@ -163,11 +174,21 @@ class _SlotState:
     #: steps enqueued for this slot whose token is not committed yet: 1 while
     #: a step is in flight, 2 between a dispatch and the commit that follows it
     unfetched: int = 0
+    #: while the slot holds an unfinished prompt: prompt + tokens generated
+    #: before a preemption, of which ``cache_len`` are fed; None once all are
+    feeding: Optional[list[int]] = None
+    #: the sliding layers' blocks staged for the prompt, where the model has
+    #: any: block of the sequence -> block; the ring's after the last chunk
+    staged: dict[int, int] = dataclasses.field(default_factory=dict)
 
     @property
     def more_to_decode(self) -> bool:
-        """False once the tokens held plus those in flight reach the budget: a
-        finish by count is known a step ahead, so the slot is not stepped again."""
+        """False while the prompt is being fed, for a request that ends with
+        its first token, and once the tokens held plus those in flight reach
+        the budget: a finish by count is known a step ahead, so the slot is
+        not stepped again."""
+        if self.feeding is not None or self.req.prefill_only:
+            return False
         return len(self.req.generated) + self.unfetched < self.req.max_new_tokens
 
 
@@ -176,7 +197,10 @@ class _InFlight:
     """A decode step enqueued and not yet fetched."""
 
     nxt: jax.Array  # [max_slots] sampled tokens, still on the device
-    stepping: list[tuple[int, _SlotState]]  # the slots it stepped, as they were then
+    #: the slots it has a token for, as they were then: those it stepped, and
+    #: the one whose prompt its chunk ended
+    stepping: list[tuple[int, _SlotState]]
+    first_of: Optional[int] = None  # the slot whose token is its request's first
 
 
 @dataclasses.dataclass
@@ -209,8 +233,9 @@ class _Handoff:
 _FROM_DEVICE = -1
 
 
-def _next_pow2(n: int) -> int:
-    return 1 << max(0, (n - 1)).bit_length()
+def _seed32(req: ServeRequest) -> np.int32:
+    """A request's seed as the programs take it: its low 32 bits."""
+    return np.int32(np.uint32(req.seed & 0xFFFFFFFF))
 
 
 def _fold_keys(seeds: jnp.ndarray, sample_pos: jnp.ndarray) -> jnp.ndarray:
@@ -241,6 +266,7 @@ class ServeEngine:
         num_blocks: Optional[int] = None,
         num_window_blocks: Optional[int] = None,
         max_prefill_batch: int = 4,
+        chunk_width: int = 256,
         enable_prefix_cache: bool = True,
         prefix_cache_reserve: float = 0.0,
         clock: Callable[[], float] = time.monotonic,
@@ -253,7 +279,15 @@ class ServeEngine:
         self.max_slots = max_slots
         self.block_size = block_size
         self.blocks_per_slot = math.ceil(cfg.max_seq / block_size)
+        #: requests that may be mid-prompt at once, and with them the window
+        #: blocks staged for prompts
         self.max_prefill_batch = max(1, max_prefill_batch)
+        if chunk_width < block_size or chunk_width % block_size:
+            raise ValueError(f"chunk_width must be a positive multiple of block_size={block_size}, got {chunk_width}")
+        #: prompt tokens a step can carry: compiled geometry, like block_size
+        #: (256 is the one width measured and checked on the chip; the tests
+        #: pass a small one). No prompt is longer than a slot's blocks
+        self.chunk_width = min(chunk_width, self.blocks_per_slot * block_size)
         if num_blocks is None:
             num_blocks = 1 + max_slots * max(1, self.blocks_per_slot // 2)
         if num_blocks < self.blocks_per_slot + 1:
@@ -270,7 +304,7 @@ class ServeEngine:
         self.window = cfg.sliding_window if cfg.layers_of("window") else 0
         self.window_ring = window_ring(self.window, block_size) if self.window else 0
         if self.window and num_window_blocks is None:
-            # every slot's ring, and the rows of one prefill round staged whole
+            # every slot's ring, and the prompts being fed staged whole
             num_window_blocks = 1 + max_slots * self.window_ring + self.max_prefill_batch * self.blocks_per_slot
         self.num_window_blocks = num_window_blocks if self.window else 0
         self.pools = gen.init_kv_pools(cfg, num_blocks, block_size, self.num_window_blocks)
@@ -325,18 +359,15 @@ class ServeEngine:
         #: slot-steps whose token was dropped at commit: the step after an EOS,
         #: a slot preempted with its step in flight
         self.tokens_discarded = 0
-        # the admission rounds so far, each added to once a round
-        self.prefill_rounds = 0
-        self.prefill_tokens = 0  # suffix tokens prefilled
-        self.prefill_padded_tokens = 0  # rows x width of the programs that prefilled them
-        self.prefill_programs_built = 0  # (rows, width) programs made: each compiles or loads in its round
-        self.slot_steps_stalled = 0  # slots that held a request while a round's program ran, summed
+        self.chunk_steps = 0  # steps that carried a chunk of a prompt
+        self.prefill_tokens = 0  # prompt tokens fed: the chunks' real tokens
+        self.prefill_padded_tokens = 0  # positions computed for them: chunk_steps x chunk_width
         self._in_flight: Optional[_InFlight] = None
         #: why the loop died (a step raised), else None; a dead engine
         #: refuses work and fails the replica's health check
         self.failed: Optional[str] = None
 
-        # one compiled decode step for the engine's lifetime. The weights
+        # two compiled programs for the engine's lifetime. The weights
         # are an ARGUMENT of every jitted function: closed over, they lower
         # to constants — at 1B parameters 2.5 GB of literals in each
         # program's HLO and a private device copy in each executable.
@@ -355,8 +386,25 @@ class ServeEngine:
                 params, tokens, positions, tables, pools, cfg_c, keys, temps
             )
 
+        def _decode_chunk(params, tokens, prev, positions, tables, pools, seeds, temps, chunk, at, chunk_tables):  # noqa: ANN001
+            # the same step with ``chunk`` riding it: the next tokens of one
+            # prompt from position ``at[0]`` on, ``at[1]`` of them real. ``seeds``
+            # and ``temps`` end with that request's. Where the chunk ends its
+            # prompt, ``at[2]`` is the request's slot and the token sampled
+            # behind the chunk, the request's first, takes that slot's place in
+            # what the step leaves on the device; else ``at[2]`` is no slot
+            tokens = jnp.where(tokens == _FROM_DEVICE, prev, tokens)
+            # the key is a function of the *absolute* position of the last
+            # prompt token, so a prompt fed behind a cached head, or in other
+            # chunks after a preemption, draws the same first token
+            keys = _fold_keys(seeds, jnp.append(positions, at[0] + at[1] - 1))
+            sampled, pools = gen.paged_decode_chunk_step(
+                params, tokens, positions, tables, chunk, at[0], at[1], chunk_tables, pools, cfg_c, keys, temps
+            )
+            return jnp.where(jnp.arange(max_slots) == at[2], sampled[-1], sampled[:-1]), pools
+
         self._decode = jax.jit(_decode, donate_argnums=donate)
-        self._prefill_fns: dict[tuple[int, int], Callable] = {}
+        self._decode_chunk = jax.jit(_decode_chunk, donate_argnums=donate)
 
     @classmethod
     def from_plan(
@@ -557,11 +605,10 @@ class ServeEngine:
                 "preemptions": self.preemptions,
                 "steps_overlapped": self.steps_overlapped,
                 "tokens_discarded": self.tokens_discarded,
-                "prefill_rounds": self.prefill_rounds,
+                "chunk_steps": self.chunk_steps,
+                "chunk_width": self.chunk_width,
                 "prefill_tokens": self.prefill_tokens,
                 "prefill_padded_tokens": self.prefill_padded_tokens,
-                "prefill_programs_built": self.prefill_programs_built,
-                "slot_steps_stalled": self.slot_steps_stalled,
                 "kv_bytes_per_token": self.kv_bytes_per_token,
                 "kv_bytes_per_slot_window": self.kv_bytes_per_slot_window,
                 "kv_blocks_window_used": self.window_alloc.used_blocks if self.window else 0,
@@ -647,7 +694,7 @@ class ServeEngine:
         self._in_flight = None
         for i, st in enumerate(self._slots):
             if st is not None:
-                self._slots[i] = None
+                self._release_slot(i)
                 pending.append(st.req)
         for req in pending:
             if not req.done.is_set():
@@ -656,40 +703,7 @@ class ServeEngine:
                 req.done.set()
                 obs_metrics.SERVE_REQUESTS.inc(status="error")
 
-    # -- admission / prefill ----------------------------------------------
-
-    def _prefill_fn(self, rows: int, width: int) -> Callable:
-        fn = self._prefill_fns.get((rows, width))
-        if fn is None:
-            donate = (5,) if jax.default_backend() != "cpu" else ()
-            cfg_c = self._cfg
-
-            def _prefill(params, tokens, prefix_lens, suffix_lens, tables, pools, seeds, temps):  # noqa: ANN001
-                # sampling key is a function of the *absolute* position of
-                # the last prompt token, so a cache-hit suffix prefill
-                # draws the same first token a cold prefill would
-                keys = _fold_keys(seeds, prefix_lens + suffix_lens - 1)
-                return gen.paged_prefill_chunk(
-                    params,
-                    tokens,
-                    prefix_lens,
-                    suffix_lens,
-                    tables,
-                    pools,
-                    cfg_c,
-                    keys,
-                    temps,
-                )
-
-            fn = jax.jit(_prefill, donate_argnums=donate)
-            self._prefill_fns[(rows, width)] = fn
-        return fn
-
-    def _bucket_width(self, plen: int) -> int:
-        return min(
-            max(self.block_size, _next_pow2(plen)),
-            _next_pow2(self._cfg.max_seq),
-        )
+    # -- admission -----------------------------------------------------------
 
     def _alloc_pressure(self, n: int, kind: str = "full") -> Optional[list[int]]:
         """:meth:`BlockAllocator.alloc` from the pool of ``kind`` that spills
@@ -705,205 +719,105 @@ class ServeEngine:
 
     def _kv_blocks(self) -> dict[str, int]:
         """Blocks the slots hold in each kind of pool (not what the prefix
-        cache keeps beside them), and window blocks given back so far: what the
-        ``serve.decode`` and ``serve.admit`` spans carry."""
+        cache keeps beside them; those staged for a prompt being fed among
+        them), and window blocks given back so far: what the ``serve.decode``
+        and ``serve.admit`` spans carry."""
+        staged = sum(len(st.staged) for st in self._slots if st is not None)
         return {
             "kv_blocks_full": self.tables.held_blocks,
-            "kv_blocks_window": self.window_tables.held_blocks if self.window else 0,
+            "kv_blocks_window": self.window_tables.held_blocks + staged if self.window else 0,
             "window_blocks_released": self.window_blocks_released,
         }
 
+    def _release_slot(self, slot: int) -> _SlotState:
+        """Empty ``slot``: a reference to each block it holds goes back to the
+        block's allocator, those staged for an unfinished prompt too. -> the
+        state it held."""
+        st = self._slots[slot]
+        self._slots[slot] = None
+        self.alloc.release(self.tables.release(slot))
+        if self.window:
+            self.window_alloc.release(self.window_tables.release(slot) + list(st.staged.values()))
+            st.staged = {}
+        return st
+
     def _admit(self) -> bool:
+        """Give waiting requests the free slots, as many as may be mid-prompt
+        at once: planning alone (prefix match, blocks), no program. The decode
+        steps that follow feed each prompt (:meth:`_decode_once`)."""
         free_slots = [i for i, s in enumerate(self._slots) if s is None]
         # an unlocked peek: most loop turns find nothing waiting, and those
         # turns open no span
         if not free_slots or not self._waiting:
             return False
-        with hot.span(hot.SERVE_ADMIT) as round_span:
+        feeding = sum(1 for s in self._slots if s is not None and s.feeding is not None)
+        room = min(len(free_slots), self.max_prefill_batch - feeding)
+        if room <= 0:
+            return False
+        with hot.span(hot.SERVE_ADMIT) as admit_span:
             with hot.span(hot.SERVE_ADMIT_PLAN):
-                admitted, width = self._plan_admission(len(free_slots))
+                admitted = self._plan_admission(room)
             if not admitted:
                 return False
-            with hot.span(hot.SERVE_ADMIT_BUILD):
-                rows = _next_pow2(len(admitted))
-                tokens = np.zeros((rows, width), np.int32)
-                prefix_lens = np.zeros((rows,), np.int32)
-                suffix_lens = np.ones((rows,), np.int32)
-                tables_rows = np.full(
-                    (rows, self.blocks_per_slot), TRASH_BLOCK, np.int32
+            for a, slot in zip(admitted, free_slots):
+                self.tables.assign(slot, a.cached_blocks + a.new_blocks)
+                self.tables.lengths[slot] = a.cached_tokens
+                self._slots[slot] = _SlotState(
+                    req=a.req,
+                    cache_len=a.cached_tokens,
+                    last_tok=0,  # never read: the slot's first step takes its token from the device
+                    admit_seq=next(self._admit_counter),
+                    feeding=a.toks,
+                    staged=a.window_blocks,
                 )
-                # the sliding layers' blocks of a round lie as the full layers' do, block b at entry b
-                window_rows = np.full_like(tables_rows, TRASH_BLOCK)
-                seeds = np.zeros((rows,), np.int32)
-                temps = np.zeros((rows,), np.float32)
-                cached_total = suffix_total = 0
-                for r, a in enumerate(admitted):
-                    blocks = a.cached_blocks + a.new_blocks
-                    sfx = a.toks[a.cached_tokens :]
-                    tokens[r, : len(sfx)] = sfx
-                    prefix_lens[r] = a.cached_tokens
-                    suffix_lens[r] = len(sfx)
-                    tables_rows[r, : len(blocks)] = blocks
-                    for b, block in a.window_blocks.items():
-                        window_rows[r, b] = block
-                    seeds[r] = np.int32(np.uint32(a.req.seed & 0xFFFFFFFF))
-                    temps[r] = a.req.temperature
-                    cached_total += a.cached_tokens
-                    suffix_total += len(sfx)
-            built = (rows, width) not in self._prefill_fns
-            stalled = self.max_slots - len(free_slots)
-            round_span.set_metadata(
-                rows=len(admitted),
-                rows_padded=rows,
-                width=width,
-                cached_tokens=cached_total,
-                tokens=suffix_total,
+            with self._lock:
+                self._admitting = []
+            self._update_gauges()
+            admit_span.set_metadata(
+                admitted=len(admitted),
+                cached_tokens=sum(a.cached_tokens for a in admitted),
                 queue_depth=len(self._waiting),
-                slots_stalled=stalled,
-                built=int(built),
                 kv_bytes_per_token=self.kv_bytes_per_token,
+                **self._kv_blocks(),
             )
-            self.prefill_rounds += 1
-            self.prefill_tokens += suffix_total
-            self.prefill_padded_tokens += rows * width
-            self.prefill_programs_built += built
-            self.slot_steps_stalled += stalled
-
-            with hot.span(hot.SERVE_PREFILL_DISPATCH):
-                fn = self._prefill_fn(rows, width)
-                first, self.pools = fn(
-                    self._params,
-                    jnp.asarray(tokens),
-                    jnp.asarray(prefix_lens),
-                    jnp.asarray(suffix_lens),
-                    self._tables_arg(tables_rows, window_rows),
-                    self.pools,
-                    jnp.asarray(seeds),
-                    jnp.asarray(temps),
-                )
-            with hot.span(hot.SERVE_PREFILL_FETCH):
-                first = np.asarray(first)
-
-            with hot.span(hot.SERVE_ADMIT_COMMIT):
-                self._commit_admission(admitted, first, free_slots)
-            round_span.set_metadata(**self._kv_blocks())
         return True
 
-    def _plan_admission(
-        self, free_slots: int
-    ) -> tuple[list[_Admit], Optional[int]]:
-        """Under the lock: match prefixes, allocate blocks and take the
-        requests of one prefill bucket off the queue.
-        -> (what to prefill, the bucket's width)."""
+    def _plan_admission(self, limit: int) -> list[_Admit]:
+        """Under the lock: match prefixes, allocate blocks and take up to
+        ``limit`` requests off the head of the queue. -> what to feed."""
         admitted: list[_Admit] = []
-        width: Optional[int] = None
         with self._lock:
-            limit = min(free_slots, self.max_prefill_batch)
-            for req in list(self._waiting):
-                if len(admitted) >= limit:
-                    break
+            for req in list(self._waiting)[:limit]:
                 toks = list(req.prompt) + req.generated
                 cached_blocks: list[int] = []
                 cached_window: dict[int, int] = {}
                 cached_tokens = 0
                 if self.prefix_cache is not None:
                     # retains the matched blocks on our behalf; never
-                    # covers the last token, so suffix_len >= 1
+                    # covers the last token, so a token is left to feed
                     cached_blocks, cached_window, cached_tokens = self.prefix_cache.match_kinds(toks)
-
-                def give_back() -> None:
-                    if cached_blocks:
-                        self.alloc.release(cached_blocks)  # noqa: B023
-                    if cached_window:
-                        self.window_alloc.release(list(cached_window.values()))  # noqa: B023
-
-                suffix_len = len(toks) - cached_tokens
-                w = self._bucket_width(suffix_len)
-                if width is None:
-                    width = w  # head of queue picks this round's bucket
-                if w != width:
-                    give_back()
-                    continue
                 need = math.ceil(len(toks) / self.block_size) - len(cached_blocks)
                 new_blocks = self._alloc_pressure(need)
-                # the round stages a window block for every new block of a row;
-                # _commit_admission hands back those below the row's window
+                # a window block is staged for every new block of the prompt;
+                # _chunk_enqueued hands back those below the next chunk's window
                 new_window = self._alloc_pressure(need, "window") if self.window and new_blocks is not None else []
                 if new_blocks is None or new_window is None:
                     if new_blocks:
                         self.alloc.release(new_blocks)
-                    give_back()
+                    if cached_blocks:
+                        self.alloc.release(cached_blocks)
+                    if cached_window:
+                        self.window_alloc.release(list(cached_window.values()))
                     break  # pool pressure: admit what fits, retry later
                 window_blocks = dict(cached_window)
                 window_blocks.update({len(cached_blocks) + j: block for j, block in enumerate(new_window)})
-                admitted.append(
-                    _Admit(req, toks, cached_blocks, cached_tokens, new_blocks, window_blocks)
-                )
+                admitted.append(_Admit(req, toks, cached_blocks, cached_tokens, new_blocks, window_blocks))
             for a in admitted:
                 self._waiting.remove(a.req)
-            # visible to drain(): popped but not yet in a slot/completed
+            # visible to drain(): popped but not yet in a slot
             self._admitting = [a.req for a in admitted]
             obs_metrics.SERVE_QUEUE_DEPTH.set(len(self._waiting))
-        return admitted, width
-
-    def _commit_admission(
-        self, admitted: list[_Admit], first: np.ndarray, free_slots: list[int]
-    ) -> None:
-        """Hand each prefilled request its first token and a slot (or
-        complete it), index its blocks, publish the gauges."""
-        now = self._clock()
-        for r, a in enumerate(admitted):
-            req = a.req
-            blocks = a.cached_blocks + a.new_blocks
-            resumed = bool(req.generated)  # preempted earlier; TTFT already set
-            tok = int(first[r])
-            req.generated.append(tok)
-            if not resumed:
-                req.t_first = now
-                obs_metrics.SERVE_TTFT_SECONDS.observe(req.ttft_s)
-            obs_metrics.SERVE_TOKENS.inc(phase="prefill")
-            self.tokens_out += 1
-            # index the freshly computed full blocks while they're valid —
-            # the next same-prefix request prefills only its tail
-            if self.prefix_cache is not None:
-                self.prefix_cache.insert(a.toks, blocks, a.window_blocks)
-            if req.prefill_only:
-                # a request its first token already finishes never needs
-                # the decode side: no handoff, the caller reads .tokens
-                if not self._finished(req, tok):
-                    req.handoff = self._export_handoff(req, a.toks, blocks, a.window_blocks)
-                self.alloc.release(blocks)
-                if self.window:
-                    self.window_alloc.release(list(a.window_blocks.values()))
-                self._complete(req, now)
-                continue
-            if self._finished(req, tok):
-                self.alloc.release(blocks)
-                if self.window:
-                    self.window_alloc.release(list(a.window_blocks.values()))
-                self._complete(req, now)
-                continue
-            slot = free_slots.pop(0)
-            self.tables.assign(slot, blocks)
-            self.tables.lengths[slot] = len(a.toks)
-            if self.window:
-                # the next query sits at len(toks): what lies below its window goes back now
-                keep_from = self._window_first_block(len(a.toks))
-                below = [block for b, block in a.window_blocks.items() if b < keep_from]
-                self.window_alloc.release(below)
-                self.window_blocks_released += len(below)
-                for b, block in a.window_blocks.items():
-                    if b >= keep_from:
-                        self.window_tables.assign(slot, b, block)
-            self._slots[slot] = _SlotState(
-                req=req,
-                cache_len=len(a.toks),
-                last_tok=tok,
-                admit_seq=next(self._admit_counter),
-            )
-        with self._lock:
-            self._admitting = []
-        self._update_gauges()
+        return admitted
 
     def _window_ids(self, window_blocks: dict[int, int], n: int) -> Optional[np.ndarray]:
         """A sequence's ``n`` blocks in the window pools as an array: the trash
@@ -960,13 +874,9 @@ class ServeEngine:
         if not victims:
             return False
         _, slot = max(victims)
-        st = self._slots[slot]
-        self._slots[slot] = None
-        self.alloc.free(self.tables.release(slot))
-        if self.window:
-            self.window_alloc.release(self.window_tables.release(slot))
+        st = self._release_slot(slot)
         with self._lock:
-            self._waiting.appendleft(st.req)  # resumes via re-prefill
+            self._waiting.appendleft(st.req)  # resumes by being fed again, its generated tokens behind its prompt
             obs_metrics.SERVE_QUEUE_DEPTH.set(len(self._waiting))
         obs_metrics.SERVE_PREEMPTIONS.inc()
         self.preemptions += 1
@@ -1030,12 +940,56 @@ class ServeEngine:
                 return False
         return True
 
+    def _next_chunk(self) -> Optional[tuple[int, _SlotState, int]]:
+        """-> (slot, its state, real tokens) of the chunk the next step
+        carries: the next ``chunk_width`` tokens, or what is left, of the
+        oldest request that is mid-prompt. None with no prompt pending."""
+        feeding = [(st.admit_seq, slot) for slot, st in enumerate(self._slots) if st is not None and st.feeding is not None]
+        if not feeding:
+            return None
+        slot = min(feeding)[1]
+        st = self._slots[slot]
+        return slot, st, min(self.chunk_width, len(st.feeding) - st.cache_len)
+
+    def _chunk_enqueued(self, slot: int, st: _SlotState, n: int) -> bool:
+        """The step just enqueued carries the next ``n`` tokens of ``slot``'s
+        prompt. Their blocks are valid for every later program (device order),
+        so the full ones are indexed now; of the sliding layers' staged blocks,
+        those below the window of the next chunk's first query go back. Behind
+        the prompt's last chunk the ring takes what is still in reach and the
+        slot decodes from the next step on. -> whether that chunk was the last."""
+        st.cache_len += n
+        self.chunk_steps += 1
+        self.prefill_tokens += n
+        self.prefill_padded_tokens += self.chunk_width
+        if self.prefix_cache is not None:
+            self.prefix_cache.insert(st.feeding[: st.cache_len], self.tables.blocks_of(slot), st.staged)
+        if self.window:
+            keep_from = self._window_first_block(st.cache_len)
+            below = [st.staged.pop(b) for b in sorted(st.staged) if b < keep_from]
+            self.window_alloc.release(below)
+            self.window_blocks_released += len(below)
+        last = st.cache_len == len(st.feeding)
+        if last:
+            for b, block in st.staged.items():
+                self.window_tables.assign(slot, b, block)
+            st.feeding, st.staged = None, {}
+            # as a decode step leaves it: the step that writes the sequence's
+            # last row is in flight, and its token is the next step's input
+            st.cache_len -= 1
+            st.unfetched = 1
+        self.tables.lengths[slot] = st.cache_len
+        return last
+
     def _decode_once(self) -> bool:
         """One turn of the decode pipeline: prepare and enqueue the next step,
         then fetch and commit the one in flight. The step enqueued reads, on
-        the device, the tokens the one in flight will have sampled; with
-        nothing left to step, the turn still fetches what is in flight, so
-        the loop never idles on an unfetched token."""
+        the device, the tokens the one in flight will have sampled, and carries
+        the next chunk of a prompt where one is pending (a chunk needs no
+        result of the device, so it follows the chunk before it with that
+        step still in flight); with nothing left to step, the turn still
+        fetches what is in flight, so the loop never idles on an unfetched
+        token."""
         before = self._in_flight
         if before is None and all(s is None for s in self._slots):
             return False
@@ -1060,24 +1014,40 @@ class ServeEngine:
                     if st is None:
                         continue
                     if not st.more_to_decode:
-                        # its last token is in flight. The program writes a row
-                        # for every slot: this one's goes where an empty slot's does
+                        # its prompt is still being fed, or its last token is
+                        # in flight. The program writes a row for every slot:
+                        # this one's goes where an empty slot's does
                         tables[slot] = TRASH_BLOCK
                         if self.window:
                             window_tables[slot] = TRASH_BLOCK
                         continue
                     tokens[slot] = _FROM_DEVICE if st.unfetched else st.last_tok
                     positions[slot] = st.cache_len + st.unfetched
-                    seeds[slot] = np.int32(np.uint32(st.req.seed & 0xFFFFFFFF))
+                    seeds[slot] = _seed32(st.req)
                     temps[slot] = st.req.temperature
                     stepping.append((slot, st))
 
+                chunk = self._next_chunk()
+                if chunk is not None:
+                    c_slot, c_st, n = chunk
+                    start = c_st.cache_len
+                    chunk_tokens = np.zeros((self.chunk_width,), np.int32)
+                    chunk_tokens[:n] = c_st.feeding[start : start + n]
+                    ends = start + n == len(c_st.feeding)
+                    at = np.asarray([start, n, c_slot if ends else -1], np.int32)
+                    seeds = np.append(seeds, _seed32(c_st.req))
+                    temps = np.append(temps, np.float32(c_st.req.temperature))
+                    # the request's own table (the copy above sends its slot's decode row to the
+                    # trash block); its staged window blocks lie as the full ones do, block b at entry b
+                    chunk_full = self.tables.tables[c_slot : c_slot + 1].copy()
+                    chunk_window = self._window_ids(c_st.staged, self.blocks_per_slot)
+
             enqueued = None
             step_span.set_metadata(**self._kv_blocks())  # as the step is dispatched
-            if stepping:
+            if stepping or chunk is not None:
                 with hot.span(hot.SERVE_DECODE_DISPATCH):
                     host_tokens = jnp.asarray(tokens)
-                    nxt, self.pools = self._decode(
+                    args = (
                         self._params,
                         host_tokens,
                         # with no step in flight every slot reads the host's token
@@ -1088,9 +1058,22 @@ class ServeEngine:
                         jnp.asarray(seeds),
                         jnp.asarray(temps),
                     )
-                for _, st in stepping:
-                    st.unfetched += 1
-                enqueued = _InFlight(nxt, stepping)
+                    first_of = None
+                    if chunk is None:
+                        nxt, self.pools = self._decode(*args)
+                    else:
+                        nxt, self.pools = self._decode_chunk(
+                            *args,
+                            jnp.asarray(chunk_tokens),
+                            jnp.asarray(at),
+                            self._tables_arg(chunk_full, chunk_window[None] if self.window else None),
+                        )
+                    for _, st in stepping:
+                        st.unfetched += 1
+                    if chunk is not None and self._chunk_enqueued(c_slot, c_st, n):
+                        stepping.append((c_slot, c_st))
+                        first_of = c_slot
+                enqueued = _InFlight(nxt, stepping, first_of)
                 self.steps_overlapped += before is not None
 
             if before is not None:
@@ -1098,54 +1081,68 @@ class ServeEngine:
                     sampled = np.asarray(before.nxt)
                 self.steps += 1
                 with hot.span(hot.SERVE_DECODE_COMMIT) as commit_span:
-                    commit_span.set_metadata(finished=self._commit_step(before.stepping, sampled))
+                    commit_span.set_metadata(finished=self._commit_step(before, sampled))
             # only now: drain() must not see "nothing in flight" between the two
             self._in_flight = enqueued
             step_span.set_metadata(
                 active=len(stepping),
+                chunk_tokens=n if chunk is not None else 0,
+                chunk_width=self.chunk_width,
                 steps_overlapped=self.steps_overlapped,
                 tokens_discarded=self.tokens_discarded,
             )
-        return bool(stepping) or before is not None
+        return enqueued is not None or before is not None
 
-    def _commit_step(self, stepping: list[tuple[int, _SlotState]], sampled: np.ndarray) -> int:
-        """Hand each slot a fetched step stepped its token; -> how many
-        finished. A slot that no longer holds the state it was stepped with
-        finished by EOS or was preempted while the step was in flight: its
-        token is dropped (a preempted request draws it again at its
-        re-prefill: the key is seed and position). The row that step wrote
+    def _commit_step(self, step: _InFlight, sampled: np.ndarray) -> int:
+        """Hand each slot a fetched step has a token for its token; -> how
+        many finished. A slot that no longer holds the state it was stepped
+        with finished by EOS or was preempted while the step was in flight: its
+        token is dropped (a preempted request draws it again when it is fed
+        again: the key is seed and position). The row that step wrote
         for it lies in a block the slot owned unshared at dispatch, past every
         token the prefix cache indexes, and whatever reuses the block is
-        enqueued after the step."""
+        enqueued after the step. The token behind a prompt's last chunk
+        (``step.first_of``) is its request's first: it stamps the time to first
+        token, and ends a ``prefill_only`` request, whose blocks are exported
+        as they then lie."""
         finished = kept = 0
         now = self._clock()
-        for slot, st in stepping:
+        for slot, st in step.stepping:
             if self._slots[slot] is not st:
                 self.tokens_discarded += 1
                 continue
-            kept += 1
             st.unfetched -= 1
             st.cache_len += 1
             self.tables.lengths[slot] = st.cache_len
             tok = int(sampled[slot])
             st.last_tok = tok
-            st.req.generated.append(tok)
+            req = st.req
+            if slot == step.first_of:
+                if not req.generated:  # else preempted earlier: its first token came then
+                    req.t_first = now
+                    obs_metrics.SERVE_TTFT_SECONDS.observe(req.ttft_s)
+                obs_metrics.SERVE_TOKENS.inc(phase="prefill")
+            else:
+                kept += 1
+            req.generated.append(tok)
             self.tokens_out += 1
-            if self._finished(st.req, tok):
+            done = self._finished(req, tok)
+            if done or req.prefill_only:
                 finished += 1
-                self._slots[slot] = None
-                blocks = self.tables.release(slot)
+                blocks = self.tables.blocks_of(slot)
                 window_blocks = self.window_tables.blocks_of(slot) if self.window else {}
+                seq = list(req.prompt) + req.generated
+                if not done:
+                    # a request its first token already finishes never needs
+                    # the decode side: no handoff, the caller reads .tokens
+                    req.handoff = self._export_handoff(req, seq[: st.cache_len], blocks, window_blocks)
                 if self.prefix_cache is not None:
                     # index the completed sequence's full blocks (cache
                     # holds cache_len tokens: everything but the final
                     # sampled token) before dropping the slot's refs
-                    seq = list(st.req.prompt) + st.req.generated
                     self.prefix_cache.insert(seq[: st.cache_len], blocks, window_blocks)
-                self.alloc.release(blocks)
-                if self.window:
-                    self.window_alloc.release(self.window_tables.release(slot))
-                self._complete(st.req, now)
+                self._release_slot(slot)
+                self._complete(req, now)
         if kept:
             obs_metrics.SERVE_TOKENS.inc(kept, phase="decode")
         self._update_gauges()
