@@ -13,7 +13,8 @@ each width given.
 describe a latent pool; this asks ``generate.init_kv_pools`` for the pools' shapes: K/V pools,
 a pool a cache kind (``k-exaone-serve-decode-long``), or a latent pool a layer group at 64 slots
 (``kimi-vl-a3b-serve-backlog``) or 128 (``xing4-serve-decode-long``: 16,897 blocks x 8 layers x
-1,280 B, 2.58 GiB beside 10.55 GiB of weights; decode 13.14 GiB live).
+1,280 B, 2.58 GiB beside 10.55 GiB of weights; decode 13.14 GiB live), and beside the K/V pools a
+state-space mixer's store a slot (``falcon-h1-serve-decode-long``: 65 rows x 6 layers x 4.2 MB).
 Under each program it prints what its layer loop moves of a layer's pool size or more
 (``torchx_tpu/obs/hlo.py::loop_moves``: nothing, since the pools ride the scan's carry),
 and every pure data movement anywhere in the program of the size of a layer's smallest
@@ -41,7 +42,7 @@ from benchmark.lib import models, spec  # noqa: E402
 from benchmark.rehearse_compile import report as report_memory, shapes_of  # noqa: E402
 
 
-KERNELS = ("paged_mla_decode", "paged_attention_decode", "gmm")
+KERNELS = ("paged_mla_decode", "paged_attention_decode", "gmm", "ssm_step")
 
 
 def main() -> None:
@@ -71,14 +72,18 @@ def main() -> None:
     ring = window_ring(window, bs) if window else 0
     n_window = 1 + slots * ring + int(dep["max_prefill_batch"]) * per_slot if window else None
     pools = jax.tree.map(lambda p: sds(p.shape, p.dtype),
-                         jax.eval_shape(lambda: gen.init_kv_pools(cfg, n_blocks, bs, n_window)))  # fmt: skip
+                         jax.eval_shape(lambda: gen.init_kv_pools(cfg, n_blocks, bs, n_window, slots)))  # fmt: skip
     i32, f32 = jnp.int32, jnp.float32
 
     def tables(rows: int, window_width: int):  # noqa: ANN202
         full = sds((rows, per_slot), i32)
+        if cfg.ssm_heads:  # a mixer's state rows ride beside the one table
+            return {"full": full, "state": sds((rows,), i32)}
         return {"full": full, "window": sds((rows, window_width), i32)} if window else full
 
-    layer_bytes = min(p.size // p.shape[0] * p.dtype.itemsize for p in jax.tree.leaves(pools))
+    # a layer's smallest pool; a mixer's convolution tails (a few MB a layer) are no pool's size
+    layer_bytes = min(p.size // p.shape[0] * p.dtype.itemsize
+                      for name, p in jax.tree_util.tree_leaves_with_path(pools) if "conv" not in jax.tree_util.keystr(name))
     projection_bytes = min(w.size // w.shape[0] * w.dtype.itemsize for group in llama.layer_groups(params)
                            for name, w in params[group].items() if name in ("wq", "wk", "wv", "wo", "w_qa", "w_qb", "w_kva", "w_kvb", "w_uk", "w_uv"))  # fmt: skip
 

@@ -185,6 +185,22 @@ def test_span_attributes(engine_line, name, attrs):
         assert not any({"rows", "tokens", "rows_padded", "slots_stalled"} & set(ev[3]) for ev in events)
 
 
+def test_a_mixers_state_rides_every_decode_span_and_no_other_engines(tmp_path, engine_line):
+    """``state_bytes_per_slot`` on ``serve.decode`` is what ``benchmark/layer_metrics/
+    engine.state_bytes_per_slot.py`` reads; an engine without recurrent state does not carry it."""
+    cfg = llama.CONFIGS["tiny"](ssm_heads=4, ssm_head_dim=8, ssm_state=16, ssm_groups=2)
+    engine = ServeEngine(llama.init_params(cfg, jax.random.PRNGKey(0)), cfg, max_slots=2, block_size=16, chunk_width=16).start()
+    try:
+        lines = _session(tmp_path, lambda: engine.generate([1, 2, 3], 4, timeout=120))
+        want = engine.stats()["state_bytes_per_slot"]
+    finally:
+        engine.stop()
+    turns = [ev for ln in lines for ev in ln if ev[0] == hot.SERVE_DECODE]
+    assert turns and want == cfg.n_layers * (4 * 8 * 16 * 4 + 3 * 96 * 4)
+    assert all(ev[3]["state_bytes_per_slot"] == want for ev in turns)
+    assert not any("state_bytes_per_slot" in ev[3] for ev in engine_line)
+
+
 @pytest.mark.parametrize(
     "name,calls",
     [
@@ -353,17 +369,19 @@ def _op_names(lowered) -> set[str]:
 def _serving_programs(cfg):
     init, _ = llama.model_fns(cfg)
     params = init(cfg, jax.random.PRNGKey(0))
-    pools = gen.init_kv_pools(cfg, 9, 16)
     slots, bps = 4, cfg.max_seq // 16
+    pools = gen.init_kv_pools(cfg, 9, 16, slots=slots)
     i32 = lambda *shape: jnp.zeros(shape, jnp.int32)  # noqa: E731
     keys = jnp.zeros((slots, 2), jnp.uint32)
     temps = jnp.zeros((slots,), jnp.float32)
+    # a mixer's rows ride beside the block table: each slot its own row of the store
+    tables = {"full": i32(slots, bps), "state": 1 + jnp.arange(slots, dtype=jnp.int32)} if cfg.ssm_heads else i32(slots, bps)
     decode = jax.jit(
         lambda p, t, pos, tab, pl, k, tm: gen.paged_decode_step(p, t, pos, tab, pl, cfg, k, tm)
-    ).lower(params, i32(slots), i32(slots), i32(slots, bps), pools, keys, temps)
+    ).lower(params, i32(slots), i32(slots), tables, pools, keys, temps)
     prefill = jax.jit(
         lambda p, t, pre, suf, tab, pl, k, tm: gen.paged_prefill_chunk(p, t, pre, suf, tab, pl, cfg, k, tm)
-    ).lower(params, i32(slots, 32), i32(slots), i32(slots) + 1, i32(slots, bps), pools, keys, temps)
+    ).lower(params, i32(slots, 32), i32(slots), i32(slots) + 1, tables, pools, keys, temps)
     return {"decode": decode, "prefill": prefill}
 
 
@@ -381,8 +399,11 @@ def _train_step(cfg):
 @pytest.fixture(scope="module")
 def lowered():
     dense, sparse = llama.CONFIGS["tiny"](), moe.moe_tiny()
+    mixer = llama.CONFIGS["tiny"](ssm_heads=4, ssm_head_dim=8, ssm_state=16, ssm_groups=2)
     out = {f"dense.{k}": _op_names(v) for k, v in _serving_programs(dense).items()}
     out.update({f"moe.{k}": _op_names(v) for k, v in _serving_programs(sparse).items()})
+    out.update({f"mixer.{k}": _op_names(v) for k, v in _serving_programs(mixer).items()})
+    out["mixer.train"] = _op_names(_train_step(mixer))
     out["dense.train"] = _op_names(_train_step(dense))
     out["moe.train"] = _op_names(_train_step(sparse))
     return out
@@ -393,17 +414,26 @@ SERVING = (hot.EMBED, hot.LAYERS, hot.NORM, hot.ATTN, hot.APPEND_KV, hot.PAGED_A
 TRAINING = (hot.EMBED, hot.LAYERS, hot.NORM, hot.ATTN, hot.ATTN_KERNEL, hot.LM_HEAD, hot.LOSS, hot.GRAD_CLIP,
             hot.OPTIMIZER)  # fmt: skip
 EXPERTS = (hot.MOE_ROUTER, hot.MOE_DISPATCH, hot.MOE_EXPERTS, hot.MOE_COMBINE)
+MIXER = (hot.SSM, hot.SSM_PROJ, hot.SSM_CONV, hot.SSM_GATE_NORM)  # and the form each program takes:
 CASES = (
     [(f"dense.{p}", s) for p in ("decode", "prefill") for s in SERVING + (hot.MLP,)]
     + [(f"moe.{p}", s) for p in ("decode", "prefill") for s in EXPERTS]
     + [("dense.train", s) for s in TRAINING + (hot.MLP,)]
     + [("moe.train", s) for s in EXPERTS]
-)
+    + [(f"mixer.{p}", s) for p, form in (("decode", hot.SSM_STEP), ("prefill", hot.SSM_SCAN), ("train", hot.SSM_SCAN))
+       for s in MIXER + (hot.ATTN, form)]
+)  # fmt: skip
 
 
 @pytest.mark.parametrize("program,scope", CASES)
 def test_scope_names_reach_the_lowered_program(lowered, program, scope):
     assert scope in lowered[program]
+
+
+def test_a_program_takes_one_form_of_the_mixer_and_a_model_without_one_neither(lowered):
+    assert hot.SSM_SCAN not in lowered["mixer.decode"] and hot.SSM_STEP not in lowered["mixer.prefill"]
+    assert not {hot.SSM, hot.SSM_STEP, hot.SSM_SCAN} & (lowered["dense.decode"] | lowered["dense.prefill"] | lowered["dense.train"])
+    assert set(MIXER + (hot.SSM_STEP, hot.SSM_SCAN)) <= set(hot.DEVICE_SCOPES)
 
 
 # -- training spans -----------------------------------------------------------------

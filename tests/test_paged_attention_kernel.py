@@ -99,6 +99,8 @@ CASES = [
     _case("pool-bfloat16", [9, 120, 64, 33], dtype=jnp.bfloat16),
     _case("benchmark-heads-real-chunk", [1, 300, 530], h=32, kvh=8, bs=16, bpr=34, real_chunk=True,
           dtype=jnp.bfloat16),  # fmt: skip
+    _case("twenty-over-four-heads-real-chunk", [1, 300, 530], h=20, kvh=4, bs=16, bpr=34, real_chunk=True,
+          dtype=jnp.bfloat16),  # fmt: skip
 ]
 
 
@@ -191,6 +193,7 @@ RULE = [
     ("float32-on-tpu", MISTRAL, jnp.float32, jnp.float32, "tpu", True),
     ("group-1-block-32", dict(q=(4, 8, 128), pool=(9, 32, 8, 128)), jnp.bfloat16, jnp.bfloat16, "tpu", True),
     ("head-dim-256", dict(q=(4, 16, 256), pool=(9, 16, 8, 256)), jnp.bfloat16, jnp.bfloat16, "tpu", True),
+    ("four-cache-heads", dict(q=(64, 20, 128), pool=(8449, 16, 4, 128)), jnp.bfloat16, jnp.bfloat16, "tpu", True),
     ("cpu", MISTRAL, jnp.bfloat16, jnp.bfloat16, "cpu", False),
     ("gpu", MISTRAL, jnp.bfloat16, jnp.bfloat16, "gpu", False),
     ("head-dim-64", dict(q=(4, 32, 64), pool=(9, 16, 8, 64)), jnp.bfloat16, jnp.bfloat16, "tpu", False),
@@ -313,6 +316,41 @@ def test_kernel_compiles_for_the_chip(one_chip, bpr, dtype):
 # The latent-attention decode kernel and the grouped matmul of the expert layers (PR 27),
 # at the widths of the kimi-vl-a3b-serve-backlog cell. Kept in this file: the worker that
 # holds the TPU's library is the one that runs it.
+
+
+def test_kernel_compiles_for_the_chip_over_four_cache_heads(one_chip):
+    """``falcon-h1-serve-decode-long``: 64 slots of 20 query heads over 4 cache heads, into a
+    stack of six layers' pools, a block 64 rows of 128."""
+    shape = lambda s, d: jax.ShapeDtypeStruct(s, d, sharding=one_chip)  # noqa: E731
+    pool = shape((6, 8449, 16, 4, 128), jnp.bfloat16)
+    assert pa.kernel_eligible((64, 20, 128), pool.shape[1:], jnp.dtype(jnp.bfloat16), pool.dtype, "tpu")
+    fn = lambda q, k, v, t, n, i: pk.paged_attention_pallas(q, k, v, t, n, layer=i)  # noqa: E731
+    text = jax.jit(fn).lower(
+        shape((64, 20, 128), jnp.bfloat16), pool, pool, shape((64, 264), jnp.int32), shape((64,), jnp.int32), shape((), jnp.int32)
+    ).compile().as_text()  # fmt: skip
+    assert "paged_attention_decode" in text
+    assert not [ln for ln in text.splitlines() if " copy(" in ln and "bf16[6,8449," in ln]
+
+
+def test_state_step_kernel_compiles_for_the_chip_in_place(one_chip):
+    """``falcon-h1-serve-decode-long``'s recurrent state: 64 slots' rows of 32 heads x 256 x 128
+    float32 in a store of six layers, moved on where they lie: the store goes in and comes out
+    the same buffer, and nothing of its size is copied in front of or behind the kernel."""
+    from torchx_tpu.models import ssm
+    from torchx_tpu.ops.ssm_step_kernel import ssm_step_pallas
+
+    shape = lambda s, d: jax.ShapeDtypeStruct(s, d, sharding=one_chip)  # noqa: E731
+    store = shape((6, 65, 32, 256, 128), jnp.float32)
+    assert ssm.kernel_eligible(store.shape, 2, "tpu")
+    fn = lambda st, r, d, f, b, c, i: ssm_step_pallas(st, r, d, f, b, c, layer=i)  # noqa: E731
+    compiled = jax.jit(fn, donate_argnums=(0,)).lower(
+        store, shape((64,), jnp.int32), shape((64, 32), jnp.float32), shape((64, 32, 128), jnp.float32),
+        shape((64, 2, 256), jnp.float32), shape((64, 2, 256), jnp.float32), shape((), jnp.int32)
+    ).compile()  # fmt: skip
+    text, memory = compiled.as_text(), compiled.memory_analysis()
+    assert "ssm_step" in text and "tpu_custom_call" in text
+    assert memory.alias_size_in_bytes >= 6 * 65 * 32 * 256 * 128 * 4 and memory.temp_size_in_bytes < 2**26
+    assert not [ln for ln in text.splitlines() if " copy(" in ln and "f32[6,65," in ln]
 
 
 def test_windowed_kernel_compiles_for_the_chip(one_chip):

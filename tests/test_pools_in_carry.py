@@ -57,11 +57,11 @@ def _one_layer_at_a_time(step, x, params, pools, cfg):
         for i in range(jax.tree.leaves(params[group])[0].shape[0]):
             layer = jax.tree.map(lambda w: w[i], params[group])  # noqa: B023
             if "k" in pools:
-                x, k, v = step(x, layer, pools["k"][i], pools["v"][i])
+                x, k, v, _ = step(x, layer, pools["k"][i], pools["v"][i], None)
                 new["k"].append(k)
                 new["v"].append(v)
             else:
-                x, pool, _ = step(x, layer, pools[group][i], None)
+                x, pool, _, _ = step(x, layer, pools[group][i], None, None)
                 new[group].append(pool)
     return x, {name: jnp.stack(layers) for name, layers in new.items()}
 

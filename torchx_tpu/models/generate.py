@@ -19,7 +19,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from torchx_tpu.models import hyper, llama, mla
+from torchx_tpu.models import hyper, llama, mla, ssm
 from torchx_tpu.obs import hot
 from torchx_tpu.ops.attention import note_traced, project_heads as _project_heads
 from torchx_tpu.ops.norms import rms_norm
@@ -34,7 +34,8 @@ from torchx_tpu.ops.rope import apply_rope
 
 KVCache = dict[str, jnp.ndarray]  # {"k": [L,b,S,kvh,hd], "v": ...}
 # every leaf [L_group, num_blocks, block_size, ...]: {"k", "v"} of [.., kvh, hd],
-# or one latent pool a layer group, {group: [.., cache_width]} (init_kv_pools)
+# or one latent pool a layer group, {group: [.., cache_width]} (init_kv_pools);
+# beside them under "ssm", where the layers have a mixer, its store a row a slot (ssm.init_store)
 KVPools = dict[str, jnp.ndarray]
 
 
@@ -42,9 +43,9 @@ def init_kv_cache(
     cfg: llama.LlamaConfig, batch: int, max_seq: int
 ) -> KVCache:
     """Zeroed [layers, batch, max_seq, kv_heads, head_dim] K/V buffers."""
-    if cfg.kv_lora_rank:
+    if cfg.kv_lora_rank or cfg.ssm_heads:
         raise NotImplementedError(
-            "latent attention is served through the paged path (ServeEngine), not the dense cache"
+            "latent attention and state-space layers are served through the paged path (ServeEngine), not the dense cache"
         )
     shape = (cfg.n_layers, batch, max_seq, cfg.n_kv_heads, cfg.head_dim)
     return {
@@ -94,15 +95,17 @@ def _layer_step(
         with jax.named_scope(hot.NORM):
             attn_in = rms_norm(stream_in, layer["attn_norm"], cfg.norm_eps)
         with jax.named_scope(hot.ATTN):
+            attn_in = llama.scaled(attn_in, cfg.attention_in_multiplier)
             q = apply_rope(_project_heads(attn_in, layer["wq"], h, hd), cos, sin)
-            k = apply_rope(_project_heads(attn_in, layer["wk"], kvh, hd), cos, sin)
+            k = apply_rope(llama.scaled(_project_heads(attn_in, layer["wk"], kvh, hd), cfg.key_multiplier), cos, sin)
             v = _project_heads(attn_in, layer["wv"], kvh, hd)
             with jax.named_scope(hot.APPEND_KV):
                 k_new = jax.lax.dynamic_update_slice(k_cache, k, (0, start, 0, 0))
                 v_new = jax.lax.dynamic_update_slice(v_cache, v, (0, start, 0, 0))
             with jax.named_scope(hot.ATTN_KERNEL):
                 attn = _cached_attention(q, k_new, v_new, q_pos)
-            return mm(attn.reshape(b, t, h * hd), layer["wo"]), (k_new, v_new)
+            out = llama.scaled(mm(attn.reshape(b, t, h * hd), layer["wo"]), cfg.attention_out_multiplier)
+            return out, (k_new, v_new)
 
     x, (k_cache, v_cache) = hyper.residual(cfg, layer, "attn", x, attend)
     x, _aux = hyper.residual(cfg, layer, "mlp", x, functools.partial(_feed_forward, cfg, layer))
@@ -121,7 +124,7 @@ def forward_with_cache(
     b, t = tokens.shape
     S = cache["k"].shape[2]
     with jax.named_scope(hot.EMBED):
-        x = hyper.expand(cfg, params["embed"][tokens].astype(cfg.dtype))
+        x = hyper.expand(cfg, llama.scaled(params["embed"][tokens].astype(cfg.dtype), cfg.embedding_multiplier))
     q_pos = start + jnp.arange(t)
     cos_full, sin_full = llama.rope_table(cfg, S)
     cos = jax.lax.dynamic_slice_in_dim(cos_full, start, t, axis=0)
@@ -148,7 +151,7 @@ def forward_with_cache(
             logits = jnp.einsum(
                 "btd,dv->btv", x, head, preferred_element_type=jnp.float32
             )
-    return logits, {"k": k_new, "v": v_new}
+    return llama.scaled(logits, cfg.lm_head_multiplier), {"k": k_new, "v": v_new}
 
 
 @jax.named_scope(hot.SAMPLE)
@@ -347,7 +350,7 @@ def layer_group_sizes(cfg: llama.LlamaConfig) -> dict[str, int]:
 
 
 def init_kv_pools(
-    cfg: llama.LlamaConfig, num_blocks: int, block_size: int, num_window_blocks: Optional[int] = None
+    cfg: llama.LlamaConfig, num_blocks: int, block_size: int, num_window_blocks: Optional[int] = None, slots: int = 0
 ) -> KVPools:
     """Zeroed paged pools (block 0 is the trash block — see
     :mod:`torchx_tpu.ops.paged_attention`). Grouped-query attention: K and V,
@@ -360,7 +363,17 @@ def init_kv_pools(
     of their own, handed out by an allocator of their own, since a slot holds
     there only the blocks its window touches. Every leaf leads with ``[layers,
     blocks, block_size]``: the engine allocates, copies, exports and imports
-    blocks over the tree a cache kind at a time."""
+    blocks over the tree a cache kind at a time. Layers with a state-space mixer
+    (``cfg.ssm_heads``) keep its store beside them under ``"ssm"``, ``1 + slots``
+    rows a layer (:func:`torchx_tpu.models.ssm.init_store`): addressed by slot,
+    not by block, and no part of what is allocated, copied, exported or imported."""
+    if cfg.ssm_heads:
+        return {**kv_pools(cfg, num_blocks, block_size), "ssm": ssm.init_store(cfg, 1 + slots)}
+    return kv_pools(cfg, num_blocks, block_size, num_window_blocks)
+
+
+def kv_pools(cfg: llama.LlamaConfig, num_blocks: int, block_size: int, num_window_blocks: Optional[int] = None) -> KVPools:
+    """:func:`init_kv_pools`' paged pools alone."""
     if cfg.kv_lora_rank:
         return {
             group: jnp.zeros((n, num_blocks, block_size, cfg.cache_width), dtype=cfg.dtype)
@@ -449,8 +462,16 @@ def _sample_rows(
 
 def _table_of(tables, layer: llama.Params):  # noqa: ANN001, ANN202
     """The block table of ``layer``'s cache kind: one array serves a stack of
-    one kind, ``{"full": .., "window": ..}`` a stack that mixes them."""
+    one kind, ``{"full": .., "window": ..}`` a stack that mixes them. Where the
+    layers have a state-space mixer the rows' state rows ride beside the one
+    table, ``{"full": .., "state": [rows] int32}`` (:func:`_state_rows`)."""
     return tables[layer["attn_kind"]] if isinstance(tables, dict) else tables
+
+
+def _state_rows(tables) -> Optional[jnp.ndarray]:  # noqa: ANN001
+    """The row of the mixer's store each row of ``tables`` reads and writes (a
+    slot's own, or the trash row 0): None for a model without a mixer."""
+    return tables.get("state") if isinstance(tables, dict) else None
 
 
 def _feed_forward(cfg: llama.LlamaConfig, layer: llama.Params, stream_in: jnp.ndarray):  # noqa: ANN202
@@ -506,6 +527,20 @@ class _Rows(NamedTuple):
         )
         return out.reshape(self.rows, *out.shape[2:])
 
+    def mix(self, cfg, layer, xbc, dt, store, at):  # noqa: ANN001, ANN201
+        """The rows through the layer's mixer between its projections
+        (``ssm.project``'s ``xbc`` and ``dt [rows, ...]``), each row from and to
+        its state in ``store`` -> ``(y [rows, H P], store)``. A chunk that starts
+        its sequence (position 0) starts from zeros: nothing else resets a row.
+        The rows of the store ride beside the block tables (:func:`_state_rows`):
+        a decode part's one a slot, a chunk part's one a sequence."""
+        state_rows = _state_rows(self.tables)
+        if self.valid is None:
+            return ssm.decode_rows(cfg, layer, xbc, dt, store, at, state_rows)
+        fresh = self.positions[:, 0] == 0
+        y, store = ssm.chunk_rows(cfg, layer, self._chunked(xbc), self._chunked(dt), store, at, state_rows, fresh, self.valid)
+        return y.reshape(self.rows, -1), store
+
 
 def _cat(xs: list[jnp.ndarray]) -> jnp.ndarray:
     return xs[0] if len(xs) == 1 else jnp.concatenate(xs)
@@ -522,14 +557,18 @@ def _paged_layer_step(
     # (under _scan_groups) the stack of its cache kind [layers, num_blocks, ...]
     k_pool: jnp.ndarray,
     v_pool: jnp.ndarray,
-) -> tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
+    store: Optional[ssm.Store],  # the mixer's store whole (read and written at layer["kind_index"]); None without a mixer
+) -> tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray, Optional[ssm.Store]]:
     """One layer over every part's rows at once: norms, projections, the
     output projection, the feed-forward (every row a group of its own: a
     capacity of one a group and expert never drops a routing) and the residual
     mix run once over all rows, so a chunk that rides a decode step reads no
     weight a second time. Between the projections each part writes its rows to
     the pools and attends them its own way (:class:`_Rows`): all writes, then
-    all reads, the parts' blocks being disjoint but for the trash block."""
+    all reads, the parts' blocks being disjoint but for the trash block. A
+    state-space mixer runs beside attention off the same norm and into the same
+    residual add, each part's rows from and to their own rows of ``store``, the
+    parts' rows being disjoint but for the trash row: a row has one writer."""
     window = llama.window_of(cfg, layer)
     bounds = np.cumsum([0] + [part.rows for part in parts])
     split = lambda a: [a[lo:hi] for lo, hi in zip(bounds, bounds[1:])] if len(parts) > 1 else [a]  # noqa: E731
@@ -555,10 +594,11 @@ def _paged_layer_step(
             else:
                 at = layer.get("kind_index")
                 h, kvh, hd = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
-                q = _project_heads(rows, layer["wq"], h, hd)
-                k = _project_heads(rows, layer["wk"], kvh, hd)
+                scaled_rows = llama.scaled(rows, cfg.attention_in_multiplier)
+                q = _project_heads(scaled_rows, layer["wq"], h, hd)
+                k = _project_heads(scaled_rows, layer["wk"], kvh, hd)
                 q, k = llama.norm_and_rotate(cfg, layer, q, k, cos, sin, _rope_rows)
-                v = _project_heads(rows, layer["wv"], kvh, hd)
+                v = _project_heads(scaled_rows, layer["wv"], kvh, hd)
                 k_new, v_new = k_pool, v_pool
                 for part, table, k_rows, v_rows in zip(parts, tables, split(k), split(v)):
                     k_new = part.cache(k_new, table, k_rows, at, bool(window))
@@ -568,15 +608,25 @@ def _paged_layer_step(
                     for part, table, q_rows in zip(parts, tables, split(q))
                 ])  # fmt: skip
                 pools = (k_new, v_new)
-            return mm(out.reshape(out.shape[0], 1, -1), layer["wo"]), pools
+            out = llama.scaled(mm(out.reshape(out.shape[0], 1, -1), layer["wo"]), cfg.attention_out_multiplier)
+        if store is None:
+            return out, (*pools, None)
+        with jax.named_scope(hot.SSM):
+            z, xbc, dt = ssm.project(cfg, layer, rows)
+            new_store, read_out = store, []
+            for part, xbc_rows, dt_rows in zip(parts, split(xbc), split(dt)):
+                y, new_store = part.mix(cfg, layer, xbc_rows, dt_rows, new_store, at)
+                read_out.append(y)
+            mixed = ssm.finish(cfg, layer, _cat(read_out), z)
+        return out + mixed[:, None, :], (*pools, new_store)
 
-    x, (k_pool, v_pool) = hyper.residual(cfg, layer, "attn", x, attend)
+    x, (k_pool, v_pool, store) = hyper.residual(cfg, layer, "attn", x, attend)
     x, _aux = hyper.residual(cfg, layer, "mlp", x, functools.partial(_feed_forward, cfg, layer))
-    return x, k_pool, v_pool
+    return x, k_pool, v_pool, store
 
 
 def _scan_groups(step, x, params: llama.Params, pools: KVPools, cfg: llama.LlamaConfig):  # noqa: ANN001, ANN202
-    """Run ``step(x, layer, k_pool, v_pool) -> (x, k_pool, v_pool)`` over every
+    """Run ``step(x, layer, k_pool, v_pool, store) -> (x, k_pool, v_pool, store)`` over every
     layer in the order the layers run, a group of the parameter tree at a time
     (``llama.scan_layers``: one scan a group of equal layers, a scan over whole
     periods where attention kinds alternate). The pools the group's layers
@@ -586,9 +636,11 @@ def _scan_groups(step, x, params: llama.Params, pools: KVPools, cfg: llama.Llama
     ``layer["kind_index"]`` where they lie, so nothing the size of a layer's
     pool is sliced out, copied or stacked back. A latent pool is its group's
     one array (read at ``layer["layer_index"]``) and goes through as ``k_pool``
-    with no ``v_pool``. ``ops.attention.traced("kv_pools")`` answers ``carried``."""
+    with no ``v_pool``. A mixer's store (``pools["ssm"]``, else None) rides the
+    carry whole beside them. ``ops.attention.traced("kv_pools")`` answers ``carried``."""
     note_traced("kv_pools", "carried")
     latent, mixed = bool(cfg.kv_lora_rank), bool(cfg.layer_types)
+    store = pools.get("ssm")
     first = 0
     for group in llama.layer_groups(params):
         n = jax.tree.leaves(params[group])[0].shape[0]
@@ -600,20 +652,20 @@ def _scan_groups(step, x, params: llama.Params, pools: KVPools, cfg: llama.Llama
             held = {"full": (pools["k"], pools["v"])}
 
         def scan_step(carry, layer, group=group):  # noqa: ANN001
-            x, held = carry
+            x, held, store = carry
             key = group if latent else layer["attn_kind"]
-            x, k_pool, v_pool = step(x, layer, *held[key])
-            return (x, {**held, key: (k_pool, v_pool)}), None
+            x, k_pool, v_pool, store = step(x, layer, *held[key], store)
+            return (x, {**held, key: (k_pool, v_pool)}, store), None
 
-        (x, held), _ = llama.scan_layers(cfg, scan_step, (x, held), params[group], first)
+        (x, held, store), _ = llama.scan_layers(cfg, scan_step, (x, held, store), params[group], first)
         if latent:
             pools = {**pools, group: held[group][0]}
         elif mixed:
             pools = {**pools, **{kind: {"k": k, "v": v} for kind, (k, v) in held.items()}}
         else:
-            pools = dict(zip(("k", "v"), held["full"]))
+            pools = {**pools, **dict(zip(("k", "v"), held["full"]))}
         first += n
-    return x, pools
+    return x, pools if store is None else {**pools, "ssm": store}
 
 
 @jax.named_scope(hot.LM_HEAD)
@@ -621,8 +673,8 @@ def _lm_head_rows(params: llama.Params, x: jnp.ndarray, cfg: llama.LlamaConfig):
     # [rows, d] -> [rows, vocab] f32, same head dispatch as forward_with_cache
     head = llama.lm_head(params, cfg)
     if isinstance(head, dict):  # int8-quantized lm_head: keep f32 accum
-        return mm(x, head, out_dtype=jnp.float32)
-    return jnp.einsum("rd,dv->rv", x, head, preferred_element_type=jnp.float32)
+        return llama.scaled(mm(x, head, out_dtype=jnp.float32), cfg.lm_head_multiplier)
+    return llama.scaled(jnp.einsum("rd,dv->rv", x, head, preferred_element_type=jnp.float32), cfg.lm_head_multiplier)
 
 
 def _decode_rows(cfg: llama.LlamaConfig, positions: jnp.ndarray, tables) -> _Rows:  # noqa: ANN001
@@ -644,7 +696,8 @@ def _paged_stream(params: llama.Params, tokens: jnp.ndarray, parts: tuple[_Rows,
     """``tokens [rows]``, the parts' one after another, through the layer
     stack -> (the stream ahead of the final norm ``[rows, d]``, the pools)."""
     with jax.named_scope(hot.EMBED):
-        x = hyper.expand(cfg, params["embed"][tokens].astype(cfg.dtype)[:, None, :])  # [rows, 1, d]
+        x = llama.scaled(params["embed"][tokens].astype(cfg.dtype), cfg.embedding_multiplier)
+        x = hyper.expand(cfg, x[:, None, :])  # [rows, 1, d]
     cos, sin = _cat([part.cos for part in parts]), _cat([part.sin for part in parts])
     x, pools = _scan_groups(functools.partial(_paged_layer_step, cfg, parts, cos, sin), x, params, pools, cfg)
     return hyper.collapse(cfg, x)[:, 0, :], pools

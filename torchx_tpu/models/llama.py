@@ -34,7 +34,7 @@ import jax.numpy as jnp
 from jax.ad_checkpoint import checkpoint_name
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from torchx_tpu.models import hyper
+from torchx_tpu.models import hyper, ssm
 from torchx_tpu.obs import hot
 from torchx_tpu.parallel import mesh as mesh_lib
 from torchx_tpu.ops.attention import attention
@@ -144,10 +144,49 @@ class LlamaConfig:
     hc_sinkhorn_iters: int = 0
     hc_eps: float = 1e-6
     hc_res_clamp: tuple[float, float] = (-30.0, 30.0)
+    # a Mamba-2 mixer (models/ssm.py) beside attention in every layer when ssm_heads > 0,
+    # both off the layer's one norm and into its one residual add: ssm_heads heads of
+    # ssm_head_dim channels over ssm_groups groups of ssm_state-wide input and output maps,
+    # a depthwise causal convolution of ssm_conv taps ahead of them; the chunked form
+    # multiplies inside chunks of ssm_chunk positions. What a sequence carries is a state
+    # [heads, head_dim, state] in float32 and the convolution's last ssm_conv - 1 inputs:
+    # a serving engine keeps them a slot, beside the paged K/V. 0: attention alone
+    ssm_heads: int = 0
+    ssm_head_dim: int = 0
+    ssm_state: int = 0
+    ssm_groups: int = 1
+    ssm_conv: int = 4
+    ssm_chunk: int = 128
+    # fixed multipliers on the model's paths (maximal-update parametrisation): the
+    # embedding's rows, the logits, attention's input, its keys ahead of the rotation and
+    # its output, the mixer's input, the five segments of its projection (z, x, B, C, dt)
+    # and its output, the feed-forward's gate ahead of its activation and its output.
+    # 1.0 multiplies nothing: the program of a model without them is as it was
+    embedding_multiplier: float = 1.0
+    lm_head_multiplier: float = 1.0
+    attention_in_multiplier: float = 1.0
+    attention_out_multiplier: float = 1.0
+    key_multiplier: float = 1.0
+    ssm_in_multiplier: float = 1.0
+    ssm_out_multiplier: float = 1.0
+    ssm_multipliers: tuple[float, ...] = (1.0, 1.0, 1.0, 1.0, 1.0)
+    mlp_multipliers: tuple[float, float] = (1.0, 1.0)
 
     def __post_init__(self) -> None:
         object.__setattr__(self, "layer_types", tuple(self.layer_types))
         object.__setattr__(self, "hc_res_clamp", tuple(float(v) for v in self.hc_res_clamp))
+        object.__setattr__(self, "ssm_multipliers", tuple(float(v) for v in self.ssm_multipliers))
+        object.__setattr__(self, "mlp_multipliers", tuple(float(v) for v in self.mlp_multipliers))
+        if len(self.ssm_multipliers) != 5 or len(self.mlp_multipliers) != 2:
+            raise ValueError("ssm_multipliers has five entries (z, x, B, C, dt) and mlp_multipliers two (gate, down)")
+        if self.ssm_heads:
+            if not (self.ssm_head_dim and self.ssm_state) or self.ssm_heads % self.ssm_groups or self.ssm_conv < 2:
+                raise ValueError("a mixer needs ssm_head_dim, ssm_state, ssm_conv >= 2 and ssm_heads a multiple of ssm_groups")
+            if self.kv_lora_rank or self.layer_types or self.hc_mult or self.kernels != "reference" or self.use_ring_attention:
+                raise ValueError(
+                    "the mixer runs beside full grouped-query attention through the stock layer ops: not beside latent"
+                    " attention, sliding layers, hyper-connections, the fused or the ring kernels"
+                )
         if self.hc_mult and (self.kernels != "reference" or self.use_ring_attention):
             raise ValueError("hyper-connections run through the stock layer ops only, not the fused or ring kernels")
         if self.q_lora_rank and not self.kv_lora_rank:
@@ -179,6 +218,16 @@ class LlamaConfig:
     def head_dim(self) -> int:
         """Per-head projection width (dim / n_heads unless stated)."""
         return self.attn_head_dim or self.dim // self.n_heads
+
+    @property
+    def ssm_inner(self) -> int:
+        """Channels of the mixer: its heads times a head's width."""
+        return self.ssm_heads * self.ssm_head_dim
+
+    @property
+    def ssm_conv_width(self) -> int:
+        """What the mixer's convolution runs over: x, B and C side by side."""
+        return self.ssm_inner + 2 * self.ssm_groups * self.ssm_state
 
     @property
     def rope_dim(self) -> int:
@@ -256,6 +305,7 @@ class LlamaConfig:
             + 3 * d * f  # gate, up, down
             + 2 * d  # norms
             + (2 * ((n * d + 1) * (2 * n + n * n) + 3) if n else 0)  # hyper-connections: phi, b, a of two sublayers
+            + ssm.param_count(self)
         )
         total = self.n_layers * per_layer + v * d + d  # embed + final norm
         if not self.tie_embeddings:
@@ -380,6 +430,7 @@ def init_params(cfg: LlamaConfig, key: jax.Array) -> Params:
             "w_up": norm_init(ks[5], (L, d, f), d),
             "w_down": norm_init(ks[6], (L, f, d), f),
             **hyper.init_leaves(cfg, jax.random.fold_in(k_layers, 7), L),
+            **ssm.init_leaves(cfg, jax.random.fold_in(k_layers, 8), L),
         },
         "final_norm": jnp.ones((d,), dtype=cfg.dtype),
     }
@@ -440,6 +491,11 @@ def param_specs(cfg: LlamaConfig, pp: bool = False) -> Params:
             "w_down": P(layer_axis, "tp", "fsdp"),
             # a sublayer's mixing coefficients are every chip's: a few rows a token, read whole
             **{name: P(layer_axis, *(None,) * len(shape)) for name, shape in hyper.leaf_shapes(cfg).items()},
+            # the mixer's heads and groups are not split: its two matrices shard their model axis, the rest is every chip's
+            **{
+                name: P(layer_axis, *{"ssm_in": ("fsdp", None), "ssm_out": (None, "fsdp")}.get(name, (None,) * len(shape)))
+                for name, shape in ssm.leaf_shapes(cfg).items()
+            },
         },
         "final_norm": P(None),
     }
@@ -616,11 +672,17 @@ def ffn(
         return moe_ffn(cfg, layer, mlp_in)
 
     i8 = cfg.int8_matmuls
+    gate_by, down_by = cfg.mlp_multipliers
     with jax.named_scope(hot.MLP):
-        gate = jax.nn.silu(maybe_matmul(mlp_in, layer["w_gate"], int8_training=i8))
+        gate = jax.nn.silu(scaled(maybe_matmul(mlp_in, layer["w_gate"], int8_training=i8), gate_by))
         up = maybe_matmul(mlp_in, layer["w_up"], int8_training=i8)
-        down = maybe_matmul(gate * up, layer["w_down"], int8_training=i8)
+        down = scaled(maybe_matmul(gate * up, layer["w_down"], int8_training=i8), down_by)
     return down, jnp.zeros((AUX_LEN,), jnp.float32)  # aux vector: dense = zeros
+
+
+def scaled(x: jnp.ndarray, by: float) -> jnp.ndarray:
+    """``x`` times one of the model's fixed multipliers; 1.0 multiplies nothing."""
+    return x if by == 1.0 else x * by
 
 
 def window_of(cfg: LlamaConfig, layer: Params) -> int:
@@ -630,11 +692,13 @@ def window_of(cfg: LlamaConfig, layer: Params) -> int:
 
 
 def norm_and_rotate(cfg: LlamaConfig, layer: Params, q, k, cos, sin, rope):  # noqa: ANN001, ANN201
-    """One layer's q and k ``[..., heads, hd]`` as attention takes them: normed
+    """One layer's q and k ``[..., heads, hd]`` as attention takes them: the keys
+    times the model's ``key_multiplier`` where it has one, normed
     a head where the model has QK-norm, then rotated by ``rope(x, cos, sin)``
     where this layer takes the rotary embedding (every layer, or with
     ``rope_full_layers`` off the sliding ones alone). The uncached forward and
     both serving programs share it, each with the rotation of its own layout."""
+    k = scaled(k, cfg.key_multiplier)
     if cfg.qk_norm:
         with jax.named_scope(hot.QK_NORM):
             q = rms_norm(q, layer["q_norm"], cfg.norm_eps)
@@ -731,7 +795,11 @@ def _layer(
                 from torchx_tpu.models import mla
 
                 return mla.attention_full(cfg, layer, attn_in, cos, sin), None
-            return _gqa_attention(cfg, mesh, cos, sin, attn_in, layer), None
+            out = _gqa_attention(cfg, mesh, cos, sin, scaled(attn_in, cfg.attention_in_multiplier), layer)
+            out = scaled(out, cfg.attention_out_multiplier)
+        if cfg.ssm_heads:  # the mixer reads the same norm and adds into the same residual
+            out = out + ssm.forward(cfg, layer, attn_in)
+        return out, None
 
     def feed_forward(stream_in):  # noqa: ANN001, ANN202 - dense SwiGLU, or MoE when the config carries experts
         with jax.named_scope(hot.NORM):
@@ -829,7 +897,8 @@ def forward_features(
     with jax.named_scope(hot.EMBED):
         table = _constraint(params["embed"], mesh, None, None)
         x = _constraint(table[tokens], mesh, ("dp", "fsdp"), seq_spec, None)
-    return features_from_embeddings(params, x.astype(cfg.dtype), cfg, mesh)
+        x = scaled(x.astype(cfg.dtype), cfg.embedding_multiplier)
+    return features_from_embeddings(params, x, cfg, mesh)
 
 
 def features_from_embeddings(
@@ -947,6 +1016,7 @@ def forward_from_embeddings(
         logits = jnp.einsum(
             "bsd,dv->bsv", x, lm_head(params, cfg), preferred_element_type=jnp.float32
         )
+        logits = scaled(logits, cfg.lm_head_multiplier)
     return _constraint(logits, mesh, ("dp", "fsdp"), "sp", "tp")
 
 
@@ -963,6 +1033,7 @@ def forward(
         logits = jnp.einsum(
             "bsd,dv->bsv", x, lm_head(params, cfg), preferred_element_type=jnp.float32
         )
+        logits = scaled(logits, cfg.lm_head_multiplier)
     # keep the vocab axis tp-sharded: the lm_head einsum produces it that
     # way, and all-gathering [b, s, vocab] f32 logits would cost ~GBs of
     # HBM + ICI per step at 128k vocab (log_softmax is fine sharded)
@@ -1034,6 +1105,7 @@ def loss_and_aux(
     aux[AUX_BALANCE] is scaled into the loss."""
     tokens = batch["tokens"]
     x, aux = forward_features(params, tokens[:, :-1], cfg, mesh)
+    x = scaled(x, cfg.lm_head_multiplier)  # on the head's input: the logits are made a chunk at a time
     aux_term = getattr(cfg, "router_aux_coef", 0.0) * aux[AUX_BALANCE]
     targets = tokens[:, 1:]
     head = lm_head(params, cfg)

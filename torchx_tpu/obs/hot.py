@@ -119,6 +119,12 @@ HC_PRE = "hc_pre"  # hyper-connections, a sublayer: the stream's norm, its proje
 HC_SINKHORN = "hc_sinkhorn"  # ... the write-back matrix made doubly stochastic
 HC_POST = "hc_post"  # ... the write-back: the streams mixed, the sublayer's output added
 HC_HEAD = "hc_head"  # the streams summed ahead of the final norm
+SSM = "ssm"  # the whole state-space mixer of a layer, beside attn
+SSM_PROJ = "ssm_proj"  # under ssm: its input and output projections
+SSM_CONV = "ssm_conv"  # ... the depthwise causal convolution and its activation
+SSM_STEP = "ssm_step"  # ... one position a row: the rows' state read, moved on, read out and written back
+SSM_SCAN = "ssm_scan"  # ... many positions a row in chunks: the same for a chunk of a prompt or a whole sequence
+SSM_GATE_NORM = "ssm_gate_norm"  # ... the gate and the grouped norm ahead of the output projection
 APPEND_LATENT = "append_latent"
 PAGED_ATTENTION = "paged_attention"
 GATHER_KV = "gather_kv"
@@ -134,6 +140,7 @@ DEVICE_SCOPES = (
     MOE_EXPERTS, MOE_COMBINE, PAGED_ATTENTION, GATHER_KV, SCORES, VALUES,
     APPEND_KV, SAMPLE, GRAD_CLIP, OPTIMIZER, MOE_SORT, MOE_SHARED, MLA_LATENT, MLA_ABSORB,
     APPEND_LATENT, ATTN_WINDOW, ATTN_FULL, QK_NORM, MLA_Q_LATENT, HC_PRE, HC_SINKHORN, HC_POST, HC_HEAD,
+    SSM, SSM_PROJ, SSM_CONV, SSM_STEP, SSM_SCAN, SSM_GATE_NORM,
 )  # fmt: skip
 
 

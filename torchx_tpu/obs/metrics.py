@@ -508,6 +508,13 @@ SERVE_KV_BLOCKS_USED = REGISTRY.gauge(
     "paged KV-cache blocks held by active sequences",
 )
 
+#: recurrent state the engine holds beside its paged K/V, over all slots' rows
+#: (state-space layers; 0 for a model whose every cache is attention's).
+SERVE_STATE_BYTES = REGISTRY.gauge(
+    "tpx_serve_state_bytes",
+    "bytes of recurrent (state-space) state held for the engine's slots",
+)
+
 #: decode tokens produced, by phase ("prefill" first tokens vs "decode").
 SERVE_TOKENS = REGISTRY.counter(
     "tpx_serve_tokens_total",

@@ -106,16 +106,18 @@ def kernel_eligible(
     """Whether :func:`paged_attention` lowers to the Pallas kernel: a pure
     function of shapes, dtypes and backend. The kernel needs a TPU, lanes
     full of one head (``hd`` a multiple of 128), query heads that group
-    evenly over the cache heads, the 8 cache heads of one position filling
-    one sublane tile (so a block lands in VMEM as whole ``[bs * kvh, hd]``
-    rows), a block of whole packed tiles, and bf16 or float32 throughout."""
+    evenly over the cache heads, the cache heads of one position a whole
+    number of packed sublane rows (4 or a multiple: a block then lies in HBM
+    and lands in VMEM as whole ``[bs * kvh, hd]`` rows; the chip's compiler
+    takes 8 heads a position and, since PR 41, 4: 20 query heads over 4), a
+    block of whole packed tiles, and bf16 or float32 throughout."""
     _, h, hd = q_shape
     _, bs, kvh, _ = pool_shape
     return (
         backend == "tpu"
         and hd % 128 == 0
         and h % kvh == 0
-        and kvh % 8 == 0
+        and kvh % 4 == 0
         and bs % 8 == 0
         and q_dtype == pool_dtype
         and pool_dtype in (jnp.bfloat16, jnp.float32)

@@ -59,6 +59,19 @@ This engine keeps a **fixed slot array** decoding continuously:
   between two chunks of a prompt, whose blocks are staged block ``b`` at entry
   ``b`` until its last chunk hands those still in reach to the ring).
   Preemption frees both; a prefix-cache node holds a block of each;
+* **recurrent state beside the paged K/V**: where the layers have a state-space
+  mixer (``cfg.ssm_heads``), slot ``i`` owns row ``i + 1`` of the mixer's store
+  (:func:`torchx_tpu.models.ssm.init_store`; row 0 is the trash row, as block 0
+  is the trash block), addressed by slot and not by position. **A row has one
+  writer a step**: a step's decode part addresses the trash row for every slot
+  that is not decoding (empty, or mid-prompt), so a slot whose prompt is being
+  fed is written by its chunk alone. A chunk that starts at position 0 starts
+  from zeros inside the program, which is the whole of a reset: for a new
+  tenant, and for a preempted request, which is fed again from position 0. The
+  step in flight behind an EOS moves a row on that nobody reads again. State is
+  not yet cached or handed off: such an engine has no prefix cache (a hit would
+  bring K/V without the state that goes with it; ``stats()`` says so) and
+  refuses ``prefill_only`` requests and :meth:`ServeEngine.submit_prefilled`;
 * **disaggregation seams**: a request marked ``prefill_only`` completes
   with its first token, its KV blocks exported as a
   :class:`~torchx_tpu.serve.kv_transfer.KvPayload` (the prefill-replica
@@ -307,7 +320,12 @@ class ServeEngine:
             # every slot's ring, and the prompts being fed staged whole
             num_window_blocks = 1 + max_slots * self.window_ring + self.max_prefill_batch * self.blocks_per_slot
         self.num_window_blocks = num_window_blocks if self.window else 0
-        self.pools = gen.init_kv_pools(cfg, num_blocks, block_size, self.num_window_blocks)
+        self.pools = gen.init_kv_pools(cfg, num_blocks, block_size, self.num_window_blocks, max_slots)
+        #: recurrent state a slot holds whatever its length (a mixer's store), and over all rows
+        store = jax.tree.leaves(self.pools.get("ssm", ()))
+        self.state_bytes_per_slot = sum(p.nbytes // p.shape[1] for p in store)
+        self.state_bytes = sum(p.nbytes for p in store)
+        obs_metrics.SERVE_STATE_BYTES.set(self.state_bytes)
         row_bytes = lambda pools: sum(  # noqa: E731 - a token's bytes over the layers of a pool tree
             p.shape[0] * math.prod(p.shape[3:]) * p.dtype.itemsize for p in jax.tree.leaves(pools)
         )
@@ -325,7 +343,14 @@ class ServeEngine:
         self._slots: list[Optional[_SlotState]] = [None] * max_slots
         self._admit_counter = itertools.count()
         self.prefix_cache: Optional[PrefixCache] = None
-        if enable_prefix_cache:
+        #: why there is no prefix cache though one was asked for, else None
+        self.prefix_cache_off: Optional[str] = None
+        if enable_prefix_cache and self.state_bytes:
+            self.prefix_cache_off = (
+                "recurrent state: the cache indexes K/V blocks alone, and a hit would hand a request K/V without"
+                " the state that goes with it"
+            )
+        elif enable_prefix_cache:
             cap = (
                 max(1, int(prefix_cache_reserve * num_blocks))
                 if prefix_cache_reserve > 0
@@ -432,11 +457,17 @@ class ServeEngine:
         return max(0, query_pos - self.window + 1) // self.block_size
 
     def _pools_of(self, kind: str):  # noqa: ANN202
-        """The pools of one cache kind: the whole tree where the model has one kind."""
-        return self.pools[kind] if self.window else self.pools
+        """The paged pools of one cache kind: the whole tree where the model has
+        one kind, a mixer's store (addressed by slot, not by block) left out."""
+        if self.window:
+            return self.pools[kind]
+        return {name: pool for name, pool in self.pools.items() if name != "ssm"}
 
-    def _tables_arg(self, full, window):  # noqa: ANN001, ANN202
-        """What the programs take as ``tables``: one array, or one a cache kind."""
+    def _tables_arg(self, full, window, state_rows=None):  # noqa: ANN001, ANN202
+        """What the programs take as ``tables``: one array, or one a cache kind;
+        with a mixer the rows' state rows beside the one table."""
+        if self.state_bytes:
+            return {"full": jnp.asarray(full), "state": jnp.asarray(state_rows, jnp.int32)}
         return {"full": jnp.asarray(full), "window": jnp.asarray(window)} if self.window else jnp.asarray(full)
 
     # -- public API --------------------------------------------------------
@@ -462,6 +493,8 @@ class ServeEngine:
             )
         if req.max_new_tokens < 1:
             raise ValueError("max_new_tokens must be >= 1")
+        if req.prefill_only:
+            self._refuse_handoff()
         with self._lock:
             if self.failed is not None:
                 raise EngineStopped(self.failed)
@@ -490,6 +523,7 @@ class ServeEngine:
         with no prefill pass. Raises :class:`EngineStopped` while
         draining — the transfer sender requeues to another decode
         target (the disaggregated drain-race contract)."""
+        self._refuse_handoff()
         n_need = math.ceil(cache_len / self.block_size)
         if k.shape[1] != n_need or v.shape[1] != n_need:
             raise ValueError(
@@ -512,6 +546,14 @@ class ServeEngine:
             self._handoffs.append(_Handoff(req, k, v, cache_len, last_tok))
         self._work.set()
         return req
+
+    def _refuse_handoff(self) -> None:
+        """A hand-off carries K/V blocks and no recurrent state."""
+        if self.state_bytes:
+            raise NotImplementedError(
+                "a model with state-space layers is not handed off: a KvPayload carries K/V blocks and not the"
+                " recurrent state that goes with them"
+            )
 
     def _admit_handoffs(self) -> bool:
         """Place transferred prefills into free slots: scatter the
@@ -612,12 +654,16 @@ class ServeEngine:
                 "kv_bytes_per_token": self.kv_bytes_per_token,
                 "kv_bytes_per_slot_window": self.kv_bytes_per_slot_window,
                 "kv_blocks_window_used": self.window_alloc.used_blocks if self.window else 0,
+                "state_bytes_per_slot": self.state_bytes_per_slot,
+                "state_bytes": self.state_bytes,
                 **self._kv_blocks(),
                 "draining": self._draining,
                 "failed": self.failed,
             }
         if self.prefix_cache is not None:
             out["prefix_cache"] = self.prefix_cache.stats()
+        elif self.prefix_cache_off:
+            out["prefix_cache_off"] = self.prefix_cache_off
         return out
 
     def prefix_summary(self, max_entries: int = 128) -> list[str]:
@@ -727,6 +773,8 @@ class ServeEngine:
             "kv_blocks_full": self.tables.held_blocks,
             "kv_blocks_window": self.window_tables.held_blocks + staged if self.window else 0,
             "window_blocks_released": self.window_blocks_released,
+            # what a slot holds beside its blocks whatever its length; not there without a mixer
+            **({"state_bytes_per_slot": self.state_bytes_per_slot} if self.state_bytes else {}),
         }
 
     def _release_slot(self, slot: int) -> _SlotState:
@@ -834,6 +882,7 @@ class ServeEngine:
     ) -> KvPayload:
         """Snapshot the prefilled K/V blocks for transfer to a decode
         replica (the ``prefill_only`` completion path)."""
+        self._refuse_handoff()
         k, v = gen.export_blocks(
             self.pools, np.asarray(blocks, np.int32), self._cfg.layer_types and self._cfg.cache_kinds,
             self._window_ids(window_blocks, len(blocks)),
@@ -888,7 +937,7 @@ class ServeEngine:
         the cache adopts whole blocks only)."""
         with hot.span(hot.SERVE_COW_COPY):
             copied = jax.tree.map(lambda p: p.at[:, dst].set(p[:, src]), self._pools_of("full"))
-            self.pools = {**self.pools, "full": copied} if self.window else copied
+            self.pools = {**self.pools, **({"full": copied} if self.window else copied)}
 
     def _ensure_capacity(self, slot: int, write_pos: int) -> bool:
         """Make sure ``slot`` holds a *writable* block for ``write_pos``:
@@ -1009,6 +1058,9 @@ class ServeEngine:
                 # it lies
                 tables = self.tables.tables.copy()
                 window_tables = self.window_tables.tables.copy() if self.window else None
+                # a mixer's state row a slot: its own (slot + 1) while it decodes, else the
+                # trash row, so that a slot being fed is written by its chunk alone
+                state_rows = np.zeros((self.max_slots,), np.int32)
                 stepping: list[tuple[int, _SlotState]] = []
                 for slot, st in enumerate(self._slots):
                     if st is None:
@@ -1025,6 +1077,7 @@ class ServeEngine:
                     positions[slot] = st.cache_len + st.unfetched
                     seeds[slot] = _seed32(st.req)
                     temps[slot] = st.req.temperature
+                    state_rows[slot] = slot + 1
                     stepping.append((slot, st))
 
                 chunk = self._next_chunk()
@@ -1053,7 +1106,7 @@ class ServeEngine:
                         # with no step in flight every slot reads the host's token
                         host_tokens if before is None else before.nxt,
                         jnp.asarray(positions),
-                        self._tables_arg(tables, window_tables),
+                        self._tables_arg(tables, window_tables, state_rows),
                         self.pools,
                         jnp.asarray(seeds),
                         jnp.asarray(temps),
@@ -1066,7 +1119,7 @@ class ServeEngine:
                             *args,
                             jnp.asarray(chunk_tokens),
                             jnp.asarray(at),
-                            self._tables_arg(chunk_full, chunk_window[None] if self.window else None),
+                            self._tables_arg(chunk_full, chunk_window[None] if self.window else None, [c_slot + 1]),
                         )
                     for _, st in stepping:
                         st.unfetched += 1
