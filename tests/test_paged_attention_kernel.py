@@ -20,6 +20,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from jax.experimental.pallas import tpu as pltpu
 
 from torchx_tpu.models import generate as gen
 from torchx_tpu.models import llama, moe
@@ -73,6 +74,14 @@ def _poison(k, v, tables, lengths, bs):
     return k, v
 
 
+def _shrink_the_kernels_geometry(monkeypatch, rows, row_bytes):
+    """Chunks of 4 blocks in two parts of two groups of one, so that a table of 10-12 blocks spans several of each."""
+    monkeypatch.setattr(pk, "_CHUNK_BYTES", 4 * rows * row_bytes)
+    monkeypatch.setattr(pk, "_PART_ROWS", 2 * rows)
+    monkeypatch.setattr(pk, "_GROUP_BYTES", rows * row_bytes)
+    assert pk._geometry(rows, row_bytes, 10) == (4, 2, 1)
+
+
 # window = bs * bpr; the kernel's chunk is shrunk to 4 blocks below, so
 # "mid" spans several chunks and "full" ends on the table's last entry
 def _case(id, lengths, h=8, kvh=2, bs=16, bpr=12, dtype=jnp.float32, **kw):
@@ -110,7 +119,7 @@ def test_kernel_matches_the_xla_function(case, monkeypatch):
     dtype, bs = case.pop("dtype"), case["bs"]
     poison, inactive = case.pop("poison", False), case.pop("inactive", ())
     if not case.pop("real_chunk", False):
-        monkeypatch.setattr(pk, "_CHUNK_BYTES", 4 * bs * case["kvh"] * HD * jnp.dtype(dtype).itemsize)
+        _shrink_the_kernels_geometry(monkeypatch, bs * case["kvh"], HD * jnp.dtype(dtype).itemsize)
     q, k, v, tables, lengths = _problem(dtype=dtype, **case)
     for i in inactive:  # as the engine leaves a slot nobody holds
         tables[i, :] = pa.TRASH_BLOCK
@@ -139,7 +148,7 @@ def test_kernel_reads_its_layer_out_of_the_stack(dtype, monkeypatch):
     function at that layer: ragged lengths, a slot of length 0, an inactive slot on the
     trash block, which holds NaN in every layer."""
     bs, kvh, h, bpr, layers = 16, 2, 8, 12, 3
-    monkeypatch.setattr(pk, "_CHUNK_BYTES", 4 * bs * kvh * HD * jnp.dtype(dtype).itemsize)
+    _shrink_the_kernels_geometry(monkeypatch, bs * kvh, HD * jnp.dtype(dtype).itemsize)
     lengths = [100, 0, 17, 1, 192]
     problems = [_problem(lengths, h, kvh, bs, bpr, dtype, seed=s) for s in range(layers)]
     q, _, _, tables, lens = problems[0]  # one query and one set of tables; a layer's K and V differ
@@ -183,6 +192,179 @@ def test_writes_land_in_their_layer_of_the_stack_and_nowhere_else():
             assert not np.array_equal(got[i], stack[i])
             others = [j for j in range(3) if j != i]
             np.testing.assert_array_equal(got[jnp.asarray(others)], stack[jnp.asarray(others)])
+
+
+# -- the copies' bookkeeping, in the TPU interpreter -------------------------------------
+# Every edge the kernel's bookkeeping has, at its real geometry (float32 pools: two buffers of 512 KiB of K
+# and of V, multiplied 1,024 rows at a time, copied in groups of 128 KiB: a chunk of 256 positions in two
+# parts of four groups at 4 cache heads, of 128 in one part of four groups at 8). A case is a function of
+# the positions a group, a part and a chunk hold.
+
+EDGES = [
+    pytest.param(lambda g, p, c: [0, 1, 40], 40, id="no-length-then-one-position"),
+    pytest.param(lambda g, p, c: [g - 1, g, g + 1], 40, id="one-short-of-a-group-a-group-and-one-past"),
+    pytest.param(lambda g, p, c: [p - 1, p, p + 1], 40, id="one-short-of-a-part-a-part-and-one-past"),
+    pytest.param(lambda g, p, c: [c - 1, c, c + 1], 40, id="one-short-of-a-chunk-a-chunk-and-one-past"),
+    pytest.param(lambda g, p, c: [640, 16], 40, id="every-block-of-the-table"),
+    pytest.param(lambda g, p, c: [2 * c + 100], 40, id="one-slot-more-chunks-than-buffers"),
+    pytest.param(lambda g, p, c: [2 * c - 7, 5], 40, id="long-then-short"),
+    pytest.param(lambda g, p, c: [5, 2 * c - 7], 40, id="short-then-long"),
+    pytest.param(lambda g, p, c: [p, c + p, c + p, p], 40, id="whole-parts-only"),
+    pytest.param(lambda g, p, c: [c, 2 * c, c, 2 * c], 40, id="whole-chunks-only-the-first-buffer-alternates"),
+    pytest.param(lambda g, p, c: [c + g + 3, 0, 0, p + 1], 40, id="slots-of-no-length-between"),
+    pytest.param(lambda g, p, c: [96, 1, 50, 81], 6, id="a-table-shorter-than-a-chunk"),
+    pytest.param(lambda g, p, c: [112, 97], 7, id="a-table-of-a-prime-number-of-blocks"),
+]
+NOT_A_BLOCK = 2**30  # in a table entry no live block reaches: an id that must never become an address
+
+
+def _held_in_the_tpu_interpreter(capfd, dma, want, live, q, k, v, tables, lens, **kw):
+    """``paged_attention_pallas`` in the TPU interpreter (semaphores simulated, scratch full of
+    NaN) against ``want`` on every slot that has positions, and nothing left over or raced for."""
+    got = pk.paged_attention_pallas(
+        q, k, v, tables, lens, **kw, interpret=pltpu.InterpretParams(dma_execution_mode=dma, detect_races=True))
+    np.testing.assert_allclose(np.asarray(got)[live], np.asarray(want)[live], **TOLERANCE[jnp.float32])
+    assert np.isfinite(np.asarray(got)[live]).all()
+    out = capfd.readouterr().out
+    assert "non-zero count" not in out and "RACE DETECTED" not in out, out
+
+
+@pytest.mark.parametrize("dma", ["on_wait", "eager"])
+@pytest.mark.parametrize("h,kvh", [pytest.param(20, 4, id="four-cache-heads"), pytest.param(16, 8, id="eight-cache-heads")])
+@pytest.mark.parametrize("lengths,bpr", EDGES)
+def test_kernel_bookkeeping_on_every_edge(lengths, bpr, h, kvh, dma, capfd):
+    """The kernel in the TPU interpreter, which simulates the copies' semaphores and hands out
+    scratch memory full of NaN, against the XLA function in float32. ``on_wait``: a copy lands
+    only when its semaphore is waited for, so a part read before its wait, or a group started
+    and never waited for, reads NaN or stale rows. ``eager``: every byte started must have been
+    waited for when the kernel ends, or the interpreter says so. (A wait for bytes nobody
+    started would hang here as on the chip.) The trash block holds NaN where no slot is empty,
+    and every table entry past a slot's live blocks is no block at all: past its last live
+    block a slot reads that block again, never the table."""
+    bs = 16
+    chunk, part, group = pk._geometry(bs * kvh, HD * 4, bpr)
+    lengths = lengths(group * bs, part * bs, chunk * bs)
+    assert max(lengths) <= bpr * bs
+    q, k, v, tables, lens = _problem(lengths, h, kvh, bs, bpr, jnp.float32)
+    want = pa.paged_attention_xla(*map(jnp.asarray, (q, k, v, tables, lens)))
+    if min(lengths) > 0:
+        k[pa.TRASH_BLOCK] = v[pa.TRASH_BLOCK] = np.nan
+    for row, n in zip(tables, lengths):
+        row[max(-(-n // bs), 1):] = NOT_A_BLOCK  # a slot of no length reads its first entry: the trash block
+    _held_in_the_tpu_interpreter(capfd, dma, want, lens > 0, *map(jnp.asarray, (q, k, v, tables, lens)))
+
+
+def _ring_problem(lengths, window, ring, h, kvh, bs=16, seed=0):
+    """Pools that hold only the blocks each slot's window touches, block ``b`` of the sequence
+    at ring entry ``b % ring`` and the positions of ``b`` below the length written: a pair with
+    zeros wherever nothing lies (the reference's), and a pair with NaN in the trash block, in
+    every block the window has left and, K's, in the unwritten tail of a slot's last block."""
+    rng = np.random.default_rng(seed)
+    nb = 1 + len(lengths) * ring
+    pools = rng.standard_normal((2, nb, bs, kvh, HD)).astype(np.float32)
+    written = np.zeros((nb, bs), bool)
+    tables = np.full((len(lengths), ring), pa.TRASH_BLOCK, np.int32)
+    perm = iter(rng.permutation(np.arange(1, nb)))
+    for i, n in enumerate(lengths):
+        for b in range(max(0, n - window) // bs, -(-n // bs)):
+            tables[i, b % ring] = blk = next(perm)
+            written[blk, : min(n, (b + 1) * bs) - b * bs] = True
+    clean = np.where(written[None, :, :, None, None], pools, 0.0)
+    poisoned = clean.copy()
+    poisoned[0][~written] = np.nan  # K wherever nothing lies; V must hold numbers in a live block's tail (0 * NaN is NaN)
+    poisoned[1][~written.any(axis=1)] = np.nan
+    q = rng.standard_normal((len(lengths), h, HD)).astype(np.float32)
+    return q, clean, poisoned, tables, np.asarray(lengths, np.int32)
+
+
+@pytest.mark.parametrize("dma", ["on_wait", "eager"])
+@pytest.mark.parametrize("h,kvh", [pytest.param(20, 4, id="four-cache-heads"), pytest.param(64, 8, id="eight-cache-heads")])
+@pytest.mark.parametrize("lengths", [
+    pytest.param([1, 15, 16, 17, 100], id="below-the-window"),
+    pytest.param([127, 128, 129, 0], id="at-the-window-and-a-slot-of-no-length"),
+    pytest.param([144, 145, 1000, 1007, 4096], id="well-past-the-window"),
+])  # fmt: skip
+def test_kernel_bookkeeping_on_the_ring_of_a_windowed_layer(lengths, h, kvh, dma, capfd):
+    """The same accounting on ``k-exaone``'s ring: 10 entries of 16 positions under a window of
+    128, which touches 8 or 9 of them; what is copied past a slot's last live block is that
+    block again, clamped before the ring's modulo, never an entry the window has left (NaN)."""
+    window, ring = 128, 10
+    q, clean, poisoned, tables, lens = _ring_problem(lengths, window, ring, h, kvh)
+    want = pa.paged_attention_xla(*map(jnp.asarray, (q, *clean, tables, lens)), None, window)
+    assert np.isnan(poisoned[:, pa.TRASH_BLOCK]).all()
+    _held_in_the_tpu_interpreter(capfd, dma, want, lens > 0, *map(jnp.asarray, (q, *poisoned, tables, lens)), window=window)
+
+
+@pytest.mark.parametrize("layer", [0, 2], ids=["first-layer", "last-layer"])
+def test_kernel_bookkeeping_into_a_stack(layer, capfd):
+    """The same accounting with the block ids moved into layer ``layer`` of a stack seen flat, up
+    to the stack's last block: the clip that stands in for the compiler's check admits it."""
+    bs, kvh, h, bpr = 16, 4, 20, 40
+    lengths = [300, 0, 513, 16]
+    problems = [_problem(lengths, h, kvh, bs, bpr, jnp.float32, seed=s) for s in range(3)]
+    q, _, _, tables, lens = problems[0]
+    tables[0, 0] = problems[0][1].shape[0] - 1  # the pool's last block is somebody's
+    k_stack, v_stack = (np.stack([p[i] for p in problems]) for i in (1, 2))
+    want = pa.paged_attention_xla(*map(jnp.asarray, (q, k_stack, v_stack, tables, lens)), jnp.int32(layer))
+    for row, n in zip(tables, lengths):
+        row[max(-(-n // bs), 1):] = NOT_A_BLOCK
+    _held_in_the_tpu_interpreter(
+        capfd, "eager", want, lens > 0, *map(jnp.asarray, (q, k_stack, v_stack, tables, lens)), layer=jnp.int32(layer))
+
+
+def test_no_copy_leaves_the_pool_whatever_a_table_holds(capfd):
+    """The compiler's bounds check in front of every copy is off, so the kernel clips every id:
+    a slot nobody reads (no length) copies its first entry's block, and where that entry is no
+    block at all, too large or negative, the copy still lands inside the pool and the other
+    slots' results are what they were."""
+    bs, kvh, h, bpr = 16, 4, 20, 40
+    q, k, v, tables, lens = _problem([70, 0, 0, 33], h, kvh, bs, bpr, jnp.float32)
+    want = pa.paged_attention_xla(*map(jnp.asarray, (q, k, v, tables, lens)))
+    tables[1, :], tables[2, :] = NOT_A_BLOCK, -7
+    _held_in_the_tpu_interpreter(capfd, "eager", want, lens > 0, *map(jnp.asarray, (q, k, v, tables, lens)))
+
+
+def test_one_pair_of_starts_is_traced_however_many_a_chunk_holds():
+    """A chunk at ``falcon-h1``'s shape is 32 blocks, 64 copies, started at two places in the
+    kernel. The loops multiply one traced pair: written out in Python the starts cost each of a
+    server's two programs 3 s of tracing at every start-up, warm compile cache or not, and the
+    cell's ``setup_s`` a fifth (PERF.md section 6, PR 42)."""
+    sds = jax.ShapeDtypeStruct
+    pool = sds((6, 8449, 16, 4, 128), jnp.bfloat16)
+    fn = lambda q, k, v, t, n, i: pk.paged_attention_pallas(q, k, v, t, n, layer=i)  # noqa: E731
+    jaxpr = jax.make_jaxpr(fn)(
+        sds((64, 20, 128), jnp.bfloat16), pool, pool, sds((64, 264), jnp.int32), sds((64,), jnp.int32), sds((), jnp.int32))
+    assert str(jaxpr).count("dma_start") == 4  # K and V, ahead of a slot's first chunk and ahead of every other
+
+
+GEOMETRY = [  # rows a block, bytes a row, the most blocks a slot has live -> blocks a chunk, a part, a group
+    pytest.param(16 * 4, 256, 264, (32, 16, 8), id="falcon-h1-serve-decode-long"),
+    pytest.param(16 * 8, 256, 264, (16, 8, 4), id="k-exaone-full"),
+    pytest.param(16 * 8, 256, 9, (9, 9, 3), id="k-exaone-what-a-window-of-128-touches"),
+    pytest.param(16 * 8, 256, 256, (16, 8, 4), id="mistral7b-serve-chat"),
+    pytest.param(16 * 8, 512, 128, (8, 8, 2), id="float32-pools"),
+    pytest.param(16 * 8, 256, 6, (6, 6, 3), id="a-short-table"),
+    pytest.param(16 * 8, 256, 7, (7, 7, 1), id="a-prime-table"),
+    pytest.param(32 * 16, 1024, 3, (1, 1, 1), id="a-block-larger-than-a-chunk"),
+]
+
+
+@pytest.mark.parametrize("rows,row_bytes,span,want", GEOMETRY)
+def test_the_kernels_geometry_is_a_function_of_the_shapes(rows, row_bytes, span, want):
+    assert pk._geometry(rows, row_bytes, span) == want
+
+
+def _geometry_said(bs, kvh, bpr):
+    """What ``traced("paged_geometry")`` says the one lowering since ``TRACED`` was emptied chose,
+    in blocks, held to the rule: whole groups a part, whole parts a chunk, no chunk longer than the table."""
+    said = attn_ops.traced("paged_geometry")
+    words = said.split()
+    assert words[0::2][:3] == ["chunk", "part", "group"] and said.endswith("rows, 2 buffers"), said
+    rows = [int(w) for w in words[1:6:2]]
+    assert all(r % (bs * kvh) == 0 for r in rows), said
+    chunk, part, group = (r // (bs * kvh) for r in rows)
+    assert chunk % part == 0 and part % group == 0 and 1 <= group and chunk <= bpr, said
+    return chunk, part, group
 
 
 # -- which path a call takes -----------------------------------------------------------
@@ -298,7 +480,8 @@ def one_chip():
     pytest.param(128, jnp.bfloat16, id="mixtral8x7b-serve-backlog"),
     pytest.param(256, jnp.float32, id="float32-pools"),
 ])  # fmt: skip
-def test_kernel_compiles_for_the_chip(one_chip, bpr, dtype):
+def test_kernel_compiles_for_the_chip(one_chip, bpr, dtype, monkeypatch):
+    monkeypatch.setattr(attn_ops, "TRACED", {})
     slots, h, kvh, bs, nb = 16, 32, 8, 16, 2049
     shape = lambda s, d: jax.ShapeDtypeStruct(s, d, sharding=one_chip)  # noqa: E731
     pool = shape((nb, bs, kvh, HD), dtype)
@@ -311,6 +494,7 @@ def test_kernel_compiles_for_the_chip(one_chip, bpr, dtype):
     # the pools go in as they lie in HBM: no relayout of 67 MB a layer in front of the kernel
     pool_type = f"{jnp.dtype(dtype).name.replace('bfloat16', 'bf16').replace('float32', 'f32')}[{nb},"
     assert not [ln for ln in text.splitlines() if " copy(" in ln and pool_type in ln]
+    assert _geometry_said(bs, kvh, bpr) == ((16, 8, 4) if dtype == jnp.bfloat16 else (8, 8, 2))
 
 
 # The latent-attention decode kernel and the grouped matmul of the expert layers (PR 27),
@@ -318,18 +502,39 @@ def test_kernel_compiles_for_the_chip(one_chip, bpr, dtype):
 # holds the TPU's library is the one that runs it.
 
 
-def test_kernel_compiles_for_the_chip_over_four_cache_heads(one_chip):
-    """``falcon-h1-serve-decode-long``: 64 slots of 20 query heads over 4 cache heads, into a
-    stack of six layers' pools, a block 64 rows of 128."""
+def _compiled_into_a_stack(one_chip, q_shape, pool_shape, bpr, window=0):
+    """The compiled program's text of the kernel lowered for the chip at a cell's shapes, handed
+    a stack of layers' pools and a traced layer, as the cells' decode programs call it; the
+    stack goes in as it lies in HBM (no relayout of it in front of the kernel)."""
     shape = lambda s, d: jax.ShapeDtypeStruct(s, d, sharding=one_chip)  # noqa: E731
-    pool = shape((6, 8449, 16, 4, 128), jnp.bfloat16)
-    assert pa.kernel_eligible((64, 20, 128), pool.shape[1:], jnp.dtype(jnp.bfloat16), pool.dtype, "tpu")
-    fn = lambda q, k, v, t, n, i: pk.paged_attention_pallas(q, k, v, t, n, layer=i)  # noqa: E731
+    pool = shape(pool_shape, jnp.bfloat16)
+    assert pa.kernel_eligible(q_shape, pool.shape[1:], jnp.dtype(jnp.bfloat16), pool.dtype, "tpu")
+    fn = lambda q, k, v, t, n, i: pk.paged_attention_pallas(q, k, v, t, n, layer=i, window=window)  # noqa: E731
     text = jax.jit(fn).lower(
-        shape((64, 20, 128), jnp.bfloat16), pool, pool, shape((64, 264), jnp.int32), shape((64,), jnp.int32), shape((), jnp.int32)
+        shape(q_shape, jnp.bfloat16), pool, pool, shape((q_shape[0], bpr), jnp.int32), shape((q_shape[0],), jnp.int32),
+        shape((), jnp.int32)
     ).compile().as_text()  # fmt: skip
     assert "paged_attention_decode" in text
-    assert not [ln for ln in text.splitlines() if " copy(" in ln and "bf16[6,8449," in ln]
+    stack_types = [f"bf16[{pool_shape[0]},{pool_shape[1]},", f"bf16[{pool_shape[0] * pool_shape[1]},"]  # as given, and seen flat
+    assert not [ln for ln in text.splitlines() if " copy(" in ln and any(t in ln for t in stack_types)]
+    return text
+
+
+def test_kernel_compiles_for_the_chip_over_four_cache_heads(one_chip, monkeypatch):
+    """``falcon-h1-serve-decode-long``: 64 slots of 20 query heads over 4 cache heads, into a
+    stack of six layers' pools, a block 64 rows of 128: 32 blocks a buffer, multiplied 16 at
+    a time, copied in groups of 8."""
+    monkeypatch.setattr(attn_ops, "TRACED", {})
+    _compiled_into_a_stack(one_chip, (64, 20, 128), (6, 8449, 16, 4, 128), 264)
+    assert _geometry_said(16, 4, 264) == (32, 16, 8)
+
+
+def test_kernel_compiles_for_the_chip_at_64_heads_over_eight(one_chip, monkeypatch):
+    """``k-exaone-serve-decode-long``'s full layers: 64 slots of 64 query heads over 8 cache
+    heads, tables of 264 blocks into the stack of its two full layers' pools."""
+    monkeypatch.setattr(attn_ops, "TRACED", {})
+    _compiled_into_a_stack(one_chip, (64, 64, 128), (2, 8449, 16, 8, 128), 264)
+    assert _geometry_said(16, 8, 264) == (16, 8, 4)
 
 
 def test_state_step_kernel_compiles_for_the_chip_in_place(one_chip):
@@ -353,16 +558,12 @@ def test_state_step_kernel_compiles_for_the_chip_in_place(one_chip):
     assert not [ln for ln in text.splitlines() if " copy(" in ln and "f32[6,65," in ln]
 
 
-def test_windowed_kernel_compiles_for_the_chip(one_chip):
-    """The new cell's sliding layers: 64 slots, rings of 10 blocks into a stack of six layers' window pools."""
-    shape = lambda s, d: jax.ShapeDtypeStruct(s, d, sharding=one_chip)  # noqa: E731
-    pool = shape((6, 1169, 16, 8, 128), jnp.bfloat16)
-    fn = lambda q, k, v, t, n, i: pk.paged_attention_pallas(q, k, v, t, n, layer=i, window=128)  # noqa: E731
-    text = jax.jit(fn).lower(
-        shape((64, 64, 128), jnp.bfloat16), pool, pool, shape((64, 10), jnp.int32), shape((64,), jnp.int32), shape((), jnp.int32)
-    ).compile().as_text()  # fmt: skip
-    assert "paged_attention_decode" in text
-    assert not [ln for ln in text.splitlines() if " copy(" in ln and "bf16[6,1169," in ln]
+def test_windowed_kernel_compiles_for_the_chip(one_chip, monkeypatch):
+    """``k-exaone-serve-decode-long``'s sliding layers: 64 slots, rings of 10 blocks into a stack of six layers'
+    window pools; a window of 128 touches 9 blocks at most, which is the chunk and the one part: three groups of three."""
+    monkeypatch.setattr(attn_ops, "TRACED", {})
+    _compiled_into_a_stack(one_chip, (64, 64, 128), (6, 1169, 16, 8, 128), 10, window=128)
+    assert _geometry_said(16, 8, 10) == (9, 9, 3)
 
 
 @pytest.mark.parametrize("slots,h,pool_shape", [
