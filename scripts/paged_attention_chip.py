@@ -10,7 +10,8 @@ A one-off measurement (PR 25), not a tool of the benchmark. On one TPU it
   slots x 32 heads over 8; ``falcon-h1``: 64 slots x 20 heads over 4 cache
   heads at ~1.45 k tokens a slot; ``k-exaone``: 64 slots x 64 heads over 8,
   its full layers' tables of 264 blocks and its sliding layers' rings of 10
-  under a window of 128), and at a window held full, each as a loop of calls
+  under a window of 128; ``evabyte``: 32 slots x 32 heads over 32 cache heads,
+  tables of 176 blocks, ~1.4 k rows a slot), and at a window held full, each as a loop of calls
   inside one program (the output of a call is the next call's query, so
   nothing is hoisted), and prints the live K/V bytes a call must read over
   its time as a share of the device's published HBM bandwidth, and the
@@ -120,6 +121,7 @@ def main() -> int:
         difference("h20.kvh4.bs16", 16, 20, 4, 128, 16, 264, ragged, dtype)
         difference("h64.kvh8.bs32", 16, 64, 8, 128, 32, 128, ragged, dtype)
         difference("h32.kvh16.bs8", 4, 32, 16, 128, 8, 64, [1, 9, 100, 512], dtype)
+        difference("h32.kvh32.bs16", 8, 32, 32, 128, 16, 176, [1, 15, 16, 17, 255, 700, 2047, 2816], dtype)
 
     def timed(fn, a):  # noqa: ANN001, ANN202
         @jax.jit
@@ -175,6 +177,8 @@ def main() -> int:
     chat = [int(x) for x in rng.integers(300, 1100, 11)] + [1] * 5
     backlog = [int(x) for x in rng.integers(100, 760, 16)]
     reasoning = [int(x) for x in rng.integers(300, 2600, 64)]
+    # evabyte: 32 slots whose rows are a window's (1-2,048) behind 128 pooled rows a finished window (1-5 of them)
+    eva_rows = [int(128 * w + r) for w, r in zip(rng.integers(1, 6, 32), rng.integers(1, 2049, 32))]
     shapes = {
         "chat": (16, 32, 8, 256, chat, 0),
         "backlog": (16, 32, 8, 128, backlog, 0),
@@ -182,6 +186,7 @@ def main() -> int:
         "falcon-h1": (64, 20, 4, 264, reasoning, 0),
         "k-exaone.full": (64, 64, 8, 264, reasoning, 0),
         "k-exaone.ring": (64, 64, 8, 264, reasoning, 128),
+        "evabyte": (32, 32, 32, 176, eva_rows, 0),  # one query head a cache head: blocks of 128 KiB of K
     }
     shapes = {k: v for k, v in shapes.items() if args.shapes is None or k in args.shapes}
     for name, shape in shapes.items():

@@ -14,7 +14,8 @@ describe a latent pool; this asks ``generate.init_kv_pools`` for the pools' shap
 a pool a cache kind (``k-exaone-serve-decode-long``), or a latent pool a layer group at 64 slots
 (``kimi-vl-a3b-serve-backlog``) or 128 (``xing4-serve-decode-long``: 16,897 blocks x 8 layers x
 1,280 B, 2.58 GiB beside 10.55 GiB of weights; decode 13.14 GiB live), and beside the K/V pools a
-state-space mixer's store a slot (``falcon-h1-serve-decode-long``: 65 rows x 6 layers x 4.2 MB).
+state-space mixer's store a slot (``falcon-h1-serve-decode-long``: 65 rows x 6 layers x 4.2 MB), or one K/V pool
+whose rows are not tokens (``evabyte-serve-decode-long``: tables of 176 entries and 8 staging blocks a slot beside them).
 Under each program it prints what its layer loop moves of a layer's pool size or more
 (``torchx_tpu/obs/hlo.py::loop_moves``: nothing, since the pools ride the scan's carry),
 and every pure data movement anywhere in the program of the size of a layer's smallest
@@ -52,7 +53,7 @@ def main() -> None:
     from torchx_tpu.models import llama
     from torchx_tpu.obs.hlo import loop_moves, program_moves
     from torchx_tpu.serve import engine as eng
-    from torchx_tpu.serve.kv_pool import window_ring
+    from torchx_tpu.serve.kv_pool import EvaTables, window_ring
 
     cell = spec.load_cell(sys.argv[1])
     widths = [int(a) for a in sys.argv[2:]] or [inspect.signature(eng.ServeEngine).parameters["chunk_width"].default]
@@ -64,6 +65,11 @@ def main() -> None:
     slots, bs = int(dep["max_slots"]), int(dep["block_size"])
     per_slot = -(-cfg.max_seq // bs)
     n_blocks = 1 + slots * max(1, per_slot // 2)
+    # a cache whose rows are not its tokens (EVA attention): the engine's own table width and default pool
+    eva = EvaTables(slots, cfg.max_seq, cfg.eva_window, cfg.eva_chunk, bs) if cfg.eva_window else None
+    if eva:
+        per_slot = eva.blocks_per_slot
+        n_blocks = 1 + slots * (eva.pooled_blocks * eva.windows + per_slot // 2)
     sds = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=one)  # noqa: E731
     is_leaf = lambda x: isinstance(x, tuple) and isinstance(x[0], tuple)  # noqa: E731
     params = shapes_of(config, jnp.bfloat16, jax.tree.map(lambda _: one, models.weight_shapes(config), is_leaf=is_leaf))
@@ -79,6 +85,8 @@ def main() -> None:
         full = sds((rows, per_slot), i32)
         if cfg.ssm_heads:  # a mixer's state rows ride beside the one table
             return {"full": full, "state": sds((rows,), i32)}
+        if eva:  # the rows' staging blocks, where a step pools the chunks it fills
+            return {"full": full, "stage": sds((rows, eva.pooled_blocks), i32)}
         return {"full": full, "window": sds((rows, window_width), i32)} if window else full
 
     # a layer's smallest pool; a mixer's convolution tails (a few MB a layer) are no pool's size

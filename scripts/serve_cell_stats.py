@@ -40,7 +40,9 @@ def main() -> int:
         s = self.stats()
         keep = ("preemptions", "steps_overlapped", "tokens_discarded", "kv_bytes_per_token", "kv_blocks_used",
                 "kv_blocks_free", "requests_done", "steps", "prefix_cache", "chunk_steps", "chunk_width",
-                "prefill_tokens", "prefill_padded_tokens", "state_bytes_per_slot", "state_bytes", "prefix_cache_off")  # fmt: skip
+                "prefill_tokens", "prefill_padded_tokens", "state_bytes_per_slot", "state_bytes", "prefix_cache_off",
+                "kv_blocks_window", "kv_blocks_pooled", "window_blocks_released", "pooled_blocks_promoted", "cache_rows_held",
+                "cache_tokens_held")  # fmt: skip
         print("engine stats at stop:", json.dumps({k: s[k] for k in keep if k in s}), flush=True)
         return stop(self, *a, **kw)
 

@@ -201,6 +201,31 @@ def test_a_mixers_state_rides_every_decode_span_and_no_other_engines(tmp_path, e
     assert not any("state_bytes_per_slot" in ev[3] for ev in engine_line)
 
 
+def test_rows_that_are_not_tokens_ride_every_decode_span_and_no_other_engines(tmp_path, engine_line):
+    """``cache_rows_held``, ``cache_tokens_held``, ``cache_rows_read``, ``kv_blocks_pooled`` and the running
+    ``pooled_blocks_promoted`` on ``serve.decode`` are what ``benchmark/layer_metrics/engine.cache_rows_per_token.py``
+    and ``kernels.decode_eva_hbm_pct.py`` read: an engine under EVA attention carries them, counted from the cache
+    coordinate, and no other engine does."""
+    cfg = llama.CONFIGS["tiny"](n_kv_heads=4, eva_window=32, eva_chunk=4, max_seq=96)
+    engine = ServeEngine(llama.init_params(cfg, jax.random.PRNGKey(0)), cfg, max_slots=2, block_size=4, chunk_width=8).start()
+    try:
+        lines = _session(tmp_path, lambda: engine.generate(list(range(1, 31)), 40, timeout=120))
+        stats = engine.stats()
+    finally:
+        engine.stop()
+    turns = [ev[3] for ln in lines for ev in ln if ev[0] == hot.SERVE_DECODE]
+    eva = {"cache_rows_held", "cache_tokens_held", "cache_rows_read", "kv_blocks_pooled", "pooled_blocks_promoted"}
+    assert turns and all(eva <= set(t) for t in turns)
+    # one slot decoding position t reads 8 pooled rows a finished window and its window's rows up to t
+    stepped = [t for t in turns if t["active"] == 1 and not t["chunk_tokens"]]
+    assert {t["cache_rows_read"] for t in stepped} >= {31, 32, 8 + 1, 8 + 32, 16 + 1}  # positions 30, 31, 32, 63, 64
+    assert all(t["cache_rows_held"] <= t["cache_tokens_held"] + 8 for t in turns)
+    deep = [t for t in turns if t["cache_tokens_held"] > 64]
+    assert deep and all(t["cache_rows_held"] < 0.7 * t["cache_tokens_held"] for t in deep)
+    assert stats["pooled_blocks_promoted"] == 4 and stats["window_blocks_released"] == 16 and turns[-1]["pooled_blocks_promoted"] == 4
+    assert not any(eva & set(ev[3]) for ev in engine_line)
+
+
 @pytest.mark.parametrize(
     "name,calls",
     [
@@ -376,6 +401,8 @@ def _serving_programs(cfg):
     temps = jnp.zeros((slots,), jnp.float32)
     # a mixer's rows ride beside the block table: each slot its own row of the store
     tables = {"full": i32(slots, bps), "state": 1 + jnp.arange(slots, dtype=jnp.int32)} if cfg.ssm_heads else i32(slots, bps)
+    if cfg.eva_window:  # the rows' staging blocks ride beside it
+        tables = {"full": i32(slots, bps), "stage": i32(slots, cfg.eva_window // cfg.eva_chunk // 16)}
     decode = jax.jit(
         lambda p, t, pos, tab, pl, k, tm: gen.paged_decode_step(p, t, pos, tab, pl, cfg, k, tm)
     ).lower(params, i32(slots), i32(slots), tables, pools, keys, temps)
@@ -404,6 +431,8 @@ def lowered():
     out.update({f"moe.{k}": _op_names(v) for k, v in _serving_programs(sparse).items()})
     out.update({f"mixer.{k}": _op_names(v) for k, v in _serving_programs(mixer).items()})
     out["mixer.train"] = _op_names(_train_step(mixer))
+    windowed = llama.CONFIGS["tiny"](n_kv_heads=4, eva_window=256, eva_chunk=16, max_seq=512)
+    out.update({f"eva.{k}": _op_names(v) for k, v in _serving_programs(windowed).items()})
     out["dense.train"] = _op_names(_train_step(dense))
     out["moe.train"] = _op_names(_train_step(sparse))
     return out
@@ -422,6 +451,7 @@ CASES = (
     + [("moe.train", s) for s in EXPERTS]
     + [(f"mixer.{p}", s) for p, form in (("decode", hot.SSM_STEP), ("prefill", hot.SSM_SCAN), ("train", hot.SSM_SCAN))
        for s in MIXER + (hot.ATTN, form)]
+    + [(f"eva.{p}", s) for p in ("decode", "prefill") for s in (hot.ATTN, hot.APPEND_KV, hot.PAGED_ATTENTION, hot.EVA_POOL)]
 )  # fmt: skip
 
 
@@ -434,6 +464,11 @@ def test_a_program_takes_one_form_of_the_mixer_and_a_model_without_one_neither(l
     assert hot.SSM_SCAN not in lowered["mixer.decode"] and hot.SSM_STEP not in lowered["mixer.prefill"]
     assert not {hot.SSM, hot.SSM_STEP, hot.SSM_SCAN} & (lowered["dense.decode"] | lowered["dense.prefill"] | lowered["dense.train"])
     assert set(MIXER + (hot.SSM_STEP, hot.SSM_SCAN)) <= set(hot.DEVICE_SCOPES)
+
+
+def test_only_eva_attention_pools(lowered):
+    assert hot.EVA_POOL in hot.DEVICE_SCOPES
+    assert not any(hot.EVA_POOL in names for program, names in lowered.items() if not program.startswith("eva."))
 
 
 # -- training spans -----------------------------------------------------------------

@@ -110,6 +110,10 @@ CASES = [
           dtype=jnp.bfloat16),  # fmt: skip
     _case("twenty-over-four-heads-real-chunk", [1, 300, 530], h=20, kvh=4, bs=16, bpr=34, real_chunk=True,
           dtype=jnp.bfloat16),  # fmt: skip
+    # evabyte-serve-decode-long: one query head a cache head, 32 cache heads a position, a block 512 rows of 128
+    _case("one-query-head-a-cache-head", [40, 130, 1], h=4, kvh=4),
+    _case("thirty-two-over-thirty-two-heads-real-chunk", [1, 300, 530, 16], h=32, kvh=32, bs=16, bpr=34, real_chunk=True,
+          dtype=jnp.bfloat16),  # fmt: skip
 ]
 
 
@@ -229,9 +233,17 @@ def _held_in_the_tpu_interpreter(capfd, dma, want, live, q, k, v, tables, lens, 
     assert "non-zero count" not in out and "RACE DETECTED" not in out, out
 
 
+def _one_head_a_cache_head(edge):
+    return pytest.param(*edge.values, 32, 32, id=f"{edge.id}-thirty-two-cache-heads")
+
+
 @pytest.mark.parametrize("dma", ["on_wait", "eager"])
-@pytest.mark.parametrize("h,kvh", [pytest.param(20, 4, id="four-cache-heads"), pytest.param(16, 8, id="eight-cache-heads")])
-@pytest.mark.parametrize("lengths,bpr", EDGES)
+@pytest.mark.parametrize("lengths,bpr,h,kvh", [
+    *(pytest.param(*e.values, h, kvh, id=f"{e.id}-{name}") for name, h, kvh in (("four-cache-heads", 20, 4), ("eight-cache-heads", 16, 8))
+      for e in EDGES),
+    # one query head a cache head (evabyte-serve-decode-long): a block is 512 rows, a chunk two of them, a part both
+    *(_one_head_a_cache_head(e) for e in (EDGES[0], EDGES[3], EDGES[5], EDGES[10])),
+])  # fmt: skip
 def test_kernel_bookkeeping_on_every_edge(lengths, bpr, h, kvh, dma, capfd):
     """The kernel in the TPU interpreter, which simulates the copies' semaphores and hands out
     scratch memory full of NaN, against the XLA function in float32. ``on_wait``: a copy lands
@@ -346,6 +358,7 @@ GEOMETRY = [  # rows a block, bytes a row, the most blocks a slot has live -> bl
     pytest.param(16 * 8, 256, 6, (6, 6, 3), id="a-short-table"),
     pytest.param(16 * 8, 256, 7, (7, 7, 1), id="a-prime-table"),
     pytest.param(32 * 16, 1024, 3, (1, 1, 1), id="a-block-larger-than-a-chunk"),
+    pytest.param(16 * 32, 256, 176, (4, 2, 1), id="evabyte-serve-decode-long"),
 ]
 
 
@@ -535,6 +548,57 @@ def test_kernel_compiles_for_the_chip_at_64_heads_over_eight(one_chip, monkeypat
     monkeypatch.setattr(attn_ops, "TRACED", {})
     _compiled_into_a_stack(one_chip, (64, 64, 128), (2, 8449, 16, 8, 128), 264)
     assert _geometry_said(16, 8, 264) == (16, 8, 4)
+
+
+def test_kernel_compiles_for_the_chip_at_one_query_head_a_cache_head(one_chip, monkeypatch):
+    """``evabyte-serve-decode-long``: 32 slots of 32 query heads over 32 cache heads (no grouping: the work a byte
+    read is a grouped model's), tables of 176 entries (six finished windows' 8 blocks of pooled rows and a window's
+    128) into a stack of eight layers' pools of 4,609 blocks, a block 512 rows of 128 (128 KiB of K): 4 blocks a
+    buffer, multiplied 2 at a time, copied one by one."""
+    monkeypatch.setattr(attn_ops, "TRACED", {})
+    _compiled_into_a_stack(one_chip, (32, 32, 128), (8, 4609, 16, 32, 128), 176)
+    assert _geometry_said(16, 32, 176) == (4, 2, 1)
+
+
+def test_the_programs_of_a_window_and_pooled_rows_compile_for_the_chip(one_chip, monkeypatch):
+    """EvaByte's stack at its published widths (d 4096, 32/32 heads of 128, ffn 11008, a window of 2,048, chunks of
+    16, eight heads of 320), three layers: the step that carries a chunk, as ``evabyte-serve-decode-long`` runs it
+    (32 slots, tables of 176 entries and 8 staging blocks a slot beside them, the engine's default pool). The decode
+    kernel is handed the stack; the pooling, its gather of a block a slot and its scatter of a row included, moves
+    nothing of a layer's pool's size; no weight is moved; and of the eight heads' 2,560 columns 320 are multiplied."""
+    from torchx_tpu.obs.hlo import loop_moves, program_moves
+    from torchx_tpu.serve.kv_pool import EvaTables
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    monkeypatch.setattr(attn_ops, "TRACED", {})
+    cfg = llama.llama_tiny(vocab_size=320, dim=4096, n_heads=32, n_kv_heads=32, ffn_dim=11008, n_layers=3, max_seq=12416,
+                           dtype=jnp.bfloat16, rope_theta=1e5, eva_window=2048, eva_chunk=16, norm_unit_offset=True,
+                           fp32_skip_add=True, pred_heads=8)  # fmt: skip
+    slots, width, bs = 32, 256, 16
+    host = EvaTables(slots, cfg.max_seq, cfg.eva_window, cfg.eva_chunk, bs)
+    assert (host.blocks_per_slot, host.pooled_blocks, host.most_blocks) == (176, 8, 184)
+    on_chip = lambda tree: jax.tree.map(lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip), tree)  # noqa: E731
+    shape = lambda s, d=jnp.int32: jax.ShapeDtypeStruct(s, d, sharding=one_chip)  # noqa: E731
+    params = on_chip(jax.eval_shape(lambda: llama.init_params(cfg, jax.random.PRNGKey(0))))
+    pools = on_chip(jax.eval_shape(lambda: gen.init_kv_pools(cfg, 1 + slots * (8 * 7 + 88), bs)))
+    tables = lambda n: {"full": shape((n, host.blocks_per_slot)), "stage": shape((n, host.pooled_blocks))}  # noqa: E731
+
+    def fn(p, tok, pos, tab, chunk, start, n, chunk_tab, pl, keys, temps):
+        return gen.paged_decode_chunk_step(p, tok, pos, tab, chunk, start, n, chunk_tab, pl, cfg, keys, temps)
+
+    compiled = jax.jit(fn, donate_argnums=(8,)).lower(
+        params, shape((slots,)), shape((slots,)), tables(slots), shape((width,)), shape(()), shape(()),
+        tables(1), pools, shape((slots + 1, 2), jnp.uint32), shape((slots + 1,), jnp.float32),
+    ).compile()  # fmt: skip
+    text = compiled.as_text()
+    assert attn_ops.traced("kv_pools") == "carried" and attn_ops.traced("eva") == "paged"
+    assert attn_ops.traced("attention") == "paged_pallas+paged_walk" and "paged_attention_decode" in text
+    layer_bytes = pools["k"].size // pools["k"].shape[0] * 2
+    assert loop_moves(text, layer_bytes) == []
+    moved = program_moves(text, 4096 * 4096 * 2)  # a layer's smallest projection
+    assert not [m for m in moved if any(w in m for w in ("wq", "wk", "wv", "wo", "w_gate", "w_up", "w_down"))], moved
+    assert "bf16[4096,2560]" in text and ",2560]{" not in text.replace("bf16[4096,2560]", "")  # the head goes in whole; no product is 2,560 wide
+    assert compiled.memory_analysis().temp_size_in_bytes < 2**30
 
 
 def test_state_step_kernel_compiles_for_the_chip_in_place(one_chip):

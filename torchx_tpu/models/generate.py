@@ -19,7 +19,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from torchx_tpu.models import hyper, llama, mla, ssm
+from torchx_tpu.models import eva, hyper, llama, mla, ssm
 from torchx_tpu.obs import hot
 from torchx_tpu.ops.attention import note_traced, project_heads as _project_heads
 from torchx_tpu.ops.norms import rms_norm
@@ -43,9 +43,9 @@ def init_kv_cache(
     cfg: llama.LlamaConfig, batch: int, max_seq: int
 ) -> KVCache:
     """Zeroed [layers, batch, max_seq, kv_heads, head_dim] K/V buffers."""
-    if cfg.kv_lora_rank or cfg.ssm_heads:
+    if cfg.kv_lora_rank or cfg.ssm_heads or cfg.eva_window:
         raise NotImplementedError(
-            "latent attention and state-space layers are served through the paged path (ServeEngine), not the dense cache"
+            "latent attention, state-space layers and EVA attention are served through the paged path (ServeEngine), not the dense cache"
         )
     shape = (cfg.n_layers, batch, max_seq, cfg.n_kv_heads, cfg.head_dim)
     return {
@@ -93,7 +93,7 @@ def _layer_step(
 
     def attend(stream_in):  # noqa: ANN001, ANN202
         with jax.named_scope(hot.NORM):
-            attn_in = rms_norm(stream_in, layer["attn_norm"], cfg.norm_eps)
+            attn_in = rms_norm(stream_in, llama.norm_gain(cfg, layer["attn_norm"]), cfg.norm_eps)
         with jax.named_scope(hot.ATTN):
             attn_in = llama.scaled(attn_in, cfg.attention_in_multiplier)
             q = apply_rope(_project_heads(attn_in, layer["wq"], h, hd), cos, sin)
@@ -142,9 +142,9 @@ def forward_with_cache(
         )
     x = hyper.collapse(cfg, x)
     with jax.named_scope(hot.NORM):
-        x = rms_norm(x, params["final_norm"], cfg.norm_eps)
+        x = rms_norm(x, llama.norm_gain(cfg, params["final_norm"]), cfg.norm_eps)
     with jax.named_scope(hot.LM_HEAD):
-        head = llama.lm_head(params, cfg)
+        head = llama.next_token_head(params, cfg)
         if isinstance(head, dict):  # int8-quantized lm_head: keep f32 accum
             logits = mm(x, head, out_dtype=jnp.float32)
         else:
@@ -464,7 +464,9 @@ def _table_of(tables, layer: llama.Params):  # noqa: ANN001, ANN202
     """The block table of ``layer``'s cache kind: one array serves a stack of
     one kind, ``{"full": .., "window": ..}`` a stack that mixes them. Where the
     layers have a state-space mixer the rows' state rows ride beside the one
-    table, ``{"full": .., "state": [rows] int32}`` (:func:`_state_rows`)."""
+    table, ``{"full": .., "state": [rows] int32}`` (:func:`_state_rows`), and
+    under EVA attention the rows' staging blocks, ``{"full": .., "stage": [rows,
+    W / C / block_size]}`` (:meth:`_Rows.pool`)."""
     return tables[layer["attn_kind"]] if isinstance(tables, dict) else tables
 
 
@@ -479,7 +481,7 @@ def _feed_forward(cfg: llama.LlamaConfig, layer: llama.Params, stream_in: jnp.nd
     dispatch as the training forward (dense SwiGLU or GShard MoE: static
     shapes hold at t=1); the balancing aux is training-only."""
     with jax.named_scope(hot.NORM):
-        mlp_in = rms_norm(stream_in, layer["mlp_norm"], cfg.norm_eps)
+        mlp_in = rms_norm(stream_in, llama.norm_gain(cfg, layer["mlp_norm"]), cfg.norm_eps)
     return llama.ffn(cfg, layer, mlp_in)
 
 
@@ -489,13 +491,18 @@ class _Rows(NamedTuple):
     (``valid`` None), or a chunk of consecutive prompt tokens a row of
     ``tables``, ``positions [b, t]`` flattened to ``b * t`` rows, ``valid [b, t]``
     saying which are real. The parts differ in how their rows reach the cache
-    and attend it, and in nothing else."""
+    and attend it, and in nothing else. What is roped is a row's position in its
+    sequence; what is written and read is its **cache index**, the same number
+    but under EVA attention (``sequence_at``)."""
 
     cos: jnp.ndarray  # [rows, rope/2] rope rows at each row's position
     sin: jnp.ndarray
-    positions: jnp.ndarray  # the cache index each row's token is written to
+    positions: jnp.ndarray  # the cache index each row's token is written to, and as far as it reads
     tables: Any  # [slots or b, blocks_per_slot] int32 block tables, or one a cache kind (_table_of)
     valid: Optional[jnp.ndarray] = None
+    #: the rows' positions in their sequences, shaped as ``positions``, where those are cache
+    #: coordinates and not the positions themselves (eva.cache_coord); None: they are
+    sequence_at: Optional[jnp.ndarray] = None
 
     @property
     def rows(self) -> int:
@@ -517,6 +524,19 @@ class _Rows(NamedTuple):
             return paged_attention(q, k_pool, v_pool, tables, self.positions + 1, at, window)
         out = paged_attention_chunk(self._chunked(q), k_pool, v_pool, tables, self.positions, self.valid, at, window)
         return out.reshape(self.rows, *out.shape[2:])
+
+    def pool(self, cfg, layer, k_pool, v_pool, table, at):  # noqa: ANN001, ANN201
+        """EVA attention: the chunks these rows completed, read out of the blocks
+        just written and pooled into their sequences' staging blocks: a decode
+        row's where its position ends a chunk, a prompt chunk's every whole one
+        (the chunk starts on a chunk's first position: the engine's chunks are
+        whole blocks and a block is a chunk)."""
+        c = cfg.eva_chunk
+        if self.valid is None:
+            ends, full = self.sequence_at[:, None], (self.sequence_at % c == c - 1)[:, None]
+        else:
+            ends, full = self.sequence_at[:, c - 1 :: c], self.valid[:, c - 1 :: c]
+        return eva.pool_filled(cfg, layer, k_pool, v_pool, at, ends, full, table, self.tables["stage"])
 
     def attend_latent(self, cfg, layer, q_nope, q_rope, tables, pool):  # noqa: ANN001, ANN201
         """The same over a latent pool -> ``[rows, h, v]``."""
@@ -576,7 +596,7 @@ def _paged_layer_step(
 
     def attend(stream_in):  # noqa: ANN001, ANN202
         with jax.named_scope(hot.NORM):
-            attn_in = rms_norm(stream_in, layer["attn_norm"], cfg.norm_eps)
+            attn_in = rms_norm(stream_in, llama.norm_gain(cfg, layer["attn_norm"]), cfg.norm_eps)
         with jax.named_scope(hot.ATTN), hot.attn_kind_scope(cfg, layer):
             rows = attn_in[:, 0]  # [rows, d]
             if cfg.kv_lora_rank:  # one latent pool, handed through as k_pool
@@ -603,6 +623,9 @@ def _paged_layer_step(
                 for part, table, k_rows, v_rows in zip(parts, tables, split(k), split(v)):
                     k_new = part.cache(k_new, table, k_rows, at, bool(window))
                     v_new = part.cache(v_new, table, v_rows, at, bool(window))
+                if cfg.eva_window:  # into staging blocks that no read of this step touches
+                    for part, table in zip(parts, tables):
+                        k_new, v_new = part.pool(cfg, layer, k_new, v_new, table, at)
                 out = _cat([
                     part.attend(q_rows, k_new, v_new, table, at, window)
                     for part, table, q_rows in zip(parts, tables, split(q))
@@ -671,7 +694,7 @@ def _scan_groups(step, x, params: llama.Params, pools: KVPools, cfg: llama.Llama
 @jax.named_scope(hot.LM_HEAD)
 def _lm_head_rows(params: llama.Params, x: jnp.ndarray, cfg: llama.LlamaConfig):
     # [rows, d] -> [rows, vocab] f32, same head dispatch as forward_with_cache
-    head = llama.lm_head(params, cfg)
+    head = llama.next_token_head(params, cfg)
     if isinstance(head, dict):  # int8-quantized lm_head: keep f32 accum
         return llama.scaled(mm(x, head, out_dtype=jnp.float32), cfg.lm_head_multiplier)
     return llama.scaled(jnp.einsum("rd,dv->rv", x, head, preferred_element_type=jnp.float32), cfg.lm_head_multiplier)
@@ -679,6 +702,8 @@ def _lm_head_rows(params: llama.Params, x: jnp.ndarray, cfg: llama.LlamaConfig):
 
 def _decode_rows(cfg: llama.LlamaConfig, positions: jnp.ndarray, tables) -> _Rows:  # noqa: ANN001
     cos, sin = llama.rope_table(cfg, cfg.max_seq)
+    if cfg.eva_window:
+        return _Rows(cos[positions], sin[positions], eva.cache_coord(cfg, positions), tables, sequence_at=positions)
     return _Rows(cos[positions], sin[positions], positions, tables)
 
 
@@ -689,6 +714,8 @@ def _chunk_rows(cfg: llama.LlamaConfig, prefix_lens: jnp.ndarray, suffix_lens: j
     positions = prefix_lens[:, None] + jnp.arange(t, dtype=jnp.int32)[None, :]
     pos_safe = jnp.clip(positions, 0, cfg.max_seq - 1).reshape(-1)  # padding may lie past the table
     valid = jnp.arange(t)[None, :] < suffix_lens[:, None]
+    if cfg.eva_window:  # the real positions lie in one window (the engine cuts a chunk where a window ends)
+        return _Rows(cos[pos_safe], sin[pos_safe], eva.cache_coord(cfg, positions), tables, valid, positions)
     return _Rows(cos[pos_safe], sin[pos_safe], positions, tables, valid)
 
 
@@ -706,7 +733,7 @@ def _paged_stream(params: llama.Params, tokens: jnp.ndarray, parts: tuple[_Rows,
 def _head_rows(params: llama.Params, x: jnp.ndarray, cfg: llama.LlamaConfig, keys: jnp.ndarray, temps: jnp.ndarray) -> jnp.ndarray:
     """The stream at ``x [rows, d]`` -> a sampled token a row."""
     with jax.named_scope(hot.NORM):
-        x = rms_norm(x, params["final_norm"], cfg.norm_eps)
+        x = rms_norm(x, llama.norm_gain(cfg, params["final_norm"]), cfg.norm_eps)
     return _sample_rows(_lm_head_rows(params, x, cfg), keys, temps)
 
 

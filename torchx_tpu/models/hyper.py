@@ -138,6 +138,8 @@ def residual(cfg, layer: dict, sublayer: str, x: jnp.ndarray, f: Callable[[jnp.n
     note_traced("residual", "hyper" if n else "add")
     if not n:
         y, rest = f(x)
+        if cfg.fp32_skip_add:  # the add in float32, rounded once to the stream's type
+            return (x.astype(jnp.float32) + y.astype(jnp.float32)).astype(x.dtype), rest
         return x + y, rest
     h_pre, h_post, h_res = coefficients(cfg, layer, sublayer, x)
     streams = _rows(cfg, x)

@@ -171,6 +171,21 @@ class LlamaConfig:
     ssm_out_multiplier: float = 1.0
     ssm_multipliers: tuple[float, ...] = (1.0, 1.0, 1.0, 1.0, 1.0)
     mlp_multipliers: tuple[float, float] = (1.0, 1.0)
+    # EVA attention (models/eva.py) when eva_window > 0: a position attends, in one softmax, the
+    # exact keys of its own aligned window of eva_window positions up to itself and one pooled
+    # row of every chunk of eva_chunk positions of every window before it, pooled with two
+    # learned vectors a cache head (eva_phi, eva_mu_k). A serving engine keeps the window's rows
+    # and the pooled rows in one paged pool under one table: what a sequence holds there is not
+    # its tokens. 0: every position attends every position before it
+    eva_window: int = 0
+    eva_chunk: int = 0
+    # RMSNorm gains are stored as g and applied as 1 + g (the layer's two norms and the final norm)
+    norm_unit_offset: bool = False
+    # the residual adds are made in float32 and rounded once to the stream's type
+    fp32_skip_add: bool = False
+    # output heads side by side in lm_head [dim, pred_heads * vocab_size]: head i predicts the
+    # token i + 1 ahead. Serving and the loss read head 0, the first vocab_size columns
+    pred_heads: int = 1
 
     def __post_init__(self) -> None:
         object.__setattr__(self, "layer_types", tuple(self.layer_types))
@@ -187,6 +202,26 @@ class LlamaConfig:
                     "the mixer runs beside full grouped-query attention through the stock layer ops: not beside latent"
                     " attention, sliding layers, hyper-connections, the fused or the ring kernels"
                 )
+        if self.eva_window:
+            if self.eva_chunk < 1 or self.eva_window % self.eva_chunk:
+                raise ValueError("eva_window must be whole chunks of eva_chunk positions")
+            if (
+                self.kv_lora_rank or self.layer_types or self.ssm_heads or self.hc_mult or self.qk_norm
+                or self.kernels != "reference" or self.use_ring_attention
+            ):  # fmt: skip
+                raise ValueError(
+                    "EVA attention runs through the stock layer ops over grouped-query heads: not beside latent"
+                    " attention, sliding layers, a mixer, hyper-connections, QK-norm, the fused or the ring kernels"
+                )
+        if self.norm_unit_offset and (
+            self.kv_lora_rank or self.ssm_heads or self.hc_mult or self.qk_norm or self.kernels != "reference"
+        ):
+            raise ValueError(
+                "unit-offset gains are built for the layer's two norms and the final norm through the stock ops:"
+                " not beside the norms of latent attention, a mixer, hyper-connections, QK-norm or the fused kernels"
+            )
+        if self.pred_heads < 1:
+            raise ValueError("pred_heads counts the output heads: at least one")
         if self.hc_mult and (self.kernels != "reference" or self.use_ring_attention):
             raise ValueError("hyper-connections run through the stock layer ops only, not the fused or ring kernels")
         if self.q_lora_rank and not self.kv_lora_rank:
@@ -283,7 +318,8 @@ class LlamaConfig:
                 + r  # kv_norm
             )
         hd = self.head_dim
-        return d * h * hd + 2 * d * self.n_kv_heads * hd + h * hd * d + (2 * hd if self.qk_norm else 0)
+        learned = (2 * hd if self.qk_norm else 0) + (2 * self.n_kv_heads * hd if self.eva_window else 0)  # q_norm, k_norm | eva_phi, eva_mu_k
+        return d * h * hd + 2 * d * self.n_kv_heads * hd + h * hd * d + learned
 
     def flops_per_token(self) -> float:
         """Training FLOPs/token (fwd+bwd), 6N + attention quadratic term."""
@@ -309,7 +345,7 @@ class LlamaConfig:
         )
         total = self.n_layers * per_layer + v * d + d  # embed + final norm
         if not self.tie_embeddings:
-            total += d * v
+            total += d * v * self.pred_heads
         return total
 
 
@@ -420,22 +456,27 @@ def init_params(cfg: LlamaConfig, key: jax.Array) -> Params:
         if cfg.qk_norm:
             attn["q_norm"] = jnp.ones((L, hd), dtype=cfg.dtype)
             attn["k_norm"] = jnp.ones((L, hd), dtype=cfg.dtype)
+        if cfg.eva_window:  # a clamped normal times hd^-0.5 as published; here a plain normal
+            k_phi, k_mu = jax.random.split(jax.random.fold_in(k_layers, 9))
+            attn["eva_phi"] = norm_init(k_phi, (L, kvh, hd), hd)
+            attn["eva_mu_k"] = norm_init(k_mu, (L, kvh, hd), hd)
+    gain = jnp.zeros if cfg.norm_unit_offset else jnp.ones  # applied as 1 + g: norm_gain
     params: Params = {
         "embed": norm_init(k_embed, (cfg.vocab_size, d), d),
         "layers": {
-            "attn_norm": jnp.ones((L, d), dtype=cfg.dtype),
+            "attn_norm": gain((L, d), dtype=cfg.dtype),
             **attn,
-            "mlp_norm": jnp.ones((L, d), dtype=cfg.dtype),
+            "mlp_norm": gain((L, d), dtype=cfg.dtype),
             "w_gate": norm_init(ks[4], (L, d, f), d),
             "w_up": norm_init(ks[5], (L, d, f), d),
             "w_down": norm_init(ks[6], (L, f, d), f),
             **hyper.init_leaves(cfg, jax.random.fold_in(k_layers, 7), L),
             **ssm.init_leaves(cfg, jax.random.fold_in(k_layers, 8), L),
         },
-        "final_norm": jnp.ones((d,), dtype=cfg.dtype),
+        "final_norm": gain((d,), dtype=cfg.dtype),
     }
     if not cfg.tie_embeddings:
-        params["lm_head"] = norm_init(k_head, (d, cfg.vocab_size), d)
+        params["lm_head"] = norm_init(k_head, (d, cfg.pred_heads * cfg.vocab_size), d)
     return params
 
 
@@ -478,6 +519,9 @@ def param_specs(cfg: LlamaConfig, pp: bool = False) -> Params:
         if cfg.qk_norm:
             attn["q_norm"] = P(layer_axis, None)
             attn["k_norm"] = P(layer_axis, None)
+        if cfg.eva_window:  # two vectors a cache head: every chip's, read whole
+            attn["eva_phi"] = P(layer_axis, None, None)
+            attn["eva_mu_k"] = P(layer_axis, None, None)
     specs: Params = {
         # vocab axis unsharded: a gather over a vocab-sharded table forces
         # the SPMD partitioner into full rematerialization; dim shards fine
@@ -685,6 +729,13 @@ def scaled(x: jnp.ndarray, by: float) -> jnp.ndarray:
     return x if by == 1.0 else x * by
 
 
+def norm_gain(cfg: LlamaConfig, gain: jnp.ndarray) -> jnp.ndarray:
+    """What an RMSNorm of the model multiplies by: the stored ``gain``, or ``1 +
+    gain`` in float32 where the model stores its gains about zero
+    (``norm_unit_offset``)."""
+    return 1.0 + gain.astype(jnp.float32) if cfg.norm_unit_offset else gain
+
+
 def window_of(cfg: LlamaConfig, layer: Params) -> int:
     """The window of ``layer``'s attention: ``cfg.sliding_window`` on a sliding
     layer (``scan_layers`` says which it is), 0 on a full one."""
@@ -727,7 +778,11 @@ def _gqa_attention(
     q, k = norm_and_rotate(cfg, layer, q, k, cos, sin, apply_rope)
     if window and (cfg.kernels != "reference" or cfg.use_ring_attention):
         raise NotImplementedError("a sliding layer runs through ops.attention only, not the fused or ring kernels")
-    if cfg.use_ring_attention and mesh is not None and mesh.shape.get("sp", 1) > 1:
+    if cfg.eva_window:
+        from torchx_tpu.models import eva
+
+        attn_out = eva.attention_full(cfg, layer, q, k, v)
+    elif cfg.use_ring_attention and mesh is not None and mesh.shape.get("sp", 1) > 1:
         with jax.named_scope(hot.ATTN_KERNEL):
             attn_out = ring_attention(q, k, v, mesh)
     else:
@@ -789,7 +844,7 @@ def _layer(
 
     def attend(stream_in):  # noqa: ANN001, ANN202 - the attention sublayer, its norm included
         with jax.named_scope(hot.NORM):
-            attn_in = rms_norm(stream_in, layer["attn_norm"], cfg.norm_eps, mesh=mesh)
+            attn_in = rms_norm(stream_in, norm_gain(cfg, layer["attn_norm"]), cfg.norm_eps, mesh=mesh)
         with jax.named_scope(hot.ATTN), hot.attn_kind_scope(cfg, layer):
             if cfg.kv_lora_rank:
                 from torchx_tpu.models import mla
@@ -803,7 +858,7 @@ def _layer(
 
     def feed_forward(stream_in):  # noqa: ANN001, ANN202 - dense SwiGLU, or MoE when the config carries experts
         with jax.named_scope(hot.NORM):
-            mlp_in = rms_norm(stream_in, layer["mlp_norm"], cfg.norm_eps, mesh=mesh)
+            mlp_in = rms_norm(stream_in, norm_gain(cfg, layer["mlp_norm"]), cfg.norm_eps, mesh=mesh)
         return ffn(cfg, layer, mlp_in)
 
     if cfg.kernels != "reference":
@@ -993,14 +1048,24 @@ def features_from_embeddings(
         )
     x = hyper.collapse(cfg, x)
     with jax.named_scope(hot.NORM):
-        x = rms_norm(x, params["final_norm"], cfg.norm_eps, mesh=mesh)
+        x = rms_norm(x, norm_gain(cfg, params["final_norm"]), cfg.norm_eps, mesh=mesh)
     return x, aux_total
 
 
 def lm_head(params: Params, cfg: LlamaConfig) -> jnp.ndarray:
     """[dim, vocab] output projection (the embedding transposed when
-    tied)."""
+    tied); every head's columns side by side, ``[dim, pred_heads * vocab]``,
+    where the model has several (:func:`next_token_head` is the first's)."""
     return params["embed"].T if cfg.tie_embeddings else params["lm_head"]
+
+
+def next_token_head(params: Params, cfg: LlamaConfig):  # noqa: ANN201
+    """The head that predicts the next token, ``[dim, vocab]``: what serving
+    samples from and the loss is taken over. The whole of :func:`lm_head` but
+    where the model has further heads beside it (``pred_heads``), whose columns
+    are then never multiplied."""
+    head = lm_head(params, cfg)
+    return head[:, : cfg.vocab_size] if cfg.pred_heads > 1 else head
 
 
 def forward_from_embeddings(
@@ -1108,7 +1173,7 @@ def loss_and_aux(
     x = scaled(x, cfg.lm_head_multiplier)  # on the head's input: the logits are made a chunk at a time
     aux_term = getattr(cfg, "router_aux_coef", 0.0) * aux[AUX_BALANCE]
     targets = tokens[:, 1:]
-    head = lm_head(params, cfg)
+    head = next_token_head(params, cfg)
     mask = batch.get("loss_mask")
     m = mask[:, 1:].astype(jnp.float32) if mask is not None else None
     f32 = cfg.ce_f32_logits

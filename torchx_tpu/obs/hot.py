@@ -40,7 +40,13 @@ import jax
 # compiled call inside its .dispatch is then _decode_chunk where a pure step's is _decode.
 
 # kv_blocks_full, kv_blocks_window: blocks the slots hold in the full and in the window pools (a decode
-# turn: as its step is dispatched; an admission: after it); window_blocks_released: a running count
+# turn: as its step is dispatched; an admission: after it); window_blocks_released: a running count.
+# Where a slot's rows are not its tokens (EVA attention: serve/kv_pool.py::EvaTables; one pool, so
+# kv_blocks_full is everything held) kv_blocks_window is the blocks of the slots' current windows and
+# kv_blocks_pooled those of pooled rows, staging included; cache_rows_held the rows the slots hold in
+# them (staged rows too) for cache_tokens_held tokens, cache_rows_read the rows the decode kernel of
+# the step being dispatched reads (every stepping slot's cache coordinate + 1, a layer), and
+# pooled_blocks_promoted a running count beside window_blocks_released
 #
 # serve.admit: admitted (requests given a slot), cached_tokens, queue_depth (after they left it),
 # kv_bytes_per_token, kv_blocks_*. Until PR 40 an admission was a round with a program of its own, a
@@ -131,6 +137,7 @@ GATHER_KV = "gather_kv"
 SCORES = "scores"
 VALUES = "values"
 APPEND_KV = "append_kv"
+EVA_POOL = "eva_pool"  # under attn, beside paged_attention and append_kv: the blocks a step filled read, pooled, one row each written
 SAMPLE = "sample"
 GRAD_CLIP = "grad_clip"
 OPTIMIZER = "optimizer"
@@ -140,7 +147,7 @@ DEVICE_SCOPES = (
     MOE_EXPERTS, MOE_COMBINE, PAGED_ATTENTION, GATHER_KV, SCORES, VALUES,
     APPEND_KV, SAMPLE, GRAD_CLIP, OPTIMIZER, MOE_SORT, MOE_SHARED, MLA_LATENT, MLA_ABSORB,
     APPEND_LATENT, ATTN_WINDOW, ATTN_FULL, QK_NORM, MLA_Q_LATENT, HC_PRE, HC_SINKHORN, HC_POST, HC_HEAD,
-    SSM, SSM_PROJ, SSM_CONV, SSM_STEP, SSM_SCAN, SSM_GATE_NORM,
+    SSM, SSM_PROJ, SSM_CONV, SSM_STEP, SSM_SCAN, SSM_GATE_NORM, EVA_POOL,
 )  # fmt: skip
 
 

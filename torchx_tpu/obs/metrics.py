@@ -515,6 +515,14 @@ SERVE_STATE_BYTES = REGISTRY.gauge(
     "bytes of recurrent (state-space) state held for the engine's slots",
 )
 
+#: rows of paged cache the engine's slots hold where a slot's rows are not its
+#: tokens (EVA attention: a window's rows, the pooled rows behind it, those
+#: staged); not set for a model that holds a row a token.
+SERVE_CACHE_ROWS_HELD = REGISTRY.gauge(
+    "tpx_serve_cache_rows_held",
+    "rows of paged cache held by the engine's slots (a cache whose rows are not its tokens)",
+)
+
 #: decode tokens produced, by phase ("prefill" first tokens vs "decode").
 SERVE_TOKENS = REGISTRY.counter(
     "tpx_serve_tokens_total",

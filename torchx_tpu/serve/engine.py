@@ -72,6 +72,23 @@ This engine keeps a **fixed slot array** decoding continuously:
   not yet cached or handed off: such an engine has no prefix cache (a hit would
   bring K/V without the state that goes with it; ``stats()`` says so) and
   refuses ``prefill_only`` requests and :meth:`ServeEngine.submit_prefilled`;
+* **a cache whose rows are not its tokens**: under EVA attention
+  (``cfg.eva_window``, :mod:`torchx_tpu.models.eva`) a slot holds the blocks of
+  its current window, the pooled rows of every window behind it (one row for
+  every ``cfg.eva_chunk`` positions) and staging blocks in which the current
+  window's pooled rows are being written, all in the one pool and, but for the
+  staging, under the one table the programs read
+  (:class:`~torchx_tpu.serve.kv_pool.EvaTables`). The programs take a slot's
+  position (roped, and what the sampling key is folded from) and work out its
+  cache coordinate themselves. When a write starts a new window the host moves
+  the staging blocks into the table, gives the ended window's blocks back all at
+  once and allocates anew, with the step that wrote the window's last row still
+  in flight (device order keeps its blocks its own until it has run). A chunk of
+  a prompt stops where a window ends, and a prompt is given the blocks of its
+  first window at admission and the rest as its chunks reach them. Admission,
+  pressure and the spans reckon in the rows held. Such an engine has no prefix
+  cache and refuses hand-offs, as with recurrent state: neither indexes a cache
+  by anything but tokens yet;
 * **disaggregation seams**: a request marked ``prefill_only`` completes
   with its first token, its KV blocks exported as a
   :class:`~torchx_tpu.serve.kv_transfer.KvPayload` (the prefill-replica
@@ -109,7 +126,7 @@ from torchx_tpu.models import llama
 from torchx_tpu.obs import hot
 from torchx_tpu.obs import metrics as obs_metrics
 from torchx_tpu.ops.paged_attention import TRASH_BLOCK
-from torchx_tpu.serve.kv_pool import BlockAllocator, PoolPlan, SlotTables, WindowTables, window_ring
+from torchx_tpu.serve.kv_pool import BlockAllocator, EvaTables, PoolPlan, SlotTables, WindowTables, window_ring
 from torchx_tpu.serve.kv_transfer import KvPayload, new_request_id
 from torchx_tpu.serve.prefix_cache import PrefixCache
 
@@ -291,7 +308,9 @@ class ServeEngine:
         self._cfg = cfg
         self.max_slots = max_slots
         self.block_size = block_size
-        self.blocks_per_slot = math.ceil(cfg.max_seq / block_size)
+        #: EVA attention: the slots' tables, whose rows are not their tokens; None for every other model
+        self.eva = EvaTables(max_slots, cfg.max_seq, cfg.eva_window, cfg.eva_chunk, block_size) if cfg.eva_window else None
+        self.blocks_per_slot = self.eva.blocks_per_slot if self.eva else math.ceil(cfg.max_seq / block_size)
         #: requests that may be mid-prompt at once, and with them the window
         #: blocks staged for prompts
         self.max_prefill_batch = max(1, max_prefill_batch)
@@ -301,12 +320,17 @@ class ServeEngine:
         #: (256 is the one width measured and checked on the chip; the tests
         #: pass a small one). No prompt is longer than a slot's blocks
         self.chunk_width = min(chunk_width, self.blocks_per_slot * block_size)
+        #: the most blocks one sequence holds at once
+        most = self.eva.most_blocks if self.eva else self.blocks_per_slot
         if num_blocks is None:
-            num_blocks = 1 + max_slots * max(1, self.blocks_per_slot // 2)
-        if num_blocks < self.blocks_per_slot + 1:
+            # half a table a slot; where rows are not tokens, beside every block of pooled rows a slot
+            # can come to hold (they stay for as long as the sequence does, the window's come back)
+            pooled = self.eva.pooled_blocks * self.eva.windows if self.eva else 0
+            num_blocks = 1 + max_slots * (pooled + max(1, self.blocks_per_slot // 2))
+        if num_blocks < most + 1:
             raise ValueError(
                 f"num_blocks={num_blocks} cannot hold one max_seq sequence"
-                f" ({self.blocks_per_slot} blocks + trash)"
+                f" ({most} blocks + trash)"
             )
         self.num_blocks = num_blocks
         self._clock = clock
@@ -332,14 +356,17 @@ class ServeEngine:
         #: bytes a further token of context holds, as the pools are laid out:
         #: every layer's, but for the sliding layers, whose cost a slot is constant
         self.kv_bytes_per_token = row_bytes(self._pools_of("full"))
+        if self.eva:  # a row's bytes while the token is in its window; for ever after, its share of a pooled row
+            self.kv_bytes_per_token //= cfg.eva_chunk
         self.kv_bytes_per_slot_window = (
             self.window_ring * block_size * row_bytes(self.pools["window"]) if self.window else 0
         )
         self.alloc = BlockAllocator(num_blocks)
-        self.tables = SlotTables(max_slots, self.blocks_per_slot)
+        self.tables = self.eva or SlotTables(max_slots, self.blocks_per_slot)
         self.window_alloc = BlockAllocator(self.num_window_blocks) if self.window else None
         self.window_tables = WindowTables(max_slots, self.window_ring) if self.window else None
-        self.window_blocks_released = 0  # window blocks slots gave back as their windows moved on
+        self.window_blocks_released = 0  # window blocks slots gave back as their windows moved on (EVA: ended)
+        self.pooled_blocks_promoted = 0  # EVA: staging blocks moved into a table as their window ended
         self._slots: list[Optional[_SlotState]] = [None] * max_slots
         self._admit_counter = itertools.count()
         self.prefix_cache: Optional[PrefixCache] = None
@@ -349,6 +376,11 @@ class ServeEngine:
             self.prefix_cache_off = (
                 "recurrent state: the cache indexes K/V blocks alone, and a hit would hand a request K/V without"
                 " the state that goes with it"
+            )
+        elif enable_prefix_cache and self.eva:
+            self.prefix_cache_off = (
+                "a cache whose rows are not its tokens: the prefix cache indexes a block by the tokens it holds, and a"
+                " window's blocks are given back and its pooled rows laid out anew as the sequence grows"
             )
         elif enable_prefix_cache:
             cap = (
@@ -468,6 +500,8 @@ class ServeEngine:
         with a mixer the rows' state rows beside the one table."""
         if self.state_bytes:
             return {"full": jnp.asarray(full), "state": jnp.asarray(state_rows, jnp.int32)}
+        if self.eva:  # ``window`` is then the rows' staging blocks
+            return {"full": jnp.asarray(full), "stage": jnp.asarray(window)}
         return {"full": jnp.asarray(full), "window": jnp.asarray(window)} if self.window else jnp.asarray(full)
 
     # -- public API --------------------------------------------------------
@@ -548,11 +582,16 @@ class ServeEngine:
         return req
 
     def _refuse_handoff(self) -> None:
-        """A hand-off carries K/V blocks and no recurrent state."""
+        """A hand-off carries K/V blocks by token, and no recurrent state."""
         if self.state_bytes:
             raise NotImplementedError(
                 "a model with state-space layers is not handed off: a KvPayload carries K/V blocks and not the"
                 " recurrent state that goes with them"
+            )
+        if self.eva:
+            raise NotImplementedError(
+                "a cache whose rows are not its tokens is not handed off: a KvPayload carries a block for every"
+                " block_size tokens, not a window's rows and the pooled rows behind it"
             )
 
     def _admit_handoffs(self) -> bool:
@@ -768,6 +807,17 @@ class ServeEngine:
         cache keeps beside them; those staged for a prompt being fed among
         them), and window blocks given back so far: what the ``serve.decode``
         and ``serve.admit`` spans carry."""
+        if self.eva:
+            tokens, rows = self._rows_held()
+            return {
+                "kv_blocks_full": self.eva.held_blocks,
+                "kv_blocks_window": self.eva.held_window,
+                "kv_blocks_pooled": self.eva.held_pooled,
+                "window_blocks_released": self.window_blocks_released,
+                "pooled_blocks_promoted": self.pooled_blocks_promoted,
+                "cache_tokens_held": tokens,
+                "cache_rows_held": rows,
+            }
         staged = sum(len(st.staged) for st in self._slots if st is not None)
         return {
             "kv_blocks_full": self.tables.held_blocks,
@@ -776,6 +826,12 @@ class ServeEngine:
             # what a slot holds beside its blocks whatever its length; not there without a mixer
             **({"state_bytes_per_slot": self.state_bytes_per_slot} if self.state_bytes else {}),
         }
+
+    def _rows_held(self) -> tuple[int, int]:
+        """Where rows are not tokens: (the tokens the slots hold, written or in
+        flight; the cache rows they hold for them, staged ones among them)."""
+        held = [st.cache_len + st.unfetched for st in self._slots if st is not None]
+        return sum(held), sum(self.eva.rows(n) for n in held)
 
     def _release_slot(self, slot: int) -> _SlotState:
         """Empty ``slot``: a reference to each block it holds goes back to the
@@ -845,6 +901,8 @@ class ServeEngine:
                     # covers the last token, so a token is left to feed
                     cached_blocks, cached_window, cached_tokens = self.prefix_cache.match_kinds(toks)
                 need = math.ceil(len(toks) / self.block_size) - len(cached_blocks)
+                if self.eva:  # its staging and its first window's blocks; _ensure_rows brings the rest
+                    need = self.eva.pooled_blocks + math.ceil(min(len(toks), self.eva.window) / self.block_size)
                 new_blocks = self._alloc_pressure(need)
                 # a window block is staged for every new block of the prompt;
                 # _chunk_enqueued hands back those below the next chunk's window
@@ -945,6 +1003,8 @@ class ServeEngine:
         (another holder — cache or sibling slot — still reads it), and
         preempts the youngest slot under pool pressure. False if ``slot``
         itself was preempted away."""
+        if self.eva:
+            return self._ensure_rows(slot, write_pos)
         idx = write_pos // self.block_size
         while True:
             have = len(self.tables.blocks_of(slot))
@@ -989,6 +1049,30 @@ class ServeEngine:
                 return False
         return True
 
+    def _ensure_rows(self, slot: int, write_pos: int) -> bool:
+        """:meth:`_ensure_capacity` where rows are not tokens (EVA attention):
+        make ``slot`` writable up to ``write_pos``, which lies in the window its
+        table is laid out for or starts the next. Then the window before has
+        ended, whatever step wrote its last row still in flight: its staged rows
+        go into the table, its blocks go back to the pool, all of them, and the
+        new window is given staging and a first block. False if ``slot`` itself
+        was preempted away for them."""
+        tables = self.eva
+        if write_pos // tables.window > tables.window_of(slot):
+            released = tables.turn(slot)
+            self.alloc.release(released)
+            self.window_blocks_released += len(released)
+            self.pooled_blocks_promoted += tables.pooled_blocks
+        while short := tables.short(slot, write_pos):
+            blocks = self._alloc_pressure(short)
+            if blocks is not None:
+                tables.assign(slot, blocks)
+                break
+            self._preempt_youngest()
+            if self._slots[slot] is None:
+                return False
+        return True
+
     def _next_chunk(self) -> Optional[tuple[int, _SlotState, int]]:
         """-> (slot, its state, real tokens) of the chunk the next step
         carries: the next ``chunk_width`` tokens, or what is left, of the
@@ -998,7 +1082,10 @@ class ServeEngine:
             return None
         slot = min(feeding)[1]
         st = self._slots[slot]
-        return slot, st, min(self.chunk_width, len(st.feeding) - st.cache_len)
+        n = min(self.chunk_width, len(st.feeding) - st.cache_len)
+        if self.eva:  # a chunk stops where its window ends: the table is laid anew there
+            n = min(n, self.eva.window - st.cache_len % self.eva.window)
+        return slot, st, n
 
     def _chunk_enqueued(self, slot: int, st: _SlotState, n: int) -> bool:
         """The step just enqueued carries the next ``n`` tokens of ``slot``'s
@@ -1048,6 +1135,11 @@ class ServeEngine:
                     # None by now: preempted by an earlier slot's capacity grab
                     if st is not None and st.more_to_decode:
                         self._ensure_capacity(slot, st.cache_len + st.unfetched)
+                # where rows are not tokens a prompt's blocks come as its chunks reach them: now, ahead
+                # of the tables' copy below (a request preempted away for them leaves the next to be fed)
+                while self.eva and (chunk := self._next_chunk()) is not None:
+                    if self._ensure_rows(chunk[0], chunk[1].cache_len + chunk[2] - 1):
+                        break
 
                 tokens = np.zeros((self.max_slots,), np.int32)
                 positions = np.zeros((self.max_slots,), np.int32)
@@ -1058,10 +1150,13 @@ class ServeEngine:
                 # it lies
                 tables = self.tables.tables.copy()
                 window_tables = self.window_tables.tables.copy() if self.window else None
+                if self.eva:  # in the window tables' place: a slot's staging blocks, where the programs pool what a step fills
+                    window_tables = self.eva.stage.copy()
                 # a mixer's state row a slot: its own (slot + 1) while it decodes, else the
                 # trash row, so that a slot being fed is written by its chunk alone
                 state_rows = np.zeros((self.max_slots,), np.int32)
                 stepping: list[tuple[int, _SlotState]] = []
+                rows_read = 0  # EVA: cache rows this step's decode attention reads, a layer
                 for slot, st in enumerate(self._slots):
                     if st is None:
                         continue
@@ -1070,7 +1165,7 @@ class ServeEngine:
                         # in flight. The program writes a row for every slot:
                         # this one's goes where an empty slot's does
                         tables[slot] = TRASH_BLOCK
-                        if self.window:
+                        if window_tables is not None:
                             window_tables[slot] = TRASH_BLOCK
                         continue
                     tokens[slot] = _FROM_DEVICE if st.unfetched else st.last_tok
@@ -1079,6 +1174,8 @@ class ServeEngine:
                     temps[slot] = st.req.temperature
                     state_rows[slot] = slot + 1
                     stepping.append((slot, st))
+                    if self.eva:
+                        rows_read += self.eva.coord(int(positions[slot])) + 1
 
                 chunk = self._next_chunk()
                 if chunk is not None:
@@ -1093,10 +1190,12 @@ class ServeEngine:
                     # the request's own table (the copy above sends its slot's decode row to the
                     # trash block); its staged window blocks lie as the full ones do, block b at entry b
                     chunk_full = self.tables.tables[c_slot : c_slot + 1].copy()
-                    chunk_window = self._window_ids(c_st.staged, self.blocks_per_slot)
+                    chunk_window = self.eva.stage[c_slot].copy() if self.eva else self._window_ids(c_st.staged, self.blocks_per_slot)
 
             enqueued = None
             step_span.set_metadata(**self._kv_blocks())  # as the step is dispatched
+            if self.eva:
+                step_span.set_metadata(cache_rows_read=rows_read)
             if stepping or chunk is not None:
                 with hot.span(hot.SERVE_DECODE_DISPATCH):
                     host_tokens = jnp.asarray(tokens)
@@ -1119,7 +1218,7 @@ class ServeEngine:
                             *args,
                             jnp.asarray(chunk_tokens),
                             jnp.asarray(at),
-                            self._tables_arg(chunk_full, chunk_window[None] if self.window else None, [c_slot + 1]),
+                            self._tables_arg(chunk_full, None if chunk_window is None else chunk_window[None], [c_slot + 1]),
                         )
                     for _, st in stepping:
                         st.unfetched += 1
@@ -1206,6 +1305,8 @@ class ServeEngine:
         obs_metrics.SERVE_SLOTS_ACTIVE.set(active)
         obs_metrics.SERVE_OCCUPANCY.set(active / self.max_slots)
         obs_metrics.SERVE_KV_BLOCKS_USED.set(self.alloc.used_blocks)
+        if self.eva:
+            obs_metrics.SERVE_CACHE_ROWS_HELD.set(self._rows_held()[1])
 
 
 def serve_kv_payload(

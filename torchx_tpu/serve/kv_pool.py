@@ -37,6 +37,7 @@ __all__ = [
     "BlockAllocator",
     "SlotTables",
     "WindowTables",
+    "EvaTables",
     "window_ring",
 ]
 
@@ -406,4 +407,130 @@ class WindowTables:
         blocks = list(self._held[slot].values())
         self._held[slot] = {}
         self.tables[slot, :] = TRASH_BLOCK
+        return blocks
+
+
+class EvaTables:
+    """Per-slot tables of a cache whose rows are not its tokens, host side (numpy).
+
+    EVA attention (:mod:`torchx_tpu.models.eva`) reads the exact rows of a
+    position's own window of ``window`` positions and one pooled row of every
+    ``chunk`` positions of every window before it. Both kinds of row lie in the
+    one pool under the one table the decode programs take, a slot's row of
+    :attr:`tables` being ``[pooled_blocks blocks a finished window ... | the
+    current window's blocks]``: position ``t`` is written at cache coordinate
+    :meth:`coord` and reads the ``coord(t) + 1`` rows in front of it. Beside
+    them a slot holds ``pooled_blocks`` **staging** blocks (its row of
+    :attr:`stage`), into which the programs pool the current window's chunks as
+    they fill and which nothing reads until the window ends. Then :meth:`turn`
+    moves them into the table behind the pooled blocks already there, starts the
+    next window at the entry behind them, and hands back the window's blocks
+    **all at once** (a ring gives back one at a time). A block is a chunk
+    (``block_size == chunk``), and a window's pooled rows are whole blocks.
+
+    The same surface as :class:`SlotTables` where the engine needs one
+    (:attr:`tables`, :attr:`lengths`, :meth:`assign`, :meth:`blocks_of`,
+    :attr:`held_blocks`, :meth:`release`).
+    """
+
+    def __init__(self, max_slots: int, max_seq: int, window: int, chunk: int, block_size: int) -> None:
+        if block_size != chunk or window % chunk or (window // chunk) % block_size:
+            raise ValueError(
+                f"a block must be one chunk and a window's pooled rows whole blocks: block_size={block_size},"
+                f" chunk={chunk}, window={window}"
+            )
+        self.max_slots, self.window, self.chunk, self.block_size = max_slots, window, chunk, block_size
+        self.window_blocks = min(window, max_seq + block_size - 1) // block_size  # a whole window, or all a sequence can have
+        self.pooled_blocks = window // chunk // block_size  # what a finished window leaves behind, and a slot's staging
+        self.windows = math.ceil(max_seq / window)
+        #: entries of a slot's table: the finished windows of the longest sequence, and its last window whole
+        self.blocks_per_slot = self.pooled_blocks * (self.windows - 1) + self.window_blocks
+        #: the most blocks a slot holds at once: those, and its staging
+        self.most_blocks = self.blocks_per_slot + self.pooled_blocks
+        self.tables = np.full((max_slots, self.blocks_per_slot), TRASH_BLOCK, np.int32)
+        self.stage = np.full((max_slots, self.pooled_blocks), TRASH_BLOCK, np.int32)
+        self.lengths = np.zeros((max_slots,), np.int32)
+        self._pooled: list[list[int]] = [[] for _ in range(max_slots)]
+        self._stage: list[list[int]] = [[] for _ in range(max_slots)]
+        self._window: list[list[int]] = [[] for _ in range(max_slots)]
+
+    def coord(self, position: int) -> int:
+        """The row of its slot's cache that ``position`` is written to
+        (:func:`torchx_tpu.models.eva.cache_coord`)."""
+        return (self.window // self.chunk) * (position // self.window) + position % self.window
+
+    def rows(self, tokens: int) -> int:
+        """Rows a sequence of ``tokens`` holds: the pooled rows of its finished
+        windows, its last window's own, and those staged of that window."""
+        if not tokens:
+            return 0
+        return self.coord(tokens - 1) + 1 + ((tokens - 1) % self.window + 1) // self.chunk
+
+    def window_of(self, slot: int) -> int:
+        """The window ``slot``'s table is laid out for: how many it has finished."""
+        return len(self._pooled[slot]) // self.pooled_blocks
+
+    def short(self, slot: int, position: int) -> int:
+        """Blocks ``slot`` lacks to be written up to ``position`` of its current
+        window: its staging where it has none yet, and the window's blocks."""
+        in_window = (position % self.window) // self.block_size + 1
+        return self.pooled_blocks - len(self._stage[slot]) + max(0, in_window - len(self._window[slot]))
+
+    def assign(self, slot: int, blocks: list[int]) -> None:
+        """Give ``slot`` ``blocks``: its staging first where that is short, the
+        rest behind its window's last block."""
+        n = self.pooled_blocks - len(self._stage[slot])
+        stage, window = self._stage[slot] + blocks[:n], self._window[slot] + blocks[n:]
+        if len(window) > self.window_blocks:
+            raise ValueError(f"slot {slot}: {len(window)} blocks exceed a window's {self.window_blocks}")
+        self._stage[slot], self._window[slot] = stage, window
+        self._lay(slot)
+
+    def turn(self, slot: int) -> list[int]:
+        """``slot``'s window has ended: its staged rows become readable (the
+        staging blocks go into the table behind the pooled blocks), the next
+        window starts at the entry behind them with no block yet and no staging,
+        and the ended window's blocks come back for :meth:`BlockAllocator.release`."""
+        if len(self._window[slot]) != self.window_blocks or len(self._stage[slot]) != self.pooled_blocks:
+            raise ValueError(f"slot {slot}: a window that is not whole cannot end")
+        if self.window_of(slot) + 1 >= self.windows:
+            raise ValueError(f"slot {slot}: no window behind the {self.windows} of max_seq")
+        released, self._window[slot] = self._window[slot], []
+        self._pooled[slot] = self._pooled[slot] + self._stage[slot]
+        self._stage[slot] = []
+        self._lay(slot)
+        return released
+
+    def _lay(self, slot: int) -> None:
+        held = self._pooled[slot] + self._window[slot]
+        self.tables[slot, : len(held)] = held
+        self.tables[slot, len(held) :] = TRASH_BLOCK
+        self.stage[slot, : len(self._stage[slot])] = self._stage[slot]
+        self.stage[slot, len(self._stage[slot]) :] = TRASH_BLOCK
+
+    def blocks_of(self, slot: int) -> list[int]:
+        """Every block ``slot`` holds: pooled, staging, its window's."""
+        return self._pooled[slot] + self._stage[slot] + self._window[slot]
+
+    @property
+    def held_blocks(self) -> int:
+        """Blocks all slots hold together."""
+        return self.held_window + self.held_pooled
+
+    @property
+    def held_window(self) -> int:
+        """Blocks of the slots' current windows."""
+        return sum(len(b) for b in self._window)
+
+    @property
+    def held_pooled(self) -> int:
+        """Blocks of pooled rows: the finished windows' and the staging."""
+        return sum(len(b) for b in self._pooled) + sum(len(b) for b in self._stage)
+
+    def release(self, slot: int) -> list[int]:
+        """Clear ``slot`` back to trash and return its blocks."""
+        blocks = self.blocks_of(slot)
+        self._pooled[slot], self._stage[slot], self._window[slot] = [], [], []
+        self._lay(slot)
+        self.lengths[slot] = 0
         return blocks
