@@ -18,9 +18,13 @@ the benchmark: no cell runs it. On one TPU
 * ``--protocol`` makes 200 calls in one program and 200 programs of one call at the ragged
   draw with the edges among its slots: none may differ from the first, and ``--watchdog-s``
   ends a run that hangs;
-* ``--grouped`` compares the Pallas grouped matmul (``megablox``, as ``ops/grouped_matmul.py``
-  tiles it, the layer read out of the stack) and ``jax.lax.ragged_dot`` with float64 at
-  decode's 384 rows and at a prefill's 12,288, and times both (``kimi``'s widths);
+* ``--grouped`` compares the grouped matmul the program calls (``ops/grouped_matmul.py``: since PR 46
+  the Pallas call ``grouped_matmul_walk``, the layer read out of the stack), ``megablox`` (imported
+  from jax for the comparison only, tiled and called as the program did until PR 46) and
+  ``jax.lax.ragged_dot`` with float64 and times each: microseconds a call and the bytes of the experts
+  that have rows over that time as a share of 819 GB/s (``weights_hbm_pct``), at the rows of a decode
+  step and of a step that carries a chunk of a prompt: 384 and 1,920 at ``kimi``'s widths, 512 and 2,560
+  with 16 of 128 experts held at ``k-exaone``'s; ``--grouped-only`` skips the latent kernel;
 * ``--parity`` serves one prompt through ``ServeEngine`` twice, with the kernels and with
   ``kernel_eligible`` answering no (the XLA functions), and prints for each how far the served
   tokens' logits lie below the reference's best (the numbers the cell's ``correct`` compares).
@@ -226,38 +230,69 @@ def protocol(rng, sh: dict, qd, stack, calls: int = 200) -> dict:  # noqa: ANN00
             "finite": bool(np.isfinite(first).all())}  # fmt: skip
 
 
-def grouped(rng, m: int) -> dict:  # noqa: ANN001
+#: the grouped matmul's shapes in the two cells whose steps differ most: (k, n) of the gate / up projection (the
+#: down projection is its transpose), experts held of experts published, rows of a decode step and of a step
+#: that carries a chunk of 256 (``(slots + 256) x picks``)
+GROUPED_SHAPES = {
+    "kimi": dict(k=2048, n=1408, held=64, experts=64, rows=(384, 1920)),
+    "k-exaone": dict(k=6144, n=2048, held=16, experts=128, rows=(512, 2560)),
+}
+
+
+def _megablox_tiling(k: int, n: int) -> tuple[int, int, int]:
+    """What ``ops/grouped_matmul.py::_tiling`` gave megablox until PR 46 at these rows: a row tile of
+    128 and the largest ``[tk, tn]`` under 3 MiB, the wider of two of one size."""
+    tiles = lambda x: [t for t in range(128, x + 1, 128) if x % t == 0]  # noqa: E731
+    _, tn, tk = max((tk * tn, tn, tk) for tk in tiles(k) for tn in tiles(n) if tk * tn * 2 <= 3 * 1024 * 1024)
+    return 128, tk, tn
+
+
+def grouped(rng, name: str, m: int, k: int, n: int, held: int, experts: int) -> dict:  # noqa: ANN001
+    """The kernel the program calls, megablox (imported from jax for this comparison only) and
+    ``jax.lax.ragged_dot`` at one shape: each against float64 and timed, with the bytes of the
+    experts that have rows over the time as a share of the wire."""
     import jax
     import jax.numpy as jnp
     import numpy as np
+    from jax.experimental.pallas.ops.tpu.megablox import gmm
 
     from torchx_tpu.ops import grouped_matmul as gm
+    from torchx_tpu.ops.attention import traced
 
-    L, E, k, n = 2, 64, 2048, 1408
-    w = jnp.asarray(rng.standard_normal((L, E, k, n), dtype=np.float32) * k**-0.5, jnp.bfloat16)
+    L = 2
+    w = jnp.asarray(rng.standard_normal((L, held, k, n), dtype=np.float32) * k**-0.5, jnp.bfloat16)
     x = jnp.asarray(rng.standard_normal((m, k), dtype=np.float32), jnp.bfloat16)
-    picks = np.sort(rng.integers(0, E, m))
-    sizes = jnp.asarray(np.bincount(picks, minlength=E), jnp.int32)
+    picks = np.sort(rng.integers(0, experts, m))  # the rows of experts held elsewhere lie behind the last group
+    sizes = jnp.asarray(np.bincount(picks, minlength=experts)[:held], jnp.int32)
+    here = int(np.asarray(sizes).sum())
     x64, w64 = np.asarray(x, np.float64), np.asarray(w[1], np.float64)
-    rows = min(m, 512)  # float64 on the host: the first rows are enough
+    rows = min(here, 512)  # float64 on the host: the first rows are enough
     want = np.stack([x64[r] @ w64[picks[r]] for r in range(rows)])
     layer = jnp.int32(1)
-    out = {"rows": m, "tiling": gm._tiling(m, k, n, 2, E)}
+    reached = int((np.asarray(sizes) > 0).sum())
+    out = {"shape": name, "rows": m, "rows_here": here, "groups_with_rows": reached, "tiling": gm._tiling(m, k, n, 2, experts)}
+    every = jnp.zeros((L * held,), jnp.int32)  # megablox as the program called it: every layer's groups, all empty but one's
     fns = {
-        "megablox": lambda x, w, s: gm.grouped_matmul(x, w, s, layer),
+        "kernel": lambda x, w, s: gm.grouped_matmul(x, w, s, layer, spread_over=experts),
+        "megablox": lambda x, w, s: gmm(
+            x, w.reshape(L * held, k, n), jax.lax.dynamic_update_slice(every, s, (layer * held,)),
+            preferred_element_type=x.dtype, tiling=_megablox_tiling(k, n),
+        ),
         "ragged_dot": lambda x, w, s: jax.lax.ragged_dot(x, w[1], s),
-    }
-    for name, fn in fns.items():
+    }  # fmt: skip
+    valid = (jnp.arange(m) < here)[:, None]  # a row of no group is whatever the kernel's buffer held
+    for impl, fn in fns.items():
         got = np.asarray(jax.jit(fn)(x, w, sizes), np.float64)[:rows]
-        out[f"{name}_max_err"] = float(np.abs(got - want).max())
+        out[f"{impl}_max_err"] = float(np.abs(got - want).max())
 
         def loop(x, w, s, fn=fn):  # noqa: ANN001, ANN202
-            return jax.lax.fori_loop(0, CALLS, lambda _, x: x + fn(x, w, s)[:, :1] * 1e-6, x)
+            return jax.lax.fori_loop(0, CALLS, lambda _, x: x + jnp.where(valid, fn(x, w, s)[:, :1], 0) * 1e-6, x)
 
         us = timed(jax.jit(loop), x, w, sizes) / CALLS * 1e6
-        out[f"{name}_us"] = us
-        out[f"{name}_weights_hbm_pct"] = 100.0 * min(E, m) * k * n * 2 / HBM_BYTES_PER_S / (us * 1e-6)
-        out[f"{name}_mxu_pct"] = 100.0 * 2 * m * k * n / 197e12 / (us * 1e-6)
+        out[f"{impl}_us"] = us
+        out[f"{impl}_weights_hbm_pct"] = 100.0 * reached * k * n * 2 / HBM_BYTES_PER_S / (us * 1e-6)
+        out[f"{impl}_mxu_pct"] = 100.0 * 2 * here * k * n / 197e12 / (us * 1e-6)
+    out["kernel_is"] = traced("grouped_matmul")
     return out
 
 
@@ -303,7 +338,8 @@ def main() -> int:
     ap.add_argument("--seed", type=int, default=27)
     ap.add_argument("--cell", choices=CELLS, default=CELLS[0], help="whose shapes the latent kernel is run at")
     ap.add_argument("--protocol", action="store_true", help="200 calls in one program and 200 programs of one call")
-    ap.add_argument("--grouped", action="store_true", help="the grouped matmul too (kimi's widths)")
+    ap.add_argument("--grouped", action="store_true", help="the grouped matmul too (kimi's and k-exaone's widths)")
+    ap.add_argument("--grouped-only", action="store_true", help="the grouped matmul and nothing else")
     ap.add_argument("--parity", action="store_true")
     ap.add_argument("--watchdog-s", type=int, default=1500, help="exit if the whole run takes longer: a kernel that hangs")
     args = ap.parse_args()
@@ -321,14 +357,18 @@ def main() -> int:
     setup_compilation_cache()
     faulthandler.dump_traceback_later(args.watchdog_s, exit=True)
     rng = np.random.default_rng(args.seed)
-    sh = cell_shapes(args.cell)
-    qd, stack = latent_inputs(args.seed, sh)
-    print(json.dumps({"device_kind": dev.device_kind, "attention": attention(rng, sh, qd, stack)}), flush=True)
-    if args.protocol:
-        print(json.dumps({"protocol": protocol(rng, sh, qd, stack)}), flush=True)
-    del qd, stack
-    if args.grouped:
-        print(json.dumps({"grouped_decode": grouped(rng, 384), "grouped_prefill": grouped(rng, 12288)}), flush=True)
+    if not args.grouped_only:
+        sh = cell_shapes(args.cell)
+        qd, stack = latent_inputs(args.seed, sh)
+        print(json.dumps({"device_kind": dev.device_kind, "attention": attention(rng, sh, qd, stack)}), flush=True)
+        if args.protocol:
+            print(json.dumps({"protocol": protocol(rng, sh, qd, stack)}), flush=True)
+        del qd, stack
+    if args.grouped or args.grouped_only:
+        for name, sh in GROUPED_SHAPES.items():
+            for m in sh["rows"]:
+                for k, n in ((sh["k"], sh["n"]), (sh["n"], sh["k"])):  # gate / up, then down
+                    print(json.dumps({"grouped": grouped(rng, name, m, k, n, sh["held"], sh["experts"])}), flush=True)
     if args.parity:
         print(json.dumps({"parity": parity(args.seed)}), flush=True)
     return 0

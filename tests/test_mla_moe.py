@@ -15,7 +15,9 @@ comparison to that.
 from __future__ import annotations
 
 import dataclasses
+import functools
 import importlib
+import re
 
 import jax
 import jax.numpy as jnp
@@ -447,11 +449,111 @@ def test_grouped_matmul_kernel_matches_ragged_dot(sizes):
     assert attn_ops.traced("grouped_matmul") == "ragged_dot"
 
 
+@pytest.mark.parametrize("m,k,n,chunks,sizes", [
+    pytest.param(512, 128, 128, 1, [60, 300, 100, 52], id="a-group-crosses-two-tile-edges"),
+    pytest.param(384, 256, 128, 2, [100, 0, 30, 20], id="groups-sum-to-less-than-m"),  # a chip's share: rows of no group behind
+    pytest.param(256, 128, 128, 1, [0, 0, 0, 0], id="no-group-has-a-row"),
+    # the step that carries a chunk at kimi's counts ((64 + 256) x 6 rows, 64 experts), cut in k and n
+    pytest.param(1920, 256, 256, 2, None, id="64-groups-over-15-tiles"),
+    pytest.param(512, 384, 512, 3, [128, 128, 1, 255], id="three-chunks-and-edges-on-tile-edges"),
+])  # fmt: skip
+def test_grouped_matmul_walks_the_groups_that_have_rows(m, k, n, chunks, sizes):
+    """What the walk has that a grid over row tiles had not: a group's weights land once whatever
+    the row tiles it reaches into, in chunks of ``k``; rows behind the last group are not computed."""
+    rng = np.random.default_rng(m + k)
+    if sizes is None:
+        sizes = np.bincount(rng.integers(0, 64, m), minlength=64)
+    g, here = len(sizes), int(np.sum(sizes))
+    lhs = jnp.asarray(rng.standard_normal((m, k)), jnp.float32)
+    rhs = jnp.asarray(rng.standard_normal((g, k, n)), jnp.float32)
+    assert k // gm._tiling(m, k, n, 4, g)[1] == chunks  # of k: what a copy brings and a product multiplies by
+    want = jax.lax.ragged_dot(lhs, rhs, jnp.asarray(sizes, jnp.int32), precision=jax.lax.Precision.HIGHEST)
+    got = gm.grouped_matmul(lhs, rhs, jnp.asarray(sizes, jnp.int32), interpret=True)
+    np.testing.assert_allclose(got[:here], want[:here], atol=1e-4, rtol=1e-4)
+
+
+@pytest.mark.parametrize("dma", ["on_wait", "eager"])
+@pytest.mark.parametrize("ring_columns", [256, 128], ids=["one-column-tile", "two-column-tiles"])
+def test_grouped_matmul_bookkeeping_in_the_tpu_interpreter(ring_columns, dma, capfd, monkeypatch):
+    """The walk in the TPU interpreter, which simulates the copies' semaphores, hands out scratch full of
+    NaN and watches for races: every chunk waited for before it is multiplied by, none started into a
+    chunk still to be read, nothing left in flight behind the last pair; with the ring cut to half of
+    ``n`` the walk runs once a column tile and the last pair of one starts the first group of the next."""
+    rng = np.random.default_rng(5)
+    m, k, n, sizes = 512, 384, 256, [60, 300, 0, 100]
+    monkeypatch.setattr(gm, "_RING_BYTES", k * ring_columns * 4)
+    assert gm._tiling(m, k, n, 4, 4) == (128, 128, ring_columns)
+    lhs = jnp.asarray(rng.standard_normal((m, k)), jnp.float32)
+    stack = jnp.asarray(rng.standard_normal((2, 4, k, n)), jnp.float32)
+    g = jnp.asarray(sizes, jnp.int32)
+    want = jax.lax.ragged_dot(lhs, stack[1], g, precision=jax.lax.Precision.HIGHEST)
+    got = gm.grouped_matmul(
+        lhs, stack, g, jnp.int32(1), interpret=pltpu.InterpretParams(dma_execution_mode=dma, detect_races=True))  # fmt: skip
+    np.testing.assert_allclose(np.asarray(got)[: sum(sizes)], np.asarray(want)[: sum(sizes)], atol=1e-4, rtol=1e-4)
+    out = capfd.readouterr().out
+    assert "non-zero count" not in out and "RACE DETECTED" not in out, out
+
+
+def test_grouped_matmul_differentiates_as_ragged_dot_does():
+    """A gradient through the interpreted kernel (its layer read out of a stack), with respect to the
+    rows and to the stack, against the gradient through ``jax.lax.ragged_dot`` on that layer."""
+    rng = np.random.default_rng(46)
+    lhs = jnp.asarray(rng.standard_normal((256, 128)), jnp.float32)
+    stack = jnp.asarray(rng.standard_normal((3, 4, 128, 256)), jnp.float32)
+    g = jnp.asarray([100, 0, 28, 128], jnp.int32)
+    weigh = jnp.asarray(rng.standard_normal((256, 256)), jnp.float32)
+    kernel = lambda lhs, stack: (gm.grouped_matmul(lhs, stack, g, jnp.int32(2), interpret=True) * weigh).sum()  # noqa: E731
+    plain = lambda lhs, stack: (jax.lax.ragged_dot(lhs, stack[2], g, precision=jax.lax.Precision.HIGHEST) * weigh).sum()  # noqa: E731
+    got, want = jax.grad(kernel, (0, 1))(lhs, stack), jax.grad(plain, (0, 1))(lhs, stack)
+    for a, b in zip(got, want):
+        np.testing.assert_allclose(a, b, atol=1e-4, rtol=1e-4)
+    assert float(jnp.abs(got[1][:2]).max()) == 0.0 and float(jnp.abs(got[1][2]).max()) > 0.0  # the layer's experts alone
+
+
+def test_grouped_matmul_is_traced_once_a_shape_not_once_a_call_site(monkeypatch):
+    """The set-up budget (PR 46): seven call sites of one shape in one program, three with the layer's
+    number a Python int and four written out in a ``lax.scan`` body with a traced one, as
+    ``llama.scan_layers`` runs ``k-exaone``'s expert layers, trace the kernel's body once, and the lowered
+    module holds one function with the kernel in it, called from the seven sites (jax lowers the scan
+    body's calls to the same function as the others: one, not two). A ``pallas_call`` built in a plain
+    function was traced and lowered at every site: 0.35 s a site on the benchmark machine, 42 sites an engine."""
+    from torchx_tpu.ops import grouped_matmul_kernel as gk
+
+    traces = []
+    body = gk._kernel
+    monkeypatch.setattr(gk, "_kernel", functools.wraps(body)(lambda *refs: traces.append(1) or body(*refs)))
+    rng = np.random.default_rng(7)
+    lhs = jnp.asarray(rng.standard_normal((384, 128)), jnp.float32)  # a shape no other test of this file walks
+    stack = jnp.asarray(rng.standard_normal((7, 4, 128, 128)) * 0.1, jnp.float32)
+    g = jnp.asarray([100, 0, 156, 128], jnp.int32)
+
+    def program(x, stack, g):
+        for i in range(3):
+            x = gm.grouped_matmul(x, stack, g, i, interpret=True)
+
+        def period(x, p):
+            for j in range(4):
+                x = gm.grouped_matmul(x, stack, g, 3 + 4 * p + j, interpret=True)
+            return x, None
+
+        return jax.lax.scan(period, x, jnp.arange(1, dtype=jnp.int32))[0]
+
+    text = jax.jit(program).lower(lhs, stack, g).as_text()
+    assert len(traces) == 1
+    assert len(re.findall(r"func\.func private @walk\w*\(", text)) == 1
+    assert len(re.findall(r"call @walk\w*\(", text)) == 7
+    want = lhs
+    for i in range(7):
+        want = jax.lax.ragged_dot(want, stack[i], g, precision=jax.lax.Precision.HIGHEST)
+    np.testing.assert_allclose(jax.jit(program)(lhs, stack, g), want, atol=1e-4, rtol=1e-4)
+    assert len(traces) == 1
+
+
 @pytest.mark.parametrize("m,k,n,want", [
-    (384, 2048, 1408, (128, 1024, 1408)),  # decode: 64 slots x 6 picks, 6 rows a group
-    (12288, 2048, 1408, (256, 1024, 1408)),  # a prefill of 2,048 tokens: 192 rows a group
-    (49152, 1408, 2048, (512, 1408, 1024)),  # the widest prefill's down-projection: 768
-    (96, 2048, 1408, (0, 1024, 1408)),  # rows that fill no tile: ragged_dot
+    (384, 2048, 1408, (128, 256, 1408)),  # decode: 64 slots x 6 picks, 6 rows a group; an expert in 8 chunks of 0.7 MB
+    (12288, 2048, 1408, (256, 256, 1408)),  # a prefill of 2,048 tokens: 192 rows a group
+    (49152, 1408, 2048, (512, 128, 2048)),  # the widest prefill's down-projection: 768; 1,408 = 11 x 128 splits no other way
+    (96, 2048, 1408, (0, 256, 1408)),  # rows that fill no tile: ragged_dot
 ])  # fmt: skip
 def test_grouped_matmul_tiles_follow_the_shapes(m, k, n, want):
     assert gm._tiling(m, k, n, 2, groups=64) == want

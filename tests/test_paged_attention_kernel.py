@@ -798,17 +798,18 @@ def test_decode_program_multiplies_the_projections_where_they_lie_on_the_chip(on
     pytest.param(16384, 2048, 6144, 16, 128, id="share-widest-prefill-down"),
 ])  # fmt: skip
 def test_grouped_matmul_compiles_for_the_chip(one_chip, m, k, n, held, spread):
-    from jax.experimental.pallas.ops.tpu.megablox import gmm
-
+    """The entry the program calls (``ops/grouped_matmul_kernel.py::walk``, PR 46: megablox until then), with the
+    tiling ``grouped_matmul`` hands it at this shape: the ring of a group's weights fits beside the row tiles."""
     from torchx_tpu.ops import grouped_matmul as gm
+    from torchx_tpu.ops import grouped_matmul_kernel as gk
 
     shape = lambda s, d: jax.ShapeDtypeStruct(s, d, sharding=one_chip)  # noqa: E731
     assert gm.kernel_eligible((m, k), (held, k, n), jnp.dtype(jnp.bfloat16), jnp.dtype(jnp.bfloat16), "tpu")
     tiling = gm._tiling(m, k, n, 2, groups=spread)
-    compiled = jax.jit(lambda l, r, g: gmm(l, r, g, preferred_element_type=jnp.bfloat16, tiling=tiling)).lower(
-        shape((m, k), jnp.bfloat16), shape((held, k, n), jnp.bfloat16), shape((held,), jnp.int32)
+    compiled = jax.jit(lambda l, r, g, at: gk.walk(l, r, g, at, tiling=tiling)).lower(
+        shape((m, k), jnp.bfloat16), shape((2, held, k, n), jnp.bfloat16), shape((held,), jnp.int32), shape((), jnp.int32)
     ).compile()  # fmt: skip
-    assert "tpu_custom_call" in compiled.as_text()
+    assert "tpu_custom_call" in compiled.as_text() and gk.KERNEL_NAME in compiled.as_text()
 
 
 # The step that carries a chunk of a prompt (PR 40), at the five serving cells' widths and slots (their layers

@@ -18,8 +18,12 @@ A model of many small experts (64 of width 1,408, six a token) cannot pay
 for capacity buffers: to drop nothing every expert would compute every
 token. ``capacity_factor = 0`` says the model drops no routing, and
 :func:`moe_ffn` then sorts the (token, choice) rows by expert and runs one
-grouped matmul over the experts held (:mod:`torchx_tpu.ops.grouped_matmul`),
-whatever the imbalance. The router's scoring (softmax, or sigmoid with a
+grouped matmul over the experts held (:mod:`torchx_tpu.ops.grouped_matmul`:
+on a TPU the Pallas call ``grouped_matmul_walk``, a walk over the experts that
+have rows in which an expert's weights cross the wire once a call, traced once
+a shape in a process whatever the call sites; elsewhere
+``jax.lax.ragged_dot``, which also differentiates it), whatever the
+imbalance. The router's scoring (softmax, or sigmoid with a
 selection bias that chooses and never weighs), the scale on the routed sum,
 a shared expert beside the routed ones and leading dense layers are fields
 of the config, not functions of their own.

@@ -2,7 +2,7 @@
 
 ``grouped_matmul(lhs [m, k], rhs [g, k, n], group_sizes [g])`` multiplies the
 first ``group_sizes[0]`` rows of ``lhs`` by ``rhs[0]``, the next
-``group_sizes[1]`` by ``rhs[1]``, and so on (``sum(group_sizes) == m``): what a
+``group_sizes[1]`` by ``rhs[1]``, and so on (``sum(group_sizes) <= m``): what a
 dropless expert layer needs once its (token, choice) rows are in expert
 order. No row is padded to a capacity and none is dropped; an expert nobody
 chose costs nothing but the look at its count.
@@ -11,18 +11,25 @@ chose costs nothing but the look at its count.
 saying which one to multiply by: a layer's experts sliced out of the stack
 under a ``lax.scan`` are copied in front of a kernel (1.1 GB a layer a step
 at 64 experts of 2048 x 1408: 21 ms of a decode step, my chip run, PR 27),
-where the kernel can as well be given every layer's experts and sizes that
-are zero outside the one layer: an empty group costs a look at its count.
+where the kernel can as well be given the stack and read its layer where it
+lies.
 
-On a TPU, where :func:`kernel_eligible` allows, it is the Pallas grouped
-matmul that ships with jax (``jax.experimental.pallas.ops.tpu.megablox``): a
-grid over row tiles that each visit the one or two experts their rows belong
-to, so an expert's weights are read once for each row tile it reaches into
-and a tile's rows are multiplied on the MXU together. The tile sizes are
-chosen from the shapes (:func:`_tiling`). Elsewhere (the CPU, shapes that do
-not tile) it is :func:`jax.lax.ragged_dot`, which is also what the kernel is
-tested against. ``ops.attention.traced("grouped_matmul")`` says which a
-program lowered to (``megablox`` / ``ragged_dot``).
+On a TPU, where :func:`kernel_eligible` allows, it is this repo's own Pallas
+kernel (:mod:`torchx_tpu.ops.grouped_matmul_kernel`, the Pallas call
+``grouped_matmul_walk``; PR 46): a walk over the groups that have rows in
+which an expert's weights cross the wire once a call, chunk by chunk into a
+ring that the next group's copies refill as the chunks are done with, while
+the row tiles of ``lhs`` and of the result stay in fast memory for as long as
+groups reach into them. Until PR 46 it was the grouped matmul that ships
+with jax (``megablox``), a grid over row tiles that reads an expert's weights
+once for every row tile the expert's rows reach into: +18% of the experts'
+bytes at the 1,920 sorted rows of a step that carries a chunk of a prompt
+(PERF.md section 6, PR 46). The kernel is traced once a shape in a process,
+not once a call site. The tile sizes are chosen from the shapes
+(:func:`_tiling`). Elsewhere (the CPU, shapes that do not tile) it is
+:func:`jax.lax.ragged_dot`, which is also what the kernel is tested against
+and what differentiates it. ``ops.attention.traced("grouped_matmul")`` says
+which a program lowered to (``walk`` / ``ragged_dot``).
 """
 
 from __future__ import annotations
@@ -32,8 +39,10 @@ import jax.numpy as jnp
 
 from torchx_tpu.ops.attention import note_traced
 
-#: bytes of one ``rhs`` tile the kernel keeps in each of its two buffers
-_RHS_TILE_BYTES = 3 * 1024 * 1024
+#: bytes of one chunk of a group's weights: what one copy brings and one product multiplies by
+_CHUNK_BYTES = 1024 * 1024
+#: bytes of the ring a group's ``[k, tn]`` weights land in, the kernel's largest buffer in fast memory
+_RING_BYTES = 32 * 1024 * 1024
 
 
 def _tiles(x: int, limit: int) -> list[int]:
@@ -47,18 +56,20 @@ def _tiling(m: int, k: int, n: int, itemsize: int, groups: int = 1) -> tuple[int
     (``m / groups``): a tile multiplies all its rows by every expert that
     reaches into it, so at decode's six rows a group a tile of 384 rows did
     64 times the arithmetic and ran compute-bound at 56% of the weights' wire
-    (my chip run, PR 27), while prefill's hundreds of rows a group want tiles
-    that re-read an expert's weights seldom. The ``rhs`` tile is the largest
-    ``[tk, tn]`` under :data:`_RHS_TILE_BYTES`, the wider of two of one size."""
+    (my chip run, PR 27), while the hundreds of rows a group of a long prompt
+    want a last row tile long enough to hide the next group's copy behind. A
+    group's weights land as ``[k, tn]``, ``tn`` the widest the ring's
+    :data:`_RING_BYTES` hold (all of ``n`` at every width a cell has: ``lhs``
+    is read once for each ``tn``), in chunks of ``tk`` rows, the largest under
+    :data:`_CHUNK_BYTES` that leaves two chunks or more, so that a copy is in
+    flight while a chunk multiplies (my chip runs, PR 46, the kernel alone at
+    1,920 rows of ``kimi``'s gate projection: chunks of 0.7 / 1.4 / 2.9 MB
+    543 / 559 / 583 us a call; at 384 rows all alike)."""
     fits = [t for t in _tiles(m, 512) if t >= m / groups]
     tm = min(fits, default=max(_tiles(m, 512), default=0))
-    pairs = [
-        (tk * tn, tn, tk)
-        for tk in _tiles(k, k)
-        for tn in _tiles(n, n)
-        if tk * tn * itemsize <= _RHS_TILE_BYTES
-    ]
-    _, tn, tk = max(pairs, default=(0, 0, 0))
+    tn = max((t for t in _tiles(n, n) if k * t * itemsize <= _RING_BYTES), default=0)
+    halves = _tiles(k, max(128, k // 2))
+    tk = max((t for t in halves if t * tn * itemsize <= _CHUNK_BYTES), default=min(halves, default=0))
     return tm, tk, tn
 
 
@@ -87,7 +98,7 @@ def grouped_matmul(
     lhs: jnp.ndarray,  # [m, k] rows in group order
     rhs: jnp.ndarray,  # [g, k, n], or a stack of layers [L, g, k, n] with ``layer``
     group_sizes: jnp.ndarray,  # [g] int32, summing to m
-    layer: jnp.ndarray | None = None,  # scalar int32: which of ``rhs``'s L layers
+    layer: jnp.ndarray | int | None = None,  # scalar int32: which of ``rhs``'s L layers
     interpret: bool = False,
     spread_over: int = 0,
 ) -> jnp.ndarray:
@@ -98,19 +109,19 @@ def grouped_matmul(
     ``spread_over`` is the number of groups the ``m`` rows were drawn over where
     that is more than ``rhs`` holds (the published experts): a group's mean
     size, which the row tile is chosen by, is ``m / spread_over``.
-    ``interpret`` runs the Pallas kernel in its interpreter (the CPU tests)."""
+    ``interpret`` runs the Pallas kernel in its interpreter (the CPU tests).
+    One kernel serves every shape :func:`kernel_eligible` admits, the decode
+    step's few rows a group and the rows of a step that carries a chunk alike."""
     group_sizes = group_sizes.astype(jnp.int32)
     g = rhs.shape[-3]
     if interpret or kernel_eligible(lhs.shape, rhs.shape[-3:], lhs.dtype, rhs.dtype, jax.default_backend()):
         # imported here: Pallas costs a second that no CPU process should pay
-        from jax.experimental.pallas.ops.tpu.megablox import gmm
+        from torchx_tpu.ops.grouped_matmul_kernel import walk
 
-        note_traced("grouped_matmul", "megablox")
-        if rhs.ndim == 4:  # every layer's groups, all empty but this layer's
-            every = jnp.zeros((rhs.shape[0] * g,), jnp.int32)
-            group_sizes = jax.lax.dynamic_update_slice(every, group_sizes, (layer * g,))
-            rhs = rhs.reshape(rhs.shape[0] * g, *rhs.shape[2:])
-        tiling = _tiling(lhs.shape[0], lhs.shape[1], rhs.shape[2], rhs.dtype.itemsize, spread_over or g)
-        return gmm(lhs, rhs, group_sizes, preferred_element_type=lhs.dtype, tiling=tiling, interpret=interpret)
+        note_traced("grouped_matmul", "walk")
+        tiling = _tiling(lhs.shape[0], lhs.shape[1], rhs.shape[-1], rhs.dtype.itemsize, spread_over or g)
+        # an array whatever the caller holds, a layer's number in a Python loop or a scan's counter: one trace
+        at = jnp.asarray(0 if layer is None else layer, jnp.int32)
+        return walk(lhs, rhs.reshape(-1, *rhs.shape[-3:]), group_sizes, at, tiling=tiling, interpret=interpret)
     note_traced("grouped_matmul", "ragged_dot")
     return jax.lax.ragged_dot(lhs, rhs[layer] if rhs.ndim == 4 else rhs, group_sizes)
