@@ -1,6 +1,7 @@
 """Model/ops/parallel stack tests on the 8-device CPU mesh."""
 
 import dataclasses
+import importlib
 
 import jax
 import jax.numpy as jnp
@@ -13,6 +14,8 @@ from torchx_tpu.ops.norms import rms_norm
 from torchx_tpu.ops.ring_attention import ring_attention
 from torchx_tpu.ops.rope import apply_rope, rope_frequencies
 from torchx_tpu.parallel.mesh import MeshConfig, make_mesh
+
+attn_ops = importlib.import_module("torchx_tpu.ops.attention")  # the package exports the function under this name
 
 
 class TestMeshConfig:
@@ -354,34 +357,154 @@ class TestLlama:
         )
         np.testing.assert_allclose(out, ref, atol=1e-3)
 
-    def test_chunked_loss_matches_unchunked(self):
-        cfg = llama.llama_tiny(max_seq=64, loss_chunk=16)
-        cfg_full = dataclasses.replace(cfg, loss_chunk=0)
-        params = llama.init_params(cfg, jax.random.PRNGKey(0))
+    @staticmethod
+    def _chunked_and_whole(make=llama.llama_tiny, **overrides):
+        """A tiny config whose loss is summed four chunks at a time, the same
+        with the loss taken whole, and parameters and a batch for both."""
+        cfg = make(max_seq=64, loss_chunk=16, **overrides)
+        init, _ = llama.model_fns(cfg)
+        params = init(cfg, jax.random.PRNGKey(0))
         tokens = jax.random.randint(jax.random.PRNGKey(1), (4, 65), 0, 512)
-        batch = {"tokens": tokens}
+        return cfg, dataclasses.replace(cfg, loss_chunk=0), params, {"tokens": tokens}
+
+    @staticmethod
+    def _assert_same_loss_and_gradients(cfg, cfg_full, params, batch, mesh=None, by=1.0, atol=2e-5):
+        """Loss and every gradient leaf, chunked against whole, under an outer
+        cotangent of ``by``; the chunked one differentiated through the fused rule."""
+
+        def run(c):
+            return jax.jit(jax.value_and_grad(lambda p: by * llama.loss_fn(p, batch, c, mesh)))(params)
+
+        attn_ops.TRACED.pop("loss", None)
+        (l1, g1), traced = run(cfg), attn_ops.traced("loss")
+        l2, g2 = run(cfg_full)
+        assert traced == "fused" and attn_ops.traced("loss") == "fused+whole"
+        np.testing.assert_allclose(l1, l2, rtol=1e-5)
+        assert jax.tree.structure(g1) == jax.tree.structure(params)
+        for (path, a), b in zip(jax.tree_util.tree_leaves_with_path(g1), jax.tree.leaves(g2)):
+            assert a.dtype == b.dtype and float(jnp.abs(b).max()) > 0, path
+            np.testing.assert_allclose(a, b, atol=atol * by, err_msg=str(path))
+
+    @pytest.mark.parametrize(
+        "overrides,by",
+        [
+            pytest.param({}, 1.0, id="plain"),
+            pytest.param({}, 3.0, id="outer-cotangent-3"),
+            pytest.param({"tie_embeddings": True}, 1.0, id="tied-embeddings"),
+            pytest.param({"ce_f32_logits": True}, 1.0, id="f32-logits"),
+            pytest.param({"pred_heads": 2}, 1.0, id="first-of-two-heads"),
+            pytest.param({"lm_head_multiplier": 0.5}, 1.0, id="head-multiplier"),
+        ],
+    )
+    def test_chunked_loss_matches_unchunked(self, overrides, by):
+        cfg, cfg_full, params, batch = self._chunked_and_whole(**overrides)
         np.testing.assert_allclose(
             llama.loss_fn(params, batch, cfg),
             llama.loss_fn(params, batch, cfg_full),
             rtol=1e-5,
         )
-        g1 = jax.grad(llama.loss_fn)(params, batch, cfg)
-        g2 = jax.grad(llama.loss_fn)(params, batch, cfg_full)
-        for a, b in zip(jax.tree.leaves(g1), jax.tree.leaves(g2)):
+        self._assert_same_loss_and_gradients(cfg, cfg_full, params, batch, by=by)
+
+    @pytest.mark.parametrize("ce_f32_logits", [False, True], ids=["stored-bf16", "stored-f32"])
+    def test_chunked_loss_matches_unchunked_in_bf16(self, ce_f32_logits):
+        # bf16 weights: the logits are stored bf16 or float32 as the config says, and the two
+        # gradient matmuls take autodiff's own operands; both sides round alike, a chunk or whole
+        cfg, cfg_full, params, batch = self._chunked_and_whole(dtype=jnp.bfloat16, ce_f32_logits=ce_f32_logits)
+        self._assert_same_loss_and_gradients(cfg, cfg_full, params, batch, by=3.0, atol=2e-3)
+
+    def test_chunked_loss_matches_unchunked_moe_with_aux_term(self):
+        from torchx_tpu.models import moe
+
+        cfg, cfg_full, params, batch = self._chunked_and_whole(moe.moe_tiny, router_aux_coef=0.05)
+        assert float(llama.loss_and_aux(params, batch, cfg)[1][llama.AUX_BALANCE]) > 0
+        self._assert_same_loss_and_gradients(cfg, cfg_full, params, batch, by=3.0)
+
+    def test_chunked_loss_matches_unchunked_on_an_fsdp_tp_mesh(self):
+        cfg, cfg_full, params, batch = self._chunked_and_whole()
+        mesh = make_mesh(MeshConfig(dp=2, fsdp=2, tp=2, sp=1))
+        sharded = llama.shard_params(params, cfg, mesh)
+        self._assert_same_loss_and_gradients(cfg, cfg_full, sharded, batch, mesh=mesh, by=3.0)
+        ref = jax.grad(llama.loss_fn)(params, batch, cfg_full)  # and against one device
+        got = jax.jit(jax.grad(lambda p: llama.loss_fn(p, batch, cfg, mesh)))(sharded)
+        for a, b in zip(jax.tree.leaves(got), jax.tree.leaves(ref)):
             np.testing.assert_allclose(a, b, atol=2e-5)
 
-    def test_chunked_loss_with_mask(self):
-        cfg = llama.llama_tiny(max_seq=64, loss_chunk=16)
-        cfg_full = dataclasses.replace(cfg, loss_chunk=0)
-        params = llama.init_params(cfg, jax.random.PRNGKey(0))
-        tokens = jax.random.randint(jax.random.PRNGKey(1), (2, 65), 0, 512)
-        mask = jax.random.uniform(jax.random.PRNGKey(2), (2, 65)) > 0.5
-        batch = {"tokens": tokens, "loss_mask": mask}
+    @pytest.mark.parametrize("by", [1.0, 3.0], ids=["plain", "outer-cotangent-3"])
+    def test_chunked_loss_with_mask(self, by):
+        cfg, cfg_full, params, batch = self._chunked_and_whole()
+        batch["loss_mask"] = jax.random.uniform(jax.random.PRNGKey(2), (4, 65)) > 0.5
         np.testing.assert_allclose(
             llama.loss_fn(params, batch, cfg),
             llama.loss_fn(params, batch, cfg_full),
             rtol=1e-5,
         )
+        self._assert_same_loss_and_gradients(cfg, cfg_full, params, batch, by=by)
+
+    def test_chunked_loss_with_an_empty_mask(self):
+        cfg, cfg_full, params, batch = self._chunked_and_whole()
+        batch["loss_mask"] = jnp.zeros((4, 65), bool)
+        loss, grads = jax.value_and_grad(llama.loss_fn)(params, batch, cfg)
+        assert float(loss) == 0.0 and all(float(jnp.abs(g).max()) == 0.0 for g in jax.tree.leaves(grads))
+
+    @pytest.mark.parametrize(
+        "loss_chunk,seq,grad,want",
+        [
+            pytest.param(16, 64, False, "chunked", id="evaluation"),
+            pytest.param(16, 64, True, "fused", id="differentiated"),
+            pytest.param(0, 64, True, "whole", id="no-chunk"),
+            pytest.param(16, 56, True, "whole", id="chunk-does-not-divide"),
+            pytest.param(16, 16, True, "whole", id="one-chunk"),
+        ],
+    )
+    def test_the_loss_says_which_form_it_traced(self, loss_chunk, seq, grad, want):
+        cfg = llama.llama_tiny(max_seq=64, loss_chunk=loss_chunk)
+        params = llama.init_params(cfg, jax.random.PRNGKey(0))
+        batch = {"tokens": jnp.zeros((2, seq + 1), jnp.int32)}
+        attn_ops.TRACED.pop("loss", None)
+        loss = lambda p: llama.loss_fn(p, batch, cfg)  # noqa: E731
+        jax.eval_shape(jax.grad(loss) if grad else loss, params)
+        assert attn_ops.traced("loss") == want
+
+    def test_the_train_step_differentiates_the_fused_loss(self):
+        from torchx_tpu.train import step as tl
+
+        cfg = llama.llama_tiny(max_seq=64, loss_chunk=16)
+        mesh = make_mesh(MeshConfig(fsdp=1), devices=jax.devices()[:1])
+        optimizer = tl.make_optimizer()
+        state = jax.eval_shape(lambda: tl.init_state(cfg, mesh, optimizer))
+        attn_ops.TRACED.pop("loss", None)
+        tl.make_train_step(cfg, mesh, optimizer).lower(state, {"tokens": jax.ShapeDtypeStruct((2, 65), jnp.int32)})
+        assert attn_ops.traced("loss") == "fused"
+
+    @pytest.mark.parametrize("masked", [False, True], ids=["unmasked", "masked"])
+    def test_a_chunks_logits_are_made_once_under_grad(self, masked):
+        """The mechanism in the program and not in the numbers: one matmul makes a
+        chunk's logits and two make its gradient; nothing rematerializes them."""
+        vocab = 384  # no other width of the tiny model
+        cfg = llama.llama_tiny(max_seq=64, loss_chunk=16, vocab_size=vocab)
+        params = llama.init_params(cfg, jax.random.PRNGKey(0))
+        batch = {"tokens": jnp.zeros((4, 65), jnp.int32)}
+        if masked:
+            batch["loss_mask"] = jnp.ones((4, 65), bool)
+
+        def walk(jaxpr, inside=()):
+            for eqn in jaxpr.eqns:
+                yield eqn, inside
+                for sub in jax.core.jaxprs_in_params(eqn.params):
+                    yield from walk(sub, inside + (eqn.primitive.name,))
+
+        jaxpr = jax.make_jaxpr(jax.grad(lambda p: llama.loss_fn(p, batch, cfg)))(params).jaxpr
+        over_vocab = [
+            (eqn, inside)
+            for eqn, inside in walk(jaxpr)
+            if eqn.primitive.name == "dot_general" and any(vocab in v.aval.shape for v in (*eqn.invars, *eqn.outvars))
+        ]
+        assert len(over_vocab) == 3
+        for eqn, inside in over_vocab:
+            assert "scan" in inside and not {"checkpoint", "remat", "remat2"} & set(inside), inside
+        # logits [b, c, v]; dx [b, c, d], the vocabulary contracted away; dW [d, v] (dimensions in any order)
+        made = sorted(tuple(sorted(eqn.outvars[0].aval.shape)) for eqn, _ in over_vocab)
+        assert made == [(4, 16, 64), (4, 16, vocab), (64, vocab)]
 
     def test_loss_decreases(self):
         from torchx_tpu.train.run import train

@@ -812,6 +812,40 @@ def test_grouped_matmul_compiles_for_the_chip(one_chip, m, k, n, held, spread):
     assert "tpu_custom_call" in compiled.as_text() and gk.KERNEL_NAME in compiled.as_text()
 
 
+def test_the_train_cells_step_compiles_for_the_chip_with_a_chunks_logits_made_once(one_chip, monkeypatch):
+    """``mistral7b-train-4k``'s step as ``benchmark/rehearse_compile.py`` builds it (the cell's config, splash named,
+    state and batch as shapes): the loss's loop holds no recomputed head matmul (PR 47: the backward loop of a
+    rematerialized scan made every chunk's ``[2, 512, 32768]`` logits again), and the program still fits the chip."""
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    from benchmark.lib import models, spec
+    from torchx_tpu.parallel.mesh import make_mesh
+    from torchx_tpu.parallel.mesh_config import parse_mesh_spec
+    from torchx_tpu.train import step as tl
+
+    cell = spec.load_cell("mistral7b-train-4k")
+    dep, job = cell.config["deployment"], cell.traffic
+    batch, seq = int(dep["batch"]), int(job["seq"])
+    cfg = models.program_config(cell.config, max_seq=seq, remat_policy=dep["remat_policy"], kernels="reference", attn_impl="splash")  # fmt: skip
+    assert cfg.loss_chunk and seq % cfg.loss_chunk == 0 and seq > cfg.loss_chunk
+    mesh = make_mesh(parse_mesh_spec(dep["mesh"]), devices=list(one_chip.device_set))
+    whole = NamedSharding(mesh, P())
+    on_chip = lambda tree: jax.tree.map(lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=whole), tree)  # noqa: E731
+    optimizer = tl.make_optimizer(lr=job["lr"], warmup=job["warmup"])
+    state = on_chip(jax.eval_shape(lambda: tl.init_state(cfg, mesh, optimizer)))
+    tokens = on_chip({"tokens": jax.ShapeDtypeStruct((batch, seq + 1), jnp.int32)})
+    monkeypatch.setattr(attn_ops, "TRACED", {})
+    step = tl.make_train_step(cfg, mesh, optimizer, state_shardings=jax.tree.map(lambda x: x.sharding, state))
+    compiled = step.lower(state, tokens).compile()
+    assert attn_ops.traced("loss") == "fused" and attn_ops.traced("attention") == "splash"
+    text = compiled.as_text()
+    assert "lm_head" in text and "rematted_computation/lm_head" not in text
+    assert "rematted_computation/mlp" in text  # the layers' recomputation is the file's remat_policy, and stays
+    m = compiled.memory_analysis()
+    live = m.argument_size_in_bytes + m.output_size_in_bytes + m.temp_size_in_bytes - m.alias_size_in_bytes
+    assert live <= 13.85 * 2**30  # 13.57 GiB before this form, 13.80 with it (rehearsal, PR 47)
+
+
 # The step that carries a chunk of a prompt (PR 40), at the five serving cells' widths and slots (their layers
 # cut to a few: a scan's temporaries do not grow with its length), compiled for the chip: the slots' rows still
 # go through the decode kernel, the pools still ride the carry, the experts' and the FFN's stacks are read where

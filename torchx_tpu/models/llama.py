@@ -37,7 +37,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from torchx_tpu.models import hyper, ssm
 from torchx_tpu.obs import hot
 from torchx_tpu.parallel import mesh as mesh_lib
-from torchx_tpu.ops.attention import attention
+from torchx_tpu.ops.attention import attention, note_traced
 from torchx_tpu.ops.norms import rms_norm
 from torchx_tpu.ops.quant import maybe_matmul
 from torchx_tpu.ops.ring_attention import ring_attention
@@ -1105,6 +1105,38 @@ def forward(
     return _constraint(logits, mesh, ("dp", "fsdp"), "sp", "tp")
 
 
+def _head_logits(
+    x: jnp.ndarray,  # [b, c, d] hidden states
+    head: jnp.ndarray,  # [d, v]
+    mesh: Optional[Mesh],
+    f32_logits: bool,
+) -> jnp.ndarray:
+    """-> the logits the loss is taken over, ``[b, c, v]``, stored bf16 unless
+    ``f32_logits`` (the MXU accumulates the matmul in f32 either way)."""
+    with jax.named_scope(hot.LM_HEAD):
+        logits = jnp.einsum(
+            "bcd,dv->bcv",
+            x,
+            head,
+            preferred_element_type=jnp.float32 if f32_logits else None,
+        )
+        # keep the vocab axis tp-sharded (same guard as forward(): never
+        # all-gather [b, *, vocab] logits on a tensor-parallel mesh)
+        return _constraint(logits, mesh, ("dp", "fsdp"), None, "tp")
+
+
+def _nll_of_logits(
+    logits: jnp.ndarray,  # [b, c, v]
+    targets: jnp.ndarray,  # [b, c]
+) -> tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
+    """-> (nll ``[b, c]``, the logits in float32, their logsumexp ``[b, c, 1]``),
+    all float32: what the loss sums, and what its gradient is made from."""
+    lf = logits.astype(jnp.float32)
+    lse = jax.nn.logsumexp(lf, axis=-1, keepdims=True)
+    tgt = jnp.take_along_axis(lf, targets[..., None], axis=-1)
+    return (lse - tgt)[..., 0], lf, lse
+
+
 def _token_nll(
     x: jnp.ndarray,  # [b, c, d] hidden states
     head: jnp.ndarray,  # [d, v]
@@ -1114,7 +1146,8 @@ def _token_nll(
 ) -> jnp.ndarray:
     """-> per-token negative log-likelihood [b, c] float32.
 
-    Two deliberate choices, both measured on v5e (docs/performance.md):
+    What the loss costs, each choice measured on v5e (docs/performance.md;
+    the last PERF.md section 6, PR 47):
 
     * ``logsumexp(logits) - logits[target]`` instead of
       ``log_softmax + take``: log_softmax materializes a SECOND
@@ -1127,22 +1160,92 @@ def _token_nll(
       the CE gradient (softmax - onehot) run in f32 from the bf16 tensor.
       Loss trajectories match f32 to 3 decimals at 1B scale; flip
       ``LlamaConfig.ce_f32_logits`` for exact-f32 CE.
+    * summed a chunk of the sequence at a time (:func:`_chunked_loss`), only
+      ``[b, chunk, vocab]`` logits ever exist, and under differentiation a
+      chunk's logits are made ONCE: its gradient is formed from them in the
+      pass that made them, three vocabulary matmuls a chunk (logits, ``dx``,
+      ``dW``) where a rematerialized scan ran the first one twice.
     """
-    with jax.named_scope(hot.LM_HEAD):
-        logits = jnp.einsum(
-            "bcd,dv->bcv",
-            x,
-            head,
-            preferred_element_type=jnp.float32 if f32_logits else None,
-        )
-        # keep the vocab axis tp-sharded (same guard as forward(): never
-        # all-gather [b, *, vocab] logits on a tensor-parallel mesh)
-        logits = _constraint(logits, mesh, ("dp", "fsdp"), None, "tp")
+    logits = _head_logits(x, head, mesh, f32_logits)
     with jax.named_scope(hot.LOSS):
-        lf = logits.astype(jnp.float32)
-        lse = jax.nn.logsumexp(lf, axis=-1)
-        tgt = jnp.take_along_axis(lf, targets[..., None], axis=-1)[..., 0]
-        return lse - tgt
+        return _nll_of_logits(logits, targets)[0]
+
+
+def _loss_denominator(ts: jnp.ndarray, ms: Optional[jnp.ndarray]):  # noqa: ANN202
+    """What the summed loss is divided by: the tokens, or the mask's sum (at least 1)."""
+    return float(ts.size) if ms is None else jnp.maximum(ms.sum(), 1.0)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5))
+def _chunked_loss(
+    xs: jnp.ndarray,  # [n, b, c, d] hidden states, a chunk of the sequence a row
+    head: jnp.ndarray,  # [d, v]
+    ts: jnp.ndarray,  # [n, b, c] targets
+    ms: Optional[jnp.ndarray],  # [n, b, c] float32 loss mask, None: every token counts
+    mesh: Optional[Mesh],
+    f32_logits: bool,
+) -> jnp.ndarray:
+    """-> the (masked) mean of :func:`_token_nll` over all chunks, one chunk's
+    ``[b, c, v]`` logits alive at a time.
+
+    Called plainly (an evaluation) this is a scan with one matmul a chunk.
+    Under differentiation JAX runs :func:`_chunked_loss_fwd` in its place,
+    which makes each chunk's gradient from the logits it has just made: the
+    gradient is linear in the one scalar the backward pass brings, so nothing
+    has to wait for it but a multiply. ``ops.attention.traced("loss")`` says
+    which ran: ``chunked`` or ``fused``."""
+    note_traced("loss", "chunked")
+
+    def body(acc, xt):  # noqa: ANN001
+        x_c, t_c, m_c = xt
+        nll = _token_nll(x_c, head, t_c, mesh, f32_logits)
+        return acc + (nll if m_c is None else nll * m_c).sum(), None
+
+    total, _ = jax.lax.scan(body, jnp.float32(0), (xs, ts, ms))
+    return total / _loss_denominator(ts, ms)
+
+
+def _chunked_loss_fwd(xs, head, ts, ms, mesh, f32_logits):  # noqa: ANN001, ANN202
+    """-> (loss, (d loss / d xs, d loss / d head)): one scan, each chunk's
+    logits made once. ``dW`` is summed over the chunks in the head's dtype, as
+    the transposed scan's carry was."""
+    note_traced("loss", "fused")
+    denominator = _loss_denominator(ts, ms)
+    scale = 1.0 / denominator
+
+    def body(carry, xt):  # noqa: ANN001
+        total, dw = carry
+        x_c, t_c, m_c = xt
+        logits = _head_logits(x_c, head, mesh, f32_logits)
+        with jax.named_scope(hot.LOSS):
+            nll, lf, lse = _nll_of_logits(logits, t_c)
+            w = scale if m_c is None else (m_c * scale)[..., None]
+            onehot = jax.nn.one_hot(t_c, lf.shape[-1], dtype=jnp.float32)
+            dlogits = ((jnp.exp(lf - lse) - onehot) * w).astype(logits.dtype)
+        # the two gradient matmuls are autodiff's own transposes of the logits'
+        # einsum (its operands' dtypes, its constraint). dW contracts x_c over
+        # its rows: it reads a copy written [b, d, c], as the transposed scan
+        # kept one; without it the chip's compiler transposes the operand inside
+        # the matmul, 13.6 ms a step where this takes 12.5 (PERF.md, PR 47)
+        x_t = jax.lax.optimization_barrier(x_c.swapaxes(1, 2))
+        (dx_c,) = jax.linear_transpose(lambda x: _head_logits(x, head, mesh, f32_logits), x_c)(dlogits)
+        (dw_c,) = jax.linear_transpose(lambda h: _head_logits(x_t.swapaxes(1, 2), h, mesh, f32_logits), head)(dlogits)
+        return (total + (nll if m_c is None else nll * m_c).sum(), dw + dw_c), dx_c
+
+    (total, dw), dxs = jax.lax.scan(body, (jnp.float32(0), jnp.zeros_like(head)), (xs, ts, ms))
+    return total / denominator, (dxs, dw)
+
+
+def _chunked_loss_bwd(mesh, f32_logits, residuals, g):  # noqa: ANN001, ANN202
+    """The incoming scalar times the two gradients the forward pass made
+    (multiplied in float32, so that ``g`` is not rounded to bf16); targets
+    and mask get none."""
+    dxs, dw = residuals
+    times_g = lambda r: (r.astype(jnp.float32) * g).astype(r.dtype)  # noqa: E731
+    return times_g(dxs), times_g(dw), None, None
+
+
+_chunked_loss.defvjp(_chunked_loss_fwd, _chunked_loss_bwd)
 
 
 def loss_fn(
@@ -1181,31 +1284,15 @@ def loss_and_aux(
     s = targets.shape[1]
     chunk = cfg.loss_chunk
     if chunk and s % chunk == 0 and s > chunk:
-        # scan over sequence chunks with remat: only [b, chunk, vocab]
-        # logits ever exist (fwd and bwd) instead of [b, s, vocab]
+        # a chunk of the sequence at a time: only [b, chunk, vocab] logits
+        # ever exist, and each chunk's are made once (see _chunked_loss)
         b = targets.shape[0]
         n = s // chunk
-        xs = x.reshape(b, n, chunk, -1).swapaxes(0, 1)  # [n, b, c, d]
-        ts = targets.reshape(b, n, chunk).swapaxes(0, 1)
+        chunks = lambda a: a.reshape(b, n, chunk, *a.shape[2:]).swapaxes(0, 1)  # noqa: E731
+        loss = _chunked_loss(chunks(x), head, chunks(targets), None if m is None else chunks(m), mesh, f32)
+        return loss + aux_term, aux
 
-        def body(acc, xt):  # noqa: ANN001
-            x_c, t_c = xt
-            return acc + _token_nll(x_c, head, t_c, mesh, f32).sum(), None
-
-        if m is None:
-            total, _ = jax.lax.scan(jax.checkpoint(body), jnp.float32(0), (xs, ts))
-            return total / (b * s) + aux_term, aux
-        ms = m.reshape(b, n, chunk).swapaxes(0, 1)
-
-        def body_masked(acc, xt):  # noqa: ANN001
-            x_c, t_c, m_c = xt
-            return acc + (_token_nll(x_c, head, t_c, mesh, f32) * m_c).sum(), None
-
-        total, _ = jax.lax.scan(
-            jax.checkpoint(body_masked), jnp.float32(0), (xs, ts, ms)
-        )
-        return total / jnp.maximum(m.sum(), 1.0) + aux_term, aux
-
+    note_traced("loss", "whole")
     nll = _token_nll(x, head, targets, mesh, f32)
     if m is not None:
         return (nll * m).sum() / jnp.maximum(m.sum(), 1.0) + aux_term, aux
