@@ -34,7 +34,8 @@ def dropping_the_state():  # noqa: ANN201
     def faulty(self, slot, st, n):  # noqa: ANN001, ANN202
         last = enqueued(self, slot, st, n)
         if last:
-            self.pools = {**self.pools, "ssm": zero_row(self.pools["ssm"], jnp.int32(slot + 1))}
+            pools = self.cache.pools
+            self.cache.pools = {**pools, "ssm": zero_row(pools["ssm"], jnp.int32(slot + 1))}
         return last
 
     return faulty

@@ -91,6 +91,7 @@ MODULES = [
     ("torchx_tpu.models.generate", "models_generate", "KV-cache generation"),
     ("torchx_tpu.serve.kv_pool", "serve_kv_pool", "paged KV cache: HBM-budget pool planning + block allocation"),
     ("torchx_tpu.serve.engine", "serve_engine", "continuous-batching decode engine over a fixed slot array"),
+    ("torchx_tpu.serve.slot_cache", "serve_slot_cache", "what a slot holds in the cache: one object a kind of cache behind the engine"),
     ("torchx_tpu.serve.prefix_cache", "serve_prefix_cache", "refcounted radix prefix cache over the paged KV pool"),
     ("torchx_tpu.serve.kv_transfer", "serve_kv_transfer", "prefill->decode KV-block transfer seam (local/http/file)"),
     ("torchx_tpu.serve.pool", "serve_pool", "autoscaling serve pool: cache-aware routing + disaggregated gangs"),

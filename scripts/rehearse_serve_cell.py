@@ -10,7 +10,8 @@ here as the engine writes them), the second at the engine's default ``chunk_widt
 each width given.
 
 ``benchmark/rehearse_compile.py::serve_cell`` builds K/V pools by hand and so cannot
-describe a latent pool; this asks ``generate.init_kv_pools`` for the pools' shapes: K/V pools,
+describe a latent pool; this makes the engine's own cache of the cell's kind under ``jax.eval_shape``
+(``serve/slot_cache.py``) and takes from it the pools' shapes and the ``tables`` the programs are handed: K/V pools,
 a pool a cache kind (``k-exaone-serve-decode-long``), or a latent pool a layer group at 64 slots
 (``kimi-vl-a3b-serve-backlog``) or 128 (``xing4-serve-decode-long``: 16,897 blocks x 8 layers x
 1,280 B, 2.58 GiB beside 10.55 GiB of weights; decode 13.14 GiB live), and beside the K/V pools a
@@ -53,7 +54,7 @@ def main() -> None:
     from torchx_tpu.models import llama
     from torchx_tpu.obs.hlo import loop_moves, program_moves
     from torchx_tpu.serve import engine as eng
-    from torchx_tpu.serve.kv_pool import EvaTables, window_ring
+    from torchx_tpu.serve.slot_cache import slot_cache
 
     cell = spec.load_cell(sys.argv[1])
     widths = [int(a) for a in sys.argv[2:]] or [inspect.signature(eng.ServeEngine).parameters["chunk_width"].default]
@@ -63,31 +64,23 @@ def main() -> None:
     config, mix, dep = cell.config, cell.traffic, cell.config["deployment"]
     cfg = models.program_config(config, max_seq=int(dep["max_seq"]))
     slots, bs = int(dep["max_slots"]), int(dep["block_size"])
-    per_slot = -(-cfg.max_seq // bs)
-    n_blocks = 1 + slots * max(1, per_slot // 2)
-    # a cache whose rows are not its tokens (EVA attention): the engine's own table width and default pool
-    eva = EvaTables(slots, cfg.max_seq, cfg.eva_window, cfg.eva_chunk, bs) if cfg.eva_window else None
-    if eva:
-        per_slot = eva.blocks_per_slot
-        n_blocks = 1 + slots * (eva.pooled_blocks * eva.windows + per_slot // 2)
     sds = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=one)  # noqa: E731
     is_leaf = lambda x: isinstance(x, tuple) and isinstance(x[0], tuple)  # noqa: E731
     params = shapes_of(config, jnp.bfloat16, jax.tree.map(lambda _: one, models.weight_shapes(config), is_leaf=is_leaf))
-    # a stack with sliding layers: the engine's default window pool, and a ring table beside the full one
-    window = cfg.sliding_window if cfg.layers_of("window") else 0
-    ring = window_ring(window, bs) if window else 0
-    n_window = 1 + slots * ring + int(dep["max_prefill_batch"]) * per_slot if window else None
-    pools = jax.tree.map(lambda p: sds(p.shape, p.dtype),
-                         jax.eval_shape(lambda: gen.init_kv_pools(cfg, n_blocks, bs, n_window, slots)))  # fmt: skip
-    i32, f32 = jnp.int32, jnp.float32
+    # the engine's own cache of the cell's kind at its default pools, made abstractly: its geometry, the pools' shapes,
+    # and the pytrees it hands the programs as ``tables`` (numpy on the host; only their shapes are used here)
+    made = {}
 
-    def tables(rows: int, window_width: int):  # noqa: ANN202
-        full = sds((rows, per_slot), i32)
-        if cfg.ssm_heads:  # a mixer's state rows ride beside the one table
-            return {"full": full, "state": sds((rows,), i32)}
-        if eva:  # the rows' staging blocks, where a step pools the chunks it fills
-            return {"full": full, "stage": sds((rows, eva.pooled_blocks), i32)}
-        return {"full": full, "window": sds((rows, window_width), i32)} if window else full
+    def cache_pools():  # noqa: ANN202
+        made["cache"] = slot_cache(cfg, max_slots=slots, block_size=bs, num_blocks=None, num_window_blocks=None,
+                                   max_prefill_batch=int(dep["max_prefill_batch"]), prefix_cache=True, prefix_cache_reserve=0.0)  # fmt: skip
+        return made["cache"].pools
+
+    pools = jax.tree.map(lambda p: sds(p.shape, p.dtype), jax.eval_shape(cache_pools))
+    cache = made["cache"]
+    n_blocks, n_window, ring = cache.num_blocks, cache.num_window_blocks, getattr(cache, "window_ring", 0)
+    as_shapes = lambda tables: jax.tree.map(lambda t: sds(t.shape, t.dtype), tables)  # noqa: E731
+    i32, f32 = jnp.int32, jnp.float32
 
     # a layer's smallest pool; a mixer's convolution tails (a few MB a layer) are no pool's size
     layer_bytes = min(p.size // p.shape[0] * p.dtype.itemsize
@@ -116,15 +109,15 @@ def main() -> None:
                                                      chunk_tables, pools, cfg, keys, temps)
         return jnp.where(jnp.arange(slots) == at[2], sampled[-1], sampled[:-1]), pools
 
-    geometry = f"{slots} slots, {n_blocks} blocks" + (f", {n_window} window blocks in rings of {ring}" if window else "")
-    slot_args = (sds((slots,), i32), sds((slots,), i32), sds((slots,), i32), tables(slots, ring), pools)
+    geometry = f"{slots} slots, {n_blocks} blocks" + (f", {n_window} window blocks in rings of {ring}" if n_window else "")
+    slot_args = (sds((slots,), i32), sds((slots,), i32), sds((slots,), i32), as_shapes(cache.step_tables([], [])), pools)
     c = jax.jit(decode, donate_argnums=(5,)).lower(params, *slot_args, sds((slots,), i32), sds((slots,), f32)).compile()
     report(f"{cell.name}: decode step ({geometry})", c)
     print("  kernels:", sorted({n for n in KERNELS if n in c.as_text()}))
     for width in widths:
         c = jax.jit(decode_chunk, donate_argnums=(5,)).lower(
             params, *slot_args, sds((slots + 1,), i32), sds((slots + 1,), f32),
-            sds((width,), i32), sds((3,), i32), tables(1, per_slot)).compile()
+            sds((width,), i32), sds((3,), i32), as_shapes(cache.chunk_tables(0))).compile()
         report(f"{cell.name}: decode step carrying a chunk of {width} ({geometry})", c)
         print("  kernels:", sorted({n for n in KERNELS if n in c.as_text()}))
 

@@ -43,6 +43,26 @@ def _isolated_registries(tmp_path, monkeypatch):
     )
 
 
+@pytest.fixture
+def one_local_gang():
+    """One gang at a time on the launcher's coordinator port. The local scheduler gives every
+    app's ``jax.distributed`` coordinator the one port ``settings.TPX_COORDINATOR_PORT``, and two
+    coordinators listen on it side by side: a process of one gang then reaches the other's
+    ("task 1 unexpectedly tried to connect with a different incarnation"), which failed
+    ``test_docs.py::test_quickstart_local_path_executes`` beside ``test_e2e_spmd.py`` on another
+    worker (two gangs at once by hand: two of six failed). Tests that start a gang of two or
+    more processes take this lock, which holds across the workers' processes."""
+    import fcntl
+    import tempfile
+
+    from torchx_tpu import settings
+
+    path = os.path.join(tempfile.gettempdir(), f"tpx-test-coordinator-{settings.TPX_COORDINATOR_PORT}.lock")
+    with open(path, "w") as lock:
+        fcntl.flock(lock, fcntl.LOCK_EX)
+        yield
+
+
 def _early_stop_case(generate, max_new, prompt_len=3):
     """A prompt on which "stop at this id" can be told from both "stop at
     the first token" and "never stop": ``(prompt, full, cut)``, where

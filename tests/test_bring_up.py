@@ -236,7 +236,7 @@ class TestDeadEngine:
             with pytest.raises(RuntimeError, match="ran out of hbm"):
                 engine.generate([1, 2, 3], max_new_tokens=4, timeout=30)
             assert "ran out of hbm" in engine.failed
-            assert engine.alloc.used_blocks == 0  # the slot's blocks went back with it
+            assert engine.cache.alloc.used_blocks == 0  # the slot's blocks went back with it
         finally:
             engine.stop()
 

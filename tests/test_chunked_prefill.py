@@ -308,22 +308,22 @@ def _mid_prompt(built, kind, **kw):
     with one chunk fed and one more in flight."""
     cfg, params, bs = built(kind)
     engine = ServeEngine(params, cfg, max_slots=2, block_size=bs, chunk_width=C, enable_prefix_cache=False, **kw)
-    start = (engine.alloc.free_blocks, engine.window_alloc.free_blocks if engine.window else 0)
+    start = (engine.cache.alloc.free_blocks, engine.cache.window_alloc.free_blocks if engine.cache.num_window_blocks else 0)
     req = engine.submit(ServeRequest(prompt=_prompt(3, 2 * C + 3, cfg.vocab_size), max_new_tokens=4))
     assert engine._admit() and engine._decode_once() and engine._decode_once()
-    (st,) = [s for s in engine._slots if s is not None]
+    ((slot, st),) = [(i, s) for i, s in enumerate(engine._slots) if s is not None]
     assert st.feeding is not None and st.cache_len == 2 * C and not req.t_first
-    assert engine.alloc.used_blocks == -(-len(req.prompt) // bs)
-    if engine.window:
+    assert engine.cache.alloc.used_blocks == -(-len(req.prompt) // bs)
+    if engine.cache.num_window_blocks:
         # staged for the whole prompt at admission; those below the next chunk's window went back already
-        below = max(0, 2 * C - engine.window + 1) // bs
-        assert engine.window_alloc.used_blocks == len(st.staged) == -(-len(req.prompt) // bs) - below
-        assert engine.window_blocks_released == below and engine.window_tables.held_blocks == 0
+        below = max(0, 2 * C - engine.cache.window + 1) // bs
+        assert engine.cache.window_alloc.used_blocks == len(engine.cache._staged[slot]) == -(-len(req.prompt) // bs) - below
+        assert engine.cache.window_blocks_released == below and engine.cache.window_tables.held_blocks == 0
     return engine, req, start
 
 
 def _free(engine):
-    return (engine.alloc.free_blocks, engine.window_alloc.free_blocks if engine.window else 0)
+    return (engine.cache.alloc.free_blocks, engine.cache.window_alloc.free_blocks if engine.cache.num_window_blocks else 0)
 
 
 @pytest.mark.parametrize("how", ["preempted", "drained", "stopped", "failed"])

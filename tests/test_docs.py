@@ -41,7 +41,7 @@ def test_quickstart_has_verified_blocks():
 
 
 @pytest.mark.integ
-def test_quickstart_local_path_executes(tmp_path):
+def test_quickstart_local_path_executes(tmp_path, one_local_gang):
     """Run the quickstart's CI-verified journey end to end in a scratch
     dir: write train.py exactly as documented, then execute every
     documented command and require success (the spmd run must actually

@@ -20,7 +20,7 @@ EXAMPLE = os.path.join(
 
 
 @pytest.mark.e2e
-def test_spmd_mesh_formation(tmp_path):
+def test_spmd_mesh_formation(tmp_path, one_local_gang):
     with get_runner("spmd-e2e") as runner:
         handle = runner.run_component(
             "dist.spmd",
@@ -105,7 +105,7 @@ def test_spmd_failure_surfaces_structured_error(tmp_path):
 
 
 @pytest.mark.e2e
-def test_spmd_retry_restarts_failed_gang(tmp_path):
+def test_spmd_retry_restarts_failed_gang(tmp_path, one_local_gang):
     """Fault-injected replica death + max_retries: the gang restarts and
     the SECOND attempt forms the full mesh (BASELINE: retry policies
     actually restart a failed gang, proven end-to-end)."""
@@ -137,7 +137,7 @@ def test_spmd_retry_restarts_failed_gang(tmp_path):
 
 
 @pytest.mark.e2e
-def test_resize_resumes_training_from_checkpoint(tmp_path):
+def test_resize_resumes_training_from_checkpoint(tmp_path, one_local_gang):
     """BASELINE config 4, operator-driven: `resize` a live 2-process SPMD
     training gang down to 1; the restarted world re-forms jax.distributed,
     resumes from the checkpoint, and finishes."""

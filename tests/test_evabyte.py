@@ -33,6 +33,7 @@ from torchx_tpu.ops.paged_attention import TRASH_BLOCK
 from torchx_tpu.ops.rope import apply_rope
 from torchx_tpu.serve.engine import ServeEngine, ServeRequest
 from torchx_tpu.serve.kv_pool import BlockAllocator, EvaTables
+from torchx_tpu.serve.slot_cache import PagedCache
 
 attn_ops = importlib.import_module("torchx_tpu.ops.attention")
 LOGITS = dict(atol=2e-4, rtol=2e-4)
@@ -256,8 +257,8 @@ def _requests():
 def _serve(params, cfg, reqs, **kw):
     engine = ServeEngine(params, cfg, max_slots=3, block_size=BS, max_prefill_batch=2, chunk_width=CHUNK, **kw)
     log, turns = _spy(engine), []
-    turn = engine.eva.turn
-    engine.eva.turn = lambda slot: turns.append(slot) or turn(slot)
+    turn = engine.cache.tables.turn
+    engine.cache.tables.turn = lambda slot: turns.append(slot) or turn(slot)
     for r in reqs:
         engine.submit(r)
     engine.start()
@@ -308,14 +309,14 @@ def test_engine_serves_the_references_tokens(served, model):
 
 def test_the_pool_is_empty_at_the_end_and_the_counts_are_as_reckoned(served):
     engine, log, turns, stats, reqs, _ = served
-    assert engine.alloc.used_blocks == 0 and engine.eva.held_blocks == 0 and (engine.eva.tables == TRASH_BLOCK).all()
+    assert engine.cache.alloc.used_blocks == 0 and engine.cache.tables.held_blocks == 0 and (engine.cache.tables.tables == TRASH_BLOCK).all()
     assert stats["kv_blocks_full"] == stats["kv_blocks_window"] == stats["kv_blocks_pooled"] == stats["cache_rows_held"] == 0
     assert stats["window_blocks_released"] == 8 * len(turns) and stats["pooled_blocks_promoted"] == 2 * len(turns)
     # a request turns once for every boundary its writes crossed: the prompt and all but its last token are written;
     # the step in flight behind request 4's EOS crossed one more; a preempted request crosses its boundaries again
     crossed = sum((len(r.prompt) + len(r.generated) - 2) // W for r in reqs) + 1
     assert len(turns) >= crossed and (stats["preemptions"] > 0 or len(turns) == crossed)
-    assert "not its tokens" in stats["prefix_cache_off"] and engine.prefix_cache is None
+    assert "not its tokens" in stats["prefix_cache_off"] and engine.cache.prefix_cache is None
     assert stats["kv_bytes_per_token"] == 2 * 2 * 4 * 16 * 4 // C  # two layers' K and V of 4 heads of 16 in float32, a sixteenth... a fourth here
 
 
@@ -338,11 +339,11 @@ def test_the_counts_of_one_request_alone(model):
     reqs = [ServeRequest(_tokens(40, (41,)).tolist(), max_new_tokens=100)]
     engine, log, turns, stats = _serve(params, cfg, reqs)
     assert len(turns) == 4 and stats["window_blocks_released"] == 32 and stats["pooled_blocks_promoted"] == 8
-    assert stats["preemptions"] == 0 and engine.alloc.used_blocks == 0
-    assert engine.num_blocks == 1 + 3 * (2 * 5 + 16 // 2)  # every pooled block a slot can hold and half a table
+    assert stats["preemptions"] == 0 and engine.cache.alloc.used_blocks == 0
+    assert engine.cache.num_blocks == 1 + 3 * (2 * 5 + 16 // 2)  # every pooled block a slot can hold and half a table
     assert _served_gaps(params, reqs[0]).max() < SERVED
     # the rows the decode kernel reads and the rows held, as the spans carry them
-    assert engine.eva.rows(140) == 4 * 8 + 12 + 3 and engine.eva.coord(139) + 1 == 4 * 8 + 12
+    assert engine.cache.tables.rows(140) == 4 * 8 + 12 + 3 and engine.cache.tables.coord(139) + 1 == 4 * 8 + 12
 
 
 @pytest.mark.parametrize("fault", ["the chunk a prompt leaves open is never pooled", "the table is laid anew one step late"])
@@ -361,13 +362,13 @@ def test_a_planted_engine_fault_is_caught(model, fault, monkeypatch):
 
         monkeypatch.setattr(gen._Rows, "pool", pool)
     else:
-        real = ServeEngine._ensure_rows
+        real = ServeEngine._make_writable
 
         def late(self, slot, write_pos):
             st = self._slots[slot]
             return real(self, slot, write_pos - 1 if st.feeding is None and write_pos % W == 0 else write_pos)
 
-        monkeypatch.setattr(ServeEngine, "_ensure_rows", late)
+        monkeypatch.setattr(ServeEngine, "_make_writable", late)
     _serve(params, cfg, reqs)
     assert _served_gaps(params, reqs[0]).max() > 10 * SERVED
 
@@ -489,5 +490,5 @@ def test_the_default_pool_is_the_older_kinds_own():
     """The engine's geometry for a model whose rows are its tokens is what it was."""
     cfg = llama.llama_tiny(max_seq=64)
     engine = ServeEngine(llama.init_params(cfg, jax.random.PRNGKey(0)), cfg, max_slots=2)
-    assert engine.eva is None and engine.blocks_per_slot == 4 and engine.num_blocks == 1 + 2 * 2
+    assert type(engine.cache) is PagedCache and engine.cache.blocks_per_slot == 4 and engine.cache.num_blocks == 1 + 2 * 2
     assert "cache_rows_held" not in engine.stats() and math.isclose(engine.stats()["occupancy"], 0.0)

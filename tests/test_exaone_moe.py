@@ -412,7 +412,7 @@ def test_engine_serves_the_references_tokens_over_two_pools(model):
     try:
         stats = engine.stats()
         assert stats["kv_bytes_per_token"] == 2 * 2 * 2 * 32 * 4  # the two full layers' K and V
-        assert stats["kv_bytes_per_slot_window"] == 5 * BS * 6 * 2 * 2 * 32 * 4 and engine.window_ring == 5
+        assert stats["kv_bytes_per_slot_window"] == 5 * BS * 6 * 2 * 2 * 32 * 4 and engine.cache.window_ring == 5
         first = engine.generate(prompts[0], 6, timeout=300)  # primes the prefix cache
         reqs = [engine.submit(ServeRequest(p, max_new_tokens=60)) for p in prompts[1:]]
         assert all(r.wait(900) and not r.error for r in reqs)
@@ -430,7 +430,7 @@ def test_engine_serves_the_references_tokens_over_two_pools(model):
         assert again.generated == seq[len(prompts[1]) + 40 :][:5]
     finally:
         engine.stop()
-    assert engine.window_alloc.used_blocks <= engine.prefix_cache.cached_blocks  # the slots gave all theirs back
+    assert engine.cache.window_alloc.used_blocks <= engine.cache.prefix_cache.cached_blocks  # the slots gave all theirs back
     for req in [first, *reqs, again]:
         assert _served_gaps(params, req).max() < 1e-4
 
@@ -449,7 +449,7 @@ def test_a_hand_off_carries_both_kinds_of_blocks(model):
         whole = sender.generate(prompt, 30, timeout=300)
         pre = sender.submit(ServeRequest(prompt, max_new_tokens=30, prefill_only=True))
         assert pre.wait(300) and pre.handoff is not None
-        assert sender.tables.held_blocks == 0 and sender.window_tables.held_blocks == 0
+        assert sender.cache.tables.held_blocks == 0 and sender.cache.window_tables.held_blocks == 0
     finally:
         sender.stop()
     payload = KvPayload.from_bytes(pre.handoff.to_bytes())
@@ -457,7 +457,7 @@ def test_a_hand_off_carries_both_kinds_of_blocks(model):
     receiver = ServeEngine(params, cfg, max_slots=2, block_size=BS, enable_prefix_cache=False).start()
     try:
         reply = serve_kv_payload(receiver, payload, timeout=300)
-        assert receiver.window_alloc.used_blocks == 0 and receiver.alloc.used_blocks == 0
+        assert receiver.cache.window_alloc.used_blocks == 0 and receiver.cache.alloc.used_blocks == 0
     finally:
         receiver.stop()
     assert reply["tokens"] == whole.generated
@@ -471,7 +471,7 @@ def test_spans_and_scopes_name_the_kinds(model):
     for name in ("ATTN_WINDOW", "ATTN_FULL", "QK_NORM"):
         assert getattr(hot, name) in hot.DEVICE_SCOPES
     engine = ServeEngine(params, cfg, max_slots=2, block_size=BS)
-    tables = {"full": jnp.zeros((2, engine.blocks_per_slot), jnp.int32), "window": jnp.zeros((2, 5), jnp.int32)}
+    tables = {"full": jnp.zeros((2, engine.cache.blocks_per_slot), jnp.int32), "window": jnp.zeros((2, 5), jnp.int32)}
 
     def decode(params, pools):
         z = jnp.zeros((2,), jnp.int32)
@@ -484,9 +484,9 @@ def test_spans_and_scopes_name_the_kinds(model):
     for path in ("attn/attn_window/paged_attention/", "attn/attn_full/paged_attention/", "attn/attn_window/qk_norm/",
                  "attn/attn_full/qk_norm/", "attn/attn_window/append_kv/", "moe_experts/", "moe_shared/"):  # fmt: skip
         assert any(loc.startswith(path) or f"/{path}" in loc for loc in locs), path
-    assert set(engine._kv_blocks()) == {"kv_blocks_full", "kv_blocks_window", "window_blocks_released"}
+    assert set(engine.cache.span_attrs(())) == {"kv_blocks_full", "kv_blocks_window", "window_blocks_released"}
     assert jax.tree.map(lambda p: p.shape, engine.pools) == {
-        "full": {"k": (2, engine.num_blocks, BS, 2, 32), "v": (2, engine.num_blocks, BS, 2, 32)},
-        "window": {"k": (6, engine.num_window_blocks, BS, 2, 32), "v": (6, engine.num_window_blocks, BS, 2, 32)},
+        "full": {"k": (2, engine.cache.num_blocks, BS, 2, 32), "v": (2, engine.cache.num_blocks, BS, 2, 32)},
+        "window": {"k": (6, engine.cache.num_window_blocks, BS, 2, 32), "v": (6, engine.cache.num_window_blocks, BS, 2, 32)},
     }
-    assert engine.num_window_blocks == 1 + 2 * 5 + 4 * engine.blocks_per_slot
+    assert engine.cache.num_window_blocks == 1 + 2 * 5 + 4 * engine.cache.blocks_per_slot

@@ -305,7 +305,7 @@ def test_state_is_counted_and_not_cached_or_handed_off(served, model):
     per_slot = cfg.n_layers * (4 * 8 * 16 * 4 + 3 * 96 * 4)  # S [4, 8, 16] float32 + three inputs of 32 + 2 x 2 x 16 a layer
     assert stats["state_bytes_per_slot"] == per_slot and stats["state_bytes"] == 4 * per_slot  # three slots + the trash row
     assert stats["kv_bytes_per_token"] == cfg.n_layers * 2 * 2 * 16 * 4  # attention's alone
-    assert engine.prefix_cache is None and "recurrent state" in stats["prefix_cache_off"] and "prefix_cache" not in stats
+    assert engine.cache.prefix_cache is None and "recurrent state" in stats["prefix_cache_off"] and "prefix_cache" not in stats
     with pytest.raises(NotImplementedError, match="recurrent state"):
         engine.submit(ServeRequest([1, 2, 3], max_new_tokens=1, prefill_only=True))
     with pytest.raises(NotImplementedError, match="recurrent state"):
@@ -314,7 +314,7 @@ def test_state_is_counted_and_not_cached_or_handed_off(served, model):
         gen.generate(params, jnp.zeros((1, 4), jnp.int32), cfg, 2)
     # an engine of a model without a mixer says nothing of either
     plain = ServeEngine(llama.init_params(llama.llama_tiny(), jax.random.PRNGKey(0)), llama.llama_tiny(), max_slots=2)
-    assert plain.prefix_cache is not None and plain.stats()["state_bytes"] == 0 and "prefix_cache_off" not in plain.stats()
+    assert plain.cache.prefix_cache is not None and plain.stats()["state_bytes"] == 0 and "prefix_cache_off" not in plain.stats()
 
 
 def test_the_contiguous_cache_takes_the_multipliers_too():

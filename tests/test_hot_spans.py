@@ -83,13 +83,13 @@ def _force_copy_on_write(engine):
     """On an idle engine, from the calling thread: slot 0 gets a tail block that
     another holder shares, and is made to write into it (no request of a
     running engine comes to that: the cache adopts whole blocks only)."""
-    blocks = engine.alloc.alloc(2)
-    engine.tables.assign(0, blocks)
-    engine.alloc.retain([blocks[1]])
-    assert engine._ensure_capacity(0, engine.block_size)
-    assert engine.tables.blocks_of(0)[1] != blocks[1]
-    engine.alloc.release([blocks[1]])
-    engine.alloc.free(engine.tables.release(0))
+    blocks = engine.cache.alloc.alloc(2)
+    engine.cache.tables.assign(0, blocks)
+    engine.cache.alloc.retain([blocks[1]])
+    assert engine._make_writable(0, engine.block_size)
+    assert engine.cache.tables.blocks_of(0)[1] != blocks[1]
+    engine.cache.alloc.release([blocks[1]])
+    engine.cache.alloc.release(engine.cache.tables.release(0))
 
 
 @pytest.fixture(scope="module")

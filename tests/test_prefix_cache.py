@@ -51,9 +51,9 @@ class TestAllocatorRefcount:
     def test_double_free_raises(self):
         a = BlockAllocator(8)
         (b,) = a.alloc(1)
-        a.free([b])
+        a.release([b])
         with pytest.raises(ValueError, match="double-free"):
-            a.free([b])
+            a.release([b])
 
     def test_batch_double_free_validated_before_any_count_moves(self):
         a = BlockAllocator(8)
@@ -76,7 +76,7 @@ class TestAllocatorRefcount:
     def test_retain_free_block_raises(self):
         a = BlockAllocator(8)
         (b,) = a.alloc(1)
-        a.free([b])
+        a.release([b])
         with pytest.raises(ValueError, match="retaining free"):
             a.retain([b])
 

@@ -59,9 +59,9 @@ class TestPrefixCacheParity:
             prompt = list(range(1, 20))  # spans 2 full blocks at bs=8
             cold = eng.generate(prompt, 6, timeout=120).tokens
             assert cold == dense_generate(params, cfg, prompt, 6)
-            hits0 = eng.prefix_cache.stats()["hits"]
+            hits0 = eng.cache.prefix_cache.stats()["hits"]
             warm = eng.generate(prompt, 6, timeout=120).tokens
-            assert eng.prefix_cache.stats()["hits"] > hits0
+            assert eng.cache.prefix_cache.stats()["hits"] > hits0
             # the cache-hit suffix prefill reproduced the cold output
             # exactly — same tokens, not merely similar
             assert warm == cold
@@ -91,7 +91,7 @@ class TestPrefixCacheParity:
             longer = base + [40, 41, 42]
             got = eng.generate(longer, 4, timeout=120).tokens
             assert got == dense_generate(params, cfg, longer, 4)
-            assert eng.prefix_cache.stats()["hit_tokens"] >= 16
+            assert eng.cache.prefix_cache.stats()["hit_tokens"] >= 16
         finally:
             eng.stop()
 
@@ -101,30 +101,30 @@ class TestPrefixCacheParity:
 
 class TestCopyOnWrite:
     def test_shared_tail_is_copied_before_write(self, tiny):
-        # drive _ensure_capacity directly: a slot whose tail block another
+        # drive _make_writable directly: a slot whose tail block another
         # holder references must get a private copy, never write in place
         eng = make_engine(tiny)
         try:
-            blocks = eng.alloc.alloc(2)
-            eng.tables.assign(0, blocks)
-            eng.alloc.retain([blocks[1]])  # e.g. the prefix cache
-            assert eng._ensure_capacity(0, 8)  # write pos in block index 1
-            tail = eng.tables.blocks_of(0)[1]
+            blocks = eng.cache.alloc.alloc(2)
+            eng.cache.tables.assign(0, blocks)
+            eng.cache.alloc.retain([blocks[1]])  # e.g. the prefix cache
+            assert eng._make_writable(0, 8)  # write pos in block index 1
+            tail = eng.cache.tables.blocks_of(0)[1]
             assert tail != blocks[1]
-            assert not eng.alloc.is_shared(tail)
+            assert not eng.cache.alloc.is_shared(tail)
             # the other holder keeps its (now sole) reference
-            assert eng.alloc.refcount(blocks[1]) == 1
-            assert eng.tables.blocks_of(0)[0] == blocks[0]  # untouched
+            assert eng.cache.alloc.refcount(blocks[1]) == 1
+            assert eng.cache.tables.blocks_of(0)[0] == blocks[0]  # untouched
         finally:
             eng.stop()
 
     def test_unshared_tail_is_left_in_place(self, tiny):
         eng = make_engine(tiny)
         try:
-            blocks = eng.alloc.alloc(2)
-            eng.tables.assign(0, blocks)
-            assert eng._ensure_capacity(0, 8)
-            assert eng.tables.blocks_of(0) == blocks
+            blocks = eng.cache.alloc.alloc(2)
+            eng.cache.tables.assign(0, blocks)
+            assert eng._make_writable(0, 8)
+            assert eng.cache.tables.blocks_of(0) == blocks
         finally:
             eng.stop()
 
@@ -157,7 +157,7 @@ class TestPrefillOnly:
                 cfg.head_dim,
             )
             # the exported blocks were released back to the pool
-            assert eng.alloc.used_blocks == eng.prefix_cache.cached_blocks
+            assert eng.cache.alloc.used_blocks == eng.cache.prefix_cache.cached_blocks
         finally:
             eng.stop()
 

@@ -805,42 +805,6 @@ def paged_decode_chunk_step(
     return _head_rows(params, jnp.concatenate((x[:slots], last)), cfg, keys, temps), pools
 
 
-def paged_prefill(
-    params: llama.Params,
-    prompts: jnp.ndarray,  # [b, t] int32, right-padded to the bucket width
-    true_lens: jnp.ndarray,  # [b] int32 — real prompt lengths
-    block_ids: jnp.ndarray,  # [b, t // block_size] physical blocks per row
-    pools: KVPools,
-    cfg: llama.LlamaConfig,
-    keys: jnp.ndarray,  # [b, 2] per-row PRNG keys for the first token
-    temps: jnp.ndarray,  # [b] f32
-) -> tuple[jnp.ndarray, KVPools]:
-    """Prefill a bucket of prompts straight into the paged pools.
-
-    Runs the dense stacked-layer prefill over the right-padded bucket
-    (causal masking keeps every position < ``true_lens[i]`` exact despite
-    the padding), scatters the bucket's K/V into each row's assigned
-    blocks, and samples the first output token from the logits at
-    ``true_lens[i] - 1``. ``t`` must be a multiple of the pool block size;
-    rows that need fewer blocks pad ``block_ids`` with the trash block.
-    -> (first token [b], updated pools).
-    """
-    b, t = prompts.shape
-    cache = init_kv_cache(cfg, b, t)
-    logits, cache = forward_with_cache(params, prompts, cache, jnp.int32(0), cfg)
-    bs = pools["k"].shape[2]
-    nb = t // bs
-    kvh, hd = cfg.n_kv_heads, cfg.head_dim
-    k = cache["k"].reshape(cfg.n_layers, b, nb, bs, kvh, hd)
-    v = cache["v"].reshape(cfg.n_layers, b, nb, bs, kvh, hd)
-    pools = {
-        "k": pools["k"].at[:, block_ids].set(k, mode="drop"),
-        "v": pools["v"].at[:, block_ids].set(v, mode="drop"),
-    }
-    last = logits[jnp.arange(b), true_lens - 1]  # [b, vocab]
-    return _sample_rows(last, keys, temps), pools
-
-
 def paged_prefill_chunk(
     params: llama.Params,
     tokens: jnp.ndarray,  # [b, t] int32 suffix tokens, right-padded
@@ -861,7 +825,9 @@ def paged_prefill_chunk(
     prefix + chunk through the same block tables. With ``prefix_lens = 0``
     it is a cold paged prefill, so cached and cold requests run the exact
     same program — reused prefix blocks hold bit-identical K/V to what
-    the cold path would recompute, keeping decode parity exact.
+    the cold path would recompute, keeping decode parity exact. The engine
+    feeds prompts through :func:`paged_decode_chunk_step`; this program has
+    no caller there and stays as that step's reference in the tests.
 
     ``t`` is the suffix bucket width; samples the first output token from
     the logits at each row's last real suffix position.

@@ -7,7 +7,11 @@ Three layers, bottom-up:
   :mod:`torchx_tpu.ops.paged_attention`);
 * :mod:`torchx_tpu.serve.engine` — the continuous-batching decode engine:
   a fixed slot array XLA compiles once, per-step admission and eviction,
-  bucketed prefill interleaved with decode;
+  prompts fed in chunks that ride the decode steps;
+* :mod:`torchx_tpu.serve.slot_cache` — what a slot holds in the cache, one
+  object a kind of cache (one paged pool; beside a ring; beside a mixer's
+  state; a window's rows and the pooled rows behind it), picked once from
+  the configuration: the engine's loop knows no kind by name;
 * :mod:`torchx_tpu.serve.prefix_cache` — refcounted radix prefix cache
   over the pool: shared prompt prefixes resolve to shared physical
   blocks instead of recomputing (LRU-evicted under pool pressure);

@@ -42,53 +42,16 @@ This engine keeps a **fixed slot array** decoding continuously:
   finished tokens kept; decode resumes exactly — sampling keys are a pure
   function of (seed, position));
 * **prefix reuse**: admission consults the refcounted radix
-  :class:`~torchx_tpu.serve.prefix_cache.PrefixCache` and prefills only
-  the *uncached suffix* of each prompt (its chunks start at the cached
-  length); newly computed full blocks are inserted back as soon as the chunk
-  that fills them is enqueued (device order makes them valid for every later
-  program) and on completion.
-  Cached blocks are shared by refcount — a shared tail block about to be
-  written is copy-on-write copied first, and under pool pressure the
-  engine evicts cache-only blocks before preempting live slots;
-* **two kinds of cache in one manager**: a model that mixes sliding and full
-  attention layers (``cfg.layer_types``) keeps a pool a kind. The full layers'
-  blocks are paged by a slot's table as above; in the sliding layers' pools a
-  slot holds a ring of :func:`~torchx_tpu.serve.kv_pool.window_ring` blocks,
-  and the oldest goes back to that pool's allocator as soon as every
-  position in it is below every future query's window (while decoding, and
-  between two chunks of a prompt, whose blocks are staged block ``b`` at entry
-  ``b`` until its last chunk hands those still in reach to the ring).
-  Preemption frees both; a prefix-cache node holds a block of each;
-* **recurrent state beside the paged K/V**: where the layers have a state-space
-  mixer (``cfg.ssm_heads``), slot ``i`` owns row ``i + 1`` of the mixer's store
-  (:func:`torchx_tpu.models.ssm.init_store`; row 0 is the trash row, as block 0
-  is the trash block), addressed by slot and not by position. **A row has one
-  writer a step**: a step's decode part addresses the trash row for every slot
-  that is not decoding (empty, or mid-prompt), so a slot whose prompt is being
-  fed is written by its chunk alone. A chunk that starts at position 0 starts
-  from zeros inside the program, which is the whole of a reset: for a new
-  tenant, and for a preempted request, which is fed again from position 0. The
-  step in flight behind an EOS moves a row on that nobody reads again. State is
-  not yet cached or handed off: such an engine has no prefix cache (a hit would
-  bring K/V without the state that goes with it; ``stats()`` says so) and
-  refuses ``prefill_only`` requests and :meth:`ServeEngine.submit_prefilled`;
-* **a cache whose rows are not its tokens**: under EVA attention
-  (``cfg.eva_window``, :mod:`torchx_tpu.models.eva`) a slot holds the blocks of
-  its current window, the pooled rows of every window behind it (one row for
-  every ``cfg.eva_chunk`` positions) and staging blocks in which the current
-  window's pooled rows are being written, all in the one pool and, but for the
-  staging, under the one table the programs read
-  (:class:`~torchx_tpu.serve.kv_pool.EvaTables`). The programs take a slot's
-  position (roped, and what the sampling key is folded from) and work out its
-  cache coordinate themselves. When a write starts a new window the host moves
-  the staging blocks into the table, gives the ended window's blocks back all at
-  once and allocates anew, with the step that wrote the window's last row still
-  in flight (device order keeps its blocks its own until it has run). A chunk of
-  a prompt stops where a window ends, and a prompt is given the blocks of its
-  first window at admission and the rest as its chunks reach them. Admission,
-  pressure and the spans reckon in the rows held. Such an engine has no prefix
-  cache and refuses hand-offs, as with recurrent state: neither indexes a cache
-  by anything but tokens yet;
+  :class:`~torchx_tpu.serve.prefix_cache.PrefixCache` and feeds only the
+  *uncached suffix* of each prompt (its chunks start at the cached length);
+* **what a slot holds is one object** (:mod:`torchx_tpu.serve.slot_cache`,
+  picked once from the configuration): one paged pool; a full pool beside a
+  ring where sliding and full attention layers mix; a paged pool beside a
+  mixer's recurrent state a slot; a window's rows and the pooled rows behind
+  it under one table (EVA attention). The loop asks it for a request's blocks,
+  a slot made writable at a position, the tables a step takes and what the
+  spans report, and knows no kind by name; a kind that is not cached or handed
+  off yet says so (``stats()["prefix_cache_off"]``, a refusal);
 * **disaggregation seams**: a request marked ``prefill_only`` completes
   with its first token, its KV blocks exported as a
   :class:`~torchx_tpu.serve.kv_transfer.KvPayload` (the prefill-replica
@@ -125,10 +88,9 @@ from torchx_tpu.models import generate as gen
 from torchx_tpu.models import llama
 from torchx_tpu.obs import hot
 from torchx_tpu.obs import metrics as obs_metrics
-from torchx_tpu.ops.paged_attention import TRASH_BLOCK
-from torchx_tpu.serve.kv_pool import BlockAllocator, EvaTables, PoolPlan, SlotTables, WindowTables, window_ring
+from torchx_tpu.serve.kv_pool import PoolPlan
 from torchx_tpu.serve.kv_transfer import KvPayload, new_request_id
-from torchx_tpu.serve.prefix_cache import PrefixCache
+from torchx_tpu.serve.slot_cache import Plan, slot_cache
 
 logger = logging.getLogger(__name__)
 
@@ -207,9 +169,6 @@ class _SlotState:
     #: while the slot holds an unfinished prompt: prompt + tokens generated
     #: before a preemption, of which ``cache_len`` are fed; None once all are
     feeding: Optional[list[int]] = None
-    #: the sliding layers' blocks staged for the prompt, where the model has
-    #: any: block of the sequence -> block; the ring's after the last chunk
-    staged: dict[int, int] = dataclasses.field(default_factory=dict)
 
     @property
     def more_to_decode(self) -> bool:
@@ -239,12 +198,7 @@ class _Admit:
 
     req: ServeRequest
     toks: list[int]  # prompt + already-generated (resume) tokens
-    cached_blocks: list[int]  # retained from the prefix cache
-    cached_tokens: int  # block-aligned prefix length served from cache
-    new_blocks: list[int]  # freshly allocated for the suffix
-    #: the sliding layers' pool, where the model has one: block of the sequence
-    #: -> block, for the cached prefix's last blocks and for every new block
-    window_blocks: dict[int, int] = dataclasses.field(default_factory=dict)
+    plan: Plan
 
 
 @dataclasses.dataclass
@@ -308,94 +262,23 @@ class ServeEngine:
         self._cfg = cfg
         self.max_slots = max_slots
         self.block_size = block_size
-        #: EVA attention: the slots' tables, whose rows are not their tokens; None for every other model
-        self.eva = EvaTables(max_slots, cfg.max_seq, cfg.eva_window, cfg.eva_chunk, block_size) if cfg.eva_window else None
-        self.blocks_per_slot = self.eva.blocks_per_slot if self.eva else math.ceil(cfg.max_seq / block_size)
         #: requests that may be mid-prompt at once, and with them the window
         #: blocks staged for prompts
         self.max_prefill_batch = max(1, max_prefill_batch)
         if chunk_width < block_size or chunk_width % block_size:
             raise ValueError(f"chunk_width must be a positive multiple of block_size={block_size}, got {chunk_width}")
+        #: what the slots hold, by the kind of cache the model keeps: pools, tables, allocators, prefix cache
+        self.cache = slot_cache(cfg, max_slots=max_slots, block_size=block_size, num_blocks=num_blocks, num_window_blocks=num_window_blocks,
+                                max_prefill_batch=self.max_prefill_batch, prefix_cache=enable_prefix_cache, prefix_cache_reserve=prefix_cache_reserve)  # fmt: skip
+        obs_metrics.SERVE_STATE_BYTES.set(self.cache.state_bytes)
         #: prompt tokens a step can carry: compiled geometry, like block_size
         #: (256 is the one width measured and checked on the chip; the tests
         #: pass a small one). No prompt is longer than a slot's blocks
-        self.chunk_width = min(chunk_width, self.blocks_per_slot * block_size)
-        #: the most blocks one sequence holds at once
-        most = self.eva.most_blocks if self.eva else self.blocks_per_slot
-        if num_blocks is None:
-            # half a table a slot; where rows are not tokens, beside every block of pooled rows a slot
-            # can come to hold (they stay for as long as the sequence does, the window's come back)
-            pooled = self.eva.pooled_blocks * self.eva.windows if self.eva else 0
-            num_blocks = 1 + max_slots * (pooled + max(1, self.blocks_per_slot // 2))
-        if num_blocks < most + 1:
-            raise ValueError(
-                f"num_blocks={num_blocks} cannot hold one max_seq sequence"
-                f" ({most} blocks + trash)"
-            )
-        self.num_blocks = num_blocks
+        self.chunk_width = min(chunk_width, self.cache.blocks_per_slot * block_size)
         self._clock = clock
         self._sleep = sleep
-
-        #: the sliding layers' window (0: the model has none) and the entries of a
-        #: slot's ring table in their pools
-        self.window = cfg.sliding_window if cfg.layers_of("window") else 0
-        self.window_ring = window_ring(self.window, block_size) if self.window else 0
-        if self.window and num_window_blocks is None:
-            # every slot's ring, and the prompts being fed staged whole
-            num_window_blocks = 1 + max_slots * self.window_ring + self.max_prefill_batch * self.blocks_per_slot
-        self.num_window_blocks = num_window_blocks if self.window else 0
-        self.pools = gen.init_kv_pools(cfg, num_blocks, block_size, self.num_window_blocks, max_slots)
-        #: recurrent state a slot holds whatever its length (a mixer's store), and over all rows
-        store = jax.tree.leaves(self.pools.get("ssm", ()))
-        self.state_bytes_per_slot = sum(p.nbytes // p.shape[1] for p in store)
-        self.state_bytes = sum(p.nbytes for p in store)
-        obs_metrics.SERVE_STATE_BYTES.set(self.state_bytes)
-        row_bytes = lambda pools: sum(  # noqa: E731 - a token's bytes over the layers of a pool tree
-            p.shape[0] * math.prod(p.shape[3:]) * p.dtype.itemsize for p in jax.tree.leaves(pools)
-        )
-        #: bytes a further token of context holds, as the pools are laid out:
-        #: every layer's, but for the sliding layers, whose cost a slot is constant
-        self.kv_bytes_per_token = row_bytes(self._pools_of("full"))
-        if self.eva:  # a row's bytes while the token is in its window; for ever after, its share of a pooled row
-            self.kv_bytes_per_token //= cfg.eva_chunk
-        self.kv_bytes_per_slot_window = (
-            self.window_ring * block_size * row_bytes(self.pools["window"]) if self.window else 0
-        )
-        self.alloc = BlockAllocator(num_blocks)
-        self.tables = self.eva or SlotTables(max_slots, self.blocks_per_slot)
-        self.window_alloc = BlockAllocator(self.num_window_blocks) if self.window else None
-        self.window_tables = WindowTables(max_slots, self.window_ring) if self.window else None
-        self.window_blocks_released = 0  # window blocks slots gave back as their windows moved on (EVA: ended)
-        self.pooled_blocks_promoted = 0  # EVA: staging blocks moved into a table as their window ended
         self._slots: list[Optional[_SlotState]] = [None] * max_slots
         self._admit_counter = itertools.count()
-        self.prefix_cache: Optional[PrefixCache] = None
-        #: why there is no prefix cache though one was asked for, else None
-        self.prefix_cache_off: Optional[str] = None
-        if enable_prefix_cache and self.state_bytes:
-            self.prefix_cache_off = (
-                "recurrent state: the cache indexes K/V blocks alone, and a hit would hand a request K/V without"
-                " the state that goes with it"
-            )
-        elif enable_prefix_cache and self.eva:
-            self.prefix_cache_off = (
-                "a cache whose rows are not its tokens: the prefix cache indexes a block by the tokens it holds, and a"
-                " window's blocks are given back and its pooled rows laid out anew as the sequence grows"
-            )
-        elif enable_prefix_cache:
-            cap = (
-                max(1, int(prefix_cache_reserve * num_blocks))
-                if prefix_cache_reserve > 0
-                else None
-            )
-            self.prefix_cache = PrefixCache(
-                self.alloc,
-                block_size,
-                max_blocks=cap,
-                window_alloc=self.window_alloc,
-                # the blocks ahead of a suffix that its first query's window reaches into
-                window_back=math.ceil((self.window - 1) / block_size) if self.window else 0,
-            )
 
         self._lock = threading.Lock()
         self._waiting: deque[ServeRequest] = deque()
@@ -483,26 +366,10 @@ class ServeEngine:
             **kwargs,
         )
 
-    def _window_first_block(self, query_pos: int) -> int:
-        """The lowest block of a sequence that the window of a query at
-        ``query_pos``, and so of every later one, still touches."""
-        return max(0, query_pos - self.window + 1) // self.block_size
-
-    def _pools_of(self, kind: str):  # noqa: ANN202
-        """The paged pools of one cache kind: the whole tree where the model has
-        one kind, a mixer's store (addressed by slot, not by block) left out."""
-        if self.window:
-            return self.pools[kind]
-        return {name: pool for name, pool in self.pools.items() if name != "ssm"}
-
-    def _tables_arg(self, full, window, state_rows=None):  # noqa: ANN001, ANN202
-        """What the programs take as ``tables``: one array, or one a cache kind;
-        with a mixer the rows' state rows beside the one table."""
-        if self.state_bytes:
-            return {"full": jnp.asarray(full), "state": jnp.asarray(state_rows, jnp.int32)}
-        if self.eva:  # ``window`` is then the rows' staging blocks
-            return {"full": jnp.asarray(full), "stage": jnp.asarray(window)}
-        return {"full": jnp.asarray(full), "window": jnp.asarray(window)} if self.window else jnp.asarray(full)
+    @property
+    def pools(self):  # noqa: ANN201
+        """The pools on the device, as the last step enqueued leaves them."""
+        return self.cache.pools
 
     # -- public API --------------------------------------------------------
 
@@ -582,17 +449,9 @@ class ServeEngine:
         return req
 
     def _refuse_handoff(self) -> None:
-        """A hand-off carries K/V blocks by token, and no recurrent state."""
-        if self.state_bytes:
-            raise NotImplementedError(
-                "a model with state-space layers is not handed off: a KvPayload carries K/V blocks and not the"
-                " recurrent state that goes with them"
-            )
-        if self.eva:
-            raise NotImplementedError(
-                "a cache whose rows are not its tokens is not handed off: a KvPayload carries a block for every"
-                " block_size tokens, not a window's rows and the pooled rows behind it"
-            )
+        """A hand-off carries K/V blocks by token: a kind that holds more says no."""
+        if self.cache.no_handoff:
+            raise NotImplementedError(self.cache.no_handoff)
 
     def _admit_handoffs(self) -> bool:
         """Place transferred prefills into free slots: scatter the
@@ -604,34 +463,16 @@ class ServeEngine:
                 if not self._handoffs or not free:
                     return worked
                 h = self._handoffs[0]
-                n = math.ceil(h.cache_len / self.block_size)
-                blocks = self._alloc_pressure(n)
-                # of a sliding layer's blocks, those the next query's window still touches
-                keep_from = self._window_first_block(h.cache_len) if self.window else n
-                kept = self._alloc_pressure(n - keep_from, "window") if self.window and blocks is not None else []
-                if blocks is None or kept is None:
-                    if blocks:
-                        self.alloc.release(blocks)
+                plan = self.cache.plan_handoff(h.cache_len)
+                if plan is None:
                     return worked  # pool pressure; retry next loop pass
-                window_blocks = dict(zip(range(keep_from, n), kept))
                 self._handoffs.popleft()
                 self._admitting = [h.req]  # visible to drain() until slotted
-            with hot.span(
-                hot.SERVE_KV_IMPORT, blocks=len(blocks), cache_len=h.cache_len
-            ):
-                idx = jnp.asarray(np.asarray(blocks, np.int32))
-                self.pools = gen.import_blocks(
-                    self.pools, idx, h.k, h.v, self._cfg.layer_types and self._cfg.cache_kinds,
-                    self._window_ids(window_blocks, n),
-                )  # fmt: skip
-            seq = list(h.req.prompt) + h.req.generated
-            if self.prefix_cache is not None:
-                self.prefix_cache.insert(seq[: h.cache_len], blocks, window_blocks)
+            with hot.span(hot.SERVE_KV_IMPORT, blocks=len(plan.blocks), cache_len=h.cache_len):
+                self.cache.import_blocks(plan, h.k, h.v)
             slot = free[0]
-            self.tables.assign(slot, blocks)
-            self.tables.lengths[slot] = h.cache_len
-            for b, block in window_blocks.items():
-                self.window_tables.assign(slot, b, block)
+            self.cache.place(slot, plan)
+            self.cache.fed(slot, list(h.req.prompt) + h.req.generated, h.cache_len, last=True)
             self._slots[slot] = _SlotState(
                 req=h.req,
                 cache_len=h.cache_len,
@@ -678,8 +519,8 @@ class ServeEngine:
                 "occupancy": active / self.max_slots,
                 "queue_depth": len(self._waiting),
                 "handoffs_pending": len(self._handoffs),
-                "kv_blocks_used": self.alloc.used_blocks,
-                "kv_blocks_free": self.alloc.free_blocks,
+                "kv_blocks_used": self.cache.alloc.used_blocks,
+                "kv_blocks_free": self.cache.alloc.free_blocks,
                 "requests_done": self.requests_done,
                 "tokens_out": self.tokens_out,
                 "steps": self.steps,
@@ -690,27 +531,22 @@ class ServeEngine:
                 "chunk_width": self.chunk_width,
                 "prefill_tokens": self.prefill_tokens,
                 "prefill_padded_tokens": self.prefill_padded_tokens,
-                "kv_bytes_per_token": self.kv_bytes_per_token,
-                "kv_bytes_per_slot_window": self.kv_bytes_per_slot_window,
-                "kv_blocks_window_used": self.window_alloc.used_blocks if self.window else 0,
-                "state_bytes_per_slot": self.state_bytes_per_slot,
-                "state_bytes": self.state_bytes,
-                **self._kv_blocks(),
+                **self.cache.stats(self._held()),
                 "draining": self._draining,
                 "failed": self.failed,
             }
-        if self.prefix_cache is not None:
-            out["prefix_cache"] = self.prefix_cache.stats()
-        elif self.prefix_cache_off:
-            out["prefix_cache_off"] = self.prefix_cache_off
+        if self.cache.prefix_cache is not None:
+            out["prefix_cache"] = self.cache.prefix_cache.stats()
+        elif self.cache.prefix_cache_off:
+            out["prefix_cache_off"] = self.cache.prefix_cache_off
         return out
 
     def prefix_summary(self, max_entries: int = 128) -> list[str]:
         """Digests of this engine's hottest cached prefixes — published
         on ``/healthz`` for the cache-aware router."""
-        if self.prefix_cache is None:
+        if self.cache.prefix_cache is None:
             return []
-        return self.prefix_cache.summary(max_entries)
+        return self.cache.prefix_cache.summary(max_entries)
 
     @property
     def queue_depth(self) -> int:
@@ -790,59 +626,15 @@ class ServeEngine:
 
     # -- admission -----------------------------------------------------------
 
-    def _alloc_pressure(self, n: int, kind: str = "full") -> Optional[list[int]]:
-        """:meth:`BlockAllocator.alloc` from the pool of ``kind`` that spills
-        cache-only blocks first: under pool pressure, LRU prefix-cache entries
-        are cheaper to reclaim than preempting a live slot."""
-        alloc = self.window_alloc if kind == "window" else self.alloc
-        blocks = alloc.alloc(n)
-        if blocks is None and self.prefix_cache is not None:
-            evict = self.prefix_cache.evict_window if kind == "window" else self.prefix_cache.evict
-            evict(n - alloc.free_blocks)
-            blocks = alloc.alloc(n)
-        return blocks
-
-    def _kv_blocks(self) -> dict[str, int]:
-        """Blocks the slots hold in each kind of pool (not what the prefix
-        cache keeps beside them; those staged for a prompt being fed among
-        them), and window blocks given back so far: what the ``serve.decode``
-        and ``serve.admit`` spans carry."""
-        if self.eva:
-            tokens, rows = self._rows_held()
-            return {
-                "kv_blocks_full": self.eva.held_blocks,
-                "kv_blocks_window": self.eva.held_window,
-                "kv_blocks_pooled": self.eva.held_pooled,
-                "window_blocks_released": self.window_blocks_released,
-                "pooled_blocks_promoted": self.pooled_blocks_promoted,
-                "cache_tokens_held": tokens,
-                "cache_rows_held": rows,
-            }
-        staged = sum(len(st.staged) for st in self._slots if st is not None)
-        return {
-            "kv_blocks_full": self.tables.held_blocks,
-            "kv_blocks_window": self.window_tables.held_blocks + staged if self.window else 0,
-            "window_blocks_released": self.window_blocks_released,
-            # what a slot holds beside its blocks whatever its length; not there without a mixer
-            **({"state_bytes_per_slot": self.state_bytes_per_slot} if self.state_bytes else {}),
-        }
-
-    def _rows_held(self) -> tuple[int, int]:
-        """Where rows are not tokens: (the tokens the slots hold, written or in
-        flight; the cache rows they hold for them, staged ones among them)."""
-        held = [st.cache_len + st.unfetched for st in self._slots if st is not None]
-        return sum(held), sum(self.eva.rows(n) for n in held)
+    def _held(self):  # noqa: ANN202
+        """The tokens each occupied slot holds, written or in flight (iterated by a kind that reports in rows alone)."""
+        return (st.cache_len + st.unfetched for st in self._slots if st is not None)
 
     def _release_slot(self, slot: int) -> _SlotState:
-        """Empty ``slot``: a reference to each block it holds goes back to the
-        block's allocator, those staged for an unfinished prompt too. -> the
-        state it held."""
+        """Empty ``slot``: what it holds in the cache goes back. -> the state it held."""
         st = self._slots[slot]
         self._slots[slot] = None
-        self.alloc.release(self.tables.release(slot))
-        if self.window:
-            self.window_alloc.release(self.window_tables.release(slot) + list(st.staged.values()))
-            st.staged = {}
+        self.cache.release(slot)
         return st
 
     def _admit(self) -> bool:
@@ -864,25 +656,23 @@ class ServeEngine:
             if not admitted:
                 return False
             for a, slot in zip(admitted, free_slots):
-                self.tables.assign(slot, a.cached_blocks + a.new_blocks)
-                self.tables.lengths[slot] = a.cached_tokens
+                self.cache.place(slot, a.plan)
                 self._slots[slot] = _SlotState(
                     req=a.req,
-                    cache_len=a.cached_tokens,
+                    cache_len=a.plan.cached_tokens,
                     last_tok=0,  # never read: the slot's first step takes its token from the device
                     admit_seq=next(self._admit_counter),
                     feeding=a.toks,
-                    staged=a.window_blocks,
                 )
             with self._lock:
                 self._admitting = []
             self._update_gauges()
             admit_span.set_metadata(
                 admitted=len(admitted),
-                cached_tokens=sum(a.cached_tokens for a in admitted),
+                cached_tokens=sum(a.plan.cached_tokens for a in admitted),
                 queue_depth=len(self._waiting),
-                kv_bytes_per_token=self.kv_bytes_per_token,
-                **self._kv_blocks(),
+                kv_bytes_per_token=self.cache.kv_bytes_per_token,
+                **self.cache.span_attrs(self._held()),
             )
         return True
 
@@ -893,31 +683,10 @@ class ServeEngine:
         with self._lock:
             for req in list(self._waiting)[:limit]:
                 toks = list(req.prompt) + req.generated
-                cached_blocks: list[int] = []
-                cached_window: dict[int, int] = {}
-                cached_tokens = 0
-                if self.prefix_cache is not None:
-                    # retains the matched blocks on our behalf; never
-                    # covers the last token, so a token is left to feed
-                    cached_blocks, cached_window, cached_tokens = self.prefix_cache.match_kinds(toks)
-                need = math.ceil(len(toks) / self.block_size) - len(cached_blocks)
-                if self.eva:  # its staging and its first window's blocks; _ensure_rows brings the rest
-                    need = self.eva.pooled_blocks + math.ceil(min(len(toks), self.eva.window) / self.block_size)
-                new_blocks = self._alloc_pressure(need)
-                # a window block is staged for every new block of the prompt;
-                # _chunk_enqueued hands back those below the next chunk's window
-                new_window = self._alloc_pressure(need, "window") if self.window and new_blocks is not None else []
-                if new_blocks is None or new_window is None:
-                    if new_blocks:
-                        self.alloc.release(new_blocks)
-                    if cached_blocks:
-                        self.alloc.release(cached_blocks)
-                    if cached_window:
-                        self.window_alloc.release(list(cached_window.values()))
+                plan = self.cache.plan(toks)
+                if plan is None:
                     break  # pool pressure: admit what fits, retry later
-                window_blocks = dict(cached_window)
-                window_blocks.update({len(cached_blocks) + j: block for j, block in enumerate(new_window)})
-                admitted.append(_Admit(req, toks, cached_blocks, cached_tokens, new_blocks, window_blocks))
+                admitted.append(_Admit(req, toks, plan))
             for a in admitted:
                 self._waiting.remove(a.req)
             # visible to drain(): popped but not yet in a slot
@@ -925,26 +694,11 @@ class ServeEngine:
             obs_metrics.SERVE_QUEUE_DEPTH.set(len(self._waiting))
         return admitted
 
-    def _window_ids(self, window_blocks: dict[int, int], n: int) -> Optional[np.ndarray]:
-        """A sequence's ``n`` blocks in the window pools as an array: the trash
-        block where none is held. None for a model of one cache kind."""
-        if not self.window:
-            return None
-        ids = np.full((n,), TRASH_BLOCK, np.int32)
-        for b, block in window_blocks.items():
-            ids[b] = block
-        return ids
-
-    def _export_handoff(
-        self, req: ServeRequest, toks: list[int], blocks: list[int], window_blocks: dict[int, int]
-    ) -> KvPayload:
-        """Snapshot the prefilled K/V blocks for transfer to a decode
-        replica (the ``prefill_only`` completion path)."""
-        self._refuse_handoff()
-        k, v = gen.export_blocks(
-            self.pools, np.asarray(blocks, np.int32), self._cfg.layer_types and self._cfg.cache_kinds,
-            self._window_ids(window_blocks, len(blocks)),
-        )  # fmt: skip
+    def _export_handoff(self, req: ServeRequest, toks: list[int], slot: int) -> KvPayload:
+        """Snapshot ``slot``'s prefilled K/V blocks for transfer to a decode
+        replica (the ``prefill_only`` completion path, which :meth:`submit`
+        lets only a kind that is handed off take)."""
+        k, v = self.cache.export(slot)
         return KvPayload(
             request_id=new_request_id(),
             tokens=list(toks),
@@ -955,8 +709,8 @@ class ServeEngine:
             seed=req.seed,
             eos_id=req.eos_id,
             block_size=self.block_size,
-            k=np.asarray(k),
-            v=np.asarray(v),
+            k=k,
+            v=v,
         )
 
     # -- decode ------------------------------------------------------------
@@ -989,85 +743,10 @@ class ServeEngine:
         self.preemptions += 1
         return True
 
-    def _copy_block(self, src: int, dst: int) -> None:
-        """Device-side copy of one physical block across all layers (of the
-        full kind: a block being written in a window pool is never a cached one,
-        the cache adopts whole blocks only)."""
-        with hot.span(hot.SERVE_COW_COPY):
-            copied = jax.tree.map(lambda p: p.at[:, dst].set(p[:, src]), self._pools_of("full"))
-            self.pools = {**self.pools, **({"full": copied} if self.window else copied)}
-
-    def _ensure_capacity(self, slot: int, write_pos: int) -> bool:
-        """Make sure ``slot`` holds a *writable* block for ``write_pos``:
-        grows the table lazily, copy-on-writes a shared tail block
-        (another holder — cache or sibling slot — still reads it), and
-        preempts the youngest slot under pool pressure. False if ``slot``
-        itself was preempted away."""
-        if self.eva:
-            return self._ensure_rows(slot, write_pos)
-        idx = write_pos // self.block_size
-        while True:
-            have = len(self.tables.blocks_of(slot))
-            if have >= idx + 1:
-                tail = self.tables.blocks_of(slot)[idx]
-                if not self.alloc.is_shared(tail):
-                    return self._ensure_window(slot, write_pos) if self.window else True
-                fresh = self._alloc_pressure(1)
-                if fresh is not None:
-                    self._copy_block(tail, fresh[0])
-                    self.tables.replace_block(slot, idx, fresh[0])
-                    self.alloc.release([tail])
-                    obs_metrics.SERVE_COW_COPIES.inc()
-                    continue  # re-check the (fresh, unshared) tail
-            else:
-                blocks = self._alloc_pressure(idx + 1 - have)
-                if blocks is not None:
-                    self.tables.assign(slot, blocks)
-                    continue  # re-check the (fresh, unshared) tail
-            self._preempt_youngest()
-            if self._slots[slot] is None:
-                return False  # preempted ourselves: nothing else to evict
-
-    def _ensure_window(self, slot: int, write_pos: int) -> bool:
-        """The sliding layers' side of :meth:`_ensure_capacity`: hand back the
-        blocks of ``slot`` whose every position is below the window of the
-        query at ``write_pos`` (every later query's window lies higher), then
-        make sure the ring holds a block for ``write_pos``. False if ``slot``
-        itself was preempted away for it."""
-        tables = self.window_tables
-        below = tables.release_below(slot, self._window_first_block(write_pos))
-        self.window_alloc.release(below)
-        self.window_blocks_released += len(below)
-        idx = write_pos // self.block_size
-        while not tables.has(slot, idx):
-            block = self._alloc_pressure(1, "window")
-            if block is not None:
-                tables.assign(slot, idx, block[0])
-                break
-            self._preempt_youngest()
-            if self._slots[slot] is None:
-                return False
-        return True
-
-    def _ensure_rows(self, slot: int, write_pos: int) -> bool:
-        """:meth:`_ensure_capacity` where rows are not tokens (EVA attention):
-        make ``slot`` writable up to ``write_pos``, which lies in the window its
-        table is laid out for or starts the next. Then the window before has
-        ended, whatever step wrote its last row still in flight: its staged rows
-        go into the table, its blocks go back to the pool, all of them, and the
-        new window is given staging and a first block. False if ``slot`` itself
-        was preempted away for them."""
-        tables = self.eva
-        if write_pos // tables.window > tables.window_of(slot):
-            released = tables.turn(slot)
-            self.alloc.release(released)
-            self.window_blocks_released += len(released)
-            self.pooled_blocks_promoted += tables.pooled_blocks
-        while short := tables.short(slot, write_pos):
-            blocks = self._alloc_pressure(short)
-            if blocks is not None:
-                tables.assign(slot, blocks)
-                break
+    def _make_writable(self, slot: int, write_pos: int) -> bool:
+        """Make ``slot`` writable at ``write_pos``, preempting the youngest slot for as long as
+        a pool is short. False if ``slot`` itself was preempted away: nothing else to evict."""
+        while not self.cache.grow(slot, write_pos):
             self._preempt_youngest()
             if self._slots[slot] is None:
                 return False
@@ -1082,39 +761,25 @@ class ServeEngine:
             return None
         slot = min(feeding)[1]
         st = self._slots[slot]
-        n = min(self.chunk_width, len(st.feeding) - st.cache_len)
-        if self.eva:  # a chunk stops where its window ends: the table is laid anew there
-            n = min(n, self.eva.window - st.cache_len % self.eva.window)
-        return slot, st, n
+        return slot, st, self.cache.chunk_tokens(st.cache_len, min(self.chunk_width, len(st.feeding) - st.cache_len))
 
     def _chunk_enqueued(self, slot: int, st: _SlotState, n: int) -> bool:
         """The step just enqueued carries the next ``n`` tokens of ``slot``'s
-        prompt. Their blocks are valid for every later program (device order),
-        so the full ones are indexed now; of the sliding layers' staged blocks,
-        those below the window of the next chunk's first query go back. Behind
-        the prompt's last chunk the ring takes what is still in reach and the
-        slot decodes from the next step on. -> whether that chunk was the last."""
+        prompt: the cache is told (it indexes the full blocks, and a kind moves
+        on what it staged), and behind the prompt's last chunk the slot decodes
+        from the next step on. -> whether that chunk was the last."""
         st.cache_len += n
         self.chunk_steps += 1
         self.prefill_tokens += n
         self.prefill_padded_tokens += self.chunk_width
-        if self.prefix_cache is not None:
-            self.prefix_cache.insert(st.feeding[: st.cache_len], self.tables.blocks_of(slot), st.staged)
-        if self.window:
-            keep_from = self._window_first_block(st.cache_len)
-            below = [st.staged.pop(b) for b in sorted(st.staged) if b < keep_from]
-            self.window_alloc.release(below)
-            self.window_blocks_released += len(below)
         last = st.cache_len == len(st.feeding)
+        self.cache.fed(slot, st.feeding, st.cache_len, last)
         if last:
-            for b, block in st.staged.items():
-                self.window_tables.assign(slot, b, block)
-            st.feeding, st.staged = None, {}
+            st.feeding = None
             # as a decode step leaves it: the step that writes the sequence's
             # last row is in flight, and its token is the next step's input
             st.cache_len -= 1
             st.unfetched = 1
-        self.tables.lengths[slot] = st.cache_len
         return last
 
     def _decode_once(self) -> bool:
@@ -1134,50 +799,32 @@ class ServeEngine:
                 for slot, st in enumerate(self._slots):
                     # None by now: preempted by an earlier slot's capacity grab
                     if st is not None and st.more_to_decode:
-                        self._ensure_capacity(slot, st.cache_len + st.unfetched)
-                # where rows are not tokens a prompt's blocks come as its chunks reach them: now, ahead
-                # of the tables' copy below (a request preempted away for them leaves the next to be fed)
-                while self.eva and (chunk := self._next_chunk()) is not None:
-                    if self._ensure_rows(chunk[0], chunk[1].cache_len + chunk[2] - 1):
-                        break
+                        self._make_writable(slot, st.cache_len + st.unfetched)
+                # a kind may give a prompt its blocks as its chunks reach them: now, ahead of the
+                # tables' copy below (a request preempted away for them leaves the next to be fed)
+                chunk = self._next_chunk()
+                while chunk is not None and not self._make_writable(chunk[0], chunk[1].cache_len + chunk[2] - 1):
+                    chunk = self._next_chunk()
 
                 tokens = np.zeros((self.max_slots,), np.int32)
                 positions = np.zeros((self.max_slots,), np.int32)
                 seeds = np.zeros((self.max_slots,), np.int32)
                 temps = np.zeros((self.max_slots,), np.float32)
-                # a copy: the loop goes on to change the table while the step
-                # is in flight, and the CPU backend reads a numpy array where
-                # it lies
-                tables = self.tables.tables.copy()
-                window_tables = self.window_tables.tables.copy() if self.window else None
-                if self.eva:  # in the window tables' place: a slot's staging blocks, where the programs pool what a step fills
-                    window_tables = self.eva.stage.copy()
-                # a mixer's state row a slot: its own (slot + 1) while it decodes, else the
-                # trash row, so that a slot being fed is written by its chunk alone
-                state_rows = np.zeros((self.max_slots,), np.int32)
                 stepping: list[tuple[int, _SlotState]] = []
-                rows_read = 0  # EVA: cache rows this step's decode attention reads, a layer
+                parked: list[int] = []  # its prompt is still being fed, or its last token is in flight
                 for slot, st in enumerate(self._slots):
                     if st is None:
                         continue
                     if not st.more_to_decode:
-                        # its prompt is still being fed, or its last token is
-                        # in flight. The program writes a row for every slot:
-                        # this one's goes where an empty slot's does
-                        tables[slot] = TRASH_BLOCK
-                        if window_tables is not None:
-                            window_tables[slot] = TRASH_BLOCK
+                        parked.append(slot)
                         continue
                     tokens[slot] = _FROM_DEVICE if st.unfetched else st.last_tok
                     positions[slot] = st.cache_len + st.unfetched
                     seeds[slot] = _seed32(st.req)
                     temps[slot] = st.req.temperature
-                    state_rows[slot] = slot + 1
                     stepping.append((slot, st))
-                    if self.eva:
-                        rows_read += self.eva.coord(int(positions[slot])) + 1
+                tables = self.cache.step_tables([slot for slot, _ in stepping], parked)
 
-                chunk = self._next_chunk()
                 if chunk is not None:
                     c_slot, c_st, n = chunk
                     start = c_st.cache_len
@@ -1187,15 +834,12 @@ class ServeEngine:
                     at = np.asarray([start, n, c_slot if ends else -1], np.int32)
                     seeds = np.append(seeds, _seed32(c_st.req))
                     temps = np.append(temps, np.float32(c_st.req.temperature))
-                    # the request's own table (the copy above sends its slot's decode row to the
-                    # trash block); its staged window blocks lie as the full ones do, block b at entry b
-                    chunk_full = self.tables.tables[c_slot : c_slot + 1].copy()
-                    chunk_window = self.eva.stage[c_slot].copy() if self.eva else self._window_ids(c_st.staged, self.blocks_per_slot)
+                    # the request's own (the step's copy sends its slot's decode row to the trash block)
+                    chunk_tables = self.cache.chunk_tables(c_slot)
 
             enqueued = None
-            step_span.set_metadata(**self._kv_blocks())  # as the step is dispatched
-            if self.eva:
-                step_span.set_metadata(cache_rows_read=rows_read)
+            reading = (st.cache_len + st.unfetched for _, st in stepping)
+            step_span.set_metadata(**self.cache.span_attrs(self._held(), reading))  # as the step is dispatched
             if stepping or chunk is not None:
                 with hot.span(hot.SERVE_DECODE_DISPATCH):
                     host_tokens = jnp.asarray(tokens)
@@ -1205,20 +849,20 @@ class ServeEngine:
                         # with no step in flight every slot reads the host's token
                         host_tokens if before is None else before.nxt,
                         jnp.asarray(positions),
-                        self._tables_arg(tables, window_tables, state_rows),
-                        self.pools,
+                        jax.tree.map(jnp.asarray, tables),
+                        self.cache.pools,
                         jnp.asarray(seeds),
                         jnp.asarray(temps),
                     )
                     first_of = None
                     if chunk is None:
-                        nxt, self.pools = self._decode(*args)
+                        nxt, self.cache.pools = self._decode(*args)
                     else:
-                        nxt, self.pools = self._decode_chunk(
+                        nxt, self.cache.pools = self._decode_chunk(
                             *args,
                             jnp.asarray(chunk_tokens),
                             jnp.asarray(at),
-                            self._tables_arg(chunk_full, None if chunk_window is None else chunk_window[None], [c_slot + 1]),
+                            jax.tree.map(jnp.asarray, chunk_tables),
                         )
                     for _, st in stepping:
                         st.unfetched += 1
@@ -1265,7 +909,6 @@ class ServeEngine:
                 continue
             st.unfetched -= 1
             st.cache_len += 1
-            self.tables.lengths[slot] = st.cache_len
             tok = int(sampled[slot])
             st.last_tok = tok
             req = st.req
@@ -1281,18 +924,14 @@ class ServeEngine:
             done = self._finished(req, tok)
             if done or req.prefill_only:
                 finished += 1
-                blocks = self.tables.blocks_of(slot)
-                window_blocks = self.window_tables.blocks_of(slot) if self.window else {}
                 seq = list(req.prompt) + req.generated
                 if not done:
                     # a request its first token already finishes never needs
                     # the decode side: no handoff, the caller reads .tokens
-                    req.handoff = self._export_handoff(req, seq[: st.cache_len], blocks, window_blocks)
-                if self.prefix_cache is not None:
-                    # index the completed sequence's full blocks (cache
-                    # holds cache_len tokens: everything but the final
-                    # sampled token) before dropping the slot's refs
-                    self.prefix_cache.insert(seq[: st.cache_len], blocks, window_blocks)
+                    req.handoff = self._export_handoff(req, seq[: st.cache_len], slot)
+                # index the completed sequence's full blocks (cache_len tokens: all
+                # but the final sampled token) before dropping the slot's refs
+                self.cache.fed(slot, seq, st.cache_len, last=True)
                 self._release_slot(slot)
                 self._complete(req, now)
         if kept:
@@ -1304,9 +943,7 @@ class ServeEngine:
         active = sum(1 for s in self._slots if s is not None)
         obs_metrics.SERVE_SLOTS_ACTIVE.set(active)
         obs_metrics.SERVE_OCCUPANCY.set(active / self.max_slots)
-        obs_metrics.SERVE_KV_BLOCKS_USED.set(self.alloc.used_blocks)
-        if self.eva:
-            obs_metrics.SERVE_CACHE_ROWS_HELD.set(self._rows_held()[1])
+        self.cache.update_gauges(self._held())
 
 
 def serve_kv_payload(

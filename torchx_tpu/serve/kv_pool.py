@@ -191,11 +191,10 @@ class BlockAllocator:
 
     Every allocated block carries a reference count (1 on :meth:`alloc`):
     the prefix cache and any slot sharing a cached prefix each hold one
-    reference via :meth:`retain`, and :meth:`release` (or its legacy
-    alias :meth:`free`) returns the block to the free list only when the
-    count reaches zero. A shared block (refcount > 1) must never be
-    written in place — the engine copy-on-writes the partial tail block
-    through :meth:`is_shared` before appending to it.
+    reference via :meth:`retain`, and :meth:`release` returns the block to
+    the free list only when the count reaches zero. A shared block
+    (refcount > 1) must never be written in place — the engine
+    copy-on-writes the partial tail block through :meth:`is_shared` first.
 
     Freeing a block that is already free (double-free) or freeing the
     trash block raises ``ValueError`` instead of silently corrupting the
@@ -229,11 +228,15 @@ class BlockAllocator:
         if not 0 < b < self.num_blocks:
             raise ValueError(f"block {b} outside pool of {self.num_blocks}")
 
-    def alloc(self, n: int) -> list[int] | None:
+    def alloc(self, n: int, evict=None) -> list[int] | None:  # noqa: ANN001
         """Take ``n`` blocks (each with refcount 1), or ``None`` (and take
-        nothing) if fewer are free."""
+        nothing) if fewer are free. ``evict(k)`` (a prefix cache's) is first
+        asked for the ``k`` that are missing: under pool pressure its LRU
+        entries are cheaper to reclaim than preempting a live slot."""
         if n < 0:
             raise ValueError(f"negative allocation: {n}")
+        if n > len(self._free) and evict is not None:
+            evict(n - len(self._free))
         if n > len(self._free):
             return None
         out = [self._free.popleft() for _ in range(n)]
@@ -285,27 +288,22 @@ class BlockAllocator:
                 freed.append(b)
         return freed
 
-    def free(self, blocks: list[int]) -> None:
-        """Drop one reference per block (see :meth:`release`); the
-        historical name for the owner's release path."""
-        self.release(blocks)
-
 
 class SlotTables:
-    """Per-slot block tables + valid lengths, host side (numpy).
+    """Per-slot block tables, host side (numpy).
 
-    The engine passes :attr:`tables` / :attr:`lengths` into the jitted
-    decode step every iteration; unassigned entries stay ``TRASH_BLOCK``
-    so inactive slots are inert under the mask. One instance is shared by
+    The engine passes a copy of :attr:`tables` into the jitted decode step
+    every iteration; unassigned entries stay ``TRASH_BLOCK`` so inactive
+    slots are inert under the mask. One instance is shared by
     all layers — every layer of a sequence uses the same physical block
     ids into its own layer-indexed pool.
     """
 
     def __init__(self, max_slots: int, blocks_per_slot: int) -> None:
         self.max_slots = max_slots
-        self.blocks_per_slot = blocks_per_slot
+        self.blocks_per_slot = self.most_blocks = blocks_per_slot  # a table's entries, and the most blocks a slot holds at once
+        self.kept_blocks = 0  # of them, those a slot keeps whatever its tokens
         self.tables = np.full((max_slots, blocks_per_slot), TRASH_BLOCK, np.int32)
-        self.lengths = np.zeros((max_slots,), np.int32)
         self._blocks: list[list[int]] = [[] for _ in range(max_slots)]
 
     def assign(self, slot: int, blocks: list[int]) -> None:
@@ -339,17 +337,12 @@ class SlotTables:
         self._blocks[slot][index] = block
         self.tables[slot, index] = block
 
-    def token_capacity(self, slot: int, block_size: int) -> int:
-        """Token capacity of ``slot``'s currently-assigned blocks."""
-        return len(self._blocks[slot]) * block_size
-
     def release(self, slot: int) -> list[int]:
         """Clear ``slot`` back to trash and return its blocks for
-        :meth:`BlockAllocator.free`."""
+        :meth:`BlockAllocator.release`."""
         blocks = self._blocks[slot]
         self._blocks[slot] = []
         self.tables[slot, :] = TRASH_BLOCK
-        self.lengths[slot] = 0
         return blocks
 
 
@@ -427,10 +420,6 @@ class EvaTables:
     next window at the entry behind them, and hands back the window's blocks
     **all at once** (a ring gives back one at a time). A block is a chunk
     (``block_size == chunk``), and a window's pooled rows are whole blocks.
-
-    The same surface as :class:`SlotTables` where the engine needs one
-    (:attr:`tables`, :attr:`lengths`, :meth:`assign`, :meth:`blocks_of`,
-    :attr:`held_blocks`, :meth:`release`).
     """
 
     def __init__(self, max_slots: int, max_seq: int, window: int, chunk: int, block_size: int) -> None:
@@ -447,9 +436,9 @@ class EvaTables:
         self.blocks_per_slot = self.pooled_blocks * (self.windows - 1) + self.window_blocks
         #: the most blocks a slot holds at once: those, and its staging
         self.most_blocks = self.blocks_per_slot + self.pooled_blocks
+        self.kept_blocks = self.pooled_blocks * self.windows  # they stay for as long as the sequence does, the window's come back
         self.tables = np.full((max_slots, self.blocks_per_slot), TRASH_BLOCK, np.int32)
         self.stage = np.full((max_slots, self.pooled_blocks), TRASH_BLOCK, np.int32)
-        self.lengths = np.zeros((max_slots,), np.int32)
         self._pooled: list[list[int]] = [[] for _ in range(max_slots)]
         self._stage: list[list[int]] = [[] for _ in range(max_slots)]
         self._window: list[list[int]] = [[] for _ in range(max_slots)]
@@ -532,5 +521,4 @@ class EvaTables:
         blocks = self.blocks_of(slot)
         self._pooled[slot], self._stage[slot], self._window[slot] = [], [], []
         self._lay(slot)
-        self.lengths[slot] = 0
         return blocks
