@@ -7,7 +7,8 @@ it. A check that lets that run through does not see the mixer's state at all.
     chiprun -- python3 scripts/calibrate_falcon_h1.py --workload falcon-h1-serve-decode-long --seeds 1,2,3 --seconds 45
     chiprun -- python3 scripts/calibrate_falcon_h1.py --workload falcon-h1-serve-decode-long --seeds 4 --seconds 45 --control '' --drop-state
 
-The zeroing is one small compiled program with the store donated; the warm-up's requests
+``scripts/calibrate_qwen3_next.py`` is this script under the name of the other kind that keeps
+such state (``StateCache.store`` says where a kind's rows lie). The zeroing is one small compiled program with the store donated; the warm-up's requests
 run it first, so it compiles outside the window like the engine's own two.
 """
 
@@ -34,8 +35,8 @@ def dropping_the_state():  # noqa: ANN201
     def faulty(self, slot, st, n):  # noqa: ANN001, ANN202
         last = enqueued(self, slot, st, n)
         if last:
-            pools = self.cache.pools
-            self.cache.pools = {**pools, "ssm": zero_row(pools["ssm"], jnp.int32(slot + 1))}
+            pools, store = self.cache.pools, self.cache.store  # "ssm", or "gdn" where the state is the linear layers'
+            self.cache.pools = {**pools, store: zero_row(pools[store], jnp.int32(slot + 1))}
         return last
 
     return faulty

@@ -15,7 +15,9 @@ describe a latent pool; this makes the engine's own cache of the cell's kind und
 a pool a cache kind (``k-exaone-serve-decode-long``), or a latent pool a layer group at 64 slots
 (``kimi-vl-a3b-serve-backlog``) or 128 (``xing4-serve-decode-long``: 16,897 blocks x 8 layers x
 1,280 B, 2.58 GiB beside 10.55 GiB of weights; decode 13.14 GiB live), and beside the K/V pools a
-state-space mixer's store a slot (``falcon-h1-serve-decode-long``: 65 rows x 6 layers x 4.2 MB), or one K/V pool
+state-space mixer's store a slot (``falcon-h1-serve-decode-long``: 65 rows x 6 layers x 4.2 MB), a store beside pools
+of fewer layers (``qwen3-next-serve-decode-long``: 129 rows x 6 linear layers x 2.1 MB beside the two attending layers'
+16,897 blocks, the attention's weights under ``params["mixers"]`` and not in the layers' stack), or one K/V pool
 whose rows are not tokens (``evabyte-serve-decode-long``: tables of 176 entries and 8 staging blocks a slot beside them).
 Under each program it prints what its layer loop moves of a layer's pool size or more
 (``torchx_tpu/obs/hlo.py::loop_moves``: nothing, since the pools ride the scan's carry),
@@ -44,7 +46,7 @@ from benchmark.lib import models, spec  # noqa: E402
 from benchmark.rehearse_compile import report as report_memory, shapes_of  # noqa: E402
 
 
-KERNELS = ("paged_mla_decode", "paged_attention_decode", "gmm", "ssm_step")
+KERNELS = ("paged_mla_decode", "paged_attention_decode", "gmm", "ssm_step", "gdn_step")
 
 
 def main() -> None:
@@ -85,8 +87,9 @@ def main() -> None:
     # a layer's smallest pool; a mixer's convolution tails (a few MB a layer) are no pool's size
     layer_bytes = min(p.size // p.shape[0] * p.dtype.itemsize
                       for name, p in jax.tree_util.tree_leaves_with_path(pools) if "conv" not in jax.tree_util.keystr(name))
-    projection_bytes = min(w.size // w.shape[0] * w.dtype.itemsize for group in llama.layer_groups(params)
-                           for name, w in params[group].items() if name in ("wq", "wk", "wv", "wo", "w_qa", "w_qb", "w_kva", "w_kvb", "w_uk", "w_uv"))  # fmt: skip
+    stacks = [params[group] for group in llama.layer_groups(params)] + list(params.get("mixers", {}).values())
+    projection_bytes = min(w.size // w.shape[0] * w.dtype.itemsize for stack in stacks
+                           for name, w in stack.items() if name in ("wq", "wk", "wv", "wo", "w_qa", "w_qb", "w_kva", "w_kvb", "w_uk", "w_uv"))  # fmt: skip
 
     def report(name, compiled):  # noqa: ANN001, ANN202
         report_memory(name, compiled)

@@ -394,7 +394,7 @@ def test_what_this_cache_is_not_built_for_is_refused(model):
             llama.llama_tiny(**{**eva_on, **more})
     with pytest.raises(ValueError):
         moe.moe_tiny(kv_lora_rank=32, qk_nope_dim=16, qk_rope_dim=8, v_head_dim=16, **eva_on)
-    for more in (dict(qk_norm=True), dict(kernels="pallas"), dict(hc_mult=2, hc_sinkhorn_iters=2)):
+    for more in (dict(kernels="pallas"), dict(hc_mult=2, hc_sinkhorn_iters=2), dict(ssm_heads=4, ssm_head_dim=8, ssm_state=16)):  # (QK-norm: built since PR 49)
         with pytest.raises(ValueError, match="unit-offset"):
             llama.llama_tiny(norm_unit_offset=True, **more)
     with pytest.raises(ValueError, match="pred_heads"):

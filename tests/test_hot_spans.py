@@ -201,6 +201,23 @@ def test_a_mixers_state_rides_every_decode_span_and_no_other_engines(tmp_path, e
     assert not any("state_bytes_per_slot" in ev[3] for ev in engine_line)
 
 
+def test_linear_layers_state_rides_every_decode_span_over_the_layers_that_have_it(tmp_path):
+    """The same attribute where three layers of four are linear (a Gated DeltaNet state a slot and no K/V): counted
+    over the linear layers alone, as ``kv_bytes_per_token`` on ``serve.admit`` is over the attending one."""
+    cfg = llama.CONFIGS["tiny"](n_layers=4, layer_types=("linear", "linear", "linear", "full"), gdn_heads=4, gdn_key_heads=2, gdn_head_dim=8)
+    engine = ServeEngine(llama.init_params(cfg, jax.random.PRNGKey(0)), cfg, max_slots=2, block_size=16, chunk_width=16).start()
+    try:
+        lines = _session(tmp_path, lambda: engine.generate([1, 2, 3], 4, timeout=120))
+        stats = engine.stats()
+    finally:
+        engine.stop()
+    turns = [ev for ln in lines for ev in ln if ev[0] == hot.SERVE_DECODE]
+    admits = [ev for ln in lines for ev in ln if ev[0] == hot.SERVE_ADMIT]
+    assert turns and stats["state_bytes_per_slot"] == 3 * (4 * 8 * 8 * 4 + 3 * 64 * 4)
+    assert all(ev[3]["state_bytes_per_slot"] == stats["state_bytes_per_slot"] for ev in turns)
+    assert admits and all(ev[3]["kv_bytes_per_token"] == 1 * 2 * 2 * 16 * 4 == stats["kv_bytes_per_token"] for ev in admits)
+
+
 def test_rows_that_are_not_tokens_ride_every_decode_span_and_no_other_engines(tmp_path, engine_line):
     """``cache_rows_held``, ``cache_tokens_held``, ``cache_rows_read``, ``kv_blocks_pooled`` and the running
     ``pooled_blocks_promoted`` on ``serve.decode`` are what ``benchmark/layer_metrics/engine.cache_rows_per_token.py``
@@ -400,7 +417,7 @@ def _serving_programs(cfg):
     keys = jnp.zeros((slots, 2), jnp.uint32)
     temps = jnp.zeros((slots,), jnp.float32)
     # a mixer's rows ride beside the block table: each slot its own row of the store
-    tables = {"full": i32(slots, bps), "state": 1 + jnp.arange(slots, dtype=jnp.int32)} if cfg.ssm_heads else i32(slots, bps)
+    tables = {"full": i32(slots, bps), "state": 1 + jnp.arange(slots, dtype=jnp.int32)} if cfg.ssm_heads or cfg.gdn_heads else i32(slots, bps)
     if cfg.eva_window:  # the rows' staging blocks ride beside it
         tables = {"full": i32(slots, bps), "stage": i32(slots, cfg.eva_window // cfg.eva_chunk // 16)}
     decode = jax.jit(
@@ -435,6 +452,9 @@ def lowered():
     out.update({f"eva.{k}": _op_names(v) for k, v in _serving_programs(windowed).items()})
     out["dense.train"] = _op_names(_train_step(dense))
     out["moe.train"] = _op_names(_train_step(sparse))
+    linear = moe.moe_tiny(n_layers=4, layer_types=("linear", "linear", "linear", "full"), gdn_heads=4, gdn_key_heads=2, gdn_head_dim=8,
+                          attn_output_gate=True, n_shared_experts=1, shared_expert_gate=True, capacity_factor=0.0)  # fmt: skip
+    out.update({f"linear.{k}": _op_names(v) for k, v in _serving_programs(linear).items()})
     return out
 
 
@@ -443,6 +463,7 @@ SERVING = (hot.EMBED, hot.LAYERS, hot.NORM, hot.ATTN, hot.APPEND_KV, hot.PAGED_A
 TRAINING = (hot.EMBED, hot.LAYERS, hot.NORM, hot.ATTN, hot.ATTN_KERNEL, hot.LM_HEAD, hot.LOSS, hot.GRAD_CLIP,
             hot.OPTIMIZER)  # fmt: skip
 EXPERTS = (hot.MOE_ROUTER, hot.MOE_DISPATCH, hot.MOE_EXPERTS, hot.MOE_COMBINE)
+LINEAR = (hot.GDN, hot.GDN_PROJ, hot.GDN_CONV, hot.GDN_GATE_NORM)  # a linear layer's mixer, and the form each program takes
 MIXER = (hot.SSM, hot.SSM_PROJ, hot.SSM_CONV, hot.SSM_GATE_NORM)  # and the form each program takes:
 CASES = (
     [(f"dense.{p}", s) for p in ("decode", "prefill") for s in SERVING + (hot.MLP,)]
@@ -452,6 +473,8 @@ CASES = (
     + [(f"mixer.{p}", s) for p, form in (("decode", hot.SSM_STEP), ("prefill", hot.SSM_SCAN), ("train", hot.SSM_SCAN))
        for s in MIXER + (hot.ATTN, form)]
     + [(f"eva.{p}", s) for p in ("decode", "prefill") for s in (hot.ATTN, hot.APPEND_KV, hot.PAGED_ATTENTION, hot.EVA_POOL)]
+    + [(f"linear.{p}", s) for p, form in (("decode", hot.GDN_STEP), ("prefill", hot.GDN_CHUNK))
+       for s in LINEAR + (hot.ATTN, hot.ATTN_FULL, hot.ATTN_GATE, hot.MOE_SHARED, hot.MOE_EXPERTS, form)]
 )  # fmt: skip
 
 
@@ -464,6 +487,13 @@ def test_a_program_takes_one_form_of_the_mixer_and_a_model_without_one_neither(l
     assert hot.SSM_SCAN not in lowered["mixer.decode"] and hot.SSM_STEP not in lowered["mixer.prefill"]
     assert not {hot.SSM, hot.SSM_STEP, hot.SSM_SCAN} & (lowered["dense.decode"] | lowered["dense.prefill"] | lowered["dense.train"])
     assert set(MIXER + (hot.SSM_STEP, hot.SSM_SCAN)) <= set(hot.DEVICE_SCOPES)
+
+
+def test_a_program_takes_one_form_of_the_linear_mixer_and_a_model_without_one_neither(lowered):
+    assert hot.GDN_CHUNK not in lowered["linear.decode"] and hot.GDN_STEP not in lowered["linear.prefill"]
+    others = lowered["dense.decode"] | lowered["dense.prefill"] | lowered["dense.train"] | lowered["mixer.decode"] | lowered["moe.decode"]
+    assert not {hot.GDN, hot.GDN_STEP, hot.GDN_CHUNK, hot.ATTN_GATE} & others
+    assert set(LINEAR + (hot.GDN_STEP, hot.GDN_CHUNK, hot.ATTN_GATE)) <= set(hot.DEVICE_SCOPES)
 
 
 def test_only_eva_attention_pools(lowered):

@@ -900,3 +900,93 @@ def test_the_step_that_carries_a_chunk_compiles_for_the_chip(one_chip, make, slo
     # what the program needs beside its arguments, at this width of rows whatever the depth: within the cell's HBM
     assert compiled.memory_analysis().temp_size_in_bytes <= (15.75 - CELL_ARGS_GIB[cell]) * 2**30
 
+
+
+# -- two cache heads of 256 (PR 49): a position as four rows of 128, and the Gated DeltaNet step ----------
+
+
+def _two_heads_of_256(lengths, h, bs, bpr, dtype, seed=0):
+    """:func:`_problem` at 2 cache heads of 256: ``q [slots, h, 256]``, the pools as the heads they are ``[nb, bs, 2,
+    256]`` and as they lie (``LlamaConfig.cache_row``) ``[nb, bs, 4, 128]``, the same bytes."""
+    _, _, _, tables, lens = _problem(lengths, h, 2, bs, bpr, dtype, seed=seed)
+    rng = np.random.default_rng(seed + 1)
+    nb = int(tables.max()) + 1
+    q, k, v = (jnp.asarray(rng.standard_normal(shape), dtype) for shape in ((len(lengths), h, 256), (nb, bs, 2, 256), (nb, bs, 2, 256)))
+    return q, k, v, k.reshape(nb, bs, 4, 128), v.reshape(nb, bs, 4, 128), jnp.asarray(tables), jnp.asarray(lens)
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16], ids=["float32", "bfloat16"])
+def test_heads_of_two_rows_match_the_xla_function(dtype):
+    """``qwen3-next-serve-decode-long``'s attention at its real geometry (16 query heads over 2 cache heads of 256, a
+    block 64 rows of 128): each half of a query against the rows that hold that half, the pair summed, one softmax;
+    against the XLA function over the pool as it lies and over the same bytes seen as the heads they are."""
+    q, k, v, k_rows, v_rows, tables, lens = _two_heads_of_256([1, 300, 530, 16], 16, 16, 34, dtype)
+    want = pa.paged_attention_xla(q, k, v, tables, lens)
+    np.testing.assert_array_equal(pa.paged_attention_xla(q, k_rows, v_rows, tables, lens), want)
+    got = pk.paged_attention_pallas(q, k_rows, v_rows, tables, lens, interpret=True)
+    assert got.shape == want.shape == (4, 16, 256) and got.dtype == want.dtype
+    np.testing.assert_allclose(np.asarray(got, np.float32), np.asarray(want, np.float32), **TOLERANCE[dtype])
+
+
+@pytest.mark.parametrize("dma", ["on_wait", "eager"])
+def test_kernel_bookkeeping_at_heads_of_two_rows(dma, capfd):
+    """The same in the TPU interpreter, into a stack of two layers' pools: chunks, parts and groups as at four cache
+    heads of 128 (the copies know rows, not heads), nothing raced for or left over."""
+    q, k, v, k_rows, v_rows, tables, lens = _two_heads_of_256([0, 1, 255, 257, 520], 16, 16, 40, jnp.float32, seed=3)
+    stack = lambda p: jnp.stack([jnp.full_like(p, jnp.nan), p])  # noqa: E731 - layer 0 is nobody's
+    want = pa.paged_attention_xla(q, k, v, tables, lens)
+    _held_in_the_tpu_interpreter(capfd, dma, want, np.asarray(lens) > 0, q, stack(k_rows), stack(v_rows), tables, lens, layer=jnp.int32(1))
+
+
+def test_kernel_compiles_for_the_chip_over_two_heads_of_256(one_chip, monkeypatch):
+    """``qwen3-next-serve-decode-long``: 128 slots of 16 query heads over 2 cache heads of 256, into the stack of its two
+    attending layers' pools, a position 4 rows of 128 and a block 64: ``falcon-h1``'s geometry, 32 blocks a buffer,
+    multiplied 16 at a time, copied in groups of 8. As the heads they are, ``[.., 16, 2, 256]``, the chip lays a position
+    out as two tiles of two packed rows and re-lays the whole stack in front of the kernel (553 MB a layer)."""
+    monkeypatch.setattr(attn_ops, "TRACED", {})
+    _compiled_into_a_stack(one_chip, (128, 16, 256), (2, 16897, 16, 4, 128), 264)
+    assert _geometry_said(16, 4, 264) == (32, 16, 8)
+
+
+def test_delta_step_kernel_compiles_for_the_chip_in_place(one_chip):
+    """``qwen3-next-serve-decode-long``'s recurrent state: 128 slots' rows of 32 heads x 128 x 128 float32 in a store of
+    six linear layers, moved on by the delta rule where they lie: the store goes in and comes out the same buffer, and
+    nothing of its size is copied in front of or behind the kernel."""
+    from torchx_tpu.models import gdn
+    from torchx_tpu.ops.gdn_step_kernel import gdn_step_pallas
+
+    shape = lambda s, d: jax.ShapeDtypeStruct(s, d, sharding=one_chip)  # noqa: E731
+    store = shape((6, 129, 32, 128, 128), jnp.float32)
+    assert gdn.kernel_eligible(store.shape, 16, "tpu") and not gdn.kernel_eligible((6, 129, 4, 16, 16), 2, "tpu")
+    fn = lambda st, r, d, b, q, k, v, i: gdn_step_pallas(st, r, d, b, q, k, v, layer=i)  # noqa: E731
+    compiled = jax.jit(fn, donate_argnums=(0,)).lower(
+        store, shape((128,), jnp.int32), shape((128, 32), jnp.float32), shape((128, 32), jnp.float32),
+        shape((128, 16, 128), jnp.float32), shape((128, 16, 128), jnp.float32), shape((128, 32, 128), jnp.float32), shape((), jnp.int32)
+    ).compile()  # fmt: skip
+    text, memory = compiled.as_text(), compiled.memory_analysis()
+    assert "gdn_step" in text and "tpu_custom_call" in text
+    assert memory.alias_size_in_bytes >= 6 * 129 * 32 * 128 * 128 * 4 and memory.temp_size_in_bytes < 2**26
+    assert not [ln for ln in text.splitlines() if " copy(" in ln and "f32[6,129," in ln]
+
+
+#: sha256 of the decode kernel's jaxpr (the ``pallas_call`` with its body) at the older cells' shapes as the parent commit
+#: (PR 48's tree, 9c2bd17) traced it: heads of two rows are a static branch, and a pool of whole heads takes none of it
+KERNEL_AT_THE_PARENT = {
+    "falcon-h1": ((64, 20, 128), (6, 8449, 16, 4, 128), 264, 0, jnp.bfloat16, "ef0edc1a61df5245"),
+    "k-exaone.full": ((64, 64, 128), (2, 8449, 16, 8, 128), 264, 0, jnp.bfloat16, "bb8632eb243c218b"),
+    "k-exaone.ring": ((64, 64, 128), (6, 1169, 16, 8, 128), 10, 128, jnp.bfloat16, "e7e18fea8083c46f"),
+    "evabyte": ((32, 32, 128), (8, 4609, 16, 32, 128), 176, 0, jnp.bfloat16, "812528310ad44250"),
+    "float32-pools": ((16, 32, 128), (1, 2049, 16, 8, 128), 256, 0, jnp.float32, "b04df7e7cff38296"),
+}
+
+
+@pytest.mark.parametrize("cell", sorted(KERNEL_AT_THE_PARENT))
+def test_a_pool_of_whole_heads_traces_the_kernel_it_traced_at_the_parent(cell):
+    import hashlib
+
+    q, pool, bpr, window, dtype, want = KERNEL_AT_THE_PARENT[cell]
+    shape = lambda s, d: jax.ShapeDtypeStruct(s, d)  # noqa: E731
+    fn = lambda q_, k, v, t, n, i: pk.paged_attention_pallas(q_, k, v, t, n, layer=i, window=window)  # noqa: E731
+    traced = jax.make_jaxpr(fn)(shape(q, dtype), shape(pool, dtype), shape(pool, dtype), shape((q[0], bpr), jnp.int32),
+                                shape((q[0],), jnp.int32), shape((), jnp.int32))  # fmt: skip
+    assert hashlib.sha256(str(traced).encode()).hexdigest()[:16] == want

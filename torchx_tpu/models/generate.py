@@ -19,7 +19,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from torchx_tpu.models import eva, hyper, llama, mla, ssm
+from torchx_tpu.models import eva, gdn, hyper, llama, mla, ssm
 from torchx_tpu.obs import hot
 from torchx_tpu.ops.attention import note_traced, project_heads as _project_heads
 from torchx_tpu.ops.norms import rms_norm
@@ -35,7 +35,8 @@ from torchx_tpu.ops.rope import apply_rope
 KVCache = dict[str, jnp.ndarray]  # {"k": [L,b,S,kvh,hd], "v": ...}
 # every leaf [L_group, num_blocks, block_size, ...]: {"k", "v"} of [.., kvh, hd],
 # or one latent pool a layer group, {group: [.., cache_width]} (init_kv_pools);
-# beside them under "ssm", where the layers have a mixer, its store a row a slot (ssm.init_store)
+# beside them under "ssm", where the layers have a mixer, its store a row a slot (ssm.init_store); under "gdn"
+# the store of the linear layers (gdn.init_store), which have no blocks in the pools beside it
 KVPools = dict[str, jnp.ndarray]
 
 
@@ -43,9 +44,9 @@ def init_kv_cache(
     cfg: llama.LlamaConfig, batch: int, max_seq: int
 ) -> KVCache:
     """Zeroed [layers, batch, max_seq, kv_heads, head_dim] K/V buffers."""
-    if cfg.kv_lora_rank or cfg.ssm_heads or cfg.eva_window:
+    if cfg.kv_lora_rank or cfg.ssm_heads or cfg.eva_window or cfg.gdn_heads:
         raise NotImplementedError(
-            "latent attention, state-space layers and EVA attention are served through the paged path (ServeEngine), not the dense cache"
+            "latent attention, state-space and linear layers and EVA attention are served through the paged path (ServeEngine), not the dense cache"
         )
     shape = (cfg.n_layers, batch, max_seq, cfg.n_kv_heads, cfg.head_dim)
     return {
@@ -366,9 +367,14 @@ def init_kv_pools(
     blocks over the tree a cache kind at a time. Layers with a state-space mixer
     (``cfg.ssm_heads``) keep its store beside them under ``"ssm"``, ``1 + slots``
     rows a layer (:func:`torchx_tpu.models.ssm.init_store`): addressed by slot,
-    not by block, and no part of what is allocated, copied, exported or imported."""
+    not by block, and no part of what is allocated, copied, exported or imported.
+    Linear layers (``"linear"`` in ``cfg.layer_types``) have no blocks at all: the
+    pools are the attending layers', and the linear layers' store lies beside them
+    under ``"gdn"`` (:func:`torchx_tpu.models.gdn.init_store`), ``1 + slots`` rows each."""
     if cfg.ssm_heads:
         return {**kv_pools(cfg, num_blocks, block_size), "ssm": ssm.init_store(cfg, 1 + slots)}
+    if cfg.gdn_heads:
+        return {**kv_pools(cfg, num_blocks, block_size), "gdn": gdn.init_store(cfg, 1 + slots)}
     return kv_pools(cfg, num_blocks, block_size, num_window_blocks)
 
 
@@ -381,7 +387,7 @@ def kv_pools(cfg: llama.LlamaConfig, num_blocks: int, block_size: int, num_windo
         }
 
     def kv(layers: int, blocks: int) -> KVPools:
-        shape = (layers, blocks, block_size, cfg.n_kv_heads, cfg.head_dim)
+        shape = (layers, blocks, block_size, *cfg.cache_row)
         return {"k": jnp.zeros(shape, dtype=cfg.dtype), "v": jnp.zeros(shape, dtype=cfg.dtype)}
 
     if not cfg.layer_types:
@@ -464,7 +470,8 @@ def _table_of(tables, layer: llama.Params):  # noqa: ANN001, ANN202
     """The block table of ``layer``'s cache kind: one array serves a stack of
     one kind, ``{"full": .., "window": ..}`` a stack that mixes them. Where the
     layers have a state-space mixer the rows' state rows ride beside the one
-    table, ``{"full": .., "state": [rows] int32}`` (:func:`_state_rows`), and
+    table, ``{"full": .., "state": [rows] int32}`` (:func:`_state_rows`; what a
+    linear layer, whose cache kind is ``"state"``, gets as its table), and
     under EVA attention the rows' staging blocks, ``{"full": .., "stage": [rows,
     W / C / block_size]}`` (:meth:`_Rows.pool`)."""
     return tables[layer["attn_kind"]] if isinstance(tables, dict) else tables
@@ -554,12 +561,21 @@ class _Rows(NamedTuple):
         its sequence (position 0) starts from zeros: nothing else resets a row.
         The rows of the store ride beside the block tables (:func:`_state_rows`):
         a decode part's one a slot, a chunk part's one a sequence."""
+        y, store = self._through(ssm, cfg, layer, (xbc, dt), store, at)
+        return (y if self.valid is None else y.reshape(self.rows, -1)), store
+
+    def mix_linear(self, cfg, layer, qkv, b, a, store, at):  # noqa: ANN001, ANN201
+        """The same through a linear layer's Gated DeltaNet mixer (``gdn.project``'s
+        ``qkv``, ``b`` and ``a [rows, ...]``) -> ``(o [rows, H, D], store)``."""
+        o, store = self._through(gdn, cfg, layer, (qkv, b, a), store, at)
+        return (o if self.valid is None else o.reshape(self.rows, *o.shape[-2:])), store
+
+    def _through(self, mixer, cfg, layer, inputs, store, at):  # noqa: ANN001, ANN202
         state_rows = _state_rows(self.tables)
         if self.valid is None:
-            return ssm.decode_rows(cfg, layer, xbc, dt, store, at, state_rows)
+            return mixer.decode_rows(cfg, layer, *inputs, store, at, state_rows)
         fresh = self.positions[:, 0] == 0
-        y, store = ssm.chunk_rows(cfg, layer, self._chunked(xbc), self._chunked(dt), store, at, state_rows, fresh, self.valid)
-        return y.reshape(self.rows, -1), store
+        return mixer.chunk_rows(cfg, layer, *(self._chunked(x) for x in inputs), store, at, state_rows, fresh, self.valid)
 
 
 def _cat(xs: list[jnp.ndarray]) -> jnp.ndarray:
@@ -578,6 +594,7 @@ def _paged_layer_step(
     k_pool: jnp.ndarray,
     v_pool: jnp.ndarray,
     store: Optional[ssm.Store],  # the mixer's store whole (read and written at layer["kind_index"]); None without a mixer
+    # (a linear layer, whose mixer stands in attention's place, comes with its store and no pools)
 ) -> tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray, Optional[ssm.Store]]:
     """One layer over every part's rows at once: norms, projections, the
     output projection, the feed-forward (every row a group of its own: a
@@ -588,15 +605,32 @@ def _paged_layer_step(
     all reads, the parts' blocks being disjoint but for the trash block. A
     state-space mixer runs beside attention off the same norm and into the same
     residual add, each part's rows from and to their own rows of ``store``, the
-    parts' rows being disjoint but for the trash row: a row has one writer."""
+    parts' rows being disjoint but for the trash row: a row has one writer. A
+    linear layer (``layer["attn_kind"] == "state"``) is the one branch: its Gated
+    DeltaNet mixer alone, from and to the store, and no pool is touched."""
     window = llama.window_of(cfg, layer)
     bounds = np.cumsum([0] + [part.rows for part in parts])
     split = lambda a: [a[lo:hi] for lo, hi in zip(bounds, bounds[1:])] if len(parts) > 1 else [a]  # noqa: E731
     tables = [_table_of(part.tables, layer) for part in parts]
 
-    def attend(stream_in):  # noqa: ANN001, ANN202
+    def normed(stream_in):  # noqa: ANN001, ANN202 - what the layer's mixer reads
         with jax.named_scope(hot.NORM):
-            attn_in = rms_norm(stream_in, llama.norm_gain(cfg, layer["attn_norm"]), cfg.norm_eps)
+            return rms_norm(stream_in, llama.norm_gain(cfg, layer["attn_norm"]), cfg.norm_eps)
+
+    def mix_linear(stream_in):  # noqa: ANN001, ANN202
+        attn_in = normed(stream_in)
+        with jax.named_scope(hot.GDN):
+            at = layer.get("kind_index")
+            qkv, z, b, a = gdn.project(cfg, layer, attn_in[:, 0])
+            new_store, read_out = store, []
+            for part, qkv_rows, b_rows, a_rows in zip(parts, split(qkv), split(b), split(a)):
+                o, new_store = part.mix_linear(cfg, layer, qkv_rows, b_rows, a_rows, new_store, at)
+                read_out.append(o)
+            mixed = gdn.finish(cfg, layer, _cat(read_out), z)
+        return mixed[:, None, :], (k_pool, v_pool, new_store)
+
+    def attend(stream_in):  # noqa: ANN001, ANN202
+        attn_in = normed(stream_in)
         with jax.named_scope(hot.ATTN), hot.attn_kind_scope(cfg, layer):
             rows = attn_in[:, 0]  # [rows, d]
             if cfg.kv_lora_rank:  # one latent pool, handed through as k_pool
@@ -615,10 +649,12 @@ def _paged_layer_step(
                 at = layer.get("kind_index")
                 h, kvh, hd = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
                 scaled_rows = llama.scaled(rows, cfg.attention_in_multiplier)
-                q = _project_heads(scaled_rows, layer["wq"], h, hd)
+                q, gate = llama.split_gate(cfg, _project_heads(scaled_rows, layer["wq"], h, cfg.query_width))
                 k = _project_heads(scaled_rows, layer["wk"], kvh, hd)
                 q, k = llama.norm_and_rotate(cfg, layer, q, k, cos, sin, _rope_rows)
                 v = _project_heads(scaled_rows, layer["wv"], kvh, hd)
+                if cfg.cache_row != (kvh, hd):  # a position's heads as the rows they lie as in the pool
+                    k, v = k.reshape(-1, *cfg.cache_row), v.reshape(-1, *cfg.cache_row)
                 k_new, v_new = k_pool, v_pool
                 for part, table, k_rows, v_rows in zip(parts, tables, split(k), split(v)):
                     k_new = part.cache(k_new, table, k_rows, at, bool(window))
@@ -630,10 +666,11 @@ def _paged_layer_step(
                     part.attend(q_rows, k_new, v_new, table, at, window)
                     for part, table, q_rows in zip(parts, tables, split(q))
                 ])  # fmt: skip
+                out = llama.gate_heads(out, gate)
                 pools = (k_new, v_new)
             out = llama.scaled(mm(out.reshape(out.shape[0], 1, -1), layer["wo"]), cfg.attention_out_multiplier)
-        if store is None:
-            return out, (*pools, None)
+        if not cfg.ssm_heads:
+            return out, (*pools, store)
         with jax.named_scope(hot.SSM):
             z, xbc, dt = ssm.project(cfg, layer, rows)
             new_store, read_out = store, []
@@ -643,7 +680,8 @@ def _paged_layer_step(
             mixed = ssm.finish(cfg, layer, _cat(read_out), z)
         return out + mixed[:, None, :], (*pools, new_store)
 
-    x, (k_pool, v_pool, store) = hyper.residual(cfg, layer, "attn", x, attend)
+    mixer = mix_linear if layer.get("attn_kind") == "state" else attend
+    x, (k_pool, v_pool, store) = hyper.residual(cfg, layer, "attn", x, mixer)
     x, _aux = hyper.residual(cfg, layer, "mlp", x, functools.partial(_feed_forward, cfg, layer))
     return x, k_pool, v_pool, store
 
@@ -659,28 +697,33 @@ def _scan_groups(step, x, params: llama.Params, pools: KVPools, cfg: llama.Llama
     ``layer["kind_index"]`` where they lie, so nothing the size of a layer's
     pool is sliced out, copied or stacked back. A latent pool is its group's
     one array (read at ``layer["layer_index"]``) and goes through as ``k_pool``
-    with no ``v_pool``. A mixer's store (``pools["ssm"]``, else None) rides the
-    carry whole beside them. ``ops.attention.traced("kv_pools")`` answers ``carried``."""
+    with no ``v_pool``. A mixer's store (``pools["ssm"]``, or the linear layers'
+    ``pools["gdn"]``, else None) rides the carry whole beside them; a linear layer
+    is handed the store and no pool. ``ops.attention.traced("kv_pools")`` answers ``carried``."""
     note_traced("kv_pools", "carried")
     latent, mixed = bool(cfg.kv_lora_rank), bool(cfg.layer_types)
-    store = pools.get("ssm")
+    stored = next((name for name in ("ssm", "gdn") if name in pools), None)
+    store = pools.get(stored)
     first = 0
     for group in llama.layer_groups(params):
         n = jax.tree.leaves(params[group])[0].shape[0]
         if latent:
             held = {group: (pools[group], None)}
         elif mixed:
-            held = {kind: (pools[kind]["k"], pools[kind]["v"]) for kind in sorted(set(cfg.cache_kinds[first : first + n]))}
+            held = {kind: (pools[kind]["k"], pools[kind]["v"]) for kind in sorted(set(cfg.cache_kinds[first : first + n]) - {"state"})}
         else:
             held = {"full": (pools["k"], pools["v"])}
 
         def scan_step(carry, layer, group=group):  # noqa: ANN001
             x, held, store = carry
             key = group if latent else layer["attn_kind"]
+            if key == "state":  # a linear layer: the store alone
+                x, _, _, store = step(x, layer, None, None, store)
+                return (x, held, store), None
             x, k_pool, v_pool, store = step(x, layer, *held[key], store)
             return (x, {**held, key: (k_pool, v_pool)}, store), None
 
-        (x, held, store), _ = llama.scan_layers(cfg, scan_step, (x, held, store), params[group], first)
+        (x, held, store), _ = llama.scan_layers(cfg, scan_step, (x, held, store), params[group], first, params.get("mixers"))
         if latent:
             pools = {**pools, group: held[group][0]}
         elif mixed:
@@ -688,7 +731,7 @@ def _scan_groups(step, x, params: llama.Params, pools: KVPools, cfg: llama.Llama
         else:
             pools = {**pools, **dict(zip(("k", "v"), held["full"]))}
         first += n
-    return x, pools if store is None else {**pools, "ssm": store}
+    return x, pools if store is None else {**pools, stored: store}
 
 
 @jax.named_scope(hot.LM_HEAD)

@@ -34,7 +34,7 @@ import jax.numpy as jnp
 from jax.ad_checkpoint import checkpoint_name
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from torchx_tpu.models import hyper, ssm
+from torchx_tpu.models import gdn, hyper, ssm
 from torchx_tpu.obs import hot
 from torchx_tpu.parallel import mesh as mesh_lib
 from torchx_tpu.ops.attention import attention, note_traced
@@ -119,10 +119,12 @@ class LlamaConfig:
     qk_rope_dim: int = 0
     v_head_dim: int = 0
     # the attention of each layer, in the order the layers run: "sliding" (query
-    # i admits key j where i - sliding_window < j <= i) or "full" (j <= i).
+    # i admits key j where i - sliding_window < j <= i), "full" (j <= i) or "linear"
+    # (no attention: a Gated DeltaNet mixer, models/gdn.py, in attention's place).
     # Empty: every layer is full. The pattern is data: a stack of mixed kinds
-    # runs in this order (scan_layers), and a sliding layer's cache is a pool of
-    # its own that holds a slot's window and no more (generate.init_kv_pools)
+    # runs in this order (scan_layers), a sliding layer's cache is a pool of
+    # its own that holds a slot's window and no more (generate.init_kv_pools), and
+    # a linear layer keeps no K/V at all: a row of the mixer's store a slot
     layer_types: tuple[str, ...] = ()
     sliding_window: int = 0
     # RMSNorm over each head's width on q and on k, a learned gain each
@@ -186,6 +188,22 @@ class LlamaConfig:
     # output heads side by side in lm_head [dim, pred_heads * vocab_size]: head i predicts the
     # token i + 1 ahead. Serving and the loss read head 0, the first vocab_size columns
     pred_heads: int = 1
+    # the rotary embedding turns the first rotary_dim values of a head, pairs (i, i + rotary_dim / 2), and
+    # leaves the rest as they are; 0: the whole head
+    rotary_dim: int = 0
+    # wq makes [heads, 2 head_dim]: a head's first half its query, its second a gate, and the heads'
+    # output is multiplied by sigmoid(gate) ahead of wo
+    attn_output_gate: bool = False
+    # the Gated DeltaNet mixer of the "linear" layers (models/gdn.py): gdn_heads value heads and
+    # gdn_key_heads key heads of gdn_head_dim each (a key head serves gdn_heads / gdn_key_heads value
+    # heads), a depthwise causal convolution of gdn_conv taps over [q | k | v]; the chunked form solves
+    # inside sub-chunks of gdn_chunk positions. What a sequence carries is a state [heads, head_dim,
+    # head_dim] in float32 and the convolution's last gdn_conv - 1 inputs. 0: no such layers
+    gdn_heads: int = 0
+    gdn_key_heads: int = 0
+    gdn_head_dim: int = 0
+    gdn_conv: int = 4
+    gdn_chunk: int = 64
 
     def __post_init__(self) -> None:
         object.__setattr__(self, "layer_types", tuple(self.layer_types))
@@ -213,13 +231,29 @@ class LlamaConfig:
                     "EVA attention runs through the stock layer ops over grouped-query heads: not beside latent"
                     " attention, sliding layers, a mixer, hyper-connections, QK-norm, the fused or the ring kernels"
                 )
-        if self.norm_unit_offset and (
-            self.kv_lora_rank or self.ssm_heads or self.hc_mult or self.qk_norm or self.kernels != "reference"
-        ):
+        if self.norm_unit_offset and (self.kv_lora_rank or self.ssm_heads or self.hc_mult or self.kernels != "reference"):
             raise ValueError(
-                "unit-offset gains are built for the layer's two norms and the final norm through the stock ops:"
-                " not beside the norms of latent attention, a mixer, hyper-connections, QK-norm or the fused kernels"
+                "unit-offset gains are built for the layer's two norms, the final norm and QK-norm through the stock ops:"
+                " not beside the norms of latent attention, a mixer, hyper-connections or the fused kernels"
             )
+        if self.rotary_dim and (self.rotary_dim % 2 or self.rotary_dim > self.head_dim or self.kv_lora_rank):
+            raise ValueError("rotary_dim is an even part of a grouped-query head")
+        if self.attn_output_gate and (self.kv_lora_rank or self.eva_window or self.kernels != "reference" or self.use_ring_attention):
+            raise ValueError("the output gate is built for grouped-query attention through the stock layer ops")
+        linear = "linear" in self.layer_types
+        if linear != bool(self.gdn_heads):
+            raise ValueError("'linear' layers and gdn_heads go together: a linear layer is a Gated DeltaNet mixer")
+        if linear:
+            if not (self.gdn_key_heads and self.gdn_head_dim) or self.gdn_heads % self.gdn_key_heads or self.gdn_conv < 2:
+                raise ValueError("a linear layer needs gdn_head_dim, gdn_conv >= 2 and gdn_heads a multiple of gdn_key_heads")
+            if (
+                "sliding" in self.layer_types or "full" not in self.layer_types or self.kv_lora_rank or self.ssm_heads
+                or self.hc_mult or self.eva_window or self.kernels != "reference" or self.use_ring_attention
+            ):  # fmt: skip
+                raise ValueError(
+                    "linear layers run between full grouped-query layers through the stock layer ops: not beside sliding"
+                    " layers, latent attention, a Mamba mixer, hyper-connections, EVA attention, the fused or the ring kernels"
+                )
         if self.pred_heads < 1:
             raise ValueError("pred_heads counts the output heads: at least one")
         if self.hc_mult and (self.kernels != "reference" or self.use_ring_attention):
@@ -227,9 +261,9 @@ class LlamaConfig:
         if self.q_lora_rank and not self.kv_lora_rank:
             raise ValueError("q_lora_rank compresses latent attention's query: it needs kv_lora_rank")
         if self.layer_types:
-            if len(self.layer_types) != self.n_layers or set(self.layer_types) - {"sliding", "full"}:
+            if len(self.layer_types) != self.n_layers or set(self.layer_types) - {"sliding", "full", "linear"}:
                 raise ValueError(
-                    f"layer_types must name {self.n_layers} layers 'sliding' or 'full', got {self.layer_types!r}"
+                    f"layer_types must name {self.n_layers} layers 'sliding', 'full' or 'linear', got {self.layer_types!r}"
                 )
             if "sliding" in self.layer_types and self.sliding_window < 1:
                 raise ValueError("a sliding layer needs sliding_window >= 1")
@@ -267,7 +301,17 @@ class LlamaConfig:
     @property
     def rope_dim(self) -> int:
         """Width of what the rotary embedding turns in a head."""
-        return self.qk_rope_dim if self.kv_lora_rank else self.head_dim
+        return self.qk_rope_dim if self.kv_lora_rank else self.rotary_dim or self.head_dim
+
+    @property
+    def query_width(self) -> int:
+        """Columns of ``wq`` a head: its query, and with an output gate the gate beside it."""
+        return self.head_dim * (2 if self.attn_output_gate else 1)
+
+    @property
+    def gdn_conv_width(self) -> int:
+        """What a linear layer's convolution runs over: q, k and v side by side."""
+        return (2 * self.gdn_key_heads + self.gdn_heads) * self.gdn_head_dim
 
     @property
     def attn_scale(self) -> float:
@@ -281,8 +325,10 @@ class LlamaConfig:
     @property
     def cache_kinds(self) -> tuple[str, ...]:
         """The cache kind of each layer: ``"window"`` for a sliding layer (its
-        pool holds the blocks that still touch a slot's window), else ``"full"``."""
-        return tuple("window" if t == "sliding" else "full" for t in self.layer_types) or ("full",) * self.n_layers
+        pool holds the blocks that still touch a slot's window), ``"state"`` for a
+        linear one (no blocks: a row of the mixer's store), else ``"full"``."""
+        kinds = {"sliding": "window", "linear": "state"}
+        return tuple(kinds.get(t, "full") for t in self.layer_types) or ("full",) * self.n_layers
 
     @property
     def layer_period(self) -> int:
@@ -293,6 +339,19 @@ class LlamaConfig:
     def layers_of(self, kind: str) -> int:
         """How many layers keep a cache of ``kind``."""
         return self.cache_kinds.count(kind)
+
+    @property
+    def cache_row(self) -> tuple[int, int]:
+        """``(rows, width)`` of what a position holds of K (and of V) in a layer's paged pool: its
+        cache heads as they are, or, where they are fewer than fill a packed tile (the decode kernel
+        takes 4 or a multiple: ``ops.paged_attention.kernel_eligible``) and a head is several lanes
+        wide, each head as its 128-value parts one after another: 2 heads of 256 lie as 4 rows of
+        128, which is how the chip lays ``[2, 256]`` out anyway, and the kernel then needs no
+        re-laying of the pool in front of it (553 MB a layer at 128 slots: my rehearsal, PR 49)."""
+        kvh, hd = self.n_kv_heads, self.head_dim
+        if kvh % 4 and hd > 128 and hd % 128 == 0 and (kvh * hd // 128) % 4 == 0:
+            return kvh * hd // 128, 128
+        return kvh, hd
 
     @property
     def cache_width(self) -> int:
@@ -319,7 +378,7 @@ class LlamaConfig:
             )
         hd = self.head_dim
         learned = (2 * hd if self.qk_norm else 0) + (2 * self.n_kv_heads * hd if self.eva_window else 0)  # q_norm, k_norm | eva_phi, eva_mu_k
-        return d * h * hd + 2 * d * self.n_kv_heads * hd + h * hd * d + learned
+        return d * h * self.query_width + 2 * d * self.n_kv_heads * hd + h * hd * d + learned
 
     def flops_per_token(self) -> float:
         """Training FLOPs/token (fwd+bwd), 6N + attention quadratic term."""
@@ -337,13 +396,14 @@ class LlamaConfig:
         d, f, v = self.dim, self.ffn_dim, self.vocab_size
         n = self.hc_mult
         per_layer = (
-            self.attention_param_count()
-            + 3 * d * f  # gate, up, down
+            3 * d * f  # gate, up, down
             + 2 * d  # norms
             + (2 * ((n * d + 1) * (2 * n + n * n) + 3) if n else 0)  # hyper-connections: phi, b, a of two sublayers
             + ssm.param_count(self)
         )
-        total = self.n_layers * per_layer + v * d + d  # embed + final norm
+        n_linear = self.layers_of("state")  # a linear layer has a Gated DeltaNet mixer where the others have attention
+        mixers = (self.n_layers - n_linear) * self.attention_param_count() + n_linear * gdn.param_count(self)
+        total = self.n_layers * per_layer + mixers + v * d + d  # embed + final norm
         if not self.tie_embeddings:
             total += d * v * self.pred_heads
         return total
@@ -421,6 +481,7 @@ def init_params(cfg: LlamaConfig, key: jax.Array) -> Params:
         ).astype(cfg.dtype)
 
     ks = jax.random.split(k_layers, 7)
+    n_linear = cfg.layers_of("state")  # layers with a Gated DeltaNet mixer where the others have attention
     if cfg.kv_lora_rank:
         r, dn, dr, dv = cfg.kv_lora_rank, cfg.qk_nope_dim, cfg.qk_rope_dim, cfg.v_head_dim
         rq = cfg.q_lora_rank
@@ -447,20 +508,26 @@ def init_params(cfg: LlamaConfig, key: jax.Array) -> Params:
             "wo": norm_init(ks[3], (L, h * dv, d), h * dv),
         }
     else:
+        La = L - n_linear  # attention's leaves: of the layers that attend
         attn = {
-            "wq": norm_init(ks[0], (L, d, h * hd), d),
-            "wk": norm_init(ks[1], (L, d, kvh * hd), d),
-            "wv": norm_init(ks[2], (L, d, kvh * hd), d),
-            "wo": norm_init(ks[3], (L, h * hd, d), h * hd),
+            "wq": norm_init(ks[0], (La, d, h * cfg.query_width), d),
+            "wk": norm_init(ks[1], (La, d, kvh * hd), d),
+            "wv": norm_init(ks[2], (La, d, kvh * hd), d),
+            "wo": norm_init(ks[3], (La, h * hd, d), h * hd),
         }
+        gain = jnp.zeros if cfg.norm_unit_offset else jnp.ones  # applied as 1 + g: norm_gain
         if cfg.qk_norm:
-            attn["q_norm"] = jnp.ones((L, hd), dtype=cfg.dtype)
-            attn["k_norm"] = jnp.ones((L, hd), dtype=cfg.dtype)
+            attn["q_norm"] = gain((La, hd), dtype=cfg.dtype)
+            attn["k_norm"] = gain((La, hd), dtype=cfg.dtype)
         if cfg.eva_window:  # a clamped normal times hd^-0.5 as published; here a plain normal
             k_phi, k_mu = jax.random.split(jax.random.fold_in(k_layers, 9))
-            attn["eva_phi"] = norm_init(k_phi, (L, kvh, hd), hd)
-            attn["eva_mu_k"] = norm_init(k_mu, (L, kvh, hd), hd)
+            attn["eva_phi"] = norm_init(k_phi, (La, kvh, hd), hd)
+            attn["eva_mu_k"] = norm_init(k_mu, (La, kvh, hd), hd)
     gain = jnp.zeros if cfg.norm_unit_offset else jnp.ones  # applied as 1 + g: norm_gain
+    mixers = {}
+    if n_linear:  # the layers' mixers differ in their leaves: a stack a kind beside the stack of what every layer has
+        mixers = {"mixers": {"full": attn, "state": gdn.init_leaves(cfg, jax.random.fold_in(k_layers, 10), n_linear)}}
+        attn = {}
     params: Params = {
         "embed": norm_init(k_embed, (cfg.vocab_size, d), d),
         "layers": {
@@ -473,6 +540,7 @@ def init_params(cfg: LlamaConfig, key: jax.Array) -> Params:
             **hyper.init_leaves(cfg, jax.random.fold_in(k_layers, 7), L),
             **ssm.init_leaves(cfg, jax.random.fold_in(k_layers, 8), L),
         },
+        **mixers,
         "final_norm": gain((d,), dtype=cfg.dtype),
     }
     if not cfg.tie_embeddings:
@@ -522,6 +590,13 @@ def param_specs(cfg: LlamaConfig, pp: bool = False) -> Params:
         if cfg.eva_window:  # two vectors a cache head: every chip's, read whole
             attn["eva_phi"] = P(layer_axis, None, None)
             attn["eva_mu_k"] = P(layer_axis, None, None)
+    mixers = {}
+    if cfg.layers_of("state"):  # the linear mixer's heads are not split: its matrices shard their model axis
+        state = {
+            name: P(layer_axis, *{"gdn_in": ("fsdp", None), "gdn_ba": ("fsdp", None), "gdn_out": (None, "fsdp")}.get(name, (None,) * len(shape)))
+            for name, shape in gdn.leaf_shapes(cfg).items()
+        }
+        mixers, attn = {"mixers": {"full": attn, "state": state}}, {}
     specs: Params = {
         # vocab axis unsharded: a gather over a vocab-sharded table forces
         # the SPMD partitioner into full rematerialization; dim shards fine
@@ -541,6 +616,7 @@ def param_specs(cfg: LlamaConfig, pp: bool = False) -> Params:
                 for name, shape in ssm.leaf_shapes(cfg).items()
             },
         },
+        **mixers,
         "final_norm": P(None),
     }
     if not cfg.tie_embeddings:
@@ -565,7 +641,7 @@ class _Kind(str):
     goes through ``jax.checkpoint`` and ``lax.scan`` as a tree of arrays."""
 
 
-def scan_layers(cfg: LlamaConfig, step, x, stack: Params, first: int = 0):  # noqa: ANN001, ANN201
+def scan_layers(cfg: LlamaConfig, step, x, stack: Params, first: int = 0, mixers: Optional[Params] = None):  # noqa: ANN001, ANN201
     """Run ``step(x, layer) -> (x, ys)`` over one group's stack of layers in
     the order they lie, ``x`` any carry; ``first`` is the group's first layer's
     number in the model. A layer is its slice of every leaf plus what says
@@ -576,7 +652,10 @@ def scan_layers(cfg: LlamaConfig, step, x, stack: Params, first: int = 0):  # no
     experts of a dropless expert layer, which go in whole beside it, for
     :mod:`torchx_tpu.ops.grouped_matmul`; the paged pools a serving step carries
     in ``x``) would be copied if sliced out of its stack first, and is read at
-    those indices where it lies instead.
+    those indices where it lies instead. Where the layers' mixers differ in their
+    leaves (an attention's, a linear mixer's: ``params["mixers"]``, a stack a cache
+    kind over the layers of that kind) a layer's own mixer is indexed out of its
+    kind's stack at ``kind_index``, as its cache is out of its kind's pools.
 
     Layers of one kind are one ``lax.scan`` with the stack as ``xs``, as it
     always was. Where kinds alternate (``cfg.layer_types``) the scan runs over
@@ -598,9 +677,12 @@ def scan_layers(cfg: LlamaConfig, step, x, stack: Params, first: int = 0):  # no
         return kind, p * kinds[:period].count(kind) + kinds[:k].count(kind)
 
     def run(x, layer, i, kind, kind_index):  # noqa: ANN001, ANN202
-        return step(x, dict(layer, **whole, layer_index=i, attn_kind=kind, kind_index=kind_index))
+        own = {k: jax.lax.dynamic_index_in_dim(w, kind_index, keepdims=False) for k, w in mixers[kind].items()} if mixers else {}
+        return step(x, dict(layer, **own, **whole, layer_index=i, attn_kind=kind, kind_index=kind_index))
 
     if period == 1:
+        if mixers:
+            raise NotImplementedError("a stack a kind of mixers runs under a period of mixed kinds")
 
         def body(x, xs):  # noqa: ANN001, ANN202
             i, layer = xs
@@ -752,11 +834,32 @@ def norm_and_rotate(cfg: LlamaConfig, layer: Params, q, k, cos, sin, rope):  # n
     k = scaled(k, cfg.key_multiplier)
     if cfg.qk_norm:
         with jax.named_scope(hot.QK_NORM):
-            q = rms_norm(q, layer["q_norm"], cfg.norm_eps)
-            k = rms_norm(k, layer["k_norm"], cfg.norm_eps)
+            q = rms_norm(q, norm_gain(cfg, layer["q_norm"]), cfg.norm_eps)
+            k = rms_norm(k, norm_gain(cfg, layer["k_norm"]), cfg.norm_eps)
     if cfg.rope_full_layers or window_of(cfg, layer):
+        if cfg.rotary_dim and cfg.rotary_dim < cfg.head_dim:  # the head's first values turn, the rest pass
+
+            def rope(x, cos, sin, whole=rope, n=cfg.rotary_dim):  # noqa: ANN001, ANN202
+                return jnp.concatenate((whole(x[..., :n], cos, sin), x[..., n:]), axis=-1)
+
         q, k = rope(q, cos, sin), rope(k, cos, sin)
     return q, k
+
+
+def split_gate(cfg: LlamaConfig, q: jnp.ndarray):  # noqa: ANN201
+    """``wq``'s product ``[..., heads, width]`` -> ``(q [..., heads, hd], gate)``: with an output gate a
+    head's first ``hd`` values are its query and its last ``hd`` the gate, else there is none."""
+    if not cfg.attn_output_gate:
+        return q, None
+    return q[..., : cfg.head_dim], q[..., cfg.head_dim :]
+
+
+def gate_heads(out: jnp.ndarray, gate: Optional[jnp.ndarray]) -> jnp.ndarray:
+    """The heads' output times ``sigmoid(gate)`` where attention has an output gate."""
+    if gate is None:
+        return out
+    with jax.named_scope(hot.ATTN_GATE):
+        return (out.astype(jnp.float32) * jax.nn.sigmoid(gate.astype(jnp.float32))).astype(out.dtype)
 
 
 def _gqa_attention(
@@ -771,7 +874,7 @@ def _gqa_attention(
     b, s, _ = attn_in.shape
     h, kvh, hd = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
     i8_attn = cfg.int8_matmuls and cfg.int8_scope == "all"
-    q = maybe_matmul(attn_in, layer["wq"], int8_training=i8_attn).reshape(b, s, h, hd)
+    q, gate = split_gate(cfg, maybe_matmul(attn_in, layer["wq"], int8_training=i8_attn).reshape(b, s, h, -1))
     k = maybe_matmul(attn_in, layer["wk"], int8_training=i8_attn).reshape(b, s, kvh, hd)
     v = maybe_matmul(attn_in, layer["wv"], int8_training=i8_attn).reshape(b, s, kvh, hd)
     window = window_of(cfg, layer)
@@ -817,7 +920,7 @@ def _gqa_attention(
     # named so remat policies can SAVE the kernel output: the attention
     # kernels are not dot_generals, so "dots" alone recomputes the whole
     # flash/splash forward in the backward pass (see "dots_attn")
-    attn_out = checkpoint_name(attn_out, "attn_out")
+    attn_out = gate_heads(checkpoint_name(attn_out, "attn_out"), gate)
     return maybe_matmul(
         attn_out.reshape(b, s, h * hd), layer["wo"], int8_training=i8_attn
     )
@@ -845,6 +948,8 @@ def _layer(
     def attend(stream_in):  # noqa: ANN001, ANN202 - the attention sublayer, its norm included
         with jax.named_scope(hot.NORM):
             attn_in = rms_norm(stream_in, norm_gain(cfg, layer["attn_norm"]), cfg.norm_eps, mesh=mesh)
+        if layer.get("attn_kind") == "state":  # a linear layer: the Gated DeltaNet mixer in attention's place
+            return gdn.forward(cfg, layer, attn_in), None
         with jax.named_scope(hot.ATTN), hot.attn_kind_scope(cfg, layer):
             if cfg.kv_lora_rank:
                 from torchx_tpu.models import mla
@@ -988,7 +1093,7 @@ def features_from_embeddings(
     body = _remat(functools.partial(_layer, cfg, mesh, cos, sin), cfg)
 
     if pp > 1:
-        if "dense_layers" in params or cfg.layer_types or cfg.hc_mult:
+        if "dense_layers" in params or cfg.layer_types or cfg.hc_mult:  # (a stack a kind of mixers comes with layer_types)
             raise NotImplementedError(
                 "pipeline parallelism over a stack that leads with dense layers, mixes attention kinds"
                 " or carries several residual streams"
@@ -1033,7 +1138,7 @@ def features_from_embeddings(
         # the tree has them, then the stack proper
         aux_groups, first = [], 0
         for group in layer_groups(params):
-            x, aux_group = scan_layers(cfg, body, x, params[group], first)
+            x, aux_group = scan_layers(cfg, body, x, params[group], first, params.get("mixers"))
             aux_groups.append(aux_group)
             first += aux_group.shape[0]
         aux_per_layer = jnp.concatenate(aux_groups)

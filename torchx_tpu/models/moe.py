@@ -57,6 +57,8 @@ class MoEConfig(llama.LlamaConfig):
     # shared experts: one SwiGLU of n_shared_experts * expert width that
     # every token takes, added to the routed sum
     n_shared_experts: int = 0
+    # the shared expert's output times sigmoid(x . w_shared_gate), a scalar a token
+    shared_expert_gate: bool = False
     # "softmax": probabilities over the experts, the top_k renormalised.
     # "sigmoid": a score an expert, the top_k of score + router_bias chosen,
     # weighed by their scores alone over their sum, times routed_scale
@@ -80,6 +82,8 @@ class MoEConfig(llama.LlamaConfig):
             self.capacity_factor <= 0 and 0 <= self.experts_held_from <= self.n_experts - self.experts_held
         ):
             raise ValueError("a share of the experts needs the dropless dispatch and a range inside n_experts")
+        if self.shared_expert_gate and not self.n_shared_experts:
+            raise ValueError("shared_expert_gate weighs the shared expert: it needs n_shared_experts")
         if self.router_score not in ("softmax", "sigmoid"):
             raise ValueError(f"router_score must be 'softmax' or 'sigmoid', got {self.router_score!r}")
         if not 0 <= self.n_dense_layers < self.n_layers:
@@ -103,6 +107,7 @@ class MoEConfig(llama.LlamaConfig):
             3 * d * self.expert_width * (experts + self.n_shared_experts)
             + d * self.n_experts  # router
             + (self.n_experts if self.router_bias else 0)
+            + (d if self.shared_expert_gate else 0)
             - 3 * d * self.ffn_dim  # in place of the dense SwiGLU
         )
 
@@ -196,6 +201,8 @@ def init_params(cfg: MoEConfig, key: jax.Array) -> llama.Params:
         layers["ws_gate"] = init(ks[0], (L, d, fs), d)
         layers["ws_up"] = init(ks[1], (L, d, fs), d)
         layers["ws_down"] = init(ks[2], (L, fs, d), fs)
+        if cfg.shared_expert_gate:
+            layers["w_shared_gate"] = init(jax.random.fold_in(k_s, 3), (L, d), d)
     return params
 
 
@@ -221,6 +228,8 @@ def param_specs(cfg: MoEConfig, pp: bool = False) -> llama.Params:
         layers["ws_gate"] = P(layer_axis, "fsdp", "tp")
         layers["ws_up"] = P(layer_axis, "fsdp", "tp")
         layers["ws_down"] = P(layer_axis, "tp", "fsdp")
+        if cfg.shared_expert_gate:
+            layers["w_shared_gate"] = P(layer_axis, None)
     return specs
 
 
@@ -366,7 +375,11 @@ def moe_ffn(
         with jax.named_scope(hot.MOE_SHARED):
             gate = jax.nn.silu(jnp.einsum("bsd,df->bsf", x, layer["ws_gate"]))
             up = jnp.einsum("bsd,df->bsf", x, layer["ws_up"])
-            out = out + jnp.einsum("bsf,fd->bsd", gate * up, layer["ws_down"])
+            shared = jnp.einsum("bsf,fd->bsd", gate * up, layer["ws_down"])
+            if cfg.shared_expert_gate:
+                weight = jax.nn.sigmoid(jnp.einsum("bsd,d->bs", x, layer["w_shared_gate"], preferred_element_type=jnp.float32))
+                shared = (shared.astype(jnp.float32) * weight[..., None]).astype(shared.dtype)
+            out = out + shared
     with jax.named_scope(hot.MOE_ROUTER):  # router health, beside the routing itself
         # load-balancing aux: fraction of top-1 routings per expert x mean
         # router probability per expert (Switch Transformer eq. 4-6)

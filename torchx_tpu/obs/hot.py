@@ -117,7 +117,7 @@ MOE_DISPATCH = "moe_dispatch"
 MOE_EXPERTS = "moe_experts"
 MOE_COMBINE = "moe_combine"
 MOE_SORT = "moe_sort"  # under moe_dispatch, dropless path: sort by expert, count each group, gather rows
-MOE_SHARED = "moe_shared"  # the shared expert beside the routed ones
+MOE_SHARED = "moe_shared"  # the shared expert beside the routed ones, its sigmoid gate included where it has one
 MLA_LATENT = "mla_latent"  # down-projection, latent norm, rotary key
 MLA_ABSORB = "mla_absorb"  # decode: W_kvb folded into the query and out of the result
 MLA_Q_LATENT = "mla_q_latent"  # under attn: the query's own down-projection and its norm (q_lora_rank)
@@ -131,6 +131,13 @@ SSM_CONV = "ssm_conv"  # ... the depthwise causal convolution and its activation
 SSM_STEP = "ssm_step"  # ... one position a row: the rows' state read, moved on, read out and written back
 SSM_SCAN = "ssm_scan"  # ... many positions a row in chunks: the same for a chunk of a prompt or a whole sequence
 SSM_GATE_NORM = "ssm_gate_norm"  # ... the gate and the grouped norm ahead of the output projection
+GDN = "gdn"  # the whole Gated DeltaNet mixer of a linear layer, in attention's place
+GDN_PROJ = "gdn_proj"  # under gdn: its two input projections and its output projection
+GDN_CONV = "gdn_conv"  # ... the depthwise causal convolution and its activation
+GDN_STEP = "gdn_step"  # ... one position a row: the rows' state decayed, corrected by the delta rule, read out, written back
+GDN_CHUNK = "gdn_chunk"  # ... many positions a row: the same for a chunk of a prompt or a whole sequence, solved in sub-chunks
+GDN_GATE_NORM = "gdn_gate_norm"  # ... the norm over each head's read-out and the gate behind it
+ATTN_GATE = "attn_gate"  # under attn: the heads' output times the sigmoid of the gate wq made beside the query
 APPEND_LATENT = "append_latent"
 PAGED_ATTENTION = "paged_attention"
 GATHER_KV = "gather_kv"
@@ -148,6 +155,7 @@ DEVICE_SCOPES = (
     APPEND_KV, SAMPLE, GRAD_CLIP, OPTIMIZER, MOE_SORT, MOE_SHARED, MLA_LATENT, MLA_ABSORB,
     APPEND_LATENT, ATTN_WINDOW, ATTN_FULL, QK_NORM, MLA_Q_LATENT, HC_PRE, HC_SINKHORN, HC_POST, HC_HEAD,
     SSM, SSM_PROJ, SSM_CONV, SSM_STEP, SSM_SCAN, SSM_GATE_NORM, EVA_POOL,
+    GDN, GDN_PROJ, GDN_CONV, GDN_STEP, GDN_CHUNK, GDN_GATE_NORM, ATTN_GATE,
 )  # fmt: skip
 
 

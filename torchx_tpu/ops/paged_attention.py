@@ -110,18 +110,31 @@ def kernel_eligible(
     number of packed sublane rows (4 or a multiple: a block then lies in HBM
     and lands in VMEM as whole ``[bs * kvh, hd]`` rows; the chip's compiler
     takes 8 heads a position and, since PR 41, 4: 20 query heads over 4), a
-    block of whole packed tiles, and bf16 or float32 throughout."""
+    block of whole packed tiles, and bf16 or float32 throughout. Where cache
+    heads are too few for that and several lanes wide, a position's heads lie
+    in the pool as their 128-value parts one after another
+    (``LlamaConfig.cache_row``: 2 heads of 256 as 4 rows of 128) and the rows
+    are what has to be 4 or a multiple; the kernel takes heads of two parts."""
     _, h, hd = q_shape
-    _, bs, kvh, _ = pool_shape
+    _, bs, rows, width = pool_shape
+    parts = hd // width if width and hd % width == 0 else 0  # rows of the pool one cache head lies as
     return (
         backend == "tpu"
-        and hd % 128 == 0
-        and h % kvh == 0
-        and kvh % 4 == 0
+        and width % 128 == 0
+        and parts in (1, 2)
+        and rows % parts == 0
+        and h % (rows // parts) == 0
+        and rows % 4 == 0
         and bs % 8 == 0
         and q_dtype == pool_dtype
         and pool_dtype in (jnp.bfloat16, jnp.float32)
     )
+
+
+def as_heads(kv: jnp.ndarray, hd: int) -> jnp.ndarray:
+    """Gathered rows ``[..., rows, width]`` as the cache heads they are, ``[..., kvh, hd]``: themselves,
+    but where a head lies in the pool as several rows (``LlamaConfig.cache_row``)."""
+    return kv if kv.shape[-1] == hd else kv.reshape(*kv.shape[:-2], -1, hd)
 
 
 @jax.named_scope(hot.PAGED_ATTENTION)
@@ -185,8 +198,8 @@ def paged_attention_xla(
     and the reference the kernel is tested against."""
     slots, h, d = q.shape
     with jax.named_scope(hot.GATHER_KV):
-        k = gather_kv(k_pool, tables, layer)  # [slots, S, kvh, hd]
-        v = gather_kv(v_pool, tables, layer)
+        k = as_heads(gather_kv(k_pool, tables, layer), d)  # [slots, S, kvh, hd]
+        v = as_heads(gather_kv(v_pool, tables, layer), d)
         n_rep = h // k.shape[2]
         if n_rep > 1:
             k = jnp.repeat(k, n_rep, axis=2)
@@ -248,7 +261,7 @@ def paged_attention_chunk(
     """
     note_traced("attention", "paged_walk_window" if window else "paged_walk")
     slots, t, h, d = q.shape
-    kvh, bs, bpr = k_pool.shape[-2], k_pool.shape[-3], tables.shape[1]
+    kvh, bs, bpr = k_pool.shape[-2] * k_pool.shape[-1] // d, k_pool.shape[-3], tables.shape[1]
     step_blocks = max(1, min(bpr, _PREFILL_K_ROWS // bs))
     step_rows = step_blocks * bs
     # whole steps: the blocks past the table are the trash block, past every position
@@ -263,8 +276,8 @@ def paged_attention_chunk(
             m, l, acc = carry
             with jax.named_scope(hot.GATHER_KV):
                 held = jax.lax.dynamic_slice_in_dim(tables, c * step_blocks, step_blocks, axis=1)
-                k = gather_kv(k_pool, held, layer)  # [slots, step_rows, kvh, hd]
-                v = gather_kv(v_pool, held, layer)
+                k = as_heads(gather_kv(k_pool, held, layer), d)  # [slots, step_rows, kvh, hd]
+                v = as_heads(gather_kv(v_pool, held, layer), d)
             with jax.named_scope(hot.SCORES):
                 s = jnp.einsum("bqgrd,bkgd->bgrqk", q_rows, k, preferred_element_type=jnp.float32) * d**-0.5
                 at = c * step_rows + jnp.arange(step_rows)

@@ -59,7 +59,7 @@ def _decode_kernel(
     sems,  # DMA [2 (k, v), 2 (buffer), groups a chunk]
     pos_ref,  # VMEM [h, rows] int32: a column's position in a part, for its head's rows
     buf_ref,  # SMEM [1]: the buffer that holds this slot's first chunk
-    *,
+    *half_ref,  # with halves: VMEM [h, rows] int32, which half of its head's a column holds (else -1)
     bpr: int,
     scale: float,
     window: int,
@@ -67,8 +67,10 @@ def _decode_kernel(
     kvh: int,
     part: int,
     group: int,
+    halves: bool = False,
 ):
-    h, hd = q_ref.shape
+    h = q_ref.shape[0]
+    width = k_buf.shape[-1]  # a row's: a cache head's, or with halves half of one
     block = bs * kvh  # rows of one block
     chunk = k_buf.shape[1] // block
     groups, in_part, rows = chunk // group, part // group, part * block
@@ -127,8 +129,12 @@ def _decode_kernel(
         # written yet must hold numbers.
         v_buf[...] = jnp.zeros_like(v_buf)
         col = jax.lax.broadcasted_iota(jnp.int32, (h, rows), 1)
-        head = jax.lax.broadcasted_iota(jnp.int32, (h, rows), 0) // (h // kvh)
-        pos_ref[...] = jnp.where(col % kvh == head, col // kvh, _NO_POSITION)
+        head = jax.lax.broadcasted_iota(jnp.int32, (h, rows), 0) // (h // (kvh // 2 if halves else kvh))
+        if halves:  # a head's two rows side by side: its score is complete in the second's column
+            half_ref[0][...] = jnp.where(col % kvh // 2 == head, col % 2, -1)
+            pos_ref[...] = jnp.where(col % kvh == 2 * head + 1, col // kvh, _NO_POSITION)
+        else:
+            pos_ref[...] = jnp.where(col % kvh == head, col // kvh, _NO_POSITION)
         buf_ref[0] = 0
         start_groups(0, 0, 0, live_groups(0, 0))
 
@@ -156,9 +162,15 @@ def _decode_kernel(
             m, l, acc = carry
             wait_part(k_buf, 0, k)
             keys = k_buf[cur, pl.ds(k * rows, rows)]
-            s = jax.lax.dot_general(
-                q, keys, (((1,), (1,)), ((), ())), precision=precision, preferred_element_type=jnp.float32
-            ) * scale  # [h, rows]: every query head against every cache head
+            scores = lambda queries: jax.lax.dot_general(  # noqa: E731 - [h, rows]: every query head against every row
+                queries, keys, (((1,), (1,)), ((), ())), precision=precision, preferred_element_type=jnp.float32
+            )
+            if halves:  # each half of a query against the rows that hold that half of its cache head, the pair summed
+                first, second = scores(q[:, :width]), scores(q[:, width:])
+                s = jnp.where(half_ref[0][...] == 0, first, jnp.where(half_ref[0][...] == 1, second, 0.0))
+                s = (s + pltpu.roll(s, 1, 1)) * scale
+            else:
+                s = scores(q) * scale
             base = (c * chunk + k * part) * bs
             if window:
                 base = base + first_block(slot) * bs
@@ -172,6 +184,9 @@ def _decode_kernel(
             wait_part(v_buf, 1, k)
             values = v_buf[cur, pl.ds(k * rows, rows)]
             pv = jnp.dot(p.astype(values.dtype), values, precision=precision, preferred_element_type=jnp.float32)
+            if halves:  # a probability stands in its head's second row's column: moved one down it weighs the first's
+                ahead = pltpu.roll(p, rows - 1, 1).astype(values.dtype)
+                pv = jnp.concatenate((jnp.dot(ahead, values, precision=precision, preferred_element_type=jnp.float32), pv), axis=1)
             return m_new, alpha * l + p.sum(axis=-1, keepdims=True), alpha * acc + pv
 
         return jax.lax.fori_loop(0, pl.cdiv(n_cur, in_part), products, carry)
@@ -183,7 +198,7 @@ def _decode_kernel(
         (
             jnp.full((h, 1), _MASKED, jnp.float32),
             jnp.zeros((h, 1), jnp.float32),
-            jnp.zeros((h, hd), jnp.float32),
+            jnp.zeros(q_ref.shape, jnp.float32),
         ),
     )
     buf_ref[0] = (first_buf + n_chunks) % 2
@@ -244,29 +259,31 @@ def paged_attention_pallas(
     slots, h, hd = q.shape
     if layer is not None:
         tables = tables + layer * k_pool.shape[1]
-    *_, bs, kvh, _ = k_pool.shape
+    *_, bs, kvh, width = k_pool.shape  # the rows a position lies as: its cache heads, or (hd twice the width) their halves
+    halves = hd == 2 * width
     bpr = tables.shape[1]
     span = min(bpr, pl.cdiv(window, bs) + 1) if window else bpr
     block = bs * kvh  # rows of one block
-    # a block as the [block, hd] rows it lies as, every layer's in one pool: reshapes that move nothing
-    k_pool, v_pool = (p.reshape(-1, block, hd) for p in (k_pool, v_pool))
-    chunk, part, group = _geometry(block, hd * k_pool.dtype.itemsize, span)
+    # a block as the [block, width] rows it lies as, every layer's in one pool: reshapes that move nothing
+    k_pool, v_pool = (p.reshape(-1, block, width) for p in (k_pool, v_pool))
+    chunk, part, group = _geometry(block, width * k_pool.dtype.itemsize, span)
     note_traced("paged_geometry", f"chunk {chunk * block} part {part * block} group {group * block} rows, 2 buffers")
     per_slot = pl.BlockSpec((None, h, hd), lambda i, *_: (i, 0, 0))
     in_hbm = pl.BlockSpec(memory_space=pl.ANY)
     return pl.pallas_call(
-        functools.partial(_decode_kernel, bpr=bpr, scale=hd**-0.5, window=window, bs=bs, kvh=kvh, part=part, group=group),
+        functools.partial(_decode_kernel, bpr=bpr, scale=hd**-0.5, window=window, bs=bs, kvh=kvh, part=part, group=group, **({"halves": True} if halves else {})),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2,
             grid=(slots,),
             in_specs=[per_slot, in_hbm, in_hbm],
             out_specs=per_slot,
             scratch_shapes=[
-                pltpu.VMEM((2, chunk * block, hd), k_pool.dtype),
-                pltpu.VMEM((2, chunk * block, hd), v_pool.dtype),
+                pltpu.VMEM((2, chunk * block, width), k_pool.dtype),
+                pltpu.VMEM((2, chunk * block, width), v_pool.dtype),
                 pltpu.SemaphoreType.DMA((2, 2, chunk // group)),
                 pltpu.VMEM((h, part * block), jnp.int32),
                 pltpu.SMEM((1,), jnp.int32),
+                *([pltpu.VMEM((h, part * block), jnp.int32)] if halves else []),
             ],
         ),
         out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),

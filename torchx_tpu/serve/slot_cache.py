@@ -326,13 +326,14 @@ class StateCache(PagedCache):
     program, which is the whole of a reset: for a new tenant, and for a preempted request, fed again from position 0.
     The step in flight behind an EOS moves a row on that nobody reads again. State is not yet cached or handed off."""
 
-    beside = ("ssm",)
+    store = "ssm"  # where the rows lie in the pools
+    beside = (store,)
     no_prefix_cache = "recurrent state: the cache indexes K/V blocks alone, and a hit would hand a request K/V without the state that goes with it"
     no_handoff = "a model with state-space layers is not handed off: a KvPayload carries K/V blocks and not the recurrent state that goes with them"
 
     def __init__(self, cfg: llama.LlamaConfig, **shared) -> None:  # noqa: ANN003
         super().__init__(cfg, **shared)
-        store = jax.tree.leaves(self.pools["ssm"])
+        store = jax.tree.leaves(self.pools[self.store])
         self.state_bytes_per_slot = sum(p.nbytes // p.shape[1] for p in store)
         self.state_bytes = sum(p.nbytes for p in store)
 
@@ -348,6 +349,16 @@ class StateCache(PagedCache):
     def span_attrs(self, held: Iterable[int], reading: Optional[Iterable[int]] = None) -> dict[str, int]:
         # what a slot holds beside its blocks whatever its length; not there without a mixer
         return {**super().span_attrs(held), "state_bytes_per_slot": self.state_bytes_per_slot}
+
+
+class LinearStateCache(StateCache):
+    """The same where some layers are linear (``"linear"`` in ``cfg.layer_types``: a Gated DeltaNet mixer,
+    :mod:`torchx_tpu.models.gdn`, in attention's place): those layers own a row of the store a slot and **no** blocks,
+    so the pool under the slots' one table is the attending layers' alone, and a token's bytes count those. A prefix
+    hit would bring K/V for the attending layers and no state for the linear ones."""
+
+    store = "gdn"
+    beside = (store,)
 
 
 class RowsCache(PagedCache):
@@ -430,6 +441,6 @@ def slot_cache(cfg: llama.LlamaConfig, *, num_window_blocks: Optional[int], max_
         return RowsCache(cfg, tables, **shared)
     if cfg.layers_of("window"):
         return RingCache(cfg, num_window_blocks=num_window_blocks, max_prefill_batch=max_prefill_batch, **shared)
-    if cfg.ssm_heads:
-        return StateCache(cfg, **shared)
+    if cfg.ssm_heads or cfg.layers_of("state"):
+        return (StateCache if cfg.ssm_heads else LinearStateCache)(cfg, **shared)
     return PagedCache(cfg, **shared)
