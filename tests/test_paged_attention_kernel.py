@@ -812,10 +812,11 @@ def test_grouped_matmul_compiles_for_the_chip(one_chip, m, k, n, held, spread):
     assert "tpu_custom_call" in compiled.as_text() and gk.KERNEL_NAME in compiled.as_text()
 
 
-def test_the_train_cells_step_compiles_for_the_chip_with_a_chunks_logits_made_once(one_chip, monkeypatch):
+@pytest.fixture(scope="module")
+def train_cell_step(one_chip):
     """``mistral7b-train-4k``'s step as ``benchmark/rehearse_compile.py`` builds it (the cell's config, splash named,
-    state and batch as shapes): the loss's loop holds no recomputed head matmul (PR 47: the backward loop of a
-    rematerialized scan made every chunk's ``[2, 512, 32768]`` logits again), and the program still fits the chip."""
+    state and batch as shapes), compiled once for the described chip: the program's text, its ``memory_analysis``
+    and what its trace left in ``ops.attention.TRACED``."""
     from jax.sharding import NamedSharding, PartitionSpec as P
 
     from benchmark.lib import models, spec
@@ -834,16 +835,60 @@ def test_the_train_cells_step_compiles_for_the_chip_with_a_chunks_logits_made_on
     optimizer = tl.make_optimizer(lr=job["lr"], warmup=job["warmup"])
     state = on_chip(jax.eval_shape(lambda: tl.init_state(cfg, mesh, optimizer)))
     tokens = on_chip({"tokens": jax.ShapeDtypeStruct((batch, seq + 1), jnp.int32)})
-    monkeypatch.setattr(attn_ops, "TRACED", {})
-    step = tl.make_train_step(cfg, mesh, optimizer, state_shardings=jax.tree.map(lambda x: x.sharding, state))
-    compiled = step.lower(state, tokens).compile()
-    assert attn_ops.traced("loss") == "fused" and attn_ops.traced("attention") == "splash"
-    text = compiled.as_text()
+    with pytest.MonkeyPatch.context() as patch:
+        patch.setattr(attn_ops, "TRACED", {})
+        step = tl.make_train_step(cfg, mesh, optimizer, state_shardings=jax.tree.map(lambda x: x.sharding, state))
+        compiled = step.lower(state, tokens).compile()
+        said = {op: attn_ops.traced(op) for op in ("loss", "attention", "attention_bwd")}
+    return compiled.as_text(), compiled.memory_analysis(), said
+
+
+def test_the_train_cells_step_compiles_for_the_chip_with_a_chunks_logits_made_once(train_cell_step):
+    """The loss's loop holds no recomputed head matmul (PR 47: the backward loop of a rematerialized scan made every
+    chunk's ``[2, 512, 32768]`` logits again), and the program still fits the chip."""
+    text, m, said = train_cell_step
+    assert said["loss"] == "fused" and said["attention"] == "splash"
     assert "lm_head" in text and "rematted_computation/lm_head" not in text
     assert "rematted_computation/mlp" in text  # the layers' recomputation is the file's remat_policy, and stays
-    m = compiled.memory_analysis()
     live = m.argument_size_in_bytes + m.output_size_in_bytes + m.temp_size_in_bytes - m.alias_size_in_bytes
-    assert live <= 13.85 * 2**30  # 13.57 GiB before this form, 13.80 with it (rehearsal, PR 47)
+    assert live <= 13.85 * 2**30  # 13.57 GiB before this form, 13.80 with it (rehearsal, PR 47) and since PR 50
+
+
+def test_the_train_cells_step_makes_attentions_scores_once_in_its_backward(train_cell_step):
+    """Three splash calls a layer where there were four (PR 50): the forward kernel in the forward loop, the same as
+    the file's recomputation in the backward loop, and one ``dkv`` kernel that also writes ``dq``'s partials, one
+    ``[2, 32, 4096, 128]`` a kv block; no ``dq`` kernel walks the block pairs a second time."""
+    text, _, said = train_cell_step
+    assert said["attention_bwd"] == "fused"
+    calls = [line.split(" = ")[0].split()[-1] for line in text.splitlines() if "custom_call_target=\"tpu_custom_call\"" in line]
+    splash = sorted(c.lstrip("%").split(".")[0] for c in calls if "splash_mha" in c)
+    assert splash == ["splash_mha_dkv_no_residuals", "splash_mha_fwd_residuals", "splash_mha_fwd_residuals"], calls
+    partials = 4096 // attn_ops._backward_blocks(4096, 4096, 128, 512, 1024)["block_kv_dkv"]
+    assert partials <= attn_ops._DQ_PARTIALS and f"bf16[2,{partials},32,4096,128]" in text
+
+
+@pytest.mark.parametrize("b,s,h,kv_h,d,window,packed,form", [
+    pytest.param(2, 4096, 32, 8, 128, 0, False, "fused", id="the-train-cell"),
+    pytest.param(2, 4096, 32, 8, 128, 0, True, "fused", id="packed"),
+    pytest.param(2, 4096, 32, 8, 128, 128, False, "fused", id="a-window-of-128"),
+    pytest.param(1, 8192, 32, 8, 128, 0, True, "fused", id="8k-packed-kv-blocks-of-2048"),
+    pytest.param(1, 8192, 16, 4, 256, 0, True, "fused", id="8k-packed-heads-of-256"),
+    pytest.param(1, 8192, 32, 8, 64, 0, False, "fused", id="8k-heads-of-64"),
+    pytest.param(1, 16384, 32, 8, 128, 0, False, "split", id="16k-two-kernels"),
+])  # fmt: skip
+def test_splash_backward_compiles_for_the_chip(one_chip, b, s, h, kv_h, d, window, packed, form, monkeypatch):
+    """The blocks ``ops/attention.py::_backward_blocks`` picks fit the kernel's fast memory at the shapes its rule
+    reaches (PR 50: a q block of 1,024 under kv blocks of 2,048 is refused at heads of 256, so the rule asks ``d``)."""
+    monkeypatch.setattr(attn_ops, "TRACED", {})
+    shape = lambda heads: jax.ShapeDtypeStruct((b, s, heads, d), jnp.bfloat16, sharding=one_chip)  # noqa: E731
+    seg = jax.ShapeDtypeStruct((b, s), jnp.int32, sharding=one_chip)
+
+    def loss(q, k, v, seg):  # noqa: ANN001, ANN202
+        return attn_ops.splash_attention(q, k, v, window=window, segment_ids=seg if packed else None).astype(jnp.float32).sum()
+
+    text = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(shape(h), shape(kv_h), shape(kv_h), seg).compile().as_text()
+    assert attn_ops.traced("attention_bwd") == form
+    assert ("splash_mha_dq" in text or "splash_mha_segmented_dq" in text) == (form == "split") and "dkv" in text
 
 
 # The step that carries a chunk of a prompt (PR 40), at the five serving cells' widths and slots (their layers

@@ -198,6 +198,35 @@ def pallas_attention(
     return out.transpose(0, 2, 1, 3)
 
 
+#: the most ``dq`` partials the fused backward may write: one ``q``-sized array a kv block, so this many times
+#: ``q``'s bytes, transient, a call (256 MiB a layer at ``mistral7b-train-4k``'s 2 x 4096 x 32 x 128)
+_DQ_PARTIALS = 4
+#: the widest kv block the fused backward copies: ``k``, ``v``, ``dk``, ``dv`` and the float32 sums of the last two
+#: at this many rows of 128 are 6 MiB of the kernel's 16 MiB of fast memory, beside the score tiles
+_BWD_BLOCK_KV = 2048
+#: the fused backward's q block at heads of 128 or narrower: half the grid steps of 512 rows, 7% off a call at the
+#: train cell's shape (PERF.md section 6, PR 50); at heads of 256 under kv blocks of 2,048 Mosaic refuses it
+_BWD_BLOCK_Q = 1024
+
+
+def _backward_blocks(s_q: int, s_k: int, d: int, bq: int, bkv: int) -> dict:
+    """The splash backward's blocks and form, from the sequence lengths and the head width under forward blocks
+    ``bq`` x ``bkv``: the ``BlockSizes`` fields beside the forward's.
+
+    Fused (one walk over the block pairs makes ``dk``, ``dv`` and ``dq`` from one ``S``, one ``P``, one ``dP`` a
+    pair, and writes one ``dq`` partial a kv block that is summed behind the call) while the partials stay at
+    :data:`_DQ_PARTIALS` times ``q`` or fewer: the kv block the backward copies is the narrowest multiple of
+    ``bkv`` that divides ``s_k`` and leaves that few blocks, up to :data:`_BWD_BLOCK_KV` rows (the scores are still
+    made ``bkv`` rows at a time), its q block :data:`_BWD_BLOCK_Q` rows where they divide ``s_q``. Past that (over
+    8 k keys, or a length only narrow blocks divide) the two kernels at the forward's blocks, each of which makes
+    the scores again and neither of which writes anything but its gradients."""
+    for blk in range(bkv, min(_BWD_BLOCK_KV, s_k) + 1, bkv):
+        if s_k % blk == 0 and s_k // blk <= _DQ_PARTIALS:
+            wide = max(bq, _fit_block(_BWD_BLOCK_Q, s_q)) if d <= 128 else bq
+            return dict(block_q_dkv=wide, block_kv_dkv=blk, block_kv_dkv_compute=bkv, use_fused_bwd_kernel=True)
+    return dict(block_q_dkv=bq, block_kv_dkv=bkv, block_kv_dkv_compute=bkv, block_q_dq=bq, block_kv_dq=bkv)
+
+
 def splash_attention(
     q: jnp.ndarray,
     k: jnp.ndarray,
@@ -246,20 +275,13 @@ def splash_attention(
     else:
         one_head = CausalMask((s_q, s_k)) if causal else FullMask((s_q, s_k))
     mask = MultiHeadMask([one_head] * h)
+    backward = _backward_blocks(s_q, s_k, d, bq, bkv)
+    note_traced("attention_bwd", "fused" if backward.get("use_fused_bwd_kernel") else "split")
     kernel = make_splash_mha(
         mask,
         head_shards=1,
         q_seq_shards=1,
-        block_sizes=BlockSizes(
-            block_q=bq,
-            block_kv=bkv,
-            block_kv_compute=bkv,
-            block_q_dkv=bq,
-            block_kv_dkv=bkv,
-            block_kv_dkv_compute=bkv,
-            block_q_dq=bq,
-            block_kv_dq=bkv,
-        ),
+        block_sizes=BlockSizes(block_q=bq, block_kv=bkv, block_kv_compute=bkv, **backward),
         interpret=interpret,
     )
     seg = None
