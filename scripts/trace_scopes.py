@@ -1,6 +1,6 @@
 """Device time of a cell's last traced run by program and by scope, as JSON.
 
-    python3 benchmark/run.py --workload <cell> --seed 1 --seconds 45 --trace 1; python3 scripts/trace_scopes.py <cell | file.xplane.pb>
+    python3 benchmark/run.py --workload <cell> --seed 1 --seconds 45 --trace 1; python3 scripts/trace_scopes.py <cell | file.xplane.pb> [--ops REGEX]
 
 Reads the ``.xplane.pb`` the harness left under ``.bench_scratch/<cell>/trace`` with the
 benchmark's own reader (``benchmark/lib/scopes.py``): for every compiled program of the
@@ -9,13 +9,17 @@ trace its runs, its median run and the milliseconds a run spends under each devi
 that took longest; and for each of the program's outermost loops (a training step's layers
 forward, its loss, its layers backward: the ``while`` events no other ``while`` holds, in the
 order they run) the milliseconds a run spends in it, by scope and in its fourteen longest
-operations. For PERF.md's "where the time goes"; no run of the benchmark calls it.
+operations. With ``--ops REGEX`` every operation of a program whose instruction name the expression
+finds is listed too, ms a run beside its scope path (``--ops .`` lists them all: the names are those of
+``compiled.as_text()``, which ``scripts/rehearse_train_step.py`` prints the moves of). For PERF.md's
+"where the time goes"; no run of the benchmark calls it.
 """
 
 from __future__ import annotations
 
 import json
 import os
+import re
 import statistics
 import sys
 
@@ -28,6 +32,16 @@ def top_ops(ops: list[tuple[float, list[str], str]], n: int, per_run: float) -> 
     for d, _, short in ops:
         by_op[short] = by_op.get(short, 0.0) + d
     return {k: round(v * per_run, 3) for k, v in sorted(by_op.items(), key=lambda kv: -kv[1])[:n]}
+
+
+def named_ops(ops: list[tuple[float, list[str], str]], wanted: re.Pattern, per_run: float) -> dict[str, list]:
+    """Every instruction of ``ops`` whose name ``wanted`` finds: ``[ms a run, scope path]``, by name."""
+    found: dict[str, list] = {}
+    for d, parts, short in ops:
+        if wanted.search(short):
+            entry = found.setdefault(short, [0.0, "/".join(parts)])
+            entry[0] += d * per_run
+    return {k: [round(ms, 4), path] for k, (ms, path) in sorted(found.items())}
 
 
 def loops(planes: list[dict], module: str, device_scopes) -> list[dict]:  # noqa: ANN001
@@ -64,6 +78,7 @@ def main() -> int:
     from benchmark.lib import scopes, spec, trace
     from torchx_tpu.obs import hot
 
+    wanted = re.compile(sys.argv[sys.argv.index("--ops") + 1]) if "--ops" in sys.argv else None
     path = sys.argv[1] if sys.argv[1].endswith(".pb") else trace.find_xplane(os.path.join(spec.REPO_ROOT, ".bench_scratch", sys.argv[1], "trace"))
     planes = scopes.read_planes(path)
     runs: dict[str, list[float]] = {}
@@ -84,6 +99,8 @@ def main() -> int:
             "ms_a_run_top_ops": top_ops(ops, 10, per_run),
             "loops": loops(planes, module, hot.DEVICE_SCOPES),
         }  # fmt: skip
+        if wanted is not None:
+            out[module]["ms_a_run_ops"] = named_ops(ops, wanted, per_run)
     print(json.dumps(out))
     return 0
 

@@ -450,12 +450,14 @@ OLDER = {
 }  # fmt: skip
 #: sha256 of the jaxprs below as the parent commit (PR 42's tree, 93ffc25) traced them: the defaults of the fields this
 #: PR added leave a model without them alone. A PR that changes what these programs compute on purpose records its own.
+#: PR 51 did, for the uncached forward of the grouped-query kinds alone (whole-head rotation, ``tests/test_rope_whole.py``);
+#: every serving step's digest is the parent's.
 AT_THE_PARENT = {
-    "llama": ("0d238167015cc164", "e1b66726a2a720a0"),
-    "mixer": ("923e4ec10b0095e5", "5169375dde80b4dd"),
+    "llama": ("0d238167015cc164", "49b8059943fd7a24"),
+    "mixer": ("923e4ec10b0095e5", "85ad609ce1ecd246"),
     "mla_moe_hc": ("a6091627457f9312", "482c3837461f078d"),
-    "moe": ("95d1f827f13bf2e5", "293dcf54b95a4444"),
-    "sliding_qk_norm": ("178965a15efc42ed", "daf10c0a9b237f47"),
+    "moe": ("95d1f827f13bf2e5", "a4cba197c5f6b94c"),
+    "sliding_qk_norm": ("178965a15efc42ed", "a7e55fa8d2330bd8"),
 }
 
 

@@ -419,11 +419,13 @@ OLDER = {
 }  # fmt: skip
 #: sha256 of the jaxprs below as the commit before the mixer traced them (PR 40's tree,
 #: 8e40f2b): the defaults of the fields PR 41 added leave a model without them alone. A PR
-#: that changes what these programs compute on purpose records its own, with :func:`_digests`.
+#: that changes what these programs compute on purpose records its own, with :func:`_digests`. PR 51 did, for the
+#: uncached forward of the three grouped-query kinds alone (``_gqa_attention`` rotates whole heads, ``ops/rope.py::
+#: apply_rope_whole``: the values are ``apply_rope``'s to the bit, ``tests/test_rope_whole.py``); every serving step's is the parent's.
 BEFORE_THE_MIXER = {
-    "llama": ("0d238167015cc164", "e1b66726a2a720a0"),
-    "moe": ("95d1f827f13bf2e5", "293dcf54b95a4444"),
-    "sliding_qk_norm": ("178965a15efc42ed", "daf10c0a9b237f47"),
+    "llama": ("0d238167015cc164", "49b8059943fd7a24"),
+    "moe": ("95d1f827f13bf2e5", "a4cba197c5f6b94c"),
+    "sliding_qk_norm": ("178965a15efc42ed", "a7e55fa8d2330bd8"),
     "mla_moe_hc": ("a6091627457f9312", "482c3837461f078d"),
 }
 

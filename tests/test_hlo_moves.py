@@ -15,7 +15,7 @@ from __future__ import annotations
 
 import pytest
 
-from torchx_tpu.obs.hlo import loop_moves, program_moves
+from torchx_tpu.obs.hlo import loop_moves, moves_by_loop, program_moves
 
 MIB = 2**20
 
@@ -163,3 +163,38 @@ def test_it_reads_the_whole_program_where_loop_moves_reads_the_loops():
 def test_a_program_with_nothing_to_read_moves_nothing():
     assert program_moves("", 1) == []
     assert program_moves("HloModule empty\n", 1) == []
+    assert moves_by_loop("HloModule empty\n", 1) == {}
+
+
+def test_the_moves_are_shared_out_among_the_loops_with_what_each_writes_a_turn():
+    """``moves_by_loop`` (PR 51): the same instructions, under the loop whose body runs them (named by the ``while``'s
+    ``op_name``, the body's name where the text has none) with the turns its condition counts to, the rest under
+    ``entry``; a fusion with several outputs by all it writes, where the threshold reads its largest."""
+    by_loop = moves_by_loop(HLO, 12 * MIB)
+    assert set(by_loop) == {"body", "entry"}
+    assert by_loop["body"] == {"turns": 16, "moves": {"constant_dynamic-slice_fusion.6": 32 * MIB, "copy.41": 32 * MIB}}
+    assert by_loop["entry"]["turns"] == 1
+    assert by_loop["entry"]["moves"] == {"copy.224": 96 * MIB, "slice_bitcast_fusion": 192 * MIB, "transpose.225": 12 * MIB}
+    flat = sorted(inst for found in by_loop.values() for inst in found["moves"])
+    assert flat == sorted(_names(program_moves(HLO, 12 * MIB)))
+    named = HLO.replace("condition=%cond, body=%body", 'condition=%cond, body=%body, metadata={op_name="jit(step)/jvp(layers)/while"}')
+    assert set(moves_by_loop(named, 12 * MIB)) == {"jit(step)/jvp(layers)/while", "entry"}
+
+
+
+def test_the_rehearsal_script_prints_each_loops_moves_and_the_steps_total(capsys):
+    """``scripts/rehearse_train_step.py::print_moves`` over the same text: a line a loop with MiB a turn, its turns
+    and GiB a step, a line a move, and the step's total with what it would cost at the wire."""
+    import importlib.util
+    import os
+
+    path = os.path.join(os.path.dirname(os.path.dirname(os.path.abspath(__file__))), "scripts", "rehearse_train_step.py")
+    spec = importlib.util.spec_from_file_location("rehearse_train_step", path)
+    script = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(script)
+    script.print_moves(HLO, 12 * MIB)
+    out = capsys.readouterr().out.splitlines()
+    assert "  body: 64 MiB written a turn, 16 turns, 1.00 GiB a step" in out
+    assert "  entry: 300 MiB written a turn, 1 turns, 0.29 GiB a step" in out
+    assert any(line.split()[:2] == ["32", "MiB"] and "copy.41" in line for line in out)
+    assert out[-1].startswith("  in all: 1.29 GiB written a step and as much read, 3.4 ms at 819 GB/s")

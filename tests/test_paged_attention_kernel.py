@@ -15,6 +15,7 @@ from __future__ import annotations
 import functools
 import importlib
 import math
+import re
 
 import jax
 import jax.numpy as jnp
@@ -839,7 +840,7 @@ def train_cell_step(one_chip):
         patch.setattr(attn_ops, "TRACED", {})
         step = tl.make_train_step(cfg, mesh, optimizer, state_shardings=jax.tree.map(lambda x: x.sharding, state))
         compiled = step.lower(state, tokens).compile()
-        said = {op: attn_ops.traced(op) for op in ("loss", "attention", "attention_bwd")}
+        said = {op: attn_ops.traced(op) for op in ("loss", "attention", "attention_bwd", "remat", "rotation")}
     return compiled.as_text(), compiled.memory_analysis(), said
 
 
@@ -865,6 +866,59 @@ def test_the_train_cells_step_makes_attentions_scores_once_in_its_backward(train
     assert splash == ["splash_mha_dkv_no_residuals", "splash_mha_fwd_residuals", "splash_mha_fwd_residuals"], calls
     partials = 4096 // attn_ops._backward_blocks(4096, 4096, 128, 512, 1024)["block_kv_dkv"]
     assert partials <= attn_ops._DQ_PARTIALS and f"bf16[2,{partials},32,4096,128]" in text
+
+
+#: What the layers' two loops of ``mistral7b-train-4k``'s compiled step still write a turn without computing on it, 8 MiB
+#: or more a move (PR 51; ``scripts/rehearse_train_step.py`` prints them by name): ``array{layout}: (how many, why it
+#: stays)``. The parent's loops moved 160 and 1,040 MiB a turn: ``jax.checkpoint``'s CSE guard, an optimization barrier
+#: round everything the recomputation reads, made a buffer of each of its operands (a layer's seven weights out of their
+#: stacks, 416 MiB; rope's float32 halves and their copies, 320) and kept the two sides of it from fusing. The rotation
+#: itself splits no head (``ops/rope.py::apply_rope_whole``), so no float32 half is among them under either form.
+LAYER_LOOP_MOVES = {
+    "jit(step)/jvp(layers)/while": {  # 160 MiB a turn, as the parent's
+        "bf16[1,4096,4096]{2,1,0}": (1, "wq out of its stack: its product is split into heads, which the chip runs as a convolution"),
+        "bf16[1,4096,4096]{1,2,0}": (1, "... that wants the weight as [heads, hd, d]: PR 32's barrier cures it and un-fuses rope (576 MiB)"),
+        "bf16[1,4096,1024]{2,1,0}": (2, "wk, wv: the same"),
+        "bf16[1,4096,1024]{1,2,0}": (2, "wk, wv: the same"),
+        "bf16[2,4096,32,128]{1,3,2,0}": (1, "the kernel writes [b, h, s, d], wo's matmul reads the sequence minor-most"),
+    },
+    "jit(step)/transpose(jvp(layers))/while": {  # 320 MiB a turn, of the parent's 1,040
+        "bf16[1,4096,4096]{1,2,0}": (1, "wq's slice, re-laid for the recomputed projection"),
+        "bf16[1,4096,4096]{2,1,0}": (1, "... and back for the gradient's"),
+        "bf16[4096,4096]{1,0}": (1, "wq for dx: prefetched into fast memory, 4,400 cycles by the compiler's estimate"),
+        "bf16[4096,4096]{0,1}": (1, "wq transposed, one user"),
+        "bf16[1024,4096]{1,0}": (2, "wk, wv: as wq"),
+        "bf16[1024,4096]{0,1}": (2, "wk, wv: as wq"),
+        "bf16[2,4096,32,128]{1,3,2,0}": (2, "the recomputed kernel's output to wo's matmul; dq back from the kernel's [b, h, s, d]"),
+        "bf16[2,4096,8,128]{1,3,2,0}": (2, "dk, dv back from the kernel's layout"),
+    },
+}
+
+
+def test_the_train_cells_layer_loops_move_what_is_listed_and_no_more(train_cell_step):
+    """The layers' rematerialization runs without ``jax.checkpoint``'s CSE guard where a layer is a scan's turn
+    (``models/llama.py::_remat``, PR 51) and q and k are rotated as whole heads, and the compiled step's two layer
+    loops write 160 + 320 MiB a turn that nothing multiplies where the parent's wrote 160 + 1,040: every survivor
+    is on the list with its reason, none of them a float32 half of a head."""
+    from torchx_tpu.obs.hlo import instruction_lines, moves_by_loop
+
+    text, m, said = train_cell_step
+    assert said["remat"] == "in_loop" and said["rotation"] == "whole_heads"
+    assert "rematted_computation/mlp" in text and "rematted_computation/attn" in text  # still recomputed: the file's policy
+    lines = instruction_lines(text)
+    by_loop = moves_by_loop(text, 8 * 2**20)
+    for loop, allowed in LAYER_LOOP_MOVES.items():
+        assert by_loop[loop]["turns"] == 5
+        moved: dict[str, int] = {}
+        for inst in by_loop[loop]["moves"]:
+            array = re.sub(r":[^}]*\}", "}", lines[inst].split(" = ")[1].split(" ")[0])  # the tiling and memory space off
+            moved[array] = moved.get(array, 0) + 1
+        assert moved == {array: count for array, (count, _) in allowed.items()}, (loop, moved)
+        assert not [array for array in moved if array.startswith("f32")]
+    a_turn = {loop: sum(found["moves"].values()) / 2**20 for loop, found in by_loop.items()}
+    assert a_turn["jit(step)/jvp(layers)/while"] == 160 and a_turn["jit(step)/transpose(jvp(layers))/while"] == 320
+    live = m.argument_size_in_bytes + m.output_size_in_bytes + m.temp_size_in_bytes - m.alias_size_in_bytes
+    assert live <= 13.30 * 2**30  # 13.26 GiB (rehearsal, PR 51): 0.54 under the parent's, nothing is kept that was recomputed
 
 
 @pytest.mark.parametrize("b,s,h,kv_h,d,window,packed,form", [

@@ -41,7 +41,7 @@ from torchx_tpu.ops.attention import attention, note_traced
 from torchx_tpu.ops.norms import rms_norm
 from torchx_tpu.ops.quant import maybe_matmul
 from torchx_tpu.ops.ring_attention import ring_attention
-from torchx_tpu.ops.rope import YarnScaling, apply_rope, rope_frequencies
+from torchx_tpu.ops.rope import YarnScaling, apply_rope_whole, rope_frequencies
 
 Params = dict[str, Any]
 
@@ -878,7 +878,7 @@ def _gqa_attention(
     k = maybe_matmul(attn_in, layer["wk"], int8_training=i8_attn).reshape(b, s, kvh, hd)
     v = maybe_matmul(attn_in, layer["wv"], int8_training=i8_attn).reshape(b, s, kvh, hd)
     window = window_of(cfg, layer)
-    q, k = norm_and_rotate(cfg, layer, q, k, cos, sin, apply_rope)
+    q, k = norm_and_rotate(cfg, layer, q, k, cos, sin, apply_rope_whole)
     if window and (cfg.kernels != "reference" or cfg.use_ring_attention):
         raise NotImplementedError("a sliding layer runs through ops.attention only, not the fused or ring kernels")
     if cfg.eva_window:
@@ -992,7 +992,16 @@ def _layer(
     return _constraint(x, mesh, ("dp", "fsdp"), "sp", None), aux
 
 
-def _remat(body, cfg: LlamaConfig):  # noqa: ANN001
+def _remat(body, cfg: LlamaConfig, looped: bool = False):  # noqa: ANN001
+    """``body`` under the configuration's rematerialization. ``looped`` says that every call of it is the body
+    of a ``lax.scan`` of two turns or more: differentiation then puts the forward and its recomputation into
+    two loops, which no common-subexpression pass can merge, and ``jax.checkpoint``'s own guard against that
+    merge (``prevent_cse``: an optimization barrier round everything the recomputation reads) only costs. On
+    the chip's compiler the barrier makes a buffer of each of its operands, so a layer's seven weights were
+    sliced out of their stacks and written again every backward turn, and nothing on either side of it fused
+    with the other: 1,040 MiB of pure movement a layer in ``mistral7b-train-4k``'s backward loop, 320 without
+    (PERF.md section 6, PR 51; ``obs.hlo.moves_by_loop``). A layer that runs outside a loop, or in a loop of
+    one turn, which the compiler unrolls, keeps the guard."""
     if not cfg.remat:
         return body
     if cfg.remat_policy == "auto":
@@ -1004,23 +1013,21 @@ def _remat(body, cfg: LlamaConfig):  # noqa: ANN001
             "call torchx_tpu.parallel.remat_auto.choose_remat_policy"
             " (the trainer does this at launch)"
         )
+    note_traced("remat", "in_loop" if looped else "guarded")
     if cfg.remat_policy == "dots":
-        return jax.checkpoint(
-            body, policy=jax.checkpoint_policies.dots_with_no_batch_dims_saveable
-        )
-    if cfg.remat_policy == "dots_attn":
+        policy = jax.checkpoint_policies.dots_with_no_batch_dims_saveable
+    elif cfg.remat_policy == "dots_attn":
         # dots + the named attention-kernel outputs: flash/splash are pallas
         # calls, not dot_generals, so plain "dots" recomputes the whole
         # attention forward in the backward; saving [b, s, h, d] bf16 per
         # layer (~17 MB/layer at 1B shapes) skips that recompute
-        return jax.checkpoint(
-            body,
-            policy=jax.checkpoint_policies.save_from_both_policies(
-                jax.checkpoint_policies.dots_with_no_batch_dims_saveable,
-                jax.checkpoint_policies.save_only_these_names("attn_out"),
-            ),
+        policy = jax.checkpoint_policies.save_from_both_policies(
+            jax.checkpoint_policies.dots_with_no_batch_dims_saveable,
+            jax.checkpoint_policies.save_only_these_names("attn_out"),
         )
-    return jax.checkpoint(body)
+    else:
+        policy = None
+    return jax.checkpoint(body, policy=policy, prevent_cse=not looped)
 
 
 def forward_features(
@@ -1090,7 +1097,9 @@ def features_from_embeddings(
     else:
         cos, sin = rope_table(cfg, s)
 
-    body = _remat(functools.partial(_layer, cfg, mesh, cos, sin), cfg)
+    # every layer a turn of a scan of its group (one kind of layer, no pipeline), and no group of one layer
+    looped = pp == 1 and cfg.layer_period == 1 and all(jax.tree.leaves(params[g])[0].shape[0] > 1 for g in layer_groups(params))
+    body = _remat(functools.partial(_layer, cfg, mesh, cos, sin), cfg, looped)
 
     if pp > 1:
         if "dense_layers" in params or cfg.layer_types or cfg.hc_mult:  # (a stack a kind of mixers comes with layer_types)

@@ -7,7 +7,10 @@ in the jaxpr. :func:`loop_moves` is the check the serving programs are held to
 (``tests/test_pools_in_carry.py``, ``scripts/rehearse_serve_cell.py``): no
 pass over a layer's paged pool inside the layer loop. :func:`program_moves`
 reads the whole program, loops or none: no layer's attention projection re-laid
-in front of its matmul (``tests/test_paged_attention_kernel.py``).
+in front of its matmul (``tests/test_paged_attention_kernel.py``);
+:func:`moves_by_loop` shares those out among the program's outermost loops with
+what each writes a turn (``scripts/rehearse_train_step.py``: the training
+step's two layer loops).
 """
 
 from __future__ import annotations
@@ -22,13 +25,18 @@ _CALLED = re.compile(r"(?:calls|to_apply|body|condition)=%?([\w.\-]+)|branch_com
 _MOVES = ("copy", "copy-start", "dynamic-slice", "dynamic-update-slice")
 
 
-def _bytes(type_text: str) -> int:
-    """The largest array of an HLO type (a tuple's largest element)."""
-    sizes = [0]
+def _sizes(type_text: str) -> list[int]:
+    """Bytes of each array of an HLO type (a tuple's elements)."""
+    sizes = []
     for dtype, bits, dims in _ARRAY.findall(type_text):
         item = 1 if dtype == "pred" else int(bits or 8) // 8
         sizes.append(item * math.prod(int(d) for d in dims.split(",") if d))
-    return max(sizes)
+    return sizes
+
+
+def _bytes(type_text: str) -> int:
+    """The largest array of an HLO type (a tuple's largest element)."""
+    return max(_sizes(type_text), default=0)
 
 
 def _split(rest: str) -> tuple[str, str, str]:
@@ -147,3 +155,52 @@ def program_moves(hlo_text: str, at_least_bytes: int) -> list[str]:
             if opcode in _PURE_MOVES or (opcode == "fusion" and all(map(only_moves, _FUSED.findall(rest)))):
                 found.append(f"{name}: {inst} = {type_text} {opcode}")
     return sorted(found)
+
+
+_OP_NAME = re.compile(r'op_name="([^"]*)"')
+
+
+def instruction_lines(hlo_text: str) -> dict[str, str]:
+    """instruction name -> its whole line (type, layout, ``op_name``, the backend's ``estimated_cycles``): what a
+    reader of :func:`moves_by_loop` prints or compares beside a move's name."""
+    return {m.group(1): line for line in hlo_text.splitlines() if (m := _INSTRUCTION.match(line))}
+
+
+def moves_by_loop(hlo_text: str, at_least_bytes: int) -> dict[str, dict]:
+    """:func:`program_moves` by where they run: ``{loop: {"turns": n, "moves": {instruction: bytes written, every array of a tuple}}}``,
+    a loop named by the ``op_name`` of its ``while`` (``jit(step)/jvp(layers)/while`` is a scan over ``layers``
+    under differentiation, ``.../transpose(jvp(layers))/while`` its backward), whatever runs in no loop under
+    ``"entry"`` with one turn. A move inside a loop that a loop's body runs counts under the outer one, once a
+    turn of the outer. ``turns`` is the integer the loop's condition compares against (a scan's length), None
+    where it holds no single such constant."""
+    computations = _parse(hlo_text)
+    owner: dict[str, str] = {}
+    turns: dict[str, int | None] = {"entry": 1}
+
+    def claim(name: str, loop: str) -> None:
+        if name in owner or name not in computations:
+            return
+        owner[name] = loop
+        for *_, rest in computations[name].values():
+            for a, b in _CALLED.findall(rest):
+                for c in filter(None, [a, *re.findall(r"[\w.\-]+", b)]):
+                    claim(c, loop)
+
+    # the loops the entry computation runs, each with everything its body calls; then the entry's own
+    for name in re.findall(r"^ENTRY %?([\w.\-]+) ", hlo_text, re.M):
+        for *_, opcode, _, rest in computations.get(name, {}).values():
+            body, cond = re.search(r"body=%?([\w.\-]+)", rest), re.search(r"condition=%?([\w.\-]+)", rest)
+            if opcode == "while" and body and cond:
+                loop = (_OP_NAME.search(rest) or body).group(1)
+                bounds = [int(r.split(")")[0]) for *_, op, _, r in computations.get(cond.group(1), {}).values()
+                          if op == "constant" and r.split(")")[0].isdigit()]  # fmt: skip
+                turns[loop] = bounds[0] if len(bounds) == 1 else None
+                claim(body.group(1), loop)
+        claim(name, "entry")
+    found: dict[str, dict] = {}
+    for line in program_moves(hlo_text, at_least_bytes):
+        where, _, inst = line.partition(": ")
+        loop = owner.get(where, "entry")
+        inst, _, type_text = inst.partition(" = ")
+        found.setdefault(loop, {"turns": turns.get(loop), "moves": {}})["moves"][inst] = sum(_sizes(type_text.rpartition(" ")[0]))
+    return found

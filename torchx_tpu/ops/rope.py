@@ -11,6 +11,7 @@ import dataclasses
 import math
 from typing import Optional
 
+import jax
 import jax.numpy as jnp
 
 from torchx_tpu.ops.attention import note_traced
@@ -102,6 +103,49 @@ def apply_rope(
     c = cos[None, :, None, :]
     s = sin[None, :, None, :]
     return jnp.concatenate((x1 * c - x2 * s, x2 * c + x1 * s), axis=-1).astype(dtype)
+
+
+def _whole(x: jnp.ndarray, cos: jnp.ndarray, sin: jnp.ndarray) -> jnp.ndarray:
+    """``x c + (x P) s`` over whole heads: ``P`` the signed permutation that takes a head's ``(x1, x2)`` to
+    ``(-x2, x1)``, ``c`` and ``s`` the tables laid twice side by side. Every product of the matmul is an element of
+    ``x`` times 0, 1 or -1, so it is exact in ``x``'s own dtype (float32 at the highest precision, which the chip
+    needs to carry all 24 bits); the rotation itself is float32 and rounded once, as :func:`apply_rope`'s."""
+    note_traced("rotation", "whole_heads")
+    half = x.shape[-1] // 2
+    eye, zero = jnp.eye(half, dtype=x.dtype), jnp.zeros((half, half), x.dtype)
+    swapped = jnp.matmul(x, jnp.block([[zero, eye], [-eye, zero]]), precision="highest" if x.dtype == jnp.float32 else None)
+    c = jnp.concatenate((cos, cos), axis=-1)[None, :, None, :]
+    s = jnp.concatenate((sin, sin), axis=-1)[None, :, None, :]
+    return (x.astype(jnp.float32) * c + swapped.astype(jnp.float32) * s).astype(x.dtype)
+
+
+@jax.custom_vjp
+def apply_rope_whole(x: jnp.ndarray, cos: jnp.ndarray, sin: jnp.ndarray) -> jnp.ndarray:
+    """:func:`apply_rope` to the bit, for a training step: ``[batch, seq, heads, head_dim]`` rotated without
+    splitting a head into its halves.
+
+    :func:`apply_rope` splits the last axis at ``head_dim / 2`` and joins it again. Inside a serving program's
+    fusion over 16-128 rows that costs nothing; over a training step's ``[2, 4096, 32, 128]`` the chip's compiler
+    materialises the float32 halves (64 of 128 lanes each), re-lays them and joins them, in the forward, in the
+    recomputation and, transposed, in the backward: 4.6 ms a step of pure movement in ``mistral7b-train-4k`` and
+    more inside the fusions that compute (PERF.md section 6, PR 51). Here the halves change places in a
+    ``[head_dim, head_dim]`` matmul on the otherwise idle MXU (:func:`_whole`) and everything else works on whole
+    128-lane rows. The gradient is the inverse rotation, the same function at ``-sin``, so it needs no halves
+    either and rounds once as autodiff of :func:`apply_rope` does; ``cos`` and ``sin`` are tables made from
+    positions and get no gradient. ``ops.attention.traced("rotation")`` answers ``whole_heads``."""
+    return _whole(x, cos, sin)
+
+
+def _apply_rope_whole_fwd(x, cos, sin):  # noqa: ANN001, ANN202
+    return _whole(x, cos, sin), (cos, sin)
+
+
+def _apply_rope_whole_bwd(tables, g):  # noqa: ANN001, ANN202
+    cos, sin = tables
+    return _whole(g, cos, -sin), jnp.zeros_like(cos), jnp.zeros_like(sin)
+
+
+apply_rope_whole.defvjp(_apply_rope_whole_fwd, _apply_rope_whole_bwd)
 
 
 def rotate(x: jnp.ndarray, cos: jnp.ndarray, sin: jnp.ndarray) -> jnp.ndarray:
